@@ -8,15 +8,12 @@ engine with ``forward/backward/step`` plus data loader and LR scheduler.
 
 from deepspeed_tpu.version import __version__  # noqa: F401
 
-from deepspeed_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()  # older jax: jax.shard_map / sharding.set_mesh shims
-
 from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu import ops  # noqa: F401  (registers Pallas kernels, e.g. 'flash')
 from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401
 from deepspeed_tpu.config import DeepSpeedTpuConfig, from_config  # noqa: F401
 from deepspeed_tpu.parallel import Topology, build_mesh  # noqa: F401
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
 
 def initialize(model=None, config=None, optimizer=None, model_parameters=None,
@@ -47,6 +44,7 @@ def initialize(model=None, config=None, optimizer=None, model_parameters=None,
     if config is None and config_params is not None:
         config = config_params
     ds_config = from_config(config)
+    place_compile_cache()
     comm.init_distributed()
     engine_cls = DeepSpeedTpuEngine
     if ds_config.hybrid_engine.enabled:
@@ -83,6 +81,7 @@ def init_inference(model=None, config=None, checkpoint=None, dtype=None,
 
     from deepspeed_tpu.inference.engine import InferenceEngine
 
+    place_compile_cache()
     if checkpoint is not None and "params" not in kwargs:
         if _os.path.exists(_os.path.join(checkpoint, "config.json")):
             from deepspeed_tpu.inference.quant import parse_weight_dtype

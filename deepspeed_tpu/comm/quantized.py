@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu
 from deepspeed_tpu.comm.comm import _log
 from deepspeed_tpu.ops.quantization import (dequantize_blockwise,
                                             quantize_blockwise)
@@ -263,8 +264,14 @@ def two_hop_reduce_scatter(x: jax.Array, axis, slice_size: int,
                                 tiled=True)
     x = _slice_split(x, scatter_dim, s, m)
     _log("reduce_scatter_intra", x)
-    x = lax.psum_scatter(x, axis, scatter_dimension=scatter_dim, tiled=True,
-                         axis_index_groups=intra_groups(world, s))
+    # XLA:CPU's AllReducePromotion check-fails on a grouped bf16
+    # reduce-scatter inside a partial-manual region ("Invalid binary
+    # instruction opcode copy") and aborts the process: the CPU dev mesh
+    # reduces this hop in fp32, a TPU keeps the input dtype on ICI
+    wire = x.dtype if on_tpu() else jnp.float32
+    x = lax.psum_scatter(x.astype(wire), axis, scatter_dimension=scatter_dim,
+                         tiled=True, axis_index_groups=intra_groups(world, s)
+                         ).astype(x.dtype)
     return reduce_scatter_q(x, axis, bits=bits, block_size=block_size,
                             scatter_dim=scatter_dim,
                             axis_index_groups=cross_groups(world, s),

@@ -82,7 +82,9 @@ class InferenceEngineV2:
 
         from deepspeed_tpu.parallel import build_mesh
         from deepspeed_tpu.parallel import sharding as shd
+        from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
+        place_compile_cache()
         self.module = model
         self.cfg = model.cfg
         self.max_seq_len = max_seq_len or self.cfg.max_seq_len
@@ -185,6 +187,18 @@ class InferenceEngineV2:
         else:
             self.decode_kernel_mode = "xla"
         self.decode_kernel = decode_kernel
+        # what each compiled step really runs: the ops keep geometry rules
+        # that route to an XLA twin (head_dim % 128, > 256 rows, ...), so
+        # "pallas was asked for" is not "pallas runs"
+        self.kernel_paths = self._resolve_kernel_paths(max_sequences)
+        att, why = self.kernel_paths["decode"]["attention"]
+        if self.decode_kernel_mode != "xla" and att == "xla-twin":
+            self.decode_kernel_mode, self.decode_kernel_reason = "xla", why
+        log_dist("kernel paths: " + "; ".join(
+            f"{step} attention={v['attention'][0]} ({v['attention'][1]})"
+            + "".join(f" {n}={c[0]} ({c[1]})"
+                      for n, c in v["dequant_matmul"].items())
+            for step, v in self.kernel_paths.items()))
         # ---- MoE expert-parallel serving (moe.kernel / a2a wire / AutoEP).
         # Mirrors the decode-kernel selection above: the grouped-GEMM
         # kernel is resolved ONCE (probe + one logged fallback warning) and
@@ -406,6 +420,44 @@ class InferenceEngineV2:
         self._fused_saved_dispatches = 0
 
     _QUANT_LEAVES = QUANT_LEAVES
+
+    def _resolve_kernel_paths(self, max_sequences: int) -> Dict[str, dict]:
+        """Per compiled step kind (``decode``: 1-token atoms, one row per
+        sequence; ``prefill``: the widest atom), which of {pallas-native,
+        pallas-interpret, xla-twin} attention and each quantized projection
+        take, and why — from the ops' own geometry rules, at the unsharded
+        shapes."""
+        from deepspeed_tpu.models.transformer import QuantizedWeight
+        from deepspeed_tpu.ops.paged_attention import attention_kernel_path
+        from deepspeed_tpu.ops.quant_matmul import quant_matmul_path
+
+        interpret = self.decode_kernel_mode != "native"
+        pallas = "pallas-interpret" if interpret else "pallas-native"
+        qleaves = [
+            (jax.tree_util.keystr(path[-1:]), leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(
+                self.params,
+                is_leaf=lambda x: isinstance(x, QuantizedWeight))[0]
+            if isinstance(leaf, QuantizedWeight)]
+        itemsize = jnp.dtype(self.cfg.dtype).itemsize
+        out = {}
+        for step, tq, rows in (
+                ("decode", 1, max(8, 1 << (max_sequences - 1).bit_length())),
+                ("prefill", self.module.MAX_ATOM, self.module.MAX_ATOM)):
+            path, why = attention_kernel_path(
+                self.cfg.head_dim, self.block_size, tq, self.decode_kernel,
+                interpret)
+            mm = {}
+            for name, qw in qleaves:
+                F = qw.scales.shape[-1]
+                bf, mwhy = quant_matmul_path(
+                    rows, qw.din, F, qw.din // qw.scales.shape[-2], itemsize)
+                mm[name] = (pallas if bf else "xla-twin", mwhy)
+            out[step] = {
+                "rows": rows,
+                "attention": (pallas if path == "pallas" else "xla-twin", why),
+                "dequant_matmul": mm}
+        return out
 
     def _quantize_weights(self, params, bits: int):
         from deepspeed_tpu.inference.quant import quantize_serving_params
@@ -1970,8 +2022,7 @@ class InferenceEngineV2:
             # host scheduling vs dispatch vs device+transfer accounting:
             # host_ms is pure python/numpy batch building, dispatch_ms is
             # the async jit call (argument transfer + enqueue), fetch_ms
-            # blocks on the device step + the logits D2H (on a tunneled
-            # runtime it also carries the transport RTT)
+            # blocks on the device step + the logits D2H
             self.timing = {
                 "host_ms": (t_host - t_put) * 1e3,
                 "dispatch_ms": (t_disp - t_host) * 1e3,

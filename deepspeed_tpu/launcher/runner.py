@@ -63,9 +63,23 @@ def filter_hosts(hosts: Dict[str, int], include: str = "", exclude: str = ""
     return out
 
 
+def _host_has_tpu() -> bool:
+    """TPU device nodes on this host, asked without touching JAX (a launcher
+    that initialised the backend would hold the chip its child needs)."""
+    import glob
+
+    return os.environ.get("JAX_PLATFORMS") != "cpu" and bool(
+        glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def _spawn_local(args, env_base) -> int:
     """Single-host / multi-process local launch (launch.py:237 spawn loop)."""
     nprocs = max(args.num_procs, 1)
+    if nprocs > 1 and _host_has_tpu():
+        raise SystemExit(
+            "--num_procs > 1 on a TPU host: a chip belongs to one process, "
+            "and one process drives all local chips — launch one process "
+            "per host (--num_procs is for CPU-backend development)")
     procs: List[subprocess.Popen] = []
     coordinator = f"127.0.0.1:{args.master_port}"
 

@@ -462,10 +462,11 @@ def _grouped_moe_ep(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         else:
             dest = flat_e // e_local
             lslot = flat_e % e_local
-        if _TRACKER is not None:
-            cnt = jnp.sum(jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-                          * real_pairs[:, None].astype(jnp.int32), axis=0)
-            jax.debug.callback(_emit_expert_counts, cnt)
+        # this shard's routed-token counts leave the region as an output:
+        # jax 0.9's debug-callback lowering fails inside a region that
+        # leaves more than one mesh axis auto, so the callback sits outside
+        cnt = jnp.sum(jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
+                      * real_pairs[:, None].astype(jnp.int32), axis=0)
         oh = jax.nn.one_hot(dest, ep, dtype=jnp.int32) \
             * real_pairs[:, None].astype(jnp.int32)
         slot = jnp.sum((jnp.cumsum(oh, axis=0) - oh) * oh, axis=1)  # per-dest pos
@@ -501,7 +502,7 @@ def _grouped_moe_ep(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         wgt = topk_vals.reshape(-1).astype(dt) * keep          # invalid rows: 0
         y_pair = y_back[dest, jnp.minimum(slot, cap - 1)]      # [n, D]
         out = jnp.zeros((s_local, D), dt).at[tok].add(y_pair * wgt[:, None])
-        return out, aux
+        return out, aux, cnt
 
     ew = P("ep", None, None)
     experts = {n: v for n, v in w.items() if n != "router"}
@@ -512,22 +513,21 @@ def _grouped_moe_ep(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     x2 = h.reshape(S, D)
     v2 = (jnp.ones((S,), bool) if valid is None else valid.reshape(S))
     if s_pad != S:
-        # pad, not concatenate: resharding a concatenate into the ep region
-        # trips a 0.4.x SPMD partitioner bug (the shard→replicated move is an
-        # add-all-reduce that double-counts the replicas of unmentioned mesh
-        # axes, scaling every row by the dp world size); jnp.pad lowers to a
-        # collective-free layout on every jax we target
+        # jnp.pad lowers to a collective-free layout into the ep region
         x2 = jnp.pad(x2, ((0, s_pad - S), (0, 0)))
         v2 = jnp.pad(v2, (0, s_pad - S))
     # router enters replicated-over-ep in fp32: its cotangent is a psum over
     # ep, and a *bf16* replicated-in grad trips an XLA:CPU check failure in
     # AllReducePromotion (all-reduce with copy reduction); fp32 sidesteps it
     # and is what _route computes in anyway.
-    out2, aux = jax.shard_map(
+    out2, aux, cnt = jax.shard_map(
         shard, mesh=mesh,
         in_specs=(P("ep", None), P("ep"), P(None, None), especs),
-        out_specs=(P("ep", None), P()), axis_names={"ep"},
+        out_specs=(P("ep", None), P(), P("ep")), axis_names={"ep"},
         check_vma=False)(x2, v2, w["router"].astype(jnp.float32), experts)
+    if _TRACKER is not None:
+        jax.debug.callback(_emit_expert_counts,
+                           cnt.reshape(ep, E).sum(axis=0))
     if s_pad != S:
         # the sliced-off-pad result has no expressible ep sharding — pin it
         # replicated (pad only occurs at decode-sized S, where this is cheap)

@@ -13,6 +13,8 @@ from typing import Callable, Dict, List, Optional, Type
 
 import jax
 
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu
+
 
 class OpBuilder:
     """Compatibility/discovery shim (reference ``op_builder/builder.py`` OpBuilder)."""
@@ -25,12 +27,7 @@ class OpBuilder:
     def load(self) -> Callable:
         raise NotImplementedError
 
-    @staticmethod
-    def on_tpu() -> bool:
-        try:
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+    on_tpu = staticmethod(on_tpu)
 
 
 class FlashAttentionBuilder(OpBuilder):
@@ -84,17 +81,56 @@ def op_report() -> List[tuple]:
     return [(name, cls().is_compatible()) for name, cls in ALL_OPS.items()]
 
 
+def _flash_on_mesh(q, k, v, **kw):
+    """The flash kernel under whatever mesh is ambient. Mosaic kernels cannot
+    be partitioned automatically: on a mesh with several devices the kernel
+    runs per shard — batch over the data axes (the engine's batch layout),
+    heads over tp. A layout that cannot run it per shard (sequence-sharded,
+    indivisible batch or heads) takes XLA attention, with a warning."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.models.transformer import xla_attention
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+    from deepspeed_tpu.utils.logging import logger
+
+    mesh = jax.sharding.get_abstract_mesh()
+    live = [] if mesh is None or mesh.empty else [
+        a for a in mesh.axis_names
+        if a not in mesh.manual_axes and mesh.shape[a] > 1]
+    if not live:                            # one device, or all manual
+        return flash_attention(q, k, v, **kw)
+    batch = tuple(a for a in ("dp", "fsdp") if a in live)
+    heads = "tp" if "tp" in live else None
+    nb = 1
+    for a in batch:
+        nb *= mesh.shape[a]
+    if "sp" in live or q.shape[0] % nb or (heads and (
+            q.shape[2] % mesh.shape["tp"] or k.shape[2] % mesh.shape["tp"])):
+        logger.warning(
+            f"attention_impl auto: flash kernel cannot run per shard for "
+            f"q{q.shape} on mesh {dict(mesh.shape)} — XLA attention runs "
+            f"instead (a sequence-sharded mesh wants 'ulysses' or 'ring')")
+        return xla_attention(q, k, v, **kw)
+    spec = P(batch or None, None, heads, None)
+    # every remaining axis goes manual (unnamed ones replicate): Mosaic
+    # refuses a region that is only partly manual
+    return jax.shard_map(
+        lambda a, b, c: flash_attention(a, b, c, **kw), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=set(mesh.axis_names) - set(mesh.manual_axes),
+        check_vma=False)(q, k, v)
+
+
 def _register_model_attention() -> None:
     """Plug the flash kernel into the model attention registry ('auto' dispatch)."""
     from deepspeed_tpu.models import transformer as tfm
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
     def flash_or_xla(q, k, v, *, causal=True, segment_ids=None, window=None):
-        if OpBuilder.on_tpu():
-            return flash_attention(q, k, v, causal=causal,
-                                   segment_ids=segment_ids, window=window)
-        return tfm.xla_attention(q, k, v, causal=causal,
-                                 segment_ids=segment_ids, window=window)
+        kw = dict(causal=causal, segment_ids=segment_ids, window=window)
+        if on_tpu():
+            return _flash_on_mesh(q, k, v, **kw)
+        return tfm.xla_attention(q, k, v, **kw)
 
     tfm.register_attention_impl("flash", flash_or_xla)
     tfm.register_attention_impl("flash_pallas", flash_attention)  # force kernel (tests)

@@ -34,31 +34,50 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 def decode_kernel_support() -> Tuple[Optional[str], str]:
     """How the fused Pallas decode kernel can run on this backend:
     ``("native", why)`` on TPU (Mosaic lowering), ``("interpret", why)`` on
     CPU (the CI parity mode), ``(None, why)`` anywhere else — the engine
-    logs ``why`` and falls back to ``decode_kernel: xla``."""
-    try:
-        backend = jax.default_backend()
-    except Exception as e:                     # no devices / broken runtime
-        return None, f"backend probe failed: {e!r}"
+    logs ``why`` and falls back to ``decode_kernel: xla``. A runtime that
+    cannot initialise raises."""
+    backend = jax.default_backend()
     if backend == "tpu":
         return "native", "TPU backend: Mosaic lowering available"
     if backend == "cpu":
         return "interpret", "CPU backend: Pallas interpret mode"
     return None, (f"backend {backend!r} has no Pallas TPU lowering "
                   f"(only tpu/native and cpu/interpret are supported)")
+
+
+def attention_kernel_path(d: int, bs: int, tq: int, kernel: str = "pallas",
+                          interpret: Optional[bool] = None
+                          ) -> Tuple[str, str]:
+    """Which implementation the paged attention entry points run for head
+    dim ``d``, block size ``bs`` and atom width ``tq``: ``("pallas", why)``
+    or ``("xla", why)`` — the dense-gather twin, numerically identical.
+    Mosaic wants 128-lane-aligned DMA chunks and reshapes; geometries off
+    the serving sweet spot (small head_dim models, tiny test configs) take
+    the twin. The dispatch below and the engine's ``kernel_paths`` record
+    both read this one rule."""
+    if _check_kernel(kernel):
+        return "xla", "kernel='xla' requested"
+    if interpret is None:
+        interpret = not _on_tpu()
+    if interpret:
+        return "pallas", "interpret mode takes every geometry"
+    if d % 128:
+        return "xla", f"head_dim {d} is not a multiple of the 128 lanes"
+    if bs % 8:
+        return "xla", f"block_size {bs} is not a multiple of 8 sublanes"
+    if tq > 1 and bs % 128:
+        return "xla", (f"block_size {bs} is not a multiple of 128 "
+                       f"(prefill atoms read whole 128-row blocks)")
+    return "pallas", "Mosaic lowering"
 
 
 def _check_kernel(kernel: str) -> bool:
@@ -308,6 +327,12 @@ _DECODE_G = 8       # KV blocks per decode work item (one DMA pair per item)
 _PAST_G = 2         # KV blocks per prefill-past work item (bigger per-block
                     # compute; smaller groups keep VMEM under the 16MB cap)
 _DMA_DEPTH = 3      # work-item fetches kept in flight across the work list
+# The past kernel keeps (m, l, acc) for all H*tq rows of an atom in VMEM:
+# 18.3 MiB at H=32/K=8/d=128 with the widest atom (tq=256), over Mosaic's
+# 16 MiB default scoped limit — the chip's compiler refuses it. v5e has
+# 128 MiB of VMEM; raise the kernel's own limit so every tq <= MAX_ATOM
+# the engine can schedule compiles.
+_PAST_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _worklist_helpers(n_items, NG, G, bs, nb_max, slot_ref, nblk_ref, lo_ref,
@@ -555,7 +580,6 @@ def decode_pool_partials(q, k_pool, v_pool, layer, block_tables, atom_slot,
     dense-gather twin — same math, for A/B benching and as the logged
     fallback when Pallas is unavailable.
     Returns fp32 acc [A, H, d] (unnormalized), m/l [A, H]."""
-    use_xla = _check_kernel(kernel)
     if interpret is None:
         interpret = not _on_tpu()
     A, H, d = q.shape
@@ -567,7 +591,7 @@ def decode_pool_partials(q, k_pool, v_pool, layer, block_tables, atom_slot,
     quantized = kv_scale is not None
     if row_pos is None:
         row_pos = atom_pos0
-    if use_xla or (not interpret and (d % 128 or bs % 8)):
+    if attention_kernel_path(d, bs, 1, kernel, interpret)[0] == "xla":
         return xla_decode_partials(q, k_pool, v_pool, layer, block_tables,
                                    atom_slot, atom_pos0, window=window,
                                    row_pos=row_pos, kv_scale=kv_scale,
@@ -1017,6 +1041,8 @@ def _prefill_attention(q, k_self, v_self, k_pool, v_pool, layer,
                 jax.ShapeDtypeStruct((A, K, R, 128), jnp.float32),
                 jax.ShapeDtypeStruct((A, K, R, 128), jnp.float32),
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_PAST_VMEM_LIMIT),
             interpret=interpret,
         )(layer.reshape(1).astype(jnp.int32), atom_slot.astype(jnp.int32),
           pos0, lo, nblk, ng, block_tables.astype(jnp.int32), *operands)
@@ -1109,7 +1135,6 @@ def ragged_paged_attention(q: jax.Array, k_self: jax.Array, v_self: jax.Array,
     ``kernel='xla'`` forces the dense-gather reference path for every atom
     (``inference.decode_kernel`` — A/B benching and the no-Pallas
     fallback). Returns [N, H, d]."""
-    use_xla = _check_kernel(kernel)
     if interpret is None:
         interpret = not _on_tpu()
     N, H, d = q.shape
@@ -1125,12 +1150,7 @@ def ragged_paged_attention(q: jax.Array, k_self: jax.Array, v_self: jax.Array,
     if layer is None:
         raise ValueError("stacked pools need a layer index")
     bs = k_pool.shape[2]
-    # Mosaic wants 128-lane-aligned DMA chunks and reshapes; geometries off
-    # the serving sweet spot (small head_dim models, tiny test configs) take
-    # the dense-gather XLA path instead — numerically identical. An
-    # explicit kernel='xla' takes the same route unconditionally.
-    if use_xla or (not interpret
-                   and (d % 128 or bs % 8 or (tq > 1 and bs % 128))):
+    if attention_kernel_path(d, bs, tq, kernel, interpret)[0] == "xla":
         kp = jax.lax.dynamic_index_in_dim(k_pool, layer, keepdims=False)
         vp = jax.lax.dynamic_index_in_dim(v_pool, layer, keepdims=False)
         if kv_scale is not None and kv_bits == 4:

@@ -29,11 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_tpu() -> bool:
-    from deepspeed_tpu.ops import OpBuilder  # single source of backend truth
-
-    return OpBuilder.on_tpu()
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
 
 
 def quantize_matmul_weight(w: jax.Array, bits: int = 4, group: int = 128
@@ -105,6 +101,34 @@ def _qmm_stacked_kernel(li_ref, x_ref, q_ref, s_ref, o_ref, *, bits: int,
                          group=group, n_g=n_g).astype(o_ref.dtype)
 
 
+def quant_matmul_path(B: int, D: int, F: int, group: int, itemsize: int = 2,
+                      block_f: int = 512) -> Tuple[int, str]:
+    """The kernel's geometry rule, in one place: ``(bf, why)`` with ``bf``
+    the f-block the Pallas kernel runs with, or 0 when the XLA
+    dequant-then-matmul twin runs instead (tiny shapes, large activation
+    batches, a VMEM budget the whole-x block blows). Read by both wrappers
+    below and by the engine's ``kernel_paths`` record."""
+    if D % 128 or F % 128 or group % 128:
+        return 0, f"D={D}/F={F}/group={group} not multiples of 128 lanes"
+    if B > 256:
+        # large-B (prefill) shapes are compute-bound — the XLA fallback
+        # fuses the dequant into the dot's operand read
+        return 0, f"{B} rows > 256: compute-bound, XLA fuses the dequant"
+    bf = min(block_f, F)
+    while F % bf:
+        bf //= 2
+    # VMEM budget: the whole-x (B, D) block + unpacked bf16 [D, bf] tile +
+    # double-buffered packed input must fit; shrink the f-block for wide D
+    # and fall back entirely when x alone blows the budget
+    x_bytes = B * D * itemsize
+    while bf > 128 and D * bf * 3 + x_bytes > 10 * 1024 * 1024:
+        bf //= 2
+    if bf % 128 or D * bf * 3 + x_bytes > 12 * 1024 * 1024:
+        return 0, (f"x [{B}, {D}] + one [D, {bf}] weight tile exceed the "
+                   f"12 MiB VMEM budget")
+    return bf, "Mosaic lowering"
+
+
 def quantized_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
                      bits: int = 4, block_f: int = 512,
                      interpret: bool = None, layer=None) -> jax.Array:
@@ -126,20 +150,8 @@ def quantized_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array,
     G, F = scales.shape
     group = D // G
     assert packed.shape[0] == (D // 2 if bits == 4 else D)
-    if D % 128 or F % 128 or group % 128 or B > 256:
-        # large-B (prefill) shapes are compute-bound — the XLA fallback
-        # fuses the dequant into the dot's operand read
-        return x @ dequantize_matmul_weight(packed, scales, bits, D)
-    bf = min(block_f, F)
-    while F % bf:
-        bf //= 2
-    # VMEM budget: the whole-x (B, D) block + unpacked bf16 [D, bf] tile +
-    # double-buffered packed input must fit; shrink the f-block for wide D
-    # and fall back entirely when x alone blows the budget
-    x_bytes = B * D * x.dtype.itemsize
-    while bf > 128 and D * bf * 3 + x_bytes > 10 * 1024 * 1024:
-        bf //= 2
-    if bf % 128 or D * bf * 3 + x_bytes > 12 * 1024 * 1024:
+    bf, _ = quant_matmul_path(B, D, F, group, x.dtype.itemsize, block_f)
+    if not bf:
         return x @ dequantize_matmul_weight(packed, scales, bits, D)
     rows = group // 2 if bits == 4 else group
     kernel = functools.partial(_qmm_kernel, bits=bits, group=group, n_g=G)
@@ -171,15 +183,8 @@ def _quantized_matmul_stacked(x, packed, scales, bits, block_f, interpret,
         sl_ = jax.lax.dynamic_index_in_dim(scales, layer, 0, keepdims=False)
         return x @ dequantize_matmul_weight(pl_, sl_, bits, D)
 
-    if D % 128 or F % 128 or group % 128 or B > 256:
-        return _fallback()
-    bf = min(block_f, F)
-    while F % bf:
-        bf //= 2
-    x_bytes = B * D * x.dtype.itemsize
-    while bf > 128 and D * bf * 3 + x_bytes > 10 * 1024 * 1024:
-        bf //= 2
-    if bf % 128 or D * bf * 3 + x_bytes > 12 * 1024 * 1024:
+    bf, _ = quant_matmul_path(B, D, F, group, x.dtype.itemsize, block_f)
+    if not bf:
         return _fallback()
     kernel = functools.partial(_qmm_stacked_kernel, bits=bits, group=group,
                                n_g=G)

@@ -72,9 +72,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis: str = "sp",
 
     # mark the fresh accumulators as device-varying over the ring axis so the scan
     # carry type matches the computed updates (shard_map vma check)
-    m0 = lax.pvary(jnp.full((B, H, Tl, 1), NEG_INF, jnp.float32), axis)
-    l0 = lax.pvary(jnp.zeros((B, H, Tl, 1), jnp.float32), axis)
-    acc0 = lax.pvary(jnp.zeros((B, Tl, H, d), jnp.float32), axis)
+    m0 = lax.pcast(jnp.full((B, H, Tl, 1), NEG_INF, jnp.float32), axis, to="varying")
+    l0 = lax.pcast(jnp.zeros((B, H, Tl, 1), jnp.float32), axis, to="varying")
+    acc0 = lax.pcast(jnp.zeros((B, Tl, H, d), jnp.float32), axis, to="varying")
     (k_f, v_f, m, l, acc), _ = lax.scan(step, (k, v, m0, l0, acc0), jnp.arange(n))
     denom = jnp.maximum(l, 1e-30).transpose(0, 2, 1, 3)  # [B, Tl, H, 1]
     return (acc / denom).astype(q.dtype)

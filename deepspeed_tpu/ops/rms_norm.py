@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu
+
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[:].astype(jnp.float32)
@@ -42,7 +44,7 @@ def _rms_pallas(x2d: jax.Array, w: jax.Array, eps: float, block_rows: int,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _rms(x2d, w, eps):
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     block = 256
     n = x2d.shape[0]
     while n % block != 0:

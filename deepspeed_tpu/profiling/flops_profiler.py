@@ -24,14 +24,6 @@ def profile_fn(fn: Callable, *args, static_argnums=(), **kwargs) -> Dict[str, fl
     lowered = jitted.lower(*args, **kwargs)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        # jax 0.4.x returns one dict per device computation; merge by sum
-        merged: Dict[str, float] = {}
-        for c in cost:
-            for k, v in (c or {}).items():
-                if isinstance(v, (int, float)):
-                    merged[k] = merged.get(k, 0.0) + float(v)
-        cost = merged
     out = {"flops": float(cost.get("flops", 0.0)),
            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
     try:

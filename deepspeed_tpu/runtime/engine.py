@@ -547,10 +547,16 @@ class DeepSpeedTpuEngine:
     # ---- fp16 dynamic loss scaler (loss_scaler.py:187 parity) ----------
     def _init_scaler_state(self) -> Dict[str, jax.Array]:
         c = self.config.fp16
-        if not self.fp16_enabled:
-            return {"scale": jnp.float32(1.0), "good_steps": jnp.int32(0)}
-        init_scale = c.loss_scale if c.loss_scale > 0 else 2.0 ** c.initial_scale_power
-        return {"scale": jnp.float32(init_scale), "good_steps": jnp.int32(0)}
+        init_scale = 1.0
+        if self.fp16_enabled:
+            init_scale = (c.loss_scale if c.loss_scale > 0
+                          else 2.0 ** c.initial_scale_power)
+        # placed on the mesh like the step jits' outputs: an unplaced scalar
+        # has a different input type than the mesh-typed one the first step
+        # returns, which recompiled every step program on its second call
+        return jax.device_put(
+            {"scale": np.float32(init_scale), "good_steps": np.int32(0)},
+            NamedSharding(self.mesh, P()))
 
     def _scaler_update(self, scaler, finite):
         c = self.config.fp16

@@ -241,7 +241,7 @@ class PipelineModule:
             stage_fn = jax.checkpoint(stage_fn)
 
         D = params["embed"]["tokens"].shape[1]
-        state = lax.pvary(jnp.zeros((mb, T, D), dt), "pp")
+        state = lax.pcast(jnp.zeros((mb, T, D), dt), "pp", to="varying")
         perm = [(i, (i + 1) % n) for i in range(n)]
 
         # GPipe schedule, unrolled over the (static) M + n - 1 ticks. Unrolling

@@ -94,12 +94,6 @@ def sp_shard_map(inner: Callable, q: jax.Array, k: jax.Array, v: jax.Array,
     parent_manual = set(getattr(mesh, "manual_axes", ()) or ())
     if axis in parent_manual:
         return inner(q, k, v)
-    # 0.4.x compat: the full-manual shard_map shim binds every mesh axis, so
-    # ``axis`` may be manual with REPLICATED data (the enclosing region never
-    # sharded it) and re-entry is impossible — dense fallback computes the
-    # identical result on the replicated sequence.
-    if axis in set(getattr(mesh, "compat_replicated_axes", ()) or ()):
-        return None
     from jax.sharding import PartitionSpec as P
 
     axes = {axis}

@@ -151,16 +151,11 @@ class WarmStartCache:
             "warm_load_failures": 0, "cold_builds": 0, "warm_builds": 0,
         }
         if executable_cache:
-            # best-effort: the JAX persistent compilation cache makes the
-            # executable half of the warm start survive process restarts
-            try:
-                import jax
+            # the JAX persistent compilation cache makes the executable
+            # half of the warm start survive process restarts
+            from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
-                jax.config.update("jax_compilation_cache_dir",
-                                  os.path.join(cache_dir, "xla"))
-            except Exception as e:
-                logger.warning(f"serving: persistent compilation cache "
-                               f"unavailable: {e!r}")
+            place_compile_cache()
 
     # ------------------------------------------------------------------
     # storage plumbing

@@ -403,6 +403,11 @@ class TestCoordination:
         recorded in its manifest."""
         fleet = ThreadFleet(2)
         tags = [None, None]
+        # the simulated processes are threads of ONE process: two orbax saves
+        # at once race on its process-wide temp-dir bookkeeping ("Checkpoint
+        # directory does not exist ... orbax-checkpoint-tmp", seen under
+        # load), which real processes cannot do to each other
+        one_save_at_a_time = threading.Lock()
 
         def proc(rank):
             eng = _fake_engine(step=5)
@@ -416,8 +421,9 @@ class TestCoordination:
             assert decision == SAVE           # ...but BOTH agree to save
             mgr.preempted = False
             tag = f"preempt_step{eng.global_steps}"
-            mgr.save(eng, tag=tag, emergency=True,
-                     decision=coord.decision_record())
+            with one_save_at_a_time:
+                mgr.save(eng, tag=tag, emergency=True,
+                         decision=coord.decision_record())
             tags[rank] = tag
 
         fleet.run(proc)
