@@ -523,36 +523,60 @@ def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
     return out + w["b_down"] if "b_down" in w else out
 
 
+#: the scopes of the fused train step: every operation of the step program
+#: lies under one of them (``grad_accum`` and ``optimizer`` are the engine's,
+#: ``layers`` is the layer loop's own slicing and stacking; inside it a block's
+#: scope, the innermost, is the one that counts). tests/unit/test_step_scopes.py
+#: holds the lowered program to this; benchmarks/readers/program.py sums
+#: device time by them.
+STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
+               "lm_head", "loss", "grad_accum", "optimizer")
+
+
+def _cast_layers(w: Params, dt, ffn: str) -> Params:
+    """fp32 master weights of one block (or the whole stack) to the compute
+    dtype, each under the scope of the block that reads it."""
+    out = {}
+    for k, v in w.items():
+        with jax.named_scope("attn" if k in ("ln1", "attn") else ffn):
+            out[k] = jax.tree_util.tree_map(
+                lambda p: p.astype(dt) if p.dtype == jnp.float32 else p, v)
+    return out
+
+
 def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                       freqs: Optional[jax.Array], attn_fn: Callable,
                       moe_fn: Optional[Callable] = None,
                       positions: Optional[jax.Array] = None) -> Any:
     """One pre-norm decoder block. Returns (x, aux_loss). ``positions`` [B, T]
     overrides RoPE positions (random-LTD token subsets)."""
-    dt = jnp.dtype(cfg.dtype)
-    wc = jax.tree_util.tree_map(lambda p: p.astype(dt) if p.dtype == jnp.float32 else p, w)
-    hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
     # named scopes land in HLO op metadata — the per-module profiler
-    # (profiling/flops_profiler.per_module_profile) groups cost by them
+    # (profiling/flops_profiler.per_module_profile) and the benchmark's
+    # device-time-by-scope reader group cost by them. Every operation of the
+    # block lies under one of STEP_SCOPES: the first norm with attention, the
+    # residual adds and the second norm with the FFN.
+    ffn = "moe" if moe_fn is not None else "mlp"
+    wc = _cast_layers(w, jnp.dtype(cfg.dtype), ffn)
     with jax.named_scope("attn"):
+        hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
         attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                    positions=positions)
-    if cfg.parallel_block:
-        # falcon/gpt-neox: attn and mlp branch from the SAME residual input
-        h = hn1 if cfg.parallel_shared_norm else _norm(x, wc["ln2"], cfg.norm,
-                                                       cfg.norm_eps)
-    else:
-        x = x + attn_out
-        h = _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps)
-    if moe_fn is not None:
-        with jax.named_scope("moe"):
+    with jax.named_scope(ffn):
+        if cfg.parallel_block:
+            # falcon/gpt-neox: attn and mlp branch from the SAME residual
+            # input
+            h = hn1 if cfg.parallel_shared_norm else _norm(
+                x, wc["ln2"], cfg.norm, cfg.norm_eps)
+        else:
+            x = x + attn_out
+            h = _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps)
+        if moe_fn is not None:
             mlp_out, aux = moe_fn(h, wc["mlp"], cfg)
-    else:
-        with jax.named_scope("mlp"):
+        else:
             mlp_out = mlp_block(h, wc["mlp"], cfg)
-        aux = jnp.zeros((), jnp.float32)
-    x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
-    return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
+            aux = jnp.zeros((), jnp.float32)
+        x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
+        return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
 
 
 def _maybe_remat(fn: Callable, policy: str) -> Callable:
@@ -713,7 +737,7 @@ class TransformerLM:
         """hidden [B, T, D] → logits [B, T, V] with the canonical sharding."""
         with jax.named_scope("lm_head"):
             logits = self._head_proj(params, hidden)
-        return constrain(logits, P(("dp", "fsdp"), "sp", "tp"))
+            return constrain(logits, P(("dp", "fsdp"), "sp", "tp"))
 
     def logits(self, params: Params, input_ids: jax.Array,
                positions: Optional[jax.Array] = None,
@@ -750,23 +774,36 @@ class TransformerLM:
         head) — the input of the tiled logits loss."""
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
-        x = params["embed"]["tokens"].astype(dt)[input_ids]
-        if cfg.learned_pos:
-            T = input_ids.shape[1]
-            pos_emb = (params["embed"]["pos"][:T] if positions is None
-                       else params["embed"]["pos"][positions])
-            x = x + pos_emb.astype(dt)
-        x = constrain(x, P(("dp", "fsdp"), "sp", None))
+        with jax.named_scope("embed"):
+            x = params["embed"]["tokens"].astype(dt)[input_ids]
+            if cfg.learned_pos:
+                T = input_ids.shape[1]
+                pos_emb = (params["embed"]["pos"][:T] if positions is None
+                           else params["embed"]["pos"][positions])
+                x = x + pos_emb.astype(dt)
+            x = constrain(x, P(("dp", "fsdp"), "sp", None))
         attn_fn = get_attention_impl(cfg.attention_impl)
         freqs = self._freqs
+        with jax.named_scope("layers"):
+            x = self._run_layers(params, x, input_ids, attn_fn, freqs,
+                                 ltd_seed, pld_theta)
+        with jax.named_scope("final_norm"):
+            x = _norm(x, {k: v for k, v in params["final_norm"].items()},
+                      cfg.norm, cfg.norm_eps)
+            return constrain(x, P(("dp", "fsdp"), "sp", None))
 
+    def _run_layers(self, params: Params, x: jax.Array, input_ids: jax.Array,
+                    attn_fn: Callable, freqs, ltd_seed, pld_theta
+                    ) -> jax.Array:
+        """The layer stack on embedded ``x``; leaves the summed MoE aux loss
+        in ``_last_aux_loss``."""
+        cfg = self.cfg
         # Cast the whole layer stack to compute dtype ONCE, outside the layer
         # scan: the per-layer cast inside transformer_block then no-ops. Done
         # per layer (and re-done under remat) this was a full extra pass over
         # the fp32 master weights every micro-batch.
-        layers = jax.tree_util.tree_map(
-            lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-            params["layers"])
+        layers = _cast_layers(params["layers"], jnp.dtype(cfg.dtype),
+                              "moe" if self.moe_fn is not None else "mlp")
 
         segs = self._window_segments()
         T = input_ids.shape[1]
@@ -803,10 +840,8 @@ class TransformerLM:
                         xi = jax.tree_util.tree_map(lambda p: p[i], seg_layers)
                         x, aux = seg_body(x, xi)
                         aux_total = aux_total + aux
-            x = _norm(x, {k: v for k, v in params["final_norm"].items()},
-                      cfg.norm, cfg.norm_eps)
             self._last_aux_loss = aux_total
-            return constrain(x, P(("dp", "fsdp"), "sp", None))
+            return x
         if ltd or pld_theta is not None:
             # shared routing key for LTD/PLD: step seed (engine-provided,
             # fresh per step/epoch) folded with batch content (fresh per
@@ -880,10 +915,8 @@ class TransformerLM:
                 xi = jax.tree_util.tree_map(lambda p: p[i], layers)
                 x, aux = body(x, (xi, jnp.int32(i)) if wrapped else xi)
                 aux_total = aux_total + aux
-        x = _norm(x, {k: v for k, v in params["final_norm"].items()}, cfg.norm,
-                  cfg.norm_eps)
         self._last_aux_loss = aux_total
-        return constrain(x, P(("dp", "fsdp"), "sp", None))
+        return x
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -920,14 +953,16 @@ class TransformerLM:
             params, batch["input_ids"],
             ltd_seed=None if seed is None else seed[0],
             pld_theta=None if pld is None else pld[0])
-        if cfg.loss_tiling > 1:
-            loss = self._tiled_loss(params, batch, hidden)
-        else:
-            loss = lm_loss(cfg, self._project(params, hidden), batch)
-        aux = getattr(self, "_last_aux_loss", None)
-        if aux is not None and cfg.num_experts > 1:
-            loss = loss + cfg.moe_aux_loss_coef * aux
-        return loss
+        # the tiled loss holds the head matmul too, so it has no lm_head scope
+        logits = (None if cfg.loss_tiling > 1
+                  else self._project(params, hidden))
+        with jax.named_scope("loss"):
+            loss = (self._tiled_loss(params, batch, hidden) if logits is None
+                    else lm_loss(cfg, logits, batch))
+            aux = getattr(self, "_last_aux_loss", None)
+            if aux is not None and cfg.num_experts > 1:
+                loss = loss + cfg.moe_aux_loss_coef * aux
+            return loss
 
     # ---- decode path (KV cache) ------------------------------------------
     def init_kv_cache(self, batch_size: int, max_seq_len: Optional[int] = None,

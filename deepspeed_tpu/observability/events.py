@@ -36,7 +36,9 @@ Bounded by construction: the ring drops the oldest event, never grows,
 never blocks. Disabled cost is one attribute check per ``emit`` (and the
 instrumented call sites guard on ``bus.enabled`` before building args, so
 a disabled bus costs an attribute load + branch — measured ~0 in
-``obs_drill --scenario tracing-overhead``).
+``obs_drill --scenario tracing-overhead``). :meth:`EventBus.span` is the
+exception: it is also the program's one path into the profiler's trace, so
+it enters a ``TraceAnnotation`` whether or not the rings are enabled.
 
 Sampling is per-*trace* and deterministic: :meth:`EventBus.mint_trace`
 keeps every ``sample``-th minted trace id (count-based, no wall clock), so
@@ -55,6 +57,8 @@ import threading
 import time
 from collections import deque
 from typing import Dict, Iterable, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["TraceEvent", "EventBus", "get_bus", "set_bus",
            "configure_tracing", "PHASES", "SAMPLED_OUT"]
@@ -96,41 +100,29 @@ class TraceEvent(NamedTuple):
         return out
 
 
-class _NoopSpan:
-    """Returned by :meth:`EventBus.span` when tracing is off — one shared
-    instance, so a disabled span costs no allocation."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_SPAN = _NoopSpan()
-
-
 class _Span:
-    """Context manager pairing ``B``/``E`` on the calling thread. The
-    ``finally`` semantics of ``with`` guarantee the ``E`` lands on every
-    exit path — the exact lifecycle discipline the dslint ``event-span``
-    rule enforces on hand-rolled begin/end pairs."""
+    """Context manager pairing ``B``/``E`` on the calling thread, inside the
+    span's profiler annotation. The ``finally`` semantics of ``with``
+    guarantee the ``E`` lands on every exit path — the exact lifecycle
+    discipline the dslint ``event-span`` rule enforces on hand-rolled
+    begin/end pairs."""
 
-    __slots__ = ("bus", "cat", "name", "trace_id", "parent_id", "args")
+    __slots__ = ("bus", "cat", "name", "trace_id", "parent_id", "args",
+                 "ann")
 
     def __init__(self, bus: "EventBus", cat: str, name: str,
                  trace_id: Optional[int], parent_id: Optional[int],
-                 args: Optional[dict]):
+                 args: Optional[dict], ann: TraceAnnotation):
         self.bus = bus
         self.cat = cat
         self.name = name
         self.trace_id = trace_id
         self.parent_id = parent_id
         self.args = args
+        self.ann = ann
 
     def __enter__(self):
+        self.ann.__enter__()
         self.bus.emit("B", self.cat, self.name, trace_id=self.trace_id,
                       parent_id=self.parent_id, args=self.args)
         return self
@@ -139,6 +131,7 @@ class _Span:
         self.bus.emit("E", self.cat, self.name, trace_id=self.trace_id,
                       args=({"error": repr(exc)[:200]}
                             if exc_type is not None else None))
+        self.ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -238,12 +231,19 @@ class EventBus:
         self.emit("n", cat, name, trace_id=trace_id, args=args)
 
     def span(self, cat: str, name: str, *, trace_id=None, parent_id=None,
-             args=None):
-        """``with bus.span(...):`` — a B/E pair that closes on every exit
-        path. Returns a shared no-op when tracing is disabled."""
+             args=None, step: Optional[int] = None):
+        """``with bus.span(...):`` — the one way the program opens a span.
+        It always enters a ``jax.profiler.TraceAnnotation``
+        ``ds.<cat>.<name>`` (``step`` becomes the annotation's ``step``
+        argument): a no-op of well under a microsecond while no profiler
+        session runs, and an event on the profiler's own clock when one
+        does. The B/E pair goes into the host-clock ring only when tracing
+        is enabled; disabled, the annotation itself is returned."""
+        ann = (TraceAnnotation(f"ds.{cat}.{name}") if step is None
+               else TraceAnnotation(f"ds.{cat}.{name}", step=step))
         if not self.enabled:
-            return _NOOP_SPAN
-        return _Span(self, cat, name, trace_id, parent_id, args)
+            return ann
+        return _Span(self, cat, name, trace_id, parent_id, args, ann)
 
     # ------------------------------------------------------------------
     # reading

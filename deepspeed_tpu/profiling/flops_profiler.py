@@ -80,8 +80,10 @@ def per_module_profile(fn: Callable, *args, depth: int = 2,
     cdim_re = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
     label_re = re.compile(r"dim_labels=([a-z0-9]+)_")
     win_re = re.compile(r"window=\{size=([0-9x]+)")
+    # "layers" is the model's scope around its layer loop: structure, like
+    # the loop's own while/body, so that blocks stay the top-level modules
     drop = ("while", "body", "cond", "closed_call", "checkpoint", "rematted",
-            "transpose")
+            "transpose", "layers")
     out: Dict[str, Dict[str, float]] = {}
     for m in inst.finditer(txt):
         res, kind, lhs_name, rhs_name, attrs, op_name = m.groups()

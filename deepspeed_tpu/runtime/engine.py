@@ -37,6 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.config import DeepSpeedTpuConfig
 from deepspeed_tpu.models.spec import num_params
+from deepspeed_tpu.observability import steplog
 from deepspeed_tpu.parallel import Topology, build_mesh
 from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.runtime.dataloader import DeepSpeedTpuDataLoader
@@ -512,25 +513,26 @@ class DeepSpeedTpuEngine:
             identical semantics (loss scaling, skip, scaler window). ``ga`` is
             keyword-only so fused callers pass their own accumulation factor
             rather than silently inheriting the build-time value."""
-            scale = scaler["scale"]
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32) / (scale * ga), grads)
-            gnorm = optax.global_norm(grads)
-            if fp16:
-                finite = jnp.isfinite(gnorm)
-                safe = jax.tree_util.tree_map(
-                    lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads)
-                updates, new_opt = tx.update(safe, opt_state, params)
+            with jax.named_scope("optimizer"):
+                scale = scaler["scale"]
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32) / (scale * ga), grads)
+                gnorm = optax.global_norm(grads)
+                if fp16:
+                    finite = jnp.isfinite(gnorm)
+                    safe = jax.tree_util.tree_map(
+                        lambda g: jnp.where(finite, g, jnp.zeros_like(g)), grads)
+                    updates, new_opt = tx.update(safe, opt_state, params)
+                    new_params = optax.apply_updates(params, updates)
+                    new_params = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o), new_params, params)
+                    new_opt = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(finite, n, o), new_opt, opt_state)
+                    new_scaler = self._scaler_update(scaler, finite)
+                    return new_params, new_opt, new_scaler, gnorm, ~finite
+                updates, new_opt = tx.update(grads, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
-                new_params = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(finite, n, o), new_params, params)
-                new_opt = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(finite, n, o), new_opt, opt_state)
-                new_scaler = self._scaler_update(scaler, finite)
-                return new_params, new_opt, new_scaler, gnorm, ~finite
-            updates, new_opt = tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
-            return new_params, new_opt, scaler, gnorm, jnp.zeros((), bool)
+                return new_params, new_opt, scaler, gnorm, jnp.zeros((), bool)
 
         self._init_fn = jax.jit(model.init, out_shardings=self.param_sharding)
         if tx is not None:
@@ -679,7 +681,8 @@ class DeepSpeedTpuEngine:
             spec = P(*list(bspec)[:max(x.ndim, 0)]) if x.ndim else P()
             return jax.device_put(x, NamedSharding(self.mesh, spec))
 
-        return jax.tree_util.tree_map(put, batch)
+        with self._ebus.span("train", "put_batch"):
+            return jax.tree_util.tree_map(put, batch)
 
     # ------------------------------------------------------------------
     # train loop UX
@@ -890,28 +893,29 @@ class DeepSpeedTpuEngine:
     def _commit_step(self, skipped: bool) -> None:
         """Shared end-of-step bookkeeping for the imperative, fused, and fused
         offload paths: skip accounting, LR schedule, progress + monitor."""
-        if skipped:
-            self.skipped_steps += 1
-        else:
-            self.global_steps += 1
-            if self.lr_scheduler is not None:
-                self.lr_scheduler.step()
-        self.global_samples += int(self.config.train_batch_size)
-        if self.global_steps and self.global_steps % self.config.steps_per_print == 0:
-            self._report_progress()
-        if self.monitor is not None:
-            self.monitor.write_events([
-                ("Train/Samples/train_loss", float(self._last_loss), self.global_samples),
-                ("Train/Samples/lr", self.get_lr()[0], self.global_samples),
-            ])
-            if self.global_steps and \
-                    self.global_steps % self.config.steps_per_print == 0:
-                self.monitor.write_events(self._resilience_events())
-        if self._obs is not None:
-            self._emit_train_metrics()
-        if self._heartbeat is not None:
-            self._heartbeat.notify_step(self.global_steps)
-        self._resilience_step_boundary()
+        with self._ebus.span("train", "commit"):
+            if skipped:
+                self.skipped_steps += 1
+            else:
+                self.global_steps += 1
+                if self.lr_scheduler is not None:
+                    self.lr_scheduler.step()
+            self.global_samples += int(self.config.train_batch_size)
+            if self.global_steps and self.global_steps % self.config.steps_per_print == 0:
+                self._report_progress()
+            if self.monitor is not None:
+                self.monitor.write_events([
+                    ("Train/Samples/train_loss", float(self._last_loss), self.global_samples),
+                    ("Train/Samples/lr", self.get_lr()[0], self.global_samples),
+                ])
+                if self.global_steps and \
+                        self.global_steps % self.config.steps_per_print == 0:
+                    self.monitor.write_events(self._resilience_events())
+            if self._obs is not None:
+                self._emit_train_metrics()
+            if self._heartbeat is not None:
+                self._heartbeat.notify_step(self.global_steps)
+            self._resilience_step_boundary()
 
     def train_batch(self, data_iter: Optional[Iterable] = None):
         """One full global batch = GA micro-steps + optimizer step
@@ -946,7 +950,41 @@ class DeepSpeedTpuEngine:
         overlap — with the SAME semantics as forward/backward/step: fp16 loss
         scaling, overflow skip and scaler update ride inside the jit, and the
         host-offload optimizer is supported via a fused grads-only program.
+
+        The whole call is the ``ds.train.step`` span (``put_batch``,
+        ``dispatch`` and ``commit`` nest inside it), and leaves one row in the
+        process's :class:`~deepspeed_tpu.observability.steplog.StepLog`.
         """
+        step = self.global_steps
+        t_enter = time.perf_counter()
+        with self._ebus.span("train", "step", step=step):
+            loss = self._fused_train_step(batch)
+        self._steplog.step(step, t_enter, self._t_dispatched,
+                           time.perf_counter())
+        return loss
+
+    def _step_program(self, key, jitted: Callable) -> None:
+        """A ``_fused_step_cache`` miss: keep the jitted step program and enter
+        it in the step-program table under its function's name, which is also
+        what the device trace's module line says ran (``jit_ds_train_step``)."""
+        self._fused_step_cache[key] = jitted
+        self._uncaptured[key] = steplog.record_program(
+            jitted.__name__, key, jitted, self.mesh)
+
+    def _dispatch_fused(self, key, *args):
+        """Call the jitted step program of ``key`` (the ``ds.train.dispatch``
+        span; the call returns when the program is enqueued)."""
+        if self._uncaptured:
+            row = self._uncaptured.pop(key, None)
+            if row is not None:
+                row.capture(args)
+        with self._ebus.span("train", "dispatch"), \
+                jax.sharding.set_mesh(self.mesh):
+            out = self._fused_step_cache[key](*args)
+        self._t_dispatched = time.perf_counter()
+        return out
+
+    def _fused_train_step(self, batch):
         ga = int(self.config.gradient_accumulation_steps)
         if self._ltd_cfg is not None:
             self._update_random_ltd()
@@ -962,21 +1000,20 @@ class DeepSpeedTpuEngine:
             return self._guarded_loss(self._fused_zpp_step(batch, ga))
         key = ga
         if key not in self._fused_step_cache:
-            def fused(params, opt_state, batch, scaler):
+            def ds_train_step(params, opt_state, batch, scaler):
                 grads, loss = self._fused_grads(params, batch, scaler["scale"], ga)
                 new_params, new_opt, new_scaler, gnorm, skipped = \
                     self._apply_body(params, opt_state, grads, scaler, ga=float(ga))
                 return new_params, new_opt, new_scaler, loss, gnorm, skipped
 
-            self._fused_step_cache[key] = jax.jit(
-                fused, donate_argnums=(0, 1),
+            self._step_program(key, jax.jit(
+                ds_train_step, donate_argnums=(0, 1),
                 out_shardings=(self.param_sharding, self.opt_sharding,
-                               None, None, None, None))
+                               None, None, None, None)))
         batch = self._put_batch(batch)
-        with jax.sharding.set_mesh(self.mesh):
-            (self.params, self.opt_state, self.scaler_state, loss, gnorm,
-             skipped) = self._fused_step_cache[key](
-                self.params, self.opt_state, batch, self.scaler_state)
+        (self.params, self.opt_state, self.scaler_state, loss, gnorm,
+         skipped) = self._dispatch_fused(
+            key, self.params, self.opt_state, batch, self.scaler_state)
         self._last_loss, self._last_gnorm = loss, gnorm
         # only fp16 can skip; reading `skipped` otherwise would force a host
         # sync per step and serialize the dispatch pipeline
@@ -998,21 +1035,19 @@ class DeepSpeedTpuEngine:
         ob = self._onebit
         key = ("onebit", ga)
         if key not in self._fused_step_cache:
-            def fused(params, opt_state, batch):
+            def ds_train_step_onebit(params, opt_state, batch):
                 grads, loss = ob.grads_fn(params, batch, jnp.float32(1.0), ga)
                 new_p, new_s, gnorm = ob.apply_fn(params, opt_state, grads,
                                                   jnp.float32(ga))
                 return new_p, new_s, loss, gnorm
 
-            self._fused_step_cache[key] = jax.jit(
-                fused, donate_argnums=(0, 1),
+            self._step_program(key, jax.jit(
+                ds_train_step_onebit, donate_argnums=(0, 1),
                 out_shardings=(self.param_sharding, self.opt_sharding,
-                               None, None))
+                               None, None)))
         batch = self._put_batch(batch)
-        with jax.sharding.set_mesh(self.mesh):
-            (self.params, self.opt_state, loss,
-             gnorm) = self._fused_step_cache[key](self.params, self.opt_state,
-                                                  batch)
+        (self.params, self.opt_state, loss, gnorm) = self._dispatch_fused(
+            key, self.params, self.opt_state, batch)
         self._last_loss, self._last_gnorm = loss, gnorm
         self._commit_step(False)
         return loss
@@ -1026,7 +1061,7 @@ class DeepSpeedTpuEngine:
         if key not in self._fused_step_cache:
             uses_sec = zpp.uses_secondary
 
-            def fused(params, opt_state, batch, scaler, *sec):
+            def ds_train_step_zpp(params, opt_state, batch, scaler, *sec):
                 p_in = sec[0] if uses_sec else params
                 grads, loss = zpp.grads_fn(p_in, batch, scaler["scale"], ga)
                 new_params, new_opt, new_scaler, gnorm, skipped = \
@@ -1036,16 +1071,16 @@ class DeepSpeedTpuEngine:
                     out += (zpp.hpz_refresh(new_params),)
                 return out
 
-            self._fused_step_cache[key] = jax.jit(
-                fused, donate_argnums=(0, 1, 4) if uses_sec else (0, 1),
+            self._step_program(key, jax.jit(
+                ds_train_step_zpp,
+                donate_argnums=(0, 1, 4) if uses_sec else (0, 1),
                 out_shardings=(self.param_sharding, self.opt_sharding,
                                None, None, None, None)
-                + ((zpp.hpz_sharding,) if uses_sec else ()))
+                + ((zpp.hpz_sharding,) if uses_sec else ())))
         batch = self._put_batch(batch)
         sec = ((self._hpz_secondary,) if zpp.uses_secondary else ())
-        with jax.sharding.set_mesh(self.mesh):
-            out = self._fused_step_cache[key](
-                self.params, self.opt_state, batch, self.scaler_state, *sec)
+        out = self._dispatch_fused(
+            key, self.params, self.opt_state, batch, self.scaler_state, *sec)
         (self.params, self.opt_state, self.scaler_state, loss, gnorm,
          skipped) = out[:6]
         if zpp.uses_secondary:
@@ -1058,19 +1093,19 @@ class DeepSpeedTpuEngine:
         """Fused fwd/bwd jit + host optimizer step (ZeRO-Offload/Infinity)."""
         key = ("offload", ga)
         if key not in self._fused_step_cache:
-            def grads_fn(params, batch, scaler):
+            def ds_train_step_offload(params, batch, scaler):
                 scale = scaler["scale"]
                 grads, loss = self._fused_grads(params, batch, scale, ga)
                 grads = jax.tree_util.tree_map(
                     lambda g: g / (scale * ga), grads)
                 return grads, loss
 
-            self._fused_step_cache[key] = jax.jit(
-                grads_fn, out_shardings=(self.grad_sharding, None))
+            self._step_program(key, jax.jit(
+                ds_train_step_offload,
+                out_shardings=(self.grad_sharding, None)))
         batch = self._put_batch(batch)
-        with jax.sharding.set_mesh(self.mesh):
-            grads, loss = self._fused_step_cache[key](
-                self.params, batch, self.scaler_state)
+        grads, loss = self._dispatch_fused(
+            key, self.params, batch, self.scaler_state)
         if self._offload.overlap:
             self._collect_offload()
             gnorm_prev = jnp.float32(self._offload._last_gnorm)
@@ -1183,6 +1218,12 @@ class DeepSpeedTpuEngine:
         ocfg = config.observability
         self.wall_timers = SynchronizedWallClockTimer()
         self._ebus = get_bus()
+        # the fused step's record (observability/steplog.py): a row a step,
+        # a row a collector pause, a row a step program built
+        steplog.install_gc_hook()
+        self._steplog = steplog.get_steplog()
+        self._uncaptured: Dict[Any, steplog.StepProgram] = {}
+        self._t_dispatched = 0.0
         self._obs = None
         self._obs_bridge = None
         self._obs_server = None

@@ -78,22 +78,32 @@ def ga_grads(model, params, batch, scale, ga: int):
     and the 1-bit fwd/bwd region so the accumulation semantics stay single-
     sourced."""
 
+    def scaled_loss(p, mb):
+        loss = model.loss_fn(p, mb)
+        with jax.named_scope("loss"):
+            return loss * scale
+
     def micro(acc, mb):
         if hasattr(model, "loss_and_grad"):  # 1F1B pipeline: manual backward
             loss, g = model.loss_and_grad(params, mb, scale)
         else:
-            sloss, g = jax.value_and_grad(
-                lambda p: model.loss_fn(p, mb) * scale)(params)
-            loss = sloss / scale
-        return jax.tree_util.tree_map(jnp.add, acc, g), loss
+            sloss, g = jax.value_and_grad(scaled_loss)(params, mb)
+            with jax.named_scope("loss"):
+                loss = sloss / scale
+        with jax.named_scope("grad_accum"):
+            return jax.tree_util.tree_map(jnp.add, acc, g), loss
 
-    zeros = jax.tree_util.tree_map(
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
-    if ga > 1:
-        mbs = jax.tree_util.tree_map(
-            lambda x: x.reshape((ga, x.shape[0] // ga) + x.shape[1:]), batch)
-        grads, losses = lax.scan(micro, zeros, mbs)
-        return grads, losses.mean()
+    # the accumulator, and with ga > 1 the micro-batch loop's own slicing
+    # and carries; the model's operations inside keep their own scopes
+    with jax.named_scope("grad_accum"):
+        zeros = jax.tree_util.tree_map(
+            lambda p: jnp.zeros(p.shape, jnp.float32), params)
+        if ga > 1:
+            mbs = jax.tree_util.tree_map(
+                lambda x: x.reshape((ga, x.shape[0] // ga) + x.shape[1:]),
+                batch)
+            grads, losses = lax.scan(micro, zeros, mbs)
+            return grads, losses.mean()
     return micro(zeros, batch)
 
 

@@ -1,0 +1,67 @@
+"""Every operation of the fused step program lies under one of the step's
+named scopes (``models/transformer.py:STEP_SCOPES``): a refactor that drops a
+scope fails here, on the CPU, not in the benchmark's ``unscoped_device_ms`` on
+the chip."""
+
+import re
+
+import jax
+import numpy as np
+import pytest
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.models import TransformerLM, get_preset
+from deepspeed_tpu.models.transformer import STEP_SCOPES
+from deepspeed_tpu.observability import steplog
+from deepspeed_tpu.parallel import build_mesh
+
+CASES = {
+    "dense": ({}, 1),
+    "moe": ({"num_experts": 4, "top_k": 2}, 1),
+    "dense_tiled_loss_ga2": ({"loss_tiling": 4}, 2),
+    "dense_unrolled_layers": ({"scan_layers": False}, 1),
+}
+
+
+def _op_names(overrides, ga):
+    eng, *_ = ds.initialize(
+        model=TransformerLM(get_preset("tiny", **overrides)),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "gradient_accumulation_steps": ga,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "bf16": {"enabled": True}, "steps_per_print": 10 ** 9,
+                "zero_optimization": {"stage": 0}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    eng.fused_train_step({"input_ids": np.zeros((2 * ga, 32), np.int32)})
+    row = steplog.programs()[-1]
+    assert row.name == "ds_train_step" and row.key == str(ga)
+    text = row.hlo_text()
+    assert "jit_ds_train_step" in text
+    # the program's own operations carry "jit(ds_train_step)/..."; the
+    # bodies of reducers and scatters carry the bare primitive's name
+    return [n for n in re.findall(r'op_name="([^"]*)"', text)
+            if n.startswith("jit(")]
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_every_operation_carries_a_step_scope(case):
+    names = _op_names(*CASES[case])
+    assert len(names) > 200
+    loose = sorted({n for n in names
+                    if not set(re.split(r"[/()]", n)) & set(STEP_SCOPES)})
+    assert loose == []
+    found = {p for n in names for p in re.split(r"[/()]", n)} \
+        & set(STEP_SCOPES)
+    ffn = "moe" if case == "moe" else "mlp"
+    want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
+    if CASES[case][0].get("loss_tiling", 0) <= 1:
+        want.add("lm_head")
+    if CASES[case][1] > 1:      # with one micro-batch the compiler folds 0 + g
+        want.add("grad_accum")
+    assert found == want
+
+
+def test_backward_operations_keep_their_scope():
+    names = _op_names(*CASES["dense"])
+    back = [n for n in names if "transpose(" in n]
+    assert any("/attn/" in n for n in back) and any("/mlp/" in n for n in back)
