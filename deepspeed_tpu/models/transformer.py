@@ -589,24 +589,67 @@ def _maybe_remat(fn: Callable, policy: str) -> Callable:
     return checkpoint_wrapper(fn, policy=policy)
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def token_cross_entropy(logits: jax.Array, labels: jax.Array,
+                        z_loss: float = 0.0) -> jax.Array:
+    """Per-token ``logsumexp(logits) - logits[label]`` in f32, plus
+    ``z_loss * logsumexp^2`` when ``z_loss > 0``: logits [..., V] in any float
+    dtype, labels [...] in [0, V).
+
+    It has its own derivative rule so that the vocabulary-sized arrays are the
+    logits as they arrive and their gradient in the same dtype, each read or
+    written once a pass: the casts, reductions and the exponential happen in
+    f32 inside the fused passes, and only ``logz`` [...] is kept beside the
+    logits for the backward. Plain ``jnp``, so GSPMD partitions the
+    reductions when the vocabulary is sharded."""
+    return _token_ce_fwd(logits, labels, z_loss)[0]
+
+
+def _token_ce_fwd(logits, labels, z_loss):
+    lg = logits.astype(jnp.float32)
+    top = jax.lax.stop_gradient(lg.max(axis=-1))
+    logz = top + jnp.log(jnp.exp(lg - top[..., None]).sum(axis=-1))
+    # the label's logit by a comparison against an iota, which fuses into the
+    # pass that reads the logits (a gather or scatter is a pass of its own)
+    is_label = jax.nn.one_hot(labels, logits.shape[-1], dtype=bool)
+    gold = jnp.where(is_label, lg, 0.0).sum(axis=-1)
+    nll = logz - gold
+    if z_loss > 0.0:
+        nll = nll + z_loss * jnp.square(logz)
+    return nll, (logits, labels, logz)
+
+
+def _token_ce_bwd(z_loss, res, g):
+    logits, labels, logz = res
+    scale = g * (1.0 + 2.0 * z_loss * logz) if z_loss > 0.0 else g
+    probs = jnp.exp(logits.astype(jnp.float32) - logz[..., None])
+    is_label = jax.nn.one_hot(labels, logits.shape[-1], dtype=bool)
+    d = scale[..., None] * probs - jnp.where(is_label, g[..., None], 0.0)
+    # written once, here: left to itself the TPU compiler fuses this formula
+    # into both of the head's backward matmuls as their operand, and the
+    # exponential then holds the MXU back by more than the pass costs
+    # (PERF.md, PR 27: the head's backward took 17.8 ms that way, 13.6 so)
+    return jax.lax.optimization_barrier(d.astype(logits.dtype)), None
+
+
+token_cross_entropy.defvjp(_token_ce_fwd, _token_ce_bwd)
+
+
 def lm_loss(cfg: TransformerConfig, logits: jax.Array,
             batch: Dict[str, jax.Array]) -> jax.Array:
     """Next-token / labeled cross-entropy with masking and optional z-loss."""
-    ids = batch["input_ids"]
     if "labels" in batch:
-        labels, lmask = batch["labels"], (batch["labels"] >= 0)
-        labels = jnp.maximum(labels, 0)
-        lg = logits
-    else:  # next-token LM loss
-        labels, lg = ids[:, 1:], logits[:, :-1]
-        lmask = (batch["attention_mask"][:, 1:].astype(bool)
-                 if "attention_mask" in batch else jnp.ones_like(labels, bool))
-    lg = lg.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(lg, axis=-1)
-    gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    if cfg.z_loss > 0.0:
-        nll = nll + cfg.z_loss * jnp.square(logz)
+        labels = batch["labels"]
+        lmask = labels >= 0
+    else:
+        # next-token LM loss: shift the labels, not the logits, so that every
+        # array over the vocabulary keeps T rows (T - 1 is aligned to nothing)
+        ids = batch["input_ids"]
+        labels = jnp.roll(ids, -1, axis=1)
+        mask = (batch["attention_mask"].astype(bool)
+                if "attention_mask" in batch else jnp.ones_like(ids, bool))
+        lmask = jnp.roll(mask, -1, axis=1).at[:, -1].set(False)
+    nll = token_cross_entropy(logits, jnp.maximum(labels, 0), cfg.z_loss)
     denom = jnp.maximum(lmask.sum(), 1)
     return jnp.where(lmask, nll, 0.0).sum() / denom
 

@@ -14,6 +14,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models.transformer import token_cross_entropy
+
 
 def sequence_tiled_compute(fn: Callable, x: jax.Array, num_shards: int,
                            seq_dim: int = 1, remat: bool = True) -> jax.Array:
@@ -60,18 +62,11 @@ def tiled_logits_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,
 
     def chunk_loss(args):
         h, l = args
-        logits = (h @ head).astype(jnp.float32)
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
         # ALL negative labels are padding (dense lm_loss masks labels < 0;
         # -100 is just the HF spelling of it)
         mask = (l >= 0) & (l != ignore_index)
-        safe = jnp.maximum(l, 0)
-        gold = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-        nll = logz - gold
-        if z_loss > 0.0:
-            nll = nll + z_loss * jnp.square(logz)
-        nll = jnp.where(mask, nll, 0.0)
-        return nll.sum(), mask.sum()
+        nll = token_cross_entropy(h @ head, jnp.maximum(l, 0), z_loss)
+        return jnp.where(mask, nll, 0.0).sum(), mask.sum()
 
     body = jax.checkpoint(chunk_loss)
     sums, counts = jax.lax.map(body, (hc.transpose(1, 0, 2, 3), lc.transpose(1, 0, 2)))
