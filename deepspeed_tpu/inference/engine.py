@@ -117,6 +117,9 @@ class InferenceEngine:
     def __init__(self, model: TransformerLM, config=None, params=None,
                  topology: Optional[Topology] = None, dtype=None,
                  max_seq_len: Optional[int] = None, **kw):
+        if getattr(model.cfg, "looped", False):
+            model._one_pass_only("InferenceEngine (one key-value cache a "
+                                 "layer)")
         self.module = model
         self.cfg = model.cfg
         self.config = from_config(config) if not hasattr(config, "mesh") else config

@@ -85,6 +85,11 @@ class InferenceEngineV2:
         from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
         place_compile_cache()
+        if getattr(model.cfg, "looped", False):
+            # before any pool is built: R caches a layer and early exit are
+            # not here (PERF.md, open questions)
+            model._one_pass_only("InferenceEngineV2 (one key-value cache a "
+                                 "layer, one set of logits a token)")
         self.module = model
         self.cfg = model.cfg
         self.max_seq_len = max_seq_len or self.cfg.max_seq_len

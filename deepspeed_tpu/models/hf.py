@@ -37,10 +37,22 @@ from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.utils.logging import log_dist
 
 __all__ = ["config_from_hf", "load_hf_checkpoint", "from_pretrained",
-           "infer_tp_specs", "TP_PATTERNS"]
+           "infer_tp_specs", "TP_PATTERNS", "OURO_TENSORS"]
 
 
-_LLAMA_FAMILY = ("llama", "mistral", "qwen2", "phi3", "mixtral")
+_LLAMA_FAMILY = ("llama", "mistral", "qwen2", "phi3", "mixtral", "ouro")
+
+#: ``model_type: "ouro"`` (looped stack, sandwich norms, exit gate): where
+#: the tensors it has beyond the llama family's live in the parameter tree,
+#: by checkpoint name. The one table both directions read: the importer
+#: below, and whoever writes a checkpoint from a tree.
+OURO_TENSORS = {
+    "model.layers.{}.input_layernorm_2.weight": ("layers", "ln1_post", "scale"),
+    "model.layers.{}.post_attention_layernorm_2.weight":
+        ("layers", "ln2_post", "scale"),
+    "model.early_exit_gate.weight": ("exit_gate", "w"),     # [1, D] there
+    "model.early_exit_gate.bias": ("exit_gate", "b"),       # [1] there
+}
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt")
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
@@ -105,6 +117,15 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             kw["sliding_window"] = win
         if model_type == "qwen2":
             kw["qkv_bias"] = True
+        if model_type == "ouro":
+            # the layer stack runs total_ut_steps times over shared weights,
+            # each branch's output is normed again, and a gate scores every
+            # pass's output. config.json does not give the exit loss's beta:
+            # 0.1 is the family's stage-I value as held here; pass
+            # exit_loss_beta= to train with another. Serving paths refuse
+            # the model (TransformerLM._one_pass_only).
+            kw.update(num_passes=int(get("total_ut_steps", 1)),
+                      sandwich_norm=True, exit_loss_beta=0.1)
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "
@@ -287,7 +308,7 @@ def _build_llama_family(sd, cfg: TransformerConfig, model_type: str):
                 "w_up": _stack(sd, "model.layers.{}.mlp.up_proj.weight", L, True),
                 "w_down": _stack(sd, "model.layers.{}.mlp.down_proj.weight", L, True),
             }
-    return {
+    params = {
         "embed": {"tokens": sd.pop("model.embed_tokens.weight")},
         "layers": {
             "ln1": {"scale": _stack(
@@ -298,7 +319,17 @@ def _build_llama_family(sd, cfg: TransformerConfig, model_type: str):
             "mlp": mlp,
         },
         "final_norm": {"scale": sd.pop("model.norm.weight")},
-    }, "lm_head.weight"
+    }
+    if model_type == "ouro":
+        for name, path in OURO_TENSORS.items():
+            # the gate is a Linear(D, 1): [1, D] and [1] there, [D] and [] here
+            t = (_stack(sd, name, L) if "{}" in name
+                 else np.squeeze(sd.pop(name)))
+            node = params
+            for k in path[:-1]:
+                node = node.setdefault(k, {})
+            node[path[-1]] = t
+    return params, "lm_head.weight"
 
 
 def _build_falcon(sd, cfg: TransformerConfig, model_type: str):

@@ -60,6 +60,12 @@ def model_flops_per_token(cfg: "Any", include_backward: bool = True) -> float:
     if hasattr(cfg, "num_layers") and hasattr(cfg, "max_seq_len") and hasattr(cfg, "hidden_size"):
         # per-token attention score+value FLOPs: 2 * 2 * L * T * D (fwd), ×3 with bwd
         attn = (factor / 2.0) * 2 * cfg.num_layers * cfg.max_seq_len * cfg.hidden_size
+    # a looped model uses every weight but the embedding table once a pass
+    # (the layers, the final norm, the head), and attends once a pass
+    passes = int(getattr(cfg, "num_passes", 1))
+    if passes > 1:
+        once = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.hidden_size
+        return factor * (once + passes * (float(n) - once)) + passes * attn
     return factor * float(n) + attn
 
 

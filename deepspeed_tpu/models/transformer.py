@@ -85,6 +85,17 @@ class TransformerConfig:
     # alone exceed HBM (128k x 32000 vocab fp32 = 16.8 GB)
     loss_tiling: int = 0
 
+    # --- looped (weight-shared) stack: the L blocks run ``num_passes`` times
+    # over the same weights, the final norm closing every pass; each pass has
+    # its own logits through the one head (Ouro / LoopLM) ---
+    num_passes: int = 1
+    # x + norm(attn(norm(x))), then a + norm(ffn(norm(a))): a second norm
+    # scale on each branch's output (``ln1_post``, ``ln2_post``)
+    sandwich_norm: bool = False
+    # not None: a per-token exit gate sigmoid(w_g . h_t + b_g) after every
+    # pass and the expected-exit loss sum_t p_t CE_t - beta H(p) (``loss_fn``)
+    exit_loss_beta: Optional[float] = None
+
     # MoE (wired by deepspeed_tpu.moe; dense when num_experts <= 1)
     num_experts: int = 1
     top_k: int = 2
@@ -130,6 +141,14 @@ class TransformerConfig:
         assert self.num_heads % self.num_kv_heads == 0
         if self.parallel_shared_norm:
             assert self.parallel_block, "shared norm requires parallel_block"
+        if self.num_passes < 1:
+            raise ValueError(f"num_passes={self.num_passes} must be >= 1")
+        if self.sandwich_norm and self.parallel_block:
+            raise ValueError("sandwich_norm norms each branch's output before "
+                             "its own residual add; parallel_block has one add")
+        if self.exit_loss_beta is not None and self.num_passes < 2:
+            raise ValueError("exit_loss_beta (the exit gate and expected-exit "
+                             "loss) needs num_passes >= 2")
 
     # set when structured head pruning shrinks num_heads (head_dim is
     # otherwise derived as hidden_size // num_heads, which would silently
@@ -139,6 +158,14 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.hidden_size // self.num_heads
+
+    @property
+    def looped(self) -> bool:
+        """Whether anything of the looped family is on: several passes, the
+        sandwich norm or the exit gate. Paths written for one pass over a
+        pre-norm stack ask this and refuse."""
+        return (self.num_passes > 1 or self.sandwich_norm
+                or self.exit_loss_beta is not None)
 
     @property
     def rope_dim(self) -> int:
@@ -152,9 +179,14 @@ class TransformerConfig:
         mlp = (3 if self.activation == "swiglu" else 2) * D * F
         norms = (2 * D) * (2 if self.norm == "layernorm" else 1)
         per_layer = attn + mlp + 2 * norms
+        if self.sandwich_norm:
+            per_layer += norms   # the two post-branch scales, counted once
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
         head = 0 if self.tie_embeddings else D * V
-        return L * per_layer + embed + head + D
+        gate = D + 1 if self.exit_loss_beta is not None else 0
+        # passes share their weights: the count does not grow with them
+        # (models/spec.py:model_flops_per_token multiplies the work)
+        return L * per_layer + embed + head + D + gate
 
 
 # ---------------------------------------------------------------------------
@@ -530,7 +562,7 @@ def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
 #: holds the lowered program to this; benchmarks/readers/program.py sums
 #: device time by them.
 STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
-               "lm_head", "loss", "grad_accum", "optimizer")
+               "lm_head", "loss", "exit_gate", "grad_accum", "optimizer")
 
 
 def _cast_layers(w: Params, dt, ffn: str) -> Params:
@@ -538,7 +570,8 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
     dtype, each under the scope of the block that reads it."""
     out = {}
     for k, v in w.items():
-        with jax.named_scope("attn" if k in ("ln1", "attn") else ffn):
+        with jax.named_scope("attn" if k in ("ln1", "attn", "ln1_post")
+                             else ffn):
             out[k] = jax.tree_util.tree_map(
                 lambda p: p.astype(dt) if p.dtype == jnp.float32 else p, v)
     return out
@@ -549,7 +582,9 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                       moe_fn: Optional[Callable] = None,
                       positions: Optional[jax.Array] = None) -> Any:
     """One pre-norm decoder block. Returns (x, aux_loss). ``positions`` [B, T]
-    overrides RoPE positions (random-LTD token subsets)."""
+    overrides RoPE positions (random-LTD token subsets). With
+    ``cfg.sandwich_norm`` each branch's output is normed again before its
+    residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(FFN(N3(a)))``."""
     # named scopes land in HLO op metadata — the per-module profiler
     # (profiling/flops_profiler.per_module_profile) and the benchmark's
     # device-time-by-scope reader group cost by them. Every operation of the
@@ -561,6 +596,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
         attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                    positions=positions)
+        if cfg.sandwich_norm:
+            attn_out = _norm(attn_out, wc["ln1_post"], cfg.norm, cfg.norm_eps)
     with jax.named_scope(ffn):
         if cfg.parallel_block:
             # falcon/gpt-neox: attn and mlp branch from the SAME residual
@@ -575,6 +612,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         else:
             mlp_out = mlp_block(h, wc["mlp"], cfg)
             aux = jnp.zeros((), jnp.float32)
+        if cfg.sandwich_norm:
+            mlp_out = _norm(mlp_out, wc["ln2_post"], cfg.norm, cfg.norm_eps)
         x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
         return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
 
@@ -635,9 +674,9 @@ def _token_ce_bwd(z_loss, res, g):
 token_cross_entropy.defvjp(_token_ce_fwd, _token_ce_bwd)
 
 
-def lm_loss(cfg: TransformerConfig, logits: jax.Array,
-            batch: Dict[str, jax.Array]) -> jax.Array:
-    """Next-token / labeled cross-entropy with masking and optional z-loss."""
+def _lm_targets(batch: Dict[str, jax.Array]):
+    """(labels [B, T] >= 0, target mask [B, T]) of a batch: its ``labels``
+    (negative = no target) or its ``input_ids`` shifted by one."""
     if "labels" in batch:
         labels = batch["labels"]
         lmask = labels >= 0
@@ -649,7 +688,14 @@ def lm_loss(cfg: TransformerConfig, logits: jax.Array,
         mask = (batch["attention_mask"].astype(bool)
                 if "attention_mask" in batch else jnp.ones_like(ids, bool))
         lmask = jnp.roll(mask, -1, axis=1).at[:, -1].set(False)
-    nll = token_cross_entropy(logits, jnp.maximum(labels, 0), cfg.z_loss)
+    return jnp.maximum(labels, 0), lmask
+
+
+def lm_loss(cfg: TransformerConfig, logits: jax.Array,
+            batch: Dict[str, jax.Array]) -> jax.Array:
+    """Next-token / labeled cross-entropy with masking and optional z-loss."""
+    labels, lmask = _lm_targets(batch)
+    nll = token_cross_entropy(logits, labels, cfg.z_loss)
     denom = jnp.maximum(lmask.sum(), 1)
     return jnp.where(lmask, nll, 0.0).sum() / denom
 
@@ -681,19 +727,42 @@ class TransformerLM:
         # (one recompile per tier; the reference's actual wall-clock saving)
         self._pld_depth: Optional[int] = None
 
+    def _one_pass_only(self, what: str) -> None:
+        """Raise for a looped model on a path written for one pass over a
+        pre-norm stack (``cfg.looped``): nothing falls back to one pass."""
+        cfg = self.cfg
+        if cfg.looped:
+            raise NotImplementedError(
+                f"{what} runs the layer stack once, pre-norm, with one set of "
+                f"logits; this model is looped (num_passes={cfg.num_passes}, "
+                f"sandwich_norm={cfg.sandwich_norm}, exit gate "
+                f"{'on' if cfg.exit_loss_beta is not None else 'off'}) and "
+                f"would need every pass (and, with a cache, num_passes "
+                f"key-value caches a layer)")
+
     def set_random_ltd(self, keep: Optional[int],
                        layers: Optional[tuple] = None) -> None:
         L = self.cfg.num_layers
-        self._ltd_keep = keep
         if keep is not None:
+            self._one_pass_only("random layerwise token dropping")
             start, end = layers if layers is not None else (1, L - 1)
             self._ltd_layers = (max(0, start), end if end > 0 else L - 1)
+        self._ltd_keep = keep
 
     def set_pld_depth(self, k: Optional[int]) -> None:
-        if k is not None and not (1 <= k <= self.cfg.num_layers):
-            raise ValueError(f"pld depth {k} out of [1, "
-                             f"{self.cfg.num_layers}]")
+        if k is not None:
+            self._one_pass_only("progressive layer drop")
+            if not 1 <= k <= self.cfg.num_layers:
+                raise ValueError(f"pld depth {k} out of [1, "
+                                 f"{self.cfg.num_layers}]")
         self._pld_depth = k
+
+    @property
+    def layer_applications(self) -> int:
+        """Block applications one micro-batch's forward holds: the layers
+        that run, times the passes over them (the step-program table's
+        ``layer_applications``)."""
+        return (self._pld_depth or self.cfg.num_layers) * self.cfg.num_passes
 
     # ---- init -------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
@@ -745,6 +814,9 @@ class TransformerLM:
         layers: Params = {"ln1": dict(norm_w), "attn": attn_w, "mlp": mlp}
         if not cfg.parallel_shared_norm:
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
+        if cfg.sandwich_norm:
+            layers["ln1_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
+            layers["ln2_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         params: Params = {
             "embed": {"tokens": dense(keys[0], 1, (V, D)) * 0.02 * math.sqrt(1)},
             "layers": layers,
@@ -756,6 +828,9 @@ class TransformerLM:
             params["embed"]["pos"] = dense(keys[8], 1, (cfg.max_seq_len, D)) * 0.01
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(keys[9], D, (D, V))
+        if cfg.exit_loss_beta is not None:
+            params["exit_gate"] = {"w": dense(keys[11], D, (D,)),
+                                   "b": jnp.zeros((), pd)}
         return params
 
     # ---- forward ----------------------------------------------------------
@@ -814,9 +889,25 @@ class TransformerLM:
                       ltd_seed: Optional[jax.Array] = None,
                       pld_theta: Optional[jax.Array] = None) -> jax.Array:
         """Final-norm hidden states [B, T, D] (everything before the LM
-        head) — the input of the tiled logits loss."""
+        head) — the input of the tiled logits loss; of a looped model, the
+        last pass's."""
+        return self._hidden_passes(params, input_ids, positions, ltd_seed,
+                                   pld_theta)[0][-1]
+
+    def _hidden_passes(self, params: Params, input_ids: jax.Array,
+                       positions: Optional[jax.Array] = None,
+                       ltd_seed: Optional[jax.Array] = None,
+                       pld_theta: Optional[jax.Array] = None):
+        """``([h_1 .. h_R], aux)``: the final-norm hidden states after each
+        of the ``cfg.num_passes`` passes of the layer stack, and the MoE aux
+        loss summed over layers and passes. Every pass reads the same stacked
+        weights, cast once; the final norm closes a pass and its output is
+        what the next pass reads, so one backward sums each weight's gradient
+        over its uses."""
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
+        if pld_theta is not None:
+            self._one_pass_only("progressive layer drop")
         with jax.named_scope("embed"):
             x = params["embed"]["tokens"].astype(dt)[input_ids]
             if cfg.learned_pos:
@@ -826,28 +917,31 @@ class TransformerLM:
                 x = x + pos_emb.astype(dt)
             x = constrain(x, P(("dp", "fsdp"), "sp", None))
         attn_fn = get_attention_impl(cfg.attention_impl)
-        freqs = self._freqs
         with jax.named_scope("layers"):
-            x = self._run_layers(params, x, input_ids, attn_fn, freqs,
-                                 ltd_seed, pld_theta)
-        with jax.named_scope("final_norm"):
-            x = _norm(x, {k: v for k, v in params["final_norm"].items()},
-                      cfg.norm, cfg.norm_eps)
-            return constrain(x, P(("dp", "fsdp"), "sp", None))
+            # Cast the whole layer stack to compute dtype ONCE, outside the
+            # layer scan and the pass loop: the per-layer cast inside
+            # transformer_block then no-ops. Done per layer (and re-done under
+            # remat) this was a full extra pass over the fp32 master weights
+            # every micro-batch.
+            layers = _cast_layers(params["layers"], dt,
+                                  "moe" if self.moe_fn is not None else "mlp")
+        hs, aux = [], None
+        for _ in range(cfg.num_passes):
+            with jax.named_scope("layers"):
+                x, a = self._run_layers(layers, x, input_ids, attn_fn,
+                                        self._freqs, ltd_seed, pld_theta)
+            with jax.named_scope("final_norm"):
+                x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+                x = constrain(x, P(("dp", "fsdp"), "sp", None))
+            hs.append(x)
+            aux = a if aux is None else aux + a
+        return hs, aux
 
-    def _run_layers(self, params: Params, x: jax.Array, input_ids: jax.Array,
-                    attn_fn: Callable, freqs, ltd_seed, pld_theta
-                    ) -> jax.Array:
-        """The layer stack on embedded ``x``; leaves the summed MoE aux loss
-        in ``_last_aux_loss``."""
+    def _run_layers(self, layers: Params, x: jax.Array, input_ids: jax.Array,
+                    attn_fn: Callable, freqs, ltd_seed, pld_theta):
+        """One pass of the (already cast) layer stack on ``x``: ``(x, the
+        summed MoE aux loss)``."""
         cfg = self.cfg
-        # Cast the whole layer stack to compute dtype ONCE, outside the layer
-        # scan: the per-layer cast inside transformer_block then no-ops. Done
-        # per layer (and re-done under remat) this was a full extra pass over
-        # the fp32 master weights every micro-batch.
-        layers = _cast_layers(params["layers"], jnp.dtype(cfg.dtype),
-                              "moe" if self.moe_fn is not None else "mlp")
-
         segs = self._window_segments()
         T = input_ids.shape[1]
         ltd_keep = self._ltd_keep
@@ -883,8 +977,7 @@ class TransformerLM:
                         xi = jax.tree_util.tree_map(lambda p: p[i], seg_layers)
                         x, aux = seg_body(x, xi)
                         aux_total = aux_total + aux
-            self._last_aux_loss = aux_total
-            return x
+            return x, aux_total
         if ltd or pld_theta is not None:
             # shared routing key for LTD/PLD: step seed (engine-provided,
             # fresh per step/epoch) folded with batch content (fresh per
@@ -958,8 +1051,7 @@ class TransformerLM:
                 xi = jax.tree_util.tree_map(lambda p: p[i], layers)
                 x, aux = body(x, (xi, jnp.int32(i)) if wrapped else xi)
                 aux_total = aux_total + aux
-        self._last_aux_loss = aux_total
-        return x
+        return x, aux_total
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -989,28 +1081,82 @@ class TransformerLM:
 
     def loss_fn(self, params: Params, batch: Dict[str, jax.Array],
                 rng: Optional[jax.Array] = None) -> jax.Array:
+        return self.loss_and_parts(params, batch)[0]
+
+    def loss_and_parts(self, params: Params, batch: Dict[str, jax.Array]):
+        """``(loss, parts)``. ``parts`` holds what a step record carries
+        beside the loss, as device values: nothing for a model with one set
+        of logits; with the exit gate ``pass_loss`` [R] (each pass's mean
+        cross-entropy), ``exit_prob`` [R] (the mean exit distribution) and
+        ``exit_entropy`` (its mean entropy), over the target positions."""
         cfg = self.cfg
+        if cfg.loss_tiling > 1:
+            self._one_pass_only("the tiled logits loss (loss_tiling > 1)")
         seed = batch.get("ltd_seed")
         pld = batch.get("pld_theta")
-        hidden = self.hidden_states(
+        hs, aux = self._hidden_passes(
             params, batch["input_ids"],
             ltd_seed=None if seed is None else seed[0],
             pld_theta=None if pld is None else pld[0])
-        # the tiled loss holds the head matmul too, so it has no lm_head scope
-        logits = (None if cfg.loss_tiling > 1
-                  else self._project(params, hidden))
-        with jax.named_scope("loss"):
-            loss = (self._tiled_loss(params, batch, hidden) if logits is None
-                    else lm_loss(cfg, logits, batch))
-            aux = getattr(self, "_last_aux_loss", None)
-            if aux is not None and cfg.num_experts > 1:
+        if cfg.exit_loss_beta is not None:
+            loss, parts = self._expected_exit_loss(params, batch, hs)
+        else:
+            # the tiled loss holds the head matmul too, so it has no lm_head
+            # scope
+            logits = (None if cfg.loss_tiling > 1
+                      else self._project(params, hs[-1]))
+            with jax.named_scope("loss"):
+                loss = (self._tiled_loss(params, batch, hs[-1])
+                        if logits is None else lm_loss(cfg, logits, batch))
+            parts = {}
+        if cfg.num_experts > 1:
+            with jax.named_scope("loss"):
                 loss = loss + cfg.moe_aux_loss_coef * aux
-            return loss
+        return loss, parts
+
+    def _expected_exit_loss(self, params: Params,
+                            batch: Dict[str, jax.Array], hs):
+        """The looped family's stage-I objective over the R pass outputs
+        ``hs``: logits ``z_t = W_head h_t`` through the one head, a per-token
+        gate ``lambda_t = sigmoid(w_g . h_t + b_g)``, the exit distribution
+        ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` (``p_R`` takes what is
+        left, so ``lambda_R`` is never read), and the mean over target
+        positions of ``sum_t p_t CE(z_t) - beta H(p)``. The distribution is
+        carried as its logarithm (``log_sigmoid``), in f32."""
+        cfg = self.cfg
+        with jax.named_scope("loss"):
+            labels, lmask = _lm_targets(batch)
+        nll = []
+        for h in hs:
+            logits = self._project(params, h)
+            with jax.named_scope("loss"):
+                nll.append(token_cross_entropy(logits, labels, cfg.z_loss))
+        with jax.named_scope("loss"), jax.named_scope("exit_gate"):
+            w = params["exit_gate"]["w"].astype(jnp.float32)
+            b = params["exit_gate"]["b"].astype(jnp.float32)
+            log_p, log_stay = [], 0.0
+            for h in hs[:-1]:
+                # a multiply and a sum, not a dot: f32 on the vector unit
+                s = (h.astype(jnp.float32) * w).sum(axis=-1) + b
+                log_p.append(log_stay + jax.nn.log_sigmoid(s))
+                log_stay = log_stay + jax.nn.log_sigmoid(-s)
+            log_p = jnp.stack(log_p + [log_stay])               # [R, B, T]
+            p, nll = jnp.exp(log_p), jnp.stack(nll)
+            entropy = -(p * log_p).sum(axis=0)
+            denom = jnp.maximum(lmask.sum(), 1)
+
+            def mean(a):
+                return jnp.where(lmask, a, 0.0).sum(axis=(-2, -1)) / denom
+
+            loss = mean((p * nll).sum(axis=0) - cfg.exit_loss_beta * entropy)
+            return loss, {"pass_loss": mean(nll), "exit_prob": mean(p),
+                          "exit_entropy": mean(entropy)}
 
     # ---- decode path (KV cache) ------------------------------------------
     def init_kv_cache(self, batch_size: int, max_seq_len: Optional[int] = None,
                       dtype: Optional[Any] = None) -> Dict[str, jax.Array]:
         """Allocate a dense per-layer KV cache (inference engine decode state)."""
+        self._one_pass_only("the dense key-value cache")
         cfg = self.cfg
         S = max_seq_len or cfg.max_seq_len
         dt = jnp.dtype(dtype or cfg.dtype)
@@ -1028,6 +1174,7 @@ class TransformerLM:
         same batch may be at different decode depths (ragged batch semantics of
         ``InferenceEngineV2.put`` engine_v2.py:107, on dense tiles).
         """
+        self._one_pass_only("the cached forward (forward_with_cache)")
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         B, t = input_ids.shape
@@ -1107,6 +1254,7 @@ class TransformerLM:
         [0, bs), v in [bs, 2bs)) — KV HBM traffic halves (int8) or quarters
         (``bits=4``: lane j paired with j + K*d/2 per byte), which is the
         decode bound on a bandwidth-limited chip."""
+        self._one_pass_only("the paged key-value cache")
         cfg = self.cfg
         dt = jnp.dtype(dtype or cfg.dtype)
         lanes = cfg.num_kv_heads * cfg.head_dim
@@ -1135,6 +1283,7 @@ class TransformerLM:
         ``InferenceEngineV2.put`` (engine_v2.py:107) over paged device memory
         (v2/kernels/ragged_ops/blocked_flash parity).
         """
+        self._one_pass_only("the paged-cache forward")
         from deepspeed_tpu.ops.paged_attention import (paged_attention_tp,
                                                        paged_update)
 
@@ -1234,6 +1383,7 @@ class TransformerLM:
 
         Returns (logits [G, V], updated cache).
         """
+        self._one_pass_only("the packed ragged forward")
         from deepspeed_tpu.ops.paged_attention import (
             packed_kv_append, packed_kv_append_quant,
             ragged_paged_attention_tp)
@@ -1356,6 +1506,7 @@ class TransformerLM:
         stream once per PROMPT instead of once per 256-token chunk — on a
         bandwidth-bound chip that alone is ~T/256 x.
         """
+        self._one_pass_only("the prefill forward")
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         B, T = input_ids.shape
@@ -1447,6 +1598,7 @@ class TransformerLM:
         frontier (tokens already in the pool); row position = pos_base + t.
         Returns (logits [B, V], updated tail).
         """
+        self._one_pass_only("the fused decode tail")
         from deepspeed_tpu.ops.paged_attention import decode_pool_partials_tp
 
         cfg = self.cfg
@@ -1575,6 +1727,9 @@ class TransformerLM:
         layer_specs: Params = {"ln1": norm_spec, "attn": attn_spec, "mlp": mlp}
         if not cfg.parallel_shared_norm:
             layer_specs["ln2"] = dict(norm_spec)
+        if cfg.sandwich_norm:
+            layer_specs["ln1_post"] = dict(norm_spec)
+            layer_specs["ln2_post"] = dict(norm_spec)
         specs: Params = {
             "embed": {"tokens": P("tp", None)},
             "layers": layer_specs,
@@ -1586,4 +1741,6 @@ class TransformerLM:
             specs["embed"]["pos"] = P(None, None)
         if not cfg.tie_embeddings:
             specs["lm_head"] = P(None, "tp")
+        if cfg.exit_loss_beta is not None:
+            specs["exit_gate"] = {"w": P(None), "b": P()}
         return specs

@@ -13,6 +13,11 @@ engine.
   (start, length, generation), written from ``gc.callbacks`` inside a
   ``ds.gc`` profiler annotation. A write stores floats into the ring: nothing
   outlives the step and nothing is collector-tracked.
+* ``loss_parts`` — for a model whose loss has parts (a looped model's
+  per-pass losses and exit distribution): the last ``PARTS_KEPT`` steps'
+  loss and parts as the device values the step program returned. Writing a
+  row waits for nothing; :meth:`StepLog.parts` reads them to the host when a
+  reader asks, after the step.
 * the step-program table — one :class:`StepProgram` per jitted step program
   an engine built, appended on a ``_fused_step_cache`` miss only. The row
   keeps the program's abstract arguments, so ``memory_analysis()`` and the
@@ -39,6 +44,8 @@ __all__ = ["StepLog", "StepProgram", "get_steplog", "install_gc_hook",
 
 #: a step is slow when its period exceeds this many medians
 SLOW_FACTOR = 1.25
+#: steps whose loss parts are kept (device values: a few floats each)
+PARTS_KEPT = 256
 
 
 class StepLog:
@@ -50,8 +57,28 @@ class StepLog:
         self.size = int(size)
         self._steps = np.zeros((self.size, 4))
         self._pauses = np.zeros((self.size, 3))
+        self._parts: List[Any] = [None] * PARTS_KEPT
         self.n_steps = 0
         self.n_pauses = 0
+        self.n_parts = 0
+
+    def loss_parts(self, step: int, loss: Any, parts: Dict[str, Any]) -> None:
+        """Keep one step's loss and its parts as they came out of the step
+        program (device values; nothing is read here)."""
+        self._parts[self.n_parts % PARTS_KEPT] = (step, loss, parts)
+        self.n_parts += 1
+
+    def parts(self, last: Optional[int] = None) -> List[Dict[str, Any]]:
+        """The kept rows (the ``last`` newest of them), oldest first, read to
+        the host: ``{"step", "loss", <part>: array ...}``."""
+        n = self.n_parts
+        rows = (self._parts[:n] if n <= PARTS_KEPT else
+                self._parts[n % PARTS_KEPT:] + self._parts[:n % PARTS_KEPT])
+        if last is not None:
+            rows = rows[max(0, len(rows) - last):]
+        return [{"step": int(step), "loss": float(loss),
+                 **{k: np.asarray(v) for k, v in parts.items()}}
+                for step, loss, parts in rows]
 
     def step(self, step: int, t_enter: float, t_dispatched: float,
              t_exit: float) -> None:
@@ -125,9 +152,13 @@ class StepProgram:
     jitted function is held weakly: when its engine is gone, so is the
     program, and the row answers None."""
 
-    def __init__(self, name: str, key: Any, fn: Callable, mesh):
+    def __init__(self, name: str, key: Any, fn: Callable, mesh,
+                 layer_applications: Optional[int] = None):
         self.name = name
         self.key = str(key)
+        #: block applications one micro-batch's forward holds (layers run x
+        #: passes over them); None where the model does not say
+        self.layer_applications = layer_applications
         self.built_at = time.perf_counter()
         self._fn = weakref.ref(fn)
         self._mesh = mesh
@@ -173,8 +204,9 @@ class StepProgram:
 _PROGRAMS: deque = deque(maxlen=64)
 
 
-def record_program(name: str, key: Any, fn: Callable, mesh) -> StepProgram:
-    row = StepProgram(name, key, fn, mesh)
+def record_program(name: str, key: Any, fn: Callable, mesh,
+                   layer_applications: Optional[int] = None) -> StepProgram:
+    row = StepProgram(name, key, fn, mesh, layer_applications)
     _PROGRAMS.append(row)
     return row
 

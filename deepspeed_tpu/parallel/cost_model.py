@@ -72,6 +72,13 @@ class ModelProfile:
     def from_transformer_config(cls, cfg, seq: Optional[int] = None
                                 ) -> "ModelProfile":
         """Profile a :class:`~deepspeed_tpu.models.TransformerConfig`."""
+        if getattr(cfg, "num_passes", 1) > 1:
+            # flops and activation volumes below are 6 x parameters x tokens:
+            # one use of every weight a token. Refuse rather than under-count.
+            raise NotImplementedError(
+                f"the cost model counts one pass over the layer stack; a "
+                f"looped model (num_passes={cfg.num_passes}) uses each layer "
+                f"weight that many times a token")
         n = int(cfg.num_params_estimate())
         active = n
         if cfg.num_experts > 1:

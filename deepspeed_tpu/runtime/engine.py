@@ -935,9 +935,9 @@ class DeepSpeedTpuEngine:
 
     # ---- fused single-jit step (bench / graft path) -------------------
     def _fused_grads(self, params, batch, scale, ga: int):
-        """GA scan producing (summed scaled-loss grads, mean loss) — the shared
-        forward/backward half of the fused step (single-sourced with the 1-bit
-        fwd/bwd region in runtime/onebit.py)."""
+        """GA scan producing (summed scaled-loss grads, mean loss, the loss's
+        parts) — the shared forward/backward half of the fused step
+        (single-sourced with the 1-bit fwd/bwd region in runtime/onebit.py)."""
         from deepspeed_tpu.runtime.onebit import ga_grads
 
         return ga_grads(self.module, params, batch, scale, ga)
@@ -969,7 +969,9 @@ class DeepSpeedTpuEngine:
         what the device trace's module line says ran (``jit_ds_train_step``)."""
         self._fused_step_cache[key] = jitted
         self._uncaptured[key] = steplog.record_program(
-            jitted.__name__, key, jitted, self.mesh)
+            jitted.__name__, key, jitted, self.mesh,
+            layer_applications=getattr(self.module, "layer_applications",
+                                       None))
 
     def _dispatch_fused(self, key, *args):
         """Call the jitted step program of ``key`` (the ``ds.train.dispatch``
@@ -1001,20 +1003,28 @@ class DeepSpeedTpuEngine:
         key = ga
         if key not in self._fused_step_cache:
             def ds_train_step(params, opt_state, batch, scaler):
-                grads, loss = self._fused_grads(params, batch, scaler["scale"], ga)
+                grads, loss, parts = self._fused_grads(
+                    params, batch, scaler["scale"], ga)
                 new_params, new_opt, new_scaler, gnorm, skipped = \
                     self._apply_body(params, opt_state, grads, scaler, ga=float(ga))
-                return new_params, new_opt, new_scaler, loss, gnorm, skipped
+                return (new_params, new_opt, new_scaler, loss, gnorm, skipped,
+                        parts)
 
             self._step_program(key, jax.jit(
                 ds_train_step, donate_argnums=(0, 1),
                 out_shardings=(self.param_sharding, self.opt_sharding,
-                               None, None, None, None)))
+                               None, None, None, None, None)))
         batch = self._put_batch(batch)
         (self.params, self.opt_state, self.scaler_state, loss, gnorm,
-         skipped) = self._dispatch_fused(
+         skipped, parts) = self._dispatch_fused(
             key, self.params, self.opt_state, batch, self.scaler_state)
         self._last_loss, self._last_gnorm = loss, gnorm
+        if parts:
+            # the loss's parts (a looped model's per-pass losses and exit
+            # distribution) ride out of the step program with the loss and
+            # are kept as device values: nobody waits for them here
+            self._last_loss_parts = parts
+            self._steplog.loss_parts(self.global_steps, loss, parts)
         # only fp16 can skip; reading `skipped` otherwise would force a host
         # sync per step and serialize the dispatch pipeline
         self._commit_step(self.fp16_enabled and bool(skipped))
@@ -1095,7 +1105,7 @@ class DeepSpeedTpuEngine:
         if key not in self._fused_step_cache:
             def ds_train_step_offload(params, batch, scaler):
                 scale = scaler["scale"]
-                grads, loss = self._fused_grads(params, batch, scale, ga)
+                grads, loss, _ = self._fused_grads(params, batch, scale, ga)
                 grads = jax.tree_util.tree_map(
                     lambda g: g / (scale * ga), grads)
                 return grads, loss
@@ -1224,6 +1234,7 @@ class DeepSpeedTpuEngine:
         self._steplog = steplog.get_steplog()
         self._uncaptured: Dict[Any, steplog.StepProgram] = {}
         self._t_dispatched = 0.0
+        self._last_loss_parts: Optional[Dict[str, Any]] = None
         self._obs = None
         self._obs_bridge = None
         self._obs_server = None
@@ -1341,6 +1352,16 @@ class DeepSpeedTpuEngine:
         if at_print:
             if self._last_loss is not None:
                 o["loss"].set(float(self._last_loss))
+            if self._last_loss_parts:
+                # read where float(loss) already waited for the same program
+                from deepspeed_tpu.observability import get_registry
+
+                for name, val in self._last_loss_parts.items():
+                    for i, v in enumerate(np.atleast_1d(np.asarray(val))):
+                        get_registry().gauge(
+                            f"train/{name}", "a part of the last reported "
+                            "loss (models/transformer.py:loss_and_parts)",
+                            labels={"pass": str(i)}).set(float(v))
             o["lr"].set(float(self.get_lr()[0]))
             if self._zpp is not None:
                 # quant-error gauges ride the print cadence where the

@@ -74,24 +74,29 @@ def is_onebit(name: str) -> bool:
 
 def ga_grads(model, params, batch, scale, ga: int):
     """Per-device gradient-accumulation scan: summed grads of ``loss*scale``
-    over ``ga`` microbatches + mean loss. Shared by the engine's fused step
-    and the 1-bit fwd/bwd region so the accumulation semantics stay single-
-    sourced."""
+    over ``ga`` microbatches, the mean loss, and the mean of the loss's parts
+    (what ``model.loss_and_parts`` returns beside the loss: ``{}`` for most
+    models). Shared by the engine's fused step and the 1-bit fwd/bwd region
+    so the accumulation semantics stay single-sourced."""
+    with_parts = hasattr(model, "loss_and_parts")
 
     def scaled_loss(p, mb):
-        loss = model.loss_fn(p, mb)
+        loss, parts = (model.loss_and_parts(p, mb) if with_parts
+                       else (model.loss_fn(p, mb), {}))
         with jax.named_scope("loss"):
-            return loss * scale
+            return loss * scale, parts
 
     def micro(acc, mb):
         if hasattr(model, "loss_and_grad"):  # 1F1B pipeline: manual backward
             loss, g = model.loss_and_grad(params, mb, scale)
+            parts = {}
         else:
-            sloss, g = jax.value_and_grad(scaled_loss)(params, mb)
+            (sloss, parts), g = jax.value_and_grad(
+                scaled_loss, has_aux=True)(params, mb)
             with jax.named_scope("loss"):
                 loss = sloss / scale
         with jax.named_scope("grad_accum"):
-            return jax.tree_util.tree_map(jnp.add, acc, g), loss
+            return jax.tree_util.tree_map(jnp.add, acc, g), (loss, parts)
 
     # the accumulator, and with ga > 1 the micro-batch loop's own slicing
     # and carries; the model's operations inside keep their own scopes
@@ -102,9 +107,11 @@ def ga_grads(model, params, batch, scale, ga: int):
             mbs = jax.tree_util.tree_map(
                 lambda x: x.reshape((ga, x.shape[0] // ga) + x.shape[1:]),
                 batch)
-            grads, losses = lax.scan(micro, zeros, mbs)
-            return grads, losses.mean()
-    return micro(zeros, batch)
+            grads, (losses, parts) = lax.scan(micro, zeros, mbs)
+            return grads, losses.mean(), jax.tree_util.tree_map(
+                lambda a: a.mean(axis=0), parts)
+    grads, (loss, parts) = micro(zeros, batch)
+    return grads, loss, parts
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +258,7 @@ def build_plan(model, topology, param_spec_tree, param_shapes, opt_name: str,
     # ---- fwd/bwd: local grads with a leading device axis ----------------
     def grads_fn(params, batch, scale, ga: int):
         if not manual:  # single device — dense path, same layout
-            grads, loss = ga_grads(model, params, batch, scale, ga)
+            grads, loss, _ = ga_grads(model, params, batch, scale, ga)
             return jax.tree_util.tree_map(lambda g: g[None], grads), loss
         in_p = jax.tree_util.tree_map(lambda s: _restrict(s, manual), pspecs,
                                       is_leaf=lambda s: s is None)
@@ -261,7 +268,7 @@ def build_plan(model, topology, param_spec_tree, param_shapes, opt_name: str,
             is_leaf=lambda s: s is None)
 
         def body(params, batch, scale):
-            grads, loss = ga_grads(model, params, batch, scale, ga)
+            grads, loss, _ = ga_grads(model, params, batch, scale, ga)
             loss = lax.pmean(loss, tuple(manual))
             return jax.tree_util.tree_map(lambda g: g[None], grads), loss
 

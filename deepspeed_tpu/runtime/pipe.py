@@ -90,6 +90,10 @@ class PipelineModule:
             raise NotImplementedError(
                 "mixed-window models (window_start_layer > 0, qwen2-style) "
                 "are not supported under pipeline parallelism")
+        if getattr(model.cfg, "looped", False):
+            # a stage runs its slice of the stack once a micro-batch; a
+            # looped model sends the last stage's output back to the first
+            model._one_pass_only("pipeline parallelism (PipelineModule)")
         if schedule not in ("1f1b", "gpipe"):
             raise ValueError(f"unknown pipe schedule '{schedule}'")
         self.model = model

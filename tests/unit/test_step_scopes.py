@@ -20,6 +20,13 @@ CASES = {
     "moe": ({"num_experts": 4, "top_k": 2}, 1),
     "dense_tiled_loss_ga2": ({"loss_tiling": 4}, 2),
     "dense_unrolled_layers": ({"scan_layers": False}, 1),
+    # four passes over shared weights under recomputation, sandwich norms,
+    # the exit gate and the expected-exit loss: the pass loop's own
+    # operations and the summing of the shared weights' gradients included
+    "looped": ({"num_passes": 4, "sandwich_norm": True, "exit_loss_beta": 0.1,
+                "tie_embeddings": False, "remat_policy": "full"}, 1),
+    "looped_ga2": ({"num_passes": 2, "sandwich_norm": True,
+                    "exit_loss_beta": 0.1}, 2),
 }
 
 
@@ -58,6 +65,11 @@ def test_every_operation_carries_a_step_scope(case):
         want.add("lm_head")
     if CASES[case][1] > 1:      # with one micro-batch the compiler folds 0 + g
         want.add("grad_accum")
+    if case.startswith("looped"):
+        want.add("exit_gate")
+        # the gate's operations nest inside the loss: loss/exit_gate/...
+        gate = [n for n in names if "exit_gate" in re.split(r"[/()]", n)]
+        assert gate and all("loss" in re.split(r"[/()]", n) for n in gate)
     assert found == want
 
 
