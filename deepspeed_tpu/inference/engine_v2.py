@@ -11,8 +11,7 @@ blocks its length requires — HBM footprint follows allocated blocks, not
 ``v2/ragged/kv_cache.py``). The ``BlockedAllocator``'s block ids ARE the
 physical pool indices; host-side scheduling builds the block tables the Pallas
 paged-attention kernel (``ops/paged_attention.py``) consumes via scalar
-prefetch. A ``paged=False`` escape hatch keeps the dense per-slot cache
-(``TransformerLM.forward_with_cache``) for A/B testing.
+prefetch.
 """
 
 from __future__ import annotations
@@ -67,8 +66,7 @@ class _PausedSeq:
 class InferenceEngineV2:
     def __init__(self, model: TransformerLM, params=None, max_sequences: int = 8,
                  max_seq_len: Optional[int] = None, block_size: int = 128,
-                 num_blocks: Optional[int] = None, paged: bool = True,
-                 packed: bool = True, topology=None,
+                 num_blocks: Optional[int] = None, topology=None,
                  mesh: Optional[dict] = None, kv_dtype: str = "bf16",
                  weight_dtype: str = "bf16", prefix_cache=None,
                  speculative=None, decode_kernel: str = "pallas",
@@ -93,7 +91,6 @@ class InferenceEngineV2:
         self.module = model
         self.cfg = model.cfg
         self.max_seq_len = max_seq_len or self.cfg.max_seq_len
-        self.paged = paged
         if topology is None:
             from deepspeed_tpu.config.config import MeshConfig
 
@@ -157,8 +154,6 @@ class InferenceEngineV2:
             raise ValueError(f"kv_dtype must be bf16|int8|int4, got "
                              f"{kv_dtype!r}")
         self.kv_dtype = kv_dtype
-        if kv_dtype != "bf16" and not (paged and packed):
-            raise ValueError("quantized KV needs the packed paged engine")
         if kv_dtype == "int4" and "tp" in self.mesh.axis_names \
                 and self.mesh.shape["tp"] > 1:
             # the int4 pool's byte lanes pair feature j with j + K*d/2
@@ -239,64 +234,56 @@ class InferenceEngineV2:
                             and self.mesh.shape["ep"] > 1)
             if self._moe_ep and moe_replica_slots > 0:
                 self._moe_expand_placement(moe_replica_slots)
-        if paged:
-            self.num_blocks = self.state.allocator.num_blocks
-            cache = model.init_paged_kv_cache(
-                self.num_blocks, block_size, quantize=kv_dtype != "bf16",
-                bits=4 if kv_dtype == "int4" else 8)
-            # pool sharded over tp on the lane-folded kv-head dim
-            # ([L, nb+1, bs, K*d]: contiguous d-lanes per kv head);
-            # per-token int8 scales replicated (identical on every shard)
-            kv_spec = shd.filter_spec(P(None, None, None, "tp"),
-                                      self.mesh.axis_names)
-            cache_spec = {"k": kv_spec, "v": kv_spec}
-            if "kv_scale" in cache:
-                cache_spec["kv_scale"] = P(None, None, None, None)
-            self.cache = jax.device_put(
-                cache, {k: NamedSharding(self.mesh, s)
-                        for k, s in cache_spec.items()})
-            self._pos = np.zeros((max_sequences,), np.int32)
-            # pin the output cache to the SAME sharding as the input: an
-            # XLA-chosen output spec would change the next call's signature
-            # and retrace/recompile every step program once per alternation
-            kv_out = {k: NamedSharding(self.mesh, s)
-                      for k, s in cache_spec.items()}
-            self._kv_out = kv_out       # reused by the tier-promote scatter
-            # donate the pool: the step returns the updated {'k','v'} dict and
-            # self.cache is immediately reassigned — without donation XLA would
-            # double-buffer the whole pool and copy all unchanged blocks
-            self._step = jax.jit(model.forward_with_paged_cache,
-                                 donate_argnums=(2,),
-                                 out_shardings=(None, kv_out))
-            # the kernel choice rides a keyword-bound partial so the
-            # positional donate/static indices stay valid (a traced string
-            # argument would not jit)
-            self._fwd_packed = functools.partial(
-                model.forward_with_packed_cache,
-                decode_kernel=self.decode_kernel)
-            self._step_packed = jax.jit(self._fwd_packed,
-                                        donate_argnums=(2,),
-                                        static_argnums=(8, 9, 10),
-                                        out_shardings=(None, kv_out))
-            self._decode_loop = jax.jit(self._multi_decode,
-                                        donate_argnums=(1,),
-                                        static_argnums=(6, 9, 10, 11),
-                                        out_shardings=(None, kv_out))
-            # fused promote-prologue twins of the two decode dispatches,
-            # built lazily on the first fenced step (they close over
-            # whether the pool carries int8 scales)
-            self._decode_loop_fused = None
-            self._step_packed_fused = None
-            self._prefill_step = jax.jit(self._prefill_impl,
-                                         donate_argnums=(3,),
-                                         out_shardings=(None, kv_out))
-            log_dist(f"paged KV pool: {self.num_blocks} blocks x {block_size} "
-                     f"tokens ({self.cache['k'].nbytes * 2 / 1e6:.0f} MB), "
-                     f"mesh={self.topology}")
-        else:
-            self.cache = model.init_kv_cache(max_sequences, self.max_seq_len)
-            self._step = jax.jit(model.forward_with_cache)
-        self.packed = packed and paged
+        self.num_blocks = self.state.allocator.num_blocks
+        cache = model.init_paged_kv_cache(
+            self.num_blocks, block_size, quantize=kv_dtype != "bf16",
+            bits=4 if kv_dtype == "int4" else 8)
+        # pool sharded over tp on the lane-folded kv-head dim
+        # ([L, nb+1, bs, K*d]: contiguous d-lanes per kv head);
+        # per-token int8 scales replicated (identical on every shard)
+        kv_spec = shd.filter_spec(P(None, None, None, "tp"),
+                                  self.mesh.axis_names)
+        cache_spec = {"k": kv_spec, "v": kv_spec}
+        if "kv_scale" in cache:
+            cache_spec["kv_scale"] = P(None, None, None, None)
+        self.cache = jax.device_put(
+            cache, {k: NamedSharding(self.mesh, s)
+                    for k, s in cache_spec.items()})
+        self._pos = np.zeros((max_sequences,), np.int32)
+        # pin the output cache to the SAME sharding as the input: an
+        # XLA-chosen output spec would change the next call's signature
+        # and retrace/recompile every step program once per alternation
+        kv_out = {k: NamedSharding(self.mesh, s)
+                  for k, s in cache_spec.items()}
+        self._kv_out = kv_out       # reused by the tier-promote scatter
+        # donate the pool: the step returns the updated {'k','v'} dict and
+        # self.cache is immediately reassigned — without donation XLA would
+        # double-buffer the whole pool and copy all unchanged blocks.
+        # The kernel choice rides a keyword-bound partial so the
+        # positional donate/static indices stay valid (a traced string
+        # argument would not jit)
+        self._fwd_packed = functools.partial(
+            model.forward_with_packed_cache,
+            decode_kernel=self.decode_kernel)
+        self._step_packed = jax.jit(self._fwd_packed,
+                                    donate_argnums=(2,),
+                                    static_argnums=(8, 9, 10),
+                                    out_shardings=(None, kv_out))
+        self._decode_loop = jax.jit(self._multi_decode,
+                                    donate_argnums=(1,),
+                                    static_argnums=(6, 9, 10, 11),
+                                    out_shardings=(None, kv_out))
+        # fused promote-prologue twins of the two decode dispatches,
+        # built lazily on the first fenced step (they close over
+        # whether the pool carries int8 scales)
+        self._decode_loop_fused = None
+        self._step_packed_fused = None
+        self._prefill_step = jax.jit(self._prefill_impl,
+                                     donate_argnums=(3,),
+                                     out_shardings=(None, kv_out))
+        log_dist(f"paged KV pool: {self.num_blocks} blocks x {block_size} "
+                 f"tokens ({self.cache['k'].nbytes * 2 / 1e6:.0f} MB), "
+                 f"mesh={self.topology}")
         # ---- prefix-cache KV reuse + n-gram speculative decoding ----------
         from deepspeed_tpu.config.config import (PrefixCacheConfig,
                                                  SpeculativeConfig)
@@ -310,10 +297,6 @@ class InferenceEngineV2:
 
         self.prefix_cfg = _coerce(PrefixCacheConfig, prefix_cache)
         self.spec_cfg = _coerce(SpeculativeConfig, speculative)
-        if (self.prefix_cfg.enabled or self.spec_cfg.enabled) \
-                and not self.packed:
-            raise ValueError("prefix_cache / speculative need the packed "
-                             "paged engine (paged=True, packed=True)")
         self.prefix_cache: Optional[PrefixCache] = None
         # tiered KV spill state (inference.prefix_cache.tiers): the store
         # holding demoted blocks' pages, the queue of promotions awaiting
@@ -614,10 +597,7 @@ class InferenceEngineV2:
         for uid in uids:
             seq = self.state.sequences.get(uid)
             if seq is not None:
-                if self.paged:
-                    self._pos[seq.slot] = 0
-                else:
-                    self.cache["pos"] = self.cache["pos"].at[seq.slot].set(0)
+                self._pos[seq.slot] = 0
             self.state.flush(uid)
             if self._hist is not None:
                 self._hist.pop(uid, None)
@@ -979,8 +959,6 @@ class InferenceEngineV2:
         side effects — when the uid has no pausable state (unknown, already
         paused, mid-step, nothing in KV yet) or the store cannot hold the
         pages; the caller falls back to a plain shed."""
-        if not (self.paged and self.packed):
-            return False
         seq = self.state.sequences.get(uid)
         if seq is None or uid in self._paused or seq.in_flight:
             return False
@@ -1489,8 +1467,6 @@ class InferenceEngineV2:
                            top_p: float = 1.0, seed: int = 0
                            ) -> Dict[int, np.ndarray]:
         """The fused on-device decode scan (one dispatch for ``steps``)."""
-        if not (self.paged and self.packed):
-            raise ValueError("decode_batch needs the packed paged engine")
         if not self.state.can_schedule_batch(batch_uids,
                                              [steps] * len(batch_uids)):
             raise CapacityError(batch_uids, [steps] * len(batch_uids),
@@ -1897,8 +1873,8 @@ class InferenceEngineV2:
             ) -> Dict[int, np.ndarray]:
         """Advance every listed sequence by its token chunk; returns next-token
         logits per uid. Chunks may be whole prompts (prefill), single decode
-        tokens, or anything between — per-slot cache positions make the batch
-        ragged in effect while dense in shape. With ``inference.prefix_cache``
+        tokens, or anything between: the batch is one packed row of the
+        scheduled tokens, each with its slot and position. With ``inference.prefix_cache``
         enabled, a fresh multi-token chunk first attaches any cached
         full-block prefix and only its uncached suffix is prefilled."""
         bus = self._ebus
@@ -1947,31 +1923,30 @@ class InferenceEngineV2:
                      else 0)
                 trimmed.append(c[n:] if n else c)
             chunks = trimmed
-        if self.packed and chunks and all(len(c) > 1 for c in chunks) \
+        if chunks and all(len(c) > 1 for c in chunks) \
                 and max(len(c) for c in chunks) <= self.module.PREFILL_MAX \
                 and all(self._fresh(uid) for uid in batch_uids):
             return self._prefill_whole(batch_uids, chunks)
-        if self.packed:
-            # chunked prefill (FastGen scheduling behavior): prompts longer
-            # than one atom are fed in MAX_ATOM slices over internal steps.
-            # JOINT capacity is checked for the WHOLE batch of prompts first
-            # — a mid-prompt failure would otherwise leave sequences
-            # half-prefilled with the pool partially consumed.
-            cap = self.module.MAX_ATOM
-            if any(len(c) > cap for c in chunks) and \
-                    not self.state.can_schedule_batch(
-                        batch_uids, [len(c) for c in chunks]):
-                raise CapacityError(batch_uids, [len(c) for c in chunks],
-                                    "joint chunked prefill")
-            while any(len(c) > cap for c in chunks):
-                sel = [(u, c[:cap]) for u, c in zip(batch_uids, chunks)
-                       if len(c) > cap]
-                self.put([u for u, _ in sel], [c for _, c in sel])
-                chunks = [c[cap:] if len(c) > cap else c for c in chunks]
-                # rebase the host clock: the sub-puts above ran device
-                # steps to completion — without this, the final step's
-                # host_ms would absorb their device+fetch time
-                t_put = time.perf_counter()
+        # chunked prefill (FastGen scheduling behavior): prompts longer
+        # than one atom are fed in MAX_ATOM slices over internal steps.
+        # JOINT capacity is checked for the WHOLE batch of prompts first
+        # — a mid-prompt failure would otherwise leave sequences
+        # half-prefilled with the pool partially consumed.
+        cap = self.module.MAX_ATOM
+        if any(len(c) > cap for c in chunks) and \
+                not self.state.can_schedule_batch(
+                    batch_uids, [len(c) for c in chunks]):
+            raise CapacityError(batch_uids, [len(c) for c in chunks],
+                                "joint chunked prefill")
+        while any(len(c) > cap for c in chunks):
+            sel = [(u, c[:cap]) for u, c in zip(batch_uids, chunks)
+                   if len(c) > cap]
+            self.put([u for u, _ in sel], [c for _, c in sel])
+            chunks = [c[cap:] if len(c) > cap else c for c in chunks]
+            # rebase the host clock: the sub-puts above ran device
+            # steps to completion — without this, the final step's
+            # host_ms would absorb their device+fetch time
+            t_put = time.perf_counter()
         if not self.state.can_schedule_batch(batch_uids,
                                              [len(c) for c in chunks]):
             raise CapacityError(batch_uids, [len(c) for c in chunks])
@@ -1980,112 +1955,65 @@ class InferenceEngineV2:
 
         Bs = self.state.max_sequences
 
-        if self.packed:
-            # token-packed ragged batch (ragged_wrapper.py/atom_builder
-            # parity): one row of the scheduled tokens in two regions —
-            # decode steps as 1-token atoms, every longer chunk as ONE
-            # whole-chunk atom (its KV blocks are DMA'd once; its own tokens
-            # attend from VMEM so the step's appends hoist out of the layer
-            # scan). Region sizes and the atom width are bucketed to powers
-            # of two so the jit cache stays O(log^2) entries. Layout shared
-            # with the spec-verify path via _pack_atoms.
-            tok_ids, tok_slot, tok_pos, valid, starts, dr, tile, no_past = \
-                self._pack_atoms(descs, chunks)
-            gather_idx = np.zeros((Bs,), np.int32)
-            for i, c in enumerate(chunks):       # chunk end → next-token
-                gather_idx[i] = starts[i] + len(c) - 1
-            fused = None
-            if self._promote_q or self._pause_q:
-                fused = self._fence_promotes()  # promote-completion fence
-            t_host = time.perf_counter()
-            with jax.sharding.set_mesh(self.mesh):
-                if fused is None:
-                    logits, self.cache = self._step_packed(
+        # token-packed ragged batch (ragged_wrapper.py/atom_builder
+        # parity): one row of the scheduled tokens in two regions —
+        # decode steps as 1-token atoms, every longer chunk as ONE
+        # whole-chunk atom (its KV blocks are DMA'd once; its own tokens
+        # attend from VMEM so the step's appends hoist out of the layer
+        # scan). Region sizes and the atom width are bucketed to powers
+        # of two so the jit cache stays O(log^2) entries. Layout shared
+        # with the spec-verify path via _pack_atoms.
+        tok_ids, tok_slot, tok_pos, valid, starts, dr, tile, no_past = \
+            self._pack_atoms(descs, chunks)
+        gather_idx = np.zeros((Bs,), np.int32)
+        for i, c in enumerate(chunks):       # chunk end → next-token
+            gather_idx[i] = starts[i] + len(c) - 1
+        fused = None
+        if self._promote_q or self._pause_q:
+            fused = self._fence_promotes()  # promote-completion fence
+        t_host = time.perf_counter()
+        with jax.sharding.set_mesh(self.mesh):
+            if fused is None:
+                logits, self.cache = self._step_packed(
+                    self.params, jnp.asarray(tok_ids), self.cache,
+                    jnp.asarray(self._block_tables()),
+                    jnp.asarray(tok_slot), jnp.asarray(tok_pos),
+                    jnp.asarray(valid), jnp.asarray(gather_idx), dr,
+                    tile, no_past)
+            else:
+                recs, failed, idx, kp, vp, sp = fused
+                try:
+                    logits, self.cache = self._get_step_packed_fused()(
                         self.params, jnp.asarray(tok_ids), self.cache,
+                        jnp.asarray(idx), jnp.asarray(kp),
+                        jnp.asarray(vp), self._psp(sp),
                         jnp.asarray(self._block_tables()),
                         jnp.asarray(tok_slot), jnp.asarray(tok_pos),
-                        jnp.asarray(valid), jnp.asarray(gather_idx), dr,
-                        tile, no_past)
-                else:
-                    recs, failed, idx, kp, vp, sp = fused
-                    try:
-                        logits, self.cache = self._get_step_packed_fused()(
-                            self.params, jnp.asarray(tok_ids), self.cache,
-                            jnp.asarray(idx), jnp.asarray(kp),
-                            jnp.asarray(vp), self._psp(sp),
-                            jnp.asarray(self._block_tables()),
-                            jnp.asarray(tok_slot), jnp.asarray(tok_pos),
-                            jnp.asarray(valid), jnp.asarray(gather_idx),
-                            dr, tile, no_past)
-                    except BaseException:
-                        self.prefix_cache.cancel_promotes(recs)
-                        raise
-                    self._finish_fused_promotes(recs, failed)
-                t_disp = time.perf_counter()
-                out = np.asarray(logits)
-            t_fetch = time.perf_counter()
-            # host scheduling vs dispatch vs device+transfer accounting:
-            # host_ms is pure python/numpy batch building, dispatch_ms is
-            # the async jit call (argument transfer + enqueue), fetch_ms
-            # blocks on the device step + the logits D2H
-            self.timing = {
-                "host_ms": (t_host - t_put) * 1e3,
-                "dispatch_ms": (t_disp - t_host) * 1e3,
-                "fetch_ms": (t_fetch - t_disp) * 1e3,
-            }
-            if self._obs is not None:
-                self._obs["put_host_ms"].observe(self.timing["host_ms"])
-                self._obs["put_fetch_ms"].observe(self.timing["fetch_ms"])
-                self._obs["tokens"].inc(float(sum(len(c) for c in chunks)))
-            results: Dict[int, np.ndarray] = {}
-            for i, (d, c) in enumerate(zip(descs, chunks)):
-                results[d.uid] = out[i]
-                self._pos[d.slot] = d.seen_tokens + len(c)
-                self._commit(d.uid, c)
-            return results
-
-        t_max = max(len(c) for c in chunks)
-        # dense tile: scheduled slots get their chunk (right-padded); others no-op.
-        tile = np.zeros((Bs, t_max), np.int32)
-        for d, c in zip(descs, chunks):
-            tile[d.slot, :len(c)] = c
-
-        # next-token logits at each chunk's true end, gathered in ONE device op
-        # + ONE transfer (per-slot python indexing would pay a full dispatch
-        # round-trip per sequence)
-        slots = np.array([d.slot for d in descs], np.int32)
-        ends = np.array([len(c) - 1 for c in chunks], np.int32)
-
-        if self.paged:
-            valid = np.zeros((Bs, t_max), bool)
-            for d, c in zip(descs, chunks):
-                valid[d.slot, :len(c)] = True
-            with jax.sharding.set_mesh(self.mesh):
-                logits, self.cache = self._step(
-                    self.params, jnp.asarray(tile), self.cache,
-                    jnp.asarray(self._block_tables()), jnp.asarray(self._pos),
-                    jnp.asarray(valid))
-                out = np.asarray(logits[jnp.asarray(slots), jnp.asarray(ends)])
-            results: Dict[int, np.ndarray] = {}
-            for i, (d, c) in enumerate(zip(descs, chunks)):
-                results[d.uid] = out[i]
-                self._pos[d.slot] = d.seen_tokens + len(c)
-                self._commit(d.uid, c)
-            return results
-
-        valid = np.zeros((Bs, t_max), bool)
-        for d, c in zip(descs, chunks):
-            valid[d.slot, :len(c)] = True
-        logits, new_cache = self._step(self.params, jnp.asarray(tile),
-                                       self.cache, jnp.asarray(valid))
-        out = np.asarray(logits[jnp.asarray(slots), jnp.asarray(ends)])
-        results = {}
-        new_pos = np.asarray(self.cache["pos"]).copy()
+                        jnp.asarray(valid), jnp.asarray(gather_idx),
+                        dr, tile, no_past)
+                except BaseException:
+                    self.prefix_cache.cancel_promotes(recs)
+                    raise
+                self._finish_fused_promotes(recs, failed)
+            t_disp = time.perf_counter()
+            out = np.asarray(logits)
+        t_fetch = time.perf_counter()
+        # host scheduling vs dispatch vs device+transfer accounting:
+        # host_ms is pure python/numpy batch building, dispatch_ms is
+        # the async jit call (argument transfer + enqueue), fetch_ms
+        # blocks on the device step + the logits D2H
+        self.timing = {
+            "host_ms": (t_host - t_put) * 1e3,
+            "dispatch_ms": (t_disp - t_host) * 1e3,
+            "fetch_ms": (t_fetch - t_disp) * 1e3,
+        }
+        if self._obs is not None:
+            self._obs["put_host_ms"].observe(self.timing["host_ms"])
+            self._obs["put_fetch_ms"].observe(self.timing["fetch_ms"])
+            self._obs["tokens"].inc(float(sum(len(c) for c in chunks)))
+        results: Dict[int, np.ndarray] = {}
         for i, (d, c) in enumerate(zip(descs, chunks)):
             results[d.uid] = out[i]
-            new_pos[d.slot] = d.seen_tokens + len(c)
+            self._pos[d.slot] = d.seen_tokens + len(c)
             self._commit(d.uid, c)
-        # padded rows advanced pos by t_max; restore true per-slot positions
-        self.cache = {"k": new_cache["k"], "v": new_cache["v"],
-                      "pos": jnp.asarray(new_pos)}
         return results

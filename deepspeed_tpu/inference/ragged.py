@@ -725,7 +725,7 @@ class SequenceDescriptor:
     """Per-sequence state (ragged_manager.py sequence descriptor parity)."""
 
     uid: int
-    slot: int                      # dense tile row while scheduled
+    slot: int                      # block-table row while scheduled
     seen_tokens: int = 0           # tokens already in KV
     blocks: List[int] = dataclasses.field(default_factory=list)
     in_flight: int = 0

@@ -496,12 +496,14 @@ def _cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _decode_block(h: jax.Array, wc: Params, cfg: TransformerConfig,
                   freqs: Optional[jax.Array], positions: jax.Array,
-                  attn_cache_fn: Callable,
+                  attend: Callable,
                   moe_fn: Optional[Callable] = None,
-                  moe_valid: Optional[jax.Array] = None) -> jax.Array:
-    """One decoder block on the decode path. ``attn_cache_fn(q, k, v)`` owns
-    the cache append + attention and returns [B, t, H, hd]. Mirrors
-    :func:`transformer_block` (parallel residual, shared norm, biases, MoE).
+                  moe_valid: Optional[jax.Array] = None) -> Any:
+    """One decoder block on the decode path. ``attend(q, k, v)`` owns the
+    cache read + attention and returns ``(out [B, t, H, hd], *left)``; the
+    block returns ``(h, left)``, handing what the layer leaves behind back
+    to the layer loop. Mirrors :func:`transformer_block` (parallel residual,
+    shared norm, biases, MoE).
     ``moe_valid`` [B, t] marks real (non-padding/idle) lanes: without it the
     batch's no-op rows would compete for expert capacity and skew routing."""
     def _mlp(hn):
@@ -517,14 +519,15 @@ def _decode_block(h: jax.Array, wc: Params, cfg: TransformerConfig,
     if cfg.use_rope:
         q = apply_rope(q, freqs, positions)
         k = apply_rope(k, freqs, positions)
-    attn_out = attn_out_proj(attn_cache_fn(q, k, v), wc["attn"], cfg)
+    attn, *left = attend(q, k, v)
+    attn_out = attn_out_proj(attn, wc["attn"], cfg)
     if cfg.parallel_block:
         hn2 = (hn1 if cfg.parallel_shared_norm
                else _norm(h, wc["ln2"], cfg.norm, cfg.norm_eps))
-        return h + attn_out + _mlp(hn2)
+        return h + attn_out + _mlp(hn2), left
     h = h + attn_out
     hn2 = _norm(h, wc["ln2"], cfg.norm, cfg.norm_eps)
-    return h + _mlp(hn2)
+    return h + _mlp(hn2), left
 
 
 def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
@@ -1164,74 +1167,93 @@ class TransformerLM:
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
                 "pos": jnp.zeros((batch_size,), jnp.int32)}
 
+    def _serve_layers(self, params: Params, token_ids: jax.Array,
+                      positions: jax.Array, moe_valid: Optional[jax.Array],
+                      attend: Callable, layer_xs: Any = (),
+                      carry: Any = ()) -> Any:
+        """The layer stack of every serving forward: embed ``token_ids``
+        [.., t] at ``positions`` [.., t], scan each window segment's layers
+        through :func:`_decode_block`, final norm.
+
+        ``attend(cseg, li, q, k, v, xs, carry)`` is the caller's own: the
+        cache read and the attention of layer ``li`` under segment config
+        ``cseg``. ``xs`` is that layer's slice of ``layer_xs`` (per-layer
+        scan inputs, leading dim L), ``carry`` what the layer before handed
+        on (``carry`` here for the first). It returns ``(out [.., t, H, hd],
+        ys, carry)``: ``ys`` is stacked over the layers, ``carry`` goes to
+        the next layer.
+
+        Returns (normed hidden [.., t, D], stacked ys, final carry)."""
+        self._one_pass_only("a serving forward (the cached layer loop)")
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        x = params["embed"]["tokens"].astype(dt)[token_ids]
+        if cfg.learned_pos:
+            # a bucket-padded row may lie past max_seq_len; pad rows are
+            # never gathered or appended
+            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
+            x = x + params["embed"]["pos"][safe_pos].astype(dt)
+        dense_layers, quant_items = split_quant_leaves(params["layers"])
+
+        parts = []
+        for lo, hi, cseg in self._window_segments():
+            def body(h_carry, xs, cseg=cseg):
+                h, prev = h_carry
+                layer_w, li, lxs = xs
+                wc = jax.tree_util.tree_map(
+                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
+                    layer_w)
+                for grp, name, qw in quant_items:
+                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
+                h, (ys, nxt) = _decode_block(
+                    h, wc, cseg, self._freqs, positions,
+                    lambda q, k, v: attend(cseg, li, q, k, v, lxs, prev),
+                    self.moe_fn, moe_valid=moe_valid)
+                return (h, nxt), ys
+
+            layer_w, lxs = jax.tree_util.tree_map(
+                lambda p: p[lo:hi], (dense_layers, layer_xs))
+            (x, carry), ys = jax.lax.scan(
+                body, (x, carry),
+                (layer_w, jnp.arange(lo, hi, dtype=jnp.int32), lxs))
+            parts.append(ys)
+        ys = jax.tree_util.tree_map(
+            lambda *p: p[0] if len(p) == 1 else jnp.concatenate(p), *parts)
+        return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps), ys, carry
+
     def forward_with_cache(self, params: Params, input_ids: jax.Array,
                            cache: Dict[str, jax.Array],
                            valid: Optional[jax.Array] = None) -> Any:
         """Prefill/decode step: append ``input_ids`` [B, t] at each sequence's
         ``cache['pos']`` and return (logits [B, t, V], updated cache).
 
-        Per-sequence positions make this the continuous-batching step: slots in the
-        same batch may be at different decode depths (ragged batch semantics of
-        ``InferenceEngineV2.put`` engine_v2.py:107, on dense tiles).
+        Per-sequence positions: slots in the same batch may be at different
+        decode depths. The dense-cache forward of ``inference/engine.py``
+        (v1) and ``runtime/hybrid_engine.py``, and the tests' float reference.
         """
-        self._one_pass_only("the cached forward (forward_with_cache)")
-        cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
         B, t = input_ids.shape
         S = cache["k"].shape[2]
         pos = cache["pos"]  # [B]
         positions = pos[:, None] + jnp.arange(t)[None, :]  # [B, t]
-        x = params["embed"]["tokens"].astype(dt)[input_ids]
-        if cfg.learned_pos:
-            x = x + params["embed"]["pos"][positions].astype(dt)
-        freqs = self._freqs
 
-        dense_layers, quant_items = split_quant_leaves(params["layers"])
+        def attend(cseg, li, q, k, v, xs, carry):
+            ck, cv = xs
+            # per-sequence scatter of the new kv at each position
+            bidx = jnp.arange(B)[:, None] + jnp.zeros((1, t), jnp.int32)
+            nk = ck.at[bidx, positions].set(k.astype(ck.dtype))
+            nv = cv.at[bidx, positions].set(v.astype(cv.dtype))
+            sidx = jnp.arange(S)[None, None, :]
+            vmask = sidx <= positions[:, :, None]  # [B,t,S]
+            if cseg.sliding_window is not None:
+                vmask = vmask & (sidx > positions[:, :, None]
+                                 - cseg.sliding_window)
+            return _cached_attention(q, nk, nv, vmask), (nk, nv), carry
 
-        def make_body(cseg):
-            def body(carry, xs):
-                layer_w, ck, cv, li = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                new_kv = {}
-
-                def attn_cache_fn(q, k, v):
-                    # per-sequence scatter of the new kv at each position
-                    bidx = jnp.arange(B)[:, None] + jnp.zeros((1, t), jnp.int32)
-                    nk = ck.at[bidx, positions].set(k.astype(ck.dtype))
-                    nv = cv.at[bidx, positions].set(v.astype(cv.dtype))
-                    new_kv["k"], new_kv["v"] = nk, nv
-                    sidx = jnp.arange(S)[None, None, :]
-                    vmask = sidx <= positions[:, :, None]  # [B,t,S]
-                    if cseg.sliding_window is not None:
-                        vmask = vmask & (sidx > positions[:, :, None]
-                                         - cseg.sliding_window)
-                    return _cached_attention(q, nk, nv, vmask)
-
-                h = _decode_block(carry, wc, cseg, freqs, positions,
-                                  attn_cache_fn, self.moe_fn, moe_valid=valid)
-                return h, (new_kv["k"], new_kv["v"])
-
-            return body
-
-        nk_parts, nv_parts = [], []
-        for lo, hi, cseg in self._window_segments():
-            seg_xs = (jax.tree_util.tree_map(lambda p: p[lo:hi],
-                                             dense_layers),
-                      cache["k"][lo:hi], cache["v"][lo:hi],
-                      jnp.arange(lo, hi, dtype=jnp.int32))
-            x, (nk, nv) = jax.lax.scan(make_body(cseg), x, seg_xs)
-            nk_parts.append(nk)
-            nv_parts.append(nv)
-        nk = nk_parts[0] if len(nk_parts) == 1 else jnp.concatenate(nk_parts)
-        nv = nv_parts[0] if len(nv_parts) == 1 else jnp.concatenate(nv_parts)
-        x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        x, (nk, nv), _ = self._serve_layers(
+            params, input_ids, positions, valid, attend,
+            layer_xs=(cache["k"], cache["v"]))
         logits = self._head_proj(params, x)
-        new_cache = {"k": nk, "v": nv, "pos": pos + t}
-        return logits, new_cache
+        return logits, {"k": nk, "v": nv, "pos": pos + t}
 
     # ---- paged decode path (blocked KV pool) ------------------------------
     def init_paged_kv_cache(self, num_blocks: int, block_size: int = 128,
@@ -1270,84 +1292,6 @@ class TransformerLM:
                                           jnp.float32)}
         return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
-    def forward_with_paged_cache(self, params: Params, input_ids: jax.Array,
-                                 cache: Dict[str, jax.Array],
-                                 block_tables: jax.Array, pos: jax.Array,
-                                 valid: Optional[jax.Array] = None) -> Any:
-        """Continuous-batching step over the blocked KV pool.
-
-        ``input_ids`` [B, t] dense tile (per-slot chunks right-padded);
-        ``block_tables`` int32 [B, nb_max]; ``pos`` int32 [B] tokens already
-        cached per slot; ``valid`` bool [B, t] marks real (non-padding) lanes.
-        Returns (logits [B, t, V], updated cache). Ragged semantics of
-        ``InferenceEngineV2.put`` (engine_v2.py:107) over paged device memory
-        (v2/kernels/ragged_ops/blocked_flash parity).
-        """
-        self._one_pass_only("the paged-cache forward")
-        from deepspeed_tpu.ops.paged_attention import (paged_attention_tp,
-                                                       paged_update)
-
-        if "kv_scale" in cache:
-            raise NotImplementedError(
-                "the dense-tile escape hatch does not support the int8 KV "
-                "pool; use the packed path (packed=True)")
-        cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
-        B, t = input_ids.shape
-        positions = pos[:, None] + jnp.arange(t, dtype=pos.dtype)[None, :]
-        x = params["embed"]["tokens"].astype(dt)[input_ids]
-        if cfg.learned_pos:
-            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-            x = x + params["embed"]["pos"][safe_pos].astype(dt)
-        freqs = self._freqs
-
-        K, hd = cfg.num_kv_heads, cfg.head_dim
-
-        dense_layers, quant_items = split_quant_leaves(params["layers"])
-
-        def make_body(cseg):
-            def body(carry, xs):
-                layer_w, kp, vp, li = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                new_kv = {}
-                # legacy escape-hatch path: unfold the lane-folded pool per
-                # layer (a relayout copy — the packed path avoids this)
-                kp4 = kp.reshape(kp.shape[0], kp.shape[1], K, hd)
-                vp4 = vp.reshape(vp.shape[0], vp.shape[1], K, hd)
-
-                def attn_cache_fn(q, k, v):
-                    nk = paged_update(kp4, k, block_tables, pos, valid)
-                    nv = paged_update(vp4, v, block_tables, pos, valid)
-                    new_kv["k"] = nk.reshape(kp.shape)
-                    new_kv["v"] = nv.reshape(vp.shape)
-                    return paged_attention_tp(q, nk, nv, block_tables, pos,
-                                              window=cseg.sliding_window)
-
-                h = _decode_block(carry, wc, cseg, freqs, positions,
-                                  attn_cache_fn, self.moe_fn, moe_valid=valid)
-                return h, (new_kv["k"], new_kv["v"])
-
-            return body
-
-        nk_parts, nv_parts = [], []
-        for lo, hi, cseg in self._window_segments():
-            seg_xs = (jax.tree_util.tree_map(lambda p: p[lo:hi],
-                                             dense_layers),
-                      cache["k"][lo:hi], cache["v"][lo:hi],
-                      jnp.arange(lo, hi, dtype=jnp.int32))
-            x, (nk, nv) = jax.lax.scan(make_body(cseg), x, seg_xs)
-            nk_parts.append(nk)
-            nv_parts.append(nv)
-        nk = nk_parts[0] if len(nk_parts) == 1 else jnp.concatenate(nk_parts)
-        nv = nv_parts[0] if len(nv_parts) == 1 else jnp.concatenate(nv_parts)
-        x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-        logits = self._head_proj(params, x)
-        return logits, {"k": nk, "v": nv}
-
     MAX_ATOM = 256   # widest prefill atom (VMEM-bounded); engines chunk longer prompts
 
     def forward_with_packed_cache(self, params: Params, token_ids: jax.Array,
@@ -1362,11 +1306,10 @@ class TransformerLM:
                                   decode_kernel: str = "pallas") -> Any:
         """Token-packed continuous-batching step (ragged_wrapper.py parity).
 
-        Unlike :meth:`forward_with_paged_cache`'s dense ``[max_sequences,
-        t_max]`` tile, the batch here is ONE packed row of the scheduled
-        tokens: ``token_ids`` [N] with per-token ``tok_slot``/``tok_pos``
-        metadata, laid out in two regions (the atom layout of reference
-        ``v2/kernels/ragged_ops/atom_builder``):
+        The batch is ONE packed row of the scheduled tokens, not a dense
+        ``[max_sequences, t_max]`` tile: ``token_ids`` [N] with per-token
+        ``tok_slot``/``tok_pos`` metadata, laid out in two regions (the atom
+        layout of reference ``v2/kernels/ragged_ops/atom_builder``):
 
         * rows ``[0, decode_rows)`` — 1-token atoms (decode steps);
         * rows ``[decode_rows, N)`` — ``tile_tq``-wide atoms, each holding
@@ -1383,13 +1326,10 @@ class TransformerLM:
 
         Returns (logits [G, V], updated cache).
         """
-        self._one_pass_only("the packed ragged forward")
         from deepspeed_tpu.ops.paged_attention import (
             packed_kv_append, packed_kv_append_quant,
             ragged_paged_attention_tp)
 
-        cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
         kv_scale = cache.get("kv_scale")
         N = token_ids.shape[0]
         dr = N if decode_rows is None else decode_rows
@@ -1397,12 +1337,6 @@ class TransformerLM:
             raise ValueError(f"prefill region ({N} - {dr} rows) must be a "
                              f"multiple of the {tile_tq}-token atom tile")
         n_tiles = (N - dr) // tile_tq
-        positions = tok_pos[:, None]                            # [N, 1]
-        x = params["embed"]["tokens"].astype(dt)[token_ids][:, None, :]
-        if cfg.learned_pos:
-            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-            x = x + params["embed"]["pos"][safe_pos].astype(dt)
-        freqs = self._freqs
 
         # atom metadata (decode rows: 1-token atoms; tiles: first-row
         # slot/pos + count of real rows)
@@ -1414,62 +1348,36 @@ class TransformerLM:
             a_len_t = valid[dr:].reshape(n_tiles, tile_tq).sum(
                 axis=1, dtype=jnp.int32)
 
-        dense_layers, quant_items = split_quant_leaves(params["layers"])
+        def attend(cseg, li, q, k, v, xs, carry):
+            q2, k2, v2 = q[:, 0], k[:, 0], v[:, 0]              # [N, H|K, d]
+            # the WHOLE stacked pool rides through the scan closure
+            # (ANY-memory operand, layer picked inside the kernel):
+            # per-layer pool slices in the scan xs would materialize
+            # a full pool copy every layer
+            parts = []
+            if dr:
+                parts.append(ragged_paged_attention_tp(
+                    q2[:dr], k2[:dr], v2[:dr], cache["k"], cache["v"],
+                    block_tables, a_slot_d, a_pos_d, a_len_d, tq=1,
+                    window=cseg.sliding_window, layer=li,
+                    kv_scale=kv_scale, kv_bits=self._kv_bits(cache),
+                    kernel=decode_kernel))
+            if n_tiles:
+                parts.append(ragged_paged_attention_tp(
+                    q2[dr:], k2[dr:], v2[dr:], cache["k"], cache["v"],
+                    block_tables, a_slot_t, a_pos_t, a_len_t,
+                    tq=tile_tq, window=cseg.sliding_window, layer=li,
+                    no_past=tiles_no_past, kv_scale=kv_scale,
+                    kv_bits=self._kv_bits(cache),
+                    kernel=decode_kernel))
+            out = (parts[0] if len(parts) == 1
+                   else jnp.concatenate(parts))
+            # [N, 1, H, d]; the K/V rows are appended after the scan
+            return out[:, None], (k2, v2), carry
 
-        def make_body(cseg):
-            def body(carry, xs):
-                layer_w, li = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                new_kv = {}
-
-                def attn_cache_fn(q, k, v):
-                    q2, k2, v2 = q[:, 0], k[:, 0], v[:, 0]      # [N, H|K, d]
-                    new_kv["k"], new_kv["v"] = k2, v2  # appended after scan
-                    # the WHOLE stacked pool rides through the scan closure
-                    # (ANY-memory operand, layer picked inside the kernel):
-                    # per-layer pool slices in the scan xs would materialize
-                    # a full pool copy every layer
-                    parts = []
-                    if dr:
-                        parts.append(ragged_paged_attention_tp(
-                            q2[:dr], k2[:dr], v2[:dr], cache["k"], cache["v"],
-                            block_tables, a_slot_d, a_pos_d, a_len_d, tq=1,
-                            window=cseg.sliding_window, layer=li,
-                            kv_scale=kv_scale, kv_bits=self._kv_bits(cache),
-                            kernel=decode_kernel))
-                    if n_tiles:
-                        parts.append(ragged_paged_attention_tp(
-                            q2[dr:], k2[dr:], v2[dr:], cache["k"], cache["v"],
-                            block_tables, a_slot_t, a_pos_t, a_len_t,
-                            tq=tile_tq, window=cseg.sliding_window, layer=li,
-                            no_past=tiles_no_past, kv_scale=kv_scale,
-                            kv_bits=self._kv_bits(cache),
-                            kernel=decode_kernel))
-                    out = (parts[0] if len(parts) == 1
-                           else jnp.concatenate(parts))
-                    return out[:, None]                         # [N, 1, H, d]
-
-                h = _decode_block(carry, wc, cseg, freqs, positions,
-                                  attn_cache_fn, self.moe_fn,
-                                  moe_valid=valid[:, None])
-                return h, (new_kv["k"], new_kv["v"])
-
-            return body
-
-        kr_parts, vr_parts = [], []
-        for lo, hi, cseg in self._window_segments():
-            seg_xs = (jax.tree_util.tree_map(lambda p: p[lo:hi],
-                                             dense_layers),
-                      jnp.arange(lo, hi, dtype=jnp.int32))
-            x, (kr, vr) = jax.lax.scan(make_body(cseg), x, seg_xs)
-            kr_parts.append(kr)
-            vr_parts.append(vr)
-        krows = kr_parts[0] if len(kr_parts) == 1 else jnp.concatenate(kr_parts)
-        vrows = vr_parts[0] if len(vr_parts) == 1 else jnp.concatenate(vr_parts)
+        x, (krows, vrows), _ = self._serve_layers(
+            params, token_ids[:, None], tok_pos[:, None], valid[:, None],
+            attend)
         if kv_scale is not None:
             kvb = self._kv_bits(cache)
             nk, sc1 = packed_kv_append_quant(cache["k"], kv_scale, krows,
@@ -1485,8 +1393,7 @@ class TransformerLM:
             nv = packed_kv_append(cache["v"], vrows, block_tables, tok_slot,
                                   tok_pos, valid)
             new_cache = {"k": nk, "v": nv}
-        x = _norm(x[:, 0], params["final_norm"], cfg.norm, cfg.norm_eps)
-        logits = self._head_proj(params, x[gather_idx])         # [G, V]
+        logits = self._head_proj(params, x[:, 0][gather_idx])   # [G, V]
         return logits, new_cache
 
     PREFILL_MAX = 4096   # widest whole-prompt prefill (longer prompts chunk)
@@ -1506,62 +1413,23 @@ class TransformerLM:
         stream once per PROMPT instead of once per 256-token chunk — on a
         bandwidth-bound chip that alone is ~T/256 x.
         """
-        self._one_pass_only("the prefill forward")
-        cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
         B, T = input_ids.shape
         positions = jnp.arange(T, dtype=jnp.int32)[None, :]
         valid = positions < lengths[:, None]                    # [B, T]
-        x = params["embed"]["tokens"].astype(dt)[input_ids]
-        if cfg.learned_pos:
-            # T may be bucket-padded past max_seq_len; pad rows are never
-            # gathered or appended, so clamp like the packed path does
-            safe_pos = jnp.minimum(positions[0], cfg.max_seq_len - 1)
-            x = x + params["embed"]["pos"][safe_pos][None].astype(dt)
-        freqs = self._freqs
-        attn_fn = get_attention_impl(cfg.attention_impl)
+        attn_fn = get_attention_impl(self.cfg.attention_impl)
 
-        dense_layers, quant_items = split_quant_leaves(params["layers"])
+        def attend(cseg, li, q, k, v, xs, carry):
+            window = cseg.sliding_window
+            if window is None:
+                out = attn_fn(q, k, v, causal=True)
+            elif _attn_takes_window(attn_fn):
+                out = attn_fn(q, k, v, causal=True, window=window)
+            else:
+                out = xla_attention(q, k, v, causal=True, window=window)
+            return out, (k, v), carry
 
-        def make_body(cseg):
-            def body(carry, xs):
-                layer_w, li = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                kv = {}
-
-                def attn_cache_fn(q, k, v):
-                    kv["k"], kv["v"] = k, v
-                    if cseg.sliding_window is not None:
-                        if not _attn_takes_window(attn_fn):
-                            return xla_attention(
-                                q, k, v, causal=True,
-                                window=cseg.sliding_window)
-                        return attn_fn(q, k, v, causal=True,
-                                       window=cseg.sliding_window)
-                    return attn_fn(q, k, v, causal=True)
-
-                h = _decode_block(carry, wc, cseg, freqs, positions,
-                                  attn_cache_fn, self.moe_fn,
-                                  moe_valid=valid)
-                return h, (kv["k"], kv["v"])
-
-            return body
-
-        kr_parts, vr_parts = [], []
-        for lo, hi, cseg in self._window_segments():
-            seg_xs = (jax.tree_util.tree_map(lambda p: p[lo:hi],
-                                             dense_layers),
-                      jnp.arange(lo, hi, dtype=jnp.int32))
-            x, (kr, vr) = jax.lax.scan(make_body(cseg), x, seg_xs)
-            kr_parts.append(kr)
-            vr_parts.append(vr)
-        kr = kr_parts[0] if len(kr_parts) == 1 else jnp.concatenate(kr_parts)
-        vr = vr_parts[0] if len(vr_parts) == 1 else jnp.concatenate(vr_parts)
-        x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        x, (kr, vr), _ = self._serve_layers(params, input_ids, positions,
+                                            valid, attend)
         last = jnp.clip(lengths - 1, 0, T - 1)
         xg = x[jnp.arange(B), last]                              # [B, D]
         logits = self._head_proj(params, xg)
@@ -1598,11 +1466,9 @@ class TransformerLM:
         frontier (tokens already in the pool); row position = pos_base + t.
         Returns (logits [B, V], updated tail).
         """
-        self._one_pass_only("the fused decode tail")
         from deepspeed_tpu.ops.paged_attention import decode_pool_partials_tp
 
         cfg = self.cfg
-        dt = jnp.dtype(cfg.dtype)
         B = toks.shape[0]
         K = cfg.num_kv_heads
         hd = cfg.head_dim
@@ -1611,88 +1477,57 @@ class TransformerLM:
         if valid is None:
             valid = jnp.ones((B,), bool)
         row_pos = pos_base + t                                   # [B]
-        positions = row_pos[:, None]
-        x = params["embed"]["tokens"].astype(dt)[toks][:, None, :]
-        if cfg.learned_pos:
-            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-            x = x + params["embed"]["pos"][safe_pos].astype(dt)
-        freqs = self._freqs
         scale = 1.0 / math.sqrt(hd)
 
-        dense_layers, quant_items = split_quant_leaves(params["layers"])
+        def attend(cseg, li, q, k, v, xs, carry):
+            tk, tv = carry
+            q2, k2, v2 = q[:, 0], k[:, 0], v[:, 0]    # [B, H|K, d]
+            window = cseg.sliding_window
+            acc, m_k, l_k = decode_pool_partials_tp(
+                q2, cache["k"], cache["v"], li, block_tables, slots,
+                pos_base, window=window, row_pos=row_pos,
+                kv_scale=cache.get("kv_scale"),
+                kv_bits=self._kv_bits(cache),
+                kernel=decode_kernel)
+            # append self into the tail, then attend tail cols <= t
+            tk2 = jax.lax.dynamic_update_slice(
+                tk, k2[None, :, None].astype(tk.dtype),
+                (li, 0, t, 0, 0))
+            tv2 = jax.lax.dynamic_update_slice(
+                tv, v2[None, :, None].astype(tv.dtype),
+                (li, 0, t, 0, 0))
+            tkl = jax.lax.dynamic_index_in_dim(tk2, li, keepdims=False)
+            tvl = jax.lax.dynamic_index_in_dim(tv2, li, keepdims=False)
+            qg = q2.reshape(B, K, rep, hd).astype(jnp.float32)
+            s_t = jnp.einsum("bkrd,bskd->bkrs", qg,
+                             tkl.astype(jnp.float32)) * scale
+            col = jnp.arange(S_tail)[None, None, None, :]
+            keep = col <= t
+            if window is not None:
+                keep = keep & (col > t - window)
+            s_t = jnp.where(keep, s_t, -1e30)
+            m_t = jnp.max(s_t, axis=-1)                # [B, K, rep]
+            p_t = jnp.where(keep, jnp.exp(s_t - m_t[..., None]), 0.0)
+            l_t = jnp.sum(p_t, axis=-1)
+            acc_t = jnp.einsum("bkrs,bskd->bkrd", p_t,
+                               tvl.astype(jnp.float32))
+            H = K * rep
+            m_t = m_t.reshape(B, H)
+            l_t = l_t.reshape(B, H)
+            acc_t = acc_t.reshape(B, H, hd)
+            m2 = jnp.maximum(m_k, m_t)
+            c_k = jnp.exp(m_k - m2)
+            c_t = jnp.exp(m_t - m2)
+            denom = jnp.maximum(l_k * c_k + l_t * c_t, 1e-30)
+            out = ((acc * c_k[..., None] + acc_t * c_t[..., None])
+                   / denom[..., None])
+            out = jnp.where(valid[:, None, None], out, 0)
+            return out.astype(q.dtype)[:, None], None, (tk2, tv2)  # [B,1,H,d]
 
-        def make_body(cseg):
-            def body(carry, xs):
-                h, tk, tv = carry
-                layer_w, li = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                box = {}
-
-                def attn_cache_fn(q, k, v):
-                    q2, k2, v2 = q[:, 0], k[:, 0], v[:, 0]    # [B, H|K, d]
-                    window = cseg.sliding_window
-                    acc, m_k, l_k = decode_pool_partials_tp(
-                        q2, cache["k"], cache["v"], li, block_tables, slots,
-                        pos_base, window=window, row_pos=row_pos,
-                        kv_scale=cache.get("kv_scale"),
-                        kv_bits=self._kv_bits(cache),
-                        kernel=decode_kernel)
-                    # append self into the tail, then attend tail cols <= t
-                    tk2 = jax.lax.dynamic_update_slice(
-                        tk, k2[None, :, None].astype(tk.dtype),
-                        (li, 0, t, 0, 0))
-                    tv2 = jax.lax.dynamic_update_slice(
-                        tv, v2[None, :, None].astype(tv.dtype),
-                        (li, 0, t, 0, 0))
-                    box["tk"], box["tv"] = tk2, tv2
-                    tkl = jax.lax.dynamic_index_in_dim(tk2, li, keepdims=False)
-                    tvl = jax.lax.dynamic_index_in_dim(tv2, li, keepdims=False)
-                    qg = q2.reshape(B, K, rep, hd).astype(jnp.float32)
-                    s_t = jnp.einsum("bkrd,bskd->bkrs", qg,
-                                     tkl.astype(jnp.float32)) * scale
-                    col = jnp.arange(S_tail)[None, None, None, :]
-                    keep = col <= t
-                    if window is not None:
-                        keep = keep & (col > t - window)
-                    s_t = jnp.where(keep, s_t, -1e30)
-                    m_t = jnp.max(s_t, axis=-1)                # [B, K, rep]
-                    p_t = jnp.where(keep, jnp.exp(s_t - m_t[..., None]), 0.0)
-                    l_t = jnp.sum(p_t, axis=-1)
-                    acc_t = jnp.einsum("bkrs,bskd->bkrd", p_t,
-                                       tvl.astype(jnp.float32))
-                    H = K * rep
-                    m_t = m_t.reshape(B, H)
-                    l_t = l_t.reshape(B, H)
-                    acc_t = acc_t.reshape(B, H, hd)
-                    m2 = jnp.maximum(m_k, m_t)
-                    c_k = jnp.exp(m_k - m2)
-                    c_t = jnp.exp(m_t - m2)
-                    denom = jnp.maximum(l_k * c_k + l_t * c_t, 1e-30)
-                    out = ((acc * c_k[..., None] + acc_t * c_t[..., None])
-                           / denom[..., None])
-                    out = jnp.where(valid[:, None, None], out, 0)
-                    return out.astype(q.dtype)[:, None]        # [B, 1, H, d]
-
-                h = _decode_block(h, wc, cseg, freqs, positions,
-                                  attn_cache_fn, self.moe_fn,
-                                  moe_valid=valid[:, None])
-                return (h, box["tk"], box["tv"]), None
-
-            return body
-
-        tk, tv = tail["k"], tail["v"]
-        for lo, hi, cseg in self._window_segments():
-            seg_xs = (jax.tree_util.tree_map(lambda p: p[lo:hi],
-                                             dense_layers),
-                      jnp.arange(lo, hi, dtype=jnp.int32))
-            (x, tk, tv), _ = jax.lax.scan(make_body(cseg), (x, tk, tv),
-                                          seg_xs)
-        x = _norm(x[:, 0], params["final_norm"], cfg.norm, cfg.norm_eps)
-        logits = self._head_proj(params, x)                      # [B, V]
+        x, _, (tk, tv) = self._serve_layers(
+            params, toks[:, None], row_pos[:, None], valid[:, None], attend,
+            carry=(tail["k"], tail["v"]))
+        logits = self._head_proj(params, x[:, 0])                # [B, V]
         return logits, {"k": tk, "v": tv}
 
     # ---- sharding ---------------------------------------------------------

@@ -1,26 +1,27 @@
 """Pallas paged attention over a blocked KV pool (FastGen ragged kernel parity).
 
 Parity target: ``deepspeed/inference/v2/kernels/ragged_ops/`` — ``blocked_flash``
-(flash attention over paged KV blocks) + ``linear_blocked_kv_rotary`` (fused
-rotary+KV-append) and ``v2/ragged/kv_cache.py`` (the block pool). TPU-native
-design:
+(flash attention over paged KV blocks), ``atom_builder`` (the packed row of
+atoms) and ``v2/ragged/kv_cache.py`` (the block pool). TPU-native design:
 
-* the KV cache is a **global pool of fixed-size blocks** ``[num_blocks+1,
-  block_size, K, d]`` shared by all sequences — HBM footprint is proportional
-  to allocated blocks, not ``max_sequences × max_seq_len``. Physical block 0..
-  num_blocks-1 are allocator-owned; the LAST block is a scratch block that
-  padded lanes write into.
+* the KV cache is a **global pool of fixed-size blocks**, stacked over layers
+  and lane-folded: ``[L, num_blocks+1, block_size, K*d]``, shared by all
+  sequences — HBM footprint is proportional to allocated blocks, not
+  ``max_sequences × max_seq_len``. Physical blocks 0..num_blocks-1 are
+  allocator-owned; the LAST block is a scratch block that padded lanes write
+  into.
 * ``block_tables[b, i]`` maps logical block *i* of slot *b* to its physical
-  block. The Pallas kernel reads the table through **scalar prefetch**
-  (``pltpu.PrefetchScalarGridSpec``): the BlockSpec index map picks the
-  physical KV block to DMA for each grid step — the TPU analog of
+  block. The kernels read the table through **scalar prefetch** and stream
+  the blocks of a flat WORK LIST of (atom, block-group) items with manual
+  async copies (see "Ragged atom kernels" below): the TPU analog of
   blocked_flash's block-table indirection.
-* one grid step attends one query tile against one logical KV block with the
-  online-softmax recurrence (same math as ``ops/flash_attention.py``); blocks
-  entirely above a slot's visible range are predicated out.
-* KV append (`paged_update`) is an XLA scatter computed from the same tables —
-  fused by XLA into the surrounding step, covering linear_blocked_kv_rotary's
-  append half (rotary itself is applied by the model before the append).
+* a step's own tokens attend from VMEM, so every layer's KV append hoists out
+  of the layer scan into ONE in-place XLA scatter computed from the same
+  tables (:func:`packed_kv_append`, :func:`packed_kv_append_quant`) — rotary
+  is applied by the model before the append.
+* every kernel has an XLA twin with the same signature and numerics
+  (:func:`xla_ragged_attention`, :func:`xla_decode_partials`):
+  :func:`attention_kernel_path` names which one a geometry takes.
 """
 
 from __future__ import annotations
@@ -86,200 +87,6 @@ def _check_kernel(kernel: str) -> bool:
     if kernel not in ("pallas", "xla"):
         raise ValueError(f"kernel must be 'pallas' or 'xla', got {kernel!r}")
     return kernel == "xla"
-
-
-# ---------------------------------------------------------------------------
-# block-table math (shared by kernel wrapper and scatter)
-# ---------------------------------------------------------------------------
-
-def physical_positions(block_tables: jax.Array, positions: jax.Array,
-                       block_size: int) -> Tuple[jax.Array, jax.Array]:
-    """Map global token positions [B, t] → (physical block [B, t], offset [B, t]).
-
-    Out-of-range lanes are the caller's concern: `paged_update` redirects them
-    to the scratch block via its ``valid`` mask."""
-    logical = positions // block_size
-    logical = jnp.clip(logical, 0, block_tables.shape[1] - 1)
-    phys = jnp.take_along_axis(block_tables, logical, axis=1)
-    return phys, positions % block_size
-
-
-def paged_update(pool: jax.Array, new: jax.Array, block_tables: jax.Array,
-                 pos: jax.Array, valid: Optional[jax.Array] = None) -> jax.Array:
-    """Scatter new KV ``[B, t, K, d]`` into the pool at each slot's positions.
-
-    ``pool``: [num_blocks+1, block_size, K, d] (last block = scratch);
-    ``pos``: [B] tokens already cached per slot; invalid lanes (``valid`` False)
-    land in the scratch block.
-    """
-    B, t = new.shape[:2]
-    bs = pool.shape[1]
-    scratch = pool.shape[0] - 1
-    gpos = pos[:, None] + jnp.arange(t, dtype=pos.dtype)[None, :]      # [B, t]
-    phys, off = physical_positions(block_tables, gpos, bs)
-    if valid is not None:
-        phys = jnp.where(valid, phys, scratch)
-    return pool.at[phys, off].set(new.astype(pool.dtype))
-
-
-# ---------------------------------------------------------------------------
-# Pallas kernel
-# ---------------------------------------------------------------------------
-
-def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, block_size: int,
-                  t: int, window):
-    b, h, ib = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nb = pl.num_programs(2)
-
-    @pl.when(ib == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = pos_ref[b]
-    # a block is live if any of its cache positions is visible to the newest
-    # query row (global position pos + t - 1) — and, with a sliding window,
-    # not entirely older than the oldest query row's window
-    live = ib * block_size <= pos + t - 1
-    if window is not None:
-        live = jnp.logical_and(
-            live, ib * block_size + block_size - 1 >= pos - (window - 1))
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]                      # [t, d]
-        k = k_ref[0]                         # [block_size, d]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # [t, bs]
-        row_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col_pos = ib * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        keep = col_pos <= row_pos
-        if window is not None:  # mistral/qwen2 sliding window
-            keep = keep & (col_pos > row_pos - window)
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(ib == nb - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-
-
-def _paged_pallas(q, k_pool, v_pool, block_tables, pos, *, window,
-                  interpret: bool):
-    """q: [B, H, t, d]; pools: [nb+1, bs, K, d]; tables: [B, nb_max]; pos: [B]."""
-    B, H, t, d = q.shape
-    bs, K = k_pool.shape[1], k_pool.shape[2]
-    rep = H // K
-    nb_max = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(d)
-
-    # pools viewed per-kv-head for clean [bs, d] blocks
-    kp = k_pool.transpose(0, 2, 1, 3).reshape(-1, bs, d)  # [(nb+1)*K, bs, d]
-    vp = v_pool.transpose(0, 2, 1, 3).reshape(-1, bs, d)
-
-    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs, t=t,
-                               window=window)
-
-    def kv_index(b, h, ib, bt, ps):
-        # clamp dead grid steps (beyond the causal frontier, or older than
-        # the sliding window) onto the nearest live logical block: Pallas
-        # elides the re-fetch of an unchanged block, so out-of-range blocks
-        # cost no DMA — decode bandwidth scales with min(pos, window), not
-        # with nb_max
-        lo = 0
-        if window is not None:
-            lo = jnp.maximum((ps[b] - (window - 1)) // bs, 0)
-        hi = jnp.clip((ps[b] + t - 1) // bs, 0, nb_max - 1)
-        ibc = jnp.clip(ib, lo, hi)
-        return (bt[b, ibc] * K + h // rep, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, H, nb_max),
-        in_specs=[
-            pl.BlockSpec((1, 1, t, d), lambda b, h, ib, bt, ps: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, d), kv_index),
-            pl.BlockSpec((1, bs, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, 1, t, d), lambda b, h, ib, bt, ps: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((t, 128), jnp.float32),
-            pltpu.VMEM((t, 128), jnp.float32),
-            pltpu.VMEM((t, d), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, t, d), q.dtype),
-        interpret=interpret,
-    )(block_tables, pos, q, kp, vp)
-
-
-def xla_paged_attention(q, k_pool, v_pool, block_tables, pos, window=None):
-    """Reference implementation: gather each slot's blocks into a dense cache,
-    then masked attention. Used for numeric parity tests and as a fallback."""
-    B, t, H, d = q.shape
-    bs, K = k_pool.shape[1], k_pool.shape[2]
-    S = block_tables.shape[1] * bs
-    k_dense = k_pool[block_tables].reshape(B, S, K, d)
-    v_dense = v_pool[block_tables].reshape(B, S, K, d)
-    if K != H:
-        rep = H // K
-        k_dense = jnp.repeat(k_dense, rep, axis=2)
-        v_dense = jnp.repeat(v_dense, rep, axis=2)
-    s = jnp.einsum("bthd,bshd->bhts", q, k_dense,
-                   preferred_element_type=jnp.float32) / math.sqrt(d)
-    row = pos[:, None, None, None] + jnp.arange(t)[None, None, :, None]
-    col = jnp.arange(S)[None, None, None, :]
-    keep = col <= row
-    if window is not None:
-        keep = keep & (col > row - window)
-    s = jnp.where(keep, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhts,bshd->bthd", p, v_dense)
-
-
-def paged_attention_tp(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                       block_tables: jax.Array, pos: jax.Array,
-                       axis: str = "tp", window: Optional[int] = None
-                       ) -> jax.Array:
-    """Tensor-parallel paged attention: heads are embarrassingly parallel, so
-    the Pallas kernel runs per-shard under ``shard_map`` with q sharded on H
-    and the pools sharded on K (the v2-step TP sharding the reference applies
-    via module injection, engine_v2.py:93). Falls back to the plain kernel
-    when no mesh with a >1 ``axis`` is active."""
-    from jax.sharding import PartitionSpec as P
-
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or mesh.empty or axis not in mesh.axis_names \
-            or mesh.shape[axis] <= 1:
-        return paged_attention(q, k_pool, v_pool, block_tables, pos,
-                               window=window)
-    tp = mesh.shape[axis]
-    H, K = q.shape[2], k_pool.shape[2]
-    assert H % tp == 0 and K % tp == 0, (
-        f"tp={tp} must divide num_heads={H} and num_kv_heads={K}")
-    return jax.shard_map(
-        functools.partial(paged_attention, window=window),
-        in_specs=(P(None, None, axis, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None)),
-        out_specs=P(None, None, axis, None),
-        # pallas_call's out_shape carries no varying-mesh-axes metadata
-        check_vma=False,
-    )(q, k_pool, v_pool, block_tables, pos)
 
 
 # ---------------------------------------------------------------------------
@@ -1394,26 +1201,3 @@ def xla_ragged_attention(q, k_self, v_self, k_pool, v_pool, block_tables,
     out = jnp.where((jnp.arange(tq) < atom_len[:, None])[:, :, None, None],
                     out, 0)
     return out.reshape(N, H, d)
-
-
-def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
-                    block_tables: jax.Array, pos: jax.Array,
-                    window: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    """Attention of a dense query tile over each slot's paged KV.
-
-    ``q``: [B, t, H, d] (model layout; t = tile width, rows past a slot's real
-    chunk are don't-care); ``k_pool``/``v_pool``: [num_blocks+1, block_size, K,
-    d]; ``block_tables``: int32 [B, nb_max]; ``pos``: int32 [B] — tokens
-    already cached per slot BEFORE this tile (the tile's own KV must already be
-    appended via :func:`paged_update`). Returns [B, t, H, d].
-    """
-    if window is not None and window < 1:
-        raise ValueError(f"window must be >= 1, got {window}")
-    if interpret is None:
-        interpret = not _on_tpu()
-    qt = q.transpose(0, 2, 1, 3)  # [B, H, t, d]
-    out = _paged_pallas(qt, k_pool, v_pool,
-                        block_tables.astype(jnp.int32), pos.astype(jnp.int32),
-                        window=window, interpret=interpret)
-    return out.transpose(0, 2, 1, 3)

@@ -86,7 +86,7 @@ class ContinuousBatcher:
                  clock: Callable[[], float] = time.monotonic,
                  manager: Optional[RequestManager] = None,
                  registry=None):
-        """``engine`` is an :class:`InferenceEngineV2` (packed+paged);
+        """``engine`` is an :class:`InferenceEngineV2`;
         ``config`` a :class:`~deepspeed_tpu.config.config.ServingConfig`
         (None = defaults); ``monitor`` an optional
         :class:`~deepspeed_tpu.monitor.MonitorMaster` for the ``serving/*``
@@ -94,9 +94,6 @@ class ContinuousBatcher:
         :class:`~deepspeed_tpu.observability.MetricsRegistry` (None = the
         process-wide default that ``/metrics`` exposes). ``clock`` is
         injectable so deadline tests are deterministic."""
-        if not getattr(engine, "packed", False):
-            raise ValueError("ContinuousBatcher needs the packed paged "
-                             "engine (InferenceEngineV2(packed=True))")
         from deepspeed_tpu.config.config import ServingConfig
 
         self.engine = engine

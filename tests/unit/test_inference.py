@@ -118,26 +118,32 @@ def test_continuous_batching_matches_sequential(tiny_lm):
     assert eng.state.allocator.free_blocks == eng.state.allocator.num_blocks
 
 
-def test_paged_matches_dense_engine(tiny_lm):
-    """The paged blocked-KV engine must reproduce the dense-cache engine's
-    logits across interleaved prefill/decode scheduling."""
+@pytest.mark.parametrize("seed", [
+    pytest.param(4, id="paged_matches_dense"),
+    pytest.param(7, id="packed_matches_tile")])
+def test_engine_matches_dense_cache_forward(tiny_lm, seed):
+    """The engine (token-packed step over the paged pool) must reproduce the
+    dense-cache forward's next-token logits across interleaved prefill/decode
+    scheduling."""
     model, params = tiny_lm
-    rng = np.random.default_rng(4)
+    rng = np.random.default_rng(seed)
     p1 = rng.integers(0, 256, 7)
     p2 = rng.integers(0, 256, 5)
-    e_paged = InferenceEngineV2(model, params=params, max_sequences=4,
-                                max_seq_len=32, block_size=8, paged=True)
-    e_dense = InferenceEngineV2(model, params=params, max_sequences=4,
-                                max_seq_len=32, block_size=8, paged=False)
-    for eng in (e_paged, e_dense):
-        r1 = eng.put([1], [p1])
-        r2 = eng.put([2, 1], [p2, np.array([7])])
-        r3 = eng.put([1, 2], [np.array([3]), np.array([11])])
-        eng._r = (r1, r2, r3)
-    for a, b in zip(e_paged._r, e_dense._r):
-        for uid in a:
-            np.testing.assert_allclose(np.asarray(a[uid], np.float32),
-                                       np.asarray(b[uid], np.float32), atol=3e-2)
+    eng = InferenceEngineV2(model, params=params, max_sequences=4,
+                            max_seq_len=32, block_size=8)
+    r1 = eng.put([1], [p1])
+    r2 = eng.put([2, 1], [p2, np.array([7])])
+    r3 = eng.put([1, 2], [np.array([3]), np.array([11])])
+
+    def dense(seq):
+        lg, _ = model.forward_with_cache(
+            params, np.asarray(seq, np.int32)[None], model.init_kv_cache(1, 32))
+        return np.asarray(lg[0, -1], np.float32)
+
+    for got, seq in ((r1[1], p1), (r2[2], p2), (r2[1], [*p1, 7]),
+                     (r3[1], [*p1, 7, 3]), (r3[2], [*p2, 11])):
+        np.testing.assert_allclose(np.asarray(got, np.float32), dense(seq),
+                                   atol=3e-2)
 
 
 def test_paged_pool_smaller_than_dense(tiny_lm):
@@ -195,35 +201,33 @@ def test_paged_engine_tp2(tiny_lm, eight_devices):
                                np.asarray(rb[1], np.float32), atol=3e-2)
 
 
-def test_paged_attention_window_parity():
-    """Sliding-window paged attention (mistral/qwen2 serving): kernel output
-    matches the dense-gather reference with the same window mask."""
-    import jax
-    import jax.numpy as jnp
+@pytest.mark.parametrize("tq", [1, 4])
+def test_paged_attention_window_parity(tq):
+    """Sliding-window paged attention (mistral/qwen2 serving): the work-list
+    kernels' output (decode atoms, ``tq=1``; chunk atoms, ``tq=4``) matches
+    the dense-gather reference with the same window mask."""
+    from deepspeed_tpu.ops.paged_attention import (ragged_paged_attention,
+                                                   xla_ragged_attention)
 
-    from deepspeed_tpu.ops.paged_attention import (paged_attention,
-                                                   xla_paged_attention)
-
-    rng = jax.random.key(0)
-    B, t, H, K, d, bs, nb = 2, 4, 4, 2, 16, 8, 6
-    kq, kk, kv, kt = jax.random.split(rng, 4)
-    q = jax.random.normal(kq, (B, t, H, d), jnp.float32)
-    k_pool = jax.random.normal(kk, (nb + 1, bs, K, d), jnp.float32)
-    v_pool = jax.random.normal(kv, (nb + 1, bs, K, d), jnp.float32)
-    # slot 0 deep (pos 20), slot 1 shallow (pos 3); disjoint physical blocks
-    tables = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
-    pos = jnp.asarray([20, 3], jnp.int32)
-    for window in (1, 6, 17, 1000):
-        out = paged_attention(q, k_pool, v_pool, tables, pos, window=window,
-                              interpret=True)
-        ref = xla_paged_attention(q, k_pool, v_pool, tables, pos,
-                                  window=window)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, err_msg=f"window={window}")
+    rng = np.random.default_rng(0)
+    kp, vp, bt = TestRaggedKernels._pools(rng)
+    A, H, K, d = 3, 4, 2, 16
+    q = jnp_f(rng.normal(size=(A * tq, H, d)))
+    ks = jnp_f(rng.normal(size=(A * tq, K, d)))
+    vs = jnp_f(rng.normal(size=(A * tq, K, d)))
+    # slot 0 deep (pos 20), slot 1 shallow (pos 3), slot 2 fresh
+    a_slot = jnp_np(np.array([0, 1, 2], np.int32))
+    a_pos0 = jnp_np(np.array([20, 3, 0], np.int32))
+    a_len = jnp_np(np.array([tq, 1, tq], np.int32))
     # window=None unchanged vs plain causal
-    out = paged_attention(q, k_pool, v_pool, tables, pos, interpret=True)
-    ref = xla_paged_attention(q, k_pool, v_pool, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    for window in (1, 6, 17, 1000, None):
+        out = ragged_paged_attention(q, ks, vs, kp, vp, bt, a_slot, a_pos0,
+                                     a_len, tq=tq, window=window)
+        ref = xla_ragged_attention(q, ks, vs, kp, vp, bt, a_slot, a_pos0,
+                                   a_len, tq, window=window)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5,
+                                   err_msg=f"window={window}")
 
 
 def test_windowed_family_serves_through_paged_engine():
@@ -236,7 +240,7 @@ def test_windowed_family_serves_through_paged_engine():
     rng = np.random.default_rng(5)
     prompt = rng.integers(0, 256, 16)
     eng = InferenceEngineV2(model, params=params, max_sequences=2,
-                            max_seq_len=32, block_size=8, paged=True)
+                            max_seq_len=32, block_size=8)
     r = eng.put([1], [prompt])
     # reference: full forward with the window applied
     full = np.asarray(model.logits(params, prompt[None].astype(np.int32)),
@@ -255,30 +259,6 @@ def test_windowed_family_serves_through_paged_engine():
                                    full[0, -1], atol=3e-2)
 
 
-def test_packed_matches_tile_engine(tiny_lm):
-    """The token-packed ragged step must reproduce the dense-tile paged step
-    across interleaved prefill/decode scheduling (round-2 gap #2)."""
-    model, params = tiny_lm
-    rng = np.random.default_rng(7)
-    p1 = rng.integers(0, 256, 7)
-    p2 = rng.integers(0, 256, 5)
-    e_packed = InferenceEngineV2(model, params=params, max_sequences=4,
-                                 max_seq_len=32, block_size=8, packed=True)
-    e_tile = InferenceEngineV2(model, params=params, max_sequences=4,
-                               max_seq_len=32, block_size=8, packed=False)
-    assert e_packed.packed and not e_tile.packed
-    for eng in (e_packed, e_tile):
-        r1 = eng.put([1], [p1])
-        r2 = eng.put([2, 1], [p2, np.array([7])])
-        r3 = eng.put([1, 2], [np.array([3]), np.array([11])])
-        eng._r = (r1, r2, r3)
-    for a, b in zip(e_packed._r, e_tile._r):
-        for uid in a:
-            np.testing.assert_allclose(np.asarray(a[uid], np.float32),
-                                       np.asarray(b[uid], np.float32),
-                                       atol=3e-2)
-
-
 def test_packed_flops_scale_with_tokens(tiny_lm):
     """A mixed prefill+decode step's compiled FLOPs must follow total
     scheduled tokens, not max_sequences × t_max: one 64-token prefill + 7
@@ -294,15 +274,10 @@ def test_packed_flops_scale_with_tokens(tiny_lm):
     cache = model.init_paged_kv_cache(Bs * nb_max, bsz)
     bt = np.arange(Bs * nb_max, dtype=np.int32).reshape(Bs, nb_max)
 
-    # dense tile: [8, 64] rows
-    tile = np.zeros((Bs, t_max), np.int32)
-    pos = np.zeros((Bs,), np.int32)
-    valid_t = np.zeros((Bs, t_max), bool)
-    valid_t[0] = True
-    valid_t[1:, 0] = True
-    tile_cost = profile_fn(model.forward_with_paged_cache, params,
-                           jnp.asarray(tile), cache, jnp.asarray(bt),
-                           jnp.asarray(pos), jnp.asarray(valid_t))
+    # dense tile: [8, 64] rows through the dense-cache forward
+    tile_cost = profile_fn(model.forward_with_cache, params,
+                           jnp.zeros((Bs, t_max), jnp.int32),
+                           model.init_kv_cache(Bs, t_max))
 
     # packed: 64 + 7 = 71 tokens → 128 bucket
     npad = 128
@@ -777,6 +752,101 @@ class TestWeightQuantServing:
         first = eng.put([1], [prompt])[1]
         toks = eng.decode_batch([1], [int(np.argmax(first))], steps=4)[1]
         assert toks.shape == (4,)
+
+
+# ---- one layer loop, every program -----------------------------------------
+
+@pytest.fixture(scope="module")
+def loop_models():
+    """Two models that make the shared serving layer loop do more than one
+    plain scan, each with its engine, the parameter tree the serving
+    forwards take, the tree of the reference and the tolerance."""
+    from deepspeed_tpu.models.transformer import QuantizedWeight
+    from deepspeed_tpu.ops.quant_matmul import dequantize_matmul_weight
+
+    def dense(w):
+        """A (layer-stacked) QuantizedWeight expanded back to dense."""
+        if not isinstance(w, QuantizedWeight):
+            return w
+        if w.packed.ndim == 2:
+            return dequantize_matmul_weight(w.packed, w.scales, w.bits, w.din)
+        return jax.numpy.stack([
+            dequantize_matmul_weight(p, sc, w.bits, w.din)
+            for p, sc in zip(w.packed, w.scales)])
+
+    out = {}
+    # two window segments (layer 0 full attention, layers 1-2 windowed):
+    # the loop concatenates what the segments' scans leave behind
+    model = TransformerLM(get_preset(
+        "tiny", dtype="float32", num_layers=3, sliding_window=6,
+        window_start_layer=1))
+    params = model.init(jax.random.key(0))
+    eng = InferenceEngineV2(model, params=params, max_sequences=2,
+                            max_seq_len=32, block_size=8)
+    out["mixed_window"] = (model, eng, params, params, dict(atol=2e-3))
+    # quantized layer leaves: the loop threads QuantLayerRef into each layer
+    model, params = TestWeightQuantServing._model()
+    eng = InferenceEngineV2(model, params=params, max_sequences=2,
+                            max_seq_len=32, block_size=8,
+                            weight_dtype="int8")
+    ref = jax.tree_util.tree_map(
+        dense, eng.params, is_leaf=lambda w: isinstance(w, QuantizedWeight))
+    out["int8_leaves"] = (model, eng, eng.params, ref,
+                          dict(atol=0.2, rtol=0.2))
+    return out
+
+
+def _serve(path, model, eng, params, prompt):
+    """Drive ``prompt`` down one serving program; returns [(tokens of the
+    sequence so far, next-token logits)]."""
+    n = len(prompt)
+    if path == "forward_with_cache":
+        cache = model.init_kv_cache(1, 32)
+        got = []
+        for lo, hi in [(0, 7)] + [(i, i + 1) for i in range(7, n)]:
+            lg, cache = model.forward_with_cache(
+                params, prompt[None, lo:hi].astype(np.int32), cache)
+            got.append((prompt[:hi], lg[0, -1]))
+        return got
+    try:
+        if path == "forward_prefill":           # a fresh whole prompt
+            return [(prompt, eng.put([1], [prompt])[1])]
+        if path == "forward_with_packed_cache":  # a decode row, a tile, rows
+            return [(prompt[:hi], eng.put([1], [prompt[lo:hi]])[1])
+                    for lo, hi in [(0, 1), (1, 8)]
+                    + [(i, i + 1) for i in range(8, n)]]
+        assert path == "forward_decode_tail"
+        first = int(np.argmax(np.asarray(eng.put([1], [prompt])[1])))
+        toks = eng.decode_batch([1], [first], steps=3)[1]
+        # what the tail left in the pool, read back by one more step
+        seq = np.concatenate([prompt, [first], toks])
+        return [(seq, eng.put([1], [toks[-1:]])[1])]
+    finally:
+        eng.flush([1])
+
+
+@pytest.mark.parametrize("path", ["forward_with_cache", "forward_prefill",
+                                  "forward_with_packed_cache",
+                                  "forward_decode_tail"])
+@pytest.mark.parametrize("kind", ["mixed_window", "int8_leaves"])
+def test_every_serving_program_matches_the_whole_sequence_forward(
+        loop_models, kind, path):
+    """The four serving forwards share one layer loop: down each of them the
+    next-token logits agree with ``model.logits`` on the whole sequence."""
+    model, eng, params, ref, tol = loop_models[kind]
+    prompt = np.random.default_rng(12).integers(0, 256, 12)
+    for seq, got in _serve(path, model, eng, params, prompt):
+        want = model.logits(ref, np.asarray(seq, np.int32)[None])[0, -1]
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **tol,
+                                   err_msg=f"{len(seq)} tokens")
+
+
+def test_the_engine_has_one_cache_mode(tiny_lm):
+    model, params = tiny_lm
+    for mode in ("paged", "packed"):
+        with pytest.raises(TypeError, match=mode):
+            InferenceEngineV2(model, params=params, **{mode: False})
 
 
 def test_init_inference_checkpoint_surfaces(tmp_path, eight_devices):

@@ -319,12 +319,21 @@ def _looped_model():
 
 
 def _serving_call(name):
+    """The cache constructors refuse before they read an argument; the four
+    forwards refuse in the layer loop they share, once they have read their
+    arguments' shapes (``params`` is the loop's to read: None)."""
     def call():
-        import inspect
-
-        fn = getattr(_looped_model(), name)
-        fn(*[None for p in inspect.signature(fn).parameters.values()
-             if p.default is p.empty and p.kind is p.POSITIONAL_OR_KEYWORD])
+        ids = jnp.zeros((2, 2), jnp.int32)
+        kv = {"k": jnp.zeros((L, 2, 2, 2, 2)), "pos": ids[0]}
+        kv["v"] = kv["k"]
+        args = {"init_kv_cache": (2,), "init_paged_kv_cache": (2,),
+                "forward_with_cache": (None, ids, kv),
+                "forward_with_packed_cache": (None, ids[0], kv, ids, ids[0],
+                                              ids[0], ids[0] > 0, ids[0]),
+                "forward_prefill": (None, ids, ids[0]),
+                "forward_decode_tail": (None, ids[0], kv, kv, 0, ids, ids[0],
+                                        ids[0])}[name]
+        getattr(_looped_model(), name)(*args)
     return call
 
 
@@ -373,8 +382,8 @@ def _cost_model():
 REFUSALS = {
     **{name: _serving_call(name) for name in (
         "init_kv_cache", "init_paged_kv_cache", "forward_with_cache",
-        "forward_with_paged_cache", "forward_with_packed_cache",
-        "forward_prefill", "forward_decode_tail")},
+        "forward_with_packed_cache", "forward_prefill",
+        "forward_decode_tail")},
     "InferenceEngineV2": _engine_v2, "InferenceEngine": _engine_v1,
     "PipelineModule": _pipeline, "tiled_loss": _tiled_loss,
     "tiled_loss_no_gate": _tiled_loss_no_gate,

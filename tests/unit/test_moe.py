@@ -315,18 +315,17 @@ def test_capacity_moe_decode_ignores_idle_lanes(eight_devices):
     lg, _ = model.forward_with_cache(params, p[None].astype(np.int32), cache)
     ref = np.asarray(lg[0, -1], np.float32)
 
-    for packed in (True, False):
-        eng = InferenceEngineV2(model, params=params, max_sequences=8,
-                                max_seq_len=32, block_size=8, packed=packed)
-        # burn slots 0-3 then free 0-2: uid 5 lands in slot 4 with four
-        # idle-lane slots ahead of it in row order
-        for uid in (1, 2, 3, 4):
-            eng.put([uid], [rng.integers(0, 256, 4)])
-        eng.flush([1, 2, 3])
-        r = eng.put([5], [p])
-        assert eng.state.sequences[5].slot == 4
-        np.testing.assert_allclose(np.asarray(r[5], np.float32), ref,
-                                   atol=3e-2)
+    eng = InferenceEngineV2(model, params=params, max_sequences=8,
+                            max_seq_len=32, block_size=8)
+    # burn slots 0-3 then free 0-2: uid 5 lands in slot 4 with four
+    # idle-lane slots ahead of it in row order
+    for uid in (1, 2, 3, 4):
+        eng.put([uid], [rng.integers(0, 256, 4)])
+    eng.flush([1, 2, 3])
+    r = eng.put([5], [p])
+    assert eng.state.sequences[5].slot == 4
+    np.testing.assert_allclose(np.asarray(r[5], np.float32), ref,
+                               atol=3e-2)
 
 
 # ---------------------------------------------------------------------------

@@ -265,41 +265,6 @@ def test_attention_registry_has_flash():
     assert "flash" in _ATTENTION_IMPLS
 
 
-def test_paged_attention_parity():
-    """Paged kernel vs dense-gather reference (pattern: reference
-    tests/unit/inference/v2/kernels numeric parity)."""
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.ops.paged_attention import (paged_attention,
-                                                   paged_update,
-                                                   xla_paged_attention)
-    rng = np.random.default_rng(0)
-    B, t, H, K, d, bs, nb, nb_max = 3, 4, 8, 4, 64, 16, 24, 4
-    kp = jnp.asarray(rng.normal(size=(nb + 1, bs, K, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(nb + 1, bs, K, d)), jnp.float32)
-    bt = jnp.asarray(rng.permutation(nb)[:B * nb_max].reshape(B, nb_max), jnp.int32)
-    pos = jnp.asarray([0, 11, 37], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(B, t, H, d)), jnp.float32)
-    o1 = paged_attention(q, kp, vp, bt, pos)
-    o2 = xla_paged_attention(q, kp, vp, bt, pos)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
-
-    # update scatter places each valid token at its block/offset
-    new = jnp.asarray(rng.normal(size=(B, t, K, d)), jnp.float32)
-    valid = jnp.asarray(rng.integers(0, 2, (B, t)), bool)
-    kp2 = paged_update(kp, new, bt, pos, valid)
-    gpos = np.asarray(pos)[:, None] + np.arange(t)[None]
-    for b in range(B):
-        for j in range(t):
-            pb = int(bt[b, gpos[b, j] // bs]); off = gpos[b, j] % bs
-            if valid[b, j]:
-                np.testing.assert_allclose(np.asarray(kp2[pb, off]),
-                                           np.asarray(new[b, j]))
-            else:
-                np.testing.assert_allclose(np.asarray(kp2[pb, off]),
-                                           np.asarray(kp[pb, off]))
-
-
 class TestQuantizedMatmul:
     """Fused dequant-GEMM (reference cutlass_ops/mixed_gemm W4A16/W8A16).
 

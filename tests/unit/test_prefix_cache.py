@@ -473,10 +473,6 @@ def test_config_blocks_reach_engine(f32_lm):
     with pytest.raises(ValueError, match="max_draft"):
         DeepSpeedTpuConfig(train_batch_size=8, inference={
             "speculative": {"enabled": True, "max_draft": 0}})
-    # both features need the packed paged engine
-    with pytest.raises(ValueError, match="packed"):
-        InferenceEngineV2(model, params=params, max_sequences=2,
-                          max_seq_len=64, prefix_cache=True, paged=False)
 
 
 # ---------------------------------------------------------------------------
