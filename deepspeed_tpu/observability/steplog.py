@@ -163,6 +163,11 @@ class StepProgram:
         #: took, ``{"fused": n, "split": m}`` (``ops/flash_attention.py``);
         #: None until the program's first call has traced it
         self.flash_bwd_lowerings: Optional[Dict[str, int]] = None
+        #: the tiles one head of the flash forward takes by arm and whether
+        #: its log-sum-exp leaves as rows, ``{"masked", "unmasked", "dead",
+        #: "rows"}``, of the newest forward the program's trace lowered; None
+        #: where it lowered none, and until the first call
+        self.flash_fwd_tiles: Optional[Dict[str, Any]] = None
         self.built_at = time.perf_counter()
         self._fn = weakref.ref(fn)
         self._mesh = mesh

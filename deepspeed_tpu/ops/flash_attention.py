@@ -9,9 +9,12 @@ is a first-class Pallas TPU kernel:
   dimension; running max/denominator live in VMEM scratch across KV steps (online
   softmax — the same math as FPDT's ``_fpdt_general_attn_forward`` chunk loop, but on
   one chip's MXU instead of a CUDA stream pipeline);
-* causal block skipping: fully-masked KV blocks are predicated out with ``pl.when``;
+* causal block skipping: fully-masked KV blocks are predicated out with ``pl.when``
+  and fetch nothing (their index maps name the block already resident); the mask is
+  built only on tiles the diagonal or the window's edge crosses;
 * GQA folded into the BlockSpec index maps (KV head = Q head // group);
-* fp32 accumulation, bf16 inputs; logsumexp saved for the backward;
+* fp32 accumulation, bf16 inputs; logsumexp saved for the backward, as ``[B, H, 1, T]``
+  rows where a ``[1, block_q]`` block is legal;
 * backward = one fused kernel (dq, dk, dv from one S, one exp and one dP per tile
   pair, the head's dq resident in VMEM) using the saved logsumexp, the standard
   flash-attention-2 recurrence; where a head's dq does not fit VMEM (long
@@ -30,6 +33,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -47,24 +51,65 @@ NEG_INF = -1e30
 
 def _block_live(causal, window, q_start, k_start, block_q, block_k):
     """Per-tile liveness predicate for ``pl.when`` (q_start/k_start are traced
-    program-id products): dead when entirely above the causal diagonal or
-    entirely older than the sliding window. Callers fold any static
-    rel_offset (a global q-position shift for chunk-pair masking) into
-    q_start before calling — same convention as _bwd_mask."""
+    program-id products, Python ints or numpy arrays): dead when entirely above
+    the causal diagonal or entirely older than the sliding window. Callers
+    fold any static rel_offset (a global q-position shift for chunk-pair
+    masking) into q_start before calling — same convention as _bwd_mask."""
     live = True
     if causal:
         live = k_start <= q_start + block_q - 1
     if window is not None:
-        in_win = k_start + block_k - 1 >= q_start - (window - 1)
-        live = in_win if live is True else jnp.logical_and(live, in_win)
+        live = live & (k_start + block_k - 1 >= q_start - (window - 1))
     return live
+
+
+def _block_crossed(causal, window, q_start, k_start, block_q, block_k):
+    """Does a boundary cross the tile, so that some of its pairs are masked:
+    one above the diagonal, or one older than the window. Same arguments as
+    ``_block_live``; False (static) where nothing masks."""
+    crossed = False
+    if causal:
+        crossed = k_start + block_k - 1 > q_start
+    if window is not None:
+        crossed = crossed | (k_start < q_start + block_q - 1 - (window - 1))
+    return crossed
+
+
+# A forward tile is worked on in sub-blocks of at most this edge: their
+# matmuls and exponentials are independent of each other's, so the scheduler
+# overlaps one's MXU time with another's vector work, and on a tile a boundary
+# crosses the dead ones are skipped one by one.
+_FWD_SUB = 512
+_LOG2E = 1.4426950408889634
+
+
+def _lane_copies(x, n: int):
+    """``[r, n]`` from an ``[r, lanes]`` value whose lanes all hold the row's
+    number."""
+    if n <= x.shape[1]:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 scale: float, causal: bool, window, block_q: int,
-                block_k: int, rel_offset: int = 0):
+                block_k: int, rel_offset: int = 0, rows: bool = False):
+    """Online softmax over the kv-tiles of one q-tile, each tile doing only
+    what its position needs.
+
+    The running maximum ``m`` (of the raw scores: ``scale`` is positive) and
+    denominator ``l`` live in ``[block_q, lanes]`` scratch: every lane of
+    ``m`` holds the row's maximum, so ``s - m`` needs no lane broadcast, and
+    each lane of ``l`` holds the sum of the exponentials of its own columns,
+    added up across lanes once, at the last kv-tile. ``scale`` and log2(e)
+    are one constant in front of ``exp2``; the log-sum-exp comes back to
+    natural units once a row, at the end."""
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    d = acc_scr.shape[1]
+    hq, w = _pick_block(block_q, _FWD_SUB), _pick_block(block_k, _FWD_SUB)
+    lanes = m_scr.shape[1]
+    c = scale * _LOG2E
 
     @pl.when(ik == 0)
     def _init():
@@ -75,75 +120,178 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     q_start = iq * block_q + rel_offset
     k_start = ik * block_k
     live = _block_live(causal, window, q_start, k_start, block_q, block_k)
+    crossed = _block_crossed(causal, window, q_start, k_start, block_q, block_k)
 
-    @pl.when(live)
-    def _compute():
+    def sub_block(h, j, m_run, l_run, acc, masked):
+        """One [hq, w] sub-block folded into the running statistics of its
+        rows (values in, values out)."""
         # MXU wants low-precision inputs with fp32 accumulation: keep q/k/v in
         # their storage dtype (bf16) and set preferred_element_type — an fp32
         # cast before the dot would run the MXU at a fraction of its bf16 rate.
-        q = q_ref[0, 0]                      # [bq, d]
-        k = k_ref[0, 0]                      # [bk, d]
-        v = v_ref[0, 0]                      # [bk, d]
+        q = q_ref[0, 0, h * hq:(h + 1) * hq, :]
+        k = k_ref[0, 0, j * w:(j + 1) * w, :]
+        v = v_ref[0, 0, j * w:(j + 1) * w, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal or window is not None:
+                                preferred_element_type=jnp.float32)  # [hq, w]
+        if masked:
             # rows+q_start >= cols+k_start  ⟺  rows-cols >= k_start-q_start:
             # the iota difference is block-invariant, only the scalar threshold
             # moves, which keeps the per-block VPU mask work to compare+select
+            off = (k_start + j * w) - (q_start + h * hq)
             diff = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                     - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            keep = (diff >= k_start - q_start) if causal else True
+            keep = (diff >= off) if causal else True
             if window is not None:  # mistral/qwen2 sliding window
-                keep = keep & (diff <= window - 1 + k_start - q_start)
+                keep = keep & (diff <= window - 1 + off)
             s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_scr[:, :1]                 # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                # [bq, bk] fp32
-        corr = jnp.exp(m_prev - m_new)        # [bq, 1]
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+        # lane-wide pieces: maximum and sum run across them elementwise, and
+        # only the maximum is reduced across lanes here
+        pieces = [s[:, i:i + lanes] for i in range(0, w, lanes)]
+        m_cur = functools.reduce(jnp.maximum, pieces)
+        m_new = jnp.maximum(m_run, jnp.max(m_cur, axis=1, keepdims=True))
+        corr = jnp.exp2((m_run - m_new) * c)                    # [hq, lanes]
+        ps = [jnp.exp2((piece - m_new) * c) for piece in pieces]
+        l_run = l_run * corr + functools.reduce(jnp.add, ps)
+        p = ps[0] if len(ps) == 1 else jnp.concatenate(ps, axis=1)
+        acc = acc * _lane_copies(corr, d) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        return m_new, l_run, acc
+
+    def state(h):
+        r = slice(h * hq, (h + 1) * hq)
+        return m_scr[r, :], l_scr[r, :], acc_scr[r, :]
+
+    def keep_state(h, new):
+        r = slice(h * hq, (h + 1) * hq)
+        m_scr[r, :], l_scr[r, :], acc_scr[r, :] = new
+
+    @pl.when(live if crossed is False
+             else live & jnp.logical_not(crossed))
+    def _inside():
+        # no boundary in the tile: no mask, every sub-block, one straight line
+        for h in range(block_q // hq):
+            run = state(h)
+            for j in range(block_k // w):
+                run = sub_block(h, j, *run, masked=False)
+            keep_state(h, run)
+
+    if crossed is not False:
+        @pl.when(live & crossed)
+        def _crossed():
+            for h in range(block_q // hq):
+                for j in range(block_k // w):
+                    @pl.when(_block_live(causal, window, q_start + h * hq,
+                                         k_start + j * w, hq, w))
+                    def _live_sub_block(h=h, j=j):
+                        keep_state(h, sub_block(h, j, *state(h), masked=True))
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:, :1] + jnp.log(denom)
+        denom = jnp.maximum(jnp.sum(l_scr[:], axis=1, keepdims=True), 1e-30)
+        o_ref[0, 0] = (acc_scr[:] * (1.0 / denom)).astype(o_ref.dtype)
+        lse = m_scr[:] * scale + jnp.log(denom)               # [bq, lanes]
+        if rows:
+            lse_ref[0, 0] = _lane_copies(lse, 128).T[:1]          # [1, bq]
+        else:
+            lse_ref[0, 0] = lse[:, :1]
+
+
+# The tiles one head's forward grid takes by arm (``_fwd_kernel``: a boundary
+# crosses it; none does; dead) and whether its log-sum-exp leaves as rows, of
+# the newest trace of ``_fwd_pallas``; ``traces`` counts them, so a reader can
+# tell whether a program traced one (the step-program table does)
+_FWD_TILES = {"traces": 0, "tiles": None}
+
+
+def fwd_tiles() -> Tuple[int, Optional[dict]]:
+    tiles = _FWD_TILES["tiles"]
+    return _FWD_TILES["traces"], None if tiles is None else dict(tiles)
+
+
+def _fwd_tile_arms(T, S, block_q, block_k, causal, window, rel_offset) -> dict:
+    q_start = np.arange(T // block_q)[:, None] * block_q + rel_offset
+    k_start = np.arange(S // block_k)[None, :] * block_k
+    every = np.ones((T // block_q, S // block_k), bool)
+    live = every & _block_live(causal, window, q_start, k_start, block_q,
+                               block_k)
+    crossed = every & _block_crossed(causal, window, q_start, k_start,
+                                     block_q, block_k)
+    return {"masked": int((live & crossed).sum()),
+            "unmasked": int((live & ~crossed).sum()),
+            "dead": int((~live).sum())}
+
+
+def _rows_legal(T: int, block_q: int) -> bool:
+    """Is a ``[1, block_q]`` row of a ``[..., 1, T]`` array a legal block
+    (whole lanes, or the whole sequence)? Where it is, the log-sum-exp leaves
+    the forward as ``[B, H, 1, T]`` rows and the fused backward may read them;
+    else (a ``block_q`` of 8 for a T such as 3000) it leaves as the
+    ``[B, H, T, 1]`` column and the split kernels run."""
+    return block_q == T or block_q % 128 == 0
 
 
 def _fwd_pallas(q, k, v, *, scale, causal, window, block_q, block_k,
                 interpret, rel_offset=0):
+    """``(out [B, H, T, d], lse)``; ``lse`` is ``f32[B, H, 1, T]`` rows where
+    ``_rows_legal`` says so, else the ``f32[B, H, T, 1]`` column."""
     B, H, T, d = q.shape
     S, K = k.shape[2], k.shape[1]
     rep = H // K
     nq, nk = T // block_q, S // block_k
     grid = (B, H, nq, nk)
+    rows = _rows_legal(T, block_q)
+    _FWD_TILES["traces"] += 1
+    _FWD_TILES["tiles"] = dict(_fwd_tile_arms(
+        T, S, block_q, block_k, causal, window, rel_offset), rows=rows)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                window=window, block_q=block_q, block_k=block_k,
-                               rel_offset=rel_offset)
+                               rel_offset=rel_offset, rows=rows)
+
+    def kv_tile(iq, ik):
+        """The kv-tile a grid step brings in: its own where the pair is live
+        (``_block_live``), else the nearest one live for this q-tile (the last
+        under the diagonal, the first inside the window), so a dead step names
+        the block already resident and moves nothing."""
+        lo, hi = 0, nk - 1
+        if causal:
+            hi = (iq * block_q + rel_offset + block_q - 1) // block_k
+        if window is not None:
+            lo = (iq * block_q + rel_offset - (window - 1)) // block_k
+        return jnp.clip(ik, jnp.clip(lo, 0, nk - 1), jnp.clip(hi, 0, nk - 1))
+
+    kv_in = pl.BlockSpec((1, 1, block_k, d),
+                         lambda b, h, iq, ik: (b, h // rep, kv_tile(iq, ik), 0))
+    if rows:
+        lse_out = pl.BlockSpec((1, 1, 1, block_q),
+                               lambda b, h, iq, ik: (b, h, 0, iq))
+        lse_shape = (B, H, 1, T)
+    else:
+        lse_out = pl.BlockSpec((1, 1, block_q, 1),
+                               lambda b, h, iq, ik: (b, h, iq, 0))
+        lse_shape = (B, H, T, 1)
+    w = _pick_block(block_k, _FWD_SUB)
+    lanes = 128 if w % 128 == 0 else w
+    # One call, two results, (bf16 out, f32 lse): the benchmark's
+    # flash_fwd_roofline finds the forward as the attn Mosaic call with those
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, iq, ik: (b, h // rep, ik, 0)),
+            kv_in, kv_in,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
+            lse_out,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, lanes), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
@@ -354,14 +502,14 @@ def _bwd_takes_fused(T: int, d: int, block_q: int, block_k: int,
     """One algorithm with a size limit: the fused kernel where the head's dq
     and the tiles fit the budget and a ``[1, block_q]`` row is a legal block
     (whole lanes, or the whole sequence); else the split kernels."""
-    rows_ok = block_q == T or block_q % 128 == 0
-    return rows_ok and _fused_bwd_vmem_bytes(
+    return _rows_legal(T, block_q) and _fused_bwd_vmem_bytes(
         T, d, block_q, block_k, itemsize) <= _FUSED_VMEM_BUDGET
 
 
 def _bwd_fused_call(q, k, v, do, lse, delta, *, interpret, **kernel_kw):
-    """``kernel_kw``: the kernel's static arguments (scale, causal, window,
-    block_q, block_k, rel_offset)."""
+    """``lse`` and ``delta`` as ``f32[B, H, 1, T]`` rows (the forward's own
+    where ``_rows_legal``). ``kernel_kw``: the kernel's static arguments (scale,
+    causal, window, block_q, block_k, rel_offset)."""
     B, H, T, d = q.shape
     S, K = k.shape[2], k.shape[1]
     rep = H // K
@@ -415,11 +563,12 @@ def _bwd_fused_call(q, k, v, do, lse, delta, *, interpret, **kernel_kw):
                                  "arbitrary"),
             vmem_limit_bytes=_FUSED_VMEM_BUDGET),
         interpret=interpret,
-    )(q, k, v, do, lse.reshape(B, H, 1, T), delta.reshape(B, H, 1, T))
+    )(q, k, v, do, lse, delta)
     return dq.reshape(B, H, T, d), dkv[0], dkv[1]
 
 
 def _bwd_split_call(q, k, v, do, lse, delta, *, interpret, **kernel_kw):
+    """``lse`` and ``delta`` as ``f32[B, H, T, 1]`` columns."""
     B, H, T, d = q.shape
     S, K = k.shape[2], k.shape[1]
     rep = H // K
@@ -473,25 +622,31 @@ def _bwd_split_call(q, k, v, do, lse, delta, *, interpret, **kernel_kw):
 
 def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, window, block_q,
                 block_k, interpret, dlse=None, rel_offset=0):
+    """``lse`` as ``_fwd_pallas`` returned it (rows or column); ``dlse`` in
+    the public ``[B, H, T, 1]``."""
     B, H, T, d = q.shape
     S, K = k.shape[2], k.shape[1]
     rep = H // K
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
-                    keepdims=True)  # [B,H,T,1]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)  # [B,H,T]
     if dlse is not None:
         # lse cotangent (the lse-returning variant): d lse/d s = p, so the
         # extra term p*dlse folds into the kernels' ds = p*(dp - delta) as
         # delta' = delta - dlse — no kernel change
-        delta = delta - dlse.astype(jnp.float32)
+        delta = delta - dlse.astype(jnp.float32).reshape(B, H, T)
 
     took = ("fused" if _bwd_takes_fused(T, d, block_q, block_k,
                                         q.dtype.itemsize) else "split")
     _BWD_LOWERINGS[took] += 1
     call = _bwd_fused_call if took == "fused" else _bwd_split_call
+    # the fused kernel reads rows, the split pair columns; the forward's lse
+    # is rows wherever the fused kernel runs (``_rows_legal``), so this reshape
+    # is none there
+    stat = (B, H, 1, T) if took == "fused" else (B, H, T, 1)
     dq, dk_h, dv_h = call(
-        q, k, v, do, lse, delta, interpret=interpret, scale=scale,
-        causal=causal, window=window, block_q=block_q, block_k=block_k,
-        rel_offset=rel_offset)
+        q, k, v, do, lse.reshape(stat), delta.reshape(stat),
+        interpret=interpret, scale=scale, causal=causal, window=window,
+        block_q=block_q, block_k=block_k, rel_offset=rel_offset)
 
     if rep > 1:  # GQA: sum over the query-head group
         dk = dk_h.reshape(B, K, rep, S, d).sum(axis=2).astype(k.dtype)
@@ -551,7 +706,10 @@ def _flash_lse_fwd(q, k, v, causal, window, block_q, block_k, interpret,
     out, lse = _fwd_pallas(q, k, v, scale=scale, causal=causal,
                            window=window, block_q=block_q, block_k=block_k,
                            interpret=interpret, rel_offset=rel_offset)
-    return (out, lse), (q, k, v, out, lse)
+    # the residual keeps the kernel's layout (rows for the fused backward);
+    # the public result its documented column
+    B, H, T, _ = q.shape
+    return (out, lse.reshape(B, H, T, 1)), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, window, block_q, block_k, interpret, rel_offset,
@@ -640,7 +798,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = 
     vt = v.transpose(0, 2, 1, 3)
     out = _flash(qt, kt, vt, causal, window, bq, bk, interpret)
     out = out.transpose(0, 2, 1, 3)
-    # Named so remat policies can pin the kernel's output: attention is
-    # VPU-bound (~5-10% MFU ceiling at trainable seq lens on v5e) and must
-    # never be recomputed in the backward pass.
+    # Named so a remat policy can keep the kernel's output (``attn_saveable``,
+    # ``dots_and_attn_saveable``). Keeping it does not spare the backward a
+    # second run of the forward kernel today: the log-sum-exp is a residual of
+    # the custom_vjp that no policy can name, so under either policy the
+    # forward runs again for it (measured: PERF.md section 4; what would fix
+    # it: ROADMAP L2 (a)).
     return checkpoint_name(out, "flash_attn_out")

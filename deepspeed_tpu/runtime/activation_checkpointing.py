@@ -39,12 +39,16 @@ def resolve_policy(policy: str):
         return None
     cp = jax.checkpoint_policies
     if policy == "attn_saveable":
-        # save only the attention output: cheapest memory profile that still
-        # avoids recomputing the VPU-bound attention in the backward pass
+        # save only the attention output. Meant to spare the backward a
+        # second run of the attention forward; it does not today: the flash
+        # kernel's log-sum-exp is a residual this policy cannot name, so the
+        # forward kernel runs again for it all the same (PERF.md section 4;
+        # ROADMAP L2 (a) says what would fix it)
         return cp.save_only_these_names(ATTN_CHECKPOINT_NAME)
     if policy == "dots_and_attn_saveable":
-        # dots_saveable alone recomputes the (opaque-to-XLA) pallas attention
-        # call in the backward; pin its named output as well
+        # dots_saveable alone keeps nothing of the (opaque-to-XLA) pallas
+        # attention call; keep its named output as well (the forward kernel
+        # still runs again for the log-sum-exp, as above)
         return cp.save_from_both_policies(
             cp.dots_saveable, cp.save_only_these_names(ATTN_CHECKPOINT_NAME))
     if policy == "offload_attn":

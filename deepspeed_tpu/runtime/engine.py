@@ -38,7 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.config import DeepSpeedTpuConfig
 from deepspeed_tpu.models.spec import num_params
 from deepspeed_tpu.observability import steplog
-from deepspeed_tpu.ops.flash_attention import bwd_lowerings
+from deepspeed_tpu.ops.flash_attention import bwd_lowerings, fwd_tiles
 from deepspeed_tpu.parallel import Topology, build_mesh
 from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.runtime.dataloader import DeepSpeedTpuDataLoader
@@ -980,7 +980,7 @@ class DeepSpeedTpuEngine:
         row = self._uncaptured.pop(key, None)
         if row is not None:
             row.capture(args)
-            before = bwd_lowerings()
+            before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
         with self._ebus.span("train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
             out = self._fused_step_cache[key](*args)
@@ -988,6 +988,8 @@ class DeepSpeedTpuEngine:
         if row is not None:     # the program's first call: it was traced now
             row.flash_bwd_lowerings = {
                 kind: n - before[kind] for kind, n in bwd_lowerings().items()}
+            traces, tiles = fwd_tiles()
+            row.flash_fwd_tiles = tiles if traces > fwd_before else None
         return out
 
     def _fused_train_step(self, batch):

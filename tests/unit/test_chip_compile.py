@@ -42,19 +42,35 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _flash(d, grad):
+def _flash(d, grad, T=2048, heads=H, kv_heads=K, window=None):
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, causal=True, interpret=False)
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False)
 
     def loss(q, k, v):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    q = ((1, 2048, H, d), jnp.bfloat16)
-    kv = ((1, 2048, K, d), jnp.bfloat16)
+    q = ((1, T, heads, d), jnp.bfloat16)
+    kv = ((1, T, kv_heads, d), jnp.bfloat16)
     return fn, [q, kv, kv]
+
+
+def _flash_lse_pair(window):
+    """FPDT's chunk pair: a q-chunk against a longer run of keys at a static
+    rel_offset, gradients through both results (out and the log-sum-exp)."""
+    from deepspeed_tpu.ops.flash_attention import flash_attention_lse
+
+    def loss(q, k, v):
+        out, lse = flash_attention_lse(q, k, v, causal=True, window=window,
+                                       rel_offset=2048, interpret=False)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    q = ((1, 2048, H, 128), jnp.bfloat16)
+    kv = ((1, 4096, K, 128), jnp.bfloat16)
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, kv, kv]
 
 
 def _paged(tq, kv_int8, rows=64):
@@ -117,6 +133,26 @@ CASES = {
     "flash-grad-d64": (_flash, dict(d=64, grad=True), True),
     "flash-fwd-d128": (_flash, dict(d=128, grad=False), True),
     "flash-grad-d128": (_flash, dict(d=128, grad=True), True),
+    # the forward alone at the two training cells' shapes (BENCHMARK.json),
+    # at T 16,384, and at a T whose block_q (8 of 3000) is no legal
+    # [1, block_q] row, so that the log-sum-exp leaves as the column
+    "flash-fwd-mistral-cell": (
+        _flash, dict(d=128, grad=False, T=4096, heads=32, kv_heads=8,
+                     window=4096), True),
+    "flash-fwd-ouro-cell": (
+        _flash, dict(d=128, grad=False, T=4096, heads=16, kv_heads=16), True),
+    "flash-fwd-16k": (
+        _flash, dict(d=128, grad=False, T=16384, heads=4, kv_heads=4), True),
+    "flash-fwd-d64-window": (
+        _flash, dict(d=64, grad=False, T=4096, heads=8, kv_heads=2,
+                     window=1000), True),
+    "flash-fwd-column-T3000": (
+        _flash, dict(d=128, grad=False, T=3000, heads=4, kv_heads=4), True),
+    "flash-grad-column-T3000": (
+        _flash, dict(d=128, grad=True, T=3000, heads=4, kv_heads=4), True),
+    "flash-lse-chunk-pair-grad": (_flash_lse_pair, dict(window=None), True),
+    "flash-lse-chunk-pair-window-grad": (_flash_lse_pair, dict(window=3000),
+                                         True),
     "paged-decode-bf16": (_paged, dict(tq=1, kv_int8=False), True),
     "paged-decode-128rows": (_paged, dict(tq=1, kv_int8=False, rows=128),
                              True),
@@ -199,6 +235,16 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     fused = re.findall(rf"= \({five}, {five}\) custom-call\(.*"
                        r"tpu_custom_call", text)
     assert len(fused) == int(took == "fused"), name
+    # the forward is one Mosaic call with the results (bf16 out, f32 lse),
+    # what metrics/flash_fwd_roofline.json looks for, and its log-sum-exp
+    # leaves as the [B, H, 1, T] rows the fused kernel reads
+    fwd = re.findall(r"= \(bf16\[[\d,]*\]\{[^}]*\}, (f32\[[\d,]*\])\{[^}]*\}\) "
+                     r"custom-call\(.*tpu_custom_call", text)
+    assert fwd == [f"f32[1,{heads},1,{T}]"], (name, fwd)
+    tiles = fa.fwd_tiles()[1]
+    assert tiles["rows"]
+    if T == 4096:       # the cells: 4 x 4 tiles of 1024 a head, window inert
+        assert tiles == {"masked": 4, "unmasked": 6, "dead": 6, "rows": True}
 
 
 def test_kernel_path_rules_match_what_compiled():

@@ -156,14 +156,15 @@ def test_fused_backward(name):
     kernel_kw = dict(scale=d ** -0.5, causal=causal, window=window,
                      block_q=bq, block_k=bk, rel_offset=rel)
     out, lse = fa._fwd_pallas(qt, kt, vt, interpret=True, **kernel_kw)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1,
-                    keepdims=True)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
     if dlse:
         delta = delta - jax.random.normal(keys[4], delta.shape, jnp.float32)
-    fused = fa._bwd_fused_call(qt, kt, vt, do, lse, delta, interpret=True,
-                               **kernel_kw)
-    split = fa._bwd_split_call(qt, kt, vt, do, lse, delta, interpret=True,
-                               **kernel_kw)
+    # the fused kernel reads its statistics as rows, the split pair as columns
+    row, col = (1, H, 1, T), (1, H, T, 1)
+    fused = fa._bwd_fused_call(qt, kt, vt, do, lse.reshape(row),
+                               delta.reshape(row), interpret=True, **kernel_kw)
+    split = fa._bwd_split_call(qt, kt, vt, do, lse.reshape(col),
+                               delta.reshape(col), interpret=True, **kernel_kw)
     for a, b in zip(fused, split):
         assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
@@ -203,6 +204,119 @@ def test_fused_backward(name):
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+
+
+# The flash forward, tile by tile (interpreter). Small tiles, and sub-blocks
+# smaller still ("sub" stands in for the kernel's 512), so that tiles a
+# boundary crosses, tiles none does and dead tiles all occur, and on a crossed
+# tile dead sub-blocks: "tiles" pins the counter where the pattern is the
+# training cells' (T 4096 in 1024-wide tiles is these 4 x 4), elsewhere the
+# dense mask says what to expect. T != S puts query row t at position
+# t + S - T (rel_offset); "rows" is whether [1, block_q] is a legal block.
+FWD_TILE_CASES = {
+    "causal-mha-the-cells-pattern": dict(
+        H=2, K=2, tiles=dict(masked=4, unmasked=6, dead=6, rows=False)),
+    "causal-rows-128-wide": dict(
+        H=2, K=1, T=512, block=128, sub=64,
+        tiles=dict(masked=4, unmasked=6, dead=6, rows=True)),
+    "full-gqa2": dict(H=4, K=2, causal=False,
+                      tiles=dict(masked=0, unmasked=16, dead=0, rows=False)),
+    "window-ends-inside-a-tile-gqa4": dict(H=4, K=1, window=24),
+    "window-on-a-tile-edge-mha": dict(H=2, K=2, window=32),
+    "window-beyond-S-gqa2": dict(H=4, K=2, window=1000),
+    "window-of-one": dict(H=2, K=2, window=1),
+    "rect-rel-offset-gqa2": dict(H=4, K=2, T=32, S=64),
+    "rect-window-gqa4": dict(H=4, K=1, T=32, S=64, window=40),
+    "tall-tiles": dict(H=2, K=2, block_q=32, block_k=16),
+    "wide-tiles": dict(H=2, K=1, block_q=16, block_k=32),
+    "d64-rows-whole-sub-block": dict(H=2, K=1, T=256, block=128, d=64,
+                                     sub=None),
+    "one-tile-whole-T": dict(H=2, K=2, block=1024, sub=None,
+                             tiles=dict(masked=1, unmasked=0, dead=0,
+                                        rows=True)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FWD_TILE_CASES))
+def test_flash_forward_tiles(name, monkeypatch):
+    case = dict(FWD_TILE_CASES[name])
+    H, K, d = case["H"], case["K"], case.get("d", 16)
+    S = case.get("S", case.get("T", 64))
+    T = case.get("T", S)
+    causal, window = case.get("causal", True), case.get("window")
+    bq = min(case.get("block_q", case.get("block", 16)), T)
+    bk = min(case.get("block_k", case.get("block", 16)), S)
+    if case.get("sub", 8) is not None:
+        monkeypatch.setattr(fa, "_FWD_SUB", case.get("sub", 8))
+    rel = S - T
+    q, k, v = _qkv(T=T, S=S, H=H, K=K, d=d)
+    kw = dict(causal=causal, window=window, block_q=bq, block_k=bk,
+              interpret=True)
+
+    # (1) out and lse: against XLA's attention and the log-sum-exp of the
+    # masked scores
+    out, lse = flash_attention_lse(q, k, v, rel_offset=rel, **kw)
+    ref = xla_attention(q, k, v, causal=causal, window=window)
+    ref_lse = _ref_lse(q, k, causal, window)
+    assert lse.shape == (1, H, T, 1)            # the documented column
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               atol=2e-5)
+    if T == S:
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, **kw)), np.asarray(ref),
+            atol=2e-5)
+
+    # (2) the counter: tiles by arm as the dense mask has them, and the
+    # layout the kernel's own lse left in
+    gap = (np.arange(T)[:, None] + rel) - np.arange(S)[None, :]
+    keep = np.ones((T, S), bool)
+    if causal:
+        keep &= gap >= 0
+    if window is not None:
+        keep &= gap < window
+    per_tile = keep.reshape(T // bq, bq, S // bk, bk)
+    some, every = per_tile.any((1, 3)), per_tile.all((1, 3))
+    rows = bq == T or bq % 128 == 0
+    want = dict(masked=int((some & ~every).sum()), unmasked=int(every.sum()),
+                dead=int((~some).sum()), rows=rows)
+    assert want == case.get("tiles", want)
+    n0, _ = fa.fwd_tiles()
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    _, raw = fa._fwd_pallas(qt, kt, vt, scale=d ** -0.5, causal=causal,
+                            window=window, block_q=bq, block_k=bk,
+                            interpret=True, rel_offset=rel)
+    n1, said = fa.fwd_tiles()
+    assert n1 == n0 + 1 and said == want
+    assert raw.shape == ((1, H, 1, T) if rows else (1, H, T, 1))
+    np.testing.assert_array_equal(np.asarray(raw).reshape(-1),
+                                  np.asarray(lse).reshape(-1))
+
+    # (3) gradients as XLA's autodiff has them: through flash_attention, and
+    # through flash_attention_lse with a cotangent into the log-sum-exp
+    w_out = jax.random.normal(jax.random.key(5), q.shape, jnp.float32)
+    w_lse = jax.random.normal(jax.random.key(6), (1, H, T, 1), jnp.float32)
+
+    def f_lse(q, k, v):
+        o, l = flash_attention_lse(q, k, v, rel_offset=rel, **kw)
+        return (o * w_out).sum() + (l * w_lse).sum()
+
+    def f_ref(q, k, v, with_lse=True):
+        o = xla_attention(q, k, v, causal=causal, window=window)
+        l = _ref_lse(q, k, causal, window) if with_lse else 0.0
+        return (o * w_out).sum() + (l * w_lse).sum()
+
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.grad(f_lse, argnums=(0, 1, 2))(q, k, v), g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
+    if T == S:
+        g = jax.grad(lambda q, k, v: (flash_attention(q, k, v, **kw)
+                                      * w_out).sum(), argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.grad(lambda q, k, v: f_ref(q, k, v, with_lse=False),
+                         argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g, g_ref):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-4)
 
 
 class TestRMSNorm:

@@ -132,6 +132,7 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
     assert calls == [] and rows[0]._compiled is None    # nothing computed
     # XLA attention on the CPU: the program holds no flash backward
     assert rows[0].flash_bwd_lowerings == {"fused": 0, "split": 0}
+    assert rows[0].flash_fwd_tiles is None
     assert log.n_steps == n0 + 3
     last = log.steps()[-3:]
     assert last[:, 0].tolist() == [0, 1, 2]
@@ -151,8 +152,9 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
 
 def test_program_row_counts_the_flash_backwards_it_lowered():
     """The row of a program whose attention is the flash kernel says which
-    backward kernel its trace took (counted in ``_bwd_pallas``), once the
-    first call has traced it; later calls leave it alone."""
+    backward kernel its trace took (counted in ``_bwd_pallas``) and which arms
+    its forward's tiles take (``_fwd_pallas``), once the first call has traced
+    it; later calls leave it alone."""
     before = len(steplog.programs())
     eng = _engine(attention_impl="flash_pallas")
     batch = {"input_ids": np.zeros((2, 32), np.int32)}
@@ -160,8 +162,12 @@ def test_program_row_counts_the_flash_backwards_it_lowered():
     row, = steplog.programs()[before:]
     said = dict(row.flash_bwd_lowerings)
     assert said["fused"] >= 1 and said["split"] == 0
+    # and the tiles one head of its forward takes by arm: 32 tokens are one
+    # tile, which the diagonal crosses; a [1, 32] row is the whole sequence
+    tiles = dict(row.flash_fwd_tiles)
+    assert tiles == {"masked": 1, "unmasked": 0, "dead": 0, "rows": True}
     eng.fused_train_step(batch)
-    assert row.flash_bwd_lowerings == said
+    assert row.flash_bwd_lowerings == said and row.flash_fwd_tiles == tiles
 
 
 def test_gc_hook_records_generation_one_and_up():
