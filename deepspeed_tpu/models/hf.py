@@ -53,7 +53,9 @@ OURO_TENSORS = {
     "model.early_exit_gate.weight": ("exit_gate", "w"),     # [1, D] there
     "model.early_exit_gate.bias": ("exit_gate", "b"),       # [1] there
 }
-_SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt")
+_SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum")
+#: HF ``layer_types`` / ``rope_parameters`` names -> attention kinds here
+_HF_KINDS = {"sliding_attention": "window", "full_attention": "full"}
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
            "gelu_pytorch_tanh": "gelu", "gelu_fast": "gelu", "relu": "relu"}
@@ -112,8 +114,9 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
                 if not get("use_sliding_window", False) \
                         or mwl >= kw["num_layers"]:
                     win = None
-                elif mwl > 0:
-                    kw["window_start_layer"] = mwl  # mixed-window checkpoint
+                elif mwl > 0:       # mixed-window checkpoint
+                    kw["attn_pattern"] = (("full",) * mwl + ("window",)
+                                          * (kw["num_layers"] - mwl))
             kw["sliding_window"] = win
         if model_type == "qwen2":
             kw["qkv_bias"] = True
@@ -126,6 +129,40 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             # the model (TransformerLM._one_pass_only).
             kw.update(num_passes=int(get("total_ut_steps", 1)),
                       sandwich_norm=True, exit_loss_beta=0.1)
+    elif model_type == "mellum":
+        # window and full attention layers in turn (``layer_types``), a rope
+        # of its own for each kind (``rope_parameters``), every FFN a layer
+        # of ``num_experts`` experts of width ``moe_intermediate_size``
+        # routed by a renormalised softmax top k. The config side only: no
+        # public description of the checkpoint's tensor names was at hand,
+        # so load_hf_checkpoint refuses the type rather than guess them.
+        L = get("num_hidden_layers")
+        kinds = tuple(_HF_KINDS[k] for k in get("layer_types")[:L])
+        if set((get("mlp_layer_types") or ["sparse"])[:L]) != {"sparse"}:
+            raise ValueError("mellum with dense FFN layers "
+                             "(mlp_layer_types) is not mapped")
+        if not get("norm_topk_prob", True) or get("attention_bias", False):
+            raise ValueError("mellum without norm_topk_prob, or with "
+                             "attention biases, is not mapped")
+        ropes = {_HF_KINDS[k]: dict(v)
+                 for k, v in (get("rope_parameters") or {}).items()}
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            sliding_window=get("sliding_window") if "window" in kinds
+            else None,
+            attn_pattern=kinds, rope_by_kind=ropes or None,
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            moe_dispatch="grouped", moe_aux_loss_coef=0.001,
+        )
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "
@@ -505,6 +542,12 @@ def load_hf_checkpoint(path: str, cfg: Optional[TransformerConfig] = None,
     """
     with open(os.path.join(path, "config.json")) as f:
         hf_cfg = json.load(f)
+    if hf_cfg.get("model_type") == "mellum":
+        raise NotImplementedError(
+            "model_type 'mellum': the config maps onto TransformerConfig "
+            "(config_from_hf), but no description of the checkpoint's tensor "
+            "names was at hand when this was written, and none is guessed: "
+            "importing its weights is not supported")
     if cfg is None:
         cfg = config_from_hf(hf_cfg, param_dtype="float32", dtype=dtype)
     sd = _load_state_dict(path, np.dtype(cfg.param_dtype))

@@ -17,10 +17,11 @@ models its AutoTP/kernel-injection paths consume. TPU-first design:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Callable, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,8 @@ class TransformerConfig:
     use_rope: Optional[bool] = None
     learned_pos: Optional[bool] = None
     tie_embeddings: bool = True
+    # standard deviation the token embedding is drawn with (init)
+    embed_init_std: float = 0.02
     rope_theta: float = 10000.0
     # --- family knobs (reference: inference v2 model_implementations/ for
     # llama/mistral/qwen2/phi3/falcon/opt; each maps to one switch here) ---
@@ -58,13 +61,19 @@ class TransformerConfig:
     parallel_shared_norm: bool = False  # falcon-7b: one ln feeds both branches
     rope_pct: float = 1.0         # gpt-neox partial rotary (rotary_pct)
     sliding_window: Optional[int] = None  # mistral/qwen2 windowed attention
-    # first layer index the window applies to (HF qwen2 semantics: layers
-    # i >= max_window_layers are windowed, earlier layers attend fully);
-    # 0 = window on every layer
-    window_start_layer: int = 0
-    # HF-style rope_scaling dict ({"rope_type": "llama3"|"linear", ...});
-    # None = unscaled
+    # the period of attention kinds over the layers, each "window" (the last
+    # ``sliding_window`` keys) or "full": layer i is of kind
+    # attn_pattern[i % len]. None = every layer the one kind (windowed where
+    # sliding_window is set). HF qwen2's leading run of n full layers is
+    # ("full",) * n + ("window",) * (L - n): a period of the whole stack
+    attn_pattern: Optional[Tuple[str, ...]] = None
+    # HF-style rope_scaling dict ({"rope_type": "llama3"|"linear"|"yarn",
+    # ...}); None = unscaled
     rope_scaling: Optional[Dict[str, Any]] = None
+    # a rope of its own for a kind of layer: {"full": {"rope_theta": ...,
+    # "rope_type": "yarn", ...}}; a kind that is not named here takes
+    # rope_theta and rope_scaling
+    rope_by_kind: Optional[Dict[str, Dict[str, Any]]] = None
     norm_eps: float = 1e-5
 
     dtype: str = "bfloat16"        # compute dtype
@@ -118,6 +127,17 @@ class TransformerConfig:
     moe_a2a_bits: int = 0
     moe_a2a_slice: int = 0
     moe_a2a_block: int = 512
+    # the experts' own width (None = intermediate_size)
+    moe_intermediate_size: Optional[int] = None
+    # a share of the experts: this model holds ``moe_experts_held`` of them,
+    # from ``moe_first_expert`` on (what one chip of an expert-parallel host
+    # holds). The router keeps its num_experts outputs, the top_k and their
+    # weights are the whole model's, and the layer's output is the partial
+    # sum the held experts give (moe/sharded_moe.py:grouped_moe_mlp_block;
+    # the buffer of local pairs is bounded by moe_ep_capacity_factor).
+    # None = all of them
+    moe_experts_held: Optional[int] = None
+    moe_first_expert: int = 0
 
     def __post_init__(self):
         is_llama = self.arch == "llama"
@@ -149,6 +169,31 @@ class TransformerConfig:
         if self.exit_loss_beta is not None and self.num_passes < 2:
             raise ValueError("exit_loss_beta (the exit gate and expected-exit "
                              "loss) needs num_passes >= 2")
+        if self.attn_pattern is not None:
+            pat = tuple(self.attn_pattern)
+            if not pat or set(pat) - {"window", "full"} \
+                    or self.num_layers % len(pat):
+                raise ValueError(
+                    f"attn_pattern={pat}: a period of 'window' / 'full' "
+                    f"whose length divides num_layers={self.num_layers}")
+            if "window" in pat and self.sliding_window is None:
+                raise ValueError("attn_pattern has window layers and "
+                                 "sliding_window is not set")
+            # kept as its shortest period: a whole stack's list of kinds
+            # (HF ``layer_types``) and its period are the same model
+            p = next(p for p in range(1, len(pat) + 1) if len(pat) % p == 0
+                     and pat == pat[:p] * (len(pat) // p))
+            object.__setattr__(self, "attn_pattern", pat[:p])
+        if self.moe_experts_held is not None:
+            lo, n = self.moe_first_expert, self.moe_experts_held
+            if not (n >= 1 and lo >= 0 and lo + n <= self.num_experts):
+                raise ValueError(
+                    f"experts [{lo}, {lo + n}) are not among the "
+                    f"{self.num_experts} the router scores")
+            if self.moe_dispatch != "grouped":
+                raise ValueError("a held share of the experts "
+                                 "(moe_experts_held) runs the grouped "
+                                 "dispatch only (moe_dispatch='grouped')")
 
     # set when structured head pruning shrinks num_heads (head_dim is
     # otherwise derived as hidden_size // num_heads, which would silently
@@ -166,6 +211,36 @@ class TransformerConfig:
         pre-norm stack ask this and refuse."""
         return (self.num_passes > 1 or self.sandwich_norm
                 or self.exit_loss_beta is not None)
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The attention kind of every layer, "window" or "full"."""
+        pat = self.attn_pattern or (
+            ("full",) if self.sliding_window is None else ("window",))
+        return pat * (self.num_layers // len(pat))
+
+    @property
+    def patterned(self) -> bool:
+        """Whether the layers are of more than one attention kind."""
+        return len(set(self.layer_kinds)) > 1
+
+    def kind_cfg(self, kind: str) -> "TransformerConfig":
+        """The configuration a block of ``kind`` runs under: no window on a
+        full layer, the kind's own rope. The same object where nothing
+        differs."""
+        rope = (self.rope_by_kind or {}).get(kind)
+        window = self.sliding_window if kind == "window" else None
+        if (rope is None and window == self.sliding_window
+                and self.attn_pattern is None):
+            return self
+        kw: Dict[str, Any] = dict(sliding_window=window, attn_pattern=None,
+                                  rope_by_kind=None)
+        if rope is not None:
+            scaling = {k: v for k, v in rope.items() if k != "rope_theta"}
+            kw["rope_theta"] = float(rope.get("rope_theta", self.rope_theta))
+            kw["rope_scaling"] = (scaling if scaling.get(
+                "rope_type", "default") != "default" else None)
+        return dataclasses.replace(self, **kw)
 
     @property
     def rope_dim(self) -> int:
@@ -287,19 +362,52 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float,
             interp = (1 - smooth) * inv / factor + smooth * inv
             inv = jnp.where(wavelen > orig / lo, inv / factor,
                             jnp.where(wavelen < orig / hi, inv, interp))
+        elif rt == "yarn":
+            # HF yarn (transformers modeling_rope_utils): bands that turn
+            # more than beta_fast times over the original length keep their
+            # frequency, bands that turn less than beta_slow times divide it
+            # by `factor`, a linear ramp over the band index between. The
+            # attention factor scales cos and sin (rope_attention_factor).
+            factor = float(scaling["factor"])
+            orig = float(scaling["original_max_position_embeddings"])
+
+            def band(turns):
+                return (head_dim * math.log(orig / (turns * 2.0 * math.pi))
+                        / (2.0 * math.log(theta)))
+
+            low = max(math.floor(band(float(scaling.get("beta_fast", 32)))), 0)
+            high = min(math.ceil(band(float(scaling.get("beta_slow", 1)))),
+                       head_dim - 1)
+            if low == high:
+                high += 0.001
+            ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32)
+                             - low) / (high - low), 0.0, 1.0)
+            inv = inv / factor * ramp + inv * (1.0 - ramp)
         else:
             raise ValueError(f"unsupported rope_scaling type '{rt}' "
-                             "(have: linear, llama3)")
+                             "(have: linear, llama3, yarn)")
     t = jnp.arange(max_seq, dtype=jnp.float32)
     return jnp.outer(t, inv)  # [max_seq, head_dim//2]
 
 
-def apply_rope(x: jax.Array, freqs: jax.Array, positions: Optional[jax.Array] = None
-               ) -> jax.Array:
+def rope_attention_factor(scaling: Optional[Dict[str, Any]]) -> float:
+    """What yarn multiplies cos and sin by (so the scores by its square):
+    the config's ``attention_factor``, else ``0.1 ln(factor) + 1``; 1 for
+    every other rope."""
+    if not scaling or scaling.get("rope_type", scaling.get("type")) != "yarn":
+        return 1.0
+    given = scaling.get("attention_factor")
+    return float(given) if given is not None \
+        else 0.1 * math.log(float(scaling["factor"])) + 1.0
+
+
+def apply_rope(x: jax.Array, freqs: jax.Array, positions: Optional[jax.Array] = None,
+               scale: float = 1.0) -> jax.Array:
     """x: [B, T, H, d]; freqs: [max_seq, rd//2]; positions: [B, T] (default arange).
 
     When ``2*freqs.shape[-1] < d`` only the leading rotary dims rotate and the
-    tail passes through (gpt-neox/phi partial rotary, ``rotary_pct``)."""
+    tail passes through (gpt-neox/phi partial rotary, ``rotary_pct``).
+    ``scale`` multiplies cos and sin (yarn's attention factor)."""
     B, T = x.shape[0], x.shape[1]
     rd = 2 * freqs.shape[-1]
     tail = None
@@ -310,6 +418,8 @@ def apply_rope(x: jax.Array, freqs: jax.Array, positions: Optional[jax.Array] = 
     else:
         f = freqs[positions][:, :, None, :]  # [B, T, 1, rd/2]
     cos, sin = jnp.cos(f), jnp.sin(f)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     out = out.astype(x.dtype)
@@ -448,6 +558,11 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                     positions: Optional[jax.Array] = None) -> jax.Array:
     B, T, D = x.shape
     hd, H, K = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    rope_scale = rope_attention_factor(cfg.rope_scaling)
+    if cfg.attention_impl == "fpdt" and rope_scale != 1.0:
+        raise NotImplementedError("attention_impl='fpdt' applies a plain "
+                                  "rope; yarn's attention factor is not "
+                                  "carried into its chunk loop")
     if cfg.attention_impl == "fpdt" and positions is None:
         # fused per-chunk-projection tier: q/k/v never materialize full-T
         # (sequence/fpdt.py module docstring), incl. windowed families
@@ -463,8 +578,8 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     q = constrain(q, P(("dp", "fsdp"), "sp", "tp", None))
     k = constrain(k, P(("dp", "fsdp"), "sp", "tp", None))
     if cfg.use_rope:
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
+        q = apply_rope(q, freqs, positions, rope_scale)
+        k = apply_rope(k, freqs, positions, rope_scale)
     if cfg.sliding_window is not None:
         # windowed families (mistral/qwen2): the flash kernel takes the
         # window natively (block-skipping); impls without window support
@@ -517,8 +632,9 @@ def _decode_block(h: jax.Array, wc: Params, cfg: TransformerConfig,
     hn1 = _norm(h, wc["ln1"], cfg.norm, cfg.norm_eps)
     q, k, v = qkv_proj(hn1, wc["attn"], cfg)
     if cfg.use_rope:
-        q = apply_rope(q, freqs, positions)
-        k = apply_rope(k, freqs, positions)
+        rope_scale = rope_attention_factor(cfg.rope_scaling)
+        q = apply_rope(q, freqs, positions, rope_scale)
+        k = apply_rope(k, freqs, positions, rope_scale)
     attn, *left = attend(q, k, v)
     attn_out = attn_out_proj(attn, wc["attn"], cfg)
     if cfg.parallel_block:
@@ -565,7 +681,15 @@ def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
 #: holds the lowered program to this; benchmarks/readers/program.py sums
 #: device time by them.
 STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
-               "lm_head", "loss", "exit_gate", "grad_accum", "optimizer")
+               "lm_head", "loss", "exit_gate", "grad_accum", "optimizer",
+               # nested: the layer's kind inside attn (a model whose layers
+               # are of more than one), the grouped expert layer's parts
+               # inside moe (moe/sharded_moe.py)
+               "attn_window", "attn_full",
+               "moe_router", "moe_dispatch", "moe_experts")
+#: a period of up to this many blocks is the body of one scan over periods;
+#: a longer aperiodic list of kinds is cut into runs of one kind
+_MAX_PERIOD = 8
 
 
 def _cast_layers(w: Params, dt, ffn: str) -> Params:
@@ -583,11 +707,14 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
 def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                       freqs: Optional[jax.Array], attn_fn: Callable,
                       moe_fn: Optional[Callable] = None,
-                      positions: Optional[jax.Array] = None) -> Any:
+                      positions: Optional[jax.Array] = None,
+                      kind: Optional[str] = None) -> Any:
     """One pre-norm decoder block. Returns (x, aux_loss). ``positions`` [B, T]
     overrides RoPE positions (random-LTD token subsets). With
     ``cfg.sandwich_norm`` each branch's output is normed again before its
-    residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(FFN(N3(a)))``."""
+    residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(FFN(N3(a)))``.
+    ``kind`` names the layer's attention kind in a model that has several:
+    its operations then lie under ``attn/attn_<kind>``."""
     # named scopes land in HLO op metadata — the per-module profiler
     # (profiling/flops_profiler.per_module_profile) and the benchmark's
     # device-time-by-scope reader group cost by them. Every operation of the
@@ -595,7 +722,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     # residual adds and the second norm with the FFN.
     ffn = "moe" if moe_fn is not None else "mlp"
     wc = _cast_layers(w, jnp.dtype(cfg.dtype), ffn)
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn"), (jax.named_scope("attn_" + kind) if kind
+                                   else contextlib.nullcontext()):
         hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
         attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                    positions=positions)
@@ -703,6 +831,65 @@ def lm_loss(cfg: TransformerConfig, logits: jax.Array,
     return jnp.where(lmask, nll, 0.0).sum() / denom
 
 
+def _share_parts(aux: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """What the step record carries of a held share of the experts, from the
+    layers' counts (``moe/sharded_moe.py``): ``lb_loss``, the load-balance
+    term summed over the layers; by layer ``expert_pairs`` [L, held] (the
+    (token, expert) pairs each held expert received), ``pairs_here`` (those
+    computed), ``pairs_dropped`` (those the buffer of local pairs had no
+    room for) and ``load_max_over_mean`` (the busiest held expert's pairs
+    over the mean)."""
+    pairs = aux["expert_pairs"]
+    total = pairs.sum(axis=-1)
+    return {"lb_loss": aux["lb"], "expert_pairs": pairs,
+            "pairs_here": total - aux["pairs_dropped"],
+            "pairs_dropped": aux["pairs_dropped"],
+            "load_max_over_mean": pairs.max(axis=-1) * pairs.shape[-1]
+            / jnp.maximum(total, 1).astype(jnp.float32)}
+
+
+def _by_period(tree, lo: int, hi: int, p: int):
+    """Layers ``[lo, hi)`` of stacked leaves ``[L, ...]`` as the scan input
+    of a loop over periods of ``p`` blocks, ``[(hi - lo) / p, p, ...]``; a
+    period of one block keeps the layer axis as it is."""
+    def cut(a):
+        a = a if (lo, hi) == (0, a.shape[0]) else a[lo:hi]
+        return a if p == 1 else a.reshape(((hi - lo) // p, p) + a.shape[1:])
+    return jax.tree_util.tree_map(cut, tree)
+
+
+def _block_of(xs, j: int, p: int):
+    """Block ``j``'s slice of one period's scan input (:func:`_by_period`)."""
+    return xs if p == 1 else jax.tree_util.tree_map(lambda a: a[j], xs)
+
+
+def _stacked(trees: list):
+    """A list of like pytrees as one, its leaves stacked on a new axis."""
+    return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
+
+
+def _stack_blocks(ys: list, p: int):
+    """One period's per-block outputs as the scan's output."""
+    return ys[0] if p == 1 else _stacked(ys)
+
+
+def _by_layer(ys, p: int):
+    """A scan over periods' stacked outputs ``[periods, p, ...]`` back to
+    ``[layers, ...]``."""
+    return ys if p == 1 else jax.tree_util.tree_map(
+        lambda a: a.reshape((a.shape[0] * p,) + a.shape[2:]), ys)
+
+
+def _layer_aux(auxes):
+    """What a pass hands on of its layers' aux values ``[L, ...]``: their sum
+    (the load-balance term) for a scalar a layer; for a layer that reports
+    its router's counts (a dict, a held share of the experts) that sum under
+    ``lb`` and the rest by layer."""
+    if isinstance(auxes, dict):
+        return {**auxes, "lb": jnp.sum(auxes["lb"])}
+    return jnp.sum(auxes)
+
+
 class TransformerLM:
     """ModelSpec implementation for the decoder-only LM family."""
 
@@ -715,9 +902,16 @@ class TransformerLM:
 
             moe_fn = moe_block_for(cfg)
         self.moe_fn = moe_fn
-        self._freqs = (rope_frequencies(cfg.rope_dim, cfg.max_seq_len,
-                                        cfg.rope_theta, cfg.rope_scaling)
-                       if cfg.use_rope else None)
+        # what a block of each attention kind runs under: (its config, its
+        # rope table)
+        self._kinds = {}
+        for kind in dict.fromkeys(cfg.layer_kinds):
+            ck = cfg.kind_cfg(kind)
+            self._kinds[kind] = (ck, rope_frequencies(
+                ck.rope_dim, ck.max_seq_len, ck.rope_theta, ck.rope_scaling)
+                if cfg.use_rope else None)
+        # the first layer's: the paths written for layers of one kind read it
+        self._freqs = self._kinds[cfg.layer_kinds[0]][1]
         # random-LTD (data_routing/basic_layer.py parity): when set, layers in
         # [start, end) process only `keep` randomly chosen tokens per step;
         # dropped tokens ride the residual stream untouched. The engine owns
@@ -767,6 +961,24 @@ class TransformerLM:
         ``layer_applications``)."""
         return (self._pld_depth or self.cfg.num_layers) * self.cfg.num_passes
 
+    def step_program_facts(self) -> Dict[str, Any]:
+        """What a step program's row of the step-program table says of its
+        model (``observability/steplog.py:StepProgram``)."""
+        cfg = self.cfg
+        facts: Dict[str, Any] = {
+            "layer_applications": self.layer_applications,
+            "layer_pattern": cfg.attn_pattern or cfg.layer_kinds[:1]}
+        if cfg.num_experts > 1:
+            facts["experts_held"] = (
+                cfg.moe_first_expert if cfg.moe_experts_held else 0,
+                cfg.moe_experts_held or cfg.num_experts, cfg.num_experts)
+            if cfg.moe_dispatch == "grouped":
+                from deepspeed_tpu.moe.sharded_moe import resolve_moe_kernel
+
+                facts["moe_kernel_resolved"] = resolve_moe_kernel(
+                    cfg.moe_kernel)[0]
+        return facts
+
     # ---- init -------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -806,13 +1018,16 @@ class TransformerLM:
             mlp["b_up"] = jnp.zeros((L, F), pd)
             mlp["b_down"] = jnp.zeros((L, D), pd)
         if cfg.num_experts > 1:
-            E = cfg.num_experts
-            mlp = ({"w_gate": layer_stack(keys[4], D, (E, D, F)),
-                    "w_up": layer_stack(keys[5], D, (E, D, F)),
-                    "w_down": layer_stack(keys[6], F, (E, F, D))}
+            # the experts held here at their own width; the router scores
+            # all of them
+            E, Eh = cfg.num_experts, cfg.moe_experts_held or cfg.num_experts
+            F = cfg.moe_intermediate_size or F
+            mlp = ({"w_gate": layer_stack(keys[4], D, (Eh, D, F)),
+                    "w_up": layer_stack(keys[5], D, (Eh, D, F)),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, D))}
                    if cfg.activation == "swiglu" else
-                   {"w_up": layer_stack(keys[5], D, (E, D, F)),
-                    "w_down": layer_stack(keys[6], F, (E, F, D))})
+                   {"w_up": layer_stack(keys[5], D, (Eh, D, F)),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, D))})
             mlp["router"] = layer_stack(keys[7], D, (D, E))
         layers: Params = {"ln1": dict(norm_w), "attn": attn_w, "mlp": mlp}
         if not cfg.parallel_shared_norm:
@@ -821,7 +1036,8 @@ class TransformerLM:
             layers["ln1_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
             layers["ln2_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         params: Params = {
-            "embed": {"tokens": dense(keys[0], 1, (V, D)) * 0.02 * math.sqrt(1)},
+            "embed": {"tokens": dense(keys[0], 1, (V, D))
+                      * cfg.embed_init_std},
             "layers": layers,
             "final_norm": {"scale": jnp.ones((D,), pd)},
         }
@@ -868,24 +1084,24 @@ class TransformerLM:
             params, input_ids, positions=positions, ltd_seed=ltd_seed,
             pld_theta=pld_theta))
 
-    def _window_segments(self):
-        """Contiguous layer runs sharing one static window setting:
-        ``[(lo, hi, cfg_segment)]``. HF qwen2 gives the first
-        ``max_window_layers`` layers FULL attention (``window_start_layer``
-        here); each segment scans with its own cfg so windowed layers keep
-        the block-skipping flash/paged kernels and full layers never pay a
-        window mask."""
-        cfg = self.cfg
-        ws = cfg.window_start_layer
-        if cfg.sliding_window is None or ws <= 0:
-            return [(0, cfg.num_layers, cfg)]
-        ws = min(ws, cfg.num_layers)
-        segs = [(0, ws, dataclasses.replace(cfg, sliding_window=None,
-                                            window_start_layer=0))]
-        if ws < cfg.num_layers:
-            segs.append((ws, cfg.num_layers,
-                         dataclasses.replace(cfg, window_start_layer=0)))
-        return segs
+    def _layer_plan(self):
+        """How the layer loop runs the stack: ``[(lo, hi, period)]``, layers
+        ``[lo, hi)`` as a scan over periods whose body is the period's
+        blocks, ``period`` the kinds of one. A stack whose kinds repeat with
+        a period of up to ``_MAX_PERIOD`` blocks is one such scan (one kind:
+        a period of one block; three window layers and a full one: four
+        blocks traced whatever the depth); any other list (HF qwen2's leading
+        run of full layers before the windowed ones) is cut into runs of one
+        kind. Each block runs under its kind's own static config
+        (``self._kinds``), so a window layer keeps the tile-skipping kernels
+        and a full layer pays no window mask."""
+        kinds = self.cfg.layer_kinds
+        L = len(kinds)
+        period = self.cfg.attn_pattern or kinds[:1]
+        if len(period) <= _MAX_PERIOD:
+            return [(0, L, period)]
+        cuts = [0] + [i for i in range(1, L) if kinds[i] != kinds[i - 1]] + [L]
+        return [(lo, hi, (kinds[lo],)) for lo, hi in zip(cuts, cuts[1:])]
 
     def hidden_states(self, params: Params, input_ids: jax.Array,
                       positions: Optional[jax.Array] = None,
@@ -903,7 +1119,9 @@ class TransformerLM:
                        pld_theta: Optional[jax.Array] = None):
         """``([h_1 .. h_R], aux)``: the final-norm hidden states after each
         of the ``cfg.num_passes`` passes of the layer stack, and the MoE aux
-        loss summed over layers and passes. Every pass reads the same stacked
+        loss summed over layers and passes (with a held share of the experts
+        a dict: that sum under ``lb`` and the router's counts by layer,
+        :func:`_layer_aux`). Every pass reads the same stacked
         weights, cast once; the final norm closes a pass and its output is
         what the next pass reads, so one backward sums each weight's gradient
         over its uses."""
@@ -932,25 +1150,25 @@ class TransformerLM:
         for _ in range(cfg.num_passes):
             with jax.named_scope("layers"):
                 x, a = self._run_layers(layers, x, input_ids, attn_fn,
-                                        self._freqs, ltd_seed, pld_theta)
+                                        ltd_seed, pld_theta)
             with jax.named_scope("final_norm"):
                 x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
                 x = constrain(x, P(("dp", "fsdp"), "sp", None))
             hs.append(x)
-            aux = a if aux is None else aux + a
+            aux = a if aux is None else jax.tree_util.tree_map(jnp.add, aux, a)
         return hs, aux
 
     def _run_layers(self, layers: Params, x: jax.Array, input_ids: jax.Array,
-                    attn_fn: Callable, freqs, ltd_seed, pld_theta):
+                    attn_fn: Callable, ltd_seed, pld_theta):
         """One pass of the (already cast) layer stack on ``x``: ``(x, the
-        summed MoE aux loss)``."""
+        layers' MoE aux values as :func:`_layer_aux` hands them on)``."""
         cfg = self.cfg
-        segs = self._window_segments()
+        patterned = cfg.patterned
         T = input_ids.shape[1]
         ltd_keep = self._ltd_keep
         ltd = ltd_keep is not None and ltd_keep < T
         kpld = self._pld_depth
-        if (kpld is not None and kpld < cfg.num_layers and len(segs) == 1
+        if (kpld is not None and kpld < cfg.num_layers and not patterned
                 and not ltd):
             # static-depth PLD: run only the first k layers (real compute
             # saving — the gated-residual mode below computes every layer)
@@ -958,29 +1176,14 @@ class TransformerLM:
             n_layers_run = kpld
         else:
             n_layers_run = cfg.num_layers
-        if len(segs) > 1:
+        if patterned:
             if ltd or pld_theta is not None:
                 raise NotImplementedError(
-                    "mixed-window layers (window_start_layer > 0) cannot "
-                    "combine with random-LTD or progressive layer drop")
-            aux_total = jnp.zeros((), jnp.float32)
-            for lo, hi, cseg in segs:
-                def seg_body(carry, xs, _c=cseg):
-                    return transformer_block(carry, xs, _c, freqs, attn_fn,
-                                             self.moe_fn)
-
-                seg_body = _maybe_remat(seg_body, cfg.remat_policy)
-                seg_layers = jax.tree_util.tree_map(
-                    lambda p: p[lo:hi], layers)
-                if cfg.scan_layers:
-                    x, auxes = jax.lax.scan(seg_body, x, seg_layers)
-                    aux_total = aux_total + jnp.sum(auxes)
-                else:
-                    for i in range(hi - lo):
-                        xi = jax.tree_util.tree_map(lambda p: p[i], seg_layers)
-                        x, aux = seg_body(x, xi)
-                        aux_total = aux_total + aux
-            return x, aux_total
+                    "layers of more than one attention kind (attn_pattern) "
+                    "cannot combine with random-LTD or progressive layer "
+                    "drop")
+            return self._run_periods(self._layer_plan(), layers, x, attn_fn)
+        cfg, freqs = self._kinds[cfg.layer_kinds[0]]
         if ltd or pld_theta is not None:
             # shared routing key for LTD/PLD: step seed (engine-provided,
             # fresh per step/epoch) folded with batch content (fresh per
@@ -1032,7 +1235,8 @@ class TransformerLM:
                 y, aux = transformer_block(carry, layer_w, cfg, freqs,
                                            attn_fn, self.moe_fn)
                 x_new = jnp.where(keep, y, carry)
-                return x_new, jnp.where(keep, aux, 0.0)
+                return x_new, jax.tree_util.tree_map(
+                    lambda a: jnp.where(keep, a, jnp.zeros_like(a)), aux)
 
             xs = (layers, jnp.arange(cfg.num_layers))
         else:
@@ -1047,14 +1251,55 @@ class TransformerLM:
         wrapped = ltd or pld_theta is not None
         if cfg.scan_layers:
             x, auxes = jax.lax.scan(body, x, xs)
-            aux_total = jnp.sum(auxes)
         else:
-            aux_total = jnp.zeros((), jnp.float32)
+            auxes = []
             for i in range(n_layers_run):
                 xi = jax.tree_util.tree_map(lambda p: p[i], layers)
                 x, aux = body(x, (xi, jnp.int32(i)) if wrapped else xi)
-                aux_total = aux_total + aux
-        return x, aux_total
+                auxes.append(aux)
+            auxes = _stacked(auxes)
+        return x, _layer_aux(auxes)
+
+    def _run_periods(self, plan, layers: Params, x: jax.Array,
+                     attn_fn: Callable):
+        """The layer loop of a stack whose layers are of more than one
+        attention kind: each entry of ``plan`` (:meth:`_layer_plan`) is a scan
+        over its periods, the body the period's blocks, each under its kind's
+        config and rope table and recomputed on its own."""
+        cfg = self.cfg
+        per_layer = []
+        for lo, hi, period in plan:
+            blocks = [_maybe_remat(
+                partial(self._kind_block, kind, attn_fn), cfg.remat_policy)
+                for kind in period]
+            p = len(period)
+            seg = _by_period(layers, lo, hi, p)
+
+            def body(carry, xs, _blocks=blocks, p=p):
+                auxes = []
+                for j, blk in enumerate(_blocks):
+                    carry, aux = blk(carry, _block_of(xs, j, p))
+                    auxes.append(aux)
+                return carry, _stack_blocks(auxes, p)
+
+            if cfg.scan_layers:
+                x, auxes = jax.lax.scan(body, x, seg)
+            else:
+                auxes = []
+                for i in range((hi - lo) // p):
+                    x, aux = body(x, jax.tree_util.tree_map(
+                        lambda a: a[i], seg))
+                    auxes.append(aux)
+                auxes = _stacked(auxes)
+            per_layer.append(_by_layer(auxes, p))
+        return x, _layer_aux(jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a), *per_layer))
+
+    def _kind_block(self, kind: str, attn_fn: Callable, x: jax.Array,
+                    w: Params):
+        ck, freqs = self._kinds[kind]
+        return transformer_block(x, w, ck, freqs, attn_fn, self.moe_fn,
+                                 kind=kind)
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -1114,6 +1359,11 @@ class TransformerLM:
             parts = {}
         if cfg.num_experts > 1:
             with jax.named_scope("loss"):
+                if isinstance(aux, dict):
+                    # a held share of the experts: the load-balance term and
+                    # the router's counts go into the step record
+                    parts = {**parts, **_share_parts(aux)}
+                    aux = aux["lb"]
                 loss = loss + cfg.moe_aux_loss_coef * aux
         return loss, parts
 
@@ -1172,11 +1422,12 @@ class TransformerLM:
                       attend: Callable, layer_xs: Any = (),
                       carry: Any = ()) -> Any:
         """The layer stack of every serving forward: embed ``token_ids``
-        [.., t] at ``positions`` [.., t], scan each window segment's layers
-        through :func:`_decode_block`, final norm.
+        [.., t] at ``positions`` [.., t], scan the periods of each entry of
+        :meth:`_layer_plan` through :func:`_decode_block` (each block under
+        its kind's config and rope table), final norm.
 
         ``attend(cseg, li, q, k, v, xs, carry)`` is the caller's own: the
-        cache read and the attention of layer ``li`` under segment config
+        cache read and the attention of layer ``li`` under its kind's config
         ``cseg``. ``xs`` is that layer's slice of ``layer_xs`` (per-layer
         scan inputs, leading dim L), ``carry`` what the layer before handed
         on (``carry`` here for the first). It returns ``(out [.., t, H, hd],
@@ -1196,29 +1447,35 @@ class TransformerLM:
         dense_layers, quant_items = split_quant_leaves(params["layers"])
 
         parts = []
-        for lo, hi, cseg in self._window_segments():
-            def body(h_carry, xs, cseg=cseg):
-                h, prev = h_carry
-                layer_w, li, lxs = xs
-                wc = jax.tree_util.tree_map(
-                    lambda p: p.astype(dt) if p.dtype == jnp.float32 else p,
-                    layer_w)
-                for grp, name, qw in quant_items:
-                    wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
-                h, (ys, nxt) = _decode_block(
-                    h, wc, cseg, self._freqs, positions,
-                    lambda q, k, v: attend(cseg, li, q, k, v, lxs, prev),
-                    self.moe_fn, moe_valid=moe_valid)
-                return (h, nxt), ys
+        for lo, hi, period in self._layer_plan():
+            p = len(period)
 
-            layer_w, lxs = jax.tree_util.tree_map(
-                lambda p: p[lo:hi], (dense_layers, layer_xs))
-            (x, carry), ys = jax.lax.scan(
-                body, (x, carry),
-                (layer_w, jnp.arange(lo, hi, dtype=jnp.int32), lxs))
-            parts.append(ys)
+            def body(h_carry, xs, period=period, p=p):
+                h, prev = h_carry
+                ys = []
+                for j, kind in enumerate(period):
+                    layer_w, li, lxs = _block_of(xs, j, p)
+                    cseg, freqs = self._kinds[kind]
+                    wc = jax.tree_util.tree_map(
+                        lambda a: a.astype(dt) if a.dtype == jnp.float32
+                        else a, layer_w)
+                    for grp, name, qw in quant_items:
+                        wc[grp] = {**wc[grp], name: QuantLayerRef(qw, li)}
+                    h, (y, prev) = _decode_block(
+                        h, wc, cseg, freqs, positions,
+                        lambda q, k, v, cseg=cseg, li=li, lxs=lxs, prev=prev:
+                        attend(cseg, li, q, k, v, lxs, prev),
+                        self.moe_fn, moe_valid=moe_valid)
+                    ys.append(y)
+                return (h, prev), _stack_blocks(ys, p)
+
+            xs = _by_period(
+                (dense_layers, jnp.arange(cfg.num_layers, dtype=jnp.int32),
+                 layer_xs), lo, hi, p)
+            (x, carry), ys = jax.lax.scan(body, (x, carry), xs)
+            parts.append(_by_layer(ys, p))
         ys = jax.tree_util.tree_map(
-            lambda *p: p[0] if len(p) == 1 else jnp.concatenate(p), *parts)
+            lambda *a: a[0] if len(a) == 1 else jnp.concatenate(a), *parts)
         return _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps), ys, carry
 
     def forward_with_cache(self, params: Params, input_ids: jax.Array,

@@ -328,6 +328,11 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     Unlike the capacity path, every (token, expert) pair is computed — no
     ``capacity_factor`` padding waste and no dropped tokens — at the price of
     data-dependent group sizes (static TOTAL shape ``S*k``, so it still jits).
+    With a held share of the experts (``cfg.moe_experts_held``) the router,
+    the top k and their renormalised weights are the whole model's, only the
+    pairs whose expert is here are computed, what the absent experts would
+    add is left out, and the second value returned is a dict: the
+    load-balance term under ``lb`` and the router's counts.
     Under ``ep > 1`` dispatch routes through ``_grouped_moe_ep`` — an explicit
     padded all-to-all over the ``ep`` axis feeding per-shard grouped GEMMs (the
     ``_AllToAll`` of reference ``moe/sharded_moe.py:97``, made dropless) —
@@ -348,14 +353,39 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     k = cfg.top_k
     x = h.reshape(B * T, D)
     S = x.shape[0]
-    logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)
-    _gates, aux_loss, topk_vals, topk_idx = _route(
-        logits, k, valid=None if valid is None else valid.reshape(-1))
-
-    flat_expert = topk_idx.reshape(-1)                        # [S*k]
-    order = jnp.argsort(flat_expert)                          # group by expert
-    tok = order // k
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+    n = S * k
+    # the experts held here: all of them, or the share the config names
+    # (what one chip of an expert-parallel host holds of the layer)
+    held = getattr(cfg, "moe_experts_held", None)
+    first = int(getattr(cfg, "moe_first_expert", 0) or 0) if held else 0
+    n_held = int(held or E)
+    # rows of the buffer of local pairs: every pair (dropless whatever the
+    # router does), or moe_ep_capacity_factor times the balanced load of the
+    # held experts; pairs past it are counted (``pairs_dropped``)
+    factor = float(getattr(cfg, "moe_ep_capacity_factor", 0.0) or 0.0)
+    bound = n if not held or factor <= 0.0 else min(
+        n, int(math.ceil(n * n_held / E * factor)))
+    with jax.named_scope("moe_router"):
+        logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)
+        # the top k and their weights over ALL experts, as the whole model
+        _gates, aux_loss, topk_vals, topk_idx = _route(
+            logits, k, valid=None if valid is None else valid.reshape(-1))
+        flat_expert = topk_idx.reshape(-1)                    # [S*k]
+        local = flat_expert - first
+        here = (local >= 0) & (local < n_held)
+        key = jnp.where(here, local, n_held)                  # absent: last
+        order = jnp.argsort(key)                              # group by expert
+        counts = jnp.bincount(key, length=n_held + 1)[:n_held] \
+            .astype(jnp.int32)                    # pairs each held expert got
+        ends = jnp.minimum(jnp.cumsum(counts), bound)
+        group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        n_here = ends[-1]                         # rows that carry a pair
+        rows = order[:bound]                      # the pair in each row
+        rank = jnp.zeros((n,), jnp.int32).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        # each pair's row, ``bound`` (no row: it reads as zeros) for a pair
+        # whose expert is absent or that the buffer had no room for
+        slot = jnp.where(rank < n_here, rank, bound).reshape(S, k)
     if _TRACKER is not None:
         real = (jnp.ones((S,), bool) if valid is None
                 else valid.reshape(-1))
@@ -365,11 +395,87 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         jax.debug.callback(_emit_expert_counts, cnt)
 
     dt = h.dtype
-    xs = x[tok].astype(dt)                                    # [S*k, D]
-    ys = _grouped_ffn(xs, group_sizes, w, dt, kernel)         # [S*k, D]
-    weights = topk_vals.reshape(-1)[order].astype(dt)
-    out = jnp.zeros((S, D), dt).at[tok].add(ys * weights[:, None])
-    return out.reshape(B, T, D), aux_loss
+    with jax.named_scope("moe_dispatch"):
+        xs = _rows_of_tokens(x.astype(dt), rows // k, slot)   # [bound, D]
+    with jax.named_scope("moe_experts"):
+        ys = _grouped_ffn(xs, group_sizes, w, dt, kernel)     # [bound, D]
+        # rows past the groups hold whatever the kernel left there
+        ys = jnp.where((jnp.arange(bound) < n_here)[:, None], ys, 0)
+    with jax.named_scope("moe_dispatch"):
+        out = _weighted_sum_of_rows(ys, topk_vals, rows, slot)
+    out = out.reshape(B, T, D)
+    if not held:
+        return out, aux_loss
+    # what the step record carries of a share (models/transformer.py:
+    # _share_parts); the partial sum goes on to the next layer as it is
+    return out, {"lb": aux_loss, "expert_pairs": counts,
+                 "pairs_dropped": counts.sum() - n_here}
+
+
+# Dispatch and combine as gathers both ways. A pair's row in the buffer is a
+# one-to-one map (``rows``: row -> pair, ``slot``: pair -> row), so the
+# transpose of either gather is the other one, not a scatter-add (which a
+# v5e runs about ten times slower than the gather over the same rows).
+
+@jax.custom_vjp
+def _rows_of_tokens(x: jax.Array, tok: jax.Array, slot: jax.Array):
+    """``x[tok]``: the token each buffer row reads, [bound, D]."""
+    return x[tok]
+
+
+def _rows_fwd(x, tok, slot):
+    return x[tok], slot
+
+
+def _rows_bwd(slot, g):
+    return _sum_of_rows(g, slot, None).astype(g.dtype), None, None
+
+
+_rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
+
+
+def _sum_of_rows(ys, slot, weights):
+    """``sum_j weights[:, j] * ys[slot[:, j]]`` in f32, [S, D]; a slot past
+    the last row reads zeros."""
+    acc = None
+    for j in range(slot.shape[1]):
+        term = jnp.take(ys, slot[:, j], axis=0, mode="fill",
+                        fill_value=0).astype(jnp.float32)
+        if weights is not None:
+            term = term * weights[:, j, None]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+@jax.custom_vjp
+def _weighted_sum_of_rows(ys: jax.Array, weights: jax.Array,
+                          rows: jax.Array, slot: jax.Array):
+    """Each token's ``sum_j weights[t, j] * ys[slot[t, j]]``, summed in f32:
+    ys [bound, D], weights [S, k] f32, ``rows`` [bound] the pair of each row,
+    ``slot`` [S, k] the row of each pair."""
+    return _sum_of_rows(ys, slot, weights).astype(ys.dtype)
+
+
+def _wsum_fwd(ys, weights, rows, slot):
+    return _sum_of_rows(ys, slot, weights).astype(ys.dtype), \
+        (ys, weights, rows, slot)
+
+
+def _wsum_bwd(res, g):
+    ys, weights, rows, slot = res
+    k = slot.shape[1]
+    # a row's cotangent: its token's, times its pair's weight (a row that
+    # carries no pair is cut off by the caller's mask)
+    dys = (g[rows // k].astype(jnp.float32)
+           * weights.reshape(-1)[rows][:, None]).astype(ys.dtype)
+    gf = g.astype(jnp.float32)
+    dw = jnp.stack([
+        (gf * jnp.take(ys, slot[:, j], axis=0, mode="fill", fill_value=0)
+         .astype(jnp.float32)).sum(axis=-1) for j in range(k)], axis=1)
+    return dys, dw.astype(weights.dtype), None, None
+
+
+_weighted_sum_of_rows.defvjp(_wsum_fwd, _wsum_bwd)
 
 
 def _grouped_moe_ep(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,

@@ -153,12 +153,28 @@ class StepProgram:
     program, and the row answers None."""
 
     def __init__(self, name: str, key: Any, fn: Callable, mesh,
-                 layer_applications: Optional[int] = None):
+                 layer_applications: Optional[int] = None,
+                 layer_pattern: Optional[Sequence[str]] = None,
+                 moe_kernel_resolved: Optional[str] = None,
+                 experts_held: Optional[Sequence[int]] = None):
         self.name = name
         self.key = str(key)
         #: block applications one micro-batch's forward holds (layers run x
         #: passes over them); None where the model does not say
         self.layer_applications = layer_applications
+        #: the period of attention kinds the layer loop scans ("window" /
+        #: "full"); None where the model does not say
+        self.layer_pattern = None if layer_pattern is None \
+            else tuple(layer_pattern)
+        #: the grouped expert product the program was traced with: "ragged"
+        #: (``lax.ragged_dot``) or "padded" (its einsum twin, also what
+        #: ``resolve_moe_kernel`` falls to where ragged_dot does not lower);
+        #: None for a model without grouped experts
+        self.moe_kernel_resolved = moe_kernel_resolved
+        #: (first, count, routed) of the experts a layer holds; None for a
+        #: model without experts
+        self.experts_held = None if experts_held is None \
+            else tuple(experts_held)
         #: flash-backward lowerings of the program's trace by the kernel they
         #: took, ``{"fused": n, "split": m}`` (``ops/flash_attention.py``);
         #: None until the program's first call has traced it
@@ -214,8 +230,10 @@ _PROGRAMS: deque = deque(maxlen=64)
 
 
 def record_program(name: str, key: Any, fn: Callable, mesh,
-                   layer_applications: Optional[int] = None) -> StepProgram:
-    row = StepProgram(name, key, fn, mesh, layer_applications)
+                   **facts) -> StepProgram:
+    """Enter a step program in the table; ``facts`` are what its model says
+    of itself (:class:`StepProgram`'s keyword arguments)."""
+    row = StepProgram(name, key, fn, mesh, **facts)
     _PROGRAMS.append(row)
     return row
 

@@ -256,14 +256,14 @@ class DeepSpeedTpuEngine:
                                   .compiled_tiers)
             if self._pld_tiers > 0:
                 if getattr(getattr(self.module, "cfg", None),
-                           "window_start_layer", 0):
+                           "patterned", False):
                     # the static-depth slice would silently no-op under the
-                    # multi-segment layer loop while still paying a jit
-                    # rebuild per tier change
+                    # scan over periods while still paying a jit rebuild per
+                    # tier change
                     raise NotImplementedError(
                         "progressive_layer_drop.compiled_tiers does not "
-                        "support mixed-window models (window_start_layer "
-                        "> 0)")
+                        "support models whose layers are of more than one "
+                        "attention kind (attn_pattern)")
                 wd = float((config.optimizer.params or {}).get(
                     "weight_decay", 0.0)) if config.optimizer else 0.0
                 if wd > 0.0:
@@ -969,10 +969,10 @@ class DeepSpeedTpuEngine:
         it in the step-program table under its function's name, which is also
         what the device trace's module line says ran (``jit_ds_train_step``)."""
         self._fused_step_cache[key] = jitted
+        facts = getattr(self.module, "step_program_facts", None)
         self._uncaptured[key] = steplog.record_program(
             jitted.__name__, key, jitted, self.mesh,
-            layer_applications=getattr(self.module, "layer_applications",
-                                       None))
+            **(facts() if facts is not None else {}))
 
     def _dispatch_fused(self, key, *args):
         """Call the jitted step program of ``key`` (the ``ds.train.dispatch``

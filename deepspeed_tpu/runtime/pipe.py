@@ -82,14 +82,15 @@ class PipelineModule:
         if model.cfg.num_layers % num_stages != 0:
             raise ValueError(f"num_layers={model.cfg.num_layers} not divisible by "
                              f"pipeline stages={num_stages}")
-        if model.cfg.sliding_window is not None \
-                and model.cfg.window_start_layer > 0:
+        if model.cfg.patterned:
             # every stage runs ONE compiled program with a dynamic stage id,
-            # so a per-layer-range static window cannot be expressed here —
-            # running anyway would window the full-attention head layers
+            # so a per-layer static window or rope cannot be expressed here —
+            # running anyway would window the full-attention layers
             raise NotImplementedError(
-                "mixed-window models (window_start_layer > 0, qwen2-style) "
-                "are not supported under pipeline parallelism")
+                "models whose layers are of more than one attention kind "
+                "(attn_pattern: qwen2-style leading full layers, window and "
+                "full layers in turn) are not supported under pipeline "
+                "parallelism")
         if getattr(model.cfg, "looped", False):
             # a stage runs its slice of the stack once a micro-batch; a
             # looped model sends the last stage's output back to the first

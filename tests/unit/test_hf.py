@@ -294,8 +294,7 @@ def test_family_config_mapping():
 
 def test_qwen2_mixed_window_import_parity(tmp_path):
     """HF qwen2 windows only layers i >= max_window_layers (the first layers
-    attend fully). The import threads window_start_layer into segmented layer
-    scans; logits must match transformers on a T > window sequence through
+    attend fully). The import writes that as an attn_pattern over the stack; logits must match transformers on a T > window sequence through
     the train path AND the serving engines (round-2 ADVICE: the old gate was
     inverted and applied the window globally)."""
     import torch
@@ -314,7 +313,7 @@ def test_qwen2_mixed_window_import_parity(tmp_path):
     hf.save_pretrained(str(tmp_path))
     model, params = load_hf_checkpoint(str(tmp_path), dtype="float32")
     assert model.cfg.sliding_window == 8
-    assert model.cfg.window_start_layer == 2
+    assert model.cfg.attn_pattern == ("full", "full", "window", "window")
     ids = np.random.default_rng(4).integers(0, 128, (2, 16))  # T=16 > win=8
     ours = np.asarray(jax.jit(model.logits)(params, ids))
     with torch.no_grad():
@@ -349,4 +348,4 @@ def test_qwen2_window_gate_not_inverted():
     assert config_from_hf({**base, "max_window_layers": 2}).sliding_window \
         is None
     allwin = config_from_hf({**base, "max_window_layers": 0})
-    assert allwin.sliding_window == 8 and allwin.window_start_layer == 0
+    assert allwin.sliding_window == 8 and allwin.attn_pattern is None

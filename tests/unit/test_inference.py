@@ -775,15 +775,31 @@ def loop_models():
             for p, sc in zip(w.packed, w.scales)])
 
     out = {}
-    # two window segments (layer 0 full attention, layers 1-2 windowed):
-    # the loop concatenates what the segments' scans leave behind
+    # layer 0 full attention, layers 1-2 windowed: one period of three
+    # blocks, each under its kind's config
     model = TransformerLM(get_preset(
         "tiny", dtype="float32", num_layers=3, sliding_window=6,
-        window_start_layer=1))
+        attn_pattern=("full", "window", "window")))
     params = model.init(jax.random.key(0))
     eng = InferenceEngineV2(model, params=params, max_sequences=2,
                             max_seq_len=32, block_size=8)
     out["mixed_window"] = (model, eng, params, params, dict(atol=2e-3))
+    # window and full layers in turn (two periods of three blocks), the full
+    # layer's rope yarn with its attention factor, a held share of the
+    # experts: prefill, then decode through the cache
+    model = TransformerLM(get_preset(
+        "tiny", dtype="float32", num_layers=6, sliding_window=6,
+        attn_pattern=("window", "window", "full"),
+        rope_by_kind={"full": {"rope_type": "yarn", "rope_theta": 1e4,
+                               "factor": 4.0, "beta_fast": 4, "beta_slow": 1,
+                               "original_max_position_embeddings": 16,
+                               "attention_factor": 1.2}},
+        num_experts=8, top_k=2, moe_dispatch="grouped",
+        moe_intermediate_size=32, moe_experts_held=4, moe_first_expert=2))
+    params = model.init(jax.random.key(1))
+    eng = InferenceEngineV2(model, params=params, max_sequences=2,
+                            max_seq_len=32, block_size=8)
+    out["pattern_yarn_share"] = (model, eng, params, params, dict(atol=2e-3))
     # quantized layer leaves: the loop threads QuantLayerRef into each layer
     model, params = TestWeightQuantServing._model()
     eng = InferenceEngineV2(model, params=params, max_sequences=2,
@@ -828,7 +844,8 @@ def _serve(path, model, eng, params, prompt):
 @pytest.mark.parametrize("path", ["forward_with_cache", "forward_prefill",
                                   "forward_with_packed_cache",
                                   "forward_decode_tail"])
-@pytest.mark.parametrize("kind", ["mixed_window", "int8_leaves"])
+@pytest.mark.parametrize("kind", ["mixed_window", "int8_leaves",
+                                  "pattern_yarn_share"])
 def test_every_serving_program_matches_the_whole_sequence_forward(
         loop_models, kind, path):
     """The four serving forwards share one layer loop: down each of them the

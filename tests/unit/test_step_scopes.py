@@ -27,7 +27,22 @@ CASES = {
                 "tie_embeddings": False, "remat_policy": "full"}, 1),
     "looped_ga2": ({"num_passes": 2, "sandwich_norm": True,
                     "exit_loss_beta": 0.1}, 2),
+    # window and full layers in turn under recomputation, a rope by kind, a
+    # held share of grouped experts: the kind's scope nests inside attn, the
+    # router, the dispatch and the products inside moe
+    "pattern_share": ({"num_layers": 4, "sliding_window": 8,
+                       "attn_pattern": ("window", "full"),
+                       "rope_by_kind": {"full": {
+                           "rope_type": "yarn", "rope_theta": 1e4,
+                           "factor": 4.0,
+                           "original_max_position_embeddings": 16}},
+                       "num_experts": 8, "top_k": 2,
+                       "moe_dispatch": "grouped", "moe_intermediate_size": 32,
+                       "moe_experts_held": 4, "moe_first_expert": 4,
+                       "tie_embeddings": False, "remat_policy": "full"}, 1),
 }
+NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
+          "moe_dispatch": "moe", "moe_experts": "moe"}
 
 
 def _op_names(overrides, ga):
@@ -59,8 +74,13 @@ def test_every_operation_carries_a_step_scope(case):
     assert loose == []
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
-    ffn = "moe" if case == "moe" else "mlp"
+    ffn = "moe" if case in ("moe", "pattern_share") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
+    if case == "pattern_share":
+        want |= set(NESTED)
+        for inner, outer in NESTED.items():
+            ops = [n for n in names if inner in re.split(r"[/()]", n)]
+            assert ops and all(outer in re.split(r"[/()]", n) for n in ops)
     if CASES[case][0].get("loss_tiling", 0) <= 1:
         want.add("lm_head")
     if CASES[case][1] > 1:      # with one micro-batch the compiler folds 0 + g
