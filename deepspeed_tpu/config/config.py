@@ -432,9 +432,11 @@ class MoEConfig(DSTpuConfigModel):
     drop_tokens: bool = True
     use_rts: bool = True  # random token selection
     noisy_gate_policy: Optional[str] = None  # None|Jitter|RSample
-    # grouped-dispatch expert FFN kernel: "ragged" = lax.ragged_dot grouped
-    # GEMM (falls back to "padded" with one logged warning where it cannot
-    # lower), "padded" = force the capacity-einsum reference twin
+    # grouped-dispatch expert FFN kernel: "ragged" = grouped GEMMs over the
+    # sorted rows (the Pallas kernels or lax.ragged_dot by what the call can
+    # see, ops/grouped_matmul.py; falls back to "padded" with one logged
+    # warning where ragged_dot cannot lower), "padded" = force the
+    # capacity-einsum reference twin
     kernel: str = "ragged"
     # a2a dispatch wire format (comm/quantized.py): 0 = dense activations,
     # 4/8 = blockwise-quantized payload; a2a_slice > 1 selects the two-hop

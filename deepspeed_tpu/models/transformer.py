@@ -118,7 +118,8 @@ class TransformerConfig:
     # (cap = S_local*top_k); f>0 → cap ≈ S_local*top_k*f/ep (may drop
     # overflow pairs under extreme router imbalance)
     moe_ep_capacity_factor: float = 0.0
-    # grouped-dispatch FFN kernel: "ragged" (lax.ragged_dot grouped GEMM,
+    # grouped-dispatch FFN kernel: "ragged" (grouped GEMMs over the sorted
+    # rows: Pallas or lax.ragged_dot by shape, ops/grouped_matmul.py;
     # auto-fallback) | "padded" (capacity-einsum reference twin)
     moe_kernel: str = "ragged"
     # a2a dispatch wire (comm/quantized.py): 0 = dense, 4/8 = blockwise

@@ -10,6 +10,14 @@ Static-shape discipline: capacity ``C`` is computed from *static* sequence lengt
 capacity factor, so the whole layer jits with fixed shapes (no ragged dispatch in the
 hot path; dropped tokens pass through the residual, exactly like the reference with
 ``drop_tokens=True``).
+
+The dropless twin (``moe_dispatch: grouped``) sorts the (token, expert) pairs by expert
+and runs the experts' FFN as grouped products over the sorted rows (``moe_kernel:
+ragged``). ``ragged`` names the algebra, not a lowering: each product is the Pallas
+kernel of ``ops/grouped_matmul.py`` (forward and both transposes) where its rule finds
+the measured case — a TPU, dense bf16 stacks, whole-lane widths, a tile of rows an
+expert — and ``jax.lax.ragged_dot`` everywhere else (int8 serving stacks, decode steps,
+other backends), exactly as before.
 """
 
 from __future__ import annotations
@@ -50,7 +58,10 @@ def moe_kernel_support() -> Tuple[Optional[str], str]:
     """How the dropless grouped expert GEMM can run on this backend:
     ``("native", why)`` when ``jax.lax.ragged_dot`` lowers here, ``(None,
     why)`` otherwise — callers log ``why`` once and fall back to
-    ``moe.kernel: padded`` (the capacity-einsum reference)."""
+    ``moe.kernel: padded`` (the capacity-einsum reference). ``ragged_dot``
+    is the lowering every backend and shape can take; where the Pallas
+    kernels run instead is ``ops/grouped_matmul.py:grouped_lowering``'s to
+    say, call by call."""
     global _SUPPORT_MEMO
     if _SUPPORT_MEMO is not None:
         return _SUPPORT_MEMO
@@ -291,26 +302,60 @@ def _padded_ffn(xs: jax.Array, group_sizes: jax.Array,
 
 
 def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
-                 dt, kernel: str = "ragged") -> jax.Array:
+                 dt, kernel: str = "ragged", interpret: Optional[bool] = None,
+                 rows_past_groups: bool = False) -> jax.Array:
     """Expert-grouped FFN over tokens sorted by expert. ``kernel="ragged"``
-    is the ``lax.ragged_dot`` chain XLA lowers to a grouped
-    (MegaBlocks-style) GEMM (int8 serving stacks dequant inside the
-    operand read, see :func:`_expert_weight`); ``"padded"`` is the
-    capacity-einsum reference twin (:func:`_padded_ffn`) the engines fall
-    back to when ragged_dot has no backend lowering."""
+    names the algebra: every row times its own expert's weights, no padding
+    to a capacity. Its products take the lowering
+    ``ops/grouped_matmul.py:grouped_lowering`` picks from the call's own
+    facts, one answer for the FFN: the Pallas kernels (forward and both
+    transposes) on a TPU for dense bf16 stacks of whole-lane widths with a
+    tile of rows a group (training, prefill); ``lax.ragged_dot`` for
+    everything else (int8 serving stacks, which dequantise inside its
+    operand read, see :func:`_expert_weight`; a decode step's few rows;
+    other backends). ``"padded"`` is the capacity-einsum reference twin
+    (:func:`_padded_ffn`) the engines fall back to when ragged_dot has no
+    backend lowering. ``interpret`` is the kernels' test handle (None: ask
+    the backend).
+
+    ``rows_past_groups``: the groups may end before the rows do. No consumer
+    reads such a row of the result (a pair's ``slot`` never names one), but
+    its cotangent arrives as if it carried a pair. The kernels work only the
+    rows a group holds, forward and backward, whatever the others contain,
+    so nothing is masked there; ``ragged_dot`` leaves those rows
+    uninitialised and its transposes are given the cotangent as it comes,
+    so its result is masked (and with it the cotangent), as before."""
     if kernel == "padded":
         return _padded_ffn(xs, group_sizes, w, dt)
+    # imported here: a model without experts never loads the kernels
+    from deepspeed_tpu.ops.grouped_matmul import (grouped_lowering,
+                                                  grouped_matmul)
+
+    names = ("w_gate", "w_up", "w_down") if _has_gate(w) \
+        else ("w_up", "w_down")
+    stacks = {name: _expert_weight(w, name, dt) for name in names}
+    experts, width, inner = stacks["w_up"].shape
+    took, _ = grouped_lowering(
+        xs.shape[0], width, inner, experts, jnp.result_type(xs.dtype, dt),
+        dense=all(name in w for name in names),
+        tpu=None if interpret is None else True)
+
+    def product(rows, stack):
+        return grouped_matmul(rows, stack, group_sizes, lowering=took,
+                              interpret=bool(interpret))
+
     if _has_gate(w):
-        act = jax.nn.silu(jax.lax.ragged_dot(
-            xs, _expert_weight(w, "w_gate", dt), group_sizes))
-        act = act * jax.lax.ragged_dot(xs, _expert_weight(w, "w_up", dt),
-                                       group_sizes)
+        # one call for the two stacks that read xs: its backward sums their
+        # two cotangents of xs inside one kernel
+        gate, up = product(xs, (stacks["w_gate"], stacks["w_up"]))
+        act = jax.nn.silu(gate) * up
     else:
-        act = jax.nn.gelu(jax.lax.ragged_dot(
-            xs, _expert_weight(w, "w_up", dt), group_sizes),
-            approximate=True)
-    return jax.lax.ragged_dot(act, _expert_weight(w, "w_down", dt),
-                              group_sizes)
+        act = jax.nn.gelu(product(xs, stacks["w_up"]), approximate=True)
+    ys = product(act, stacks["w_down"])
+    if rows_past_groups and took == "xla":
+        held = jnp.arange(xs.shape[0]) < group_sizes.sum()
+        ys = jnp.where(held[:, None], ys, 0)
+    return ys
 
 
 def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
@@ -320,10 +365,12 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
                           a2a_slice: Optional[int] = None
                           ) -> Tuple[jax.Array, jax.Array]:
     """Dropless sort-based dispatch over grouped GEMMs — the
-    ``inference/v2/kernels/cutlass_ops/moe_gemm`` (MegaBlocks-style) analog,
-    expressed with ``jax.lax.ragged_dot`` so XLA emits the grouped matmul
-    (``kernel="padded"`` swaps in the capacity-einsum reference twin; the
-    default resolves ``cfg.moe_kernel`` with automatic fallback).
+    ``inference/v2/kernels/cutlass_ops/moe_gemm`` (MegaBlocks-style) analog:
+    ``kernel="ragged"`` is every pair computed over the rows sorted by
+    expert, by the Pallas grouped matmul or ``jax.lax.ragged_dot`` as
+    :func:`_grouped_ffn` says (``kernel="padded"`` swaps in the
+    capacity-einsum reference twin; the default resolves ``cfg.moe_kernel``
+    with automatic fallback).
 
     Unlike the capacity path, every (token, expert) pair is computed — no
     ``capacity_factor`` padding waste and no dropped tokens — at the price of
@@ -398,9 +445,9 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     with jax.named_scope("moe_dispatch"):
         xs = _rows_of_tokens(x.astype(dt), rows // k, slot)   # [bound, D]
     with jax.named_scope("moe_experts"):
-        ys = _grouped_ffn(xs, group_sizes, w, dt, kernel)     # [bound, D]
-        # rows past the groups hold whatever the kernel left there
-        ys = jnp.where((jnp.arange(bound) < n_here)[:, None], ys, 0)
+        # [bound, D]; rows past n_here carry no pair and are never read
+        ys = _grouped_ffn(xs, group_sizes, w, dt, kernel,
+                          rows_past_groups=True)
     with jax.named_scope("moe_dispatch"):
         out = _weighted_sum_of_rows(ys, topk_vals, rows, slot)
     out = out.reshape(B, T, D)
@@ -465,7 +512,8 @@ def _wsum_bwd(res, g):
     ys, weights, rows, slot = res
     k = slot.shape[1]
     # a row's cotangent: its token's, times its pair's weight (a row that
-    # carries no pair is cut off by the caller's mask)
+    # carries no pair gets one all the same: ``_grouped_ffn`` cuts it off, by
+    # its mask or by kernels that work only the rows a group holds)
     dys = (g[rows // k].astype(jnp.float32)
            * weights.reshape(-1)[rows][:, None]).astype(ys.dtype)
     gf = g.astype(jnp.float32)

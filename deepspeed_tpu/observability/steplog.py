@@ -167,8 +167,10 @@ class StepProgram:
         self.layer_pattern = None if layer_pattern is None \
             else tuple(layer_pattern)
         #: the grouped expert product the program was traced with: "ragged"
-        #: (``lax.ragged_dot``) or "padded" (its einsum twin, also what
-        #: ``resolve_moe_kernel`` falls to where ragged_dot does not lower);
+        #: (every pair over the sorted rows: the Pallas kernels or
+        #: ``lax.ragged_dot``, see ``moe_grouped_lowerings``) or "padded" (its
+        #: einsum twin, also what ``resolve_moe_kernel`` falls to where
+        #: ragged_dot does not lower);
         #: None for a model without grouped experts
         self.moe_kernel_resolved = moe_kernel_resolved
         #: (first, count, routed) of the experts a layer holds; None for a
@@ -184,6 +186,11 @@ class StepProgram:
         #: "rows"}``, of the newest forward the program's trace lowered; None
         #: where it lowered none, and until the first call
         self.flash_fwd_tiles: Optional[Dict[str, Any]] = None
+        #: the experts' grouped products the program's trace lowered, by the
+        #: lowering each took, ``{"pallas": n, "xla": m}``: a product counts
+        #: once and its backward's two transposes once each
+        #: (``ops/grouped_matmul.py``); None where the trace held none
+        self.moe_grouped_lowerings: Optional[Dict[str, int]] = None
         self.built_at = time.perf_counter()
         self._fn = weakref.ref(fn)
         self._mesh = mesh

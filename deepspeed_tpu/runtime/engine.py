@@ -26,6 +26,7 @@ TPU-native design (NOT a port of the hook/stream machinery):
 from __future__ import annotations
 
 import os
+import sys
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -46,6 +47,14 @@ from deepspeed_tpu.runtime.lr_schedules import LRSchedulerShim, build_schedule
 from deepspeed_tpu.runtime.optimizers import build_optimizer
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import ThroughputTimer
+
+
+def _grouped_lowerings() -> Optional[Dict[str, int]]:
+    """``ops/grouped_matmul.py``'s counts of the grouped products traced so
+    far by lowering; None while no expert layer has loaded that module (the
+    engine does not load it: a dense model imports what it always did)."""
+    mod = sys.modules.get("deepspeed_tpu.ops.grouped_matmul")
+    return None if mod is None else mod.lowerings()
 
 
 class DeepSpeedTpuEngine:
@@ -981,6 +990,7 @@ class DeepSpeedTpuEngine:
         if row is not None:
             row.capture(args)
             before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
+            grouped_before = _grouped_lowerings()
         with self._ebus.span("train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
             out = self._fused_step_cache[key](*args)
@@ -990,6 +1000,11 @@ class DeepSpeedTpuEngine:
                 kind: n - before[kind] for kind, n in bwd_lowerings().items()}
             traces, tiles = fwd_tiles()
             row.flash_fwd_tiles = tiles if traces > fwd_before else None
+            grouped = _grouped_lowerings()
+            if grouped != grouped_before:
+                row.moe_grouped_lowerings = {
+                    kind: n - (grouped_before or {}).get(kind, 0)
+                    for kind, n in grouped.items()}
         return out
 
     def _fused_train_step(self, batch):

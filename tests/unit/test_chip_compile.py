@@ -127,6 +127,29 @@ def _ragged_dot():
                 ((8,), jnp.int32)]
 
 
+def _grouped(product, C=2304, O=896, rows=65536, experts=16):
+    """The experts' products at the Mellum2 cell's shapes (BENCHMARK.json:
+    65,536 buffer rows, 16 held experts, widths 2304 and 896), each kernel
+    alone, under the VMEM limit the calls set for themselves."""
+    from deepspeed_tpu.ops import grouped_matmul as gm
+
+    bf = jnp.bfloat16
+    xs, dys, sizes = ((rows, C), bf), ((rows, O), bf), ((experts,), jnp.int32)
+    if product == "tgmm":
+        return gm.tgmm, [xs, dys, sizes]
+    if product == "gmm-transposed":
+        return (lambda d, w, g: gm.gmm(d, w, g, transpose_w=True),
+                [dys, ((experts, C, O), bf), sizes])
+    if product == "gmm-transposed-pair":        # the gate's and the up's, summed
+        return (lambda d1, d2, w1, w2, g: gm.gmm((d1, d2), (w1, w2), g,
+                                                 transpose_w=True),
+                [dys, dys, ((experts, C, O), bf), ((experts, C, O), bf), sizes])
+    # by the rule's answer for the shape, as if on the chip
+    took, _ = gm.grouped_lowering(rows, C, O, experts, bf, tpu=True)
+    return (lambda x, w, g: gm.grouped_matmul(x, w, g, lowering=took),
+            [xs, ((experts, C, O), bf), sizes])
+
+
 # (builder, kwargs, must the compiled program hold a Mosaic kernel?)
 CASES = {
     "flash-fwd-d64": (_flash, dict(d=64, grad=False), True),
@@ -177,6 +200,16 @@ CASES = {
         _qmm, dict(bits=8, din=14336, f=4096, rows=256), False),
     "rms-pallas": (_rms, {}, True),
     "ragged-dot": (_ragged_dot, {}, True),
+    "grouped-gmm-gate-up": (_grouped, dict(product="gmm"), True),
+    "grouped-gmm-down": (_grouped, dict(product="gmm", C=896, O=2304), True),
+    "grouped-gmm-transposed-gate-up": (
+        _grouped, dict(product="gmm-transposed"), True),
+    "grouped-gmm-transposed-down": (
+        _grouped, dict(product="gmm-transposed", C=896, O=2304), True),
+    "grouped-gmm-transposed-gate-and-up": (
+        _grouped, dict(product="gmm-transposed-pair"), True),
+    "grouped-tgmm-gate-up": (_grouped, dict(product="tgmm"), True),
+    "grouped-tgmm-down": (_grouped, dict(product="tgmm", C=896, O=2304), True),
 }
 
 
@@ -245,6 +278,42 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     assert tiles["rows"]
     if T == 4096:       # the cells: 4 x 4 tiles of 1024 a head, window inert
         assert tiles == {"masked": 4, "unmasked": 6, "dead": 6, "rows": True}
+
+
+@pytest.mark.parametrize("width,took", [(2304, "pallas"), (2300, "xla")])
+def test_the_gated_ffns_gradient_compiles_for_v5e(one_chip, width, took):
+    """The experts' FFN of the Mellum2 cell, forward and backward: nine
+    grouped products, every one a Mosaic call and none a ragged-dot; with a
+    width that is no multiple of 128, ``lax.ragged_dot`` as before."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+    from deepspeed_tpu.ops import grouped_matmul as gm
+
+    rows, E, F = 65536, 16, 896
+    live = (jnp.arange(rows) < 32768)[:, None]
+
+    def loss(xs, w, sizes):
+        ys = sm._grouped_ffn(xs, sizes, w, jnp.bfloat16, "ragged",
+                             interpret=False)
+        return (jnp.where(live, ys, 0).astype(jnp.float32) ** 2).sum()
+
+    def arg(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    w = {"w_gate": arg((E, width, F)), "w_up": arg((E, width, F)),
+         "w_down": arg((E, F, width))}
+    before = gm.lowerings()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        arg((rows, width), jnp.bfloat16), w, arg((E,), jnp.int32)
+    ).compile().as_text()
+    said = {k: n - before[k] for k, n in gm.lowerings().items()}
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    if took == "pallas":
+        # nine products in eight calls: the gate's and the up projection's
+        # cotangents of the rows are one gmm over both stacks
+        assert said == {"pallas": 9, "xla": 0} and calls == 8
+        assert "ragged-dot" not in text
+    else:
+        assert said == {"pallas": 0, "xla": 3} and "ragged-dot" in text
 
 
 def test_kernel_path_rules_match_what_compiled():

@@ -302,11 +302,46 @@ def test_the_step_record_carries_the_routers_counts():
     prog = steplog.programs()[-1]
     assert prog.layer_pattern == ("window", "window", "window", "full")
     assert prog.moe_kernel_resolved == "ragged"
+    # float32 at a width of 64 on a CPU: every product is lax.ragged_dot
+    # (three a layer; its transposes are autodiff's and are not counted)
+    assert prog.moe_grouped_lowerings == {"pallas": 0, "xla": 12}
     assert prog.experts_held == (2, 4, 8)
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
     assert dense.step_program_facts() == {"layer_applications": 4,
                                           "layer_pattern": ("full",)}
+
+
+def test_the_cell_shaped_step_program_takes_the_kernels(monkeypatch):
+    """What the benchmark's cell is in small: bf16, whole-lane widths, a tile
+    of rows for each held expert, no recomputation. ``ragged`` still names
+    the algebra, and every grouped product of the program, nine a layer, is
+    the Pallas kernel (interpreted here: the CPU stands in for the chip)."""
+    import functools
+
+    import deepspeed_tpu as ds
+    from deepspeed_tpu.moe import sharded_moe as sm
+    from deepspeed_tpu.observability import steplog
+    from deepspeed_tpu.parallel import build_mesh
+
+    monkeypatch.setattr(sm, "_grouped_ffn", functools.partial(
+        sm._grouped_ffn, interpret=True))
+    hf = hf_config(L=4, D=128, F=128)
+    model = model_for(hf, dtype="bfloat16", max_seq_len=128)
+    eng, *_ = ds.initialize(
+        model=model,
+        config={"train_micro_batch_size_per_gpu": 2,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "steps_per_print": 10 ** 9, "zero_optimization": {"stage": 0}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    rows = np.random.default_rng(1).integers(0, 96, (2, 128)).astype(np.int32)
+    assert np.isfinite(float(eng.fused_train_step({"input_ids": rows})))
+    prog = steplog.programs()[-1]
+    assert prog.moe_kernel_resolved == "ragged"
+    assert prog.moe_grouped_lowerings == {"pallas": 36, "xla": 0}
+    row = steplog.get_steplog().parts(last=1)[-1]
+    assert row["pairs_dropped"].tolist() == [0] * 4
+    assert row["expert_pairs"].sum() == row["pairs_here"].sum() > 0
 
 
 # ---- the published config -------------------------------------------------
