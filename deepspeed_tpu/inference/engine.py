@@ -117,7 +117,7 @@ class InferenceEngine:
     def __init__(self, model: TransformerLM, config=None, params=None,
                  topology: Optional[Topology] = None, dtype=None,
                  max_seq_len: Optional[int] = None, **kw):
-        if getattr(model.cfg, "looped", False):
+        if hasattr(model, "_one_pass_only"):
             model._one_pass_only("InferenceEngine (one key-value cache a "
                                  "layer)")
         self.module = model

@@ -83,9 +83,10 @@ class InferenceEngineV2:
         from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
         place_compile_cache()
-        if getattr(model.cfg, "looped", False):
-            # before any pool is built: R caches a layer and early exit are
-            # not here (PERF.md, open questions)
+        if hasattr(model, "_one_pass_only"):
+            # before any pool is built: R caches a layer and early exit, or
+            # a recurrent state beside the pages, are not here (PERF.md,
+            # open questions)
             model._one_pass_only("InferenceEngineV2 (one key-value cache a "
                                  "layer, one set of logits a token)")
         self.module = model

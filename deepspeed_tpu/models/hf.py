@@ -53,9 +53,14 @@ OURO_TENSORS = {
     "model.early_exit_gate.weight": ("exit_gate", "w"),     # [1, D] there
     "model.early_exit_gate.bias": ("exit_gate", "b"),       # [1] there
 }
-_SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum")
-#: HF ``layer_types`` / ``rope_parameters`` names -> attention kinds here
-_HF_KINDS = {"sliding_attention": "window", "full_attention": "full"}
+_SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
+                              "granitemoehybrid")
+#: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
+_HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
+             "attention": "full", "mamba": "ssm"}
+#: types whose config maps (config_from_hf) and whose checkpoint does not
+#: load: no description of the tensor names was at hand, and none is guessed
+_CONFIG_ONLY = ("mellum", "granitemoehybrid")
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
            "gelu_pytorch_tanh": "gelu", "gelu_fast": "gelu", "relu": "relu"}
@@ -162,6 +167,49 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             top_k=get("num_experts_per_tok"),
             moe_intermediate_size=get("moe_intermediate_size"),
             moe_dispatch="grouped", moe_aux_loss_coef=0.001,
+        )
+    elif model_type == "granitemoehybrid":
+        # Mamba-2 state-space layers and attention layers in turn
+        # (``layer_types``), every FFN the dense "shared" SwiGLU MLP, the
+        # family's four multipliers. The config side only, and only the
+        # siblings without routed experts.
+        if int(get("num_local_experts", 0) or 0) > 0:
+            raise ValueError(
+                "granitemoehybrid with routed experts (num_local_experts="
+                f"{get('num_local_experts')}) is not mapped: only the "
+                "siblings whose every FFN is the shared MLP")
+        if get("attention_bias", False) or get("mamba_proj_bias", False) \
+                or not get("mamba_conv_bias", True):
+            raise ValueError("granitemoehybrid with attention or projection "
+                             "biases, or without the convolution's bias, is "
+                             "not mapped")
+        L = get("num_hidden_layers")
+        pos = get("position_embedding_type", "nope")
+        if pos not in ("nope", "rope"):
+            raise ValueError(f"position_embedding_type {pos!r} is not mapped")
+        heads = get("mamba_n_heads")
+        if heads * get("mamba_d_head") \
+                != get("mamba_expand") * get("hidden_size"):
+            raise ValueError("mamba_n_heads x mamba_d_head is not "
+                             "mamba_expand x hidden_size")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            intermediate_size=get("shared_intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-5)),
+            tie_embeddings=bool(get("tie_word_embeddings", True)),
+            use_rope=pos == "rope",
+            rope_theta=float(get("rope_theta", 10000.0)),
+            attn_pattern=tuple(_HF_KINDS[k] for k in get("layer_types")[:L]),
+            ssm_heads=heads, ssm_head_dim=get("mamba_d_head"),
+            ssm_state=get("mamba_d_state"), ssm_groups=get("mamba_n_groups"),
+            ssm_conv=get("mamba_d_conv"), ssm_chunk=get("mamba_chunk_size"),
+            attention_multiplier=float(get("attention_multiplier")),
+            embedding_multiplier=float(get("embedding_multiplier")),
+            residual_multiplier=float(get("residual_multiplier")),
+            logits_scaling=float(get("logits_scaling")),
         )
     elif model_type == "falcon":
         if get("alibi", False):
@@ -542,9 +590,10 @@ def load_hf_checkpoint(path: str, cfg: Optional[TransformerConfig] = None,
     """
     with open(os.path.join(path, "config.json")) as f:
         hf_cfg = json.load(f)
-    if hf_cfg.get("model_type") == "mellum":
+    if hf_cfg.get("model_type") in _CONFIG_ONLY:
         raise NotImplementedError(
-            "model_type 'mellum': the config maps onto TransformerConfig "
+            f"model_type {hf_cfg['model_type']!r}: the config maps onto "
+            "TransformerConfig "
             "(config_from_hf), but no description of the checkpoint's tensor "
             "names was at hand when this was written, and none is guessed: "
             "importing its weights is not supported")

@@ -61,12 +61,33 @@ class TransformerConfig:
     parallel_shared_norm: bool = False  # falcon-7b: one ln feeds both branches
     rope_pct: float = 1.0         # gpt-neox partial rotary (rotary_pct)
     sliding_window: Optional[int] = None  # mistral/qwen2 windowed attention
-    # the period of attention kinds over the layers, each "window" (the last
-    # ``sliding_window`` keys) or "full": layer i is of kind
-    # attn_pattern[i % len]. None = every layer the one kind (windowed where
-    # sliding_window is set). HF qwen2's leading run of n full layers is
-    # ("full",) * n + ("window",) * (L - n): a period of the whole stack
+    # the period of layer kinds over the layers: "window" (attention over the
+    # last ``sliding_window`` keys), "full" (attention over every key) or
+    # "ssm" (the mixer is no attention but a Mamba-2 state-space layer of the
+    # ``ssm_*`` sizes below, models/mamba.py): layer i is of kind
+    # attn_pattern[i % len]. None = every layer the one attention kind
+    # (windowed where sliding_window is set). HF qwen2's leading run of n
+    # full layers is ("full",) * n + ("window",) * (L - n): a period of the
+    # whole stack
     attn_pattern: Optional[Tuple[str, ...]] = None
+    # a state-space layer: heads of ``ssm_head_dim`` channels (inner width =
+    # heads x head_dim), a state of ``ssm_state`` a channel, B and C shared
+    # by the heads of each of ``ssm_groups`` groups, a causal depthwise
+    # convolution over ``ssm_conv`` positions, the scan in chunks of
+    # ``ssm_chunk`` (ops/ssd_scan.py)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # the softmax scale of attention (None = 1/sqrt(head_dim)), what the
+    # embedding's rows and each branch's output are multiplied by, and what
+    # the logits are divided by (the Granite family's four multipliers)
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # HF-style rope_scaling dict ({"rope_type": "llama3"|"linear"|"yarn",
     # ...}); None = unscaled
     rope_scaling: Optional[Dict[str, Any]] = None
@@ -172,11 +193,12 @@ class TransformerConfig:
                              "loss) needs num_passes >= 2")
         if self.attn_pattern is not None:
             pat = tuple(self.attn_pattern)
-            if not pat or set(pat) - {"window", "full"} \
+            if not pat or set(pat) - {"window", "full", "ssm"} \
                     or self.num_layers % len(pat):
                 raise ValueError(
-                    f"attn_pattern={pat}: a period of 'window' / 'full' "
-                    f"whose length divides num_layers={self.num_layers}")
+                    f"attn_pattern={pat}: a period of 'window' / 'full' / "
+                    f"'ssm' whose length divides num_layers="
+                    f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
                 raise ValueError("attn_pattern has window layers and "
                                  "sliding_window is not set")
@@ -185,6 +207,20 @@ class TransformerConfig:
             p = next(p for p in range(1, len(pat) + 1) if len(pat) % p == 0
                      and pat == pat[:p] * (len(pat) // p))
             object.__setattr__(self, "attn_pattern", pat[:p])
+        if self.has_ssm:
+            if self.ssm_heads < 1 or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    f"a state-space layer needs ssm_heads={self.ssm_heads} "
+                    f"> 0, a multiple of ssm_groups={self.ssm_groups}")
+            if (self.looped or self.num_experts > 1 or self.parallel_block
+                    or self.loss_tiling > 1 or self.attention_impl == "fpdt"):
+                raise NotImplementedError(
+                    "a model with state-space layers (attn_pattern holds "
+                    "'ssm') runs one pre-norm pass with dense FFNs, whole "
+                    "logits and whole-sequence attention: not num_passes > 1, "
+                    "sandwich_norm, the exit gate, num_experts > 1, "
+                    "parallel_block, loss_tiling > 1 or attention_impl="
+                    "'fpdt'")
         if self.moe_experts_held is not None:
             lo, n = self.moe_first_expert, self.moe_experts_held
             if not (n >= 1 and lo >= 0 and lo + n <= self.num_experts):
@@ -214,16 +250,22 @@ class TransformerConfig:
                 or self.exit_loss_beta is not None)
 
     @property
+    def has_ssm(self) -> bool:
+        """Whether any layer's mixer is a state-space layer."""
+        return "ssm" in (self.attn_pattern or ())
+
+    @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The attention kind of every layer, "window" or "full"."""
+        """The kind of every layer: "window", "full" or "ssm"."""
         pat = self.attn_pattern or (
             ("full",) if self.sliding_window is None else ("window",))
         return pat * (self.num_layers // len(pat))
 
     @property
     def patterned(self) -> bool:
-        """Whether the layers are of more than one attention kind."""
-        return len(set(self.layer_kinds)) > 1
+        """Whether the layers are of more than one kind, or of one that is no
+        attention: the layer loop then runs by kind (``_run_periods``)."""
+        return len(set(self.layer_kinds)) > 1 or self.has_ssm
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -257,12 +299,18 @@ class TransformerConfig:
         per_layer = attn + mlp + 2 * norms
         if self.sandwich_norm:
             per_layer += norms   # the two post-branch scales, counted once
+        ssm_extra = 0
+        if self.has_ssm:
+            from deepspeed_tpu.models import mamba
+
+            ssm_extra = self.layer_kinds.count("ssm") \
+                * (mamba.num_params(self) - attn)
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
         head = 0 if self.tie_embeddings else D * V
         gate = D + 1 if self.exit_loss_beta is not None else 0
         # passes share their weights: the count does not grow with them
         # (models/spec.py:model_flops_per_token multiplies the work)
-        return L * per_layer + embed + head + D + gate
+        return L * per_layer + ssm_extra + embed + head + D + gate
 
 
 # ---------------------------------------------------------------------------
@@ -581,6 +629,10 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     if cfg.use_rope:
         q = apply_rope(q, freqs, positions, rope_scale)
         k = apply_rope(k, freqs, positions, rope_scale)
+    if cfg.attention_multiplier is not None:
+        # the kernels scale the scores by 1/sqrt(d); q carries the rest
+        # (Granite's 1/64 at d = 64: a factor of 1/8, exact in bf16)
+        q = _times(q, cfg.attention_multiplier * math.sqrt(hd))
     if cfg.sliding_window is not None:
         # windowed families (mistral/qwen2): the flash kernel takes the
         # window natively (block-skipping); impls without window support
@@ -687,21 +739,43 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # are of more than one), the grouped expert layer's parts
                # inside moe (moe/sharded_moe.py)
                "attn_window", "attn_full",
-               "moe_router", "moe_dispatch", "moe_experts")
+               "moe_router", "moe_dispatch", "moe_experts",
+               # a state-space layer's parts inside attn, the token mixer's
+               # slot (models/mamba.py)
+               "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
 #: a period of up to this many blocks is the body of one scan over periods;
-#: a longer aperiodic list of kinds is cut into runs of one kind
+#: a longer list of kinds is cut into runs of one kind
 _MAX_PERIOD = 8
+#: a state-space layer's leaves that stay float32 in the compute copy of the
+#: weights: they enter an exponential or a softplus, never a matmul
+_SSM_FP32 = ("A_log", "dt_bias", "D")
+#: the group of ``params["layers"]`` that holds a kind's mixer; its stack has
+#: one row for each layer of the group's kinds, in layer order. Every other
+#: group (norms, FFN) has a row for every layer
+_MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm"}
+
+
+def _times(x: jax.Array, factor: float) -> jax.Array:
+    """``x * factor`` with the factor at full precision (0.22 is no bf16
+    number): the product in float32, rounded once to ``x``'s dtype."""
+    return (x.astype(jnp.float32) * factor).astype(x.dtype)
 
 
 def _cast_layers(w: Params, dt, ffn: str) -> Params:
     """fp32 master weights of one block (or the whole stack) to the compute
     dtype, each under the scope of the block that reads it."""
+    def cast(p):
+        return p.astype(dt) if p.dtype == jnp.float32 else p
+
     out = {}
     for k, v in w.items():
-        with jax.named_scope("attn" if k in ("ln1", "attn", "ln1_post")
+        with jax.named_scope("attn" if k in ("ln1", "attn", "ssm", "ln1_post")
                              else ffn):
-            out[k] = jax.tree_util.tree_map(
-                lambda p: p.astype(dt) if p.dtype == jnp.float32 else p, v)
+            if k == "ssm":
+                out[k] = {n: p if n in _SSM_FP32 else cast(p)
+                          for n, p in v.items()}
+            else:
+                out[k] = jax.tree_util.tree_map(cast, v)
     return out
 
 
@@ -709,13 +783,18 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                       freqs: Optional[jax.Array], attn_fn: Callable,
                       moe_fn: Optional[Callable] = None,
                       positions: Optional[jax.Array] = None,
-                      kind: Optional[str] = None) -> Any:
+                      kind: Optional[str] = None,
+                      mix_ms: bool = False) -> Any:
     """One pre-norm decoder block. Returns (x, aux_loss). ``positions`` [B, T]
     overrides RoPE positions (random-LTD token subsets). With
     ``cfg.sandwich_norm`` each branch's output is normed again before its
     residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(FFN(N3(a)))``.
-    ``kind`` names the layer's attention kind in a model that has several:
-    its operations then lie under ``attn/attn_<kind>``."""
+    ``kind`` names the layer's kind in a model that has several: an
+    attention layer's operations then lie under ``attn/attn_<kind>``; a
+    layer of kind "ssm" mixes its tokens with ``w["ssm"]``
+    (models/mamba.py:ssm_block) under ``attn/ssm_*``. With ``mix_ms`` the aux
+    value is a dict that also holds the mean square of the mixer's output
+    (``mix_out_ms``)."""
     # named scopes land in HLO op metadata — the per-module profiler
     # (profiling/flops_profiler.per_module_profile) and the benchmark's
     # device-time-by-scope reader group cost by them. Every operation of the
@@ -723,13 +802,25 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     # residual adds and the second norm with the FFN.
     ffn = "moe" if moe_fn is not None else "mlp"
     wc = _cast_layers(w, jnp.dtype(cfg.dtype), ffn)
-    with jax.named_scope("attn"), (jax.named_scope("attn_" + kind) if kind
+    res = cfg.residual_multiplier
+    with jax.named_scope("attn"), (jax.named_scope("attn_" + kind)
+                                   if kind and kind != "ssm"
                                    else contextlib.nullcontext()):
         hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
-        attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
-                                   positions=positions)
+        if kind == "ssm":
+            from deepspeed_tpu.models.mamba import ssm_block
+
+            attn_out = constrain(ssm_block(hn1, wc["ssm"], cfg),
+                                 P(("dp", "fsdp"), "sp", None))
+        else:
+            attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
+                                       positions=positions)
         if cfg.sandwich_norm:
             attn_out = _norm(attn_out, wc["ln1_post"], cfg.norm, cfg.norm_eps)
+        if mix_ms:
+            ms = jnp.mean(jnp.square(attn_out.astype(jnp.float32)))
+        if res != 1.0:
+            attn_out = _times(attn_out, res)
     with jax.named_scope(ffn):
         if cfg.parallel_block:
             # falcon/gpt-neox: attn and mlp branch from the SAME residual
@@ -746,6 +837,10 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             aux = jnp.zeros((), jnp.float32)
         if cfg.sandwich_norm:
             mlp_out = _norm(mlp_out, wc["ln2_post"], cfg.norm, cfg.norm_eps)
+        if res != 1.0:
+            mlp_out = _times(mlp_out, res)
+        if mix_ms:
+            aux = {"lb": aux, "mix_out_ms": ms}
         x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
         return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
 
@@ -864,6 +959,45 @@ def _block_of(xs, j: int, p: int):
     return xs if p == 1 else jax.tree_util.tree_map(lambda a: a[j], xs)
 
 
+def _in_group(kinds, grp: str) -> int:
+    """How many of ``kinds`` keep their mixer in the group ``grp``."""
+    return sum(_MIXER_GROUP[k] == grp for k in kinds)
+
+
+def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
+    """Layers ``[lo, hi)`` of the stacks as the scan input of a loop over
+    periods of the kinds ``period``, group by group (:func:`_by_period`): a
+    mixer's group is cut to the rows of its own layers among them (a group
+    none of the period's kinds reads is left out), every other group to the
+    layers themselves."""
+    out = {}
+    for grp in sorted(layers):
+        if grp in _MIXER_GROUP.values():
+            n = _in_group(period, grp)
+            if n:
+                first = _in_group(kinds[:lo], grp)
+                out[grp] = _by_period(
+                    layers[grp], first, first + _in_group(kinds[lo:hi], grp),
+                    n)
+        else:
+            out[grp] = _by_period(layers[grp], lo, hi, len(period))
+    return out
+
+
+def _block_weights(xs: Params, j: int, period) -> Params:
+    """Block ``j``'s weights out of one period's scan input
+    (:func:`_segment`): its own mixer's group and every shared group."""
+    mine = _MIXER_GROUP[period[j]]
+    out = {}
+    for grp in sorted(xs):
+        if grp not in _MIXER_GROUP.values():
+            out[grp] = _block_of(xs[grp], j, len(period))
+        elif grp == mine:
+            out[grp] = _block_of(xs[grp], _in_group(period[:j], grp),
+                                 _in_group(period, grp))
+    return out
+
+
 def _stacked(trees: list):
     """A list of like pytrees as one, its leaves stacked on a new axis."""
     return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
@@ -926,9 +1060,27 @@ class TransformerLM:
         self._pld_depth: Optional[int] = None
 
     def _one_pass_only(self, what: str) -> None:
-        """Raise for a looped model on a path written for one pass over a
-        pre-norm stack (``cfg.looped``): nothing falls back to one pass."""
+        """Raise on a path written for one pass over a pre-norm stack of
+        attention layers: for a looped model (``cfg.looped``: nothing falls
+        back to one pass), for one with a state-space layer (no path but the
+        train step keeps a recurrent state) and for one with the Granite
+        multipliers (only ``transformer_block`` and the train forward apply
+        them)."""
         cfg = self.cfg
+        if cfg.has_ssm:
+            raise NotImplementedError(
+                f"{what} is written for attention layers: this model has "
+                f"state-space layers (attn_pattern={cfg.attn_pattern}), "
+                f"whose recurrent and convolution state it would have to "
+                f"keep beside the key-value cache; only the train step runs "
+                f"them")
+        if (cfg.attention_multiplier is not None
+                or (cfg.embedding_multiplier, cfg.residual_multiplier,
+                    cfg.logits_scaling) != (1.0, 1.0, 1.0)):
+            raise NotImplementedError(
+                f"{what} does not apply attention_multiplier, "
+                f"embedding_multiplier, residual_multiplier or "
+                f"logits_scaling; only the train step does")
         if cfg.looped:
             raise NotImplementedError(
                 f"{what} runs the layer stack once, pre-norm, with one set of "
@@ -969,6 +1121,8 @@ class TransformerLM:
         facts: Dict[str, Any] = {
             "layer_applications": self.layer_applications,
             "layer_pattern": cfg.attn_pattern or cfg.layer_kinds[:1]}
+        if cfg.has_ssm:
+            facts["ssm_chunk"] = cfg.ssm_chunk
         if cfg.num_experts > 1:
             facts["experts_held"] = (
                 cfg.moe_first_expert if cfg.moe_experts_held else 0,
@@ -979,6 +1133,18 @@ class TransformerLM:
                 facts["moe_kernel_resolved"] = resolve_moe_kernel(
                     cfg.moe_kernel)[0]
         return facts
+
+    def ssm_chunks_scanned(self, batch_shape) -> Optional[int]:
+        """Chunks the state-space layers of one step's forward scan over a
+        batch of ``batch_shape`` [rows, T]: layers x rows x ceil(T / chunk)
+        (the step-program table's ``ssm_chunks_per_step``); None for a model
+        without such a layer."""
+        cfg = self.cfg
+        if not cfg.has_ssm:
+            return None
+        rows, T = batch_shape
+        return (cfg.layer_kinds.count("ssm") * int(rows)
+                * -(-int(T) // cfg.ssm_chunk))
 
     # ---- init -------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
@@ -991,24 +1157,27 @@ class TransformerLM:
         def dense(key, fan_in, shape):
             return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in))
 
-        def layer_stack(key, fan_in, shape):
-            return dense(key, fan_in, (L,) + shape)
+        def layer_stack(key, fan_in, shape, n=L):
+            return dense(key, fan_in, (n,) + shape)
 
         norm_w = {"scale": jnp.ones((L, D), pd)}
         if cfg.norm == "layernorm":
             norm_w["bias"] = jnp.zeros((L, D), pd)
+        # one stack of mixer leaves for each kind of mixer, a row for each
+        # layer of that kind (every layer's, where all are attention)
+        La = _in_group(cfg.layer_kinds, "attn")
         attn_w = {
-            "wq": layer_stack(keys[1], D, (D, H * hd)),
-            "wk": layer_stack(keys[2], D, (D, K * hd)),
-            "wv": layer_stack(keys[10], D, (D, K * hd)),
-            "wo": layer_stack(keys[3], H * hd, (H * hd, D)),
+            "wq": layer_stack(keys[1], D, (D, H * hd), La),
+            "wk": layer_stack(keys[2], D, (D, K * hd), La),
+            "wv": layer_stack(keys[10], D, (D, K * hd), La),
+            "wo": layer_stack(keys[3], H * hd, (H * hd, D), La),
         }
         if cfg.qkv_bias:
-            attn_w["bq"] = jnp.zeros((L, H * hd), pd)
-            attn_w["bk"] = jnp.zeros((L, K * hd), pd)
-            attn_w["bv"] = jnp.zeros((L, K * hd), pd)
+            attn_w["bq"] = jnp.zeros((La, H * hd), pd)
+            attn_w["bk"] = jnp.zeros((La, K * hd), pd)
+            attn_w["bv"] = jnp.zeros((La, K * hd), pd)
         if cfg.proj_bias:
-            attn_w["bo"] = jnp.zeros((L, D), pd)
+            attn_w["bo"] = jnp.zeros((La, D), pd)
         mlp = ({"w_gate": layer_stack(keys[4], D, (D, F)),
                 "w_up": layer_stack(keys[5], D, (D, F)),
                 "w_down": layer_stack(keys[6], F, (F, D))}
@@ -1031,6 +1200,13 @@ class TransformerLM:
                     "w_down": layer_stack(keys[6], F, (Eh, F, D))})
             mlp["router"] = layer_stack(keys[7], D, (D, E))
         layers: Params = {"ln1": dict(norm_w), "attn": attn_w, "mlp": mlp}
+        if cfg.has_ssm:
+            from deepspeed_tpu.models import mamba
+
+            layers["ssm"] = mamba.init(jax.random.fold_in(rng, 12), cfg,
+                                       L - La, pd)
+            if not La:
+                del layers["attn"]
         if not cfg.parallel_shared_norm:
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         if cfg.sandwich_norm:
@@ -1075,6 +1251,8 @@ class TransformerLM:
         """hidden [B, T, D] → logits [B, T, V] with the canonical sharding."""
         with jax.named_scope("lm_head"):
             logits = self._head_proj(params, hidden)
+            if self.cfg.logits_scaling != 1.0:
+                logits = _times(logits, 1.0 / self.cfg.logits_scaling)
             return constrain(logits, P(("dp", "fsdp"), "sp", "tp"))
 
     def logits(self, params: Params, input_ids: jax.Array,
@@ -1095,7 +1273,10 @@ class TransformerLM:
         run of full layers before the windowed ones) is cut into runs of one
         kind. Each block runs under its kind's own static config
         (``self._kinds``), so a window layer keeps the tile-skipping kernels
-        and a full layer pays no window mask."""
+        and a full layer pays no window mask. A run reads its kind's own
+        stack of mixer leaves (:func:`_segment`): nine state-space layers to
+        one attention layer, a period of ten, are at 40 layers nine runs
+        (nine block bodies traced, where a period body would trace ten)."""
         kinds = self.cfg.layer_kinds
         L = len(kinds)
         period = self.cfg.attn_pattern or kinds[:1]
@@ -1132,6 +1313,8 @@ class TransformerLM:
             self._one_pass_only("progressive layer drop")
         with jax.named_scope("embed"):
             x = params["embed"]["tokens"].astype(dt)[input_ids]
+            if cfg.embedding_multiplier != 1.0:
+                x = _times(x, cfg.embedding_multiplier)
             if cfg.learned_pos:
                 T = input_ids.shape[1]
                 pos_emb = (params["embed"]["pos"][:T] if positions is None
@@ -1274,12 +1457,12 @@ class TransformerLM:
                 partial(self._kind_block, kind, attn_fn), cfg.remat_policy)
                 for kind in period]
             p = len(period)
-            seg = _by_period(layers, lo, hi, p)
+            seg = _segment(layers, cfg.layer_kinds, lo, hi, period)
 
-            def body(carry, xs, _blocks=blocks, p=p):
+            def body(carry, xs, _blocks=blocks, period=period, p=p):
                 auxes = []
                 for j, blk in enumerate(_blocks):
-                    carry, aux = blk(carry, _block_of(xs, j, p))
+                    carry, aux = blk(carry, _block_weights(xs, j, period))
                     auxes.append(aux)
                 return carry, _stack_blocks(auxes, p)
 
@@ -1300,7 +1483,7 @@ class TransformerLM:
                     w: Params):
         ck, freqs = self._kinds[kind]
         return transformer_block(x, w, ck, freqs, attn_fn, self.moe_fn,
-                                 kind=kind)
+                                 kind=kind, mix_ms=self.cfg.has_ssm)
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -1358,6 +1541,9 @@ class TransformerLM:
                 loss = (self._tiled_loss(params, batch, hs[-1])
                         if logits is None else lm_loss(cfg, logits, batch))
             parts = {}
+        if cfg.has_ssm:
+            # by layer, the mean square of the mixer's output
+            parts = {**parts, "mix_out_ms": aux["mix_out_ms"]}
         if cfg.num_experts > 1:
             with jax.named_scope("loss"):
                 if isinstance(aux, dict):
@@ -1818,6 +2004,12 @@ class TransformerLM:
         if cfg.proj_bias:
             attn_spec["bo"] = P(None, None)
         layer_specs: Params = {"ln1": norm_spec, "attn": attn_spec, "mlp": mlp}
+        if cfg.has_ssm:
+            from deepspeed_tpu.models import mamba
+
+            layer_specs["ssm"] = mamba.param_specs()
+            if not _in_group(cfg.layer_kinds, "attn"):
+                del layer_specs["attn"]
         if not cfg.parallel_shared_norm:
             layer_specs["ln2"] = dict(norm_spec)
         if cfg.sandwich_norm:

@@ -14,7 +14,9 @@ engine.
   ``ds.gc`` profiler annotation. A write stores floats into the ring: nothing
   outlives the step and nothing is collector-tracked.
 * ``loss_parts`` — for a model whose loss has parts (a looped model's
-  per-pass losses and exit distribution): the last ``PARTS_KEPT`` steps'
+  per-pass losses and exit distribution, a held share of experts' router
+  counts, by layer the mean square of the mixer's output of a model with
+  state-space layers): the last ``PARTS_KEPT`` steps'
   loss and parts as the device values the step program returned. Writing a
   row waits for nothing; :meth:`StepLog.parts` reads them to the host when a
   reader asks, after the step.
@@ -156,14 +158,16 @@ class StepProgram:
                  layer_applications: Optional[int] = None,
                  layer_pattern: Optional[Sequence[str]] = None,
                  moe_kernel_resolved: Optional[str] = None,
-                 experts_held: Optional[Sequence[int]] = None):
+                 experts_held: Optional[Sequence[int]] = None,
+                 ssm_chunk: Optional[int] = None):
         self.name = name
         self.key = str(key)
         #: block applications one micro-batch's forward holds (layers run x
         #: passes over them); None where the model does not say
         self.layer_applications = layer_applications
-        #: the period of attention kinds the layer loop scans ("window" /
-        #: "full"); None where the model does not say
+        #: the period of layer kinds the layer loop scans ("window" / "full"
+        #: attention, "ssm" a state-space layer); None where the model does
+        #: not say
         self.layer_pattern = None if layer_pattern is None \
             else tuple(layer_pattern)
         #: the grouped expert product the program was traced with: "ragged"
@@ -191,6 +195,12 @@ class StepProgram:
         #: once and its backward's two transposes once each
         #: (``ops/grouped_matmul.py``); None where the trace held none
         self.moe_grouped_lowerings: Optional[Dict[str, int]] = None
+        #: the chunk length of the state-space layers' scan, and the chunks
+        #: one step's forward scans (state-space layers x rows x ceil(T /
+        #: chunk), from the batch of the program's first call); None for a
+        #: model without such a layer
+        self.ssm_chunk = ssm_chunk
+        self.ssm_chunks_per_step: Optional[int] = None
         self.built_at = time.perf_counter()
         self._fn = weakref.ref(fn)
         self._mesh = mesh

@@ -264,6 +264,8 @@ class DeepSpeedTpuEngine:
             self._pld_tiers = int(config.progressive_layer_drop
                                   .compiled_tiers)
             if self._pld_tiers > 0:
+                if hasattr(self.module, "_one_pass_only"):
+                    self.module._one_pass_only("progressive layer drop")
                 if getattr(getattr(self.module, "cfg", None),
                            "patterned", False):
                     # the static-depth slice would silently no-op under the
@@ -996,6 +998,11 @@ class DeepSpeedTpuEngine:
             out = self._fused_step_cache[key](*args)
         self._t_dispatched = time.perf_counter()
         if row is not None:     # the program's first call: it was traced now
+            if row.ssm_chunk is not None:
+                row.ssm_chunks_per_step = next(
+                    (self.module.ssm_chunks_scanned(a["input_ids"].shape)
+                     for a in args
+                     if isinstance(a, dict) and "input_ids" in a), None)
             row.flash_bwd_lowerings = {
                 kind: n - before[kind] for kind, n in bwd_lowerings().items()}
             traces, tiles = fwd_tiles()

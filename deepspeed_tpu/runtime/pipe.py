@@ -82,6 +82,10 @@ class PipelineModule:
         if model.cfg.num_layers % num_stages != 0:
             raise ValueError(f"num_layers={model.cfg.num_layers} not divisible by "
                              f"pipeline stages={num_stages}")
+        # a stage runs its slice of the stack once a micro-batch, through
+        # attention layers alone: a looped model sends the last stage's
+        # output back to the first, a state-space layer has no block here
+        model._one_pass_only("pipeline parallelism (PipelineModule)")
         if model.cfg.patterned:
             # every stage runs ONE compiled program with a dynamic stage id,
             # so a per-layer static window or rope cannot be expressed here —
@@ -91,10 +95,6 @@ class PipelineModule:
                 "(attn_pattern: qwen2-style leading full layers, window and "
                 "full layers in turn) are not supported under pipeline "
                 "parallelism")
-        if getattr(model.cfg, "looped", False):
-            # a stage runs its slice of the stack once a micro-batch; a
-            # looped model sends the last stage's output back to the first
-            model._one_pass_only("pipeline parallelism (PipelineModule)")
         if schedule not in ("1f1b", "gpipe"):
             raise ValueError(f"unknown pipe schedule '{schedule}'")
         self.model = model

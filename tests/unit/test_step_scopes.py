@@ -40,9 +40,21 @@ CASES = {
                        "moe_dispatch": "grouped", "moe_intermediate_size": 32,
                        "moe_experts_held": 4, "moe_first_expert": 4,
                        "tie_embeddings": False, "remat_policy": "full"}, 1),
+    # state-space layers and an attention layer in turn under recomputation,
+    # the four multipliers, no rope: the mixer's parts nest inside attn (the
+    # projections, the convolution, the scan, the gated norm), the attention
+    # layer keeps attn_full
+    "hybrid": ({"num_layers": 4, "attn_pattern": ("ssm", "ssm", "full", "ssm"),
+                "use_rope": False, "ssm_heads": 8, "ssm_head_dim": 16,
+                "ssm_state": 16, "ssm_groups": 2, "ssm_chunk": 8,
+                "attention_multiplier": 1 / 64, "embedding_multiplier": 12.0,
+                "residual_multiplier": 0.22, "logits_scaling": 8.0,
+                "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
+NESTED_HYBRID = {"attn_full": "attn", "ssm_proj": "attn", "ssm_conv": "attn",
+                 "ssm_scan": "attn", "ssm_gate": "attn"}
 
 
 def _op_names(overrides, ga):
@@ -76,9 +88,10 @@ def test_every_operation_carries_a_step_scope(case):
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
-    if case == "pattern_share":
-        want |= set(NESTED)
-        for inner, outer in NESTED.items():
+    nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID}.get(case)
+    if nested:
+        want |= set(nested)
+        for inner, outer in nested.items():
             ops = [n for n in names if inner in re.split(r"[/()]", n)]
             assert ops and all(outer in re.split(r"[/()]", n) for n in ops)
     if CASES[case][0].get("loss_tiling", 0) <= 1:
