@@ -17,7 +17,10 @@ ragged``). ``ragged`` names the algebra, not a lowering: each product is the Pal
 kernel of ``ops/grouped_matmul.py`` (forward and both transposes) where its rule finds
 the measured case — a TPU, dense bf16 stacks, whole-lane widths, a tile of rows an
 expert — and ``jax.lax.ragged_dot`` everywhere else (int8 serving stacks, decode steps,
-other backends), exactly as before.
+other backends), exactly as before. Dispatch and combine, the moves between token order
+and that sorted order, take the same answer: the row kernels of ``ops/moe_rows.py``
+(each row that carries a pair copied once, its weight and its sum done in the same pass)
+where the products take the Pallas kernels, ``jnp.take`` everywhere else.
 """
 
 from __future__ import annotations
@@ -301,9 +304,30 @@ def _padded_ffn(xs: jax.Array, group_sizes: jax.Array,
     return jnp.einsum("ne,end->nd", oh, ye)
 
 
+def _stack_names(w) -> Tuple[str, ...]:
+    return ("w_gate", "w_up", "w_down") if _has_gate(w) else ("w_up", "w_down")
+
+
+def _ffn_lowering(rows: int, dtype, w: Dict[str, jax.Array], dt,
+                  interpret: Optional[bool]) -> str:
+    """``grouped_lowering``'s answer for the FFN over ``rows`` buffer rows:
+    one for its products, and for the moves into and out of the buffer."""
+    from deepspeed_tpu.ops.grouped_matmul import grouped_lowering
+
+    names = _stack_names(w)
+    up = w["w_up"] if "w_up" in w else w["w_up_q"]
+    experts, width, inner = up.shape
+    took, _ = grouped_lowering(
+        rows, width, inner, experts, jnp.result_type(dtype, dt),
+        dense=all(name in w for name in names),
+        tpu=None if interpret is None else True)
+    return took
+
+
 def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
                  dt, kernel: str = "ragged", interpret: Optional[bool] = None,
-                 rows_past_groups: bool = False) -> jax.Array:
+                 rows_past_groups: bool = False, fetch=None,
+                 buffer_rows: Optional[int] = None) -> jax.Array:
     """Expert-grouped FFN over tokens sorted by expert. ``kernel="ragged"``
     names the algebra: every row times its own expert's weights, no padding
     to a capacity. Its products take the lowering
@@ -324,37 +348,54 @@ def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
     rows a group holds, forward and backward, whatever the others contain,
     so nothing is masked there; ``ragged_dot`` leaves those rows
     uninitialised and its transposes are given the cotangent as it comes,
-    so its result is masked (and with it the cotangent), as before."""
-    if kernel == "padded":
-        return _padded_ffn(xs, group_sizes, w, dt)
-    # imported here: a model without experts never loads the kernels
-    from deepspeed_tpu.ops.grouped_matmul import (grouped_lowering,
-                                                  grouped_matmul)
+    so its result is masked (and with it the cotangent), as before.
 
-    names = ("w_gate", "w_up", "w_down") if _has_gate(w) \
-        else ("w_up", "w_down")
+    ``fetch``: ``xs`` is then the tokens, and ``fetch(xs)`` the
+    ``buffer_rows`` sorted rows (the row kernels' dispatch). The first
+    products are taken from it under a ``jax.checkpoint`` that keeps their
+    results and the packed tokens but not the rows: the backward fetches
+    them again for the weights' gradients (0.65 ms a layer at the Mellum2
+    cell, against 302 MB a layer kept from the forward to the backward,
+    which XLA, left to itself, rematerialises for a ``jnp.take`` and cannot
+    for a kernel)."""
+    with jax.named_scope("moe_experts"):
+        if kernel == "padded":
+            return _padded_ffn(xs, group_sizes, w, dt)
+    # imported here: a model without experts never loads the kernels
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+
+    names = _stack_names(w)
     stacks = {name: _expert_weight(w, name, dt) for name in names}
-    experts, width, inner = stacks["w_up"].shape
-    took, _ = grouped_lowering(
-        xs.shape[0], width, inner, experts, jnp.result_type(xs.dtype, dt),
-        dense=all(name in w for name in names),
-        tpu=None if interpret is None else True)
+    n_rows = xs.shape[0] if fetch is None else buffer_rows
+    took = _ffn_lowering(n_rows, xs.dtype, w, dt, interpret)
 
     def product(rows, stack):
         return grouped_matmul(rows, stack, group_sizes, lowering=took,
                               interpret=bool(interpret))
 
-    if _has_gate(w):
-        # one call for the two stacks that read xs: its backward sums their
-        # two cotangents of xs inside one kernel
-        gate, up = product(xs, (stacks["w_gate"], stacks["w_up"]))
-        act = jax.nn.silu(gate) * up
-    else:
-        act = jax.nn.gelu(product(xs, stacks["w_up"]), approximate=True)
-    ys = product(act, stacks["w_down"])
-    if rows_past_groups and took == "xla":
-        held = jnp.arange(xs.shape[0]) < group_sizes.sum()
-        ys = jnp.where(held[:, None], ys, 0)
+    def first(src, *first_stacks):
+        rows = src if fetch is None else fetch(src)
+        with jax.named_scope("moe_experts"):
+            return product(rows, first_stacks)
+
+    if fetch is not None:
+        first = jax.checkpoint(
+            first, policy=jax.checkpoint_policies.save_only_these_names(
+                PACKED_TOKENS))
+    # (outside the scope: the fetch inside ``first`` is the dispatch's)
+    # one call for the two stacks that read xs: its backward sums their two
+    # cotangents of xs inside one kernel
+    firsts = first(xs, *(stacks[name] for name in names[:-1]))
+    with jax.named_scope("moe_experts"):
+        if _has_gate(w):
+            gate, up = firsts
+            act = jax.nn.silu(gate) * up
+        else:
+            act = jax.nn.gelu(firsts[0], approximate=True)
+        ys = product(act, stacks["w_down"])
+        if rows_past_groups and took == "xla":
+            held = jnp.arange(n_rows) < group_sizes.sum()
+            ys = jnp.where(held[:, None], ys, 0)
     return ys
 
 
@@ -362,7 +403,8 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
                           valid: Optional[jax.Array] = None, *,
                           kernel: Optional[str] = None,
                           a2a_bits: Optional[int] = None,
-                          a2a_slice: Optional[int] = None
+                          a2a_slice: Optional[int] = None,
+                          interpret: Optional[bool] = None
                           ) -> Tuple[jax.Array, jax.Array]:
     """Dropless sort-based dispatch over grouped GEMMs — the
     ``inference/v2/kernels/cutlass_ops/moe_gemm`` (MegaBlocks-style) analog:
@@ -375,6 +417,13 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     Unlike the capacity path, every (token, expert) pair is computed — no
     ``capacity_factor`` padding waste and no dropped tokens — at the price of
     data-dependent group sizes (static TOTAL shape ``S*k``, so it still jits).
+    Dispatch (tokens into the buffer of sorted pairs) and combine (each
+    token's weighted sum of its pairs' rows) are one-to-one moves, gathers
+    both ways; they take the lowering the FFN's products take
+    (:func:`_moves_lowering`): the row kernels of ``ops/moe_rows.py``, which
+    copy only the rows that carry a pair, each once, and weight or sum them
+    in the same pass, or ``jnp.take``. ``interpret`` is the kernels' test
+    handle (None: ask the backend).
     With a held share of the experts (``cfg.moe_experts_held``) the router,
     the top k and their renormalised weights are the whole model's, only the
     pairs whose expert is here are computed, what the absent experts would
@@ -442,14 +491,25 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         jax.debug.callback(_emit_expert_counts, cnt)
 
     dt = h.dtype
+    how = _moves_lowering(S, bound, w, dt, kernel, interpret)
     with jax.named_scope("moe_dispatch"):
-        xs = _rows_of_tokens(x.astype(dt), rows // k, slot)   # [bound, D]
-    with jax.named_scope("moe_experts"):
-        # [bound, D]; rows past n_here carry no pair and are never read
-        ys = _grouped_ffn(xs, group_sizes, w, dt, kernel,
+        moves = _row_moves(rows, group_sizes, n_here, S, k, how)
+    _DISPATCH_LOWERINGS[how[0]] += 2    # the dispatch, the combine
+
+    def fetch(x):           # [bound, D]
+        with jax.named_scope("moe_dispatch"):
+            return _rows_of_tokens(x, rows // k, slot, moves, how)
+
+    # [bound, D]; rows past n_here carry no pair and are never read
+    if how[0] == "pallas":
+        ys = _grouped_ffn(x.astype(dt), group_sizes, w, dt, kernel,
+                          interpret=interpret, rows_past_groups=True,
+                          fetch=fetch, buffer_rows=bound)
+    else:
+        ys = _grouped_ffn(fetch(x.astype(dt)), group_sizes, w, dt, kernel,
                           rows_past_groups=True)
     with jax.named_scope("moe_dispatch"):
-        out = _weighted_sum_of_rows(ys, topk_vals, rows, slot)
+        out = _weighted_sum_of_rows(ys, topk_vals, rows, slot, moves, how)
     out = out.reshape(B, T, D)
     if not held:
         return out, aux_loss
@@ -463,19 +523,88 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
 # one-to-one map (``rows``: row -> pair, ``slot``: pair -> row), so the
 # transpose of either gather is the other one, not a scatter-add (which a
 # v5e runs about ten times slower than the gather over the same rows).
+# One algorithm, two lowerings, by ``how = (lowering, interpret)``, the
+# lowering the FFN's products took (:func:`_ffn_lowering`): ``"pallas"`` is
+# the row kernels of ``ops/moe_rows.py``, which copy only the rows that carry
+# a pair, each once out of a packed copy of the operand, and weight or sum
+# them in the same pass (f32, j ascending, one rounding: the takes' results
+# to the bit); ``"xla"`` is ``jnp.take``, which fetches every row it is
+# given (the CPU, float32, int8 stacks, a decode step's few rows).
 
-@jax.custom_vjp
-def _rows_of_tokens(x: jax.Array, tok: jax.Array, slot: jax.Array):
-    """``x[tok]``: the token each buffer row reads, [bound, D]."""
-    return x[tok]
+# dispatches and combines by the lowering they took, counted when traced: one
+# for the move (where the layer is traced, not in the move: the dispatch is
+# traced again for the backward's fetch) and one more when its backward is
+# traced (the step-program table reads the difference around a step
+# program's first call, as it does ``ops/grouped_matmul.py:lowerings``)
+_DISPATCH_LOWERINGS = {"pallas": 0, "xla": 0}
+# the ``checkpoint_name`` of the dispatch's packed tokens
+PACKED_TOKENS = "moe_packed_tokens"
+# the kernels keep a word a buffer row in scalar memory (512 KiB here)
+_MOST_ROWS = 131072
 
 
-def _rows_fwd(x, tok, slot):
-    return x[tok], slot
+def dispatch_lowerings() -> Dict[str, int]:
+    return dict(_DISPATCH_LOWERINGS)
 
 
-def _rows_bwd(slot, g):
-    return _sum_of_rows(g, slot, None).astype(g.dtype), None, None
+def _moves_lowering(S: int, bound: int, w: Dict[str, jax.Array], dt,
+                    kernel: str, interpret: Optional[bool]
+                    ) -> Tuple[str, bool]:
+    """``(lowering, interpret)`` of a layer's dispatch and combine: the
+    lowering its FFN's products take over the same ``bound`` rows (a TPU,
+    dense bf16 stacks, whole-lane widths, a tile of rows a group), given
+    whole tiles of tokens and indices that fit the kernels' scalar memory."""
+    if (kernel == "ragged" and S % 8 == 0 and bound <= _MOST_ROWS
+            and _ffn_lowering(bound, dt, w, dt, interpret) == "pallas"):
+        return "pallas", bool(interpret)
+    return "xla", False
+
+
+def _row_moves(rows: jax.Array, group_sizes: jax.Array, n_here: jax.Array,
+               S: int, k: int, how: Tuple[str, bool]):
+    """What the kernels walk beside ``rows`` and ``slot``: the rows that
+    carry a pair, and each token tile's runs of them; None for the takes."""
+    if how[0] != "pallas":
+        return None
+    from deepspeed_tpu.ops.moe_rows import token_tile_runs
+
+    line, runs = token_tile_runs(rows, group_sizes, S=S, k=k)
+    return {"n_here": n_here, "line": line, "runs": runs}
+
+
+def _fetch_rows(x: jax.Array, tok: jax.Array, slot: jax.Array, moves,
+                how: Tuple[str, bool]):
+    """``x[tok]``: the token each buffer row reads, [bound, D] (the kernels
+    leave the tiles of rows past the last pair unwritten: no consumer reads
+    them)."""
+    if how[0] == "xla":
+        return x[tok]
+    from deepspeed_tpu.ops import moe_rows
+
+    packed = jax.ad_checkpoint.checkpoint_name(
+        moe_rows.pack_rows(x, interpret=how[1]), PACKED_TOKENS)
+    return moe_rows.rows_of_tokens(packed, tok, moves["n_here"], D=x.shape[1],
+                                   dtype=x.dtype, interpret=how[1])
+
+
+_rows_of_tokens = jax.custom_vjp(_fetch_rows, nondiff_argnums=(4,))
+
+
+def _rows_fwd(x, tok, slot, moves, how):
+    return _fetch_rows(x, tok, slot, moves, how), (slot, moves)
+
+
+def _rows_bwd(how, res, g):
+    slot, moves = res
+    _DISPATCH_LOWERINGS[how[0]] += 1
+    if how[0] == "xla":
+        return _sum_of_rows(g, slot, None).astype(g.dtype), None, None, None
+    from deepspeed_tpu.ops import moe_rows
+
+    packed = moe_rows.pack_rows(g, moves["n_here"], interpret=how[1])
+    return moe_rows.sum_of_rows(
+        packed, slot, moves["line"], moves["runs"], None, D=g.shape[1],
+        dtype=g.dtype, interpret=how[1]), None, None, None
 
 
 _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
@@ -494,33 +623,55 @@ def _sum_of_rows(ys, slot, weights):
     return acc
 
 
-@jax.custom_vjp
-def _weighted_sum_of_rows(ys: jax.Array, weights: jax.Array,
-                          rows: jax.Array, slot: jax.Array):
+def _sum_pairs(ys: jax.Array, weights: jax.Array, rows: jax.Array,
+               slot: jax.Array, moves, how: Tuple[str, bool]):
     """Each token's ``sum_j weights[t, j] * ys[slot[t, j]]``, summed in f32:
     ys [bound, D], weights [S, k] f32, ``rows`` [bound] the pair of each row,
     ``slot`` [S, k] the row of each pair."""
-    return _sum_of_rows(ys, slot, weights).astype(ys.dtype)
+    if how[0] == "xla":
+        return _sum_of_rows(ys, slot, weights).astype(ys.dtype)
+    from deepspeed_tpu.ops import moe_rows
+
+    packed = moe_rows.pack_rows(ys, moves["n_here"], interpret=how[1])
+    return moe_rows.sum_of_rows(
+        packed, slot, moves["line"], moves["runs"], weights, D=ys.shape[1],
+        dtype=ys.dtype, interpret=how[1])
 
 
-def _wsum_fwd(ys, weights, rows, slot):
-    return _sum_of_rows(ys, slot, weights).astype(ys.dtype), \
-        (ys, weights, rows, slot)
+_weighted_sum_of_rows = jax.custom_vjp(_sum_pairs, nondiff_argnums=(5,))
 
 
-def _wsum_bwd(res, g):
-    ys, weights, rows, slot = res
+def _wsum_fwd(ys, weights, rows, slot, moves, how):
+    return _sum_pairs(ys, weights, rows, slot, moves, how), \
+        (ys, weights, rows, slot, moves)
+
+
+def _wsum_bwd(how, res, g):
+    ys, weights, rows, slot, moves = res
     k = slot.shape[1]
+    _DISPATCH_LOWERINGS[how[0]] += 1
     # a row's cotangent: its token's, times its pair's weight (a row that
     # carries no pair gets one all the same: ``_grouped_ffn`` cuts it off, by
     # its mask or by kernels that work only the rows a group holds)
-    dys = (g[rows // k].astype(jnp.float32)
-           * weights.reshape(-1)[rows][:, None]).astype(ys.dtype)
-    gf = g.astype(jnp.float32)
-    dw = jnp.stack([
-        (gf * jnp.take(ys, slot[:, j], axis=0, mode="fill", fill_value=0)
-         .astype(jnp.float32)).sum(axis=-1) for j in range(k)], axis=1)
-    return dys, dw.astype(weights.dtype), None, None
+    row_weight = weights.reshape(-1)[rows]
+    if how[0] == "xla":
+        dys = (g[rows // k].astype(jnp.float32)
+               * row_weight[:, None]).astype(ys.dtype)
+        gf = g.astype(jnp.float32)
+        dw = jnp.stack([
+            (gf * jnp.take(ys, slot[:, j], axis=0, mode="fill", fill_value=0)
+             .astype(jnp.float32)).sum(axis=-1) for j in range(k)], axis=1)
+    else:
+        from deepspeed_tpu.ops import moe_rows
+
+        # the same pass gives each row's <g[token], ys[row]>: a weight's
+        # gradient is the dot of its pair's row, a gather of scalars
+        dys, dot = moe_rows.rows_of_tokens(
+            moe_rows.pack_rows(g, interpret=how[1]), rows // k,
+            moves["n_here"], D=g.shape[1], dtype=ys.dtype, weight=row_weight,
+            ys=ys, interpret=how[1])
+        dw = jnp.take(dot, slot, mode="fill", fill_value=0)
+    return dys, dw.astype(weights.dtype), None, None, None
 
 
 _weighted_sum_of_rows.defvjp(_wsum_fwd, _wsum_bwd)

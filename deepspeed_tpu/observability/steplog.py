@@ -195,6 +195,12 @@ class StepProgram:
         #: once and its backward's two transposes once each
         #: (``ops/grouped_matmul.py``); None where the trace held none
         self.moe_grouped_lowerings: Optional[Dict[str, int]] = None
+        #: the expert layers' dispatches and combines the program's trace
+        #: lowered, by the lowering each took, ``{"pallas": n, "xla": m}``: a
+        #: move counts once and its backward once more
+        #: (``moe/sharded_moe.py``, ``ops/moe_rows.py``); None where the
+        #: trace held none
+        self.moe_dispatch_lowerings: Optional[Dict[str, int]] = None
         #: the chunk length of the state-space layers' scan, and the chunks
         #: one step's forward scans (state-space layers x rows x ceil(T /
         #: chunk), from the batch of the program's first call); None for a

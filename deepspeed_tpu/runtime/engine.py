@@ -57,6 +57,22 @@ def _grouped_lowerings() -> Optional[Dict[str, int]]:
     return None if mod is None else mod.lowerings()
 
 
+def _dispatch_lowerings() -> Optional[Dict[str, int]]:
+    """``moe/sharded_moe.py``'s counts of the dispatches and combines traced
+    so far by lowering; None while nothing has loaded that module."""
+    mod = sys.modules.get("deepspeed_tpu.moe.sharded_moe")
+    return None if mod is None else mod.dispatch_lowerings()
+
+
+def _counted(before: Optional[Dict[str, int]],
+             after: Optional[Dict[str, int]]) -> Optional[Dict[str, int]]:
+    """What a trace added to such counts; None where it added nothing."""
+    if after == before:
+        return None
+    return {kind: n - (before or {}).get(kind, 0)
+            for kind, n in after.items()}
+
+
 class DeepSpeedTpuEngine:
     """See module docstring. Public surface mirrors ``DeepSpeedEngine``."""
 
@@ -993,6 +1009,7 @@ class DeepSpeedTpuEngine:
             row.capture(args)
             before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
             grouped_before = _grouped_lowerings()
+            dispatch_before = _dispatch_lowerings()
         with self._ebus.span("train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
             out = self._fused_step_cache[key](*args)
@@ -1007,11 +1024,10 @@ class DeepSpeedTpuEngine:
                 kind: n - before[kind] for kind, n in bwd_lowerings().items()}
             traces, tiles = fwd_tiles()
             row.flash_fwd_tiles = tiles if traces > fwd_before else None
-            grouped = _grouped_lowerings()
-            if grouped != grouped_before:
-                row.moe_grouped_lowerings = {
-                    kind: n - (grouped_before or {}).get(kind, 0)
-                    for kind, n in grouped.items()}
+            row.moe_grouped_lowerings = _counted(grouped_before,
+                                                 _grouped_lowerings())
+            row.moe_dispatch_lowerings = _counted(dispatch_before,
+                                                  _dispatch_lowerings())
         return out
 
     def _fused_train_step(self, batch):

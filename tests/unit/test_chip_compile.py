@@ -11,6 +11,8 @@ every xdist worker collects the same tests and only the worker that runs this
 file loads the TPU compiler. Keep these cases in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -314,6 +316,103 @@ def test_the_gated_ffns_gradient_compiles_for_v5e(one_chip, width, took):
         assert "ragged-dot" not in text
     else:
         assert said == {"pallas": 0, "xla": 3} and "ragged-dot" in text
+
+
+def _moe_rows_case(name):
+    """(function, argument shapes) of one row kernel of the expert layer at
+    the Mellum2 cell's shapes: 16,384 tokens, eight pairs each, a buffer of
+    65,536 rows, 2304 columns."""
+    from deepspeed_tpu.ops import moe_rows as mr
+
+    S, k, bound, D, G = 16384, 8, 65536, 2304, 16
+    w = mr.packed_width(D)
+    bf16, f32, i32, u32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
+    runs = ((G * (S // 256) + 1,), i32)
+    return {
+        "pack-tokens": (mr.pack_rows, [((S, D), bf16)]),
+        "pack-live-rows": (mr.pack_rows, [((bound, D), bf16), ((), i32)]),
+        "dispatch": (
+            lambda xp, tok, n: mr.rows_of_tokens(xp, tok, n, D=D),
+            [((S, 1, w), u32), ((bound,), i32), ((), i32)]),
+        "combine-backward": (
+            lambda gp, tok, n, wt, ys: mr.rows_of_tokens(
+                gp, tok, n, D=D, weight=wt, ys=ys),
+            [((S, 1, w), u32), ((bound,), i32), ((), i32), ((bound,), f32),
+             ((bound, D), bf16)]),
+        "combine": (
+            lambda yp, slot, line, runs, wt: mr.sum_of_rows(
+                yp, slot, line, runs, wt, D=D),
+            [((bound, 1, w), u32), ((S, k), i32), ((bound,), i32), runs,
+             ((S, k), f32)]),
+        "dispatch-backward": (
+            lambda gp, slot, line, runs: mr.sum_of_rows(
+                gp, slot, line, runs, None, D=D),
+            [((bound, 1, w), u32), ((S, k), i32), ((bound,), i32), runs]),
+        "runs-of-a-token-tile": (
+            lambda rows, sizes: mr.token_tile_runs(rows, sizes, S=S, k=k),
+            [((bound,), i32), ((G,), i32)]),
+    }[name]
+
+
+@pytest.mark.parametrize("name", [
+    "pack-tokens", "pack-live-rows", "dispatch", "combine-backward",
+    "combine", "dispatch-backward", "runs-of-a-token-tile"])
+def test_the_expert_layers_row_kernels_compile_for_v5e(one_chip, name):
+    """A one-row copy out of the packed ``[n, 1, w]`` words, a load with a
+    stride of a row, 18.9 MB of rows held at once: what interpret mode cannot
+    refuse and the chip's compiler can."""
+    fn, shapes = _moe_rows_case(name)
+    args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+            for shape, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = text.count("custom_call_target=\"tpu_custom_call\"")
+    assert calls == (0 if name == "runs-of-a-token-tile" else 1)
+    if name.startswith("pack"):
+        # a packed row is laid out whole, one after another
+        assert "u32[%d,1,1152]{2,1,0:T(1,128)}" % args[0].shape[0] in text
+
+
+def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
+                                                         monkeypatch):
+    """A cell-shaped expert layer, forward and backward, as if on the chip:
+    dispatch, combine and their backwards are row kernels (with the backward's
+    second fetch of the rows, five of them, and four packings) and no gather
+    of rows is left."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+    from deepspeed_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+
+    class Share:
+        top_k = 8
+        moe_kernel = "ragged"
+        moe_experts_held = 16
+        moe_first_expert = 0
+        moe_ep_capacity_factor = 2.0
+
+    S, D, F, E, held = 16384, 2304, 896, 64, 16
+
+    def loss(h, w):
+        out, _ = sm.grouped_moe_mlp_block(h, w, Share, kernel="ragged")
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    def arg(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    w = {"router": arg((D, E)), "w_gate": arg((held, D, F)),
+         "w_up": arg((held, D, F)), "w_down": arg((held, F, D))}
+    before = sm.dispatch_lowerings()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        arg((2, S // 2, D), jnp.bfloat16), w).compile().as_text()
+    said = {n: v - before[n] for n, v in sm.dispatch_lowerings().items()}
+    assert said == {"pallas": 4, "xla": 0}
+    by = {name: len(re.findall(r"custom-call\(.*tpu_custom_call.*"
+                               rf"jit\({name}\)/", text))
+          for name in ("pack_rows", "rows_of_tokens", "sum_of_rows", "gmm",
+                       "tgmm")}
+    assert by == {"pack_rows": 4, "rows_of_tokens": 3, "sum_of_rows": 2,
+                  "gmm": 5, "tgmm": 3}
+    assert not re.search(r"bf16\[(65536|16384),2304\]\S* gather\(", text)
 
 
 def test_kernel_path_rules_match_what_compiled():

@@ -305,6 +305,8 @@ def test_the_step_record_carries_the_routers_counts():
     # float32 at a width of 64 on a CPU: every product is lax.ragged_dot
     # (three a layer; its transposes are autodiff's and are not counted)
     assert prog.moe_grouped_lowerings == {"pallas": 0, "xla": 12}
+    # and every dispatch and combine, with its backward, is jnp.take
+    assert prog.moe_dispatch_lowerings == {"pallas": 0, "xla": 16}
     assert prog.experts_held == (2, 4, 8)
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
@@ -316,7 +318,8 @@ def test_the_cell_shaped_step_program_takes_the_kernels(monkeypatch):
     """What the benchmark's cell is in small: bf16, whole-lane widths, a tile
     of rows for each held expert, no recomputation. ``ragged`` still names
     the algebra, and every grouped product of the program, nine a layer, is
-    the Pallas kernel (interpreted here: the CPU stands in for the chip)."""
+    the Pallas kernel (interpreted here: the CPU stands in for the chip), as
+    is every move into and out of the buffer of pairs, four a layer."""
     import functools
 
     import deepspeed_tpu as ds
@@ -324,8 +327,8 @@ def test_the_cell_shaped_step_program_takes_the_kernels(monkeypatch):
     from deepspeed_tpu.observability import steplog
     from deepspeed_tpu.parallel import build_mesh
 
-    monkeypatch.setattr(sm, "_grouped_ffn", functools.partial(
-        sm._grouped_ffn, interpret=True))
+    monkeypatch.setattr(sm, "grouped_moe_mlp_block", functools.partial(
+        sm.grouped_moe_mlp_block, interpret=True))
     hf = hf_config(L=4, D=128, F=128)
     model = model_for(hf, dtype="bfloat16", max_seq_len=128)
     eng, *_ = ds.initialize(
@@ -339,6 +342,8 @@ def test_the_cell_shaped_step_program_takes_the_kernels(monkeypatch):
     prog = steplog.programs()[-1]
     assert prog.moe_kernel_resolved == "ragged"
     assert prog.moe_grouped_lowerings == {"pallas": 36, "xla": 0}
+    # dispatch, combine and the backward of each, a layer, are row kernels
+    assert prog.moe_dispatch_lowerings == {"pallas": 16, "xla": 0}
     row = steplog.get_steplog().parts(last=1)[-1]
     assert row["pairs_dropped"].tolist() == [0] * 4
     assert row["expert_pairs"].sum() == row["pairs_here"].sum() > 0

@@ -134,6 +134,7 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
     assert rows[0].flash_bwd_lowerings == {"fused": 0, "split": 0}
     assert rows[0].flash_fwd_tiles is None
     assert rows[0].moe_grouped_lowerings is None      # no expert layer
+    assert rows[0].moe_dispatch_lowerings is None
     assert log.n_steps == n0 + 3
     last = log.steps()[-3:]
     assert last[:, 0].tolist() == [0, 1, 2]
