@@ -6,14 +6,30 @@ Brand-new JAX/XLA/Pallas implementation of the capability surface of DeepSpeed
 engine with ``forward/backward/step`` plus data loader and LR scheduler.
 """
 
-from deepspeed_tpu.version import __version__  # noqa: F401
+import sys as _sys
+import time as _time
 
-from deepspeed_tpu import comm  # noqa: F401
-from deepspeed_tpu import ops  # noqa: F401  (registers Pallas kernels, e.g. 'flash')
-from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401
-from deepspeed_tpu.config import DeepSpeedTpuConfig, from_config  # noqa: F401
-from deepspeed_tpu.parallel import Topology, build_mesh  # noqa: F401
-from deepspeed_tpu.utils.compile_cache import place_compile_cache
+_t_import, _jax_preloaded = _time.perf_counter(), "jax" in _sys.modules
+
+from deepspeed_tpu.observability import steplog as _steplog  # noqa: E402
+from deepspeed_tpu.observability.events import get_bus as _get_bus  # noqa: E402
+
+# the package's import is the first set-up span (stamped here, where the
+# import began, and closed on the last line) and every program the process
+# builds from here on enters the build record
+_steplog.install_build_hook()
+_import_span = _steplog.span(_get_bus(), "setup", "import", start=_t_import,
+                             jax_preloaded=_jax_preloaded)
+_import_span.__enter__()
+
+from deepspeed_tpu.version import __version__  # noqa: F401,E402
+
+from deepspeed_tpu import comm  # noqa: F401,E402
+from deepspeed_tpu import ops  # noqa: F401,E402  (registers Pallas kernels, e.g. 'flash')
+from deepspeed_tpu.accelerator import get_accelerator, set_accelerator  # noqa: F401,E402
+from deepspeed_tpu.config import DeepSpeedTpuConfig, from_config  # noqa: F401,E402
+from deepspeed_tpu.parallel import Topology, build_mesh  # noqa: F401,E402
+from deepspeed_tpu.utils.compile_cache import place_compile_cache  # noqa: E402
 
 
 def initialize(model=None, config=None, optimizer=None, model_parameters=None,
@@ -35,31 +51,36 @@ def initialize(model=None, config=None, optimizer=None, model_parameters=None,
         (engine, optimizer, training_dataloader, lr_scheduler) — same 4-tuple as the
         reference.
     """
-    try:
-        from deepspeed_tpu.runtime.engine import DeepSpeedTpuEngine
-    except ImportError as e:  # pragma: no cover
-        raise NotImplementedError(
-            "deepspeed_tpu.runtime.engine is not available in this build") from e
+    bus = _get_bus()
+    with _steplog.span(bus, "setup", "initialize"):
+        # the span's own time is what none of its four parts holds: first of
+        # all the import of the engine's module, which happens here
+        try:
+            from deepspeed_tpu.runtime.engine import DeepSpeedTpuEngine
+        except ImportError as e:  # pragma: no cover
+            raise NotImplementedError(
+                "deepspeed_tpu.runtime.engine is not available in this build") from e
 
-    if config is None and config_params is not None:
-        config = config_params
-    ds_config = from_config(config)
-    place_compile_cache()
-    comm.init_distributed()
-    engine_cls = DeepSpeedTpuEngine
-    if ds_config.hybrid_engine.enabled:
-        from deepspeed_tpu.runtime.hybrid_engine import DeepSpeedTpuHybridEngine
+        if config is None and config_params is not None:
+            config = config_params
+        with _steplog.span(bus, "setup", "config"):
+            ds_config = from_config(config)
+            place_compile_cache()
+            comm.init_distributed()
+        engine_cls = DeepSpeedTpuEngine
+        if ds_config.hybrid_engine.enabled:
+            from deepspeed_tpu.runtime.hybrid_engine import DeepSpeedTpuHybridEngine
 
-        engine_cls = DeepSpeedTpuHybridEngine
-    engine = engine_cls(
-        model=model,
-        config=ds_config,
-        optimizer=optimizer,
-        training_data=training_data,
-        lr_scheduler=lr_scheduler,
-        topology=mesh,
-        collate_fn=collate_fn,
-    )
+            engine_cls = DeepSpeedTpuHybridEngine
+        engine = engine_cls(
+            model=model,
+            config=ds_config,
+            optimizer=optimizer,
+            training_data=training_data,
+            lr_scheduler=lr_scheduler,
+            topology=mesh,
+            collate_fn=collate_fn,
+        )
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 
 
@@ -103,3 +124,6 @@ def init_inference(model=None, config=None, checkpoint=None, dtype=None,
 
             kwargs["params"] = load_params_only(checkpoint)
     return InferenceEngine(model=model, config=config, dtype=dtype, **kwargs)
+
+
+_import_span.__exit__(None, None, None)

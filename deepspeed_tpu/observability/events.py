@@ -231,16 +231,23 @@ class EventBus:
         self.emit("n", cat, name, trace_id=trace_id, args=args)
 
     def span(self, cat: str, name: str, *, trace_id=None, parent_id=None,
-             args=None, step: Optional[int] = None):
+             args=None, step: Optional[int] = None,
+             program: Optional[str] = None):
         """``with bus.span(...):`` — the one way the program opens a span.
         It always enters a ``jax.profiler.TraceAnnotation``
         ``ds.<cat>.<name>`` (``step`` becomes the annotation's ``step``
-        argument): a no-op of well under a microsecond while no profiler
+        argument, or ``program`` its ``program``: the step number of a step's
+        span, the name of the program a build span builds): a no-op of well
+        under a microsecond while no profiler
         session runs, and an event on the profiler's own clock when one
         does. The B/E pair goes into the host-clock ring only when tracing
         is enabled; disabled, the annotation itself is returned."""
-        ann = (TraceAnnotation(f"ds.{cat}.{name}") if step is None
-               else TraceAnnotation(f"ds.{cat}.{name}", step=step))
+        if step is not None:
+            ann = TraceAnnotation(f"ds.{cat}.{name}", step=step)
+        elif program is None:
+            ann = TraceAnnotation(f"ds.{cat}.{name}")
+        else:
+            ann = TraceAnnotation(f"ds.{cat}.{name}", program=program)
         if not self.enabled:
             return ann
         return _Span(self, cat, name, trace_id, parent_id, args, ann)

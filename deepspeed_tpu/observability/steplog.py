@@ -28,11 +28,24 @@ engine.
   a step.
 * :func:`slow_steps` — the arithmetic that says which steps of a record were
   slow and how much of their excess the host or the collector took.
+* set-up — :func:`span` opens a span through ``EventBus.span`` and keeps its
+  name on the calling thread's stack while it is open; a ``ds.setup.*`` span
+  also leaves a row (name, start, end, parent) that :func:`setup` returns:
+  ``ds.setup.import``, ``ds.setup.initialize`` and its children.
+* the build record — :func:`install_build_hook` registers two
+  ``jax.monitoring`` listeners that fold every trace, lowering, backend
+  compile and compile-cache event of the process into :func:`builds`: one row
+  per (program name, innermost recorded span open on the thread). The
+  listeners fire only when JAX builds something; a step whose program is
+  built costs nothing. ``benchmarks/readers/SETUP.md`` says which metric
+  reads which span and field.
 """
 
 from __future__ import annotations
 
 import gc
+import itertools
+import threading
 import time
 import weakref
 from collections import deque
@@ -42,6 +55,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 __all__ = ["StepLog", "StepProgram", "get_steplog", "install_gc_hook",
+           "install_build_hook", "span", "setup", "builds", "build_events",
            "record_program", "programs", "slow_steps", "SLOW_FACTOR"]
 
 #: a step is slow when its period exceeds this many medians
@@ -145,14 +159,206 @@ def install_gc_hook() -> None:
         gc.callbacks.append(_on_gc)
 
 
+# ---- recorded spans and the set-up record ----------------------------------
+
+_tls = threading.local()    # .stack: names of the recorded spans open here
+_SETUP: deque = deque(maxlen=256)
+_setup_ids = itertools.count(1)
+
+
+class _Recorded:
+    """A span opened through :func:`span`: the bus's own span inside, its
+    name on the thread's stack while it is open, and for a set-up span the
+    row that :func:`setup` returns."""
+
+    __slots__ = ("inner", "name", "row")
+
+    def __init__(self, inner, name: str, row: Optional[Dict]):
+        self.inner = inner
+        self.name = name
+        self.row = row
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        if self.row is not None:
+            self.row["parent"] = next(
+                (s.row["id"] for s in reversed(stack) if s.row is not None),
+                None)
+            if self.row["start"] is None:
+                self.row["start"] = time.perf_counter()
+            _SETUP.append(self.row)
+        stack.append(self)
+        self.inner.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.inner.__exit__(exc_type, exc, tb)
+        if self.row is not None:
+            self.row["end"] = time.perf_counter()
+        _tls.stack.pop()
+        return False
+
+
+def span(bus, cat: str, name: str, *, start: Optional[float] = None,
+         program: Optional[str] = None, **facts) -> _Recorded:
+    """``with steplog.span(bus, "setup", "initialize"):`` — ``bus.span(cat,
+    name)`` (the profiler's annotation ``ds.<cat>.<name>`` and, with tracing
+    on, the ring's B/E pair), remembered as the innermost span of the calling
+    thread while it is open, so that the build record can say under which
+    span a program was built. A span of category ``setup`` also leaves a row
+    for :func:`setup`, with ``facts`` beside its times; ``start`` backdates
+    that row (the import span begins before anything could open it). For
+    spans that open a few times a process: a step's own spans stay on
+    ``bus.span``."""
+    row = None
+    if cat == "setup":
+        row = {"id": next(_setup_ids), "name": f"ds.{cat}.{name}",
+               "start": start, "end": None, "parent": None, **facts}
+    return _Recorded(bus.span(cat, name, program=program, args=facts or None),
+                     f"ds.{cat}.{name}", row)
+
+
+def setup() -> List[Dict]:
+    """The process's set-up spans in the order they opened: ``{"id", "name",
+    "start", "end" (None while open), "parent" (an id or None), "self_s"`` (the
+    span's length less its children's) and the facts the span was opened
+    with``}``, on ``time.perf_counter``. The last 256."""
+    rows = [dict(r) for r in _SETUP]
+    for r in rows:
+        r["self_s"] = None if r["end"] is None else (r["end"] - r["start"]) \
+            - sum(c["end"] - c["start"] for c in rows
+                  if c["parent"] == r["id"] and c["end"] is not None)
+    return rows
+
+
+# ---- the build record ------------------------------------------------------
+
+#: most program names the build record keeps; the rest fold into ``_other_``
+BUILD_NAMES = 512
+#: the span under which a reader's request compiles a step program again
+INSPECT_SPAN = "ds.train.inspect"
+_TRACES, _LOWERS, _COMPILES, _HITS, _READ_S, _MISSES, _FIRST, _LAST = \
+    0, 2, 4, 6, 7, 8, 9, 10
+_DURATIONS = {"/jax/core/compile/jaxpr_trace_duration": _TRACES,
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWERS,
+              "/jax/core/compile/backend_compile_duration": _COMPILES}
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
+                 "/jax/compilation_cache/cache_misses": 2}
+_BUILD_FIELDS = ("traces", "trace_s", "lowers", "lower_s", "compiles",
+                 "compile_s", "cache_hits", "cache_read_s", "cache_misses",
+                 "first", "last")
+_BUILDS: Dict[Any, List[float]] = {}    # (name, span) -> the row's numbers
+_build_names: set = set()
+_build_events = [0, 0]                  # duration events, plain events seen
+_hooked = False
+
+
+def _on_duration(event: str, duration: float, fun_name: str = "?", **_):
+    """One ``jax.monitoring`` duration event into its row. A trace event
+    names the function (``f``), a lowering or a backend compile the module
+    (``jit(f)``): one name. The compile-cache's events carry no name and fire
+    inside the backend compile that asked, so they wait on the thread for the
+    backend-compile event that closes next there."""
+    _build_events[0] += 1
+    col = _DURATIONS.get(event)
+    if col is None:
+        if event == _CACHE_READ:
+            _pending()[1] += duration
+        return
+    now = time.perf_counter()
+    name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    if name not in _build_names:
+        if len(_build_names) < BUILD_NAMES:
+            _build_names.add(name)
+        else:
+            name = "_other_"
+    stack = getattr(_tls, "stack", None)
+    key = (name, stack[-1].name if stack else "outside")
+    row = _BUILDS.get(key)
+    if row is None:
+        row = _BUILDS.setdefault(key, [0, 0.0, 0, 0.0, 0, 0.0, 0, 0.0, 0,
+                                       now, now])
+    row[col] += 1
+    row[col + 1] += duration
+    row[_LAST] = now
+    if col == _COMPILES:
+        cache = getattr(_tls, "cache", None)
+        if cache is not None and (cache[0] or cache[2]):
+            row[_HITS] += cache[0]
+            row[_READ_S] += cache[1]
+            row[_MISSES] += cache[2]
+            cache[0], cache[1], cache[2] = 0, 0.0, 0
+
+
+def _on_event(event: str, **_):
+    _build_events[1] += 1
+    col = _CACHE_EVENTS.get(event)
+    if col is not None:
+        _pending()[col] += 1
+
+
+def _pending() -> List[float]:
+    """This thread's compile-cache events that no backend compile has
+    closed over yet: hits, seconds reading, misses."""
+    cache = getattr(_tls, "cache", None)
+    if cache is None:
+        cache = _tls.cache = [0, 0.0, 0]
+    return cache
+
+
+def install_build_hook() -> None:
+    """Idempotent; the package calls it when it is imported, so that programs
+    built before any engine are in the record."""
+    global _hooked
+    if not _hooked:
+        import jax.monitoring as monitoring
+
+        _hooked = True
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+
+
+def builds(name: Optional[str] = None) -> List[Dict]:
+    """The build record, oldest row first: ``{"name", "span", "traces",
+    "trace_s", "lowers", "lower_s", "compiles", "compile_s"`` (the backend
+    compile, which holds the read from the compile cache)``, "cache_hits",
+    "cache_read_s", "cache_misses", "first", "last"`` (``perf_counter`` of
+    the row's first and last event)``}``; of one program where ``name`` is
+    given."""
+    return [{"name": key[0], "span": key[1], **dict(zip(_BUILD_FIELDS, row))}
+            for key, row in sorted(_BUILDS.items(),
+                                   key=lambda kv: kv[1][_FIRST])
+            if name is None or key[0] == name]
+
+
+def build_events() -> Dict[str, int]:
+    """How often each listener has been called (what the record costs is
+    this many dict updates)."""
+    return {"duration": _build_events[0], "plain": _build_events[1]}
+
+
+def _build_sums(name: str) -> List[float]:
+    """The rows of ``name`` summed, but for what a reader's own look at the
+    compiled program built (:meth:`StepProgram.compiled`)."""
+    sums = [0, 0.0, 0, 0.0, 0, 0.0, 0, 0.0, 0]
+    for key, row in list(_BUILDS.items()):
+        if key[0] == name and key[1] != INSPECT_SPAN:
+            for i in range(_FIRST):
+                sums[i] += row[i]
+    return sums
+
+
 # ---- the step programs ----------------------------------------------------
 
 class StepProgram:
     """One jitted step program: its name (the device trace's module line says
     ``jit_<name>``), the engine's cache key, when it was built
-    (``perf_counter``), and what a reader needs to compile it again. The
-    jitted function is held weakly: when its engine is gone, so is the
-    program, and the row answers None."""
+    (``perf_counter``), how long its first two calls took, and what a reader
+    needs to compile it again. The jitted function is held weakly: when its
+    engine is gone, so is the program, and the row answers None."""
 
     def __init__(self, name: str, key: Any, fn: Callable, mesh,
                  layer_applications: Optional[int] = None,
@@ -208,6 +414,14 @@ class StepProgram:
         self.ssm_chunk = ssm_chunk
         self.ssm_chunks_per_step: Optional[int] = None
         self.built_at = time.perf_counter()
+        #: the length of the ``ds.train.dispatch`` span of the program's
+        #: first call (trace, lowering, the compile or its read from the
+        #: cache, the load) and of its second (milliseconds, unless
+        #: ``jax.jit`` built again for what the first call returned); None
+        #: until that call
+        self.first_call_s: Optional[float] = None
+        self.second_call_s: Optional[float] = None
+        self._build_base = _build_sums(name)
         self._fn = weakref.ref(fn)
         self._mesh = mesh
         self._args = None
@@ -222,15 +436,33 @@ class StepProgram:
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding), args)
 
+    def build(self) -> Dict[str, float]:
+        """The build record's rows of this program's name, summed (the
+        fields of :func:`builds` up to ``cache_misses``): what was built
+        under the name since this row was entered and before the next row
+        of the name was. ``lowers`` above 1 is a second lowering inside
+        ``jax.jit``'s own cache, which enters no row here."""
+        later = next((p for p in list(_PROGRAMS)
+                      if p.name == self.name and p.built_at > self.built_at),
+                     None)
+        upto = _build_sums(self.name) if later is None else later._build_base
+        return {f: u - b for f, u, b in
+                zip(_BUILD_FIELDS, upto, self._build_base)}
+
     def compiled(self):
-        """Lower and compile again from the kept arguments (memoised)."""
+        """Lower and compile again from the kept arguments (memoised), under
+        a ``ds.train.inspect`` span: the build record keeps what this built
+        apart from what the program's calls built."""
         if self._compiled is None:
             import jax
 
             fn = self._fn()
             if fn is None or self._args is None:
                 return None
-            with jax.sharding.set_mesh(self._mesh):
+            from deepspeed_tpu.observability.events import get_bus
+
+            with span(get_bus(), "train", "inspect"), \
+                    jax.sharding.set_mesh(self._mesh):
                 self._compiled = fn.lower(*self._args).compile()
         return self._compiled
 

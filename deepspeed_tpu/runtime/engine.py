@@ -25,6 +25,7 @@ TPU-native design (NOT a port of the hook/stream machinery):
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import time
@@ -79,6 +80,23 @@ class DeepSpeedTpuEngine:
     def __init__(self, model, config: DeepSpeedTpuConfig, optimizer=None,
                  training_data=None, lr_scheduler=None, topology: Optional[Topology] = None,
                  collate_fn: Optional[Callable] = None, init_rng: Optional[jax.Array] = None):
+        # the build in three set-up spans (``steplog.setup()``), so that a
+        # slow start says which part of it was slow
+        from deepspeed_tpu.observability.events import get_bus
+
+        bus = get_bus()
+        with steplog.span(bus, "setup", "engine.plan"):
+            init_rng, schedule_fn = self._init_plan(
+                model, config, optimizer, lr_scheduler, topology, init_rng)
+        with steplog.span(bus, "setup", "engine.state"):
+            self._init_state(config, init_rng, schedule_fn)
+        with steplog.span(bus, "setup", "engine.rest"):
+            self._init_rest(config, training_data, collate_fn)
+
+    def _init_plan(self, model, config, optimizer, lr_scheduler, topology,
+                   init_rng):
+        """``ds.setup.engine.plan``: mesh, schedules, optimizer, sharding
+        layouts and the jitted functions; nothing is on the device yet."""
         self.config = config
         if topology is None and config.mesh.auto:
             # mesh: "auto" — adopt the measured-best (or cost-model-ranked)
@@ -193,8 +211,14 @@ class DeepSpeedTpuEngine:
 
         # ---- compiled functions ---------------------------------------
         self._build_jit_fns()
+        return init_rng, schedule_fn
 
+    def _init_state(self, config, init_rng, schedule_fn):
+        """``ds.setup.engine.state``: the weights, the optimizer's state and
+        the scaler appear on the device. The span ends when they are
+        dispatched; nothing here waits for the device."""
         # ---- materialize state ----------------------------------------
+        zcfg = config.zero_optimization
         self._offload = None
         off = zcfg.offload_optimizer
         if zcfg.zenflow is not None and (off is None
@@ -215,6 +239,9 @@ class DeepSpeedTpuEngine:
         self._pending = None  # (loss, grads) from the last forward
         self._grad_acc_count = 0
 
+    def _init_rest(self, config, training_data, collate_fn):
+        """``ds.setup.engine.rest``: counters, monitors, observability, data
+        efficiency, resilience, the data loader and the start-up checks."""
         # ---- bookkeeping ----------------------------------------------
         self.global_steps = 0
         self.global_samples = 0
@@ -222,7 +249,7 @@ class DeepSpeedTpuEngine:
         self.skipped_steps = 0
         self._last_loss = None
         self._last_gnorm = None
-        self._world_params = num_params(param_shapes)
+        self._world_params = num_params(self._param_shapes)
         self.tput_timer = ThroughputTimer(
             batch_size=int(self.config.train_batch_size),
             steps_per_output=config.steps_per_print,
@@ -1005,30 +1032,61 @@ class DeepSpeedTpuEngine:
         """Call the jitted step program of ``key`` (the ``ds.train.dispatch``
         span; the call returns when the program is enqueued)."""
         row = self._uncaptured.pop(key, None)
-        if row is not None:
-            row.capture(args)
-            before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
-            grouped_before = _grouped_lowerings()
-            dispatch_before = _dispatch_lowerings()
+        if row is not None:     # the program's first or second call
+            # called from this frame like every later call: from a method
+            # of its own, one frame deeper, the program's lowering took
+            # 0.2-0.3 s longer on the chip (PERF.md §6, PR 37)
+            with self._watched(row, key, args):
+                out = self._fused_step_cache[key](*args)
+            return out
         with self._ebus.span("train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
             out = self._fused_step_cache[key](*args)
         self._t_dispatched = time.perf_counter()
-        if row is not None:     # the program's first call: it was traced now
-            if row.ssm_chunk is not None:
-                row.ssm_chunks_per_step = next(
-                    (self.module.ssm_chunks_scanned(a["input_ids"].shape)
-                     for a in args
-                     if isinstance(a, dict) and "input_ids" in a), None)
-            row.flash_bwd_lowerings = {
-                kind: n - before[kind] for kind, n in bwd_lowerings().items()}
-            traces, tiles = fwd_tiles()
-            row.flash_fwd_tiles = tiles if traces > fwd_before else None
-            row.moe_grouped_lowerings = _counted(grouped_before,
-                                                 _grouped_lowerings())
-            row.moe_dispatch_lowerings = _counted(dispatch_before,
-                                                  _dispatch_lowerings())
         return out
+
+    @contextlib.contextmanager
+    def _watched(self, row, key, args):
+        """Around a step program's first two calls, timed into its row. The
+        first traces, lowers and loads it, under a ``ds.train.build`` span
+        inside its ``ds.train.dispatch`` (so the build record and a profile
+        name the build), and reads what the trace counted; the second shows
+        whether ``jax.jit`` built again for the first call's outputs. Then
+        the engine forgets the row."""
+        first = row.first_call_s is None
+        if first:
+            row.capture(args)
+            before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
+            grouped_before = _grouped_lowerings()
+            dispatch_before = _dispatch_lowerings()
+        t0 = time.perf_counter()
+        with steplog.span(self._ebus, "train", "dispatch"), \
+                jax.sharding.set_mesh(self.mesh):
+            if first:
+                with steplog.span(self._ebus, "train", "build",
+                                  program=row.name):
+                    yield
+            else:
+                yield
+        self._t_dispatched = time.perf_counter()
+        if not first:
+            row.second_call_s = self._t_dispatched - t0
+            return
+        row.first_call_s = self._t_dispatched - t0
+        self._uncaptured[key] = row
+        if row.ssm_chunk is not None:
+            row.ssm_chunks_per_step = next(
+                (self.module.ssm_chunks_scanned(a["input_ids"].shape)
+                 for a in args
+                 if isinstance(a, dict) and "input_ids" in a), None)
+        row.flash_bwd_lowerings = {
+            kind: n - before[kind] for kind, n in bwd_lowerings().items()}
+        traces, tiles = fwd_tiles()
+        row.flash_fwd_tiles = tiles if traces > fwd_before else None
+        row.moe_grouped_lowerings = _counted(grouped_before,
+                                             _grouped_lowerings())
+        row.moe_dispatch_lowerings = _counted(dispatch_before,
+                                              _dispatch_lowerings())
 
     def _fused_train_step(self, batch):
         ga = int(self.config.gradient_accumulation_steps)

@@ -90,12 +90,20 @@ def test_fused_step_spans_nest_with_step_numbers(recorder):
     batch = {"input_ids": np.zeros((2, 32), np.int32)}
     for _ in range(2):
         eng.fused_train_step(batch)
-    want = []
+    # the engine's build: ``ds.setup.initialize`` around its four parts
+    parts = ["config", "engine.plan", "engine.state", "engine.rest"]
+    want = [("enter", "ds.setup.initialize", {})] + [
+        (act, f"ds.setup.{part}", {}) for part in parts
+        for act in ("enter", "exit")] + [("exit", "ds.setup.initialize", {})]
     for step in (0, 1):
+        # the program's first call, and only that, is a build
+        build = [(act, "ds.train.build", {"program": "ds_train_step"})
+                 for act in ("enter", "exit")] if step == 0 else []
         want += [("enter", "ds.train.step", {"step": step}),
                  ("enter", "ds.train.put_batch", {}),
                  ("exit", "ds.train.put_batch", {}),
                  ("enter", "ds.train.dispatch", {}),
+                 *build,
                  ("exit", "ds.train.dispatch", {}),
                  ("enter", "ds.train.commit", {}),
                  ("exit", "ds.train.commit", {}),

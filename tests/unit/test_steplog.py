@@ -10,6 +10,7 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import TransformerLM, get_preset
 from deepspeed_tpu.observability import steplog
+from deepspeed_tpu.observability.events import EventBus
 from deepspeed_tpu.observability.steplog import StepLog, slow_steps
 
 
@@ -25,27 +26,58 @@ def test_ring_wraps_at_its_size():
     assert log.pauses().tolist() == [[1.0, 0.5, 2.0]]
 
 
-def test_a_write_keeps_nothing():
-    """1,000 step rows and 1,000 pause rows: no net allocation, and the rings
-    are not objects the cyclic collector tracks."""
+def _write_rings():
+    """1,000 step rows and 1,000 pause rows into rings that are not objects
+    the cyclic collector tracks."""
     log = StepLog()
-    for i in range(10):                 # warm the interpreter's caches
-        log.step(i, 1.0, 2.0, 3.0)
-        log.pause(1.0, 0.1, 1)
     assert not gc.is_tracked(log._steps) and not gc.is_tracked(log._pauses)
+
+    def write(n):
+        for i in range(n):
+            log.step(i, i * 0.1, i * 0.1 + 0.01, i * 0.1 + 0.02)
+            log.pause(i * 0.1, 0.001, 2)
+    return write, 1000, 256             # two int counters, nothing per write
+
+
+def _write_build_record():
+    """10,000 ``jax.monitoring`` events of 24 programs under two spans into
+    the build record: the 48 rows, and nothing an event."""
+    names = [f"jit(kept_nothing_{i})" for i in range(24)]
+    events = list(steplog._DURATIONS) + [steplog._CACHE_READ]
+    bus = EventBus()
+
+    def write(n):
+        for i in range(n):
+            if i % 2:
+                steplog._on_event("/jax/compilation_cache/cache_hits")
+                steplog._on_duration(events[i % 4], 0.001,
+                                     fun_name=names[i % 24])
+            else:
+                with steplog.span(bus, "train", "build"):
+                    steplog._on_duration(events[i % 4], 0.001,
+                                         fun_name=names[i % 24])
+    # a row: its key's tuple, its name, a list of eleven numbers
+    return write, 10000, 48 * 256
+
+
+@pytest.mark.parametrize("case", [_write_rings, _write_build_record])
+def test_a_write_keeps_nothing(case, monkeypatch):
+    """No net allocation a write beyond what the case allows in all."""
+    monkeypatch.setattr(steplog, "_BUILDS", {})
+    monkeypatch.setattr(steplog, "_build_names", set())
+    write, n, allowed = case()
+    write(10)                           # warm the interpreter's caches
     tracemalloc.start()
     try:
         before = tracemalloc.take_snapshot()
-        for i in range(1000):
-            log.step(i, i * 0.1, i * 0.1 + 0.01, i * 0.1 + 0.02)
-            log.pause(i * 0.1, 0.001, 2)
+        write(n)
         after = tracemalloc.take_snapshot()
     finally:
         tracemalloc.stop()
     here = [tracemalloc.Filter(True, steplog.__file__)]
     grown = sum(s.size_diff for s in after.filter_traces(here)
                 .compare_to(before.filter_traces(here), "filename"))
-    assert grown <= 256, grown          # two int counters, nothing per write
+    assert 0 <= grown <= allowed, grown
 
 
 def synthetic_record():
