@@ -73,7 +73,11 @@ def param_specs() -> Dict[str, Any]:
 def ssm_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     """The mixer on the normed input u [B, T, D] -> [B, T, D]. Its operations
     lie under the nested scopes ``ssm_proj``, ``ssm_conv``, ``ssm_scan`` and
-    ``ssm_gate`` (inside the caller's ``attn``)."""
+    ``ssm_gate`` (inside the caller's ``attn``). ``ssm_scan`` holds the
+    softplus, the splits and :func:`ssd_scan`, which is two Mosaic kernels
+    with a backward of their own (``.../ssm_scan/jit(ssd_fwd)/pallas_call``,
+    ``jit(ssd_bwd)`` under ``transpose``) where the call's backend, dtype and
+    shapes allow, and einsums with autodiff's backward elsewhere."""
     B, T, _ = u.shape
     H, Pd, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     s = sizes(cfg)

@@ -74,7 +74,8 @@ class TransformerConfig:
     # heads x head_dim), a state of ``ssm_state`` a channel, B and C shared
     # by the heads of each of ``ssm_groups`` groups, a causal depthwise
     # convolution over ``ssm_conv`` positions, the scan in chunks of
-    # ``ssm_chunk`` (ops/ssd_scan.py)
+    # ``ssm_chunk`` (ops/ssd_scan.py: Pallas kernels, forward and backward,
+    # where the backend, dtype and shapes allow, einsums elsewhere)
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128

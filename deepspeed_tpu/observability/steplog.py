@@ -413,6 +413,11 @@ class StepProgram:
         #: model without such a layer
         self.ssm_chunk = ssm_chunk
         self.ssm_chunks_per_step: Optional[int] = None
+        #: the state-space layers' scans the program's trace lowered, by the
+        #: lowering each took, ``{"pallas": n, "xla": m}``: a scan counts once
+        #: and the kernels' backward once more (``ops/ssd_scan.py``); None
+        #: where the trace held none
+        self.ssm_scan_lowerings: Optional[Dict[str, int]] = None
         self.built_at = time.perf_counter()
         #: the length of the ``ds.train.dispatch`` span of the program's
         #: first call (trace, lowering, the compile or its read from the

@@ -50,19 +50,29 @@ from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import ThroughputTimer
 
 
+def _traced_counts(module: str, fn: str = "lowerings"
+                   ) -> Optional[Dict[str, int]]:
+    """What ``module`` has counted, when traced, of the lowerings its layer
+    took; None while nothing has loaded it (the engine does not: a model
+    without the layer imports what it always did)."""
+    mod = sys.modules.get(module)
+    return None if mod is None else getattr(mod, fn)()
+
+
 def _grouped_lowerings() -> Optional[Dict[str, int]]:
-    """``ops/grouped_matmul.py``'s counts of the grouped products traced so
-    far by lowering; None while no expert layer has loaded that module (the
-    engine does not load it: a dense model imports what it always did)."""
-    mod = sys.modules.get("deepspeed_tpu.ops.grouped_matmul")
-    return None if mod is None else mod.lowerings()
+    """The experts' grouped products (``ops/grouped_matmul.py``)."""
+    return _traced_counts("deepspeed_tpu.ops.grouped_matmul")
 
 
 def _dispatch_lowerings() -> Optional[Dict[str, int]]:
-    """``moe/sharded_moe.py``'s counts of the dispatches and combines traced
-    so far by lowering; None while nothing has loaded that module."""
-    mod = sys.modules.get("deepspeed_tpu.moe.sharded_moe")
-    return None if mod is None else mod.dispatch_lowerings()
+    """The expert layers' dispatches and combines (``moe/sharded_moe.py``)."""
+    return _traced_counts("deepspeed_tpu.moe.sharded_moe",
+                          "dispatch_lowerings")
+
+
+def _scan_lowerings() -> Optional[Dict[str, int]]:
+    """The state-space layers' scans (``ops/ssd_scan.py``)."""
+    return _traced_counts("deepspeed_tpu.ops.ssd_scan")
 
 
 def _counted(before: Optional[Dict[str, int]],
@@ -1059,6 +1069,7 @@ class DeepSpeedTpuEngine:
             before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
             grouped_before = _grouped_lowerings()
             dispatch_before = _dispatch_lowerings()
+            scan_before = _scan_lowerings()
         t0 = time.perf_counter()
         with steplog.span(self._ebus, "train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
@@ -1087,6 +1098,7 @@ class DeepSpeedTpuEngine:
                                              _grouped_lowerings())
         row.moe_dispatch_lowerings = _counted(dispatch_before,
                                               _dispatch_lowerings())
+        row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
 
     def _fused_train_step(self, batch):
         ga = int(self.config.gradient_accumulation_steps)

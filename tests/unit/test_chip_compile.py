@@ -152,6 +152,29 @@ def _grouped(product, C=2304, O=896, rows=65536, experts=16):
             [xs, ((experts, C, O), bf), sizes])
 
 
+def _ssd(grad, H=64, P=64, G=1, N=128, T=4096, chunk=256):
+    """The state-space scan at the Granite cell's shapes (BENCHMARK.json: 1 x
+    4096 tokens, 64 heads of 64, one group, a state of 128, chunks of 256),
+    by the picker's answer for the shape as if on the chip: the forward
+    kernel alone, and the differentiated forward with the backward kernel."""
+    from deepspeed_tpu.ops import ssd_scan as ss
+
+    took, _ = ss.scan_lowering(chunk, H, P, G, N, jnp.bfloat16, tpu=True)
+
+    def fwd(x, dt, A, B, C, D):
+        if took == "xla":
+            return ss.scan_einsum(x, dt, A, B, C, D, chunk)
+        return ss._scan_pallas(x, dt, A, B, C, D, chunk, False)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    bc = ((1, T, G, N), jnp.bfloat16)
+    return (jax.grad(loss, argnums=tuple(range(6))) if grad else fwd), [
+        ((1, T, H, P), jnp.bfloat16), ((1, T, H), jnp.float32),
+        ((H,), jnp.float32), bc, bc, ((H,), jnp.float32)]
+
+
 # (builder, kwargs, must the compiled program hold a Mosaic kernel?)
 CASES = {
     "flash-fwd-d64": (_flash, dict(d=64, grad=False), True),
@@ -212,6 +235,14 @@ CASES = {
         _grouped, dict(product="gmm-transposed-pair"), True),
     "grouped-tgmm-gate-up": (_grouped, dict(product="tgmm"), True),
     "grouped-tgmm-down": (_grouped, dict(product="tgmm", C=896, O=2304), True),
+    "ssd-scan-fwd-granite-cell": (_ssd, dict(grad=False), True),
+    "ssd-scan-grad-granite-cell": (_ssd, dict(grad=True), True),
+    # two groups, heads of 128 channels, a T that is padded to whole chunks
+    "ssd-scan-grad-2groups-p128": (
+        _ssd, dict(grad=True, H=16, P=128, G=2, T=1000, chunk=128), True),
+    # heads of 32 channels: the picker gives the einsum form
+    "ssd-scan-grad-p32-einsum": (
+        _ssd, dict(grad=True, H=16, P=32, T=1024), False),
 }
 
 

@@ -333,10 +333,42 @@ def test_the_step_record_carries_the_mixer_outputs():
     assert prog.layer_pattern == ("ssm", "ssm", "full", "ssm")
     assert prog.ssm_chunk == 8
     assert prog.ssm_chunks_per_step == 3 * 2 * 3     # layers x rows x 24 / 8
+    # float32 heads of 16 on a CPU: every scan the trace holds is the einsum
+    # form (its backward is autodiff's and is not counted)
+    assert prog.ssm_scan_lowerings == {"pallas": 0, "xla": 3}
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
     assert "ssm_chunk" not in dense.step_program_facts()
     assert dense.ssm_chunks_scanned((2, 24)) is None
+
+
+def test_the_cell_shaped_step_program_takes_the_scan_kernels(monkeypatch):
+    """What the benchmark's cell is in small: bf16, heads of 64, a state of
+    128, chunks of whole tiles, recomputation. Every scan of the program is
+    the Pallas kernels (interpreted here: the CPU stands in for the chip),
+    the backward too, and the step gives the einsum form's loss."""
+    import functools
+
+    from deepspeed_tpu.models import mamba
+    from deepspeed_tpu.observability import steplog
+
+    hf = hf_config(D=128, mamba_n_heads=4, mamba_d_head=64,
+                   mamba_d_state=128, mamba_n_groups=2, mamba_chunk_size=128)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 200)).astype(np.int32)
+    kw = dict(remat_policy="full", dtype="bfloat16", max_seq_len=256)
+    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    assert steplog.programs()[-1].ssm_scan_lowerings == {"pallas": 0,
+                                                         "xla": 3}
+    monkeypatch.setattr(mamba, "ssd_scan", functools.partial(
+        mamba.ssd_scan, interpret=True))
+    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    prog = steplog.programs()[-1]
+    # the period's three scans and the backward of each
+    assert prog.ssm_scan_lowerings == {"pallas": 6, "xla": 0}
+    assert prog.ssm_chunks_per_step == 3 * 2 * 2     # 200 tokens: two chunks
+    np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 
 @pytest.mark.parametrize("stage", [0, 1, 2, 3])

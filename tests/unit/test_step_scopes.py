@@ -106,6 +106,33 @@ def test_every_operation_carries_a_step_scope(case):
     assert found == want
 
 
+def test_the_scan_kernels_keep_the_scan_scope(monkeypatch):
+    """The hybrid case at shapes the state-space scan's Pallas kernels take
+    (interpreted here): what the forward kernel, its recomputation and the
+    backward kernel lower to lies under ``attn/ssm_scan`` with the jitted
+    kernel's name in the path, which is how the benchmark's scope readers
+    find the Mosaic calls on the chip; nothing is left without a scope."""
+    import functools
+
+    from deepspeed_tpu.models import mamba
+
+    monkeypatch.setattr(mamba, "ssd_scan", functools.partial(
+        mamba.ssd_scan, interpret=True))
+    over = dict(CASES["hybrid"][0], ssm_heads=2, ssm_head_dim=64,
+                ssm_state=128, ssm_groups=1, ssm_chunk=128)
+    names = _op_names(over, 1)
+    assert steplog.programs()[-1].ssm_scan_lowerings == {"pallas": 6,
+                                                         "xla": 0}
+    parts = [set(re.split(r"[/()]", n)) for n in names]
+    assert all(p & set(STEP_SCOPES) for p in parts)
+    fwd = [n for n in names if "/ssm_scan/jit(ssd_fwd)/" in n]
+    bwd = [n for n in names if "/ssm_scan/jit(ssd_bwd)/" in n]
+    assert fwd and bwd and all("/attn/" in n for n in fwd + bwd)
+    assert all("transpose(" in n for n in bwd)
+    assert any("rematted_computation" in n for n in fwd)
+    assert any("transpose(" not in n for n in fwd)
+
+
 def test_backward_operations_keep_their_scope():
     names = _op_names(*CASES["dense"])
     back = [n for n in names if "transpose(" in n]
