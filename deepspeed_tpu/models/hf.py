@@ -54,13 +54,13 @@ OURO_TENSORS = {
     "model.early_exit_gate.bias": ("exit_gate", "b"),       # [1] there
 }
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
-                              "granitemoehybrid")
+                              "granitemoehybrid", "deepseek_v3")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm"}
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
-_CONFIG_ONLY = ("mellum", "granitemoehybrid")
+_CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3")
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
            "gelu_pytorch_tanh": "gelu", "gelu_fast": "gelu", "relu": "relu"}
@@ -210,6 +210,57 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             embedding_multiplier=float(get("embedding_multiplier")),
             residual_multiplier=float(get("residual_multiplier")),
             logits_scaling=float(get("logits_scaling")),
+        )
+    elif model_type == "deepseek_v3":
+        # latent attention (keys of qk_nope + qk_rope over values of
+        # v_head_dim through a latent of kv_lora_rank), a leading run of
+        # dense FFN layers, then routed layers: sigmoid scores with a
+        # selection bias, the top k normalised and scaled, shared experts.
+        # The config side only, and only this shape of the family: one query
+        # matrix, one routing group, a plain rope. What training adds (the
+        # bias rule's rate, the balance term's weight) is no config key:
+        # pass moe_bias_rate= and moe_aux_loss_coef=.
+        if get("q_lora_rank") is not None:
+            raise ValueError(
+                f"deepseek_v3 with q_lora_rank={get('q_lora_rank')} (a "
+                f"low-rank query path with its norm) is not mapped")
+        if int(get("n_group", 1) or 1) > 1:
+            raise ValueError(
+                f"deepseek_v3 with n_group={get('n_group')} (group-limited "
+                f"routing) is not mapped")
+        if get("rope_scaling"):
+            raise ValueError(
+                f"deepseek_v3 with rope_scaling={get('rope_scaling')} (yarn "
+                f"with the family's mscale) is not mapped: a plain rope only")
+        if (get("scoring_func", "sigmoid") != "sigmoid"
+                or not get("norm_topk_prob", True)
+                or get("attention_bias", False)
+                or int(get("moe_layer_freq", 1)) != 1):
+            raise ValueError(
+                "deepseek_v3 is mapped with sigmoid scores whose top k is "
+                "normalised, no attention biases and every layer after the "
+                "dense ones routed (moe_layer_freq 1)")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=get("num_hidden_layers"),
+            num_heads=get("num_attention_heads"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            rope_theta=float(get("rope_theta", 10000.0)),
+            kv_lora_rank=get("kv_lora_rank"),
+            qk_nope_head_dim=get("qk_nope_head_dim"),
+            qk_rope_head_dim=get("qk_rope_head_dim"),
+            v_head_dim=get("v_head_dim"),
+            rope_interleave=bool(get("rope_interleave", True)),
+            first_k_dense=int(get("first_k_dense_replace", 0)),
+            num_experts=get("n_routed_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            moe_dispatch="grouped", moe_scoring="sigmoid",
+            moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+            moe_shared_experts=int(get("n_shared_experts", 0) or 0),
         )
     elif model_type == "falcon":
         if get("alibi", False):

@@ -19,7 +19,9 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
+import operator
 from functools import partial
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
@@ -161,6 +163,38 @@ class TransformerConfig:
     # None = all of them
     moe_experts_held: Optional[int] = None
     moe_first_expert: int = 0
+    # how the router scores: "softmax" (the top k of the softmax, weights
+    # renormalised, GShard's balance term) | "sigmoid" (the DeepSeek-V3
+    # family: the top k of ``sigmoid + router_bias``, weights the sigmoids
+    # without the bias, normalised, times ``moe_routed_scale``; the
+    # sequence-wise balance term; grouped dispatch only). ``router_bias``
+    # gets no gradient: after every step the engine moves it by
+    # ``moe_bias_rate x sign(mean count - count)`` (:meth:`TransformerLM.
+    # rule_updates`); it is drawn uniform in +-``moe_bias_init``
+    moe_scoring: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_bias_rate: float = 0.0
+    moe_bias_init: float = 0.0
+    # this many shared experts, every token's: one SwiGLU of that many times
+    # the experts' width beside the routed ones (grouped dispatch only)
+    moe_shared_experts: int = 0
+    # FFN kinds by layer: the first ``first_k_dense`` layers' FFN is dense at
+    # ``intermediate_size``, the others' routed (num_experts > 1); each kind
+    # has a stack of its own (``mlp_dense``, ``mlp_moe``)
+    first_k_dense: int = 0
+    # latent attention (models/mla.py), every layer's mixer where
+    # ``kv_lora_rank`` is set: keys and values through a latent of that rank
+    # with a norm in the middle, keys ``qk_nope_head_dim + qk_rope_head_dim``
+    # wide (the rope part one vector a position, shared by the heads), values
+    # ``v_head_dim``; ``rope_interleave``: the published weights pair the
+    # rope columns (2i, 2i + 1). A low-rank query path (``q_lora_rank``) is
+    # not implemented
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    rope_interleave: bool = False
 
     def __post_init__(self):
         is_llama = self.arch == "llama"
@@ -194,6 +228,10 @@ class TransformerConfig:
                              "loss) needs num_passes >= 2")
         if self.attn_pattern is not None:
             pat = tuple(self.attn_pattern)
+            if self.kv_lora_rank is not None:
+                raise NotImplementedError(
+                    "latent attention (kv_lora_rank) is every layer's mixer: "
+                    "not with attn_pattern")
             if not pat or set(pat) - {"window", "full", "ssm"} \
                     or self.num_layers % len(pat):
                 raise ValueError(
@@ -222,6 +260,46 @@ class TransformerConfig:
                     "sandwich_norm, the exit gate, num_experts > 1, "
                     "parallel_block, loss_tiling > 1 or attention_impl="
                     "'fpdt'")
+        if self.has_mla:
+            if self.q_lora_rank is not None:
+                raise NotImplementedError(
+                    f"q_lora_rank={self.q_lora_rank}: the low-rank query "
+                    f"path with its norm is not implemented; latent "
+                    f"attention takes one query matrix (q_lora_rank None)")
+            if (self.looped or self.parallel_block or self.qkv_bias
+                    or self.proj_bias or self.sliding_window is not None
+                    or self.rope_scaling or self.rope_by_kind
+                    or not self.use_rope or self.norm != "rmsnorm"
+                    or self.attention_multiplier is not None
+                    or self.loss_tiling > 1 or self.attention_impl == "fpdt"):
+                raise NotImplementedError(
+                    "latent attention (kv_lora_rank) runs one pre-norm "
+                    "RMSNorm pass with a plain rope over whole sequences and "
+                    "whole logits: not num_passes > 1, sandwich_norm, the "
+                    "exit gate, parallel_block, biases, sliding_window, "
+                    "rope_scaling, rope_by_kind, use_rope=False, "
+                    "attention_multiplier, loss_tiling > 1 or "
+                    "attention_impl='fpdt'")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring={self.moe_scoring!r}: 'softmax' "
+                             f"or 'sigmoid'")
+        if (self.moe_scoring == "sigmoid" or self.moe_shared_experts
+                or self.first_k_dense) and (
+                    self.num_experts <= 1 or self.moe_dispatch != "grouped"
+                    or self.activation != "swiglu"):
+            raise ValueError(
+                "moe_scoring='sigmoid', moe_shared_experts and first_k_dense "
+                "belong to a model with routed SwiGLU experts under the "
+                "grouped dispatch (num_experts > 1, moe_dispatch='grouped')")
+        if not 0 <= self.first_k_dense < self.num_layers:
+            raise ValueError(f"first_k_dense={self.first_k_dense} of "
+                             f"num_layers={self.num_layers}")
+        if self.has_ffn_kinds and (self.looped or self.parallel_block
+                                   or self.loss_tiling > 1):
+            raise NotImplementedError(
+                "FFN kinds by layer (first_k_dense) run one pre-norm pass "
+                "with whole logits: not num_passes > 1, sandwich_norm, the "
+                "exit gate, parallel_block or loss_tiling > 1")
         if self.moe_experts_held is not None:
             lo, n = self.moe_first_expert, self.moe_experts_held
             if not (n >= 1 and lo >= 0 and lo + n <= self.num_experts):
@@ -256,22 +334,44 @@ class TransformerConfig:
         return "ssm" in (self.attn_pattern or ())
 
     @property
+    def has_mla(self) -> bool:
+        """Whether the layers' mixer is latent attention."""
+        return self.kv_lora_rank is not None
+
+    @property
+    def has_ffn_kinds(self) -> bool:
+        """Whether the layers' FFNs are of more than one kind (a leading run
+        of dense ones before the routed ones)."""
+        return self.first_k_dense > 0
+
+    @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The kind of every layer: "window", "full" or "ssm"."""
+        """The kind of every layer: its mixer's, "window", "full", "ssm" or
+        "mla"; in a model whose FFNs differ by layer, then ":" and its
+        FFN's, "dense" or "moe"."""
         pat = self.attn_pattern or (
+            ("mla",) if self.has_mla else
             ("full",) if self.sliding_window is None else ("window",))
-        return pat * (self.num_layers // len(pat))
+        kinds = pat * (self.num_layers // len(pat))
+        if self.has_ffn_kinds:
+            kinds = tuple(
+                k + (":dense" if i < self.first_k_dense else ":moe")
+                for i, k in enumerate(kinds))
+        return kinds
 
     @property
     def patterned(self) -> bool:
         """Whether the layers are of more than one kind, or of one that is no
-        attention: the layer loop then runs by kind (``_run_periods``)."""
-        return len(set(self.layer_kinds)) > 1 or self.has_ssm
+        plain attention: the layer loop then runs by kind
+        (``_run_periods``)."""
+        return (len(set(self.layer_kinds)) > 1 or self.has_ssm
+                or self.has_mla)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
         full layer, the kind's own rope. The same object where nothing
         differs."""
+        kind = kind.partition(":")[0]
         rope = (self.rope_by_kind or {}).get(kind)
         window = self.sliding_window if kind == "window" else None
         if (rope is None and window == self.sliding_window
@@ -288,30 +388,57 @@ class TransformerConfig:
 
     @property
     def rope_dim(self) -> int:
-        """Rotary dims per head (gpt-neox style partial rotary when < head_dim)."""
+        """Rotary dims per head (gpt-neox style partial rotary when < head_dim;
+        under latent attention the keys' rope part)."""
+        if self.has_mla:
+            return self.qk_rope_head_dim
         return 2 * (int(self.head_dim * self.rope_pct) // 2)
 
     def num_params_estimate(self) -> int:
+        """The leaves ``TransformerLM.init`` makes, counted from the sizes:
+        each layer's mixer and FFN by its kind, the norms once each."""
         D, F, V, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         hd, nh, nkv = self.head_dim, self.num_heads, self.num_kv_heads
+        gated = self.activation == "swiglu"
+        norm = D * (2 if self.norm == "layernorm" else 1)
+        norms = norm * ((1 if self.parallel_shared_norm else 2)
+                        + (2 if self.sandwich_norm else 0))
         attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
-        mlp = (3 if self.activation == "swiglu" else 2) * D * F
-        norms = (2 * D) * (2 if self.norm == "layernorm" else 1)
-        per_layer = attn + mlp + 2 * norms
-        if self.sandwich_norm:
-            per_layer += norms   # the two post-branch scales, counted once
-        ssm_extra = 0
+        if self.qkv_bias:
+            attn += (nh + 2 * nkv) * hd
+        if self.proj_bias:
+            attn += D
+        dense = (3 if gated else 2) * D * F
+        if self.proj_bias and not gated:
+            dense += F + D
+        routed = dense
+        if self.num_experts > 1:
+            Fm = self.moe_intermediate_size or F
+            routed = ((self.moe_experts_held or self.num_experts)
+                      * (3 if gated else 2) * D * Fm + D * self.num_experts
+                      + 3 * D * Fm * self.moe_shared_experts)
+            if self.moe_scoring == "sigmoid":
+                routed += self.num_experts
+        mixers = {"attn": attn}
         if self.has_ssm:
             from deepspeed_tpu.models import mamba
 
-            ssm_extra = self.layer_kinds.count("ssm") \
-                * (mamba.num_params(self) - attn)
+            mixers["ssm"] = mamba.num_params(self)
+        if self.has_mla:
+            from deepspeed_tpu.models import mla
+
+            mixers["mla"] = mla.num_params(self)
+        layers = 0
+        for kind in self.layer_kinds:
+            mixer, _, ffn = kind.partition(":")
+            layers += (mixers[_MIXER_GROUP[mixer]] + norms
+                       + (dense if ffn == "dense" else routed))
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
         head = 0 if self.tie_embeddings else D * V
         gate = D + 1 if self.exit_loss_beta is not None else 0
         # passes share their weights: the count does not grow with them
         # (models/spec.py:model_flops_per_token multiplies the work)
-        return L * per_layer + ssm_extra + embed + head + D + gate
+        return layers + embed + head + norm + gate
 
 
 # ---------------------------------------------------------------------------
@@ -743,17 +870,38 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                "moe_router", "moe_dispatch", "moe_experts",
                # a state-space layer's parts inside attn, the token mixer's
                # slot (models/mamba.py)
-               "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate")
+               "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate",
+               # latent attention's parts inside attn/attn_mla
+               # (models/mla.py); the shared experts inside moe
+               "attn_mla", "mla_proj", "mla_rope", "moe_shared")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind
 _MAX_PERIOD = 8
 #: a state-space layer's leaves that stay float32 in the compute copy of the
 #: weights: they enter an exponential or a softplus, never a matmul
 _SSM_FP32 = ("A_log", "dt_bias", "D")
-#: the group of ``params["layers"]`` that holds a kind's mixer; its stack has
-#: one row for each layer of the group's kinds, in layer order. Every other
-#: group (norms, FFN) has a row for every layer
-_MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm"}
+#: leaves that stay float32 in the compute copy whatever group holds them:
+#: a state-space layer's, and the router's selection bias, whose steps of
+#: ``moe_bias_rate`` bf16 would round away
+_KEEP_FP32 = _SSM_FP32 + ("router_bias",)
+#: the groups of ``params["layers"]`` that hold a kind's own leaves: a layer's
+#: kind is its mixer's, and in a model whose FFNs differ by layer
+#: (``first_k_dense``) then ":" and its FFN's ("mla:dense", "mla:moe"). Such
+#: a group's stack has one row for each layer of its kinds, in layer order;
+#: every other group (norms; the FFN where all layers' are alike, "mlp") has
+#: a row for every layer
+_MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla"}
+_FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
+_KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
+    _FFN_GROUP.values())
+#: the scope an FFN kind's own group is cast and run under
+_FFN_SCOPE = {"mlp_dense": "mlp", "mlp_moe": "moe"}
+
+
+def _groups_of(kind: str) -> Tuple[str, ...]:
+    """The groups that hold the own leaves of a layer of ``kind``."""
+    mixer, _, ffn = kind.partition(":")
+    return (_MIXER_GROUP[mixer],) + ((_FFN_GROUP[ffn],) if ffn else ())
 
 
 def _times(x: jax.Array, factor: float) -> jax.Array:
@@ -770,10 +918,12 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
 
     out = {}
     for k, v in w.items():
-        with jax.named_scope("attn" if k in ("ln1", "attn", "ssm", "ln1_post")
-                             else ffn):
-            if k == "ssm":
-                out[k] = {n: p if n in _SSM_FP32 else cast(p)
+        with jax.named_scope(
+                "attn" if k in ("ln1", "attn", "ssm", "mla", "ln1_post")
+                else _FFN_SCOPE.get(k, ffn)):
+            if k == "ssm" or "router_bias" in v:
+                out[k] = {n: p if n in _KEEP_FP32
+                          else jax.tree_util.tree_map(cast, p)
                           for n, p in v.items()}
             else:
                 out[k] = jax.tree_util.tree_map(cast, v)
@@ -793,14 +943,19 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     ``kind`` names the layer's kind in a model that has several: an
     attention layer's operations then lie under ``attn/attn_<kind>``; a
     layer of kind "ssm" mixes its tokens with ``w["ssm"]``
-    (models/mamba.py:ssm_block) under ``attn/ssm_*``. With ``mix_ms`` the aux
-    value is a dict that also holds the mean square of the mixer's output
-    (``mix_out_ms``)."""
+    (models/mamba.py:ssm_block) under ``attn/ssm_*``, one of kind "mla" with
+    ``w["mla"]`` (models/mla.py:mla_block); a kind that names its FFN
+    ("mla:dense") runs ``moe_fn`` only where that is "moe". With ``mix_ms``
+    the aux value is a dict that also holds the mean square of the mixer's
+    output (``mix_out_ms``)."""
     # named scopes land in HLO op metadata — the per-module profiler
     # (profiling/flops_profiler.per_module_profile) and the benchmark's
     # device-time-by-scope reader group cost by them. Every operation of the
     # block lies under one of STEP_SCOPES: the first norm with attention, the
     # residual adds and the second norm with the FFN.
+    kind, _, ffn_kind = (kind or "").partition(":")
+    if ffn_kind == "dense":
+        moe_fn = None
     ffn = "moe" if moe_fn is not None else "mlp"
     wc = _cast_layers(w, jnp.dtype(cfg.dtype), ffn)
     res = cfg.residual_multiplier
@@ -813,6 +968,10 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
 
             attn_out = constrain(ssm_block(hn1, wc["ssm"], cfg),
                                  P(("dp", "fsdp"), "sp", None))
+        elif kind == "mla":
+            from deepspeed_tpu.models.mla import mla_block
+
+            attn_out = mla_block(hn1, wc["mla"], cfg, freqs, attn_fn)
         else:
             attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                        positions=positions)
@@ -841,7 +1000,11 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         if res != 1.0:
             mlp_out = _times(mlp_out, res)
         if mix_ms:
-            aux = {"lb": aux, "mix_out_ms": ms}
+            # a layer without a router (a dense one among routed ones)
+            # reports no balance term
+            if not isinstance(aux, dict):
+                aux = {} if ffn_kind == "dense" else {"lb": aux}
+            aux = {**aux, "mix_out_ms": ms}
         x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
         return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
 
@@ -938,11 +1101,16 @@ def _share_parts(aux: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     over the mean)."""
     pairs = aux["expert_pairs"]
     total = pairs.sum(axis=-1)
-    return {"lb_loss": aux["lb"], "expert_pairs": pairs,
-            "pairs_here": total - aux["pairs_dropped"],
-            "pairs_dropped": aux["pairs_dropped"],
-            "load_max_over_mean": pairs.max(axis=-1) * pairs.shape[-1]
-            / jnp.maximum(total, 1).astype(jnp.float32)}
+    parts = {"lb_loss": aux["lb"], "expert_pairs": pairs,
+             "pairs_here": total - aux["pairs_dropped"],
+             "pairs_dropped": aux["pairs_dropped"],
+             "load_max_over_mean": pairs.max(axis=-1) * pairs.shape[-1]
+             / jnp.maximum(total, 1).astype(jnp.float32)}
+    if "router_counts" in aux:
+        # a sigmoid router's: the pairs every expert the router scores
+        # received, held here or not (what the bias rule reads)
+        parts["router_counts"] = aux["router_counts"]
+    return parts
 
 
 def _by_period(tree, lo: int, hi: int, p: int):
@@ -961,19 +1129,19 @@ def _block_of(xs, j: int, p: int):
 
 
 def _in_group(kinds, grp: str) -> int:
-    """How many of ``kinds`` keep their mixer in the group ``grp``."""
-    return sum(_MIXER_GROUP[k] == grp for k in kinds)
+    """How many of ``kinds`` keep leaves of their own in the group ``grp``."""
+    return sum(grp in _groups_of(k) for k in kinds)
 
 
 def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
     """Layers ``[lo, hi)`` of the stacks as the scan input of a loop over
     periods of the kinds ``period``, group by group (:func:`_by_period`): a
-    mixer's group is cut to the rows of its own layers among them (a group
+    kind's group is cut to the rows of its own layers among them (a group
     none of the period's kinds reads is left out), every other group to the
     layers themselves."""
     out = {}
     for grp in sorted(layers):
-        if grp in _MIXER_GROUP.values():
+        if grp in _KIND_GROUPS:
             n = _in_group(period, grp)
             if n:
                 first = _in_group(kinds[:lo], grp)
@@ -987,15 +1155,16 @@ def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
 
 def _block_weights(xs: Params, j: int, period) -> Params:
     """Block ``j``'s weights out of one period's scan input
-    (:func:`_segment`): its own mixer's group and every shared group."""
-    mine = _MIXER_GROUP[period[j]]
+    (:func:`_segment`): its kind's own groups (its FFN's under ``mlp``, where
+    the block reads it) and every shared group."""
+    mine = _groups_of(period[j])
     out = {}
     for grp in sorted(xs):
-        if grp not in _MIXER_GROUP.values():
+        if grp not in _KIND_GROUPS:
             out[grp] = _block_of(xs[grp], j, len(period))
-        elif grp == mine:
-            out[grp] = _block_of(xs[grp], _in_group(period[:j], grp),
-                                 _in_group(period, grp))
+        elif grp in mine:
+            out["mlp" if grp in _FFN_GROUP.values() else grp] = _block_of(
+                xs[grp], _in_group(period[:j], grp), _in_group(period, grp))
     return out
 
 
@@ -1014,6 +1183,17 @@ def _by_layer(ys, p: int):
     ``[layers, ...]``."""
     return ys if p == 1 else jax.tree_util.tree_map(
         lambda a: a.reshape((a.shape[0] * p,) + a.shape[2:]), ys)
+
+
+def _join_runs(per_layer: list):
+    """The runs' per-layer aux values as one, run after run. Runs of
+    different FFN kinds report different parts (a dense layer has no
+    router): each part then holds the layers that report it."""
+    if (all(isinstance(a, dict) for a in per_layer)
+            and len({tuple(a) for a in per_layer}) > 1):
+        return {k: jnp.concatenate([a[k] for a in per_layer if k in a])
+                for k in dict.fromkeys(k for a in per_layer for k in a)}
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *per_layer)
 
 
 def _layer_aux(auxes):
@@ -1068,6 +1248,15 @@ class TransformerLM:
         multipliers (only ``transformer_block`` and the train forward apply
         them)."""
         cfg = self.cfg
+        if cfg.has_mla or cfg.has_ffn_kinds:
+            raise NotImplementedError(
+                f"{what} is written for layers of one FFN kind whose "
+                f"attention has one head width for q, k and v: this model "
+                f"has latent attention (kv_lora_rank={cfg.kv_lora_rank}: "
+                f"keys wider than values through a latent it would have to "
+                f"cache) or FFN kinds by layer (first_k_dense="
+                f"{cfg.first_k_dense}: a stack for each kind); only the "
+                f"train step runs them")
         if cfg.has_ssm:
             raise NotImplementedError(
                 f"{what} is written for attention layers: this model has "
@@ -1121,10 +1310,17 @@ class TransformerLM:
         cfg = self.cfg
         facts: Dict[str, Any] = {
             "layer_applications": self.layer_applications,
-            "layer_pattern": cfg.attn_pattern or cfg.layer_kinds[:1]}
+            # (a stack whose FFNs differ by layer: its runs' kinds)
+            "layer_pattern": cfg.attn_pattern or tuple(dict.fromkeys(
+                cfg.layer_kinds))}
         if cfg.has_ssm:
             facts["ssm_chunk"] = cfg.ssm_chunk
+        if cfg.has_mla:
+            facts["attn_widths"] = (
+                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim)
         if cfg.num_experts > 1:
+            if cfg.moe_scoring != "softmax":
+                facts["moe_scoring"] = cfg.moe_scoring
             facts["experts_held"] = (
                 cfg.moe_first_expert if cfg.moe_experts_held else 0,
                 cfg.moe_experts_held or cfg.num_experts, cfg.num_experts)
@@ -1134,6 +1330,35 @@ class TransformerLM:
                 facts["moe_kernel_resolved"] = resolve_moe_kernel(
                     cfg.moe_kernel)[0]
         return facts
+
+    def rule_leaves(self) -> Tuple[Tuple[str, ...], ...]:
+        """The paths of the parameter leaves that no gradient moves: a step
+        moves them by a rule of the model's own (:meth:`rule_updates`), and
+        the engine keeps them out of the gradient norm and the optimizer's
+        update (weight decay with it). Here a sigmoid router's selection
+        bias, which only picks experts."""
+        cfg = self.cfg
+        if cfg.num_experts > 1 and cfg.moe_scoring == "sigmoid":
+            return (("layers", "mlp_moe" if cfg.has_ffn_kinds else "mlp",
+                     "router_bias"),)
+        return ()
+
+    def rule_updates(self, params: Params, parts: Dict[str, jax.Array]
+                     ) -> Dict[Tuple[str, ...], jax.Array]:
+        """Each of :meth:`rule_leaves` after a step whose loss had the parts
+        ``parts`` (``loss_and_parts``): the selection bias of an expert that
+        received fewer pairs than the layer's mean rises by
+        ``moe_bias_rate``, one that received more falls by it (DeepSeek-V3's
+        balancing without an auxiliary loss), from the counts of this
+        device's tokens."""
+        out = {}
+        for path in self.rule_leaves():
+            counts = parts["router_counts"].astype(jnp.float32)   # [L, E]
+            bias = functools.reduce(operator.getitem, path, params)
+            out[path] = bias + self.cfg.moe_bias_rate * jnp.sign(
+                counts.mean(axis=-1, keepdims=True) - counts).astype(
+                    bias.dtype)
+        return out
 
     def ssm_chunks_scanned(self, batch_shape) -> Optional[int]:
         """Chunks the state-space layers of one step's forward scan over a
@@ -1179,35 +1404,56 @@ class TransformerLM:
             attn_w["bv"] = jnp.zeros((La, K * hd), pd)
         if cfg.proj_bias:
             attn_w["bo"] = jnp.zeros((La, D), pd)
-        mlp = ({"w_gate": layer_stack(keys[4], D, (D, F)),
-                "w_up": layer_stack(keys[5], D, (D, F)),
-                "w_down": layer_stack(keys[6], F, (F, D))}
+        # the FFNs: one stack with a row a layer, or (first_k_dense) a dense
+        # stack and a routed one
+        Ld = cfg.first_k_dense if cfg.has_ffn_kinds else L
+        mlp = ({"w_gate": layer_stack(keys[4], D, (D, F), Ld),
+                "w_up": layer_stack(keys[5], D, (D, F), Ld),
+                "w_down": layer_stack(keys[6], F, (F, D), Ld)}
                if cfg.activation == "swiglu" else
-               {"w_up": layer_stack(keys[5], D, (D, F)),
-                "w_down": layer_stack(keys[6], F, (F, D))})
+               {"w_up": layer_stack(keys[5], D, (D, F), Ld),
+                "w_down": layer_stack(keys[6], F, (F, D), Ld)})
         if cfg.proj_bias and cfg.activation != "swiglu":
             mlp["b_up"] = jnp.zeros((L, F), pd)
             mlp["b_down"] = jnp.zeros((L, D), pd)
+        layers: Params = {"ln1": dict(norm_w), "attn": attn_w}
+        if cfg.has_ffn_kinds:
+            layers["mlp_dense"] = mlp
         if cfg.num_experts > 1:
             # the experts held here at their own width; the router scores
             # all of them
             E, Eh = cfg.num_experts, cfg.moe_experts_held or cfg.num_experts
-            F = cfg.moe_intermediate_size or F
-            mlp = ({"w_gate": layer_stack(keys[4], D, (Eh, D, F)),
-                    "w_up": layer_stack(keys[5], D, (Eh, D, F)),
-                    "w_down": layer_stack(keys[6], F, (Eh, F, D))}
+            F, Lm = cfg.moe_intermediate_size or F, L - cfg.first_k_dense
+            mlp = ({"w_gate": layer_stack(keys[4], D, (Eh, D, F), Lm),
+                    "w_up": layer_stack(keys[5], D, (Eh, D, F), Lm),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, D), Lm)}
                    if cfg.activation == "swiglu" else
-                   {"w_up": layer_stack(keys[5], D, (Eh, D, F)),
-                    "w_down": layer_stack(keys[6], F, (Eh, F, D))})
-            mlp["router"] = layer_stack(keys[7], D, (D, E))
-        layers: Params = {"ln1": dict(norm_w), "attn": attn_w, "mlp": mlp}
+                   {"w_up": layer_stack(keys[5], D, (Eh, D, F), Lm),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, D), Lm)})
+            mlp["router"] = layer_stack(keys[7], D, (D, E), Lm)
+            if cfg.moe_scoring == "sigmoid" or cfg.moe_shared_experts:
+                more = jax.random.split(jax.random.fold_in(rng, 13), 4)
+            if cfg.moe_scoring == "sigmoid":
+                mlp["router_bias"] = jax.random.uniform(
+                    more[0], (Lm, E), pd, -1.0, 1.0) * cfg.moe_bias_init
+            if cfg.moe_shared_experts:
+                Fs = cfg.moe_shared_experts * F
+                mlp["shared"] = {
+                    "w_gate": layer_stack(more[1], D, (D, Fs), Lm),
+                    "w_up": layer_stack(more[2], D, (D, Fs), Lm),
+                    "w_down": layer_stack(more[3], Fs, (Fs, D), Lm)}
+        layers["mlp_moe" if cfg.has_ffn_kinds else "mlp"] = mlp
         if cfg.has_ssm:
             from deepspeed_tpu.models import mamba
 
             layers["ssm"] = mamba.init(jax.random.fold_in(rng, 12), cfg,
                                        L - La, pd)
-            if not La:
-                del layers["attn"]
+        if cfg.has_mla:
+            from deepspeed_tpu.models import mla
+
+            layers["mla"] = mla.init(jax.random.fold_in(rng, 14), cfg, L, pd)
+        if not La:
+            del layers["attn"]
         if not cfg.parallel_shared_norm:
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         if cfg.sandwich_norm:
@@ -1277,11 +1523,13 @@ class TransformerLM:
         and a full layer pays no window mask. A run reads its kind's own
         stack of mixer leaves (:func:`_segment`): nine state-space layers to
         one attention layer, a period of ten, are at 40 layers nine runs
-        (nine block bodies traced, where a period body would trace ten)."""
+        (nine block bodies traced, where a period body would trace ten); so
+        is a stack whose FFNs differ by layer (a dense run, then a routed
+        one: two bodies)."""
         kinds = self.cfg.layer_kinds
         L = len(kinds)
         period = self.cfg.attn_pattern or kinds[:1]
-        if len(period) <= _MAX_PERIOD:
+        if len(period) <= _MAX_PERIOD and not self.cfg.has_ffn_kinds:
             return [(0, L, period)]
         cuts = [0] + [i for i in range(1, L) if kinds[i] != kinds[i - 1]] + [L]
         return [(lo, hi, (kinds[lo],)) for lo, hi in zip(cuts, cuts[1:])]
@@ -1477,14 +1725,14 @@ class TransformerLM:
                     auxes.append(aux)
                 auxes = _stacked(auxes)
             per_layer.append(_by_layer(auxes, p))
-        return x, _layer_aux(jax.tree_util.tree_map(
-            lambda *a: jnp.concatenate(a), *per_layer))
+        return x, _layer_aux(_join_runs(per_layer))
 
     def _kind_block(self, kind: str, attn_fn: Callable, x: jax.Array,
                     w: Params):
         ck, freqs = self._kinds[kind]
-        return transformer_block(x, w, ck, freqs, attn_fn, self.moe_fn,
-                                 kind=kind, mix_ms=self.cfg.has_ssm)
+        return transformer_block(
+            x, w, ck, freqs, attn_fn, self.moe_fn, kind=kind,
+            mix_ms=self.cfg.has_ssm or self.cfg.has_mla)
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -1542,7 +1790,7 @@ class TransformerLM:
                 loss = (self._tiled_loss(params, batch, hs[-1])
                         if logits is None else lm_loss(cfg, logits, batch))
             parts = {}
-        if cfg.has_ssm:
+        if cfg.has_ssm or cfg.has_mla:
             # by layer, the mean square of the mixer's output
             parts = {**parts, "mix_out_ms": aux["mix_out_ms"]}
         if cfg.num_experts > 1:
@@ -1551,6 +1799,12 @@ class TransformerLM:
                     # a held share of the experts: the load-balance term and
                     # the router's counts go into the step record
                     parts = {**parts, **_share_parts(aux)}
+                    if cfg.moe_bias_rate and "router_counts" in parts:
+                        # the biases the step's rule moves: an expert's
+                        # whose count is not the layer's mean
+                        c = parts["router_counts"]
+                        parts["bias_moved"] = jnp.sum(
+                            c * c.shape[-1] != c.sum(-1, keepdims=True))
                     aux = aux["lb"]
                 loss = loss + cfg.moe_aux_loss_coef * aux
         return loss, parts
@@ -1996,6 +2250,12 @@ class TransformerLM:
                    "w_down": P(None, "ep", "tp", None), "router": P(None, None, None)}
             if cfg.activation != "swiglu":
                 mlp.pop("w_gate")
+        if cfg.num_experts > 1 and cfg.moe_scoring == "sigmoid":
+            mlp["router_bias"] = P(None, None)
+        if cfg.num_experts > 1 and cfg.moe_shared_experts:
+            mlp["shared"] = {"w_gate": P(None, None, "tp"),
+                             "w_up": P(None, None, "tp"),
+                             "w_down": P(None, "tp", None)}
         attn_spec = {"wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
                      "wv": P(None, None, "tp"), "wo": P(None, "tp", None)}
         if cfg.qkv_bias:
@@ -2005,12 +2265,22 @@ class TransformerLM:
         if cfg.proj_bias:
             attn_spec["bo"] = P(None, None)
         layer_specs: Params = {"ln1": norm_spec, "attn": attn_spec, "mlp": mlp}
+        if cfg.has_ffn_kinds:
+            del layer_specs["mlp"]
+            layer_specs["mlp_moe"] = mlp
+            layer_specs["mlp_dense"] = {
+                "w_gate": P(None, None, "tp"), "w_up": P(None, None, "tp"),
+                "w_down": P(None, "tp", None)}
         if cfg.has_ssm:
             from deepspeed_tpu.models import mamba
 
             layer_specs["ssm"] = mamba.param_specs()
-            if not _in_group(cfg.layer_kinds, "attn"):
-                del layer_specs["attn"]
+        if cfg.has_mla:
+            from deepspeed_tpu.models import mla
+
+            layer_specs["mla"] = mla.param_specs()
+        if not _in_group(cfg.layer_kinds, "attn"):
+            del layer_specs["attn"]
         if not cfg.parallel_shared_norm:
             layer_specs["ln2"] = dict(norm_spec)
         if cfg.sandwich_norm:

@@ -167,6 +167,36 @@ def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
     return gates, aux_loss, topk_vals, topk_idx
 
 
+def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
+    """The DeepSeek-V3 family's router (``moe_scoring="sigmoid"``) over
+    ``logits`` [B, T, E] in float32: scores ``s = sigmoid(logits)``, the
+    ``k`` experts with the largest ``s + bias`` (the selection bias picks
+    and gets no gradient), weights ``scale x s_i / sum_{chosen} s_j`` (the
+    bias is not in them). Returns ``(balance term, weights [B T, k], experts
+    [B T, k], counts [E])``: the sequence-wise balance term ``sum_i f_i
+    P_i``, ``f_i = E / (k T) x`` the sequence's pairs of expert i (the chosen
+    pairs, bias included: a constant), ``P_i`` the sequence's mean of ``s_i /
+    sum_j s_j``, averaged over the sequences; and the pairs each expert
+    received from all of them."""
+    B, T, E = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)   # [B, T, k]
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    weights = scale * picked / picked.sum(-1, keepdims=True)
+    by_seq = (idx[..., None] == jnp.arange(E, dtype=idx.dtype)).sum(
+        axis=(1, 2), dtype=jnp.int32)                         # [B, E]
+    f = by_seq.astype(jnp.float32) * (E / (k * T))
+    p = (s / s.sum(-1, keepdims=True)).mean(axis=1)           # [B, E]
+    aux_loss = (f * p).sum(-1).mean()
+    return (aux_loss, weights.reshape(B * T, k), idx.reshape(B * T, k),
+            by_seq.sum(axis=0))
+
+
+def _shared_ffn(h: jax.Array, w: Dict[str, jax.Array]) -> jax.Array:
+    """The shared experts: one SwiGLU every token takes."""
+    return (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
+
+
 def topk_gating(logits: jax.Array, k: int = 2, capacity_factor: float = 1.25,
                 min_capacity: int = 4, rng: Optional[jax.Array] = None,
                 noise_std: float = 0.0, valid: Optional[jax.Array] = None
@@ -429,6 +459,12 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     pairs whose expert is here are computed, what the absent experts would
     add is left out, and the second value returned is a dict: the
     load-balance term under ``lb`` and the router's counts.
+    ``cfg.moe_scoring="sigmoid"`` scores with :func:`_route_sigmoid` (the
+    selection bias ``w["router_bias"]``, ``cfg.moe_routed_scale``) and always
+    returns the dict, with ``router_counts`` [E] beside the held experts'
+    ``expert_pairs``; ``w["shared"]`` (the shared experts' SwiGLU) is added
+    for every token under the scope ``moe_shared``, whole on every share:
+    summed over shares it counts once.
     Under ``ep > 1`` dispatch routes through ``_grouped_moe_ep`` — an explicit
     padded all-to-all over the ``ep`` axis feeding per-shard grouped GEMMs (the
     ``_AllToAll`` of reference ``moe/sharded_moe.py:97``, made dropless) —
@@ -461,11 +497,22 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     factor = float(getattr(cfg, "moe_ep_capacity_factor", 0.0) or 0.0)
     bound = n if not held or factor <= 0.0 else min(
         n, int(math.ceil(n * n_held / E * factor)))
+    sigmoid = getattr(cfg, "moe_scoring", "softmax") == "sigmoid"
+    if sigmoid and valid is not None:
+        raise NotImplementedError(
+            "moe_scoring='sigmoid' routes whole training batches: no "
+            "`valid` mask (the serving paths refuse the model)")
     with jax.named_scope("moe_router"):
         logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)
         # the top k and their weights over ALL experts, as the whole model
-        _gates, aux_loss, topk_vals, topk_idx = _route(
-            logits, k, valid=None if valid is None else valid.reshape(-1))
+        if sigmoid:
+            aux_loss, topk_vals, topk_idx, router_counts = _route_sigmoid(
+                logits.reshape(B, T, E), w["router_bias"], k,
+                float(getattr(cfg, "moe_routed_scale", 1.0)))
+        else:
+            _gates, aux_loss, topk_vals, topk_idx = _route(
+                logits, k,
+                valid=None if valid is None else valid.reshape(-1))
         flat_expert = topk_idx.reshape(-1)                    # [S*k]
         local = flat_expert - first
         here = (local >= 0) & (local < n_held)
@@ -511,12 +558,18 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     with jax.named_scope("moe_dispatch"):
         out = _weighted_sum_of_rows(ys, topk_vals, rows, slot, moves, how)
     out = out.reshape(B, T, D)
-    if not held:
+    if "shared" in w:
+        with jax.named_scope("moe_shared"):
+            out = out + _shared_ffn(h, w["shared"])
+    if not held and not sigmoid:
         return out, aux_loss
     # what the step record carries of a share (models/transformer.py:
     # _share_parts); the partial sum goes on to the next layer as it is
-    return out, {"lb": aux_loss, "expert_pairs": counts,
-                 "pairs_dropped": counts.sum() - n_here}
+    aux = {"lb": aux_loss, "expert_pairs": counts,
+           "pairs_dropped": counts.sum() - n_here}
+    if sigmoid:
+        aux["router_counts"] = router_counts
+    return out, aux
 
 
 # Dispatch and combine as gathers both ways. A pair's row in the buffer is a

@@ -15,8 +15,9 @@ engine.
   outlives the step and nothing is collector-tracked.
 * ``loss_parts`` — for a model whose loss has parts (a looped model's
   per-pass losses and exit distribution, a held share of experts' router
-  counts, by layer the mean square of the mixer's output of a model with
-  state-space layers): the last ``PARTS_KEPT`` steps'
+  counts, a sigmoid router's counts over every expert and the biases its
+  rule moved, by layer the mean square of the mixer's output of a model with
+  state-space layers or latent attention): the last ``PARTS_KEPT`` steps'
   loss and parts as the device values the step program returned. Writing a
   row waits for nothing; :meth:`StepLog.parts` reads them to the host when a
   reader asks, after the step.
@@ -365,7 +366,9 @@ class StepProgram:
                  layer_pattern: Optional[Sequence[str]] = None,
                  moe_kernel_resolved: Optional[str] = None,
                  experts_held: Optional[Sequence[int]] = None,
-                 ssm_chunk: Optional[int] = None):
+                 ssm_chunk: Optional[int] = None,
+                 attn_widths: Optional[Sequence[int]] = None,
+                 moe_scoring: Optional[str] = None):
         self.name = name
         self.key = str(key)
         #: block applications one micro-batch's forward holds (layers run x
@@ -418,6 +421,15 @@ class StepProgram:
         #: and the kernels' backward once more (``ops/ssd_scan.py``); None
         #: where the trace held none
         self.ssm_scan_lowerings: Optional[Dict[str, int]] = None
+        #: (key width, value width) of a head where they differ (latent
+        #: attention: the flash kernels take both, ``flash_bwd_lowerings``
+        #: says which backward ran at them); None elsewhere
+        self.attn_widths = None if attn_widths is None \
+            else tuple(attn_widths)
+        #: how the router scores where it is not the softmax ("sigmoid": a
+        #: selection bias the step moves by rule, ``router_counts`` in the
+        #: step record's parts); None elsewhere
+        self.moe_scoring = moe_scoring
         self.built_at = time.perf_counter()
         #: the length of the ``ds.train.dispatch`` span of the program's
         #: first call (trace, lowering, the compile or its read from the

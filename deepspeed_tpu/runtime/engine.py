@@ -84,6 +84,19 @@ def _counted(before: Optional[Dict[str, int]],
             for kind, n in after.items()}
 
 
+def _leaf(tree, path):
+    for name in path:
+        tree = tree[name]
+    return tree
+
+
+def _with_leaf(tree, path, value):
+    """``tree`` (nested dicts) with the leaf at ``path`` replaced."""
+    if not path:
+        return value
+    return {**tree, path[0]: _with_leaf(tree[path[0]], path[1:], value)}
+
+
 class DeepSpeedTpuEngine:
     """See module docstring. Public surface mirrors ``DeepSpeedEngine``."""
 
@@ -599,6 +612,10 @@ class DeepSpeedTpuEngine:
                 new_params = optax.apply_updates(params, updates)
                 return new_params, new_opt, scaler, gnorm, jnp.zeros((), bool)
 
+        # leaves the model moves by a rule of its own after each step
+        # (``model.rule_leaves`` / ``rule_updates``: a sigmoid router's
+        # selection bias): no gradient, no optimizer update, no weight decay
+        self._rule_leaves = tuple(getattr(model, "rule_leaves", tuple)())
         self._init_fn = jax.jit(model.init, out_shardings=self.param_sharding)
         if tx is not None:
             self._apply_body = apply_step
@@ -853,6 +870,7 @@ class DeepSpeedTpuEngine:
         """Optimizer step at the GA boundary — engine.py:3241."""
         if not self.is_gradient_accumulation_boundary():
             return
+        self._rule_moves_only_here("forward / backward / step")
         # self-healing guard: fires configured faults, then skips (instead of
         # applying) a step whose loss/grads are non-finite
         if self._guard is not None and self._guard.intercept():
@@ -1100,6 +1118,28 @@ class DeepSpeedTpuEngine:
                                               _dispatch_lowerings())
         row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
 
+    def _rule_moves_only_here(self, what: str) -> None:
+        """Raise on a step path that does not carry the model's rule-moved
+        leaves (:meth:`_moved_by_rule`): it would train with them frozen."""
+        if self._rule_leaves:
+            raise NotImplementedError(
+                f"{what} does not carry the leaves the model moves by a "
+                f"rule of its own after each step "
+                f"({['/'.join(p) for p in self._rule_leaves]}: "
+                f"model.rule_updates); only fused_train_step's plain step "
+                f"program (ds_train_step) does")
+
+    def _moved_by_rule(self, params, new_params, parts, skipped):
+        """``new_params`` with the model's rule-moved leaves set from the
+        step's parts (inside the step program; kept as they were where the
+        step was skipped)."""
+        with jax.named_scope("optimizer"):
+            for path, new in self.module.rule_updates(params, parts).items():
+                new_params = _with_leaf(
+                    new_params, path,
+                    jnp.where(skipped, _leaf(params, path), new))
+        return new_params
+
     def _fused_train_step(self, batch):
         ga = int(self.config.gradient_accumulation_steps)
         if self._ltd_cfg is not None:
@@ -1108,6 +1148,11 @@ class DeepSpeedTpuEngine:
         batch = self._inject_ltd_seed(batch)
         if self._guard is not None:
             self._guard.pre_step()  # crash faults fire on the fused path too
+        if (self._offload is not None or self._onebit is not None
+                or self._zpp is not None):
+            self._rule_moves_only_here(
+                "the fused offload, 1-bit and ZeRO++ step programs "
+                "(ds_train_step_offload, _onebit, _zpp)")
         if self._offload is not None:
             return self._guarded_loss(self._fused_offload_step(batch, ga))
         if self._onebit is not None:
@@ -1116,11 +1161,19 @@ class DeepSpeedTpuEngine:
             return self._guarded_loss(self._fused_zpp_step(batch, ga))
         key = ga
         if key not in self._fused_step_cache:
+            rules = self._rule_leaves
+
             def ds_train_step(params, opt_state, batch, scaler):
                 grads, loss, parts = self._fused_grads(
                     params, batch, scaler["scale"], ga)
+                for path in rules:      # out of the norm and the update
+                    grads = _with_leaf(grads, path,
+                                       jnp.zeros_like(_leaf(grads, path)))
                 new_params, new_opt, new_scaler, gnorm, skipped = \
                     self._apply_body(params, opt_state, grads, scaler, ga=float(ga))
+                if rules:
+                    new_params = self._moved_by_rule(params, new_params,
+                                                     parts, skipped)
                 return (new_params, new_opt, new_scaler, loss, gnorm, skipped,
                         parts)
 

@@ -269,6 +269,55 @@ FLASH_BWD = {
 }
 
 
+# The flash kernels at a key width and a value width (latent attention: keys
+# of 128 + 64 rope dimensions over values of 128), forward and backward, at the
+# kanana-2 cell's shape (BENCHMARK.json: 2 x 8192 tokens, 32 heads), where the
+# fused backward fits its budget with dk and dv as a result each, and at a
+# length over it: (rows, T, heads, which backward the shape takes)
+FLASH_TWO_WIDTHS = {
+    "kanana2-cell": (2, 8192, 32, "fused"),
+    "32k-over-budget": (1, 32768, 2, "split"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_TWO_WIDTHS))
+def test_flash_at_a_key_and_a_value_width_compiles_for_v5e(one_chip, name):
+    from deepspeed_tpu.ops import flash_attention as fa
+
+    rows, T, heads, took = FLASH_TWO_WIDTHS[name]
+    assert fa._bwd_takes_fused(T, 192, fa.DEFAULT_BLOCK_Q,
+                               fa.DEFAULT_BLOCK_K, 2, 128) \
+        == (took == "fused")
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  interpret=False).astype(jnp.float32).sum()
+
+    qk = jax.ShapeDtypeStruct((rows, T, heads, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((rows, T, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    before = fa.bwd_lowerings()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    after = fa.bwd_lowerings()
+    assert {n: after[n] - before[n] for n in after} == {
+        "fused": int(took == "fused"), "split": int(took == "split")}
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " custom-call(" in ln]
+    # the forward writes values' width; the fused backward dq by q-tile at
+    # the keys' width and dk, dv each at its own (three results: what
+    # metrics/flash_bwd_roofline.mla.json looks for)
+    assert any(f"= (bf16[{rows},{heads},{T},128]" in ln and "f32[" in ln
+               for ln in calls)
+    if took == "fused":
+        assert any(f"bf16[{rows},{heads},{T // 1024},1024,192]" in ln
+                   and f"bf16[{rows},{heads},{T},192]" in ln
+                   and f"bf16[{rows},{heads},{T},128]" in ln for ln in calls)
+    else:
+        assert len(calls) == 3
+
+
 @pytest.mark.parametrize("name", sorted(FLASH_BWD))
 def test_flash_backward_compiles_for_v5e(one_chip, name):
     import re

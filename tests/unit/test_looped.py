@@ -222,10 +222,10 @@ def test_parameter_count_and_operations_count_the_looped_model():
     cfg, plain = TransformerConfig(**LOOPED), TransformerConfig(**PLAIN)
     made = sum(int(x.size) for x in jax.tree_util.tree_leaves(jax.eval_shape(
         TransformerLM(cfg).init, jax.random.key(0))))
-    # the estimate's known fault (each RMSNorm scale of ln1 and ln2 twice:
-    # PERF.md, open questions) is the plain model's; what the looped model
-    # adds is counted exactly: two scales a layer, the gate and its bias
-    assert cfg.num_params_estimate() - made == 2 * L * 64
+    # the estimate is the leaf count of init (since PR 39: it counted each
+    # RMSNorm scale of ln1 and ln2 twice); what the looped model adds is two
+    # scales a layer, the gate and its bias
+    assert cfg.num_params_estimate() == made
     assert cfg.num_params_estimate() - plain.num_params_estimate() \
         == 2 * L * 64 + 64 + 1
     # every weight but the embedding table works once a pass

@@ -50,11 +50,29 @@ CASES = {
                 "attention_multiplier": 1 / 64, "embedding_multiplier": 12.0,
                 "residual_multiplier": 0.22, "logits_scaling": 8.0,
                 "remat_policy": "full"}, 1),
+    # latent attention under recomputation, a dense layer then routed ones
+    # with a sigmoid router, its selection bias moved by rule in the
+    # optimizer's scope, a held share and shared experts: the mixer's parts
+    # nest inside attn/attn_mla, the shared experts inside moe, and the
+    # dense layer's FFN keeps mlp
+    "mla_moe": ({"num_layers": 3, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+                 "qk_rope_head_dim": 8, "v_head_dim": 16,
+                 "rope_interleave": True, "first_k_dense": 1,
+                 "num_experts": 8, "top_k": 2, "moe_dispatch": "grouped",
+                 "moe_intermediate_size": 32, "moe_experts_held": 4,
+                 "moe_scoring": "sigmoid", "moe_routed_scale": 2.448,
+                 "moe_shared_experts": 2, "moe_bias_rate": 1e-3,
+                 "moe_bias_init": 0.1, "tie_embeddings": False,
+                 "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
 NESTED_HYBRID = {"attn_full": "attn", "ssm_proj": "attn", "ssm_conv": "attn",
                  "ssm_scan": "attn", "ssm_gate": "attn"}
+NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
+              "mla_rope": "attn_mla", "moe_router": "moe",
+              "moe_dispatch": "moe", "moe_experts": "moe",
+              "moe_shared": "moe"}
 
 
 def _op_names(overrides, ga):
@@ -86,9 +104,12 @@ def test_every_operation_carries_a_step_scope(case):
     assert loose == []
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
-    ffn = "moe" if case in ("moe", "pattern_share") else "mlp"
+    ffn = "moe" if case in ("moe", "pattern_share", "mla_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
-    nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID}.get(case)
+    if case == "mla_moe":
+        want.add("mlp")         # the dense layer's
+    nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
+              "mla_moe": NESTED_MLA}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
