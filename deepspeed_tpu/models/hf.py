@@ -54,13 +54,15 @@ OURO_TENSORS = {
     "model.early_exit_gate.bias": ("exit_gate", "b"),       # [1] there
 }
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
-                              "granitemoehybrid", "deepseek_v3")
+                              "granitemoehybrid", "deepseek_v3",
+                              "olmo_hybrid")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
-             "attention": "full", "mamba": "ssm"}
+             "attention": "full", "mamba": "ssm",
+             "linear_attention": "delta"}
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
-_CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3")
+_CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid")
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
            "gelu_pytorch_tanh": "gelu", "gelu_fast": "gelu", "relu": "relu"}
@@ -210,6 +212,50 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             embedding_multiplier=float(get("embedding_multiplier")),
             residual_multiplier=float(get("residual_multiplier")),
             logits_scaling=float(get("logits_scaling")),
+        )
+    elif model_type == "olmo_hybrid":
+        # gated delta-rule (linear-attention) layers and full-attention
+        # layers in turn (``layer_types``), the Olmo 2 family's block (no
+        # norm before a branch, one after it), an RMSNorm on q and on k over
+        # the whole projection, dense SwiGLU FFNs, an untied head. The
+        # config side only. What the published file does not say is read the
+        # family's way: a null ``rope_theta`` is no rope, the delta layers'
+        # chunk is the fla kernels' 64 (``ops/delta_rule.py``). A share of
+        # the heads is no config key: pass heads_held=.
+        if get("attention_bias", False):
+            raise ValueError("olmo_hybrid with attention biases is not "
+                             "mapped")
+        if get("linear_num_key_heads") != get("linear_num_value_heads"):
+            raise ValueError(
+                f"olmo_hybrid with linear_num_key_heads="
+                f"{get('linear_num_key_heads')} shared by "
+                f"linear_num_value_heads={get('linear_num_value_heads')} "
+                f"(grouped value heads) is not mapped: a key head a value "
+                f"head only")
+        L = get("num_hidden_layers")
+        rope = dict(get("rope_parameters") or {})
+        theta = rope.get("rope_theta", get("rope_theta"))
+        if theta is not None and rope.get("rope_type", "default") != "default":
+            raise ValueError(f"olmo_hybrid with rope_parameters={rope} is "
+                             f"not mapped: no rope, or a plain one")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads")
+            or get("num_attention_heads"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            use_rope=theta is not None,
+            rope_theta=float(theta if theta is not None else 10000.0),
+            attn_pattern=tuple(_HF_KINDS[k] for k in get("layer_types")[:L]),
+            norm_placement="post", qk_norm="width",
+            delta_heads=get("linear_num_value_heads"),
+            delta_key_dim=get("linear_key_head_dim"),
+            delta_value_dim=get("linear_value_head_dim"),
+            delta_conv=get("linear_conv_kernel_dim"),
+            delta_neg_eigval=bool(get("linear_allow_neg_eigval", False)),
         )
     elif model_type == "deepseek_v3":
         # latent attention (keys of qk_nope + qk_rope over values of

@@ -66,8 +66,9 @@ class TransformerConfig:
     # the period of layer kinds over the layers: "window" (attention over the
     # last ``sliding_window`` keys), "full" (attention over every key) or
     # "ssm" (the mixer is no attention but a Mamba-2 state-space layer of the
-    # ``ssm_*`` sizes below, models/mamba.py): layer i is of kind
-    # attn_pattern[i % len]. None = every layer the one attention kind
+    # ``ssm_*`` sizes below, models/mamba.py) or "delta" (a gated delta-rule
+    # layer of the ``delta_*`` sizes, models/gated_delta.py): layer i is of
+    # kind attn_pattern[i % len]. None = every layer the one attention kind
     # (windowed where sliding_window is set). HF qwen2's leading run of n
     # full layers is ("full",) * n + ("window",) * (L - n): a period of the
     # whole stack
@@ -84,6 +85,17 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # a gated delta-rule (linear-attention) layer: ``delta_heads`` heads with
+    # keys of ``delta_key_dim`` and values of ``delta_value_dim`` (the state
+    # of a head is key x value), a causal depthwise convolution over
+    # ``delta_conv`` positions on q, k and v, the rule in chunks
+    # (ops/delta_rule.py: ``CHUNK``); ``delta_neg_eigval``: the step is
+    # 2 sigmoid, so that a head's transition may have eigenvalues below 0
+    delta_heads: int = 0
+    delta_key_dim: int = 128
+    delta_value_dim: int = 128
+    delta_conv: int = 4
+    delta_neg_eigval: bool = False
     # the softmax scale of attention (None = 1/sqrt(head_dim)), what the
     # embedding's rows and each branch's output are multiplied by, and what
     # the logits are divided by (the Granite family's four multipliers)
@@ -125,6 +137,22 @@ class TransformerConfig:
     # x + norm(attn(norm(x))), then a + norm(ffn(norm(a))): a second norm
     # scale on each branch's output (``ln1_post``, ``ln2_post``)
     sandwich_norm: bool = False
+    # where a block's one norm a branch stands: "pre" (x + Mix(N(x))) or
+    # "post" (x + N(Mix(x)), then h + N(FFN(h)): no norm before a branch,
+    # ``ln1_post`` / ``ln2_post`` after it; the Olmo 2 family's block)
+    norm_placement: str = "pre"
+    # "width": an RMSNorm on q and one on k over the whole projection (all
+    # the heads held), before the heads are split and before any rope
+    # (``q_norm``, ``k_norm`` in the attention group); None = none
+    qk_norm: Optional[str] = None
+    # a share of a mixer's heads: this model holds ``heads_held`` of an
+    # attention layer's ``num_heads`` (with the key-value heads that serve
+    # them) and of a delta layer's ``delta_heads``, the first of them (what
+    # one chip of several that divide a layer's mixer by heads holds).
+    # The projections are built for the heads held, the output projection has
+    # their rows, and the mixer's output is the partial sum those heads give.
+    # None = all of them
+    heads_held: Optional[int] = None
     # not None: a per-token exit gate sigmoid(w_g . h_t + b_g) after every
     # pass and the expected-exit loss sum_t p_t CE_t - beta H(p) (``loss_fn``)
     exit_loss_beta: Optional[float] = None
@@ -232,11 +260,11 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "latent attention (kv_lora_rank) is every layer's mixer: "
                     "not with attn_pattern")
-            if not pat or set(pat) - {"window", "full", "ssm"} \
+            if not pat or set(pat) - {"window", "full", "ssm", "delta"} \
                     or self.num_layers % len(pat):
                 raise ValueError(
                     f"attn_pattern={pat}: a period of 'window' / 'full' / "
-                    f"'ssm' whose length divides num_layers="
+                    f"'ssm' / 'delta' whose length divides num_layers="
                     f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
                 raise ValueError("attn_pattern has window layers and "
@@ -260,6 +288,76 @@ class TransformerConfig:
                     "sandwich_norm, the exit gate, num_experts > 1, "
                     "parallel_block, loss_tiling > 1 or attention_impl="
                     "'fpdt'")
+        if self.has_delta:
+            if self.delta_heads < 1 or self.delta_conv < 1:
+                raise ValueError(
+                    f"a delta layer needs delta_heads={self.delta_heads} "
+                    f"and delta_conv={self.delta_conv} above 0")
+            if self.num_experts > 1:
+                raise NotImplementedError(
+                    "a delta layer beside routed experts (num_experts > 1): "
+                    "the expert layer's step record and a recurrent mixer's "
+                    "have not been run together")
+            if self.looped:
+                raise NotImplementedError(
+                    "a delta layer in a looped stack (num_passes > 1, "
+                    "sandwich_norm or the exit gate): a pass would have to "
+                    "say what state the next one starts from")
+            if self.parallel_block:
+                raise NotImplementedError(
+                    "a delta layer under parallel_block: its block is "
+                    "written for one branch after the other")
+            if self.loss_tiling > 1:
+                raise NotImplementedError(
+                    "a delta layer with the tiled loss (loss_tiling > 1): "
+                    "the step record's mixer outputs come from the whole-"
+                    "logits path")
+            if self.attention_impl == "fpdt":
+                raise NotImplementedError(
+                    "a delta layer with attention_impl='fpdt': the chunked "
+                    "sequence path carries key-value chunks, not a "
+                    "recurrent state")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement={self.norm_placement!r}: "
+                             f"'pre' or 'post'")
+        if self.norm_placement == "post" and (
+                self.sandwich_norm or self.parallel_block):
+            raise ValueError(
+                "norm_placement='post' is one norm after each branch: not "
+                "with sandwich_norm (a norm before it too) or parallel_block "
+                "(one residual add)")
+        if self.qk_norm not in (None, "width"):
+            raise ValueError(f"qk_norm={self.qk_norm!r}: None or 'width' "
+                             f"(one RMSNorm over the whole projection)")
+        if self.qk_norm and (self.has_mla or self.norm != "rmsnorm"
+                             or self.attention_impl == "fpdt"):
+            raise NotImplementedError(
+                "qk_norm is an RMSNorm on the q and k projections of plain "
+                "attention layers: not with latent attention (its norm is "
+                "the latent's), norm='layernorm' or attention_impl='fpdt' "
+                "(which projects chunk by chunk)")
+        if self.heads_held is not None:
+            n = self.heads_held
+            for what, total in (("num_heads", self.num_heads),
+                                ("delta_heads", self.delta_heads
+                                 if self.has_delta else None)):
+                if total is not None and not 1 <= n <= total:
+                    raise ValueError(
+                        f"heads_held={n} are not among the {what}={total}")
+            group = self.num_heads // self.num_kv_heads
+            if n % group:
+                raise ValueError(
+                    f"heads_held={n} cut a group of {group} query heads "
+                    f"from the key-value head that serves it")
+            if (self.has_mla or self.has_ssm or self.looped
+                    or self.parallel_block or self.qkv_bias or self.proj_bias
+                    or self.attention_impl == "fpdt"):
+                raise NotImplementedError(
+                    "a held share of the heads (heads_held) is built for "
+                    "plain attention and delta layers without biases: not "
+                    "latent attention, a state-space layer, a looped stack, "
+                    "parallel_block, qkv_bias / proj_bias or "
+                    "attention_impl='fpdt'")
         if self.has_mla:
             if self.q_lora_rank is not None:
                 raise NotImplementedError(
@@ -334,9 +432,31 @@ class TransformerConfig:
         return "ssm" in (self.attn_pattern or ())
 
     @property
+    def has_delta(self) -> bool:
+        """Whether any layer's mixer is a gated delta-rule layer."""
+        return "delta" in (self.attn_pattern or ())
+
+    @property
+    def heads_here(self) -> int:
+        """The attention heads this model holds (``heads_held``, else all)."""
+        return self.heads_held or self.num_heads
+
+    @property
+    def kv_heads_here(self) -> int:
+        """The key-value heads that serve :attr:`heads_here`."""
+        return self.heads_here * self.num_kv_heads // self.num_heads
+
+    @property
     def has_mla(self) -> bool:
         """Whether the layers' mixer is latent attention."""
         return self.kv_lora_rank is not None
+
+    @property
+    def reports_mixer_outputs(self) -> bool:
+        """Whether the step record carries each layer's mixer-output mean
+        square (``mix_out_ms``): a model with a mixer that is no plain
+        attention."""
+        return self.has_ssm or self.has_mla or self.has_delta
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -346,9 +466,9 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The kind of every layer: its mixer's, "window", "full", "ssm" or
-        "mla"; in a model whose FFNs differ by layer, then ":" and its
-        FFN's, "dense" or "moe"."""
+        """The kind of every layer: its mixer's, "window", "full", "ssm",
+        "delta" or "mla"; in a model whose FFNs differ by layer, then ":" and
+        its FFN's, "dense" or "moe"."""
         pat = self.attn_pattern or (
             ("mla",) if self.has_mla else
             ("full",) if self.sliding_window is None else ("window",))
@@ -365,7 +485,7 @@ class TransformerConfig:
         plain attention: the layer loop then runs by kind
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
-                or self.has_mla)
+                or self.has_mla or self.has_delta)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -398,12 +518,14 @@ class TransformerConfig:
         """The leaves ``TransformerLM.init`` makes, counted from the sizes:
         each layer's mixer and FFN by its kind, the norms once each."""
         D, F, V, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
-        hd, nh, nkv = self.head_dim, self.num_heads, self.num_kv_heads
+        hd, nh, nkv = self.head_dim, self.heads_here, self.kv_heads_here
         gated = self.activation == "swiglu"
         norm = D * (2 if self.norm == "layernorm" else 1)
         norms = norm * ((1 if self.parallel_shared_norm else 2)
                         + (2 if self.sandwich_norm else 0))
         attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
+        if self.qk_norm:
+            attn += (nh + nkv) * hd
         if self.qkv_bias:
             attn += (nh + 2 * nkv) * hd
         if self.proj_bias:
@@ -428,6 +550,10 @@ class TransformerConfig:
             from deepspeed_tpu.models import mla
 
             mixers["mla"] = mla.num_params(self)
+        if self.has_delta:
+            from deepspeed_tpu.models import gated_delta
+
+            mixers["delta"] = gated_delta.num_params(self)
         layers = 0
         for kind in self.layer_kinds:
             mixer, _, ffn = kind.partition(":")
@@ -697,7 +823,7 @@ def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
     install a fused ``wqkv`` [D, (H+2K)*hd] leaf (one kernel launch instead
     of three — decode is a chain of small kernels)."""
     B, T = x.shape[0], x.shape[1]
-    hd, H, K = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    hd, H, K = cfg.head_dim, cfg.heads_here, cfg.kv_heads_here
     if "wqkv" in w:
         qkv = linear(x, w["wqkv"])
         if "bqkv" in w:
@@ -707,6 +833,10 @@ def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
         q, k, v = linear(x, w["wq"]), linear(x, w["wk"]), linear(x, w["wv"])
         if "bq" in w:
             q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
+    if cfg.qk_norm:
+        # over the whole projection (every head held), before the split
+        q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps)
     return (q.reshape(B, T, H, hd), k.reshape(B, T, K, hd),
             v.reshape(B, T, K, hd))
 
@@ -714,7 +844,7 @@ def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
 def attn_out_proj(attn: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
     """[B, T, H, hd] attention output → [B, T, D] (+ optional bias)."""
     B, T = attn.shape[0], attn.shape[1]
-    o = linear(attn.reshape(B, T, cfg.num_heads * cfg.head_dim), w["wo"])
+    o = linear(attn.reshape(B, T, cfg.heads_here * cfg.head_dim), w["wo"])
     return o + w["bo"] if "bo" in w else o
 
 
@@ -733,8 +863,7 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                     freqs: Optional[jax.Array],
                     attn_fn: Callable,
                     positions: Optional[jax.Array] = None) -> jax.Array:
-    B, T, D = x.shape
-    hd, H, K = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    hd = cfg.head_dim
     rope_scale = rope_attention_factor(cfg.rope_scaling)
     if cfg.attention_impl == "fpdt" and rope_scale != 1.0:
         raise NotImplementedError("attention_impl='fpdt' applies a plain "
@@ -873,24 +1002,25 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate",
                # latent attention's parts inside attn/attn_mla
                # (models/mla.py); the shared experts inside moe
-               "attn_mla", "mla_proj", "mla_rope", "moe_shared")
+               "attn_mla", "mla_proj", "mla_rope", "moe_shared",
+               # a delta layer's parts inside attn (models/gated_delta.py)
+               "delta_proj", "delta_conv", "delta_scan", "delta_gate")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind
 _MAX_PERIOD = 8
-#: a state-space layer's leaves that stay float32 in the compute copy of the
-#: weights: they enter an exponential or a softplus, never a matmul
-_SSM_FP32 = ("A_log", "dt_bias", "D")
-#: leaves that stay float32 in the compute copy whatever group holds them:
-#: a state-space layer's, and the router's selection bias, whose steps of
-#: ``moe_bias_rate`` bf16 would round away
-_KEEP_FP32 = _SSM_FP32 + ("router_bias",)
+#: leaves that stay float32 in the compute copy of the weights, by name,
+#: whatever group holds them: a state-space or a delta layer's, which enter
+#: an exponential or a softplus and never a matmul, and the router's
+#: selection bias, whose steps of ``moe_bias_rate`` bf16 would round away
+_KEEP_FP32 = ("A_log", "dt_bias", "D", "router_bias")
 #: the groups of ``params["layers"]`` that hold a kind's own leaves: a layer's
 #: kind is its mixer's, and in a model whose FFNs differ by layer
 #: (``first_k_dense``) then ":" and its FFN's ("mla:dense", "mla:moe"). Such
 #: a group's stack has one row for each layer of its kinds, in layer order;
 #: every other group (norms; the FFN where all layers' are alike, "mlp") has
 #: a row for every layer
-_MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla"}
+_MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla",
+                "delta": "delta"}
 _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
 _KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
     _FFN_GROUP.values())
@@ -919,9 +1049,10 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
     out = {}
     for k, v in w.items():
         with jax.named_scope(
-                "attn" if k in ("ln1", "attn", "ssm", "mla", "ln1_post")
+                "attn" if k in ("ln1", "attn", "ssm", "mla", "delta",
+                                "ln1_post")
                 else _FFN_SCOPE.get(k, ffn)):
-            if k == "ssm" or "router_bias" in v:
+            if any(n in _KEEP_FP32 for n in v):
                 out[k] = {n: p if n in _KEEP_FP32
                           else jax.tree_util.tree_map(cast, p)
                           for n, p in v.items()}
@@ -936,18 +1067,23 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                       positions: Optional[jax.Array] = None,
                       kind: Optional[str] = None,
                       mix_ms: bool = False) -> Any:
-    """One pre-norm decoder block. Returns (x, aux_loss). ``positions`` [B, T]
-    overrides RoPE positions (random-LTD token subsets). With
-    ``cfg.sandwich_norm`` each branch's output is normed again before its
-    residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a + N4(FFN(N3(a)))``.
+    """One decoder block, pre-norm unless the config says otherwise. Returns
+    (x, aux_loss). ``positions`` [B, T] overrides RoPE positions (random-LTD
+    token subsets). With ``cfg.sandwich_norm`` each branch's output is normed
+    again before its residual add: ``a = x + N2(Attn(N1(x)))``, ``y = a +
+    N4(FFN(N3(a)))``; with ``cfg.norm_placement == "post"`` that second norm
+    is a branch's only one: ``a = x + N2(Mix(x))``, ``y = a + N4(FFN(a))``.
     ``kind`` names the layer's kind in a model that has several: an
     attention layer's operations then lie under ``attn/attn_<kind>``; a
     layer of kind "ssm" mixes its tokens with ``w["ssm"]``
-    (models/mamba.py:ssm_block) under ``attn/ssm_*``, one of kind "mla" with
-    ``w["mla"]`` (models/mla.py:mla_block); a kind that names its FFN
+    (models/mamba.py:ssm_block) under ``attn/ssm_*``, one of kind "delta"
+    with ``w["delta"]`` (models/gated_delta.py:delta_block) under
+    ``attn/delta_*``, one of kind "mla" with ``w["mla"]``
+    (models/mla.py:mla_block); a kind that names its FFN
     ("mla:dense") runs ``moe_fn`` only where that is "moe". With ``mix_ms``
     the aux value is a dict that also holds the mean square of the mixer's
-    output (``mix_out_ms``)."""
+    output (``mix_out_ms``), taken before a norm on the branch's output,
+    which would pin it."""
     # named scopes land in HLO op metadata — the per-module profiler
     # (profiling/flops_profiler.per_module_profile) and the benchmark's
     # device-time-by-scope reader group cost by them. Every operation of the
@@ -959,11 +1095,17 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     ffn = "moe" if moe_fn is not None else "mlp"
     wc = _cast_layers(w, jnp.dtype(cfg.dtype), ffn)
     res = cfg.residual_multiplier
+    post = cfg.norm_placement == "post"
     with jax.named_scope("attn"), (jax.named_scope("attn_" + kind)
-                                   if kind and kind != "ssm"
+                                   if kind and kind not in ("ssm", "delta")
                                    else contextlib.nullcontext()):
-        hn1 = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
-        if kind == "ssm":
+        hn1 = x if post else _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
+        if kind == "delta":
+            from deepspeed_tpu.models.gated_delta import delta_block
+
+            attn_out = constrain(delta_block(hn1, wc["delta"], cfg),
+                                 P(("dp", "fsdp"), "sp", None))
+        elif kind == "ssm":
             from deepspeed_tpu.models.mamba import ssm_block
 
             attn_out = constrain(ssm_block(hn1, wc["ssm"], cfg),
@@ -975,10 +1117,10 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         else:
             attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                        positions=positions)
-        if cfg.sandwich_norm:
-            attn_out = _norm(attn_out, wc["ln1_post"], cfg.norm, cfg.norm_eps)
         if mix_ms:
             ms = jnp.mean(jnp.square(attn_out.astype(jnp.float32)))
+        if cfg.sandwich_norm or post:
+            attn_out = _norm(attn_out, wc["ln1_post"], cfg.norm, cfg.norm_eps)
         if res != 1.0:
             attn_out = _times(attn_out, res)
     with jax.named_scope(ffn):
@@ -989,13 +1131,13 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                 x, wc["ln2"], cfg.norm, cfg.norm_eps)
         else:
             x = x + attn_out
-            h = _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps)
+            h = x if post else _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps)
         if moe_fn is not None:
             mlp_out, aux = moe_fn(h, wc["mlp"], cfg)
         else:
             mlp_out = mlp_block(h, wc["mlp"], cfg)
             aux = jnp.zeros((), jnp.float32)
-        if cfg.sandwich_norm:
+        if cfg.sandwich_norm or post:
             mlp_out = _norm(mlp_out, wc["ln2_post"], cfg.norm, cfg.norm_eps)
         if res != 1.0:
             mlp_out = _times(mlp_out, res)
@@ -1243,11 +1385,27 @@ class TransformerLM:
     def _one_pass_only(self, what: str) -> None:
         """Raise on a path written for one pass over a pre-norm stack of
         attention layers: for a looped model (``cfg.looped``: nothing falls
-        back to one pass), for one with a state-space layer (no path but the
-        train step keeps a recurrent state) and for one with the Granite
-        multipliers (only ``transformer_block`` and the train forward apply
-        them)."""
+        back to one pass), for one with a state-space or a delta layer (no
+        path but the train step keeps a recurrent state), for one whose block
+        has its norm after the branch, norms q and k or holds a share of the
+        heads, and for one with the Granite multipliers (only
+        ``transformer_block`` and the train forward apply them)."""
         cfg = self.cfg
+        if cfg.has_delta:
+            raise NotImplementedError(
+                f"{what} is written for attention layers: this model has "
+                f"gated delta-rule layers (attn_pattern={cfg.attn_pattern}), "
+                f"whose recurrent and convolution state it would have to "
+                f"keep beside the key-value cache; only the train step runs "
+                f"them")
+        if (cfg.norm_placement != "pre" or cfg.qk_norm
+                or cfg.heads_held is not None):
+            raise NotImplementedError(
+                f"{what} runs pre-norm blocks whose attention holds every "
+                f"head and norms neither q nor k: this model has "
+                f"norm_placement={cfg.norm_placement!r}, qk_norm="
+                f"{cfg.qk_norm!r}, heads_held={cfg.heads_held}; only the "
+                f"train step's block applies them")
         if cfg.has_mla or cfg.has_ffn_kinds:
             raise NotImplementedError(
                 f"{what} is written for layers of one FFN kind whose "
@@ -1315,6 +1473,11 @@ class TransformerLM:
                 cfg.layer_kinds))}
         if cfg.has_ssm:
             facts["ssm_chunk"] = cfg.ssm_chunk
+        if cfg.has_delta:
+            from deepspeed_tpu.ops import delta_rule
+            facts["delta_chunk"] = delta_rule.CHUNK
+        if cfg.heads_held is not None:
+            facts["heads_held"] = (cfg.heads_held, cfg.num_heads)
         if cfg.has_mla:
             facts["attn_widths"] = (
                 cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim)
@@ -1365,19 +1528,48 @@ class TransformerLM:
         batch of ``batch_shape`` [rows, T]: layers x rows x ceil(T / chunk)
         (the step-program table's ``ssm_chunks_per_step``); None for a model
         without such a layer."""
-        cfg = self.cfg
-        if not cfg.has_ssm:
+        return self._chunks("ssm", self.cfg.ssm_chunk, batch_shape)
+
+    def _chunks(self, kind: str, chunk: int, batch_shape) -> Optional[int]:
+        """Layers of ``kind`` x rows x ceil(T / chunk) for a batch of
+        ``batch_shape`` [rows, T]; None for a model without such a layer."""
+        layers = self.cfg.layer_kinds.count(kind)
+        if not layers:
             return None
         rows, T = batch_shape
-        return (cfg.layer_kinds.count("ssm") * int(rows)
-                * -(-int(T) // cfg.ssm_chunk))
+        return layers * int(rows) * -(-int(T) // chunk)
+
+    def delta_chunks_scanned(self, batch_shape) -> Optional[int]:
+        """Chunks the delta layers of one step's forward go through over a
+        batch of ``batch_shape`` [rows, T]: layers x rows x ceil(T / chunk)
+        (the step-program table's ``delta_chunks_per_step``); None for a
+        model without such a layer."""
+        from deepspeed_tpu.ops import delta_rule
+        return self._chunks("delta", delta_rule.CHUNK, batch_shape)
+
+    def check_topology(self, axis_sizes: Dict[str, int]) -> None:
+        """Raise where the mesh has an axis this model cannot be laid over:
+        a ``tp`` axis divides an attention layer's heads, which a delta
+        layer's projections (replicated, ``gated_delta.param_specs``) and a
+        held share of the heads (``heads_held``, already one chip's part of
+        them) do not follow."""
+        cfg = self.cfg
+        if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta
+                                            or cfg.heads_held is not None):
+            raise NotImplementedError(
+                f"a tp axis of {axis_sizes['tp']} with gated delta-rule "
+                f"layers or a held share of the heads (heads_held="
+                f"{cfg.heads_held}): tensor parallelism would divide heads "
+                f"that the delta layer keeps whole and that heads_held "
+                f"already divides")
 
     # ---- init -------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
         pd = jnp.dtype(cfg.param_dtype)
         D, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
-        hd, H, K, L = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
+        hd, H, K, L = (cfg.head_dim, cfg.heads_here, cfg.kv_heads_here,
+                       cfg.num_layers)
         keys = jax.random.split(rng, 12)
 
         def dense(key, fan_in, shape):
@@ -1404,6 +1596,9 @@ class TransformerLM:
             attn_w["bv"] = jnp.zeros((La, K * hd), pd)
         if cfg.proj_bias:
             attn_w["bo"] = jnp.zeros((La, D), pd)
+        if cfg.qk_norm:
+            attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
+            attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
         # the FFNs: one stack with a row a layer, or (first_k_dense) a dense
         # stack and a routed one
         Ld = cfg.first_k_dense if cfg.has_ffn_kinds else L
@@ -1447,7 +1642,13 @@ class TransformerLM:
             from deepspeed_tpu.models import mamba
 
             layers["ssm"] = mamba.init(jax.random.fold_in(rng, 12), cfg,
-                                       L - La, pd)
+                                       _in_group(cfg.layer_kinds, "ssm"), pd)
+        if cfg.has_delta:
+            from deepspeed_tpu.models import gated_delta
+
+            layers["delta"] = gated_delta.init(
+                jax.random.fold_in(rng, 15), cfg,
+                _in_group(cfg.layer_kinds, "delta"), pd)
         if cfg.has_mla:
             from deepspeed_tpu.models import mla
 
@@ -1456,9 +1657,11 @@ class TransformerLM:
             del layers["attn"]
         if not cfg.parallel_shared_norm:
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
-        if cfg.sandwich_norm:
+        if cfg.sandwich_norm or cfg.norm_placement == "post":
             layers["ln1_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
             layers["ln2_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
+        if cfg.norm_placement == "post":
+            del layers["ln1"], layers["ln2"]
         params: Params = {
             "embed": {"tokens": dense(keys[0], 1, (V, D))
                       * cfg.embed_init_std},
@@ -1732,7 +1935,7 @@ class TransformerLM:
         ck, freqs = self._kinds[kind]
         return transformer_block(
             x, w, ck, freqs, attn_fn, self.moe_fn, kind=kind,
-            mix_ms=self.cfg.has_ssm or self.cfg.has_mla)
+            mix_ms=self.cfg.reports_mixer_outputs)
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -1790,7 +1993,7 @@ class TransformerLM:
                 loss = (self._tiled_loss(params, batch, hs[-1])
                         if logits is None else lm_loss(cfg, logits, batch))
             parts = {}
-        if cfg.has_ssm or cfg.has_mla:
+        if cfg.reports_mixer_outputs:
             # by layer, the mean square of the mixer's output
             parts = {**parts, "mix_out_ms": aux["mix_out_ms"]}
         if cfg.num_experts > 1:
@@ -2264,6 +2467,9 @@ class TransformerLM:
             attn_spec["bv"] = P(None, "tp")
         if cfg.proj_bias:
             attn_spec["bo"] = P(None, None)
+        if cfg.qk_norm:
+            attn_spec["q_norm"] = P(None, "tp")
+            attn_spec["k_norm"] = P(None, "tp")
         layer_specs: Params = {"ln1": norm_spec, "attn": attn_spec, "mlp": mlp}
         if cfg.has_ffn_kinds:
             del layer_specs["mlp"]
@@ -2279,13 +2485,19 @@ class TransformerLM:
             from deepspeed_tpu.models import mla
 
             layer_specs["mla"] = mla.param_specs()
+        if cfg.has_delta:
+            from deepspeed_tpu.models import gated_delta
+
+            layer_specs["delta"] = gated_delta.param_specs()
         if not _in_group(cfg.layer_kinds, "attn"):
             del layer_specs["attn"]
         if not cfg.parallel_shared_norm:
             layer_specs["ln2"] = dict(norm_spec)
-        if cfg.sandwich_norm:
+        if cfg.sandwich_norm or cfg.norm_placement == "post":
             layer_specs["ln1_post"] = dict(norm_spec)
             layer_specs["ln2_post"] = dict(norm_spec)
+        if cfg.norm_placement == "post":
+            del layer_specs["ln1"], layer_specs["ln2"]
         specs: Params = {
             "embed": {"tokens": P("tp", None)},
             "layers": layer_specs,

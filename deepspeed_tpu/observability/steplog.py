@@ -368,15 +368,17 @@ class StepProgram:
                  experts_held: Optional[Sequence[int]] = None,
                  ssm_chunk: Optional[int] = None,
                  attn_widths: Optional[Sequence[int]] = None,
-                 moe_scoring: Optional[str] = None):
+                 moe_scoring: Optional[str] = None,
+                 delta_chunk: Optional[int] = None,
+                 heads_held: Optional[Sequence[int]] = None):
         self.name = name
         self.key = str(key)
         #: block applications one micro-batch's forward holds (layers run x
         #: passes over them); None where the model does not say
         self.layer_applications = layer_applications
         #: the period of layer kinds the layer loop scans ("window" / "full"
-        #: attention, "ssm" a state-space layer); None where the model does
-        #: not say
+        #: attention, "ssm" a state-space layer, "delta" a gated delta-rule
+        #: layer); None where the model does not say
         self.layer_pattern = None if layer_pattern is None \
             else tuple(layer_pattern)
         #: the grouped expert product the program was traced with: "ragged"
@@ -435,6 +437,19 @@ class StepProgram:
         #: selection bias the step moves by rule, ``router_counts`` in the
         #: step record's parts); None elsewhere
         self.moe_scoring = moe_scoring
+        #: the chunk length of the delta layers' rule, and the chunks one
+        #: step's forward goes through (delta layers x rows x ceil(T /
+        #: chunk), from the batch of the program's first call); None for a
+        #: model without such a layer
+        self.delta_chunk = delta_chunk
+        self.delta_chunks_per_step: Optional[int] = None
+        #: the delta layers' rules the program's trace lowered, by the
+        #: lowering each took, ``{"xla": n}`` (``ops/delta_rule.py``: the
+        #: einsum form is the one there is); None where the trace held none
+        self.delta_scan_lowerings: Optional[Dict[str, int]] = None
+        #: (count, all) of the heads a mixer holds where that is a
+        #: share of them; None where every head is held
+        self.heads_held = None if heads_held is None else tuple(heads_held)
         self.built_at = time.perf_counter()
         #: the length of the ``ds.train.dispatch`` span of the program's
         #: first call (trace, lowering, the compile or its read from the

@@ -76,6 +76,11 @@ def _scan_lowerings() -> Optional[Dict[str, int]]:
     return _traced_counts("deepspeed_tpu.ops.ssd_scan")
 
 
+def _delta_lowerings() -> Optional[Dict[str, int]]:
+    """The delta layers' chunked rules (``ops/delta_rule.py``)."""
+    return _traced_counts("deepspeed_tpu.ops.delta_rule")
+
+
 def _counted(before: Optional[Dict[str, int]],
              after: Optional[Dict[str, int]]) -> Optional[Dict[str, int]]:
     """What a trace added to such counts; None where it added nothing."""
@@ -209,6 +214,8 @@ class DeepSpeedTpuEngine:
         # ---- sharding layouts -----------------------------------------
         if init_rng is None:
             init_rng = jax.random.key(config.seed)
+        if hasattr(model, "check_topology"):
+            model.check_topology(self.topology.axis_sizes)
         model_specs = model.param_specs() if hasattr(model, "param_specs") else None
         param_shapes = jax.eval_shape(model.init, init_rng)
         self._param_shapes = param_shapes
@@ -1090,6 +1097,7 @@ class DeepSpeedTpuEngine:
             grouped_before = _grouped_lowerings()
             dispatch_before = _dispatch_lowerings()
             scan_before = _scan_lowerings()
+            delta_before = _delta_lowerings()
         t0 = time.perf_counter()
         with steplog.span(self._ebus, "train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
@@ -1105,11 +1113,15 @@ class DeepSpeedTpuEngine:
             return
         row.first_call_s = self._t_dispatched - t0
         self._uncaptured[key] = row
-        if row.ssm_chunk is not None:
-            row.ssm_chunks_per_step = next(
-                (self.module.ssm_chunks_scanned(a["input_ids"].shape)
-                 for a in args
-                 if isinstance(a, dict) and "input_ids" in a), None)
+        batch_shape = next((a["input_ids"].shape for a in args
+                            if isinstance(a, dict) and "input_ids" in a),
+                           None)
+        if row.ssm_chunk is not None and batch_shape is not None:
+            row.ssm_chunks_per_step = self.module.ssm_chunks_scanned(
+                batch_shape)
+        if row.delta_chunk is not None and batch_shape is not None:
+            row.delta_chunks_per_step = self.module.delta_chunks_scanned(
+                batch_shape)
         row.flash_bwd_lowerings = {
             kind: n - before[kind] for kind, n in bwd_lowerings().items()}
         row.flash_rope_operand_lowerings = {
@@ -1122,6 +1134,7 @@ class DeepSpeedTpuEngine:
         row.moe_dispatch_lowerings = _counted(dispatch_before,
                                               _dispatch_lowerings())
         row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
+        row.delta_scan_lowerings = _counted(delta_before, _delta_lowerings())
 
     def _rule_moves_only_here(self, what: str) -> None:
         """Raise on a step path that does not carry the model's rule-moved

@@ -192,6 +192,20 @@ def _ssd(grad, H=64, P=64, G=1, N=128, T=4096, chunk=256):
         ((H,), jnp.float32), bc, bc, ((H,), jnp.float32)]
 
 
+def _delta(grad, H=15, dk=96, dv=192, T=4096):
+    from deepspeed_tpu.ops.delta_rule import chunked_delta_rule
+
+    def fwd(q, k, v, g, beta):
+        return chunked_delta_rule(q, k, v, g, beta)
+
+    def loss(*a):
+        return fwd(*a).astype(jnp.float32).sum()
+
+    qk, gb = ((1, T, H, dk), jnp.bfloat16), ((1, T, H), jnp.float32)
+    return (jax.grad(loss, argnums=tuple(range(5))) if grad else fwd), [
+        qk, qk, ((1, T, H, dv), jnp.bfloat16), gb, gb]
+
+
 # (builder, kwargs, must the compiled program hold a Mosaic kernel?)
 CASES = {
     "flash-fwd-d64": (_flash, dict(d=64, grad=False), True),
@@ -262,6 +276,11 @@ CASES = {
     # heads of 32 channels: the picker gives the einsum form
     "ssd-scan-grad-p32-einsum": (
         _ssd, dict(grad=True, H=16, P=32, T=1024), False),
+    # the chunked delta rule at the Olmo-Hybrid cell's shapes (15 heads held,
+    # keys of 96, values of 192, chunks of 64) and at a T that is padded:
+    # einsums, the triangular inverse's loop and the chunk loop; no kernel yet
+    "delta-rule-grad-olmo-cell": (_delta, dict(grad=True), False),
+    "delta-rule-grad-T1000": (_delta, dict(grad=True, H=4, T=1000), False),
 }
 
 
@@ -728,3 +747,62 @@ def test_kernel_path_rules_match_what_compiled():
     bf, why = quant_matmul_path(256, 14336, 4096, 128)
     assert bf == 0 and "VMEM" in why
     assert quant_matmul_path(512, 4096, 14336, 128)[0] == 0
+
+
+def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
+                                                             monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    olmo_hybrid_7b_train_d4h15v8.json``: one period at the published widths,
+    15 of 30 heads, 12544 rows, 1 x 4096 tokens, the file's recomputation
+    policy): gradient and AdamW over fp32 master weights, compiled for the
+    chip. It fits beside what a chip reserves, the full layer runs the flash
+    kernels, and the delta layers' rule is there under its scope."""
+    import json
+    import os
+
+    import optax
+
+    import deepspeed_tpu.ops as ops
+    from benchmarks import modelcfg_olmo_hybrid as modelcfg
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.ops import flash_attention as fa
+    from deepspeed_tpu.runtime.optimizers import build_optimizer
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "olmo_hybrid_7b_train_d4h15v8.json")) as f:
+        cfg = json.load(f)
+    model = TransformerLM(modelcfg.transformer_config(
+        cfg, max_seq_len=4096, param_dtype="float32"))
+    tx = build_optimizer("adamw", {"lr": 1e-6}, lr_schedule=None,
+                         gradient_clipping=0.0)
+
+    def step(params, opt, batch):
+        (loss, parts), grads = jax.value_and_grad(
+            model.loss_and_parts, has_aux=True)(params, batch)
+        updates, opt = tx.update(grads, opt, params)
+        return optax.apply_updates(params, updates), opt, loss, parts
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
+        == 766_241_946
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        described(params), described(jax.eval_shape(tx.init, params)),
+        {"input_ids": jax.ShapeDtypeStruct((1, 4096), jnp.int32,
+                                           sharding=one_chip)}).compile()
+    mem = compiled.memory_analysis()
+    # fp32 weights, Adam m and v: 12 B a parameter as arguments
+    assert mem.argument_size_in_bytes == pytest.approx(12 * 766_241_946,
+                                                       rel=1e-3)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text            # the full layer's flash
+    assert "delta_scan" in text and "delta_conv" in text

@@ -622,7 +622,8 @@ def test_the_cells_check_sees_the_fault(cell_check, monkeypatch, fault):
 def test_a_model_without_the_layer_loads_none_of_its_modules():
     """``import deepspeed_tpu`` and building, sharding and running a model
     whose layers are all attention load neither ``ops/ssd_scan.py`` nor
-    ``models/mamba.py`` (``setup_s`` of the cells that are there)."""
+    ``models/mamba.py``, neither ``ops/delta_rule.py`` nor
+    ``models/gated_delta.py`` (``setup_s`` of the cells that are there)."""
     import subprocess
 
     code = (
@@ -634,7 +635,8 @@ def test_a_model_without_the_layer_loads_none_of_its_modules():
         "p = m.init(jax.random.key(0)); m.param_specs()\n"
         "m.cfg.num_params_estimate(); m.step_program_facts()\n"
         "jax.grad(m.loss_fn)(p, {'input_ids': jnp.zeros((1, 8), 'int32')})\n"
-        "print([k for k in sys.modules if 'ssd_scan' in k or 'mamba' in k])")
+        "print([k for k in sys.modules if 'ssd_scan' in k or 'mamba' in k"
+        " or 'delta' in k])")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True,
                          capture_output=True,
                          env={**os.environ, "JAX_PLATFORMS": "cpu"})

@@ -64,11 +64,27 @@ CASES = {
                  "moe_shared_experts": 2, "moe_bias_rate": 1e-3,
                  "moe_bias_init": 0.1, "tie_embeddings": False,
                  "remat_policy": "full"}, 1),
+    # gated delta-rule layers and a full layer in turn under recomputation,
+    # a norm after each branch and none before, q/k norms, no rope, a held
+    # share of the heads, an untied head: the delta mixer's parts nest
+    # inside attn (the projections, the convolutions, the rule with its
+    # triangular inverse and chunk loop, the gated norm), the attention
+    # layer keeps attn_full
+    "delta_hybrid": ({"num_layers": 4,
+                      "attn_pattern": ("delta", "delta", "delta", "full"),
+                      "use_rope": False, "norm_placement": "post",
+                      "qk_norm": "width", "heads_held": 2, "delta_heads": 4,
+                      "delta_key_dim": 8, "delta_value_dim": 16,
+                      "delta_neg_eigval": True,
+                      "tie_embeddings": False, "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
 NESTED_HYBRID = {"attn_full": "attn", "ssm_proj": "attn", "ssm_conv": "attn",
                  "ssm_scan": "attn", "ssm_gate": "attn"}
+NESTED_DELTA = {"attn_full": "attn", "delta_proj": "attn",
+                "delta_conv": "attn", "delta_scan": "attn",
+                "delta_gate": "attn"}
 NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
               "mla_rope": "attn_mla", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe",
@@ -109,7 +125,7 @@ def test_every_operation_carries_a_step_scope(case):
     if case == "mla_moe":
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
-              "mla_moe": NESTED_MLA}.get(case)
+              "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
