@@ -104,20 +104,16 @@ def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
         q, k, v = (jax.nn.silu(causal_conv(x, w[n])) for x, n in
                    ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
     with jax.named_scope("delta_scan"):
-        def unit(x, scale=1.0):
-            x = x.reshape(B, T, H, dk)
-            return (x * (jax.lax.rsqrt(
-                jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS) * scale)
-                ).astype(u.dtype)
-
         beta = jax.nn.sigmoid(b.astype(F32))
         if cfg.delta_neg_eigval:
             beta = 2.0 * beta
         g = -jnp.exp(w["A_log"].astype(F32)) * jax.nn.softplus(
             a.astype(F32) + w["dt_bias"].astype(F32))
+        # the rule puts the norms on a head's q and k itself
         o = chunked_delta_rule(
-            unit(q, 1.0 / math.sqrt(dk)), unit(k),
-            v.astype(u.dtype).reshape(B, T, H, dv), g, beta)
+            q.reshape(B, T, H, dk), k.reshape(B, T, H, dk),
+            v.astype(u.dtype).reshape(B, T, H, dv), g, beta,
+            unit=(1.0 / math.sqrt(dk), L2_EPS))
     with jax.named_scope("delta_gate"):
         # the norm first, over a head's dv channels, then the gate
         o = _norm(o.astype(F32), {"scale": w["o_norm"]}, "rmsnorm",

@@ -444,8 +444,10 @@ class StepProgram:
         self.delta_chunk = delta_chunk
         self.delta_chunks_per_step: Optional[int] = None
         #: the delta layers' rules the program's trace lowered, by the
-        #: lowering each took, ``{"xla": n}`` (``ops/delta_rule.py``: the
-        #: einsum form is the one there is); None where the trace held none
+        #: lowering each took, ``{"xla": n}`` or ``{"pallas": n}``
+        #: (``ops/delta_rule.py``: a rule counts once, the kernels' own
+        #: backward once more; a kind that counted nothing is left out);
+        #: None where the trace held none
         self.delta_scan_lowerings: Optional[Dict[str, int]] = None
         #: (count, all) of the heads a mixer holds where that is a
         #: share of them; None where every head is held

@@ -27,6 +27,10 @@ POLICIES = (
 #: the checkpoint_name tag attached by ops/flash_attention.py (and the XLA
 #: fallback) to the attention output so policies can pin it
 ATTN_CHECKPOINT_NAME = "flash_attn_out"
+#: the tags ops/delta_rule.py attaches, in the forward of its kernels'
+#: ``custom_vjp``, to the rule's output and to the chunks' incoming states:
+#: the products a policy that keeps dots keeps of the einsum form
+RULE_CHECKPOINT_NAMES = ("delta_rule_out", "delta_rule_states")
 
 
 def resolve_policy(policy: str):
@@ -51,6 +55,12 @@ def resolve_policy(policy: str):
         # still runs again for the log-sum-exp, as above)
         return cp.save_from_both_policies(
             cp.dots_saveable, cp.save_only_these_names(ATTN_CHECKPOINT_NAME))
+    if policy == "dots_saveable":
+        # a kernel's products are no dots: where the delta rule ran as
+        # kernels, keep what it named, so that the recomputed region holds
+        # no second forward (a program without those names is unchanged)
+        return cp.save_from_both_policies(
+            cp.dots_saveable, cp.save_only_these_names(*RULE_CHECKPOINT_NAMES))
     if policy == "offload_attn":
         # the FPDT/Ulysses-Offload memory tier (sequence/fpdt_layer.py:545):
         # attention outputs live in HOST memory between forward and backward,

@@ -1134,7 +1134,11 @@ class DeepSpeedTpuEngine:
         row.moe_dispatch_lowerings = _counted(dispatch_before,
                                               _dispatch_lowerings())
         row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
-        row.delta_scan_lowerings = _counted(delta_before, _delta_lowerings())
+        # (a kind that counted nothing stays off this row: a program traced
+        # off the chip reads ``{"xla": n}`` as it did before the kernels)
+        delta = _counted(delta_before, _delta_lowerings())
+        row.delta_scan_lowerings = delta and {
+            kind: n for kind, n in delta.items() if n}
 
     def _rule_moves_only_here(self, what: str) -> None:
         """Raise on a step path that does not carry the model's rule-moved

@@ -192,16 +192,32 @@ def _ssd(grad, H=64, P=64, G=1, N=128, T=4096, chunk=256):
         ((H,), jnp.float32), bc, bc, ((H,), jnp.float32)]
 
 
-def _delta(grad, H=15, dk=96, dv=192, T=4096):
-    from deepspeed_tpu.ops.delta_rule import chunked_delta_rule
+def _delta(grad, H=15, dk=96, dv=192, T=4096, einsum=False, unit=True):
+    """The chunked delta rule at the Olmo-Hybrid cell's shapes
+    (BENCHMARK.json: 1 x 4096 tokens, 15 heads held, keys of 96, values of
+    192, chunks of 64; ``unit``: q and k in float32 as the convolutions leave
+    them, the norms the rule's), by the picker's answer for the shape as if
+    on the chip (``einsum``: the einsum form whatever it says): the forward
+    kernel alone, and the differentiated forward with the backward kernel,
+    each under the VMEM limit it sets for itself."""
+    from deepspeed_tpu.ops import delta_rule as dr
+
+    took, _ = dr.rule_lowering(T, H, dk, dv, jnp.bfloat16, tpu=True)
+    norms = (dk ** -0.5, 1e-6) if unit else None
 
     def fwd(q, k, v, g, beta):
-        return chunked_delta_rule(q, k, v, g, beta)
+        if einsum or took == "xla":
+            if unit:
+                q, k = (dr.unit_heads(x, s, norms[1], v.dtype)
+                        for x, s in ((q, norms[0]), (k, 1.0)))
+            return dr.rule_einsum(q, k, v, g, beta)
+        return dr._rule_pallas(q, k, v, g, beta, norms, False)
 
     def loss(*a):
         return fwd(*a).astype(jnp.float32).sum()
 
-    qk, gb = ((1, T, H, dk), jnp.bfloat16), ((1, T, H), jnp.float32)
+    qk = ((1, T, H, dk), jnp.float32 if unit else jnp.bfloat16)
+    gb = ((1, T, H), jnp.float32)
     return (jax.grad(loss, argnums=tuple(range(5))) if grad else fwd), [
         qk, qk, ((1, T, H, dv), jnp.bfloat16), gb, gb]
 
@@ -277,10 +293,21 @@ CASES = {
     "ssd-scan-grad-p32-einsum": (
         _ssd, dict(grad=True, H=16, P=32, T=1024), False),
     # the chunked delta rule at the Olmo-Hybrid cell's shapes (15 heads held,
-    # keys of 96, values of 192, chunks of 64) and at a T that is padded:
-    # einsums, the triangular inverse's loop and the chunk loop; no kernel yet
-    "delta-rule-grad-olmo-cell": (_delta, dict(grad=True), False),
-    "delta-rule-grad-T1000": (_delta, dict(grad=True, H=4, T=1000), False),
+    # keys of 96, values of 192, chunks of 64) and at a T that is padded: the
+    # two kernels (with q and k as the convolutions leave them, and bf16 q
+    # and k normed before); the einsum form (the triangular inverse's loop
+    # and the chunk loop) at the same shapes; keys of 128, which the picker
+    # refuses
+    "delta-rule-fwd-olmo-cell": (_delta, dict(grad=False), True),
+    "delta-rule-grad-olmo-cell": (_delta, dict(grad=True), True),
+    "delta-rule-grad-T1000-normed-before": (
+        _delta, dict(grad=True, H=4, T=1000, unit=False), True),
+    "delta-rule-grad-olmo-cell-einsum": (
+        _delta, dict(grad=True, einsum=True), False),
+    "delta-rule-grad-T1000-einsum": (
+        _delta, dict(grad=True, H=4, T=1000, einsum=True), False),
+    "delta-rule-grad-k128-refused": (
+        _delta, dict(grad=True, H=4, dk=128, dv=128, T=1024), False),
 }
 
 
@@ -765,11 +792,13 @@ def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
     import deepspeed_tpu.ops as ops
     from benchmarks import modelcfg_olmo_hybrid as modelcfg
     from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.ops import delta_rule
     from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.runtime.optimizers import build_optimizer
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(delta_rule, "_on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     with open(os.path.join(root, "benchmarks", "configs",
@@ -806,3 +835,10 @@ def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
     text = compiled.as_text()
     assert "tpu_custom_call" in text            # the full layer's flash
     assert "delta_scan" in text and "delta_conv" in text
+    assert mem.temp_size_in_bytes < 5.36e9
+    calls = [m.group(1) for m in re.finditer(
+        r"custom-call\(.*tpu_custom_call.*op_name=\"([^\"]*)\"", text)
+        if "/delta_scan/" in m.group(1)]
+    assert sum("jit(rule_fwd)" in n for n in calls) == 3
+    assert sum("jit(rule_bwd)" in n for n in calls) == 3
+    assert not any("rematted_computation" in n for n in calls)

@@ -417,6 +417,37 @@ def test_the_step_record_and_the_step_programs_row():
     assert np.any(after["dt_bias"] != params["layers"]["delta"]["dt_bias"])
 
 
+def test_the_cell_shaped_step_program_takes_the_rule_kernels(monkeypatch):
+    """What the benchmark's cell is in small: bf16, keys of 32 and values of
+    64 in chunks of 64, a period of three delta layers and a full one, the
+    cell's ``dots_saveable``. Every rule of the program is the Pallas kernels
+    (interpreted here: the CPU stands in for the chip), the backward too, and
+    the step gives the einsum form's loss; in float32 the picker's answer
+    stands, the einsum form."""
+    import functools
+
+    from deepspeed_tpu.models import gated_delta
+    from deepspeed_tpu.observability import steplog
+
+    monkeypatch.setattr(delta_rule, "CHUNK", 64)
+    hf = hf_config(D=128, heads=2, d=64, max_position_embeddings=256)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 200)).astype(np.int32)
+    kw = dict(remat_policy="dots_saveable", dtype="bfloat16",
+              max_seq_len=256)
+    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    assert steplog.programs()[-1].delta_scan_lowerings == {"xla": 3}
+    monkeypatch.setattr(gated_delta, "chunked_delta_rule", functools.partial(
+        gated_delta.chunked_delta_rule, interpret=True))
+    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    prog = steplog.programs()[-1]
+    # the period's three rules and the backward of each
+    assert prog.delta_scan_lowerings == {"pallas": 6}
+    assert prog.delta_chunks_per_step == 3 * 2 * 4    # 200 tokens: 4 chunks
+    np.testing.assert_allclose(loss, plain, atol=2e-3)
+
+
 @pytest.mark.parametrize("stage", [3])
 def test_zero_stages_shard_the_new_leaves_and_give_the_same_loss(stage):
     model = model_for(hf_config(L=2, types=PAIR))

@@ -170,6 +170,38 @@ def test_the_scan_kernels_keep_the_scan_scope(monkeypatch):
     assert any("transpose(" not in n for n in fwd)
 
 
+def test_the_rule_kernels_keep_the_scan_scope(monkeypatch):
+    """The delta case at widths the rule's Pallas kernels take (interpreted
+    here), under the benchmark cell's policy: the forward and the backward
+    kernel lower under ``attn/delta_scan`` with the jitted kernel's name in
+    the path, which is how the benchmark's scope readers find the Mosaic
+    calls on the chip; the policy keeps what the forward rule named (its
+    output and the chunks' states), so the recomputed region holds no second
+    forward; nothing is left without a scope. ``full`` runs it again."""
+    import functools
+
+    from deepspeed_tpu.models import gated_delta
+
+    monkeypatch.setattr(gated_delta, "chunked_delta_rule", functools.partial(
+        gated_delta.chunked_delta_rule, interpret=True))
+    over = dict(CASES["delta_hybrid"][0], delta_key_dim=32,
+                delta_value_dim=64, remat_policy="dots_saveable")
+    names = _op_names(over, 1)
+    assert steplog.programs()[-1].delta_scan_lowerings == {"pallas": 6}
+    parts = [set(re.split(r"[/()]", n)) for n in names]
+    assert all(p & set(STEP_SCOPES) for p in parts)
+    fwd = [n for n in names if "/delta_scan/jit(rule_fwd)/" in n]
+    bwd = [n for n in names if "/delta_scan/jit(rule_bwd)/" in n]
+    assert fwd and bwd and all("/attn/" in n for n in fwd + bwd)
+    assert all("transpose(" in n for n in bwd)
+    assert not any("transpose(" in n for n in fwd)
+    assert not any("rematted_computation" in n for n in fwd)
+    again = [n for n in _op_names(dict(over, remat_policy="full"), 1)
+             if "/delta_scan/jit(rule_fwd)/" in n]
+    assert any("rematted_computation" in n for n in again)
+    assert any("rematted_computation" not in n for n in again)
+
+
 def test_the_flash_kernels_in_parts_stay_under_attn_mla(monkeypatch):
     """The latent-attention case through the flash kernels (interpreted
     here), q and k in the parts the products write and the rope on q as its
