@@ -25,8 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.transformer import _norm
+from deepspeed_tpu.ops.causal_conv import causal_conv_silu
 from deepspeed_tpu.ops.delta_rule import chunked_delta_rule
-from deepspeed_tpu.ops.ssd_scan import causal_conv
 
 F32 = jnp.float32
 #: what the sum of squares of a head's q or k is raised by before its root
@@ -91,9 +91,14 @@ def param_specs() -> Dict[str, Any]:
 
 def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     """The mixer on its input u [B, T, D] -> [B, T, D]. Its operations lie
-    under the nested scopes ``delta_proj``, ``delta_conv``, ``delta_scan``
-    (the norms of q and k, the step and the decay, the chunked rule) and
-    ``delta_gate`` (inside the caller's ``attn``)."""
+    under the nested scopes ``delta_proj``, ``delta_conv`` (three
+    :func:`causal_conv_silu`: convolution and silu in float32, rounded once,
+    q and k to float32, which the rule norms, v to ``u``'s dtype; on the
+    chip in bf16 two Mosaic kernels each, ``.../delta_conv/jit(conv_fwd)/
+    pallas_call`` and ``jit(conv_bwd)`` under ``transpose``, elsewhere
+    ``jax.numpy``'s shifted multiply-adds), ``delta_scan`` (the norms of q
+    and k, the step and the decay, the chunked rule) and ``delta_gate``
+    (inside the caller's ``attn``)."""
     B, T, _ = u.shape
     s, dk, dv = sizes(cfg), cfg.delta_key_dim, cfg.delta_value_dim
     H = s["heads"]
@@ -101,8 +106,9 @@ def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
         q, k, v, z = (u @ w[n] for n in ("wq", "wk", "wv", "wz"))
         b, a = u @ w["wb"], u @ w["wa"]
     with jax.named_scope("delta_conv"):
-        q, k, v = (jax.nn.silu(causal_conv(x, w[n])) for x, n in
-                   ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+        q, k = (causal_conv_silu(x, w[n], out_dtype=F32)
+                for x, n in ((q, "conv_q"), (k, "conv_k")))
+        v = causal_conv_silu(v, w["conv_v"], out_dtype=u.dtype)
     with jax.named_scope("delta_scan"):
         beta = jax.nn.sigmoid(b.astype(F32))
         if cfg.delta_neg_eigval:
@@ -112,7 +118,7 @@ def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
         # the rule puts the norms on a head's q and k itself
         o = chunked_delta_rule(
             q.reshape(B, T, H, dk), k.reshape(B, T, H, dk),
-            v.astype(u.dtype).reshape(B, T, H, dv), g, beta,
+            v.reshape(B, T, H, dv), g, beta,
             unit=(1.0 / math.sqrt(dk), L2_EPS))
     with jax.named_scope("delta_gate"):
         # the norm first, over a head's dv channels, then the gate

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.transformer import _norm
-from deepspeed_tpu.ops.ssd_scan import causal_conv, ssd_scan
+from deepspeed_tpu.ops.causal_conv import causal_conv_silu
+from deepspeed_tpu.ops.ssd_scan import ssd_scan
 
 
 def sizes(cfg) -> Dict[str, int]:
@@ -73,8 +74,16 @@ def param_specs() -> Dict[str, Any]:
 def ssm_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     """The mixer on the normed input u [B, T, D] -> [B, T, D]. Its operations
     lie under the nested scopes ``ssm_proj``, ``ssm_conv``, ``ssm_scan`` and
-    ``ssm_gate`` (inside the caller's ``attn``). ``ssm_scan`` holds the
-    softplus, the splits and :func:`ssd_scan`, which is two Mosaic kernels
+    ``ssm_gate`` (inside the caller's ``attn``). ``ssm_conv`` holds
+    :func:`causal_conv_silu` on ``xBC``, the convolution, its bias and silu
+    in float32 with one rounding to ``u``'s dtype: two Mosaic kernels with a
+    backward of their own (``.../ssm_conv/jit(conv_fwd)/pallas_call``,
+    ``jit(conv_bwd)`` under ``transpose``) that read ``xBC`` where it lies in
+    ``in_proj``'s product and write ``x``, ``B`` and ``C`` as arrays of their
+    own (the backward takes their cotangents as it gets them: nothing is put
+    side by side), where the call's backend, dtype and shapes allow, and
+    ``jax.numpy``'s shifted multiply-adds and a split elsewhere. ``ssm_scan``
+    holds the softplus and :func:`ssd_scan`, which is two Mosaic kernels
     with a backward of their own (``.../ssm_scan/jit(ssd_fwd)/pallas_call``,
     ``jit(ssd_bwd)`` under ``transpose``) where the call's backend, dtype and
     shapes allow, and einsums with autodiff's backward elsewhere."""
@@ -87,10 +96,9 @@ def ssm_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
         z, xbc, dt = jnp.split(u @ w["in_proj"], [inner, inner + s["conv"]],
                                axis=-1)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, w["conv_w"], w["conv_b"])
-                          ).astype(u.dtype)
+        x, Bm, Cm = causal_conv_silu(xbc, w["conv_w"], w["conv_b"], u.dtype,
+                                     splits=(inner, inner + G * N))
     with jax.named_scope("ssm_scan"):
-        x, Bm, Cm = jnp.split(xbc, [inner, inner + G * N], axis=-1)
         dt = jax.nn.softplus(dt.astype(f32) + w["dt_bias"].astype(f32))
         y = ssd_scan(x.reshape(B, T, H, Pd), dt,
                      -jnp.exp(w["A_log"].astype(f32)),

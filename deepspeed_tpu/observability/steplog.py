@@ -428,6 +428,12 @@ class StepProgram:
         #: and the kernels' backward once more (``ops/ssd_scan.py``); None
         #: where the trace held none
         self.ssm_scan_lowerings: Optional[Dict[str, int]] = None
+        #: the state-space and delta mixers' causal convolutions the
+        #: program's trace lowered, by the lowering each took, ``{"pallas":
+        #: n}`` or ``{"xla": n}`` (``ops/causal_conv.py``: a convolution
+        #: counts once, the kernels' own backward once more; a kind that
+        #: counted nothing is left out); None where the trace held none
+        self.conv_lowerings: Optional[Dict[str, int]] = None
         #: (key width, value width) of a head where they differ (latent
         #: attention: the flash kernels take both, ``flash_bwd_lowerings``
         #: says which backward ran at them); None elsewhere

@@ -1,5 +1,5 @@
-"""The chunked scan of a Mamba-2 (state-space duality) layer, and the causal
-depthwise convolution in front of it.
+"""The chunked scan of a Mamba-2 (state-space duality) layer (the causal
+depthwise convolution in front of it: :mod:`deepspeed_tpu.ops.causal_conv`).
 
 Per head, over a state ``h`` [P, N] that starts at zero::
 
@@ -83,22 +83,6 @@ _LOWERINGS = {"pallas": 0, "xla": 0}
 
 def lowerings() -> dict:
     return dict(_LOWERINGS)
-
-
-def causal_conv(x: jax.Array, w: jax.Array,
-                b: Optional[jax.Array] = None) -> jax.Array:
-    """Depthwise causal convolution over the last ``K`` positions: x
-    [B, T, C], w [K, C], b [C] or None; ``y_t = b + sum_k w[k]
-    x_{t-(K-1)+k}`` with zeros before the sequence's start. ``K`` shifted
-    multiply-adds in float32, returned in float32 (the caller's activation
-    fuses in)."""
-    K, T = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(F32), ((0, 0), (K - 1, 0), (0, 0)))
-    y = None if b is None else b.astype(F32)
-    for k in range(K):
-        tap = xp[:, k:k + T] * w[k].astype(F32)
-        y = tap if y is None else y + tap
-    return y
 
 
 # ---------------------------------------------------------------------------

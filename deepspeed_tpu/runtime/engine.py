@@ -76,6 +76,12 @@ def _scan_lowerings() -> Optional[Dict[str, int]]:
     return _traced_counts("deepspeed_tpu.ops.ssd_scan")
 
 
+def _conv_lowerings() -> Optional[Dict[str, int]]:
+    """The state-space and delta mixers' convolutions
+    (``ops/causal_conv.py``)."""
+    return _traced_counts("deepspeed_tpu.ops.causal_conv", "conv_lowerings")
+
+
 def _delta_lowerings() -> Optional[Dict[str, int]]:
     """The delta layers' chunked rules (``ops/delta_rule.py``)."""
     return _traced_counts("deepspeed_tpu.ops.delta_rule")
@@ -88,6 +94,13 @@ def _counted(before: Optional[Dict[str, int]],
         return None
     return {kind: n - (before or {}).get(kind, 0)
             for kind, n in after.items()}
+
+
+def _kinds_counted(before, after) -> Optional[Dict[str, int]]:
+    """:func:`_counted` without the kinds that counted nothing: a program
+    traced off the chip reads ``{"xla": n}`` as it did before the kernels."""
+    counted = _counted(before, after)
+    return counted and {kind: n for kind, n in counted.items() if n}
 
 
 def _leaf(tree, path):
@@ -1097,6 +1110,7 @@ class DeepSpeedTpuEngine:
             grouped_before = _grouped_lowerings()
             dispatch_before = _dispatch_lowerings()
             scan_before = _scan_lowerings()
+            conv_before = _conv_lowerings()
             delta_before = _delta_lowerings()
         t0 = time.perf_counter()
         with steplog.span(self._ebus, "train", "dispatch"), \
@@ -1134,11 +1148,9 @@ class DeepSpeedTpuEngine:
         row.moe_dispatch_lowerings = _counted(dispatch_before,
                                               _dispatch_lowerings())
         row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
-        # (a kind that counted nothing stays off this row: a program traced
-        # off the chip reads ``{"xla": n}`` as it did before the kernels)
-        delta = _counted(delta_before, _delta_lowerings())
-        row.delta_scan_lowerings = delta and {
-            kind: n for kind, n in delta.items() if n}
+        row.conv_lowerings = _kinds_counted(conv_before, _conv_lowerings())
+        row.delta_scan_lowerings = _kinds_counted(delta_before,
+                                                  _delta_lowerings())
 
     def _rule_moves_only_here(self, what: str) -> None:
         """Raise on a step path that does not carry the model's rule-moved

@@ -222,6 +222,33 @@ def _delta(grad, H=15, dk=96, dv=192, T=4096, einsum=False, unit=True):
         qk, qk, ((1, T, H, dv), jnp.bfloat16), gb, gb]
 
 
+def _conv(grad, C=4352, bias=True, out=jnp.bfloat16, splits=(), T=4096, K=4):
+    """The mixers' convolution with its silu at the Granite cell's call
+    (BENCHMARK.json: 1 x 4096 tokens, xBC 4352 wide, a bias, bf16 out, x, B
+    and C as arrays of their own) and the Olmo-Hybrid cell's three (q and k
+    1440 wide to float32, v 2880 wide to bf16, no bias; rows that end inside
+    a lane tile), by the picker's answer for the shape as if on the chip:
+    the forward kernel alone, and the differentiated forward with the
+    backward kernel."""
+    from deepspeed_tpu.ops import causal_conv as cc
+
+    took, _ = cc.conv_lowering(T, C, K, jnp.bfloat16, out, splits, tpu=True)
+
+    def fwd(x, w, b=None):
+        if took == "xla":
+            return (jax.nn.silu(cc.causal_conv(x, w, b)).astype(out),)
+        return cc._conv_pallas(x, w, b, jnp.dtype(out).name,
+                               cc._widths(C, splits), False)
+
+    def loss(*a):
+        return sum((part.astype(jnp.float32) ** 2).sum() for part in fwd(*a))
+
+    shapes = [((1, T, C), jnp.bfloat16), ((K, C), jnp.bfloat16)] \
+        + [((C,), jnp.bfloat16)] * bias
+    return (jax.grad(loss, argnums=tuple(range(len(shapes)))) if grad
+            else fwd), shapes
+
+
 # (builder, kwargs, must the compiled program hold a Mosaic kernel?)
 CASES = {
     "flash-fwd-d64": (_flash, dict(d=64, grad=False), True),
@@ -308,6 +335,20 @@ CASES = {
         _delta, dict(grad=True, H=4, T=1000, einsum=True), False),
     "delta-rule-grad-k128-refused": (
         _delta, dict(grad=True, H=4, dk=128, dv=128, T=1024), False),
+    # the mixers' convolution with its silu at both cells' calls, a T that
+    # is no whole sublane tiles (the picker gives the jax.numpy form)
+    "conv-silu-fwd-granite-cell": (
+        _conv, dict(grad=False, splits=(4096, 4224)), True),
+    "conv-silu-grad-granite-cell": (
+        _conv, dict(grad=True, splits=(4096, 4224)), True),
+    "conv-silu-fwd-olmo-cell-q-k": (
+        _conv, dict(grad=False, C=1440, bias=False, out=jnp.float32), True),
+    "conv-silu-grad-olmo-cell-q-k": (
+        _conv, dict(grad=True, C=1440, bias=False, out=jnp.float32), True),
+    "conv-silu-grad-olmo-cell-v": (
+        _conv, dict(grad=True, C=2880, bias=False), True),
+    "conv-silu-grad-T4090-numpy-form": (
+        _conv, dict(grad=True, C=256, T=4090), False),
 }
 
 
@@ -776,36 +817,35 @@ def test_kernel_path_rules_match_what_compiled():
     assert quant_matmul_path(512, 4096, 14336, 128)[0] == 0
 
 
-def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
-                                                             monkeypatch):
-    """The whole step at the benchmark cell's size (``benchmarks/configs/
-    olmo_hybrid_7b_train_d4h15v8.json``: one period at the published widths,
-    15 of 30 heads, 12544 rows, 1 x 4096 tokens, the file's recomputation
-    policy): gradient and AdamW over fp32 master weights, compiled for the
-    chip. It fits beside what a chip reserves, the full layer runs the flash
-    kernels, and the delta layers' rule is there under its scope."""
+def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
+                       parameters: int):
+    """A benchmark cell's whole step (``benchmarks/configs/<config>.json``
+    at 1 x 4096 tokens, the file's recomputation policy): gradient and AdamW
+    over fp32 master weights, compiled for the chip, every picker answering
+    as on a TPU."""
+    import importlib
     import json
     import os
 
     import optax
 
     import deepspeed_tpu.ops as ops
-    from benchmarks import modelcfg_olmo_hybrid as modelcfg
     from deepspeed_tpu.models import TransformerLM
-    from deepspeed_tpu.ops import delta_rule
+    from deepspeed_tpu.ops import causal_conv, delta_rule, ssd_scan
     from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.runtime.optimizers import build_optimizer
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(delta_rule, "_on_tpu", lambda: True)
+    for module in (fa, delta_rule, ssd_scan, causal_conv):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     with open(os.path.join(root, "benchmarks", "configs",
-                           "olmo_hybrid_7b_train_d4h15v8.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
-    model = TransformerLM(modelcfg.transformer_config(
-        cfg, max_seq_len=4096, param_dtype="float32"))
+    model = TransformerLM(importlib.import_module(
+        "benchmarks." + modelcfg).transformer_config(
+            cfg, max_seq_len=4096, param_dtype="float32"))
     tx = build_optimizer("adamw", {"lr": 1e-6}, lr_schedule=None,
                          gradient_clipping=0.0)
 
@@ -822,23 +862,82 @@ def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
 
     params = jax.eval_shape(model.init, jax.random.key(0))
     assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
-        == 766_241_946
+        == parameters
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(params), described(jax.eval_shape(tx.init, params)),
         {"input_ids": jax.ShapeDtypeStruct((1, 4096), jnp.int32,
                                            sharding=one_chip)}).compile()
     mem = compiled.memory_analysis()
     # fp32 weights, Adam m and v: 12 B a parameter as arguments
-    assert mem.argument_size_in_bytes == pytest.approx(12 * 766_241_946,
+    assert mem.argument_size_in_bytes == pytest.approx(12 * parameters,
                                                        rel=1e-3)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text            # the full layer's flash
-    assert "delta_scan" in text and "delta_conv" in text
-    assert mem.temp_size_in_bytes < 5.36e9
-    calls = [m.group(1) for m in re.finditer(
+    return compiled.as_text(), mem
+
+
+def _kernel_calls(text, scope):
+    """``op_name`` of the Mosaic calls under ``/<scope>/``."""
+    return [m.group(1) for m in re.finditer(
         r"custom-call\(.*tpu_custom_call.*op_name=\"([^\"]*)\"", text)
-        if "/delta_scan/" in m.group(1)]
+        if f"/{scope}/" in m.group(1)]
+
+
+def _conv_kernels_alone_under(text, scope, forwards, backwards):
+    """Under ``attn/<scope>``: ``forwards`` forward kernels, half of them in
+    the backward's recomputed region, ``backwards`` backward kernels, and
+    beside them no padded copy and no shifted multiply-add."""
+    calls = _kernel_calls(text, scope)
+    assert calls and all(f"/attn/{scope}/" in n for n in calls)
+    fwd = [n for n in calls if "jit(conv_fwd)" in n]
+    assert len(fwd) == forwards and len(calls) == forwards + backwards
+    assert sum("rematted_computation" in n for n in fwd) == forwards // 2
+    assert all("jit(conv_bwd)" in n and "transpose(" in n
+               for n in calls if n not in fwd)
+    beside = {n.rsplit("/", 1)[-1] for n in re.findall(
+        r'op_name="([^"]*)"', text) if f"/{scope}/" in n}
+    assert not beside & {"pad", "mul", "add", "logistic", "reduce_sum"}, beside
+
+
+def test_the_granite_cells_step_program_compiles_for_v5e(one_chip,
+                                                         monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    granite4_h_micro_train_d10v8.json``: one period at the published widths,
+    12544 rows, ``full`` recomputation). It fits beside what a chip
+    reserves with no more temporaries than before the convolution's kernels
+    (4,497,313,280), and each of the two runs of state-space layers holds
+    the convolution's forward kernel twice (once recomputed) and its
+    backward once under ``attn/ssm_conv``, beside the scan's under
+    ``attn/ssm_scan``."""
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "granite4_h_micro_train_d10v8",
+        "modelcfg_granite4h", 772_160_448)
+    assert mem.temp_size_in_bytes < 4.5e9
+    _conv_kernels_alone_under(text, "ssm_conv", forwards=4, backwards=2)
+    scan = _kernel_calls(text, "ssm_scan")
+    assert sum("jit(ssd_fwd)" in n for n in scan) == 4
+    assert sum("jit(ssd_bwd)" in n for n in scan) == 2
+    assert not any("conv_" in n for n in scan)
+
+
+def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
+                                                             monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    olmo_hybrid_7b_train_d4h15v8.json``: one period at the published widths,
+    15 of 30 heads, 12544 rows, ``dots_saveable``). It fits beside what a
+    chip reserves, the full layer runs the flash kernels, the delta layers'
+    rule is there under its scope and is not run again in the backward, and
+    each delta layer's three convolutions are kernels under
+    ``attn/delta_conv`` (run again there: a kernel is no dot), none of them
+    under ``delta_scan``, where the benchmark's reader would take one for a
+    second forward of the rule."""
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "olmo_hybrid_7b_train_d4h15v8",
+        "modelcfg_olmo_hybrid", 766_241_946)
+    assert "tpu_custom_call" in text            # the full layer's flash
+    assert mem.temp_size_in_bytes < 4.07e9
+    calls = _kernel_calls(text, "delta_scan")
     assert sum("jit(rule_fwd)" in n for n in calls) == 3
     assert sum("jit(rule_bwd)" in n for n in calls) == 3
     assert not any("rematted_computation" in n for n in calls)
+    assert not any("conv_" in n for n in calls)
+    _conv_kernels_alone_under(text, "delta_conv", forwards=18, backwards=9)

@@ -19,7 +19,8 @@ sys.path.insert(0, os.path.dirname(__file__))
 import granite_reference as ref  # noqa: E402
 from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
 from deepspeed_tpu.models import transformer as tf  # noqa: E402
-from deepspeed_tpu.ops.ssd_scan import causal_conv, ssd_scan  # noqa: E402
+from deepspeed_tpu.ops.causal_conv import causal_conv  # noqa: E402
+from deepspeed_tpu.ops.ssd_scan import ssd_scan  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -336,6 +337,7 @@ def test_the_step_record_carries_the_mixer_outputs():
     # float32 heads of 16 on a CPU: every scan the trace holds is the einsum
     # form (its backward is autodiff's and is not counted)
     assert prog.ssm_scan_lowerings == {"pallas": 0, "xla": 3}
+    assert prog.conv_lowerings == {"xla": 3}
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
     assert "ssm_chunk" not in dense.step_program_facts()
@@ -368,6 +370,32 @@ def test_the_cell_shaped_step_program_takes_the_scan_kernels(monkeypatch):
     # the period's three scans and the backward of each
     assert prog.ssm_scan_lowerings == {"pallas": 6, "xla": 0}
     assert prog.ssm_chunks_per_step == 3 * 2 * 2     # 200 tokens: two chunks
+    np.testing.assert_allclose(loss, plain, atol=2e-3)
+
+
+def test_the_cell_shaped_step_program_takes_the_conv_kernels(monkeypatch):
+    """The same small cell with the convolution as its Pallas kernels
+    (interpreted here), rows of whole sublane tiles: every convolution of
+    the program and its backward is the kernels', and the step gives the
+    ``jax.numpy`` form's loss."""
+    import functools
+
+    from deepspeed_tpu.models import mamba
+    from deepspeed_tpu.observability import steplog
+
+    hf = hf_config(D=128, mamba_n_heads=4, mamba_d_head=64,
+                   mamba_d_state=128, mamba_n_groups=2, mamba_chunk_size=128)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 192)).astype(np.int32)
+    kw = dict(remat_policy="full", dtype="bfloat16", max_seq_len=256)
+    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    assert steplog.programs()[-1].conv_lowerings == {"xla": 3}
+    monkeypatch.setattr(mamba, "causal_conv_silu", functools.partial(
+        mamba.causal_conv_silu, interpret=True))
+    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    # the period's three convolutions and the backward of each
+    assert steplog.programs()[-1].conv_lowerings == {"pallas": 6}
     np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 

@@ -410,6 +410,7 @@ def test_the_step_record_and_the_step_programs_row():
     assert row.delta_chunk == 8
     assert row.delta_chunks_per_step == 1 * 2 * 3
     assert row.delta_scan_lowerings == {"xla": 1}
+    assert row.conv_lowerings == {"xla": 3}        # q's, k's and v's
     assert row.ssm_chunk is None and row.ssm_scan_lowerings is None
     # A_log and dt_bias moved in float32, by the optimizer
     after = jax.device_get(engine.params)["layers"]["delta"]
@@ -445,6 +446,33 @@ def test_the_cell_shaped_step_program_takes_the_rule_kernels(monkeypatch):
     # the period's three rules and the backward of each
     assert prog.delta_scan_lowerings == {"pallas": 6}
     assert prog.delta_chunks_per_step == 3 * 2 * 4    # 200 tokens: 4 chunks
+    np.testing.assert_allclose(loss, plain, atol=2e-3)
+
+
+def test_the_cell_shaped_step_program_takes_the_conv_kernels(monkeypatch):
+    """The same small cell with the convolutions as their Pallas kernels
+    (interpreted here), rows of whole sublane tiles: q's and k's leave in
+    float32, v's in bf16, every one of the program and its backward is the
+    kernels', and the step gives the ``jax.numpy`` form's loss."""
+    import functools
+
+    from deepspeed_tpu.models import gated_delta
+    from deepspeed_tpu.observability import steplog
+
+    monkeypatch.setattr(delta_rule, "CHUNK", 64)
+    hf = hf_config(D=128, heads=2, d=64, max_position_embeddings=256)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 192)).astype(np.int32)
+    kw = dict(remat_policy="dots_saveable", dtype="bfloat16",
+              max_seq_len=256)
+    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    assert steplog.programs()[-1].conv_lowerings == {"xla": 9}
+    monkeypatch.setattr(gated_delta, "causal_conv_silu", functools.partial(
+        gated_delta.causal_conv_silu, interpret=True))
+    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
+        {"input_ids": rows}))
+    # three delta layers' three convolutions and the backward of each
+    assert steplog.programs()[-1].conv_lowerings == {"pallas": 18}
     np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 

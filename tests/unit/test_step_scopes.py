@@ -202,6 +202,58 @@ def test_the_rule_kernels_keep_the_scan_scope(monkeypatch):
     assert any("rematted_computation" not in n for n in again)
 
 
+# (case, the mixer's module, the convolution's scope, the scan's, what the
+# trace counts: a convolution and its backward for each of the hybrid case's
+# three state-space layers, three convolutions and the backward of each for
+# each of the delta case's three delta layers)
+CONV_KERNELS = {
+    "hybrid": ("mamba", "ssm_conv", "ssm_scan", 6, "full"),
+    "delta_hybrid": ("gated_delta", "delta_conv", "delta_scan", 18,
+                     "dots_saveable"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONV_KERNELS))
+def test_the_conv_kernels_keep_the_conv_scope(monkeypatch, case):
+    """The mixers' convolution as its Pallas kernels (interpreted here), each
+    case under its benchmark cell's policy: the forward kernel, its
+    recomputation (``dots_saveable`` keeps products, and a kernel is none)
+    and the backward kernel lower under ``attn/<mixer>_conv`` with the jitted
+    kernel's name in the path, which is how the benchmark's scope readers
+    find the Mosaic calls on the chip, and never under the scan's scope,
+    where the delta cell's reader would take a recomputed kernel for a second
+    forward of the rule; no padded copy is left under the scope; nothing is
+    left without a scope; the row says which lowering the program took, on
+    the chip's kind of program and on a CPU's."""
+    import functools
+    import importlib
+
+    module, conv, scan, counted, policy = CONV_KERNELS[case]
+    over = dict(CASES[case][0], remat_policy=policy)
+    if case == "hybrid":        # x, B and C of whole lane tiles
+        over.update(ssm_heads=2, ssm_head_dim=64, ssm_state=128, ssm_groups=1)
+    _op_names(over, 1)
+    assert steplog.programs()[-1].conv_lowerings == {"xla": counted // 2}
+    mixer = importlib.import_module(f"deepspeed_tpu.models.{module}")
+    monkeypatch.setattr(mixer, "causal_conv_silu", functools.partial(
+        mixer.causal_conv_silu, interpret=True))
+    names = _op_names(over, 1)
+    assert steplog.programs()[-1].conv_lowerings == {"pallas": counted}
+    parts = [set(re.split(r"[/()]", n)) for n in names]
+    assert all(p & set(STEP_SCOPES) for p in parts)
+    fwd = [n for n in names if f"/{conv}/jit(conv_fwd)/" in n]
+    bwd = [n for n in names if f"/{conv}/jit(conv_bwd)/" in n]
+    assert fwd and bwd and all("/attn/" in n for n in fwd + bwd)
+    assert all("transpose(" in n for n in bwd)
+    assert any("rematted_computation" in n for n in fwd)
+    assert any("transpose(" not in n for n in fwd)
+    assert not any(scan in n for n in fwd + bwd)
+    assert not any("conv_fwd" in n or "conv_bwd" in n for n in names
+                   if f"/{conv}/" not in n)
+    under = [n for n in names if conv in re.split(r"[/()]", n)]
+    assert not any(n.endswith("/pad") for n in under)
+
+
 def test_the_flash_kernels_in_parts_stay_under_attn_mla(monkeypatch):
     """The latent-attention case through the flash kernels (interpreted
     here), q and k in the parts the products write and the rope on q as its

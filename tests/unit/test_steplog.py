@@ -168,6 +168,7 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
     assert rows[0].moe_grouped_lowerings is None      # no expert layer
     assert rows[0].moe_dispatch_lowerings is None
     assert rows[0].ssm_scan_lowerings is None         # no state-space layer
+    assert rows[0].conv_lowerings is None             # nor a convolution
     assert log.n_steps == n0 + 3
     last = log.steps()[-3:]
     assert last[:, 0].tolist() == [0, 1, 2]
