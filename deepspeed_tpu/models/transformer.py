@@ -1462,34 +1462,63 @@ class TransformerLM:
         ``layer_applications``)."""
         return (self._pld_depth or self.cfg.num_layers) * self.cfg.num_passes
 
-    def step_program_facts(self) -> Dict[str, Any]:
+    def step_program_facts(self, batch_shape=None) -> Dict[str, Any]:
         """What a step program's row of the step-program table says of its
-        model (``observability/steplog.py:StepProgram``)."""
+        model (``observability/steplog.py:StepProgram.facts``; a fact that
+        does not apply is left out and the row answers None), and what a
+        batch of ``batch_shape`` [rows, T] adds once one is known."""
         cfg = self.cfg
         facts: Dict[str, Any] = {
+            # block applications one micro-batch's forward holds
             "layer_applications": self.layer_applications,
-            # (a stack whose FFNs differ by layer: its runs' kinds)
-            "layer_pattern": cfg.attn_pattern or tuple(dict.fromkeys(
+            # the period of layer kinds the layer loop scans ("window" /
+            # "full" attention, "ssm" a state-space layer, "delta" a gated
+            # delta-rule layer; a stack whose FFNs differ by layer: its
+            # runs' kinds)
+            "layer_pattern": tuple(cfg.attn_pattern or dict.fromkeys(
                 cfg.layer_kinds))}
+        chunks = {}
         if cfg.has_ssm:
-            facts["ssm_chunk"] = cfg.ssm_chunk
+            chunks["ssm"] = cfg.ssm_chunk
         if cfg.has_delta:
             from deepspeed_tpu.ops import delta_rule
-            facts["delta_chunk"] = delta_rule.CHUNK
+            chunks["delta"] = delta_rule.CHUNK
+        for kind, chunk in chunks.items():
+            # the chunk length of the kind's scan, and the chunks one step's
+            # forward scans: layers x rows x ceil(T / chunk)
+            facts[f"{kind}_chunk"] = chunk
+            if batch_shape is not None:
+                rows, T = batch_shape
+                facts[f"{kind}_chunks_per_step"] = (
+                    cfg.layer_kinds.count(kind) * int(rows)
+                    * -(-int(T) // chunk))
         if cfg.heads_held is not None:
+            # (count, all) of the heads a mixer holds, where a share of them
             facts["heads_held"] = (cfg.heads_held, cfg.num_heads)
         if cfg.has_mla:
+            # (key width, value width) of a head where they differ (latent
+            # attention: the flash kernels take both)
             facts["attn_widths"] = (
                 cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim)
         if cfg.num_experts > 1:
             if cfg.moe_scoring != "softmax":
+                # how the router scores where it is not the softmax
+                # ("sigmoid": a selection bias the step moves by rule,
+                # ``router_counts`` in the step record's parts)
                 facts["moe_scoring"] = cfg.moe_scoring
+            # (first, count, routed) of the experts a layer holds
             facts["experts_held"] = (
                 cfg.moe_first_expert if cfg.moe_experts_held else 0,
                 cfg.moe_experts_held or cfg.num_experts, cfg.num_experts)
             if cfg.moe_dispatch == "grouped":
                 from deepspeed_tpu.moe.sharded_moe import resolve_moe_kernel
 
+                # the grouped expert product the program is traced with:
+                # "ragged" (every pair over the sorted rows: the Pallas
+                # kernels or ``lax.ragged_dot``, the row's ``moe_grouped``
+                # count says which) or "padded" (its einsum twin, also what
+                # ``resolve_moe_kernel`` falls to where ragged_dot does not
+                # lower)
                 facts["moe_kernel_resolved"] = resolve_moe_kernel(
                     cfg.moe_kernel)[0]
         return facts
@@ -1522,30 +1551,6 @@ class TransformerLM:
                 counts.mean(axis=-1, keepdims=True) - counts).astype(
                     bias.dtype)
         return out
-
-    def ssm_chunks_scanned(self, batch_shape) -> Optional[int]:
-        """Chunks the state-space layers of one step's forward scan over a
-        batch of ``batch_shape`` [rows, T]: layers x rows x ceil(T / chunk)
-        (the step-program table's ``ssm_chunks_per_step``); None for a model
-        without such a layer."""
-        return self._chunks("ssm", self.cfg.ssm_chunk, batch_shape)
-
-    def _chunks(self, kind: str, chunk: int, batch_shape) -> Optional[int]:
-        """Layers of ``kind`` x rows x ceil(T / chunk) for a batch of
-        ``batch_shape`` [rows, T]; None for a model without such a layer."""
-        layers = self.cfg.layer_kinds.count(kind)
-        if not layers:
-            return None
-        rows, T = batch_shape
-        return layers * int(rows) * -(-int(T) // chunk)
-
-    def delta_chunks_scanned(self, batch_shape) -> Optional[int]:
-        """Chunks the delta layers of one step's forward go through over a
-        batch of ``batch_shape`` [rows, T]: layers x rows x ceil(T / chunk)
-        (the step-program table's ``delta_chunks_per_step``); None for a
-        model without such a layer."""
-        from deepspeed_tpu.ops import delta_rule
-        return self._chunks("delta", delta_rule.CHUNK, batch_shape)
 
     def check_topology(self, axis_sizes: Dict[str, int]) -> None:
         """Raise where the mesh has an axis this model cannot be laid over:

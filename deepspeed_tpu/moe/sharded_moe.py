@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import lowerings
 from deepspeed_tpu.parallel.sharding import constrain
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -541,7 +542,10 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     how = _moves_lowering(S, bound, w, dt, kernel, interpret)
     with jax.named_scope("moe_dispatch"):
         moves = _row_moves(rows, group_sizes, n_here, S, k, how)
-    _DISPATCH_LOWERINGS[how[0]] += 2    # the dispatch, the combine
+    # a dispatch and a combine by the lowering they took: counted where the
+    # layer is traced, not in the move (the dispatch is traced again for the
+    # backward's fetch), and once more each when its backward is traced
+    lowerings.count("moe_dispatch", how[0], 2)
 
     def fetch(x):           # [bound, D]
         with jax.named_scope("moe_dispatch"):
@@ -584,20 +588,10 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
 # to the bit); ``"xla"`` is ``jnp.take``, which fetches every row it is
 # given (the CPU, float32, int8 stacks, a decode step's few rows).
 
-# dispatches and combines by the lowering they took, counted when traced: one
-# for the move (where the layer is traced, not in the move: the dispatch is
-# traced again for the backward's fetch) and one more when its backward is
-# traced (the step-program table reads the difference around a step
-# program's first call, as it does ``ops/grouped_matmul.py:lowerings``)
-_DISPATCH_LOWERINGS = {"pallas": 0, "xla": 0}
 # the ``checkpoint_name`` of the dispatch's packed tokens
 PACKED_TOKENS = "moe_packed_tokens"
 # the kernels keep a word a buffer row in scalar memory (512 KiB here)
 _MOST_ROWS = 131072
-
-
-def dispatch_lowerings() -> Dict[str, int]:
-    return dict(_DISPATCH_LOWERINGS)
 
 
 def _moves_lowering(S: int, bound: int, w: Dict[str, jax.Array], dt,
@@ -649,7 +643,7 @@ def _rows_fwd(x, tok, slot, moves, how):
 
 def _rows_bwd(how, res, g):
     slot, moves = res
-    _DISPATCH_LOWERINGS[how[0]] += 1
+    lowerings.count("moe_dispatch", how[0])
     if how[0] == "xla":
         return _sum_of_rows(g, slot, None).astype(g.dtype), None, None, None
     from deepspeed_tpu.ops import moe_rows
@@ -702,7 +696,7 @@ def _wsum_fwd(ys, weights, rows, slot, moves, how):
 def _wsum_bwd(how, res, g):
     ys, weights, rows, slot, moves = res
     k = slot.shape[1]
-    _DISPATCH_LOWERINGS[how[0]] += 1
+    lowerings.count("moe_dispatch", how[0])
     # a row's cotangent: its token's, times its pair's weight (a row that
     # carries no pair gets one all the same: ``_grouped_ffn`` cuts it off, by
     # its mask or by kernels that work only the rows a group holds)

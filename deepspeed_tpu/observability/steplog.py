@@ -361,103 +361,19 @@ class StepProgram:
     needs to compile it again. The jitted function is held weakly: when its
     engine is gone, so is the program, and the row answers None."""
 
-    def __init__(self, name: str, key: Any, fn: Callable, mesh,
-                 layer_applications: Optional[int] = None,
-                 layer_pattern: Optional[Sequence[str]] = None,
-                 moe_kernel_resolved: Optional[str] = None,
-                 experts_held: Optional[Sequence[int]] = None,
-                 ssm_chunk: Optional[int] = None,
-                 attn_widths: Optional[Sequence[int]] = None,
-                 moe_scoring: Optional[str] = None,
-                 delta_chunk: Optional[int] = None,
-                 heads_held: Optional[Sequence[int]] = None):
+    def __init__(self, name: str, key: Any, fn: Callable, mesh, **facts):
         self.name = name
         self.key = str(key)
-        #: block applications one micro-batch's forward holds (layers run x
-        #: passes over them); None where the model does not say
-        self.layer_applications = layer_applications
-        #: the period of layer kinds the layer loop scans ("window" / "full"
-        #: attention, "ssm" a state-space layer, "delta" a gated delta-rule
-        #: layer); None where the model does not say
-        self.layer_pattern = None if layer_pattern is None \
-            else tuple(layer_pattern)
-        #: the grouped expert product the program was traced with: "ragged"
-        #: (every pair over the sorted rows: the Pallas kernels or
-        #: ``lax.ragged_dot``, see ``moe_grouped_lowerings``) or "padded" (its
-        #: einsum twin, also what ``resolve_moe_kernel`` falls to where
-        #: ragged_dot does not lower);
-        #: None for a model without grouped experts
-        self.moe_kernel_resolved = moe_kernel_resolved
-        #: (first, count, routed) of the experts a layer holds; None for a
-        #: model without experts
-        self.experts_held = None if experts_held is None \
-            else tuple(experts_held)
-        #: flash-backward lowerings of the program's trace by the kernel they
-        #: took, ``{"fused": n, "split": m}`` (``ops/flash_attention.py``);
-        #: None until the program's first call has traced it
-        self.flash_bwd_lowerings: Optional[Dict[str, int]] = None
-        #: flash lowerings of the program's trace, forward or backward, by
-        #: whether q and k arrived in parts, the rope columns as operands of
-        #: their own (latent attention), ``{"operand": n, "none": m}``; None
-        #: until the program's first call has traced it
-        self.flash_rope_operand_lowerings: Optional[Dict[str, int]] = None
-        #: the tiles one head of the flash forward takes by arm and whether
-        #: its log-sum-exp leaves as rows, ``{"masked", "unmasked", "dead",
-        #: "rows"}``, of the newest forward the program's trace lowered; None
-        #: where it lowered none, and until the first call
-        self.flash_fwd_tiles: Optional[Dict[str, Any]] = None
-        #: the experts' grouped products the program's trace lowered, by the
-        #: lowering each took, ``{"pallas": n, "xla": m}``: a product counts
-        #: once and its backward's two transposes once each
-        #: (``ops/grouped_matmul.py``); None where the trace held none
-        self.moe_grouped_lowerings: Optional[Dict[str, int]] = None
-        #: the expert layers' dispatches and combines the program's trace
-        #: lowered, by the lowering each took, ``{"pallas": n, "xla": m}``: a
-        #: move counts once and its backward once more
-        #: (``moe/sharded_moe.py``, ``ops/moe_rows.py``); None where the
-        #: trace held none
-        self.moe_dispatch_lowerings: Optional[Dict[str, int]] = None
-        #: the chunk length of the state-space layers' scan, and the chunks
-        #: one step's forward scans (state-space layers x rows x ceil(T /
-        #: chunk), from the batch of the program's first call); None for a
-        #: model without such a layer
-        self.ssm_chunk = ssm_chunk
-        self.ssm_chunks_per_step: Optional[int] = None
-        #: the state-space layers' scans the program's trace lowered, by the
-        #: lowering each took, ``{"pallas": n, "xla": m}``: a scan counts once
-        #: and the kernels' backward once more (``ops/ssd_scan.py``); None
-        #: where the trace held none
-        self.ssm_scan_lowerings: Optional[Dict[str, int]] = None
-        #: the state-space and delta mixers' causal convolutions the
-        #: program's trace lowered, by the lowering each took, ``{"pallas":
-        #: n}`` or ``{"xla": n}`` (``ops/causal_conv.py``: a convolution
-        #: counts once, the kernels' own backward once more; a kind that
-        #: counted nothing is left out); None where the trace held none
-        self.conv_lowerings: Optional[Dict[str, int]] = None
-        #: (key width, value width) of a head where they differ (latent
-        #: attention: the flash kernels take both, ``flash_bwd_lowerings``
-        #: says which backward ran at them); None elsewhere
-        self.attn_widths = None if attn_widths is None \
-            else tuple(attn_widths)
-        #: how the router scores where it is not the softmax ("sigmoid": a
-        #: selection bias the step moves by rule, ``router_counts`` in the
-        #: step record's parts); None elsewhere
-        self.moe_scoring = moe_scoring
-        #: the chunk length of the delta layers' rule, and the chunks one
-        #: step's forward goes through (delta layers x rows x ceil(T /
-        #: chunk), from the batch of the program's first call); None for a
-        #: model without such a layer
-        self.delta_chunk = delta_chunk
-        self.delta_chunks_per_step: Optional[int] = None
-        #: the delta layers' rules the program's trace lowered, by the
-        #: lowering each took, ``{"xla": n}`` or ``{"pallas": n}``
-        #: (``ops/delta_rule.py``: a rule counts once, the kernels' own
-        #: backward once more; a kind that counted nothing is left out);
-        #: None where the trace held none
-        self.delta_scan_lowerings: Optional[Dict[str, int]] = None
-        #: (count, all) of the heads a mixer holds where that is a
-        #: share of them; None where every head is held
-        self.heads_held = None if heads_held is None else tuple(heads_held)
+        #: what the model said of the program (its ``step_program_facts``,
+        #: where each fact is described) and, from the program's first call
+        #: on, what the pickers counted while it was traced
+        #: (``ops/lowerings.py``: ``{site: {answer: n}}``). Either reads as
+        #: an attribute, a site also as ``<site>_lowerings``; a name nobody
+        #: said anything under answers None
+        self.facts: Dict[str, Any] = {
+            k: tuple(v) if isinstance(v, list) else v
+            for k, v in facts.items()}
+        self.counted: Dict[str, Any] = {}
         self.built_at = time.perf_counter()
         #: the length of the ``ds.train.dispatch`` span of the program's
         #: first call (trace, lowering, the compile or its read from the
@@ -471,6 +387,13 @@ class StepProgram:
         self._mesh = mesh
         self._args = None
         self._compiled = None
+
+    def __getattr__(self, name: str):
+        if name.startswith("_") or name in ("facts", "counted"):
+            raise AttributeError(name)
+        if name in self.facts:
+            return self.facts[name]
+        return self.counted.get(name.removesuffix("_lowerings"))
 
     def capture(self, args) -> None:
         """Keep the abstract arguments (shape, dtype, sharding) of the
@@ -532,7 +455,7 @@ _PROGRAMS: deque = deque(maxlen=64)
 def record_program(name: str, key: Any, fn: Callable, mesh,
                    **facts) -> StepProgram:
     """Enter a step program in the table; ``facts`` are what its model says
-    of itself (:class:`StepProgram`'s keyword arguments)."""
+    of itself (:attr:`StepProgram.facts`)."""
     row = StepProgram(name, key, fn, mesh, **facts)
     _PROGRAMS.append(row)
     return row

@@ -40,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+from deepspeed_tpu.ops import lowerings
 
 F32 = jnp.float32
 # rows of float32 in front of a tile in the taps' scratch (one sublane tile:
@@ -63,17 +64,6 @@ _TILE_BYTES = 10 * 1024 * 1024
 # in 0.109 and 0.236, 32 x 512 in 0.112 and 0.246, 256 x 256 in 0.109 and
 # 0.219 there but a third slower at 1440 channels
 _PIECE_ROWS, _PIECE_LANES = 64, 256
-
-# convolutions by the lowering they took, counted when traced: one for a
-# call, one more for the kernels' backward (the ``jax.numpy`` form's is
-# autodiff's); the step-program table reads the difference around a step
-# program's first call
-_LOWERINGS = {"pallas": 0, "xla": 0}
-
-
-def conv_lowerings() -> dict:
-    return dict(_LOWERINGS)
-
 
 def causal_conv(x: jax.Array, w: jax.Array,
                 b: Optional[jax.Array] = None) -> jax.Array:
@@ -375,7 +365,7 @@ def _conv_pallas_fwd(x, w, b, out_dtype, widths, interpret):
 
 
 def _conv_pallas_bwd(out_dtype, widths, interpret, res, dy):
-    _LOWERINGS["pallas"] += 1
+    lowerings.count("conv", "pallas")     # the kernels' own backward
     return conv_bwd(*res, tuple(dy), interpret=interpret)
 
 
@@ -404,7 +394,9 @@ def causal_conv_silu(x: jax.Array, w: jax.Array,
         if why:
             raise ValueError(f"the convolution's kernels do not take {why}")
         lowering = "pallas"
-    _LOWERINGS[lowering] += 1
+    # a convolution by the lowering it took: one for a call, one more for
+    # the kernels' backward (the ``jax.numpy`` form's is autodiff's)
+    lowerings.count("conv", lowering)
     if lowering == "xla":
         y = jax.nn.silu(causal_conv(x, w, b)).astype(out_dtype)
         return jnp.split(y, splits, axis=-1) if splits else y

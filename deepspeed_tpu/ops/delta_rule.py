@@ -71,8 +71,9 @@ can see: backend, dtype, widths):
   cotangent. Outside the kernels stay the transposes of ``g`` and ``beta``
   to a row a head and back.
 
-:func:`lowerings` counts the rules traced, by lowering, for the step-program
-table: one for a rule, one more for the kernels' own backward.
+The rules traced are counted by lowering (``ops/lowerings.py``, site
+``delta_scan``) for the step-program table: one for a rule, one more for the
+kernels' own backward.
 """
 
 from __future__ import annotations
@@ -88,6 +89,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+from deepspeed_tpu.ops import lowerings
 from deepspeed_tpu.ops.ssd_scan import (_NT, _TN, _dot, _exact_dot,
                                         _pad_to_chunks)
 from deepspeed_tpu.runtime.activation_checkpointing import (
@@ -100,15 +102,6 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK = 64
 #: the side of the diagonal blocks inverted row by row
 _BASE = 16
-
-# rules by the lowering they took, counted when traced (``ops/ssd_scan.py``
-# keeps the same count of its scans)
-_LOWERINGS = {"pallas": 0, "xla": 0}
-
-
-def lowerings() -> dict:
-    return dict(_LOWERINGS)
-
 
 def _rows_in_turn(a: jax.Array) -> jax.Array:
     """``(I + a)^{-1}`` of strictly lower triangular ``a`` [..., n, n] by
@@ -782,7 +775,7 @@ def _rule_pallas_fwd(q, k, v, g, beta, unit, interpret):
 
 
 def _rule_pallas_bwd(unit, interpret, res, do):
-    _LOWERINGS["pallas"] += 1
+    lowerings.count("delta_scan", "pallas")   # the kernels' own backward
     return rule_bwd(*res, do, unit=unit, interpret=interpret)
 
 
@@ -839,7 +832,9 @@ def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
         if why:
             raise ValueError(f"the rule's kernels do not take {why}")
         lowering = "pallas"
-    _LOWERINGS[lowering] += 1
+    # a rule by the lowering it took (``ops/ssd_scan.py`` counts its scans
+    # the same way)
+    lowerings.count("delta_scan", lowering)
     if lowering == "pallas":
         return _rule_pallas(q, k, v, g, beta, unit, bool(interpret))
     if unit is not None:

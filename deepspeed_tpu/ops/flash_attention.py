@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+from deepspeed_tpu.ops import lowerings
 
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
@@ -235,18 +236,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float, causal: bool,
             lse_ref[0, 0] = lse[:, :1]
 
 
-# The tiles one head's forward grid takes by arm (``_fwd_kernel``: a boundary
-# crosses it; none does; dead) and whether its log-sum-exp leaves as rows, of
-# the newest trace of ``_fwd_pallas``; ``traces`` counts them, so a reader can
-# tell whether a program traced one (the step-program table does)
-_FWD_TILES = {"traces": 0, "tiles": None}
-
-
-def fwd_tiles() -> Tuple[int, Optional[dict]]:
-    tiles = _FWD_TILES["tiles"]
-    return _FWD_TILES["traces"], None if tiles is None else dict(tiles)
-
-
 def _fwd_tile_arms(T, S, block_q, block_k, causal, window, rel_offset) -> dict:
     q_start = np.arange(T // block_q)[:, None] * block_q + rel_offset
     k_start = np.arange(S // block_k)[None, :] * block_k
@@ -267,17 +256,6 @@ def _rows_legal(T: int, block_q: int) -> bool:
     else (a ``block_q`` of 8 for a T such as 3000) it leaves as the
     ``[B, H, T, 1]`` column and the split kernels run."""
     return block_q == T or block_q % 128 == 0
-
-
-# flash lowerings (a forward, a backward) by whether q and k arrived in parts,
-# the rope columns as operands of their own, counted when ``_fwd_pallas`` and
-# ``_bwd_pallas`` are traced (the step-program table reads the difference
-# around a step program's first call)
-_ROPE_LOWERINGS = {"operand": 0, "none": 0}
-
-
-def rope_operand_lowerings() -> dict:
-    return dict(_ROPE_LOWERINGS)
 
 
 def _widths(q, k, v) -> Tuple[int, int]:
@@ -311,10 +289,14 @@ def _fwd_pallas(q, k, v, *, scale, causal, window, block_q, block_k,
     grid = (B, H, nq, nk)
     rows = _rows_legal(T, block_q)
     rope = q_rope is not None
-    _ROPE_LOWERINGS["operand" if rope else "none"] += 1
-    _FWD_TILES["traces"] += 1
-    _FWD_TILES["tiles"] = dict(_fwd_tile_arms(
-        T, S, block_q, block_k, causal, window, rel_offset), rows=rows)
+    # a flash lowering, forward or backward, by whether q and k arrived in
+    # parts, the rope columns as operands of their own (latent attention)
+    lowerings.count("flash_rope_operand", "operand" if rope else "none")
+    # the tiles one head's forward grid takes by arm (``_fwd_kernel``: a
+    # boundary crosses it; none does; dead) and whether its log-sum-exp
+    # leaves as rows: the newest forward's, which is what a reader sees
+    lowerings.note("flash_fwd_tiles", dict(_fwd_tile_arms(
+        T, S, block_q, block_k, causal, window, rel_offset), rows=rows))
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                window=window, block_q=block_q, block_k=block_k,
                                rel_offset=rel_offset, rows=rows, rope=rope)
@@ -607,16 +589,6 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 # 1024-wide tiles) the two split kernels run under the default limit.
 _FUSED_VMEM_BUDGET = 48 * 1024 * 1024
 
-# flash-backward lowerings by the kernel they took, counted when
-# ``_bwd_pallas`` is traced (the step-program table reads the difference
-# around a step program's first call)
-_BWD_LOWERINGS = {"fused": 0, "split": 0}
-
-
-def bwd_lowerings() -> dict:
-    return dict(_BWD_LOWERINGS)
-
-
 def _fused_bwd_vmem_bytes(T: int, d: int, block_q: int, block_k: int,
                           itemsize: int, dv: Optional[int] = None,
                           dr: int = 0) -> int:
@@ -856,7 +828,7 @@ def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, window, block_q,
     rep = H // K
     rope = q_rope is not None
     dr = q_rope.shape[3] if rope else 0
-    _ROPE_LOWERINGS["operand" if rope else "none"] += 1
+    lowerings.count("flash_rope_operand", "operand" if rope else "none")
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [B,H,T]
     if dlse is not None:
@@ -868,7 +840,7 @@ def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, window, block_q,
     took = ("fused" if _bwd_takes_fused(T, d, block_q, block_k,
                                         q.dtype.itemsize, dv_w, dr)
             else "split")
-    _BWD_LOWERINGS[took] += 1
+    lowerings.count("flash_bwd", took)     # by the kernel it took
     call = _bwd_fused_call if took == "fused" else _bwd_split_call
     # the fused kernel reads rows, the split pair columns; the forward's lse
     # is rows wherever the fused kernel runs (``_rows_legal``), so this reshape

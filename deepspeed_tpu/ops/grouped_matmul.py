@@ -41,6 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+from deepspeed_tpu.ops import lowerings
 
 # A weight tile of [2304, 896] bf16, a row tile and an f32 result, each
 # pipelined tile twice, are about 15 MB: over Mosaic's default 16 MiB scope
@@ -50,17 +51,6 @@ from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
 _VMEM_LIMIT = 48 * 1024 * 1024
 _VMEM_BUDGET = 36 * 1024 * 1024
 _ROW_TILES = (512, 256, 128)
-
-# grouped products by the lowering they took, counted when traced: one for a
-# product (``grouped_matmul``), two more for its transposes when its backward
-# is traced (the step-program table reads the difference around a step
-# program's first call, as it does ``flash_attention.bwd_lowerings``)
-_LOWERINGS = {"pallas": 0, "xla": 0}
-
-
-def lowerings() -> dict:
-    return dict(_LOWERINGS)
-
 
 # ---------------------------------------------------------------------------
 # which lowering, which tiles: from the call's own facts
@@ -384,7 +374,7 @@ def _products_fwd(xs, ws, group_sizes, interpret):
 
 def _products_bwd(interpret, res, dys):
     xs, ws, group_sizes = res
-    _LOWERINGS["pallas"] += 2 * len(ws)
+    lowerings.count("moe_grouped", "pallas", 2 * len(ws))  # the transposes
     # the rows' cotangent over all the stacks in one call, summed in f32
     dxs = gmm(tuple(dys), tuple(ws), group_sizes, transpose_w=True,
               interpret=interpret)
@@ -404,7 +394,9 @@ def grouped_matmul(xs: jax.Array, w, group_sizes: jax.Array, *,
     rows (their backward then forms the rows' cotangent in one kernel
     instead of one each and an add)."""
     ws = w if isinstance(w, tuple) else (w,)
-    _LOWERINGS[lowering] += len(ws)
+    # a grouped product by the lowering it took: one for a product, two more
+    # for its transposes when the kernels' backward is traced
+    lowerings.count("moe_grouped", lowering, len(ws))
     if lowering == "xla":
         outs = tuple(jax.lax.ragged_dot(xs, v, group_sizes) for v in ws)
     else:

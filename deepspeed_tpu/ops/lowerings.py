@@ -1,0 +1,48 @@
+"""What the pickers chose while a program was traced: one registry.
+
+A picker that has more than one lowering for an op (a Pallas kernel or its
+``jax.numpy`` twin, a fused backward or a split one) says which it took with
+:func:`count`, when traced; :func:`note` keeps the one record that is no count.
+Whoever traces a program (the engine, round a step program's first call; a
+test) takes a :func:`snapshot` before and reads :func:`since` after, and never
+learns a picker's name. The counts are the proof that a program ran the kernel
+its roofline claims; nothing here is touched in a timed step.
+"""
+
+from typing import Any, Dict, Tuple
+
+_COUNTS: Dict[Tuple[str, str], int] = {}
+_NOTES: Dict[str, Tuple[int, Any]] = {}     # site -> (when, value)
+_clock = [0]                                # notes written so far
+
+Snapshot = Tuple[Dict[Tuple[str, str], int], int]
+
+
+def count(site: str, answer: str, n: int = 1) -> None:
+    """``site`` lowered ``n`` more of its ops as ``answer``."""
+    _COUNTS[site, answer] = _COUNTS.get((site, answer), 0) + n
+
+
+def note(site: str, value: Any) -> None:
+    """``site``'s newest record (the older one is forgotten)."""
+    _clock[0] += 1
+    _NOTES[site] = (_clock[0], value)
+
+
+def snapshot() -> Snapshot:
+    return dict(_COUNTS), _clock[0]
+
+
+def since(snap: Snapshot) -> Dict[str, Any]:
+    """``{site: {answer: n}}`` of what counted after ``snap``, and ``{site:
+    value}`` of the notes written after it: a site that counted nothing is
+    absent, an answer that counted nothing is left out."""
+    counts, clock = snap
+    out: Dict[str, Any] = {}
+    for (site, answer), n in _COUNTS.items():
+        added = n - counts.get((site, answer), 0)
+        if added:
+            out.setdefault(site, {})[answer] = added
+    out.update({site: value for site, (when, value) in _NOTES.items()
+                if when > clock})
+    return out

@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
+from deepspeed_tpu.ops import lowerings
 
 F32 = jnp.float32
 # the decay tile is built in pieces of this side; a lane block of ``x``
@@ -74,16 +75,6 @@ _VMEM_LIMIT = 48 * 1024 * 1024
 # convolution output): XLA may fuse the slice into the operand's read
 # instead of copying 33.5 MB a layer through HBM first
 _FUSE_FWD = [True, False, False, True, True, False]
-
-# scans by the lowering they took, counted when traced: one for a scan, one
-# more for a Pallas scan's backward (the einsum form's is autodiff's); the
-# step-program table reads the difference around a step program's first call
-_LOWERINGS = {"pallas": 0, "xla": 0}
-
-
-def lowerings() -> dict:
-    return dict(_LOWERINGS)
-
 
 # ---------------------------------------------------------------------------
 # which lowering: from the call's own facts
@@ -630,7 +621,7 @@ def _scan_pallas_fwd(x, dt, A, B, C, D, chunk, interpret):
 
 
 def _scan_pallas_bwd(chunk, interpret, res, dy):
-    _LOWERINGS["pallas"] += 1
+    lowerings.count("ssm_scan", "pallas")     # the kernels' own backward
     return ssd_bwd(*res, dy, chunk=chunk, interpret=interpret)
 
 
@@ -657,7 +648,9 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         if why:
             raise ValueError(f"the scan's kernels do not take {why}")
         lowering = "pallas"
-    _LOWERINGS[lowering] += 1
+    # a scan by the lowering it took: one for a scan, one more for a Pallas
+    # scan's backward (the einsum form's is autodiff's)
+    lowerings.count("ssm_scan", lowering)
     if lowering == "xla":
         return scan_einsum(x, dt, A, B, C, D, chunk)
     return _scan_pallas(x, dt, A, B, C, D, int(chunk), bool(interpret))

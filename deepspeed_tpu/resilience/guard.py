@@ -21,6 +21,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -32,6 +33,13 @@ __all__ = ["StepGuard", "TooManyBadSteps"]
 
 class TooManyBadSteps(RuntimeError):
     """Raised when consecutive NaN/Inf steps exhaust the healing budget."""
+
+
+# the gradients' norm as one program over the tree: one dispatch where the
+# eager form made several a leaf, each a little SPMD program of its own over
+# the mesh's devices (on a host that runs other eight-device processes beside
+# it, one of those dozens of dispatches now and then aborted the process)
+_global_norm = jax.jit(optax.global_norm)
 
 
 def _finite(x) -> bool:
@@ -69,17 +77,18 @@ class StepGuard:
         """Run before the optimizer update. Returns True when the step was
         skipped (caller must not apply the update).
 
-        Cost: one global_norm dispatch + a host sync per step — unavoidable,
-        since the skip decision must land BEFORE the (donating) update runs;
-        it is the same sync the fp16 overflow path already pays. Enabled
-        only under ``resilience.enabled``; the fused path stays sync-free."""
+        Cost: one jitted global_norm dispatch + a host sync per step —
+        unavoidable, since the skip decision must land BEFORE the (donating)
+        update runs; it is the same sync the fp16 overflow path already pays.
+        Enabled only under ``resilience.enabled``; the fused path stays
+        sync-free."""
         eng = self.engine
         self.pre_step()
         inj = get_injector()
         if inj:
             eng._grad_acc = inj.maybe_poison_grads(eng.global_steps,
                                                    eng._grad_acc)
-        gnorm = optax.global_norm(eng._grad_acc)
+        gnorm = _global_norm(eng._grad_acc)
         loss_ok = eng._last_loss is None or _finite(eng._last_loss)
         if _finite(gnorm) and loss_ok:
             self.consecutive_bad = 0

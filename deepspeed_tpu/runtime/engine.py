@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import sys
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -40,8 +39,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deepspeed_tpu.config import DeepSpeedTpuConfig
 from deepspeed_tpu.models.spec import num_params
 from deepspeed_tpu.observability import steplog
-from deepspeed_tpu.ops.flash_attention import (bwd_lowerings, fwd_tiles,
-                                               rope_operand_lowerings)
+from deepspeed_tpu.ops import lowerings
 from deepspeed_tpu.parallel import Topology, build_mesh
 from deepspeed_tpu.parallel import sharding as shd
 from deepspeed_tpu.runtime.dataloader import DeepSpeedTpuDataLoader
@@ -49,58 +47,6 @@ from deepspeed_tpu.runtime.lr_schedules import LRSchedulerShim, build_schedule
 from deepspeed_tpu.runtime.optimizers import build_optimizer
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import ThroughputTimer
-
-
-def _traced_counts(module: str, fn: str = "lowerings"
-                   ) -> Optional[Dict[str, int]]:
-    """What ``module`` has counted, when traced, of the lowerings its layer
-    took; None while nothing has loaded it (the engine does not: a model
-    without the layer imports what it always did)."""
-    mod = sys.modules.get(module)
-    return None if mod is None else getattr(mod, fn)()
-
-
-def _grouped_lowerings() -> Optional[Dict[str, int]]:
-    """The experts' grouped products (``ops/grouped_matmul.py``)."""
-    return _traced_counts("deepspeed_tpu.ops.grouped_matmul")
-
-
-def _dispatch_lowerings() -> Optional[Dict[str, int]]:
-    """The expert layers' dispatches and combines (``moe/sharded_moe.py``)."""
-    return _traced_counts("deepspeed_tpu.moe.sharded_moe",
-                          "dispatch_lowerings")
-
-
-def _scan_lowerings() -> Optional[Dict[str, int]]:
-    """The state-space layers' scans (``ops/ssd_scan.py``)."""
-    return _traced_counts("deepspeed_tpu.ops.ssd_scan")
-
-
-def _conv_lowerings() -> Optional[Dict[str, int]]:
-    """The state-space and delta mixers' convolutions
-    (``ops/causal_conv.py``)."""
-    return _traced_counts("deepspeed_tpu.ops.causal_conv", "conv_lowerings")
-
-
-def _delta_lowerings() -> Optional[Dict[str, int]]:
-    """The delta layers' chunked rules (``ops/delta_rule.py``)."""
-    return _traced_counts("deepspeed_tpu.ops.delta_rule")
-
-
-def _counted(before: Optional[Dict[str, int]],
-             after: Optional[Dict[str, int]]) -> Optional[Dict[str, int]]:
-    """What a trace added to such counts; None where it added nothing."""
-    if after == before:
-        return None
-    return {kind: n - (before or {}).get(kind, 0)
-            for kind, n in after.items()}
-
-
-def _kinds_counted(before, after) -> Optional[Dict[str, int]]:
-    """:func:`_counted` without the kinds that counted nothing: a program
-    traced off the chip reads ``{"xla": n}`` as it did before the kernels."""
-    counted = _counted(before, after)
-    return counted and {kind: n for kind, n in counted.items() if n}
 
 
 def _leaf(tree, path):
@@ -1072,10 +1018,15 @@ class DeepSpeedTpuEngine:
         it in the step-program table under its function's name, which is also
         what the device trace's module line says ran (``jit_ds_train_step``)."""
         self._fused_step_cache[key] = jitted
-        facts = getattr(self.module, "step_program_facts", None)
         self._uncaptured[key] = steplog.record_program(
-            jitted.__name__, key, jitted, self.mesh,
-            **(facts() if facts is not None else {}))
+            jitted.__name__, key, jitted, self.mesh, **self._program_facts())
+
+    def _program_facts(self, batch_shape=None) -> Dict[str, Any]:
+        """What the model says of a step program of its own (over a batch of
+        ``batch_shape``, once the first call has shown one); nothing where it
+        does not say."""
+        facts = getattr(self.module, "step_program_facts", None)
+        return {} if facts is None else facts(batch_shape)
 
     def _dispatch_fused(self, key, *args):
         """Call the jitted step program of ``key`` (the ``ds.train.dispatch``
@@ -1105,13 +1056,7 @@ class DeepSpeedTpuEngine:
         first = row.first_call_s is None
         if first:
             row.capture(args)
-            before, fwd_before = bwd_lowerings(), fwd_tiles()[0]
-            rope_before = rope_operand_lowerings()
-            grouped_before = _grouped_lowerings()
-            dispatch_before = _dispatch_lowerings()
-            scan_before = _scan_lowerings()
-            conv_before = _conv_lowerings()
-            delta_before = _delta_lowerings()
+            traced = lowerings.snapshot()
         t0 = time.perf_counter()
         with steplog.span(self._ebus, "train", "dispatch"), \
                 jax.sharding.set_mesh(self.mesh):
@@ -1127,30 +1072,12 @@ class DeepSpeedTpuEngine:
             return
         row.first_call_s = self._t_dispatched - t0
         self._uncaptured[key] = row
+        row.counted = lowerings.since(traced)
         batch_shape = next((a["input_ids"].shape for a in args
                             if isinstance(a, dict) and "input_ids" in a),
                            None)
-        if row.ssm_chunk is not None and batch_shape is not None:
-            row.ssm_chunks_per_step = self.module.ssm_chunks_scanned(
-                batch_shape)
-        if row.delta_chunk is not None and batch_shape is not None:
-            row.delta_chunks_per_step = self.module.delta_chunks_scanned(
-                batch_shape)
-        row.flash_bwd_lowerings = {
-            kind: n - before[kind] for kind, n in bwd_lowerings().items()}
-        row.flash_rope_operand_lowerings = {
-            kind: n - rope_before[kind]
-            for kind, n in rope_operand_lowerings().items()}
-        traces, tiles = fwd_tiles()
-        row.flash_fwd_tiles = tiles if traces > fwd_before else None
-        row.moe_grouped_lowerings = _counted(grouped_before,
-                                             _grouped_lowerings())
-        row.moe_dispatch_lowerings = _counted(dispatch_before,
-                                              _dispatch_lowerings())
-        row.ssm_scan_lowerings = _counted(scan_before, _scan_lowerings())
-        row.conv_lowerings = _kinds_counted(conv_before, _conv_lowerings())
-        row.delta_scan_lowerings = _kinds_counted(delta_before,
-                                                  _delta_lowerings())
+        if batch_shape is not None:     # what the batch's shape adds
+            row.facts.update(self._program_facts(batch_shape))
 
     def _rule_moves_only_here(self, what: str) -> None:
         """Raise on a step path that does not carry the model's rule-moved

@@ -179,7 +179,10 @@ class TestProfiler:
 class TestAutotuner:
     def test_grid_sweeps_all_axes(self, eight_devices):
         """The tuner enumerates micro-batch x stage x remat x offload (the
-        reference tuner's full axis set) and returns the fastest OK trial."""
+        reference tuner's full axis set) and returns the fastest OK trial.
+        The smallest grid that shows it (one micro-batch, six engines where
+        two made twelve): offload only from stage 1 up, both recomputation
+        values at either stage."""
         from deepspeed_tpu.autotuning import Autotuner
         from deepspeed_tpu.models import TransformerLM, TransformerConfig
 
@@ -192,7 +195,7 @@ class TestAutotuner:
             factory,
             {"optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
              "mesh": {"dp": 8}, "steps_per_print": 1000},
-            micro_batch_candidates=(1, 2),
+            micro_batch_candidates=(1,),
             zero_stage_candidates=(0, 1),
             remat_candidates=("none", "full"),
             offload_candidates=(None, "cpu"),
@@ -208,6 +211,7 @@ class TestAutotuner:
         assert all(off is None or stage >= 1
                    for (_, stage, _, off) in axes)
         assert {r.config["remat"] for r in tuner.results} == {"none", "full"}
+        assert len(axes) == len(tuner.results) == 2 + 4     # stage 0, stage 1
 
 
 class TestAIOBench:

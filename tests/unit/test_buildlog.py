@@ -15,6 +15,10 @@ from deepspeed_tpu.observability import steplog
 from deepspeed_tpu.observability.events import EventBus
 from deepspeed_tpu.parallel import build_mesh
 
+# these cases count compiles, cache misses and build seconds: the run's
+# persistent compile cache (tests/conftest.py) stays off around them
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
+
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE = "/jax/core/compile/backend_compile_duration"

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deepspeed_tpu.ops import lowerings
+
 # llama3-1b (train phase) and llama3-8b (serve phase) widths
 H, K = 32, 8
 POOL = dict(layers=16, blocks=513, bs=128)
@@ -449,12 +451,10 @@ def test_flash_at_a_key_and_a_value_width_compiles_for_v5e(one_chip, name):
                               sharding=one_chip)
     v = jax.ShapeDtypeStruct((rows, T, heads, 128), jnp.bfloat16,
                              sharding=one_chip)
-    before = fa.bwd_lowerings()
+    before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile().as_text()
-    after = fa.bwd_lowerings()
-    assert {n: after[n] - before[n] for n in after} == {
-        "fused": int(took == "fused"), "split": int(took == "split")}
+    assert lowerings.since(before)["flash_bwd"] == {took: 1}
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
              and " custom-call(" in ln]
     # the forward writes values' width; the fused backward dq by q-tile at
@@ -487,13 +487,11 @@ def test_flash_with_the_rope_columns_as_operands_compiles_for_v5e(one_chip,
                                fa.DEFAULT_BLOCK_K, 2, 128, 64) \
         == (took == "fused")
     fn, args = _in_parts(rows, T, heads, kv_whole, one_chip)
-    before, parts_before = fa.bwd_lowerings(), fa.rope_operand_lowerings()
+    before = lowerings.snapshot()
     text = jax.jit(fn).lower(*args).compile().as_text()
-    after, parts_after = fa.bwd_lowerings(), fa.rope_operand_lowerings()
-    assert {n: after[n] - before[n] for n in after} == {
-        "fused": int(took == "fused"), "split": int(took == "split")}
-    assert parts_after["none"] == parts_before["none"]
-    assert parts_after["operand"] > parts_before["operand"]
+    said = lowerings.since(before)
+    assert said["flash_bwd"] == {took: 1}
+    assert list(said["flash_rope_operand"]) == ["operand"]
     calls = [ln.strip() for ln in text.splitlines()
              if "tpu_custom_call" in ln and " custom-call(" in ln]
     forward, backward = _mla_roofline_patterns()
@@ -560,11 +558,9 @@ def test_a_dense_models_flash_calls_are_the_parents(one_chip, monkeypatch):
         vocab_size=512, hidden_size=256, num_layers=2, num_heads=2,
         num_kv_heads=1, intermediate_size=512, max_seq_len=1024,
         dtype="bfloat16", attention_impl="flash", remat_policy="full"))
-    before = fa.rope_operand_lowerings()
+    before = lowerings.snapshot()
     text = _gradient_program(model, one_chip)
-    after = fa.rope_operand_lowerings()
-    assert after["operand"] == before["operand"]
-    assert after["none"] > before["none"]
+    assert list(lowerings.since(before)["flash_rope_operand"]) == ["none"]
     calls = []
     for line in text.splitlines():
         if "tpu_custom_call" not in line or " custom-call(" not in line:
@@ -598,11 +594,9 @@ def test_a_latent_attention_models_calls_keep_their_names(one_chip,
         attention_impl="flash", remat_policy="full", tie_embeddings=False,
         kv_lora_rank=64, qk_nope_head_dim=128, qk_rope_head_dim=64,
         v_head_dim=128, rope_interleave=True))
-    before = fa.rope_operand_lowerings()
+    before = lowerings.snapshot()
     text = _gradient_program(model, one_chip)
-    after = fa.rope_operand_lowerings()
-    assert after["none"] == before["none"]
-    assert after["operand"] - before["operand"] == 3
+    assert lowerings.since(before)["flash_rope_operand"] == {"operand": 3}
     calls = [ln.strip() for ln in text.splitlines()
              if "tpu_custom_call" in ln and " custom-call(" in ln]
     forward, backward = _mla_roofline_patterns()
@@ -638,12 +632,11 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((1, T, kv_heads, 128), jnp.bfloat16,
                               sharding=one_chip)
-    before = fa.bwd_lowerings()
+    before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
-    after = fa.bwd_lowerings()
-    assert {n: after[n] - before[n] for n in after} == {
-        "fused": int(took == "fused"), "split": int(took == "split")}
+    said = lowerings.since(before)
+    assert said["flash_bwd"] == {took: 1}
     # the fused kernel is one Mosaic call with two five-dimensional bf16
     # results (dq by q-tile; dk and dv stacked): what the benchmark's
     # metrics/flash_bwd_fused_roofline.json looks for. The split pair's
@@ -658,7 +651,7 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     fwd = re.findall(r"= \(bf16\[[\d,]*\]\{[^}]*\}, (f32\[[\d,]*\])\{[^}]*\}\) "
                      r"custom-call\(.*tpu_custom_call", text)
     assert fwd == [f"f32[1,{heads},1,{T}]"], (name, fwd)
-    tiles = fa.fwd_tiles()[1]
+    tiles = said["flash_fwd_tiles"]
     assert tiles["rows"]
     if T == 4096:       # the cells: 4 x 4 tiles of 1024 a head, window inert
         assert tiles == {"masked": 4, "unmasked": 6, "dead": 6, "rows": True}
@@ -685,19 +678,19 @@ def test_the_gated_ffns_gradient_compiles_for_v5e(one_chip, width, took):
 
     w = {"w_gate": arg((E, width, F)), "w_up": arg((E, width, F)),
          "w_down": arg((E, F, width))}
-    before = gm.lowerings()
+    before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         arg((rows, width), jnp.bfloat16), w, arg((E,), jnp.int32)
     ).compile().as_text()
-    said = {k: n - before[k] for k, n in gm.lowerings().items()}
+    said = lowerings.since(before)["moe_grouped"]
     calls = text.count("custom_call_target=\"tpu_custom_call\"")
     if took == "pallas":
         # nine products in eight calls: the gate's and the up projection's
         # cotangents of the rows are one gmm over both stacks
-        assert said == {"pallas": 9, "xla": 0} and calls == 8
+        assert said == {"pallas": 9} and calls == 8
         assert "ragged-dot" not in text
     else:
-        assert said == {"pallas": 0, "xla": 3} and "ragged-dot" in text
+        assert said == {"xla": 3} and "ragged-dot" in text
 
 
 def _moe_rows_case(name):
@@ -783,11 +776,10 @@ def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
 
     w = {"router": arg((D, E)), "w_gate": arg((held, D, F)),
          "w_up": arg((held, D, F)), "w_down": arg((held, F, D))}
-    before = sm.dispatch_lowerings()
+    before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         arg((2, S // 2, D), jnp.bfloat16), w).compile().as_text()
-    said = {n: v - before[n] for n, v in sm.dispatch_lowerings().items()}
-    assert said == {"pallas": 4, "xla": 0}
+    assert lowerings.since(before)["moe_dispatch"] == {"pallas": 4}
     by = {name: len(re.findall(r"custom-call\(.*tpu_custom_call.*"
                                rf"jit\({name}\)/", text))
           for name in ("pack_rows", "rows_of_tokens", "sum_of_rows", "gmm",

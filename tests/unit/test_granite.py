@@ -15,12 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(__file__))
-import granite_reference as ref  # noqa: E402
-from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
-from deepspeed_tpu.models import transformer as tf  # noqa: E402
-from deepspeed_tpu.ops.causal_conv import causal_conv  # noqa: E402
-from deepspeed_tpu.ops.ssd_scan import ssd_scan  # noqa: E402
+from benchmarks import reference_granite4h as ref
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models import transformer as tf
+from deepspeed_tpu.ops.causal_conv import causal_conv
+from deepspeed_tpu.ops.ssd_scan import ssd_scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -118,20 +117,32 @@ def _recurrence(x, dt, A, B, C, D):
                       for i in range(x.shape[0])])
 
 
+@pytest.fixture(scope="module")
+def scan_recurrence():
+    """The inputs, and the recurrence's output and gradients of every input
+    position by position: the same for every chunk length, so computed once
+    (as one program each, not op by op)."""
+    args = _scan_inputs()
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(_recurrence)(*args)
+        g_want = jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(_recurrence(*a))),
+            argnums=range(6)))(*args)
+    return args, want, g_want
+
+
 @pytest.mark.parametrize("chunk", [8, 16, 40, 64, 7])
-def test_the_chunked_scan_is_the_recurrence(chunk):
+def test_the_chunked_scan_is_the_recurrence(chunk, scan_recurrence):
     """Chunks that divide T (8), that do not (16, 7: the tail is padded), one
     chunk (40) and one chunk longer than T (64): forward and the gradient of
     every input."""
-    args = _scan_inputs()
+    args, want, g_want = scan_recurrence
     with jax.default_matmul_precision("highest"):
-        want = _recurrence(*args)
-        got = ssd_scan(*args, chunk)
+        got = jax.jit(lambda *a: ssd_scan(*a, chunk))(*args)
         np.testing.assert_allclose(got, want, atol=2e-4)
-        g_want = jax.grad(lambda *a: jnp.sum(jnp.sin(_recurrence(*a))),
-                          argnums=range(6))(*args)
-        g_got = jax.grad(lambda *a: jnp.sum(jnp.sin(ssd_scan(*a, chunk))),
-                         argnums=range(6))(*args)
+        g_got = jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(ssd_scan(*a, chunk))),
+            argnums=range(6)))(*args)
     for name, a, b in zip("x dt A B C D".split(), g_got, g_want):
         assert np.isfinite(np.asarray(a)).all(), name
         np.testing.assert_allclose(a, b, atol=1e-4 * float(jnp.abs(b).max()),
@@ -214,13 +225,16 @@ def test_runs_of_one_kind_and_a_period_body_give_the_same_numbers(
     assert model._layer_plan() == [(0, 5, ("ssm",)), (5, 6, ("full",)),
                                    (6, 10, ("ssm",))]
     params = init(model, seed=2)
-    cut = model.logits(params, ROWS)
+    # (each a program of its own, traced under the plan of its moment)
+    cut = jax.jit(lambda p: model.logits(p, ROWS))(params)
     monkeypatch.setattr(tf, "_MAX_PERIOD", 10)
     assert len(model._layer_plan()) == 1
-    np.testing.assert_allclose(model.logits(params, ROWS), cut, atol=2e-5)
+    np.testing.assert_allclose(
+        jax.jit(lambda p: model.logits(p, ROWS))(params), cut, atol=2e-5)
     want = ref.batch_loss(hf, getter(params, hf), ROWS)
-    np.testing.assert_allclose(model.loss_fn(params, {"input_ids": ROWS}),
-                               want["loss"], atol=2e-5)
+    np.testing.assert_allclose(
+        jax.jit(lambda p: model.loss_fn(p, {"input_ids": ROWS}))(params),
+        want["loss"], atol=2e-5)
 
 
 # ---- stacks by kind -------------------------------------------------------
@@ -336,67 +350,87 @@ def test_the_step_record_carries_the_mixer_outputs():
     assert prog.ssm_chunks_per_step == 3 * 2 * 3     # layers x rows x 24 / 8
     # float32 heads of 16 on a CPU: every scan the trace holds is the einsum
     # form (its backward is autodiff's and is not counted)
-    assert prog.ssm_scan_lowerings == {"pallas": 0, "xla": 3}
+    assert prog.ssm_scan_lowerings == {"xla": 3}
     assert prog.conv_lowerings == {"xla": 3}
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
     assert "ssm_chunk" not in dense.step_program_facts()
-    assert dense.ssm_chunks_scanned((2, 24)) is None
+    assert "ssm_chunks_per_step" not in dense.step_program_facts((2, 24))
+
+
+_SMALL_CELL = {}
+
+
+def _small_cell():
+    """What the benchmark's cell is in small: bf16, heads of 64, a state of
+    128, chunks of 128, recomputation, one state-space layer and one
+    attention layer (a second and a third state-space layer run what the
+    first runs), rows of 144 positions: two chunks, the second padded, and
+    whole sublane tiles for the convolution's kernels. Returns (a fresh
+    engine's builder, the rows, the loss of the step as the pickers choose
+    on a CPU, and what that program's row counted), the last two computed
+    once for the cases that compare with them."""
+    hf = hf_config(L=2, types=["mamba", "attention"], D=128, mamba_n_heads=4,
+                   mamba_d_head=64, mamba_d_state=128, mamba_n_groups=2,
+                   mamba_chunk_size=128)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 144)).astype(np.int32)
+
+    def build():
+        return _engine(model_for(hf, remat_policy="full", dtype="bfloat16",
+                                 max_seq_len=256))
+
+    if not _SMALL_CELL:
+        from deepspeed_tpu.observability import steplog
+
+        _SMALL_CELL["loss"] = float(build().fused_train_step(
+            {"input_ids": rows}))
+        _SMALL_CELL["counted"] = dict(steplog.programs()[-1].counted)
+    return build, rows, _SMALL_CELL["loss"], _SMALL_CELL["counted"]
 
 
 def test_the_cell_shaped_step_program_takes_the_scan_kernels(monkeypatch):
-    """What the benchmark's cell is in small: bf16, heads of 64, a state of
-    128, chunks of whole tiles, recomputation. Every scan of the program is
-    the Pallas kernels (interpreted here: the CPU stands in for the chip),
-    the backward too, and the step gives the einsum form's loss."""
+    """The small cell (:func:`_small_cell`): every scan of the program is the
+    Pallas kernels (interpreted here: the CPU stands in for the chip), the
+    backward too, and the step gives the einsum form's loss. A kernel that
+    mishandled the padded second chunk or the state carried into it would
+    move the loss."""
     import functools
 
     from deepspeed_tpu.models import mamba
     from deepspeed_tpu.observability import steplog
 
-    hf = hf_config(D=128, mamba_n_heads=4, mamba_d_head=64,
-                   mamba_d_state=128, mamba_n_groups=2, mamba_chunk_size=128)
-    rows = np.random.default_rng(2).integers(0, 96, (2, 200)).astype(np.int32)
-    kw = dict(remat_policy="full", dtype="bfloat16", max_seq_len=256)
-    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    assert steplog.programs()[-1].ssm_scan_lowerings == {"pallas": 0,
-                                                         "xla": 3}
+    build, rows, plain, counted = _small_cell()
+    assert counted["ssm_scan"] == {"xla": 1}
     monkeypatch.setattr(mamba, "ssd_scan", functools.partial(
         mamba.ssd_scan, interpret=True))
-    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
+    loss = float(build().fused_train_step({"input_ids": rows}))
     prog = steplog.programs()[-1]
-    # the period's three scans and the backward of each
-    assert prog.ssm_scan_lowerings == {"pallas": 6, "xla": 0}
-    assert prog.ssm_chunks_per_step == 3 * 2 * 2     # 200 tokens: two chunks
+    # the scan and its backward
+    assert prog.ssm_scan_lowerings == {"pallas": 2}
+    assert prog.ssm_chunks_per_step == 1 * 2 * 2     # 144 tokens: two chunks
     np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 
 def test_the_cell_shaped_step_program_takes_the_conv_kernels(monkeypatch):
     """The same small cell with the convolution as its Pallas kernels
-    (interpreted here), rows of whole sublane tiles: every convolution of
-    the program and its backward is the kernels', and the step gives the
-    ``jax.numpy`` form's loss."""
+    (interpreted here): every convolution of the program and its backward is
+    the kernels', and the step gives the ``jax.numpy`` form's loss."""
     import functools
 
     from deepspeed_tpu.models import mamba
     from deepspeed_tpu.observability import steplog
 
-    hf = hf_config(D=128, mamba_n_heads=4, mamba_d_head=64,
-                   mamba_d_state=128, mamba_n_groups=2, mamba_chunk_size=128)
-    rows = np.random.default_rng(2).integers(0, 96, (2, 192)).astype(np.int32)
-    kw = dict(remat_policy="full", dtype="bfloat16", max_seq_len=256)
-    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    assert steplog.programs()[-1].conv_lowerings == {"xla": 3}
+    build, rows, plain, counted = _small_cell()
+    assert counted["conv"] == {"xla": 1}
     monkeypatch.setattr(mamba, "causal_conv_silu", functools.partial(
         mamba.causal_conv_silu, interpret=True))
-    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    # the period's three convolutions and the backward of each
-    assert steplog.programs()[-1].conv_lowerings == {"pallas": 6}
+    loss = float(build().fused_train_step({"input_ids": rows}))
+    # the convolution and its backward
+    assert steplog.programs()[-1].conv_lowerings == {"pallas": 2}
     np.testing.assert_allclose(loss, plain, atol=2e-3)
+
+
+_ONE_DEVICE_LOSS = []
 
 
 @pytest.mark.parametrize("stage", [0, 1, 2, 3])
@@ -404,7 +438,10 @@ def test_zero_stages_shard_the_new_leaves_and_give_the_same_loss(stage):
     hf = hf_config(D=64)
     model = model_for(hf)
     rows = np.random.default_rng(4).integers(0, 96, (8, 24)).astype(np.int32)
-    want = float(_engine(model, rows=8).fused_train_step({"input_ids": rows}))
+    if not _ONE_DEVICE_LOSS:    # the same whatever the stage
+        _ONE_DEVICE_LOSS.append(float(_engine(model, rows=8).fused_train_step(
+            {"input_ids": rows})))
+    want, = _ONE_DEVICE_LOSS
     eng = _engine(model, stage=stage, rows=8, fsdp=8)
     got = float(eng.fused_train_step({"input_ids": rows}))
     assert got == pytest.approx(want, abs=2e-5)

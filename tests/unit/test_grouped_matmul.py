@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.moe import sharded_moe as sm
-from deepspeed_tpu.ops import grouped_matmul as gm
+from deepspeed_tpu.ops import grouped_matmul as gm, lowerings
 
 # (rows, row tile, group sizes): what the groups hold may end before the rows
 LAYOUTS = {
@@ -148,13 +148,12 @@ def test_the_ffns_gradient_is_the_ragged_dot_paths(gated):
         ys = jnp.where(keep, ys, 0).astype(jnp.float32)
         return (ys * jnp.cos(jnp.arange(D, dtype=jnp.float32))).sum()
 
-    before = gm.lowerings()
+    before = lowerings.snapshot()
     got = jax.value_and_grad(loss, argnums=(0, 1))(xs, w, True)
-    took = {k: n - before[k] for k, n in gm.lowerings().items()}
     products = 3 if gated else 2
-    assert took == {"pallas": 3 * products, "xla": 0}
+    assert lowerings.since(before)["moe_grouped"] == {"pallas": 3 * products}
     want = jax.value_and_grad(loss, argnums=(0, 1))(xs, w, None)
-    assert gm.lowerings()["xla"] - before["xla"] == products
+    assert lowerings.since(before)["moe_grouped"]["xla"] == products
     np.testing.assert_allclose(got[0], want[0], rtol=2e-3)
     np.testing.assert_allclose(f32(got[1][0])[:691], f32(want[1][0])[:691],
                                atol=2e-2, rtol=2e-2)
@@ -204,9 +203,9 @@ def test_a_row_past_the_groups_never_reaches_the_output_or_the_gradients(
 
         return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(h, w)
 
-    before = gm.lowerings()
+    before = lowerings.snapshot()
     (_, (out, parts)), grads = run(poison=True)
-    assert gm.lowerings()["pallas"] - before["pallas"] == 9
+    assert lowerings.since(before)["moe_grouped"] == {"pallas": 9}
     assert int(parts["pairs_dropped"]) == 0
     assert 0 < int(seen["held"]) < seen["rows"] == 512
     (_, (clean, _)), clean_grads = run(poison=False)
@@ -262,14 +261,13 @@ def test_int8_stacks_and_a_decode_step_take_ragged_dot():
         q[name + "_s"] = scale.astype(jnp.bfloat16)
     sizes = jnp.asarray([100, 200, 112, 100], jnp.int32)
     xs = jax.random.normal(jax.random.key(0), (512, D), jnp.bfloat16)
-    for stacks_, rows, want in ((q, xs, {"pallas": 0, "xla": 3}),
-                                (w, xs[:8], {"pallas": 0, "xla": 3}),
-                                (w, xs, {"pallas": 3, "xla": 0})):
-        before = gm.lowerings()
+    for stacks_, rows, want in ((q, xs, {"xla": 3}),
+                                (w, xs[:8], {"xla": 3}),
+                                (w, xs, {"pallas": 3})):
+        before = lowerings.snapshot()
         sm._grouped_ffn(rows, jnp.minimum(sizes, rows.shape[0] // 4), stacks_,
                         jnp.bfloat16, "ragged", interpret=True)
-        assert {k: n - before[k]
-                for k, n in gm.lowerings().items()} == want
+        assert lowerings.since(before)["moe_grouped"] == want
 
 
 def test_tiles_come_from_the_shapes_and_fit_the_budget():

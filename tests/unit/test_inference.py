@@ -353,62 +353,51 @@ def test_decode_batch_matches_sequential_puts(tiny_lm):
         assert seq_toks[u] == fused_toks[u], (u, seq_toks[u], fused_toks[u])
 
 
-def test_int8_kv_cache_matches_bf16(tiny_lm):
-    """The int8 paged pool (per-token dequant scales) must track the
-    full-precision engine through prefill, mixed continuation and the fused
-    decode loop — within quantization tolerance."""
-    model, params = tiny_lm
+_BF16_OUTS = {}
+
+
+def _kv_cache_outs(model, params, mode):
+    """(engine, the logits of a whole prefill, a decode step and a mixed
+    continuation) under a pool of ``mode``; the bf16 engine's are kept for
+    the next case that compares with them (one build for both)."""
+    if mode in _BF16_OUTS:
+        return _BF16_OUTS[mode]
     rng = np.random.default_rng(12)
     prompts = [rng.integers(0, 256, n) for n in (21, 9)]
     cont = rng.integers(0, 256, 5)
-    engs = {}
-    outs = {}
-    for mode in ("bf16", "int8"):
-        eng = InferenceEngineV2(model, params=params, max_sequences=4,
-                                max_seq_len=64, block_size=8, kv_dtype=mode)
-        outs[mode] = [eng.put([1, 2], prompts)]          # whole prefill
-        outs[mode].append(eng.put([1, 2], [np.array([3]), np.array([4])]))
-        outs[mode].append(eng.put([1, 2], [cont, np.array([7])]))  # w/ past
-        engs[mode] = eng
-    for step_a, step_b in zip(outs["bf16"], outs["int8"]):
-        for u in (1, 2):
-            a = np.asarray(step_a[u], np.float32)
-            b = np.asarray(step_b[u], np.float32)
-            # int8 KV error on logits: small relative to logit scale
-            assert np.abs(a - b).max() < 0.15 * max(np.abs(a).max(), 1.0), \
-                (u, np.abs(a - b).max())
-    # fused decode loop runs on the int8 pool
-    out = engs["int8"].decode_batch([1, 2], [1, 2], steps=4)
-    assert all(len(out[u]) == 4 for u in (1, 2))
+    eng = InferenceEngineV2(model, params=params, max_sequences=4,
+                            max_seq_len=64, block_size=8, kv_dtype=mode)
+    outs = [eng.put([1, 2], prompts)]                    # whole prefill
+    outs.append(eng.put([1, 2], [np.array([3]), np.array([4])]))
+    outs.append(eng.put([1, 2], [cont, np.array([7])]))  # w/ past
+    if mode == "bf16":
+        _BF16_OUTS[mode] = eng, outs
+    return eng, outs
 
 
-def test_int4_kv_cache_tracks_bf16(tiny_lm):
-    """int4 paged pool (per-head lane-paired nibbles + per-token scales):
-    must track the bf16 engine through prefill/continuation/fused decode
-    within 4-bit tolerance."""
+# int8: per-token dequant scales, the error on the logits small relative to
+# their scale; int4: per-head lane-paired nibbles + per-token scales, ~16x
+# coarser than int8: loose but bounded
+@pytest.mark.parametrize("mode,bound", [("int8", 0.15), ("int4", 0.6)])
+def test_quantized_kv_cache_tracks_bf16(tiny_lm, mode, bound):
+    """The int8 and the int4 paged pool must track the full-precision engine
+    through prefill, mixed continuation and the fused decode loop, within
+    quantization tolerance (a pool that dequantizes with the wrong scale, or
+    pairs the wrong nibbles, is off by the logits' own size)."""
     model, params = tiny_lm
-    rng = np.random.default_rng(13)
-    prompts = [rng.integers(0, 256, n) for n in (21, 9)]
-    cont = rng.integers(0, 256, 5)
-    outs = {}
-    engs = {}
-    for mode in ("bf16", "int4"):
-        eng = InferenceEngineV2(model, params=params, max_sequences=4,
-                                max_seq_len=64, block_size=8, kv_dtype=mode)
-        outs[mode] = [eng.put([1, 2], prompts)]
-        outs[mode].append(eng.put([1, 2], [np.array([3]), np.array([4])]))
-        outs[mode].append(eng.put([1, 2], [cont, np.array([7])]))
-        engs[mode] = eng
-    assert engs["int4"].cache["k"].shape[-1] \
-        == model.cfg.num_kv_heads * model.cfg.head_dim // 2
-    for step_a, step_b in zip(outs["bf16"], outs["int4"]):
+    _, want = _kv_cache_outs(model, params, "bf16")
+    eng, got = _kv_cache_outs(model, params, mode)
+    if mode == "int4":
+        assert eng.cache["k"].shape[-1] \
+            == model.cfg.num_kv_heads * model.cfg.head_dim // 2
+    for step_a, step_b in zip(want, got):
         for u in (1, 2):
             a = np.asarray(step_a[u], np.float32)
             b = np.asarray(step_b[u], np.float32)
-            # 4-bit KV: ~16x coarser than int8 — loose but bounded
-            assert np.abs(a - b).max() < 0.6 * max(np.abs(a).max(), 1.0), \
+            assert np.abs(a - b).max() < bound * max(np.abs(a).max(), 1.0), \
                 (u, np.abs(a - b).max())
-    out = engs["int4"].decode_batch([1, 2], [1, 2], steps=4)
+    # fused decode loop runs on the quantized pool
+    out = eng.decode_batch([1, 2], [1, 2], steps=4)
     assert all(len(out[u]) == 4 for u in (1, 2))
 
 
@@ -849,13 +838,21 @@ def _serve(path, model, eng, params, prompt):
 def test_every_serving_program_matches_the_whole_sequence_forward(
         loop_models, kind, path):
     """The four serving forwards share one layer loop: down each of them the
-    next-token logits agree with ``model.logits`` on the whole sequence."""
+    next-token logits agree with ``model.logits`` on the whole sequence (one
+    forward over the longest sequence served: the layers are causal, so the
+    row of a prefix's last token is what the prefix alone gives). A program
+    that read a stale or misplaced cache row, or a window's wrong edge, is
+    off at the first length that reaches it."""
     model, eng, params, ref, tol = loop_models[kind]
     prompt = np.random.default_rng(12).integers(0, 256, 12)
-    for seq, got in _serve(path, model, eng, params, prompt):
-        want = model.logits(ref, np.asarray(seq, np.int32)[None])[0, -1]
+    served = _serve(path, model, eng, params, prompt)
+    longest = max((seq for seq, _ in served), key=len)
+    assert all((longest[:len(seq)] == seq).all() for seq, _ in served)
+    whole = jax.jit(model.logits)(ref, np.asarray(longest, np.int32)[None])[0]
+    for seq, got in served:
         np.testing.assert_allclose(np.asarray(got, np.float32),
-                                   np.asarray(want, np.float32), **tol,
+                                   np.asarray(whole[len(seq) - 1],
+                                              np.float32), **tol,
                                    err_msg=f"{len(seq)} tokens")
 
 

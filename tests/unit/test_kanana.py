@@ -18,10 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(__file__))
-import kanana_reference as ref  # noqa: E402
-from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
-from deepspeed_tpu.models import transformer as tf  # noqa: E402
+from benchmarks import reference_kanana2 as ref
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models import transformer as tf
+from deepspeed_tpu.ops import lowerings
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -232,7 +232,7 @@ def test_flash_kernels_at_two_widths_match_xla_attention(T, d, dv, block):
         return fa.flash_attention(q, k, v, causal=True, block_q=block,
                                   block_k=block, interpret=True)
 
-    before = fa.bwd_lowerings()
+    before = lowerings.snapshot()
     out, vjp = jax.vjp(flash, q, k, v)
     want, vjp_want = jax.vjp(
         lambda q, k, v: tf.xla_attention(q, k, v, causal=True), q, k, v)
@@ -241,9 +241,8 @@ def test_flash_kernels_at_two_widths_match_xla_attention(T, d, dv, block):
     for got, ref_g, name in zip(vjp(do), vjp_want(do), "qkv"):
         assert got.shape == ref_g.shape
         np.testing.assert_allclose(got, ref_g, atol=1e-4, err_msg="d" + name)
-    took = {n: c - before[n] for n, c in fa.bwd_lowerings().items()}
-    assert took == ({"fused": 1, "split": 0} if block == 128
-                    else {"fused": 0, "split": 1})
+    assert lowerings.since(before)["flash_bwd"] == {
+        "fused" if block == 128 else "split": 1}
     # the log-sum-exp variant takes the two widths too
     out2, lse = fa.flash_attention_lse(q, k, v, block_q=block, block_k=block,
                                        interpret=True)
@@ -303,7 +302,7 @@ def test_flash_kernels_take_q_and_k_in_parts(case):
     def whole(q, qr, k, kr, v):
         return tf.xla_attention(*fa.assembled(q, k, v, qr, kr), causal=True)
 
-    before, parts_before = fa.bwd_lowerings(), fa.rope_operand_lowerings()
+    before = lowerings.snapshot()
     out, vjp = jax.vjp(flash, q, qr, k, kr, v)
     want, vjp_want = jax.vjp(whole, q, qr, k, kr, v)
     assert out.shape == (B, T, H, dv)
@@ -312,13 +311,10 @@ def test_flash_kernels_take_q_and_k_in_parts(case):
                                 ("dq", "dq_rope", "dk", "dk_rope", "dv")):
         assert got.shape == ref_g.shape, name
         np.testing.assert_allclose(got, ref_g, atol=1e-4, err_msg=name)
-    took = {n: c - before[n] for n, c in fa.bwd_lowerings().items()}
-    assert took == ({"fused": 1, "split": 0} if block == 128
-                    else {"fused": 0, "split": 1})
+    took = lowerings.since(before)
+    assert took["flash_bwd"] == {"fused" if block == 128 else "split": 1}
     # a forward and a backward, each counted as taking the operands
-    assert {n: c - parts_before[n]
-            for n, c in fa.rope_operand_lowerings().items()} == {
-        "operand": 2, "none": 0}
+    assert took["flash_rope_operand"] == {"operand": 2}
     # the log-sum-exp variant takes the parts too, gradients through both
     # results
     (out2, lse), vjp2 = jax.vjp(
@@ -422,13 +418,11 @@ def test_the_block_in_parts_is_the_block_on_q_and_k_whole(monkeypatch, impl,
         return jax.vjp(lambda x, w: mla.mla_block(x, w, cfg, freqs, attn_fn),
                        x, w)
 
-    before = fa.rope_operand_lowerings()
+    before = lowerings.snapshot()
     out, vjp = block(attn)
     dx, dw = vjp(do)
-    counted = {n: c - before[n]
-               for n, c in fa.rope_operand_lowerings().items()}
-    assert counted == ({"operand": 2, "none": 0} if impl == "flash_pallas"
-                       else {"operand": 0, "none": 0})
+    assert lowerings.since(before).get("flash_rope_operand") == (
+        {"operand": 2} if impl == "flash_pallas" else None)
     want, vjp_want = block(tf.xla_attention)
     dx_want, dw_want = vjp_want(do)
     np.testing.assert_allclose(out, want, atol=2e-5)

@@ -12,22 +12,19 @@ heads and are held to 1e-4 of each leaf's largest magnitude (measured: under
 2e-5)."""
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(__file__))
-import looped_reference as ref  # noqa: E402
+from benchmarks import reference_ouro as ref
 
-import deepspeed_tpu as ds  # noqa: E402
-from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
-from deepspeed_tpu.models.transformer import lm_loss  # noqa: E402
-from deepspeed_tpu.observability import steplog  # noqa: E402
-from deepspeed_tpu.parallel import build_mesh  # noqa: E402
+import deepspeed_tpu as ds
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.transformer import lm_loss
+from deepspeed_tpu.observability import steplog
+from deepspeed_tpu.parallel import build_mesh
 
 BETA, R, L, T, V = 0.1, 4, 3, 32, 256
 REF_CFG = {"hidden_size": 64, "intermediate_size": 128,

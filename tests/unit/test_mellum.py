@@ -7,17 +7,15 @@ record, and the faults the benchmark cell's check has to see."""
 
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(__file__))
-import mellum_reference as ref  # noqa: E402
-from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
-from deepspeed_tpu.models import transformer as tf  # noqa: E402
+from benchmarks import reference_mellum2 as ref
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models import transformer as tf
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -304,9 +302,9 @@ def test_the_step_record_carries_the_routers_counts():
     assert prog.moe_kernel_resolved == "ragged"
     # float32 at a width of 64 on a CPU: every product is lax.ragged_dot
     # (three a layer; its transposes are autodiff's and are not counted)
-    assert prog.moe_grouped_lowerings == {"pallas": 0, "xla": 12}
+    assert prog.moe_grouped_lowerings == {"xla": 12}
     # and every dispatch and combine, with its backward, is jnp.take
-    assert prog.moe_dispatch_lowerings == {"pallas": 0, "xla": 16}
+    assert prog.moe_dispatch_lowerings == {"xla": 16}
     assert prog.experts_held == (2, 4, 8)
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))
@@ -341,9 +339,9 @@ def test_the_cell_shaped_step_program_takes_the_kernels(monkeypatch):
     assert np.isfinite(float(eng.fused_train_step({"input_ids": rows})))
     prog = steplog.programs()[-1]
     assert prog.moe_kernel_resolved == "ragged"
-    assert prog.moe_grouped_lowerings == {"pallas": 36, "xla": 0}
+    assert prog.moe_grouped_lowerings == {"pallas": 36}
     # dispatch, combine and the backward of each, a layer, are row kernels
-    assert prog.moe_dispatch_lowerings == {"pallas": 16, "xla": 0}
+    assert prog.moe_dispatch_lowerings == {"pallas": 16}
     row = steplog.get_steplog().parts(last=1)[-1]
     assert row["pairs_dropped"].tolist() == [0] * 4
     assert row["expert_pairs"].sum() == row["pairs_here"].sum() > 0

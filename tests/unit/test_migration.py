@@ -157,14 +157,28 @@ def _mig_batcher(shared, **serving):
     return ContinuousBatcher(eng, cfg)
 
 
+_BASELINES = {}
+
+
 def _baseline(shared, prompt, n=8):
-    solo = _mig_batcher(shared)
-    uid = solo.submit(prompt, max_new_tokens=n, tier="batch")
-    solo.pump(max_steps=80)
-    base = list(solo.manager.result(uid).generated)
-    solo.engine.close()
-    assert len(base) == n
-    return base
+    """The greedy tokens of an unmigrated fp32 run (the engines' weights are
+    seeded alike); kept by prompt, so the cases that migrate the same prompt
+    build one solo engine between them."""
+    key = (tuple(int(t) for t in prompt), n)
+    if key not in _BASELINES:
+        solo = _mig_batcher(shared)
+        uid = solo.submit(prompt, max_new_tokens=n, tier="batch")
+        solo.pump(max_steps=80)
+        _BASELINES[key] = list(solo.manager.result(uid).generated)
+        solo.engine.close()
+        assert len(_BASELINES[key]) == n
+    return list(_BASELINES[key])
+
+
+# the 40-token prompt of the cases that sever a request mid-decode: one
+# prompt, so one baseline (what differs between the cases is the seam that
+# fails, not the tokens)
+PROMPT = list(np.random.default_rng(7).integers(0, 250, 40))
 
 
 def _pause_mid_decode(b, uid):
@@ -186,7 +200,7 @@ class TestCrossReplicaAdoption:
         through the same ``_flush_promotes`` fence and finishes the exact
         greedy sequence of an unmigrated fp32 run."""
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(7).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
 
         a = _mig_batcher(shared)
@@ -229,7 +243,7 @@ class TestCrossReplicaAdoption:
         cap eviction) adopts as a clean re-prefill — recompute from token
         history, never zero-fill — and still matches the baseline."""
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(11).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
 
         a = _mig_batcher(shared)
@@ -283,7 +297,7 @@ class TestCrossReplicaAdoption:
         """Satellite: two siblings race one exported manifest; the rename
         claim lets exactly one adopt durable KV, the loser re-prefills."""
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(17).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
 
         a = _mig_batcher(shared)
@@ -322,7 +336,7 @@ class TestMigrationFaults:
                                                      set_injector)
 
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(19).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
         a = _mig_batcher(shared)
         uid = a.submit(prompt, max_new_tokens=8, tier="batch")
@@ -384,7 +398,7 @@ class TestMigrationFaults:
                                                      set_injector)
 
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(29).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
         a = _mig_batcher(shared)
         uid = a.submit(prompt, max_new_tokens=8, tier="batch")
@@ -423,7 +437,7 @@ class TestRebalanceAndTrace:
         transferred — resolved locally as ``rebalanced`` with its HBM and
         slot already free — and B resumes it bit-identical."""
         shared = str(tmp_path)
-        prompt = list(np.random.default_rng(31).integers(0, 250, 40))
+        prompt = PROMPT
         base = _baseline(shared, prompt)
 
         a = _mig_batcher(shared)

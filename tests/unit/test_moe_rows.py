@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.moe import sharded_moe as sm
-from deepspeed_tpu.ops import moe_rows as mr
+from deepspeed_tpu.ops import lowerings, moe_rows as mr
 
 
 def f32(a):
@@ -245,17 +245,15 @@ def test_the_layers_gradient_through_the_kernels_is_the_take_paths(
         return (out.astype(jnp.float32) ** 2).sum(), (out, parts)
 
     grad = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
-    before = sm.dispatch_lowerings()
+    before = lowerings.snapshot()
     (_, (out, parts)), grads = grad(h, w)
-    took = {n: v - before[n] for n, v in sm.dispatch_lowerings().items()}
-    assert took == {"pallas": 4, "xla": 0}
+    assert lowerings.since(before)["moe_dispatch"] == {"pallas": 4}
     assert int(parts["pairs_dropped"]) == 0
     monkeypatch.setattr(sm, "_moves_lowering",
                         lambda *a, **kw: ("xla", False))
-    before = sm.dispatch_lowerings()
+    before = lowerings.snapshot()
     (_, (want, _)), want_grads = grad(h, w)
-    took = {n: v - before[n] for n, v in sm.dispatch_lowerings().items()}
-    assert took == {"pallas": 0, "xla": 4}
+    assert lowerings.since(before)["moe_dispatch"] == {"xla": 4}
     # the router's weights are f32 with all their bits: one bf16 rounding of
     # a sum's larger term (the CPU's contraction, see the module's docstring)
     np.testing.assert_allclose(f32(out), f32(want),

@@ -9,19 +9,17 @@ the benchmark cell's check has to see."""
 
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(__file__))
-import olmo_hybrid_reference as ref  # noqa: E402
-from deepspeed_tpu.models import TransformerConfig, TransformerLM  # noqa: E402
-from deepspeed_tpu.models.hf import config_from_hf  # noqa: E402
-from deepspeed_tpu.ops import delta_rule  # noqa: E402
-from deepspeed_tpu.ops.delta_rule import (chunked_delta_rule,  # noqa: E402
+from benchmarks import reference_olmo_hybrid as ref
+from deepspeed_tpu.models import TransformerConfig, TransformerLM
+from deepspeed_tpu.models.hf import config_from_hf
+from deepspeed_tpu.ops import delta_rule
+from deepspeed_tpu.ops.delta_rule import (chunked_delta_rule,
                                           unit_lower_inverse)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -418,61 +416,78 @@ def test_the_step_record_and_the_step_programs_row():
     assert np.any(after["dt_bias"] != params["layers"]["delta"]["dt_bias"])
 
 
-def test_the_cell_shaped_step_program_takes_the_rule_kernels(monkeypatch):
+_SMALL_CELL = {}
+
+
+def _small_cell():
     """What the benchmark's cell is in small: bf16, keys of 32 and values of
-    64 in chunks of 64, a period of three delta layers and a full one, the
-    cell's ``dots_saveable``. Every rule of the program is the Pallas kernels
-    (interpreted here: the CPU stands in for the chip), the backward too, and
-    the step gives the einsum form's loss; in float32 the picker's answer
-    stands, the einsum form."""
+    64 in chunks of 64, the cell's ``dots_saveable``, one delta layer and one
+    full one (a second and a third delta layer run what the first runs), rows
+    of 80 positions: two chunks, the second padded, and whole sublane tiles
+    for the convolution's kernels. Returns (a fresh engine's builder, the
+    rows, the loss of the step as the pickers choose on a CPU, and what that
+    program's row counted), the last two computed once for the cases that
+    compare with them."""
+    hf = hf_config(L=2, types=PAIR, D=128, heads=2, d=64,
+                   max_position_embeddings=128)
+    rows = np.random.default_rng(2).integers(0, 96, (2, 80)).astype(np.int32)
+
+    def build():
+        return _engine(model_for(hf, remat_policy="dots_saveable",
+                                 dtype="bfloat16", max_seq_len=128))
+
+    if not _SMALL_CELL:
+        from deepspeed_tpu.observability import steplog
+
+        _SMALL_CELL["loss"] = float(build().fused_train_step(
+            {"input_ids": rows}))
+        _SMALL_CELL["counted"] = dict(steplog.programs()[-1].counted)
+    return build, rows, _SMALL_CELL["loss"], _SMALL_CELL["counted"]
+
+
+def test_the_cell_shaped_step_program_takes_the_rule_kernels(monkeypatch):
+    """The small cell (:func:`_small_cell`): every rule of the program is the
+    Pallas kernels (interpreted here: the CPU stands in for the chip), the
+    backward too, and the step gives the einsum form's loss; without the
+    handle the picker's answer stands, the einsum form. A kernel that
+    mishandled the padded second chunk or the carried state would move the
+    loss."""
     import functools
 
     from deepspeed_tpu.models import gated_delta
     from deepspeed_tpu.observability import steplog
 
     monkeypatch.setattr(delta_rule, "CHUNK", 64)
-    hf = hf_config(D=128, heads=2, d=64, max_position_embeddings=256)
-    rows = np.random.default_rng(2).integers(0, 96, (2, 200)).astype(np.int32)
-    kw = dict(remat_policy="dots_saveable", dtype="bfloat16",
-              max_seq_len=256)
-    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    assert steplog.programs()[-1].delta_scan_lowerings == {"xla": 3}
+    build, rows, plain, counted = _small_cell()
+    assert counted["delta_scan"] == {"xla": 1}
     monkeypatch.setattr(gated_delta, "chunked_delta_rule", functools.partial(
         gated_delta.chunked_delta_rule, interpret=True))
-    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
+    loss = float(build().fused_train_step({"input_ids": rows}))
     prog = steplog.programs()[-1]
-    # the period's three rules and the backward of each
-    assert prog.delta_scan_lowerings == {"pallas": 6}
-    assert prog.delta_chunks_per_step == 3 * 2 * 4    # 200 tokens: 4 chunks
+    # the rule and its backward
+    assert prog.delta_scan_lowerings == {"pallas": 2}
+    assert prog.delta_chunks_per_step == 1 * 2 * 2    # 80 tokens: 2 chunks
     np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 
 def test_the_cell_shaped_step_program_takes_the_conv_kernels(monkeypatch):
     """The same small cell with the convolutions as their Pallas kernels
-    (interpreted here), rows of whole sublane tiles: q's and k's leave in
-    float32, v's in bf16, every one of the program and its backward is the
-    kernels', and the step gives the ``jax.numpy`` form's loss."""
+    (interpreted here): q's and k's leave in float32, v's in bf16, every one
+    of the program and its backward is the kernels', and the step gives the
+    ``jax.numpy`` form's loss."""
     import functools
 
     from deepspeed_tpu.models import gated_delta
     from deepspeed_tpu.observability import steplog
 
     monkeypatch.setattr(delta_rule, "CHUNK", 64)
-    hf = hf_config(D=128, heads=2, d=64, max_position_embeddings=256)
-    rows = np.random.default_rng(2).integers(0, 96, (2, 192)).astype(np.int32)
-    kw = dict(remat_policy="dots_saveable", dtype="bfloat16",
-              max_seq_len=256)
-    plain = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    assert steplog.programs()[-1].conv_lowerings == {"xla": 9}
+    build, rows, plain, counted = _small_cell()
+    assert counted["conv"] == {"xla": 3}
     monkeypatch.setattr(gated_delta, "causal_conv_silu", functools.partial(
         gated_delta.causal_conv_silu, interpret=True))
-    loss = float(_engine(model_for(hf, **kw)).fused_train_step(
-        {"input_ids": rows}))
-    # three delta layers' three convolutions and the backward of each
-    assert steplog.programs()[-1].conv_lowerings == {"pallas": 18}
+    loss = float(build().fused_train_step({"input_ids": rows}))
+    # the delta layer's three convolutions and the backward of each
+    assert steplog.programs()[-1].conv_lowerings == {"pallas": 6}
     np.testing.assert_allclose(loss, plain, atol=2e-3)
 
 

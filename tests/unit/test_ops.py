@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.transformer import xla_attention
-from deepspeed_tpu.ops import flash_attention as fa
+from deepspeed_tpu.ops import flash_attention as fa, lowerings
 from deepspeed_tpu.ops.flash_attention import (flash_attention,
                                                flash_attention_lse)
 from deepspeed_tpu.ops.quantization import (
@@ -196,11 +196,9 @@ def test_fused_backward(name):
         l = _ref_lse(q, k, causal, window) if dlse else 0.0
         return (o * w_out).sum() + (l * w_lse).sum()
 
-    before = fa.bwd_lowerings()
+    before = lowerings.snapshot()
     g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    after = fa.bwd_lowerings()
-    assert {n: after[n] - before[n] for n in after} == {
-        "fused": int(took == "fused"), "split": int(took == "split")}
+    assert lowerings.since(before)["flash_bwd"] == {took: 1}
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
@@ -281,13 +279,12 @@ def test_flash_forward_tiles(name, monkeypatch):
     want = dict(masked=int((some & ~every).sum()), unmasked=int(every.sum()),
                 dead=int((~some).sum()), rows=rows)
     assert want == case.get("tiles", want)
-    n0, _ = fa.fwd_tiles()
+    before = lowerings.snapshot()
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     _, raw = fa._fwd_pallas(qt, kt, vt, scale=d ** -0.5, causal=causal,
                             window=window, block_q=bq, block_k=bk,
                             interpret=True, rel_offset=rel)
-    n1, said = fa.fwd_tiles()
-    assert n1 == n0 + 1 and said == want
+    assert lowerings.since(before)["flash_fwd_tiles"] == want
     assert raw.shape == ((1, H, 1, T) if rows else (1, H, T, 1))
     np.testing.assert_array_equal(np.asarray(raw).reshape(-1),
                                   np.asarray(lse).reshape(-1))

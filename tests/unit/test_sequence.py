@@ -160,15 +160,18 @@ def test_sp_engine_loss_parity(impl, eight_devices):
 
 
 def test_sp_long_context_forward(eight_devices):
-    """Long-context functional check: 8k tokens through ring attention on the
+    """Long-context functional check: 2k tokens through ring attention on the
     8-way sp mesh (BASELINE.md 128k target scaled to the CPU-mesh test budget —
-    per-device attention footprint is T/sp x T/sp, not T x T)."""
+    per-device attention footprint is T/sp x T/sp, not T x T; a quarter of
+    the 8k it ran before, a sixteenth of the arithmetic: what it sees is a
+    ring step that drops or misorders a block, which shows as a loss that is
+    not finite at any length of eight blocks)."""
     import dataclasses
 
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import TransformerLM, TransformerConfig
 
-    T = 8192
+    T = 2048
     cfg = TransformerConfig(vocab_size=256, hidden_size=64, num_layers=2,
                             num_heads=4, num_kv_heads=2, max_seq_len=T,
                             attention_impl="ring")

@@ -15,6 +15,10 @@ from deepspeed_tpu.observability import events
 from deepspeed_tpu.observability.events import EventBus
 from deepspeed_tpu.parallel import build_mesh
 
+# these cases count compiles, cache misses and build seconds: the run's
+# persistent compile cache (tests/conftest.py) stays off around them
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
+
 
 class Recorder:
     """Stands in for ``TraceAnnotation``: the order of enters and exits."""
