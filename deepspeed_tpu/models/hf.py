@@ -55,14 +55,18 @@ OURO_TENSORS = {
 }
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
-                              "olmo_hybrid")
+                              "olmo_hybrid", "nemotron_h")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
              "linear_attention": "delta"}
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
-_CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid")
+_CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
+                "nemotron_h")
+#: ``hybrid_override_pattern`` letters (``model_type: "nemotron_h"``) ->
+#: layer kinds of a ``one_branch`` model
+_NEMOTRON_KINDS = {"M": "ssm", "*": "full", "E": "moe", "-": "dense"}
 
 _HF_ACT = {"silu": "swiglu", "gelu": "gelu_exact", "gelu_new": "gelu",
            "gelu_pytorch_tanh": "gelu", "gelu_fast": "gelu", "relu": "relu"}
@@ -308,6 +312,80 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
             moe_shared_experts=int(get("n_shared_experts", 0) or 0),
         )
+    elif model_type == "nemotron_h":
+        # every layer one pre-norm branch, by ``hybrid_override_pattern``:
+        # "M" a Mamba-2 mixer whose gated norm is by group, "*" attention
+        # without a rope, "E" LatentMoE (sigmoid scores with a selection
+        # bias, the top k normalised and scaled; experts of two products
+        # round relu^2 in a latent of ``moe_latent_size``; one shared expert
+        # at full width), "-" a dense relu^2 MLP. The config side only. A
+        # share of the heads or experts is no config key: pass the counts
+        # held (num_heads=, ssm_heads=, moe_experts_held=). What training
+        # adds (the bias rule's rate, the balance term's weight): pass
+        # moe_bias_rate= and moe_aux_loss_coef=.
+        if int(get("n_group", 1) or 1) > 1:
+            raise ValueError(
+                f"nemotron_h with n_group={get('n_group')} (group-limited "
+                f"routing) is not mapped")
+        if (get("attention_bias", False) or get("mamba_proj_bias", False)
+                or get("mlp_bias", False) or get("use_bias", False)
+                or not get("use_conv_bias", True)
+                or not get("norm_topk_prob", True)
+                or get("mlp_hidden_act", "relu2") != "relu2"
+                or get("mamba_hidden_act", "silu") != "silu"):
+            raise ValueError(
+                "nemotron_h is mapped without projection biases, with the "
+                "convolution's bias, silu in the mixer, relu2 FFNs and a "
+                "normalised top k")
+        L = get("num_hidden_layers")
+        pattern = str(get("hybrid_override_pattern"))[:L]
+        if len(pattern) != L or set(pattern) - set(_NEMOTRON_KINDS):
+            raise ValueError(
+                f"hybrid_override_pattern {get('hybrid_override_pattern')!r}"
+                f" does not name num_hidden_layers={L} layers by "
+                f"{sorted(_NEMOTRON_KINDS)}")
+        if int(get("num_nextn_predict_layers", 0) or 0):
+            log_dist(
+                f"nemotron_h: num_nextn_predict_layers="
+                f"{get('num_nextn_predict_layers')} and "
+                f"mtp_hybrid_override_pattern="
+                f"{get('mtp_hybrid_override_pattern')!r} are not read: the "
+                f"multi-token prediction module is an auxiliary training "
+                f"loss beside the forward pass mapped here, and is not "
+                f"implemented")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            activation="relu2", use_rope=False, one_branch=True,
+            norm_eps=float(get("layer_norm_epsilon", get("norm_eps", 1e-5))),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            attn_pattern=tuple(_NEMOTRON_KINDS[c] for c in pattern),
+            ssm_heads=get("mamba_num_heads"),
+            ssm_head_dim=get("mamba_head_dim"),
+            ssm_state=get("ssm_state_size"), ssm_groups=get("n_groups"),
+            ssm_conv=get("conv_kernel"), ssm_chunk=get("chunk_size"),
+            ssm_group_norm=True,
+        )
+        if "E" in pattern:
+            width = get("moe_intermediate_size")
+            shared = int(get("moe_shared_expert_intermediate_size", 0) or 0)
+            if shared % width:
+                raise ValueError(
+                    f"moe_shared_expert_intermediate_size={shared} is no "
+                    f"multiple of moe_intermediate_size={width}: the shared "
+                    f"expert is built as that many times an expert's width")
+            kw.update(
+                num_experts=get("n_routed_experts"),
+                top_k=get("num_experts_per_tok"),
+                moe_intermediate_size=width, moe_dispatch="grouped",
+                moe_scoring="sigmoid",
+                moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+                moe_shared_experts=shared // width,
+                moe_latent_size=get("moe_latent_size"))
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "

@@ -6,7 +6,9 @@ A layer's leaves (``params["layers"]["ssm"]``, one row per state-space
 layer): ``in_proj`` [D, 2 inner + 2 G N + H] (gate ``z``, ``xBC``, ``dt``),
 ``conv_w`` [K, inner + 2 G N] (tap k meets position t - (K - 1) + k),
 ``conv_b``, ``dt_bias``, ``A_log``, ``D`` [H], ``norm`` [inner] (the gated
-norm's scale) and ``out_proj`` [inner, D]; inner = H x P.
+norm's scale; the norm over all inner channels, or with
+``cfg.ssm_group_norm`` over each group's inner / G on their own) and
+``out_proj`` [inner, D]; inner = H x P.
 """
 
 from __future__ import annotations
@@ -105,9 +107,13 @@ def ssm_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
                      Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N), w["D"],
                      cfg.ssm_chunk)
     with jax.named_scope("ssm_gate"):
-        # the gate before the norm, one group over all inner channels
+        # the gate before the norm: one group over all inner channels, or
+        # (``ssm_group_norm``) each group's channels on their own
         g = y.reshape(B, T, inner).astype(f32) * jax.nn.silu(z.astype(f32))
-        g = _norm(g, {"scale": w["norm"]}, "rmsnorm", cfg.norm_eps
-                  ).astype(u.dtype)
+        scale = w["norm"]
+        if cfg.ssm_group_norm and G > 1:
+            g, scale = g.reshape(B, T, G, inner // G), scale.reshape(G, -1)
+        g = _norm(g, {"scale": scale}, "rmsnorm", cfg.norm_eps
+                  ).astype(u.dtype).reshape(B, T, inner)
     with jax.named_scope("ssm_proj"):
         return g @ w["out_proj"]

@@ -48,7 +48,7 @@ class TransformerConfig:
     arch: str = "llama"  # "llama" | "gpt2"
     # derived-from-arch defaults (overridable)
     norm: Optional[str] = None        # rmsnorm | layernorm
-    activation: Optional[str] = None  # swiglu | gelu | gelu_exact | relu
+    activation: Optional[str] = None  # swiglu | gelu | gelu_exact | relu | relu2
     use_rope: Optional[bool] = None
     learned_pos: Optional[bool] = None
     tie_embeddings: bool = True
@@ -73,6 +73,11 @@ class TransformerConfig:
     # full layers is ("full",) * n + ("window",) * (L - n): a period of the
     # whole stack
     attn_pattern: Optional[Tuple[str, ...]] = None
+    # every layer is one pre-norm branch, x + f(N(x)), f a mixer alone or an
+    # FFN alone (the Nemotron-H family's stack): ``attn_pattern`` then also
+    # names the FFN layers, "moe" (the routed experts) or "dense", each kind
+    # with a stack of its own leaves and one norm a layer
+    one_branch: bool = False
     # a state-space layer: heads of ``ssm_head_dim`` channels (inner width =
     # heads x head_dim), a state of ``ssm_state`` a channel, B and C shared
     # by the heads of each of ``ssm_groups`` groups, a causal depthwise
@@ -85,6 +90,9 @@ class TransformerConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # the gated norm over each of the ``ssm_groups`` groups' channels on its
+    # own (Nemotron-H), not over all inner channels at once (Granite)
+    ssm_group_norm: bool = False
     # a gated delta-rule (linear-attention) layer: ``delta_heads`` heads with
     # keys of ``delta_key_dim`` and values of ``delta_value_dim`` (the state
     # of a head is key x value), a causal depthwise convolution over
@@ -203,9 +211,15 @@ class TransformerConfig:
     moe_routed_scale: float = 1.0
     moe_bias_rate: float = 0.0
     moe_bias_init: float = 0.0
-    # this many shared experts, every token's: one SwiGLU of that many times
-    # the experts' width beside the routed ones (grouped dispatch only)
+    # this many shared experts, every token's: one FFN of the experts' kind
+    # (SwiGLU, or two products round relu^2) of that many times the experts'
+    # width beside the routed ones (grouped dispatch only)
     moe_shared_experts: int = 0
+    # LatentMoE: the tokens go through dispatch, the routed experts and
+    # combine in a latent of this width (a plain linear map down before the
+    # dispatch, one up after the combine; ``latent_down``, ``latent_up``);
+    # the router and the shared experts read the full width. None = none
+    moe_latent_size: Optional[int] = None
     # FFN kinds by layer: the first ``first_k_dense`` layers' FFN is dense at
     # ``intermediate_size``, the others' routed (num_experts > 1); each kind
     # has a stack of its own (``mlp_dense``, ``mlp_moe``)
@@ -260,11 +274,13 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "latent attention (kv_lora_rank) is every layer's mixer: "
                     "not with attn_pattern")
+            ffns = {"moe", "dense"} if self.one_branch else set()
             if not pat or set(pat) - {"window", "full", "ssm", "delta"} \
-                    or self.num_layers % len(pat):
+                    - ffns or self.num_layers % len(pat):
                 raise ValueError(
                     f"attn_pattern={pat}: a period of 'window' / 'full' / "
-                    f"'ssm' / 'delta' whose length divides num_layers="
+                    f"'ssm' / 'delta' (with one_branch also 'moe' / "
+                    f"'dense') whose length divides num_layers="
                     f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
                 raise ValueError("attn_pattern has window layers and "
@@ -279,15 +295,14 @@ class TransformerConfig:
                 raise ValueError(
                     f"a state-space layer needs ssm_heads={self.ssm_heads} "
                     f"> 0, a multiple of ssm_groups={self.ssm_groups}")
-            if (self.looped or self.num_experts > 1 or self.parallel_block
+            if (self.looped or self.parallel_block
                     or self.loss_tiling > 1 or self.attention_impl == "fpdt"):
                 raise NotImplementedError(
                     "a model with state-space layers (attn_pattern holds "
-                    "'ssm') runs one pre-norm pass with dense FFNs, whole "
-                    "logits and whole-sequence attention: not num_passes > 1, "
-                    "sandwich_norm, the exit gate, num_experts > 1, "
-                    "parallel_block, loss_tiling > 1 or attention_impl="
-                    "'fpdt'")
+                    "'ssm') runs one pre-norm pass with whole logits and "
+                    "whole-sequence attention: not num_passes > 1, "
+                    "sandwich_norm, the exit gate, parallel_block, "
+                    "loss_tiling > 1 or attention_impl='fpdt'")
         if self.has_delta:
             if self.delta_heads < 1 or self.delta_conv < 1:
                 raise ValueError(
@@ -317,6 +332,30 @@ class TransformerConfig:
                     "a delta layer with attention_impl='fpdt': the chunked "
                     "sequence path carries key-value chunks, not a "
                     "recurrent state")
+        if self.one_branch:
+            # (``kind_cfg``'s copy for one kind of layer has no pattern)
+            pat = self.attn_pattern
+            if pat is not None and (
+                    ("moe" in pat) != (self.num_experts > 1)
+                    or not set(pat) & {"moe", "dense"}):
+                raise ValueError(
+                    f"one_branch with attn_pattern={pat}: the pattern names "
+                    f"the FFN layers ('moe' / 'dense'), 'moe' where and only "
+                    f"where num_experts > 1")
+            if (self.looped or self.parallel_block or self.loss_tiling > 1
+                    or self.norm_placement != "pre" or self.has_delta
+                    or self.first_k_dense or self.heads_held is not None
+                    or self.residual_multiplier != 1.0
+                    or self.attention_impl == "fpdt"):
+                raise NotImplementedError(
+                    "one_branch (a layer is a mixer alone or an FFN alone) "
+                    "runs one pass of pre-norm attention, state-space and "
+                    "FFN layers with whole logits: not num_passes > 1, "
+                    "sandwich_norm, the exit gate, parallel_block, "
+                    "loss_tiling > 1, norm_placement='post', delta layers, "
+                    "first_k_dense (the pattern names the dense layers), "
+                    "heads_held, residual_multiplier or attention_impl="
+                    "'fpdt'")
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"norm_placement={self.norm_placement!r}: "
                              f"'pre' or 'post'")
@@ -382,13 +421,19 @@ class TransformerConfig:
             raise ValueError(f"moe_scoring={self.moe_scoring!r}: 'softmax' "
                              f"or 'sigmoid'")
         if (self.moe_scoring == "sigmoid" or self.moe_shared_experts
-                or self.first_k_dense) and (
+                or self.first_k_dense or self.moe_latent_size) and (
                     self.num_experts <= 1 or self.moe_dispatch != "grouped"
-                    or self.activation != "swiglu"):
+                    or self.activation not in ("swiglu", "relu2")):
             raise ValueError(
-                "moe_scoring='sigmoid', moe_shared_experts and first_k_dense "
-                "belong to a model with routed SwiGLU experts under the "
-                "grouped dispatch (num_experts > 1, moe_dispatch='grouped')")
+                "moe_scoring='sigmoid', moe_shared_experts, moe_latent_size "
+                "and first_k_dense belong to a model with routed SwiGLU or "
+                "relu2 experts under the grouped dispatch (num_experts > 1, "
+                "moe_dispatch='grouped')")
+        if (self.activation == "relu2" and self.num_experts > 1
+                and self.moe_dispatch != "grouped"):
+            raise ValueError("experts of two products round relu^2 "
+                             "(activation='relu2') run the grouped dispatch "
+                             "only (moe_dispatch='grouped')")
         if not 0 <= self.first_k_dense < self.num_layers:
             raise ValueError(f"first_k_dense={self.first_k_dense} of "
                              f"num_layers={self.num_layers}")
@@ -455,24 +500,31 @@ class TransformerConfig:
     def reports_mixer_outputs(self) -> bool:
         """Whether the step record carries each layer's mixer-output mean
         square (``mix_out_ms``): a model with a mixer that is no plain
-        attention."""
-        return self.has_ssm or self.has_mla or self.has_delta
+        attention, or whose layers are one branch each (then every layer's
+        branch output, an FFN layer's too)."""
+        return (self.has_ssm or self.has_mla or self.has_delta
+                or self.one_branch)
 
     @property
     def has_ffn_kinds(self) -> bool:
-        """Whether the layers' FFNs are of more than one kind (a leading run
-        of dense ones before the routed ones)."""
-        return self.first_k_dense > 0
+        """Whether each FFN kind keeps a stack of its own (``mlp_dense``,
+        ``mlp_moe``): a leading run of dense layers before the routed ones,
+        or layers of one branch each."""
+        return self.first_k_dense > 0 or self.one_branch
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of every layer: its mixer's, "window", "full", "ssm",
         "delta" or "mla"; in a model whose FFNs differ by layer, then ":" and
-        its FFN's, "dense" or "moe"."""
+        its FFN's, "dense" or "moe". A layer of one branch (``one_branch``)
+        names what it lacks "none": "ssm:none", "none:moe"."""
         pat = self.attn_pattern or (
             ("mla",) if self.has_mla else
             ("full",) if self.sliding_window is None else ("window",))
         kinds = pat * (self.num_layers // len(pat))
+        if self.one_branch:
+            return tuple("none:" + k if k in ("moe", "dense")
+                         else k + ":none" for k in kinds)
         if self.has_ffn_kinds:
             kinds = tuple(
                 k + (":dense" if i < self.first_k_dense else ":moe")
@@ -485,7 +537,7 @@ class TransformerConfig:
         plain attention: the layer loop then runs by kind
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
-                or self.has_mla or self.has_delta)
+                or self.has_mla or self.has_delta or self.one_branch)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -521,8 +573,8 @@ class TransformerConfig:
         hd, nh, nkv = self.head_dim, self.heads_here, self.kv_heads_here
         gated = self.activation == "swiglu"
         norm = D * (2 if self.norm == "layernorm" else 1)
-        norms = norm * ((1 if self.parallel_shared_norm else 2)
-                        + (2 if self.sandwich_norm else 0))
+        norms = norm * ((1 if self.parallel_shared_norm or self.one_branch
+                         else 2) + (2 if self.sandwich_norm else 0))
         attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
         if self.qk_norm:
             attn += (nh + nkv) * hd
@@ -535,10 +587,12 @@ class TransformerConfig:
             dense += F + D
         routed = dense
         if self.num_experts > 1:
-            Fm = self.moe_intermediate_size or F
+            Fm, Z = self.moe_intermediate_size or F, self.moe_latent_size
+            n = 3 if gated else 2
             routed = ((self.moe_experts_held or self.num_experts)
-                      * (3 if gated else 2) * D * Fm + D * self.num_experts
-                      + 3 * D * Fm * self.moe_shared_experts)
+                      * n * (Z or D) * Fm + D * self.num_experts
+                      + n * D * Fm * self.moe_shared_experts
+                      + 2 * D * (Z or 0))
             if self.moe_scoring == "sigmoid":
                 routed += self.num_experts
         mixers = {"attn": attn}
@@ -557,8 +611,11 @@ class TransformerConfig:
         layers = 0
         for kind in self.layer_kinds:
             mixer, _, ffn = kind.partition(":")
-            layers += (mixers[_MIXER_GROUP[mixer]] + norms
-                       + (dense if ffn == "dense" else routed))
+            layers += norms
+            if mixer != "none":
+                layers += mixers[_MIXER_GROUP[mixer]]
+            if ffn != "none":
+                layers += dense if ffn == "dense" else routed
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
         head = 0 if self.tie_embeddings else D * V
         gate = D + 1 if self.exit_loss_beta is not None else 0
@@ -976,7 +1033,8 @@ def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
         # gelu_exact = erf gelu (HF "gelu": falcon/gpt-neox); relu = opt
         act = {"gelu": partial(jax.nn.gelu, approximate=True),
                "gelu_exact": partial(jax.nn.gelu, approximate=False),
-               "relu": jax.nn.relu}[cfg.activation]
+               "relu": jax.nn.relu,
+               "relu2": lambda v: jnp.square(jax.nn.relu(v))}[cfg.activation]
         up = linear(x, w["w_up"])
         h = act(up + w["b_up"] if "b_up" in w else up)
     h = constrain(h, P(("dp", "fsdp"), "sp", "tp"))
@@ -996,7 +1054,7 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # are of more than one), the grouped expert layer's parts
                # inside moe (moe/sharded_moe.py)
                "attn_window", "attn_full",
-               "moe_router", "moe_dispatch", "moe_experts",
+               "moe_router", "moe_dispatch", "moe_experts", "moe_latent",
                # a state-space layer's parts inside attn, the token mixer's
                # slot (models/mamba.py)
                "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate",
@@ -1006,7 +1064,10 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # a delta layer's parts inside attn (models/gated_delta.py)
                "delta_proj", "delta_conv", "delta_scan", "delta_gate")
 #: a period of up to this many blocks is the body of one scan over periods;
-#: a longer list of kinds is cut into runs of one kind
+#: a longer list of kinds is cut into runs of one kind. Layers of one branch
+#: each (``one_branch``) are half a block: a period of up to twice as many,
+#: and a body that traces one block a kind, whatever the period's length
+#: (Nemotron-H's eleven layers, alternating: three)
 _MAX_PERIOD = 8
 #: leaves that stay float32 in the compute copy of the weights, by name,
 #: whatever group holds them: a state-space or a delta layer's, which enter
@@ -1018,7 +1079,8 @@ _KEEP_FP32 = ("A_log", "dt_bias", "D", "router_bias")
 #: (``first_k_dense``) then ":" and its FFN's ("mla:dense", "mla:moe"). Such
 #: a group's stack has one row for each layer of its kinds, in layer order;
 #: every other group (norms; the FFN where all layers' are alike, "mlp") has
-#: a row for every layer
+#: a row for every layer. A layer of one branch names what it lacks "none"
+#: ("ssm:none", "none:moe") and keeps leaves in the one group it has
 _MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla",
                 "delta": "delta"}
 _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
@@ -1031,7 +1093,9 @@ _FFN_SCOPE = {"mlp_dense": "mlp", "mlp_moe": "moe"}
 def _groups_of(kind: str) -> Tuple[str, ...]:
     """The groups that hold the own leaves of a layer of ``kind``."""
     mixer, _, ffn = kind.partition(":")
-    return (_MIXER_GROUP[mixer],) + ((_FFN_GROUP[ffn],) if ffn else ())
+    return tuple(group[k] for k, group in ((mixer, _MIXER_GROUP),
+                                           (ffn, _FFN_GROUP))
+                 if k and k != "none")
 
 
 def _times(x: jax.Array, factor: float) -> jax.Array:
@@ -1149,6 +1213,44 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             aux = {**aux, "mix_out_ms": ms}
         x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
         return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
+
+
+def branch_block(x: jax.Array, w: Params, cfg: TransformerConfig,
+                 freqs: Optional[jax.Array], attn_fn: Callable,
+                 moe_fn: Optional[Callable], kind: str) -> Any:
+    """One layer of a ``cfg.one_branch`` model: ``x + f(N(x))``, ``f`` the
+    one branch ``kind`` names, a mixer alone ("ssm:none", "full:none",
+    "window:none": ``w["ssm"]`` / ``w["attn"]`` under ``attn``, as
+    :func:`transformer_block` runs them) or an FFN alone ("none:moe",
+    "none:dense": ``w["mlp"]`` under ``moe`` / ``mlp``), the layer's one norm
+    ``w["ln1"]`` with it. Returns ``(x, aux)``, ``aux`` a dict: the mean
+    square of the branch's output (``mix_out_ms``) and, of a routed layer,
+    what its experts report (the balance term under ``lb``, the router's
+    counts)."""
+    mixer, _, ffn = kind.partition(":")
+    scope = "attn" if mixer != "none" else "moe" if ffn == "moe" else "mlp"
+    wc = _cast_layers(w, jnp.dtype(cfg.dtype), scope)
+    aux: Dict[str, jax.Array] = {}
+    with jax.named_scope(scope), (jax.named_scope("attn_" + mixer)
+                                  if mixer in ("window", "full")
+                                  else contextlib.nullcontext()):
+        h = _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
+        if mixer == "ssm":
+            from deepspeed_tpu.models.mamba import ssm_block
+
+            out = ssm_block(h, wc["ssm"], cfg)
+        elif mixer != "none":
+            out = attention_block(h, wc["attn"], cfg, freqs, attn_fn)
+        elif ffn == "moe":
+            out, aux = moe_fn(h, wc["mlp"], cfg)
+            if not isinstance(aux, dict):
+                aux = {"lb": aux}
+        else:
+            out = mlp_block(h, wc["mlp"], cfg)
+        out = constrain(out, P(("dp", "fsdp"), "sp", None))
+        aux = {**aux, "mix_out_ms": jnp.mean(jnp.square(
+            out.astype(jnp.float32)))}
+        return constrain(x + out, P(("dp", "fsdp"), "sp", None)), aux
 
 
 def _maybe_remat(fn: Callable, policy: str) -> Callable:
@@ -1315,27 +1417,33 @@ def _stacked(trees: list):
     return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *trees)
 
 
+def _by_part(trees: list, join: Callable):
+    """A list of aux values as one, ``join`` over each leaf's list. Layers of
+    different kinds report different parts (a routed layer its router's
+    counts, a dense or a mixer layer none): each part then holds, in order,
+    the layers that report it."""
+    if (all(isinstance(a, dict) for a in trees)
+            and len({tuple(a) for a in trees}) > 1):
+        return {k: join([a[k] for a in trees if k in a])
+                for k in dict.fromkeys(k for a in trees for k in a)}
+    return jax.tree_util.tree_map(lambda *a: join(a), *trees)
+
+
 def _stack_blocks(ys: list, p: int):
     """One period's per-block outputs as the scan's output."""
-    return ys[0] if p == 1 else _stacked(ys)
+    return ys[0] if p == 1 else _by_part(ys, jnp.stack)
 
 
 def _by_layer(ys, p: int):
-    """A scan over periods' stacked outputs ``[periods, p, ...]`` back to
-    ``[layers, ...]``."""
+    """A scan over periods' stacked outputs ``[periods, blocks, ...]`` back
+    to ``[layers, ...]``."""
     return ys if p == 1 else jax.tree_util.tree_map(
-        lambda a: a.reshape((a.shape[0] * p,) + a.shape[2:]), ys)
+        lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), ys)
 
 
 def _join_runs(per_layer: list):
-    """The runs' per-layer aux values as one, run after run. Runs of
-    different FFN kinds report different parts (a dense layer has no
-    router): each part then holds the layers that report it."""
-    if (all(isinstance(a, dict) for a in per_layer)
-            and len({tuple(a) for a in per_layer}) > 1):
-        return {k: jnp.concatenate([a[k] for a in per_layer if k in a])
-                for k in dict.fromkeys(k for a in per_layer for k in a)}
-    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *per_layer)
+    """The runs' per-layer aux values as one, run after run."""
+    return _by_part(per_layer, jnp.concatenate)
 
 
 def _layer_aux(auxes):
@@ -1344,7 +1452,8 @@ def _layer_aux(auxes):
     its router's counts (a dict, a held share of the experts) that sum under
     ``lb`` and the rest by layer."""
     if isinstance(auxes, dict):
-        return {**auxes, "lb": jnp.sum(auxes["lb"])}
+        return {**auxes, "lb": jnp.sum(auxes["lb"])} if "lb" in auxes \
+            else auxes
     return jnp.sum(auxes)
 
 
@@ -1370,6 +1479,8 @@ class TransformerLM:
                 if cfg.use_rope else None)
         # the first layer's: the paths written for layers of one kind read it
         self._freqs = self._kinds[cfg.layer_kinds[0]][1]
+        # the jitted block of each kind (:meth:`_jitted_block`)
+        self._blocks: Dict[Any, Callable] = {}
         # random-LTD (data_routing/basic_layer.py parity): when set, layers in
         # [start, end) process only `keep` randomly chosen tokens per step;
         # dropped tokens ride the residual stream untouched. The engine owns
@@ -1490,8 +1601,8 @@ class TransformerLM:
             if batch_shape is not None:
                 rows, T = batch_shape
                 facts[f"{kind}_chunks_per_step"] = (
-                    cfg.layer_kinds.count(kind) * int(rows)
-                    * -(-int(T) // chunk))
+                    sum(k.partition(":")[0] == kind for k in cfg.layer_kinds)
+                    * int(rows) * -(-int(T) // chunk))
         if cfg.heads_held is not None:
             # (count, all) of the heads a mixer holds, where a share of them
             facts["heads_held"] = (cfg.heads_held, cfg.num_heads)
@@ -1583,12 +1694,13 @@ class TransformerLM:
         def layer_stack(key, fan_in, shape, n=L):
             return dense(key, fan_in, (n,) + shape)
 
+        kinds = cfg.layer_kinds
         norm_w = {"scale": jnp.ones((L, D), pd)}
         if cfg.norm == "layernorm":
             norm_w["bias"] = jnp.zeros((L, D), pd)
         # one stack of mixer leaves for each kind of mixer, a row for each
         # layer of that kind (every layer's, where all are attention)
-        La = _in_group(cfg.layer_kinds, "attn")
+        La = _in_group(kinds, "attn")
         attn_w = {
             "wq": layer_stack(keys[1], D, (D, H * hd), La),
             "wk": layer_stack(keys[2], D, (D, K * hd), La),
@@ -1604,9 +1716,11 @@ class TransformerLM:
         if cfg.qk_norm:
             attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
             attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
-        # the FFNs: one stack with a row a layer, or (first_k_dense) a dense
-        # stack and a routed one
-        Ld = cfg.first_k_dense if cfg.has_ffn_kinds else L
+        # the FFNs: one stack with a row a layer, or (first_k_dense,
+        # one_branch) a dense stack and a routed one, a row for each layer
+        # of the kind
+        Ld = _in_group(kinds, "mlp_dense") if cfg.has_ffn_kinds else L
+        Lm = _in_group(kinds, "mlp_moe") if cfg.has_ffn_kinds else L
         mlp = ({"w_gate": layer_stack(keys[4], D, (D, F), Ld),
                 "w_up": layer_stack(keys[5], D, (D, F), Ld),
                 "w_down": layer_stack(keys[6], F, (F, D), Ld)}
@@ -1614,22 +1728,22 @@ class TransformerLM:
                {"w_up": layer_stack(keys[5], D, (D, F), Ld),
                 "w_down": layer_stack(keys[6], F, (F, D), Ld)})
         if cfg.proj_bias and cfg.activation != "swiglu":
-            mlp["b_up"] = jnp.zeros((L, F), pd)
-            mlp["b_down"] = jnp.zeros((L, D), pd)
+            mlp["b_up"] = jnp.zeros((Ld, F), pd)
+            mlp["b_down"] = jnp.zeros((Ld, D), pd)
         layers: Params = {"ln1": dict(norm_w), "attn": attn_w}
-        if cfg.has_ffn_kinds:
+        if cfg.has_ffn_kinds and Ld:
             layers["mlp_dense"] = mlp
         if cfg.num_experts > 1:
-            # the experts held here at their own width; the router scores
-            # all of them
+            # the experts held here at their own width, reading the latent
+            # where there is one; the router scores all of them
             E, Eh = cfg.num_experts, cfg.moe_experts_held or cfg.num_experts
-            F, Lm = cfg.moe_intermediate_size or F, L - cfg.first_k_dense
-            mlp = ({"w_gate": layer_stack(keys[4], D, (Eh, D, F), Lm),
-                    "w_up": layer_stack(keys[5], D, (Eh, D, F), Lm),
-                    "w_down": layer_stack(keys[6], F, (Eh, F, D), Lm)}
+            F, Z = cfg.moe_intermediate_size or F, cfg.moe_latent_size or D
+            mlp = ({"w_gate": layer_stack(keys[4], Z, (Eh, Z, F), Lm),
+                    "w_up": layer_stack(keys[5], Z, (Eh, Z, F), Lm),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, Z), Lm)}
                    if cfg.activation == "swiglu" else
-                   {"w_up": layer_stack(keys[5], D, (Eh, D, F), Lm),
-                    "w_down": layer_stack(keys[6], F, (Eh, F, D), Lm)})
+                   {"w_up": layer_stack(keys[5], Z, (Eh, Z, F), Lm),
+                    "w_down": layer_stack(keys[6], F, (Eh, F, Z), Lm)})
             mlp["router"] = layer_stack(keys[7], D, (D, E), Lm)
             if cfg.moe_scoring == "sigmoid" or cfg.moe_shared_experts:
                 more = jax.random.split(jax.random.fold_in(rng, 13), 4)
@@ -1642,25 +1756,32 @@ class TransformerLM:
                     "w_gate": layer_stack(more[1], D, (D, Fs), Lm),
                     "w_up": layer_stack(more[2], D, (D, Fs), Lm),
                     "w_down": layer_stack(more[3], Fs, (Fs, D), Lm)}
-        layers["mlp_moe" if cfg.has_ffn_kinds else "mlp"] = mlp
+                if cfg.activation != "swiglu":
+                    del mlp["shared"]["w_gate"]
+            if cfg.moe_latent_size:
+                lat = jax.random.split(jax.random.fold_in(rng, 16), 2)
+                mlp["latent_down"] = layer_stack(lat[0], D, (D, Z), Lm)
+                mlp["latent_up"] = layer_stack(lat[1], Z, (Z, D), Lm)
+        if cfg.num_experts > 1 or not cfg.has_ffn_kinds:
+            layers["mlp_moe" if cfg.has_ffn_kinds else "mlp"] = mlp
         if cfg.has_ssm:
             from deepspeed_tpu.models import mamba
 
             layers["ssm"] = mamba.init(jax.random.fold_in(rng, 12), cfg,
-                                       _in_group(cfg.layer_kinds, "ssm"), pd)
+                                       _in_group(kinds, "ssm"), pd)
         if cfg.has_delta:
             from deepspeed_tpu.models import gated_delta
 
             layers["delta"] = gated_delta.init(
                 jax.random.fold_in(rng, 15), cfg,
-                _in_group(cfg.layer_kinds, "delta"), pd)
+                _in_group(kinds, "delta"), pd)
         if cfg.has_mla:
             from deepspeed_tpu.models import mla
 
             layers["mla"] = mla.init(jax.random.fold_in(rng, 14), cfg, L, pd)
         if not La:
             del layers["attn"]
-        if not cfg.parallel_shared_norm:
+        if not (cfg.parallel_shared_norm or cfg.one_branch):
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         if cfg.sandwich_norm or cfg.norm_placement == "post":
             layers["ln1_post"] = jax.tree_util.tree_map(jnp.copy, norm_w)
@@ -1726,7 +1847,11 @@ class TransformerLM:
         a period of one block; three window layers and a full one: four
         blocks traced whatever the depth); any other list (HF qwen2's leading
         run of full layers before the windowed ones) is cut into runs of one
-        kind. Each block runs under its kind's own static config
+        kind. Layers of one branch each (``cfg.one_branch``) are one scan
+        over periods of up to ``2 * _MAX_PERIOD`` of them whose body traces
+        one block a kind (:meth:`_run_periods`): Nemotron-H's eleven
+        alternating layers are three bodies. Each block runs under its
+        kind's own static config
         (``self._kinds``), so a window layer keeps the tile-skipping kernels
         and a full layer pays no window mask. A run reads its kind's own
         stack of mixer leaves (:func:`_segment`): nine state-space layers to
@@ -1737,6 +1862,9 @@ class TransformerLM:
         kinds = self.cfg.layer_kinds
         L = len(kinds)
         period = self.cfg.attn_pattern or kinds[:1]
+        if self.cfg.one_branch and len(period) <= 2 * _MAX_PERIOD:
+            # (the kinds as ``layer_kinds`` spells them: what a layer lacks)
+            return [(0, L, kinds[:len(period)])]
         if len(period) <= _MAX_PERIOD and not self.cfg.has_ffn_kinds:
             return [(0, L, period)]
         cuts = [0] + [i for i in range(1, L) if kinds[i] != kinds[i - 1]] + [L]
@@ -1910,9 +2038,14 @@ class TransformerLM:
         cfg = self.cfg
         per_layer = []
         for lo, hi, period in plan:
-            blocks = [_maybe_remat(
-                partial(self._kind_block, kind, attn_fn), cfg.remat_policy)
-                for kind in period]
+            # (layers of one branch each: one jitted block a kind, so that a
+            # kind's later layers in the period reuse the first one's trace,
+            # forward and backward)
+            blocks = [self._jitted_block(kind, attn_fn) if cfg.one_branch
+                      else _maybe_remat(
+                          partial(self._kind_block, kind, attn_fn),
+                          cfg.remat_policy)
+                      for kind in period]
             p = len(period)
             seg = _segment(layers, cfg.layer_kinds, lo, hi, period)
 
@@ -1935,9 +2068,22 @@ class TransformerLM:
             per_layer.append(_by_layer(auxes, p))
         return x, _layer_aux(_join_runs(per_layer))
 
+    def _jitted_block(self, kind: str, attn_fn: Callable) -> Callable:
+        """:meth:`_kind_block` of ``kind`` under the config's recomputation
+        policy as one jitted function, made once a model: every layer of the
+        kind calls the same one."""
+        key = (kind, attn_fn)
+        if key not in self._blocks:
+            self._blocks[key] = jax.jit(_maybe_remat(
+                partial(self._kind_block, kind, attn_fn),
+                self.cfg.remat_policy))
+        return self._blocks[key]
+
     def _kind_block(self, kind: str, attn_fn: Callable, x: jax.Array,
                     w: Params):
         ck, freqs = self._kinds[kind]
+        if ck.one_branch:
+            return branch_block(x, w, ck, freqs, attn_fn, self.moe_fn, kind)
         return transformer_block(
             x, w, ck, freqs, attn_fn, self.moe_fn, kind=kind,
             mix_ms=self.cfg.reports_mixer_outputs)
@@ -2006,7 +2152,8 @@ class TransformerLM:
                 if isinstance(aux, dict):
                     # a held share of the experts: the load-balance term and
                     # the router's counts go into the step record
-                    parts = {**parts, **_share_parts(aux)}
+                    if "expert_pairs" in aux:
+                        parts = {**parts, **_share_parts(aux)}
                     if cfg.moe_bias_rate and "router_counts" in parts:
                         # the biases the step's rule moves: an expert's
                         # whose count is not the layer's mean
@@ -2464,6 +2611,11 @@ class TransformerLM:
             mlp["shared"] = {"w_gate": P(None, None, "tp"),
                              "w_up": P(None, None, "tp"),
                              "w_down": P(None, "tp", None)}
+            if cfg.activation != "swiglu":
+                del mlp["shared"]["w_gate"]
+        if cfg.num_experts > 1 and cfg.moe_latent_size:
+            mlp["latent_down"] = P(None, None, None)
+            mlp["latent_up"] = P(None, None, None)
         attn_spec = {"wq": P(None, None, "tp"), "wk": P(None, None, "tp"),
                      "wv": P(None, None, "tp"), "wo": P(None, "tp", None)}
         if cfg.qkv_bias:
@@ -2482,6 +2634,11 @@ class TransformerLM:
             layer_specs["mlp_dense"] = {
                 "w_gate": P(None, None, "tp"), "w_up": P(None, None, "tp"),
                 "w_down": P(None, "tp", None)}
+            if cfg.activation != "swiglu":
+                del layer_specs["mlp_dense"]["w_gate"]
+            for grp in ("mlp_moe", "mlp_dense"):
+                if not _in_group(cfg.layer_kinds, grp):
+                    del layer_specs[grp]
         if cfg.has_ssm:
             from deepspeed_tpu.models import mamba
 
@@ -2496,7 +2653,7 @@ class TransformerLM:
             layer_specs["delta"] = gated_delta.param_specs()
         if not _in_group(cfg.layer_kinds, "attn"):
             del layer_specs["attn"]
-        if not cfg.parallel_shared_norm:
+        if not (cfg.parallel_shared_norm or cfg.one_branch):
             layer_specs["ln2"] = dict(norm_spec)
         if cfg.sandwich_norm or cfg.norm_placement == "post":
             layer_specs["ln1_post"] = dict(norm_spec)

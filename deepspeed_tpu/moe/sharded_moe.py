@@ -193,9 +193,27 @@ def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
             by_seq.sum(axis=0))
 
 
-def _shared_ffn(h: jax.Array, w: Dict[str, jax.Array]) -> jax.Array:
-    """The shared experts: one SwiGLU every token takes."""
-    return (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
+def _relu2(v: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(v))
+
+
+_gelu_tanh = functools.partial(jax.nn.gelu, approximate=True)
+
+
+def _ungated_act(cfg: Any):
+    """What an expert without a gate product applies between its two
+    products: ``relu(.)^2`` where the config says ``activation="relu2"``
+    (Nemotron-H), the tanh gelu otherwise, as before."""
+    return _relu2 if getattr(cfg, "activation", None) == "relu2" \
+        else _gelu_tanh
+
+
+def _shared_ffn(h: jax.Array, w: Dict[str, jax.Array], act) -> jax.Array:
+    """The shared experts, every token's: one SwiGLU, or without a gate
+    product two products round ``act``."""
+    if "w_gate" in w:
+        return (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
+    return act(h @ w["w_up"]) @ w["w_down"]
 
 
 def topk_gating(logits: jax.Array, k: int = 2, capacity_factor: float = 1.25,
@@ -307,7 +325,7 @@ def moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
 
 
 def _padded_ffn(xs: jax.Array, group_sizes: jax.Array,
-                w: Dict[str, jax.Array], dt) -> jax.Array:
+                w: Dict[str, jax.Array], dt, act=_gelu_tanh) -> jax.Array:
     """The pad-to-capacity einsum reference at ``capacity_factor=∞``:
     every expert padded to the FULL token count and computed with the
     same einsum chain as the capacity path. O(N·E) flops and an
@@ -327,9 +345,8 @@ def _padded_ffn(xs: jax.Array, group_sizes: jax.Array,
         act = act * jnp.einsum("end,edf->enf", xe,
                                _expert_weight(w, "w_up", dt))
     else:
-        act = jax.nn.gelu(jnp.einsum("end,edf->enf", xe,
-                                     _expert_weight(w, "w_up", dt)),
-                          approximate=True)
+        act = act(jnp.einsum("end,edf->enf", xe,
+                             _expert_weight(w, "w_up", dt)))
     ye = jnp.einsum("enf,efd->end", act,
                     _expert_weight(w, "w_down", dt))      # [E, N, D]
     return jnp.einsum("ne,end->nd", oh, ye)
@@ -358,7 +375,8 @@ def _ffn_lowering(rows: int, dtype, w: Dict[str, jax.Array], dt,
 def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
                  dt, kernel: str = "ragged", interpret: Optional[bool] = None,
                  rows_past_groups: bool = False, fetch=None,
-                 buffer_rows: Optional[int] = None) -> jax.Array:
+                 buffer_rows: Optional[int] = None,
+                 act=_gelu_tanh) -> jax.Array:
     """Expert-grouped FFN over tokens sorted by expert. ``kernel="ragged"``
     names the algebra: every row times its own expert's weights, no padding
     to a capacity. Its products take the lowering
@@ -371,7 +389,8 @@ def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
     other backends). ``"padded"`` is the capacity-einsum reference twin
     (:func:`_padded_ffn`) the engines fall back to when ragged_dot has no
     backend lowering. ``interpret`` is the kernels' test handle (None: ask
-    the backend).
+    the backend). Stacks without ``w_gate`` are experts of two products
+    round ``act`` (default the tanh gelu; :func:`_ungated_act`).
 
     ``rows_past_groups``: the groups may end before the rows do. No consumer
     reads such a row of the result (a pair's ``slot`` never names one), but
@@ -391,7 +410,7 @@ def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
     for a kernel)."""
     with jax.named_scope("moe_experts"):
         if kernel == "padded":
-            return _padded_ffn(xs, group_sizes, w, dt)
+            return _padded_ffn(xs, group_sizes, w, dt, act)
     # imported here: a model without experts never loads the kernels
     from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -422,7 +441,7 @@ def _grouped_ffn(xs: jax.Array, group_sizes: jax.Array, w: Dict[str, jax.Array],
             gate, up = firsts
             act = jax.nn.silu(gate) * up
         else:
-            act = jax.nn.gelu(firsts[0], approximate=True)
+            act = act(firsts[0])
         ys = product(act, stacks["w_down"])
         if rows_past_groups and took == "xla":
             held = jnp.arange(n_rows) < group_sizes.sum()
@@ -465,7 +484,13 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     returns the dict, with ``router_counts`` [E] beside the held experts'
     ``expert_pairs``; ``w["shared"]`` (the shared experts' SwiGLU) is added
     for every token under the scope ``moe_shared``, whole on every share:
-    summed over shares it counts once.
+    summed over shares it counts once. Experts without ``w_gate`` are two
+    products round :func:`_ungated_act`'s activation, routed and shared.
+    With ``w["latent_down"]`` / ``w["latent_up"]`` (LatentMoE) the tokens are
+    projected into the latent before the dispatch and the combined rows back
+    after it, under the scope ``moe_latent``: rows move and the routed
+    experts work at the latent's width, the router and the shared experts
+    read ``h``; with a held share the partial sum is projected up.
     Under ``ep > 1`` dispatch routes through ``_grouped_moe_ep`` — an explicit
     padded all-to-all over the ``ep`` axis feeding per-shard grouped GEMMs (the
     ``_AllToAll`` of reference ``moe/sharded_moe.py:97``, made dropless) —
@@ -479,6 +504,10 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     if (mesh is not None and not mesh.empty and "ep" in mesh.axis_names
             and mesh.shape["ep"] > 1
             and "ep" not in set(getattr(mesh, "manual_axes", ()) or ())):
+        if "latent_down" in w:
+            raise NotImplementedError(
+                "a latent round the routed experts (moe_latent_size) under "
+                "an ep axis: the exchange is written for full-width rows")
         return _grouped_moe_ep(h, w, cfg, mesh, valid, kernel=kernel,
                                a2a_bits=a2a_bits, a2a_slice=a2a_slice)
     B, T, D = h.shape
@@ -539,6 +568,13 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         jax.debug.callback(_emit_expert_counts, cnt)
 
     dt = h.dtype
+    act = _ungated_act(cfg)
+    xz = x.astype(dt)
+    if "latent_down" in w:
+        # LatentMoE: dispatch, the routed experts and combine run in the
+        # latent; the router above and the shared experts below read ``h``
+        with jax.named_scope("moe_latent"):
+            xz = xz @ w["latent_down"].astype(dt)
     how = _moves_lowering(S, bound, w, dt, kernel, interpret)
     with jax.named_scope("moe_dispatch"):
         moves = _row_moves(rows, group_sizes, n_here, S, k, how)
@@ -553,18 +589,21 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
 
     # [bound, D]; rows past n_here carry no pair and are never read
     if how[0] == "pallas":
-        ys = _grouped_ffn(x.astype(dt), group_sizes, w, dt, kernel,
+        ys = _grouped_ffn(xz, group_sizes, w, dt, kernel,
                           interpret=interpret, rows_past_groups=True,
-                          fetch=fetch, buffer_rows=bound)
+                          fetch=fetch, buffer_rows=bound, act=act)
     else:
-        ys = _grouped_ffn(fetch(x.astype(dt)), group_sizes, w, dt, kernel,
-                          rows_past_groups=True)
+        ys = _grouped_ffn(fetch(xz), group_sizes, w, dt, kernel,
+                          rows_past_groups=True, act=act)
     with jax.named_scope("moe_dispatch"):
         out = _weighted_sum_of_rows(ys, topk_vals, rows, slot, moves, how)
+    if "latent_up" in w:
+        with jax.named_scope("moe_latent"):
+            out = out @ w["latent_up"].astype(dt)
     out = out.reshape(B, T, D)
     if "shared" in w:
         with jax.named_scope("moe_shared"):
-            out = out + _shared_ffn(h, w["shared"])
+            out = out + _shared_ffn(h, w["shared"], act)
     if not held and not sigmoid:
         return out, aux_loss
     # what the step record carries of a share (models/transformer.py:

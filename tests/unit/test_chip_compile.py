@@ -810,11 +810,11 @@ def test_kernel_path_rules_match_what_compiled():
 
 
 def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
-                       parameters: int):
+                       parameters: int, seq: int = 4096):
     """A benchmark cell's whole step (``benchmarks/configs/<config>.json``
-    at 1 x 4096 tokens, the file's recomputation policy): gradient and AdamW
-    over fp32 master weights, compiled for the chip, every picker answering
-    as on a TPU."""
+    at 1 x ``seq`` tokens, the file's recomputation policy): gradient and
+    AdamW over fp32 master weights, compiled for the chip, every picker
+    answering as on a TPU."""
     import importlib
     import json
     import os
@@ -823,12 +823,13 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
 
     import deepspeed_tpu.ops as ops
     from deepspeed_tpu.models import TransformerLM
-    from deepspeed_tpu.ops import causal_conv, delta_rule, ssd_scan
+    from deepspeed_tpu.ops import (causal_conv, delta_rule, grouped_matmul,
+                                   ssd_scan)
     from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.runtime.optimizers import build_optimizer
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    for module in (fa, delta_rule, ssd_scan, causal_conv):
+    for module in (fa, delta_rule, ssd_scan, causal_conv, grouped_matmul):
         monkeypatch.setattr(module, "_on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -837,7 +838,7 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
         cfg = json.load(f)
     model = TransformerLM(importlib.import_module(
         "benchmarks." + modelcfg).transformer_config(
-            cfg, max_seq_len=4096, param_dtype="float32"))
+            cfg, max_seq_len=seq, param_dtype="float32"))
     tx = build_optimizer("adamw", {"lr": 1e-6}, lr_schedule=None,
                          gradient_clipping=0.0)
 
@@ -857,7 +858,7 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
         == parameters
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(params), described(jax.eval_shape(tx.init, params)),
-        {"input_ids": jax.ShapeDtypeStruct((1, 4096), jnp.int32,
+        {"input_ids": jax.ShapeDtypeStruct((1, seq), jnp.int32,
                                            sharding=one_chip)}).compile()
     mem = compiled.memory_analysis()
     # fp32 weights, Adam m and v: 12 B a parameter as arguments
@@ -933,3 +934,39 @@ def test_the_olmo_hybrid_cells_step_program_compiles_for_v5e(one_chip,
     assert not any("rematted_computation" in n for n in calls)
     assert not any("conv_" in n for n in calls)
     _conv_kernels_alone_under(text, "delta_conv", forwards=18, backwards=9)
+
+
+def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    nemotron3_super_120b_train_d11h16e8v8.json``: one period of 11 one-branch
+    layers at the published widths, 1 x 8192 tokens, no recomputation). It
+    fits beside what a chip reserves; each of the five expert layers runs
+    the grouped products (two forward, two ``gmm`` and two ``tgmm``
+    backward: an expert is two products) and the row kernels at a latent of
+    1024 and 22 pairs a token under ``moe``, each of the five Mamba layers
+    the scan's and the convolution's kernels at chunk 128 and one group
+    under ``attn``, and the attention layer the flash kernels under
+    ``attn/attn_full``, whose instructions keep the names the benchmark's
+    patterns look for."""
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "nemotron3_super_120b_train_d11h16e8v8",
+        "modelcfg_nemotron_h", 700_865_520, seq=8192)
+    assert mem.temp_size_in_bytes < 6.4e9
+    experts = _kernel_calls(text, "moe_experts")
+    assert sum("jit(gmm)" in n for n in experts) == 5 * 4
+    assert sum("jit(tgmm)" in n for n in experts) == 5 * 2
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert sum("jit(sum_of_rows)" in n for n in moves) == 5 * 2
+    assert sum("jit(rows_of_tokens)" in n for n in moves) == 5 * 3
+    assert all("/moe/" in n for n in experts + moves)
+    scan = _kernel_calls(text, "ssm_scan")
+    assert sum("jit(ssd_fwd)" in n for n in scan) == 5
+    assert sum("jit(ssd_bwd)" in n for n in scan) == 5
+    conv = _kernel_calls(text, "ssm_conv")
+    assert sum("jit(conv_fwd)" in n for n in conv) == 5
+    assert sum("jit(conv_bwd)" in n for n in conv) == 5
+    assert len(_kernel_calls(text, "attn_full")) == 2
+    assert len(re.findall(r"^\s*%attn_full[.\d]* = .*custom-call\(.*"
+                          r"tpu_custom_call", text, re.M)) == 2
+    assert "/moe/moe_latent/" in text and "ragged-dot" not in text

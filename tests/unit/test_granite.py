@@ -482,8 +482,10 @@ def test_every_other_path_refuses_a_state_space_layer():
     with pytest.raises(NotImplementedError, **refused):
         jax.eval_shape(lambda p: model.forward_prefill(
             p, ROWS, jnp.asarray([24, 24])), params)
+    # (routed experts beside state-space layers run since PR 46:
+    # tests/unit/test_nemotron_h.py)
     for bad in (dict(loss_tiling=4), dict(attention_impl="fpdt"),
-                dict(num_passes=2), dict(num_experts=4)):
+                dict(num_passes=2), dict(parallel_block=True)):
         with pytest.raises(NotImplementedError, match="state-space layers"):
             model_for(hf_config(), **bad)
     with pytest.raises(ValueError, match="ssm_heads"):
