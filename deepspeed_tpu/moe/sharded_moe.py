@@ -131,6 +131,36 @@ def _emit_expert_counts(counts) -> None:
         t.observe(counts)
 
 
+def _hot(idx: jax.Array, n: int) -> jax.Array:
+    """``idx`` [...] against ``arange(n)``: bool [..., n], true where the
+    index names the column (an index outside ``0 .. n - 1`` names none)."""
+    return idx[..., None] == jnp.arange(n, dtype=idx.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pick(s: jax.Array, idx: jax.Array, E: int) -> jax.Array:
+    """``jnp.take_along_axis(s, idx, axis=-1)`` over ``s`` [..., E] and ``idx``
+    [..., k], to the bit, without a gather: a compare of ``idx`` against the
+    experts, a select and a sum along them, in which one term is ``s``'s and
+    the others are zeros (a v5e takes 9 ns a gathered scalar: 1.8 ms for
+    8192 tokens' 22 picks, where this is one pass over ``s``). The
+    derivative is the same compare summed along ``k`` (each expert gets the
+    cotangent of the one pick that named it, in place of a scatter-add), and
+    what is kept for it is ``idx``, never the one-hot [..., k, E]."""
+    return jnp.where(_hot(idx, E), s[..., None, :], 0).sum(axis=-1)
+
+
+def _pick_fwd(s, idx, E):
+    return _pick(s, idx, E), idx
+
+
+def _pick_bwd(E, idx, g):
+    return jnp.where(_hot(idx, E), g[..., None], 0).sum(axis=-2), None
+
+
+_pick.defvjp(_pick_fwd, _pick_bwd)
+
+
 def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
            noise_std: float = 0.0, valid: Optional[jax.Array] = None,
            psum_axis: Optional[str] = None):
@@ -160,7 +190,10 @@ def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
         cnt = jax.lax.psum(cnt, psum_axis)
     denom = jnp.maximum(cnt, 1.0)
     aux_loss = jnp.sum(g_sum * m_sum) / (denom * denom) * E
-    topk_vals, topk_idx = jax.lax.top_k(gates, k)  # [S, k]
+    # the chosen gates by :func:`_pick`, the values ``top_k`` gives to the
+    # bit: the transpose of ``top_k``'s own is a scatter-add of S k scalars
+    _, topk_idx = jax.lax.top_k(gates, k)  # [S, k]
+    topk_vals = _pick(gates, topk_idx, E)
     # renormalize the kept gate mass (reference normalizes combine weights)
     topk_vals = topk_vals / jnp.maximum(topk_vals.sum(-1, keepdims=True), 1e-9)
     if valid is not None:
@@ -178,19 +211,89 @@ def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
     P_i``, ``f_i = E / (k T) x`` the sequence's pairs of expert i (the chosen
     pairs, bias included: a constant), ``P_i`` the sequence's mean of ``s_i /
     sum_j s_j``, averaged over the sequences; and the pairs each expert
-    received from all of them."""
+    received from all of them. The chosen scores (:func:`_pick`) and the
+    counts both come from compares of the chosen experts against
+    ``arange(E)``: no gather of a scalar a pair, no scatter-add behind it."""
     B, T, E = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)   # [B, T, k]
-    picked = jnp.take_along_axis(s, idx, axis=-1)
+    picked = _pick(s, idx, E)
     weights = scale * picked / picked.sum(-1, keepdims=True)
-    by_seq = (idx[..., None] == jnp.arange(E, dtype=idx.dtype)).sum(
-        axis=(1, 2), dtype=jnp.int32)                         # [B, E]
+    by_seq = _hot(idx, E).sum(axis=(1, 2), dtype=jnp.int32)   # [B, E]
     f = by_seq.astype(jnp.float32) * (E / (k * T))
     p = (s / s.sum(-1, keepdims=True)).mean(axis=1)           # [B, E]
     aux_loss = (f * p).sum(-1).mean()
     return (aux_loss, weights.reshape(B * T, k), idx.reshape(B * T, k),
             by_seq.sum(axis=0))
+
+
+# the running count's chunk of rows
+_RUN = 128
+
+
+def _count_before(hot: jax.Array) -> jax.Array:
+    """The exclusive running sum down the rows of ``hot`` [S, G] (int, 0 or
+    1): ``out[t, g]`` counts the ones of column ``g`` in rows before ``t``.
+    Two products with a triangle of ones, within chunks of ``_RUN`` rows and
+    over the chunks' totals, because XLA's ``cumsum`` is a ``reduce-window``
+    on a TPU (PERF.md section 6, PR 38). Float32 at the highest precision:
+    the operands are 0, 1 and a chunk's total, whole numbers under 2**8,
+    which every piece the MXU splits a float32 into holds exactly, and the
+    sums are float32, exact under 2**24 rows."""
+    S, G = hot.shape
+    n = -(-S // _RUN)
+    x = jnp.pad(hot, ((0, n * _RUN - S), (0, 0))).reshape(n, _RUN, G) \
+        .astype(jnp.float32)
+
+    def earlier(m):                       # [from, to]: 1 where from < to
+        i = jnp.arange(m, dtype=jnp.int32)
+        return (i[:, None] < i[None, :]).astype(jnp.float32)
+
+    exact = jax.lax.Precision.HIGHEST
+    within = jnp.einsum("ncg,cd->ndg", x, earlier(_RUN), precision=exact)
+    chunks = jnp.einsum("ng,nm->mg", x.sum(axis=1), earlier(n),
+                        precision=exact)
+    return (within + chunks[:, None, :]).astype(jnp.int32) \
+        .reshape(n * _RUN, G)[:S]
+
+
+def _placement(topk_idx: jax.Array, first: int, n_held: int, bound: int):
+    """Where the (token, expert) pairs of ``topk_idx`` [S, k] go in the
+    buffer of ``bound`` rows sorted by expert, for the ``n_held`` experts from
+    ``first`` on. Returns ``(rows [bound], slot [S, k], group_sizes [held],
+    n_here, counts [held])``: the pair ``t * k + j`` in each row, each pair's
+    row (``bound``, a row that reads as zeros, for a pair whose expert is
+    absent or that the buffer had no room for), the rows each held expert
+    has, their sum, and the pairs each held expert received (``counts -
+    group_sizes`` did not fit).
+
+    Everything but ``rows`` comes from one compare of the pairs' experts
+    against ``arange(n_held)``, [S, k, held]: summed along ``k`` it says which
+    tokens chose which held expert (a token picks an expert at most once),
+    summed down the tokens the counts, and its running count down the tokens
+    (:func:`_count_before`) a pair's place in its expert's group, since the
+    sort is stable and a group's pairs lie in token order: ``slot = start[e]
+    + (pairs of e from earlier tokens)``, read back at the pair by the same
+    compare. That is the inverse permutation the ``argsort`` would need a
+    scatter of S k elements for, and the counts a ``bincount`` (a
+    scatter-add) gave: a v5e runs either at 9 ns an element. ``rows`` is
+    the sort itself, kept (``jnp.argsort``, stable)."""
+    S, k = topk_idx.shape
+    local = topk_idx - first
+    hot = _hot(local, n_held)                                  # [S, k, held]
+    chose = hot.sum(axis=1, dtype=jnp.int32)                   # [S, held]
+    counts = chose.sum(axis=0, dtype=jnp.int32)
+    total = jnp.cumsum(counts)
+    ends = jnp.minimum(total, bound)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    n_here = ends[-1]                             # rows that carry a pair
+    place = (total - counts) + _count_before(chose)            # [S, held]
+    rank = jnp.where(hot, place[:, None, :], 0).sum(axis=-1)   # [S, k]
+    here = hot.any(axis=-1)
+    slot = jnp.where(here & (rank < n_here), rank, bound)
+    key = jnp.where(here, local, n_held).reshape(-1)           # absent: last
+    rows = jnp.argsort(key)[:bound]               # the pair in each row
+    return rows, slot, group_sizes, n_here, counts
 
 
 def _relu2(v: jax.Array) -> jax.Array:
@@ -467,6 +570,12 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     Unlike the capacity path, every (token, expert) pair is computed — no
     ``capacity_factor`` padding waste and no dropped tokens — at the price of
     data-dependent group sizes (static TOTAL shape ``S*k``, so it still jits).
+    Which row of the buffer a pair takes, the rows each expert has and the
+    pairs it received are the router's index work (:func:`_placement`, under
+    the scope ``moe_router``): compares of the chosen experts against the
+    held ones, summed and counted down the tokens; only the pair of each row
+    (``rows``) is a sort, and nothing there gathers or scatters a scalar a
+    pair.
     Dispatch (tokens into the buffer of sorted pairs) and combine (each
     token's weighted sum of its pairs' rows) are one-to-one moves, gathers
     both ways; they take the lowering the FFN's products take
@@ -543,26 +652,12 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
             _gates, aux_loss, topk_vals, topk_idx = _route(
                 logits, k,
                 valid=None if valid is None else valid.reshape(-1))
-        flat_expert = topk_idx.reshape(-1)                    # [S*k]
-        local = flat_expert - first
-        here = (local >= 0) & (local < n_held)
-        key = jnp.where(here, local, n_held)                  # absent: last
-        order = jnp.argsort(key)                              # group by expert
-        counts = jnp.bincount(key, length=n_held + 1)[:n_held] \
-            .astype(jnp.int32)                    # pairs each held expert got
-        ends = jnp.minimum(jnp.cumsum(counts), bound)
-        group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
-        n_here = ends[-1]                         # rows that carry a pair
-        rows = order[:bound]                      # the pair in each row
-        rank = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32))
-        # each pair's row, ``bound`` (no row: it reads as zeros) for a pair
-        # whose expert is absent or that the buffer had no room for
-        slot = jnp.where(rank < n_here, rank, bound).reshape(S, k)
+        rows, slot, group_sizes, n_here, counts = _placement(
+            topk_idx, first, n_held, bound)
     if _TRACKER is not None:
         real = (jnp.ones((S,), bool) if valid is None
                 else valid.reshape(-1))
-        cnt = jnp.sum(jax.nn.one_hot(flat_expert, E, dtype=jnp.int32)
+        cnt = jnp.sum(jax.nn.one_hot(topk_idx.reshape(-1), E, dtype=jnp.int32)
                       * jnp.repeat(real, k)[:, None].astype(jnp.int32),
                       axis=0)
         jax.debug.callback(_emit_expert_counts, cnt)

@@ -747,35 +747,75 @@ def test_the_expert_layers_row_kernels_compile_for_v5e(one_chip, name):
         assert "u32[%d,1,1152]{2,1,0:T(1,128)}" % args[0].shape[0] in text
 
 
+def _index_ops_under(text, scope):
+    """The ``gather`` and ``scatter`` instructions of a compiled program that
+    belong to ``scope``: by their own ``op_name`` or, where they carry none
+    (the transpose of a gather keeps its scope only on its operands'
+    reshapes), by that of any instruction of their fused computation or of
+    the fusion that calls it."""
+    comps = re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)",
+                     text)
+    found = []
+    for comp in comps:
+        ops = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = \S+ "
+                         r"(?:gather|scatter)\((.*)$", comp, re.M)
+        if not ops:
+            continue
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", comp).group(1)
+        around = re.findall(r'op_name="([^"]*)"', comp)
+        around += re.findall(r"calls=%?" + re.escape(name)
+                             + r'\b[^\n]*op_name="([^"]*)"', text)
+        for op, rest in ops:
+            own = re.findall(r'op_name="([^"]*)"', rest)
+            if any(scope in path for path in own or around):
+                found.append(op)
+    return found
+
+
+# an expert layer at a cell's shapes: tokens (2 x 8192), width, experts'
+# width, experts, held, a token's picks, the buffer's factor, sigmoid scores
+CELL_LAYERS = {
+    "mellum2": (16384, 2304, 896, 64, 16, 8, 2.0, False),
+    "kanana2": (16384, 2048, 768, 128, 16, 6, 2.0, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LAYERS))
 def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
-                                                         monkeypatch):
+                                                         monkeypatch, cell):
     """A cell-shaped expert layer, forward and backward, as if on the chip:
     dispatch, combine and their backwards are row kernels (with the backward's
     second fetch of the rows, five of them, and four packings) and no gather
-    of rows is left."""
+    of rows is left; and the router (softmax in Mellum2, sigmoid scores with a
+    selection bias in Kanana-2) holds no gather and no scatter: the chosen
+    scores, the counts and each pair's row are compares and sums."""
     from deepspeed_tpu.moe import sharded_moe as sm
     from deepspeed_tpu.ops import grouped_matmul as gm
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    S, D, F, E, held, k, factor, sigmoid = CELL_LAYERS[cell]
 
     class Share:
-        top_k = 8
+        top_k = k
         moe_kernel = "ragged"
-        moe_experts_held = 16
+        moe_experts_held = held
         moe_first_expert = 0
-        moe_ep_capacity_factor = 2.0
-
-    S, D, F, E, held = 16384, 2304, 896, 64, 16
+        moe_ep_capacity_factor = factor
+        moe_scoring = "sigmoid" if sigmoid else "softmax"
+        moe_routed_scale = 2.448
 
     def loss(h, w):
-        out, _ = sm.grouped_moe_mlp_block(h, w, Share, kernel="ragged")
-        return (out.astype(jnp.float32) ** 2).sum()
+        out, aux = sm.grouped_moe_mlp_block(h, w, Share, kernel="ragged")
+        lb = aux["lb"] if isinstance(aux, dict) else aux
+        return (out.astype(jnp.float32) ** 2).sum() + lb
 
     def arg(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     w = {"router": arg((D, E)), "w_gate": arg((held, D, F)),
          "w_up": arg((held, D, F)), "w_down": arg((held, F, D))}
+    if sigmoid:
+        w["router_bias"] = arg((E,))
     before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         arg((2, S // 2, D), jnp.bfloat16), w).compile().as_text()
@@ -786,7 +826,49 @@ def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
                        "tgmm")}
     assert by == {"pack_rows": 4, "rows_of_tokens": 3, "sum_of_rows": 2,
                   "gmm": 5, "tgmm": 3}
-    assert not re.search(r"bf16\[(65536|16384),2304\]\S* gather\(", text)
+    assert not re.search(r"bf16\[(65536|16384),%d\]\S* gather\(" % D, text)
+    assert "moe_router" in text
+    assert _index_ops_under(text, "moe_router") == []
+    # the combine's backward still gathers a scalar a pair (``dot[slot]``):
+    # the check above sees such an op where its scope has one
+    assert _index_ops_under(text, "moe_dispatch")
+
+
+def test_an_expert_layer_keeps_no_one_hot_of_the_picks():
+    """What a Nemotron-sized expert layer (8192 tokens, 22 of 512 experts a
+    token, 8 held, sigmoid scores) keeps from its forward for its backward
+    holds no array of tokens x picks x experts elements: the pick's
+    derivative compares ``idx`` again (92 MB a layer as booleans otherwise,
+    under the cell's ``none``). Traced only: nothing runs."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    S, D, Z, F, E, held, k = 8192, 4096, 1024, 2688, 512, 8, 22
+
+    class Share:
+        top_k = k
+        moe_kernel = "ragged"
+        moe_experts_held = held
+        moe_first_expert = 0
+        moe_ep_capacity_factor = 8.0
+        moe_scoring = "sigmoid"
+        moe_routed_scale = 5.0
+        activation = "relu2"
+
+    def layer(h, w):
+        out, aux = sm.grouped_moe_mlp_block(h, w, Share, kernel="ragged")
+        return out, aux["lb"]
+
+    def arg(*shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    w = {"router": arg(D, E), "router_bias": arg(E),
+         "latent_down": arg(D, Z), "latent_up": arg(Z, D),
+         "w_up": arg(held, Z, F), "w_down": arg(held, F, Z)}
+    kept = jax.tree_util.tree_leaves(jax.eval_shape(
+        lambda h, w: jax.vjp(layer, h, w)[1], arg(1, S, D, dt=jnp.bfloat16),
+        w))
+    assert kept and max(x.size for x in kept) < S * k * E
+    assert any(x.size == S * E for x in kept)        # the scores are there
 
 
 def test_kernel_path_rules_match_what_compiled():
@@ -948,7 +1030,8 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     the scan's and the convolution's kernels at chunk 128 and one group
     under ``attn``, and the attention layer the flash kernels under
     ``attn/attn_full``, whose instructions keep the names the benchmark's
-    patterns look for."""
+    patterns look for; under ``moe_router`` nothing is gathered or
+    scattered."""
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "nemotron3_super_120b_train_d11h16e8v8",
         "modelcfg_nemotron_h", 700_865_520, seq=8192)
@@ -970,3 +1053,6 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     assert len(re.findall(r"^\s*%attn_full[.\d]* = .*custom-call\(.*"
                           r"tpu_custom_call", text, re.M)) == 2
     assert "/moe/moe_latent/" in text and "ragged-dot" not in text
+    # the router's chosen scores, counts and rows: no gather, no scatter
+    assert "/moe/moe_router/" in text
+    assert _index_ops_under(text, "moe_router") == []

@@ -438,3 +438,194 @@ class TestDroplessKernels:
         assert set(MOE_KERNELS) == {"ragged", "padded"}
         mode, why = moe_kernel_support()
         assert mode in (None, "native") and why
+
+
+# ---------------------------------------------------------------------------
+# the grouped dispatch's router: chosen scores, counts and each pair's row
+# from compares against arange(experts), held to the gather / bincount /
+# scatter forms they replaced (kept here as the references)
+# ---------------------------------------------------------------------------
+
+def _placement_by_sort(topk_idx, first, n_held, bound):
+    """The placement as it was: ``argsort``, ``bincount``, the rank scatter."""
+    S, k = topk_idx.shape
+    n = S * k
+    local = topk_idx.reshape(-1) - first
+    here = (local >= 0) & (local < n_held)
+    key = jnp.where(here, local, n_held)
+    order = jnp.argsort(key)
+    counts = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(counts), bound)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    n_here = ends[-1]
+    rows = order[:bound]
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    slot = jnp.where(rank < n_here, rank, bound).reshape(S, k)
+    return rows, slot, group_sizes, n_here, counts
+
+
+def _random_picks(seed, S, E, k):
+    """[S, k] distinct experts a token, as a top k gives them."""
+    scores = jax.random.uniform(jax.random.key(seed), (S, E))
+    return jax.lax.top_k(scores, k)[1]
+
+
+def _softmax_picks_under_a_mask(seed, S, E, k):
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    logits = jax.random.normal(jax.random.key(seed), (S, E))
+    valid = jax.random.bernoulli(jax.random.key(seed + 1), 0.6, (S,))
+    return sm._route(logits, k, valid=valid)[3]
+
+
+# name: (picks [S, k], experts, first, held, rows of the buffer)
+PLACEMENTS = {
+    "every-expert-held": lambda: (_random_picks(0, 64, 8, 2), 8, 0, 8, 128),
+    "a-share-from-the-third-on": lambda: (_random_picks(1, 96, 16, 3), 16,
+                                          2, 4, 288),
+    "the-last-experts-held": lambda: (_random_picks(2, 40, 16, 4), 16, 12,
+                                      4, 160),
+    "a-buffer-of-twice-the-balanced-load": lambda: (
+        _random_picks(3, 256, 16, 3), 16, 4, 4, 384),
+    "a-buffer-smaller-than-the-pairs-here": lambda: (
+        _random_picks(4, 64, 4, 2), 4, 1, 2, 24),
+    "every-pair-to-one-held-expert": lambda: (
+        jnp.tile(jnp.array([[5, 9]], jnp.int32), (48, 1)), 16, 4, 4, 96),
+    "every-token-to-one-expert-and-a-small-buffer": lambda: (
+        jnp.tile(jnp.array([[5, 9]], jnp.int32), (48, 1)), 16, 4, 4, 20),
+    "no-pair-here": lambda: (
+        jnp.tile(jnp.array([[0, 1, 2]], jnp.int32), (32, 1)), 16, 8, 8, 96),
+    "rows-that-fill-no-chunk": lambda: (_random_picks(5, 200, 8, 2), 8, 0,
+                                        8, 400),
+    "several-chunks-and-a-share": lambda: (_random_picks(6, 520, 32, 6), 32,
+                                           8, 8, 1000),
+    "four-decode-rows": lambda: (_random_picks(7, 4, 8, 2), 8, 0, 8, 8),
+    "one-pick-a-token": lambda: (_random_picks(8, 130, 4, 1), 4, 0, 4, 130),
+    "softmax-router-under-a-valid-mask": lambda: (
+        _softmax_picks_under_a_mask(9, 72, 8, 2), 8, 0, 8, 144),
+    "softmax-router-under-a-mask-a-share-and-a-small-buffer": lambda: (
+        _softmax_picks_under_a_mask(11, 72, 8, 2), 8, 2, 4, 60),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLACEMENTS))
+def test_placement_by_compares_is_the_sorts(name):
+    """``rows`` up to the last pair, ``slot``, ``group_sizes``, ``n_here`` and
+    ``counts`` of :func:`sharded_moe._placement` (compares against
+    ``arange(held)``, a running count) equal the ``argsort`` / ``bincount`` /
+    rank-scatter form's, element for element."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    idx, E, first, held, bound = PLACEMENTS[name]()
+    idx = idx.astype(jnp.int32)
+    assert idx.max() < E
+    got = jax.jit(sm._placement, static_argnums=(1, 2, 3))(
+        idx, first, held, bound)
+    want = _placement_by_sort(idx, first, held, bound)
+    n_here = int(want[3])
+    assert int(got[3]) == n_here
+    np.testing.assert_array_equal(got[0][:n_here], want[0][:n_here])
+    for g, w_ in zip(got[1:], want[1:]):
+        assert g.dtype == w_.dtype and g.shape == w_.shape
+        np.testing.assert_array_equal(g, w_)
+    dropped = int(want[4].sum()) - n_here
+    assert (dropped > 0) == ("small" in name)
+    if name == "no-pair-here":
+        assert n_here == 0 and (np.asarray(got[1]) == bound).all()
+
+
+def test_the_running_count_is_exact_at_a_cells_length():
+    """16,384 tokens of which every one chose the same expert: the count
+    before the last is 16,383 (bf16 operands, float32 sums)."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    hot = jnp.stack([jnp.ones((16384,), jnp.int32),
+                     jnp.arange(16384, dtype=jnp.int32) % 3 == 0], axis=1)
+    got = np.asarray(sm._count_before(hot.astype(jnp.int32)))
+    want = np.cumsum(np.asarray(hot, np.int64), axis=0) - np.asarray(hot)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_sigmoid_routers_pick_is_the_gathers_to_the_bit(ties, monkeypatch):
+    """Weights, experts, counts, the balance term and ``jax.grad`` with
+    respect to the logits of :func:`sharded_moe._route_sigmoid` equal those
+    of the router as it was, its chosen scores by ``take_along_axis`` (whose
+    transpose is a scatter-add), bit for bit, ties in ``s + bias`` included
+    (logits and biases on a coarse grid: many equal scores a token)."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    B, T, E, k = 2, 24, 16, 5
+    logits = jax.random.normal(jax.random.key(0), (B, T, E))
+    bias = 0.1 * jax.random.normal(jax.random.key(1), (E,))
+    if ties:
+        logits = jnp.round(logits)
+        bias = jnp.zeros_like(bias)
+    r = jax.random.normal(jax.random.key(2), (B * T, k))
+
+    def objective(logits):
+        aux, weights, idx, counts = sm._route_sigmoid(logits, bias, k, 2.5)
+        return (weights * r).sum() + 3.0 * aux, (weights, idx, counts, aux)
+
+    got = jax.value_and_grad(objective, has_aux=True)(logits)
+    monkeypatch.setattr(sm, "_pick", lambda s, idx, E: jnp.take_along_axis(
+        s, idx, axis=-1))
+    want = jax.value_and_grad(objective, has_aux=True)(logits)
+    if ties:    # the grid makes equal scores inside a token's top k + 1
+        s = np.asarray(jax.nn.sigmoid(logits))
+        assert any(len(np.unique(row)) < E - k for row in s.reshape(-1, E))
+    for g, w_ in zip(jax.tree_util.tree_leaves(got),
+                     jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w_.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
+    assert np.abs(np.asarray(got[1])).sum() > 0
+
+
+def test_the_pick_keeps_the_experts_and_not_the_one_hot():
+    """What the pick's derivative keeps from the forward is ``idx``: no
+    array of tokens x k x experts elements is a residual (92 MB a layer as
+    booleans at the Nemotron cell's size, under no recomputation)."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    T, E, k = 32, 16, 4
+    s = jax.random.uniform(jax.random.key(0), (T, E))
+    idx = _random_picks(1, T, E, k)
+    _, vjp = jax.vjp(lambda s: sm._pick(s, idx, E), s)
+    kept = [x.shape for x in jax.tree_util.tree_leaves(vjp)
+            if hasattr(x, "shape")]
+    assert all(np.prod(shape) < T * k * E for shape in kept), kept
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_softmax_routers_chosen_gates_are_top_ks_to_the_bit(masked):
+    """The renormalised weights of :func:`sharded_moe._route` and their
+    gradient with respect to the logits equal those taken from
+    ``lax.top_k``'s own values (whose transpose is a scatter-add of a scalar
+    a pair), bit for bit, with and without a ``valid`` mask."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    S, E, k = 40, 8, 3
+    logits = jax.random.normal(jax.random.key(0), (S, E))
+    valid = jax.random.bernoulli(jax.random.key(1), 0.7, (S,)) \
+        if masked else None
+    r = jax.random.normal(jax.random.key(2), (S, k))
+
+    def by_top_k(logits):
+        gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        vals, idx = jax.lax.top_k(gates, k)
+        vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
+        if valid is not None:
+            vals = vals * valid[:, None].astype(vals.dtype)
+        return (vals * r).sum(), (vals, idx)
+
+    def by_compares(logits):
+        _, _, vals, idx = sm._route(logits, k, valid=valid)
+        return (vals * r).sum(), (vals, idx)
+
+    got, want = (jax.value_and_grad(f, has_aux=True)(logits)
+                 for f in (by_compares, by_top_k))
+    for g, w_ in zip(jax.tree_util.tree_leaves(got),
+                     jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_))
+    assert np.abs(np.asarray(got[1])).sum() > 0
