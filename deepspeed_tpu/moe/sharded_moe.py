@@ -229,6 +229,8 @@ def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
 
 # the running count's chunk of rows
 _RUN = 128
+# (held experts + 1) x pairs up to which an expert and a pair are one int32 key
+_MOST_KEYS = 2 ** 31 - 1
 
 
 def _count_before(hot: jax.Array) -> jax.Array:
@@ -257,17 +259,20 @@ def _count_before(hot: jax.Array) -> jax.Array:
         .reshape(n * _RUN, G)[:S]
 
 
-def _placement(topk_idx: jax.Array, first: int, n_held: int, bound: int):
+def _placement(topk_idx: jax.Array, weights: jax.Array, first: int,
+               n_held: int, bound: int):
     """Where the (token, expert) pairs of ``topk_idx`` [S, k] go in the
     buffer of ``bound`` rows sorted by expert, for the ``n_held`` experts from
-    ``first`` on. Returns ``(rows [bound], slot [S, k], group_sizes [held],
-    n_here, counts [held])``: the pair ``t * k + j`` in each row, each pair's
-    row (``bound``, a row that reads as zeros, for a pair whose expert is
-    absent or that the buffer had no room for), the rows each held expert
-    has, their sum, and the pairs each held expert received (``counts -
-    group_sizes`` did not fit).
+    ``first`` on. Returns ``(order [S k], row_weight [bound], slot [S, k],
+    group_sizes [held], n_here, counts [held])``: the pairs ``t * k + j`` in
+    sorted order, of which the first ``bound`` are the pair in each row
+    (``rows``); the weight ``weights[t, j]`` of each row's pair (a constant:
+    no gradient goes through it); each pair's row (``bound``, a row that
+    reads as zeros, for a pair whose expert is absent or that the buffer had
+    no room for), the rows each held expert has, their sum, and the pairs
+    each held expert received (``counts - group_sizes`` did not fit).
 
-    Everything but ``rows`` comes from one compare of the pairs' experts
+    Everything but the sort comes from one compare of the pairs' experts
     against ``arange(n_held)``, [S, k, held]: summed along ``k`` it says which
     tokens chose which held expert (a token picks an expert at most once),
     summed down the tokens the counts, and its running count down the tokens
@@ -276,8 +281,11 @@ def _placement(topk_idx: jax.Array, first: int, n_held: int, bound: int):
     + (pairs of e from earlier tokens)``, read back at the pair by the same
     compare. That is the inverse permutation the ``argsort`` would need a
     scatter of S k elements for, and the counts a ``bincount`` (a
-    scatter-add) gave: a v5e runs either at 9 ns an element. ``rows`` is
-    the sort itself, kept (``jnp.argsort``, stable)."""
+    scatter-add) gave: a v5e runs either at 9 ns an element. The sort
+    itself is kept (``jnp.argsort``'s order: by expert, stable), and the
+    pairs' weights ride it, so that a row's weight is there without the
+    gather ``weights.reshape(-1)[rows]``; ``order`` whole is the key that
+    brings a scalar a row back to pair order (:func:`_pairs_of_rows`)."""
     S, k = topk_idx.shape
     local = topk_idx - first
     hot = _hot(local, n_held)                                  # [S, k, held]
@@ -292,8 +300,38 @@ def _placement(topk_idx: jax.Array, first: int, n_held: int, bound: int):
     here = hot.any(axis=-1)
     slot = jnp.where(here & (rank < n_here), rank, bound)
     key = jnp.where(here, local, n_held).reshape(-1)           # absent: last
-    rows = jnp.argsort(key)[:bound]               # the pair in each row
-    return rows, slot, group_sizes, n_here, counts
+    n = S * k
+    pair = jnp.arange(n, dtype=jnp.int32)
+    by_pair = jax.lax.stop_gradient(weights).reshape(-1)
+    if (n_held + 1) * n <= _MOST_KEYS:
+        # expert and pair as one key, all different: the stable sort's order
+        # from a sort of two operands that need not be stable (a third less
+        # time on a v5e than key, pair and weight sorted stably)
+        both, by_row = jax.lax.sort((key * n + pair, by_pair), num_keys=1,
+                                    is_stable=False)
+        order = jax.lax.rem(both, jnp.int32(n))
+    else:
+        _, order, by_row = jax.lax.sort((key, pair, by_pair), num_keys=1,
+                                        is_stable=True)
+    return order, by_row[:bound], slot, group_sizes, n_here, counts
+
+
+def _pairs_of_rows(by_row: jax.Array, order: jax.Array,
+                   slot: jax.Array) -> jax.Array:
+    """``jnp.take(by_row, slot, mode="fill", fill_value=0)``, a float32 a
+    buffer row brought to pair order [S, k], to the bit and without a gather
+    of S k scalars (1.0-1.3 ms a layer on a v5e, where this is 0.1-0.25):
+    ``order`` [S k] is the pair each sorted place holds (the buffer's rows
+    are its first ``bound``), so a sort of the rows' values, padded to S k,
+    keyed on it (the keys are all different) puts each at its pair. A pair
+    without a row (``slot == bound``) gets its zero by a select, never by a
+    product: the rows past the last live tile are never written and may hold
+    NaN."""
+    n = order.shape[0]
+    _, by_pair = jax.lax.sort(
+        (order, jnp.pad(by_row, (0, n - by_row.shape[0]))), num_keys=1,
+        is_stable=False)
+    return jnp.where(slot < by_row.shape[0], by_pair.reshape(slot.shape), 0)
 
 
 def _relu2(v: jax.Array) -> jax.Array:
@@ -574,15 +612,19 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     pairs it received are the router's index work (:func:`_placement`, under
     the scope ``moe_router``): compares of the chosen experts against the
     held ones, summed and counted down the tokens; only the pair of each row
-    (``rows``) is a sort, and nothing there gathers or scatters a scalar a
-    pair.
+    (``rows``) is a sort, which carries the pairs' weights with it, and
+    nothing there gathers or scatters a scalar a pair.
     Dispatch (tokens into the buffer of sorted pairs) and combine (each
-    token's weighted sum of its pairs' rows) are one-to-one moves, gathers
-    both ways; they take the lowering the FFN's products take
+    token's weighted sum of its pairs' rows) are one-to-one moves of whole
+    rows, gathers both ways; they take the lowering the FFN's products take
     (:func:`_moves_lowering`): the row kernels of ``ops/moe_rows.py``, which
     copy only the rows that carry a pair, each once, and weight or sum them
     in the same pass, or ``jnp.take``. ``interpret`` is the kernels' test
-    handle (None: ask the backend).
+    handle (None: ask the backend). The two scalars a row of the combine's
+    backward move by sorts in either lowering, so nothing there gathers or
+    scatters a scalar a pair or a row either: a row's weight is the one the
+    router's sort carried, and the rows' dots, the weights' gradient, come
+    back to pair order keyed on that sort's order (:func:`_pairs_of_rows`).
     With a held share of the experts (``cfg.moe_experts_held``) the router,
     the top k and their renormalised weights are the whole model's, only the
     pairs whose expert is here are computed, what the absent experts would
@@ -652,8 +694,9 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
             _gates, aux_loss, topk_vals, topk_idx = _route(
                 logits, k,
                 valid=None if valid is None else valid.reshape(-1))
-        rows, slot, group_sizes, n_here, counts = _placement(
-            topk_idx, first, n_held, bound)
+        order, row_weight, slot, group_sizes, n_here, counts = _placement(
+            topk_idx, topk_vals, first, n_held, bound)
+        rows = order[:bound]                      # the pair in each row
     if _TRACKER is not None:
         real = (jnp.ones((S,), bool) if valid is None
                 else valid.reshape(-1))
@@ -691,7 +734,8 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         ys = _grouped_ffn(fetch(xz), group_sizes, w, dt, kernel,
                           rows_past_groups=True, act=act)
     with jax.named_scope("moe_dispatch"):
-        out = _weighted_sum_of_rows(ys, topk_vals, rows, slot, moves, how)
+        out = _weighted_sum_of_rows(ys, topk_vals, row_weight, order, slot,
+                                    moves, how)
     if "latent_up" in w:
         with jax.named_scope("moe_latent"):
             out = out @ w["latent_up"].astype(dt)
@@ -804,11 +848,14 @@ def _sum_of_rows(ys, slot, weights):
     return acc
 
 
-def _sum_pairs(ys: jax.Array, weights: jax.Array, rows: jax.Array,
-               slot: jax.Array, moves, how: Tuple[str, bool]):
+def _sum_pairs(ys: jax.Array, weights: jax.Array, row_weight: jax.Array,
+               order: jax.Array, slot: jax.Array, moves,
+               how: Tuple[str, bool]):
     """Each token's ``sum_j weights[t, j] * ys[slot[t, j]]``, summed in f32:
-    ys [bound, D], weights [S, k] f32, ``rows`` [bound] the pair of each row,
-    ``slot`` [S, k] the row of each pair."""
+    ys [bound, D], weights [S, k] f32, ``slot`` [S, k] the row of each pair;
+    ``row_weight`` [bound] (each row's pair's weight) and ``order`` [S k]
+    (the pairs sorted by expert: the pair of each row first), as
+    :func:`_placement` gives them, are the backward's."""
     if how[0] == "xla":
         return _sum_of_rows(ys, slot, weights).astype(ys.dtype)
     from deepspeed_tpu.ops import moe_rows
@@ -819,40 +866,38 @@ def _sum_pairs(ys: jax.Array, weights: jax.Array, rows: jax.Array,
         dtype=ys.dtype, interpret=how[1])
 
 
-_weighted_sum_of_rows = jax.custom_vjp(_sum_pairs, nondiff_argnums=(5,))
+_weighted_sum_of_rows = jax.custom_vjp(_sum_pairs, nondiff_argnums=(6,))
 
 
-def _wsum_fwd(ys, weights, rows, slot, moves, how):
-    return _sum_pairs(ys, weights, rows, slot, moves, how), \
-        (ys, weights, rows, slot, moves)
+def _wsum_fwd(ys, weights, row_weight, order, slot, moves, how):
+    return _sum_pairs(ys, weights, row_weight, order, slot, moves, how), \
+        (ys, row_weight, order, slot, moves)
 
 
 def _wsum_bwd(how, res, g):
-    ys, weights, rows, slot, moves = res
+    ys, row_weight, order, slot, moves = res
     k = slot.shape[1]
+    tok = order[:ys.shape[0]] // k                # the token of each row
     lowerings.count("moe_dispatch", how[0])
     # a row's cotangent: its token's, times its pair's weight (a row that
     # carries no pair gets one all the same: ``_grouped_ffn`` cuts it off, by
-    # its mask or by kernels that work only the rows a group holds)
-    row_weight = weights.reshape(-1)[rows]
+    # its mask or by kernels that work only the rows a group holds); a
+    # weight's gradient: the dot ``<g[token], ys[row]>`` of its pair's row,
+    # brought from row order to pair order by a sort (:func:`_pairs_of_rows`)
     if how[0] == "xla":
-        dys = (g[rows // k].astype(jnp.float32)
-               * row_weight[:, None]).astype(ys.dtype)
-        gf = g.astype(jnp.float32)
-        dw = jnp.stack([
-            (gf * jnp.take(ys, slot[:, j], axis=0, mode="fill", fill_value=0)
-             .astype(jnp.float32)).sum(axis=-1) for j in range(k)], axis=1)
+        gf = g[tok].astype(jnp.float32)
+        dys = (gf * row_weight[:, None]).astype(ys.dtype)
+        dot = (gf * ys.astype(jnp.float32)).sum(axis=-1)
     else:
         from deepspeed_tpu.ops import moe_rows
 
-        # the same pass gives each row's <g[token], ys[row]>: a weight's
-        # gradient is the dot of its pair's row, a gather of scalars
+        # the same pass gives each row's dot
         dys, dot = moe_rows.rows_of_tokens(
-            moe_rows.pack_rows(g, interpret=how[1]), rows // k,
-            moves["n_here"], D=g.shape[1], dtype=ys.dtype, weight=row_weight,
-            ys=ys, interpret=how[1])
-        dw = jnp.take(dot, slot, mode="fill", fill_value=0)
-    return dys, dw.astype(weights.dtype), None, None, None
+            moe_rows.pack_rows(g, interpret=how[1]), tok, moves["n_here"],
+            D=g.shape[1], dtype=ys.dtype, weight=row_weight, ys=ys,
+            interpret=how[1])
+    dw = _pairs_of_rows(dot, order, slot).astype(row_weight.dtype)
+    return dys, dw, None, None, None, None
 
 
 _weighted_sum_of_rows.defvjp(_wsum_fwd, _wsum_bwd)

@@ -788,7 +788,8 @@ def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
     second fetch of the rows, five of them, and four packings) and no gather
     of rows is left; and the router (softmax in Mellum2, sigmoid scores with a
     selection bias in Kanana-2) holds no gather and no scatter: the chosen
-    scores, the counts and each pair's row are compares and sums."""
+    scores, the counts and each pair's row are compares and sums; nor does
+    the combine's backward, whose two scalars a row ride sorts."""
     from deepspeed_tpu.moe import sharded_moe as sm
     from deepspeed_tpu.ops import grouped_matmul as gm
 
@@ -829,9 +830,10 @@ def test_the_layers_gradient_takes_the_row_kernels_on_v5e(one_chip,
     assert not re.search(r"bf16\[(65536|16384),%d\]\S* gather\(" % D, text)
     assert "moe_router" in text
     assert _index_ops_under(text, "moe_router") == []
-    # the combine's backward still gathers a scalar a pair (``dot[slot]``):
-    # the check above sees such an op where its scope has one
-    assert _index_ops_under(text, "moe_dispatch")
+    # nor does the combine's backward: a row's weight rode the router's sort
+    # and the rows' dots come back to pair order by a sort of their own
+    assert _index_ops_under(text, "moe_dispatch") == []
+    assert len(re.findall(r" sort\(", text)) >= 2
 
 
 def test_an_expert_layer_keeps_no_one_hot_of_the_picks():
@@ -1030,8 +1032,8 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     the scan's and the convolution's kernels at chunk 128 and one group
     under ``attn``, and the attention layer the flash kernels under
     ``attn/attn_full``, whose instructions keep the names the benchmark's
-    patterns look for; under ``moe_router`` nothing is gathered or
-    scattered."""
+    patterns look for; under ``moe_router`` and ``moe_dispatch`` nothing
+    is gathered or scattered."""
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "nemotron3_super_120b_train_d11h16e8v8",
         "modelcfg_nemotron_h", 700_865_520, seq=8192)
@@ -1053,6 +1055,8 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     assert len(re.findall(r"^\s*%attn_full[.\d]* = .*custom-call\(.*"
                           r"tpu_custom_call", text, re.M)) == 2
     assert "/moe/moe_latent/" in text and "ragged-dot" not in text
-    # the router's chosen scores, counts and rows: no gather, no scatter
+    # the router's chosen scores, counts and rows: no gather, no scatter;
+    # nor the combine's backward (a row's weight, a pair's dot)
     assert "/moe/moe_router/" in text
     assert _index_ops_under(text, "moe_router") == []
+    assert _index_ops_under(text, "moe_dispatch") == []

@@ -509,30 +509,95 @@ PLACEMENTS = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(PLACEMENTS))
-def test_placement_by_compares_is_the_sorts(name):
-    """``rows`` up to the last pair, ``slot``, ``group_sizes``, ``n_here`` and
-    ``counts`` of :func:`sharded_moe._placement` (compares against
-    ``arange(held)``, a running count) equal the ``argsort`` / ``bincount`` /
-    rank-scatter form's, element for element."""
+def _placed(name):
+    """A case's picks, weights of a pair each, what :func:`_placement` makes
+    of them (jitted) and the buffer's rows."""
     from deepspeed_tpu.moe import sharded_moe as sm
 
     idx, E, first, held, bound = PLACEMENTS[name]()
     idx = idx.astype(jnp.int32)
     assert idx.max() < E
-    got = jax.jit(sm._placement, static_argnums=(1, 2, 3))(
-        idx, first, held, bound)
+    weights = jax.random.uniform(jax.random.key(12), idx.shape, jnp.float32,
+                                 0.05, 1.0)
+    got = jax.jit(lambda idx, weights: sm._placement(
+        idx, weights, first, held, bound))(idx, weights)
+    return idx, weights, first, held, bound, got
+
+
+@pytest.mark.parametrize("name", sorted(PLACEMENTS))
+def test_placement_by_compares_is_the_sorts(name):
+    """``rows`` (the first ``bound`` of ``order``) up to the last pair,
+    ``slot``, ``group_sizes``, ``n_here`` and ``counts`` of
+    :func:`sharded_moe._placement` (compares against ``arange(held)``, a
+    running count) equal the ``argsort`` / ``bincount`` / rank-scatter form's,
+    element for element."""
+    idx, _, first, held, bound, got = _placed(name)
+    order, row_weight, *got = got
     want = _placement_by_sort(idx, first, held, bound)
     n_here = int(want[3])
-    assert int(got[3]) == n_here
-    np.testing.assert_array_equal(got[0][:n_here], want[0][:n_here])
-    for g, w_ in zip(got[1:], want[1:]):
+    assert int(got[2]) == n_here
+    assert order.shape == (idx.size,) and row_weight.shape == (bound,)
+    np.testing.assert_array_equal(np.sort(order), np.arange(idx.size))
+    np.testing.assert_array_equal(order[:n_here], want[0][:n_here])
+    for g, w_ in zip(got, want[1:]):
         assert g.dtype == w_.dtype and g.shape == w_.shape
         np.testing.assert_array_equal(g, w_)
     dropped = int(want[4].sum()) - n_here
     assert (dropped > 0) == ("small" in name)
     if name == "no-pair-here":
-        assert n_here == 0 and (np.asarray(got[1]) == bound).all()
+        assert n_here == 0 and (np.asarray(got[0]) == bound).all()
+
+
+@pytest.mark.parametrize("one_key", [True, False],
+                         ids=["expert-and-pair-one-key", "a-stable-sort"])
+@pytest.mark.parametrize("name", sorted(PLACEMENTS))
+def test_a_rows_weight_rides_the_sort(name, one_key, monkeypatch):
+    """``row_weight`` of :func:`sharded_moe._placement`, the pairs' weights
+    carried by the sort that makes ``rows``, is the gather
+    ``weights.reshape(-1)[rows]`` to the bit (on every row of the buffer: a
+    row past the last pair carries the pair the sort left there), and no
+    gradient goes through it; ``order`` is ``argsort``'s, whether expert and
+    pair fit one int32 key or, past ``_MOST_KEYS``, the sort is stable on
+    the expert."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    if not one_key:
+        monkeypatch.setattr(sm, "_MOST_KEYS", 0)
+    idx, weights, first, held, bound, got = _placed(name)
+    order, row_weight = got[:2]
+    local = idx.reshape(-1) - first
+    np.testing.assert_array_equal(order, jnp.argsort(
+        jnp.where((local >= 0) & (local < held), local, held)))
+    assert row_weight.dtype == weights.dtype
+    np.testing.assert_array_equal(
+        row_weight, weights.reshape(-1)[order[:bound]])
+    through = jax.grad(lambda w: sm._placement(idx, w, first, held, bound)[1]
+                       .sum())(weights)
+    assert not np.asarray(through).any()
+
+
+@pytest.mark.parametrize("name", sorted(PLACEMENTS))
+def test_a_scalar_a_row_comes_back_to_its_pair_without_a_gather(name):
+    """:func:`sharded_moe._pairs_of_rows` on the placement's own ``order``
+    and ``slot`` is ``jnp.take(dot, slot, mode="fill", fill_value=0)``
+    element for element, NaN planted in every row past the last pair (the
+    rows kernel leaves the tiles past it unwritten); its jaxpr holds a sort
+    and no gather."""
+    from deepspeed_tpu.moe import sharded_moe as sm
+
+    *_, bound, got = _placed(name)
+    order, _, slot, _, n_here, _ = got
+    dot = jax.random.normal(jax.random.key(13), (bound,))
+    dot = jnp.where(jnp.arange(bound) < n_here, dot, jnp.nan)
+    back = jax.jit(sm._pairs_of_rows)(dot, order, slot)
+    want = jnp.take(dot, slot, mode="fill", fill_value=0)
+    assert back.dtype == want.dtype and np.isfinite(np.asarray(back)).all()
+    np.testing.assert_array_equal(back, want)
+    assert int((np.asarray(back) != 0).sum()) == int(n_here)
+    names = {e.primitive.name for e in jax.make_jaxpr(sm._pairs_of_rows)(
+        dot, order, slot).eqns}
+    assert "sort" in names and not names & {"gather", "scatter",
+                                            "scatter-add", "scatter_add"}
 
 
 def test_the_running_count_is_exact_at_a_cells_length():

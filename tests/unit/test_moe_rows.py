@@ -27,8 +27,9 @@ def f32(a):
 
 
 def routing(expert, k, held, first=0, bound=None):
-    """``rows``, ``slot``, ``group_sizes``, ``n_here``, ``bound`` as
-    ``grouped_moe_mlp_block`` makes them from each pair's expert."""
+    """``order`` (its first ``bound`` are ``rows``), ``slot``,
+    ``group_sizes``, ``n_here``, ``bound`` as ``grouped_moe_mlp_block`` makes
+    them from each pair's expert."""
     expert = np.asarray(expert).reshape(-1)
     n = expert.size
     bound = n if bound is None else bound
@@ -42,6 +43,7 @@ def routing(expert, k, held, first=0, bound=None):
     rank[order] = np.arange(n)
     slot = np.where(rank < n_here, rank, bound).reshape(-1, k)
     return dict(rows=jnp.asarray(order[:bound], jnp.int32),
+                order=jnp.asarray(order, jnp.int32),
                 slot=jnp.asarray(slot, jnp.int32),
                 sizes=jnp.asarray(np.diff(ends, prepend=0), jnp.int32),
                 n_here=n_here, bound=bound, k=k, S=n // k)
@@ -139,24 +141,68 @@ def test_the_sum_of_rows_is_the_takes_to_the_bit(name, D, weighted):
     np.testing.assert_array_equal(f32(got), f32(want))
 
 
+def planted(dot, r):
+    """``dot`` [bound] with NaN past the last tile the rows kernel writes."""
+    tile = mr._tile(r["bound"])
+    live = -(-r["n_here"] // tile) * tile
+    return jnp.where(jnp.arange(r["bound"]) < live, dot, jnp.nan)
+
+
+def the_takes_backward(ys, weights, rows, slot, g):
+    """The combine's backward as gathers: a row's weight out of the pairs',
+    each pair's row of ``ys`` for its dot with the token's cotangent."""
+    k = slot.shape[1]
+    dys = (g[rows // k].astype(jnp.float32)
+           * weights.reshape(-1)[rows][:, None]).astype(ys.dtype)
+    gf = g.astype(jnp.float32)
+    dw = jnp.stack([
+        (gf * jnp.take(ys, slot[:, j], axis=0, mode="fill", fill_value=0)
+         .astype(jnp.float32)).sum(axis=-1) for j in range(k)], axis=1)
+    return dys, dw
+
+
+@pytest.mark.parametrize("lowering", ["pallas", "xla"])
 @pytest.mark.parametrize("name,D", CASES)
-def test_the_combines_backward_is_the_takes(name, D):
+def test_the_combines_backward_is_the_takes(name, D, lowering):
     """``dys`` to the bit; the weights' gradient, the same dots summed in
-    another order, within 1e-5 of the largest."""
+    another order, within 1e-5 of the largest: by the row kernels and by
+    ``jnp.take`` of rows, a row's weight being the one the sort carried and
+    the dots coming back to pair order by :func:`sharded_moe._pairs_of_rows`."""
     r = ROUTINGS[name]()
     _, ys, weights, g = operands(r, D)
     k = r["k"]
-    how = ("pallas", True)
+    how = (lowering, lowering == "pallas")
     moves = sm._row_moves(r["rows"], r["sizes"], jnp.int32(r["n_here"]),
                           r["S"], k, how)
-    res = (ys, weights, r["rows"], r["slot"])
-    dys, dw, *_ = sm._wsum_bwd(how, res + (moves,), g)
-    want_dys, want_dw, *_ = sm._wsum_bwd(("xla", False), res + (None,), g)
-    np.testing.assert_array_equal(f32(live_tiles(dys, r))[:r["n_here"]],
+    row_weight = weights.reshape(-1)[r["rows"]]
+    dys, dw, *rest = sm._wsum_bwd(
+        how, (ys, row_weight, r["order"], r["slot"], moves), g)
+    assert rest == [None] * 4
+    want_dys, want_dw = the_takes_backward(ys, weights, r["rows"], r["slot"],
+                                           g)
+    if lowering == "pallas":
+        dys = live_tiles(dys, r)
+    np.testing.assert_array_equal(f32(dys)[:r["n_here"]],
                                   f32(want_dys)[:r["n_here"]])
-    assert np.isfinite(f32(dw)).all()
+    assert np.isfinite(f32(dw)).all() and dw.dtype == weights.dtype
     scale = max(float(jnp.abs(want_dw).max()), 1.0)
     np.testing.assert_allclose(f32(dw), f32(want_dw), atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("name", sorted(ROUTINGS))
+def test_the_rows_dots_reach_their_pairs_as_the_gather_brings_them(name):
+    """:func:`sharded_moe._pairs_of_rows` is ``jnp.take(dot, slot,
+    mode="fill", fill_value=0)`` element for element, NaN standing in the
+    tiles of ``dot`` the rows kernel never writes: a pair without a row gets
+    its zero by a select."""
+    r = ROUTINGS[name]()
+    dot = planted(jax.random.normal(jax.random.key(7), (r["bound"],)), r)
+    got = jax.jit(sm._pairs_of_rows)(dot, r["order"], r["slot"])
+    want = jnp.take(dot, r["slot"], mode="fill", fill_value=0)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.isfinite(f32(got)).all()
+    np.testing.assert_array_equal(f32(got), f32(want))
+    assert (f32(got) != 0).sum() == r["n_here"]
 
 
 def test_weights_of_full_precision_are_within_one_rounding():
@@ -266,6 +312,50 @@ def test_the_layers_gradient_through_the_kernels_is_the_take_paths(
     scale = float(jnp.abs(f32(want_grads[0])).max())
     np.testing.assert_allclose(f32(grads[0]), f32(want_grads[0]),
                                atol=0.02 * scale)
+
+
+def every_equation(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from every_equation(sub)
+
+
+def test_the_layers_backward_moves_no_scalar_a_pair_or_a_row_by_a_gather():
+    """The ``"xla"`` lowering (what a CPU gets: no kernel's body in the
+    jaxpr) of a held share's layer, 64 tokens of 3 picks in a buffer of 96
+    rows: no ``gather`` and no ``scatter`` of the backward reads or writes
+    [S k], [S, k] or [bound] scalars (the takes of whole rows stay: [bound,
+    D] out of [S, D] and back); and what the forward keeps for it holds the
+    rows' weights, [bound] float32, and no one-hot of the picks over the
+    held experts."""
+    S, D, F, E, held, k, bound = 64, 128, 256, 16, 4, 3, 96
+
+    class Share(HeldShare):
+        top_k = k
+        moe_experts_held = held
+
+    ks = jax.random.split(jax.random.key(5), 5)
+    h = jax.random.normal(ks[0], (1, S, D), jnp.float32)
+    w = {"w_gate": jax.random.normal(ks[1], (held, D, F)) * 0.05,
+         "w_up": jax.random.normal(ks[2], (held, D, F)) * 0.05,
+         "w_down": jax.random.normal(ks[3], (held, F, D)) * 0.05,
+         "router": jax.random.normal(ks[4], (D, E)) * 0.5}
+    before = lowerings.snapshot()
+    out, back = jax.vjp(
+        lambda h, w: sm.grouped_moe_mlp_block(h, w, Share)[0], h, w)
+    kept = [x for x in jax.tree_util.tree_leaves(back) if hasattr(x, "shape")]
+    assert any(x.shape == (bound,) and x.dtype == jnp.float32 for x in kept)
+    assert not any(x.shape == (S, k, held) for x in kept), \
+        [x.shape for x in kept]
+    scalars = {(S * k,), (S, k), (bound,)}
+    moved = [(eqn.primitive.name, [v.aval.shape for v in ends])
+             for eqn in every_equation(jax.make_jaxpr(back)(out).jaxpr)
+             if eqn.primitive.name.startswith(("gather", "scatter"))
+             for ends in [[eqn.invars[0], *eqn.invars[2:], *eqn.outvars]]
+             if scalars & {v.aval.shape for v in ends}]
+    assert moved == []
+    assert lowerings.since(before)["moe_dispatch"] == {"xla": 4}
 
 
 def test_a_row_past_the_pairs_never_reaches_the_output_or_the_gradients(

@@ -35,8 +35,9 @@ def expert_of(seed: int) -> np.ndarray:
 
 
 def routing(seed: int):
-    """``rows``, ``slot``, ``n_here`` as ``grouped_moe_mlp_block`` makes them,
-    from uniform random top-k choices."""
+    """``order`` (its first ``BOUND`` are ``rows``), ``slot``, ``n_here`` as
+    ``grouped_moe_mlp_block`` makes them, from uniform random top-k
+    choices."""
     expert = expert_of(seed)
     key = np.where(expert < HELD, expert, HELD)
     order = np.argsort(key, kind="stable")
@@ -44,8 +45,8 @@ def routing(seed: int):
     rank = np.empty(S * K, np.int64)
     rank[order] = np.arange(S * K)
     slot = np.where(rank < n_here, rank, BOUND).reshape(S, K)
-    return (jnp.asarray(order[:BOUND], jnp.int32),
-            jnp.asarray(slot, jnp.int32), n_here)
+    return (jnp.asarray(order, jnp.int32), jnp.asarray(slot, jnp.int32),
+            n_here)
 
 
 def timed(fn, args, calls):
@@ -76,7 +77,8 @@ def main():
                                                 interpret=True))
     dev = jax.devices()[0]
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform}))
-    rows, slot, n_here = routing(a.seed)
+    order, slot, n_here = routing(a.seed)
+    rows = order[:BOUND]
     tok = rows // K
     n = jnp.int32(n_here)
     live = (jnp.arange(BOUND) < n_here)[:, None]
@@ -138,15 +140,14 @@ def main():
              .astype(jnp.float32)).sum(axis=-1) for j in range(K)], axis=1)
         return dys, dw
 
-    def bwd_rows(g, ys, weights, rows, slot, n):
+    def bwd_rows(g, ys, wrow, order, slot, n):
         dys, dot = mr.rows_of_tokens(
-            mr.pack_rows(g), rows // K, n, D=D,
-            weight=weights.reshape(-1)[rows], ys=ys)
-        return dys, jnp.take(dot, slot, mode="fill", fill_value=0)
+            mr.pack_rows(g), order[:BOUND] // K, n, D=D, weight=wrow, ys=ys)
+        return dys, sm._pairs_of_rows(dot, order, slot)
 
     ref = say("combine bwd", "take", bwd_take, (x, ys, weights, rows, slot))
-    got = say("combine bwd", "pallas+pack", bwd_rows,
-              (x, ys, weights, rows, slot, n))
+    got = say("combine bwd", "pallas+pack+sort", bwd_rows,
+              (x, ys, wrow, order, slot, n))
     if ref is not None:
         same = bool(jnp.array_equal(jnp.where(live, got[0], 0),
                                     jnp.where(live, ref[0], 0)))
@@ -156,9 +157,14 @@ def main():
     say("combine bwd, rows only", "pallas", lambda gp, t, n, w, y:
         mr.rows_of_tokens(gp, t, n, D=D, weight=w, ys=y),
         (xp, tok, n, wrow, ys))
-    dot = jnp.zeros((BOUND,), jnp.float32)
-    say("combine bwd, dw = dot[slot]", "take", lambda d, s: jnp.take(
+    # the two scalars a row: the gathers the program made until PR 48, and
+    # the sort that brings the dots to pair order now (a row's weight rides
+    # the router's sort: ``sharded_moe._placement``)
+    dot = jnp.where(live[:, 0], jax.random.normal(ks[3], (BOUND,)), jnp.nan)
+    ref = say("combine bwd, dw = dot[slot]", "take", lambda d, s: jnp.take(
         d, s, mode="fill", fill_value=0), (dot, slot), fetched=S * K)
+    say("combine bwd, dw = dot[slot]", "sort", sm._pairs_of_rows,
+        (dot, order, slot), ref, fetched=S * K)
     say("combine bwd, w[rows]", "take", lambda w, r: w.reshape(-1)[r],
         (weights, rows), fetched=BOUND)
 
