@@ -55,15 +55,15 @@ OURO_TENSORS = {
 }
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
-                              "olmo_hybrid", "nemotron_h")
+                              "olmo_hybrid", "nemotron_h", "lfm2_moe")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
-             "linear_attention": "delta"}
+             "linear_attention": "delta", "conv": "conv"}
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
 _CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
-                "nemotron_h")
+                "nemotron_h", "lfm2_moe")
 #: ``hybrid_override_pattern`` letters (``model_type: "nemotron_h"``) ->
 #: layer kinds of a ``one_branch`` model
 _NEMOTRON_KINDS = {"M": "ssm", "*": "full", "E": "moe", "-": "dense"}
@@ -386,6 +386,57 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
                 moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
                 moe_shared_experts=shared // width,
                 moe_latent_size=get("moe_latent_size"))
+    elif model_type == "lfm2_moe":
+        # gated short convolutions and GQA layers in turn (``layer_types``),
+        # the attention's q and k normed per head before a plain rope; the
+        # first ``num_dense_layers`` FFNs dense SwiGLU, the others routed:
+        # sigmoid scores with a selection bias (``use_expert_bias``), the top
+        # k normalised and scaled, no shared expert; a tied head (the
+        # family's convention where the file is silent). The config side
+        # only. A share of the experts is no config key: pass
+        # moe_experts_held=. What training adds (the bias rule's rate, the
+        # balance term's weight): pass moe_bias_rate= and moe_aux_loss_coef=.
+        # The published router divides by the chosen scores' sum + 1e-6; the
+        # program's divides by the sum.
+        if get("conv_bias", False):
+            raise ValueError("lfm2_moe with conv_bias=true is not mapped: "
+                             "the short convolution is built without a bias")
+        rope = dict(get("rope_parameters") or {})
+        if rope.get("rope_type", "default") != "default" \
+                or get("rope_scaling"):
+            raise ValueError(
+                f"lfm2_moe with rope_parameters={rope} / rope_scaling="
+                f"{get('rope_scaling')} is not mapped: a plain rope only")
+        if not get("norm_topk_prob", True):
+            raise ValueError("lfm2_moe is mapped with a normalised top k "
+                             "(norm_topk_prob true)")
+        L = get("num_hidden_layers")
+        types = list(get("layer_types") or ())[:L]
+        if len(types) != L or set(types) - {"conv", "full_attention"}:
+            raise ValueError(
+                f"layer_types {get('layer_types')!r} do not name "
+                f"num_hidden_layers={L} layers by 'conv' / 'full_attention'")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads")
+            or get("num_attention_heads"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("norm_eps", 1e-5)),
+            tie_embeddings=bool(get("tie_word_embeddings",
+                                    get("tie_embedding", True))),
+            rope_theta=float(rope.get("rope_theta",
+                                      get("rope_theta", 1000000.0))),
+            attn_pattern=tuple(_HF_KINDS[k] for k in types),
+            qk_norm="head", conv_taps=int(get("conv_L_cache", 3)),
+            first_k_dense=int(get("num_dense_layers", 0) or 0),
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            moe_dispatch="grouped", moe_scoring="sigmoid",
+            moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+        )
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "

@@ -67,7 +67,9 @@ class TransformerConfig:
     # last ``sliding_window`` keys), "full" (attention over every key) or
     # "ssm" (the mixer is no attention but a Mamba-2 state-space layer of the
     # ``ssm_*`` sizes below, models/mamba.py) or "delta" (a gated delta-rule
-    # layer of the ``delta_*`` sizes, models/gated_delta.py): layer i is of
+    # layer of the ``delta_*`` sizes, models/gated_delta.py) or "conv" (a
+    # gated short convolution over ``conv_taps`` positions at the model's
+    # width, models/short_conv.py): layer i is of
     # kind attn_pattern[i % len]. None = every layer the one attention kind
     # (windowed where sliding_window is set). HF qwen2's leading run of n
     # full layers is ("full",) * n + ("window",) * (L - n): a period of the
@@ -104,6 +106,11 @@ class TransformerConfig:
     delta_value_dim: int = 128
     delta_conv: int = 4
     delta_neg_eigval: bool = False
+    # a gated short-convolution layer (the LFM2 family's): ``in_proj`` to
+    # three times the width, a gate before and a gate after a causal
+    # depthwise convolution over ``conv_taps`` positions (no bias, no
+    # activation), ``out_proj``
+    conv_taps: int = 3
     # the softmax scale of attention (None = 1/sqrt(head_dim)), what the
     # embedding's rows and each branch's output are multiplied by, and what
     # the logits are divided by (the Granite family's four multipliers)
@@ -151,7 +158,10 @@ class TransformerConfig:
     norm_placement: str = "pre"
     # "width": an RMSNorm on q and one on k over the whole projection (all
     # the heads held), before the heads are split and before any rope
-    # (``q_norm``, ``k_norm`` in the attention group); None = none
+    # (``q_norm``, ``k_norm`` in the attention group); "head": the norm over
+    # each head's ``head_dim`` channels on their own, one scale of
+    # ``head_dim`` for q and one for k, shared by the heads, after the heads
+    # are split and before any rope; None = none
     qk_norm: Optional[str] = None
     # a share of a mixer's heads: this model holds ``heads_held`` of an
     # attention layer's ``num_heads`` (with the key-value heads that serve
@@ -275,11 +285,12 @@ class TransformerConfig:
                     "latent attention (kv_lora_rank) is every layer's mixer: "
                     "not with attn_pattern")
             ffns = {"moe", "dense"} if self.one_branch else set()
-            if not pat or set(pat) - {"window", "full", "ssm", "delta"} \
+            if not pat or set(pat) - {"window", "full", "ssm", "delta",
+                                      "conv"} \
                     - ffns or self.num_layers % len(pat):
                 raise ValueError(
                     f"attn_pattern={pat}: a period of 'window' / 'full' / "
-                    f"'ssm' / 'delta' (with one_branch also 'moe' / "
+                    f"'ssm' / 'delta' / 'conv' (with one_branch also 'moe' / "
                     f"'dense') whose length divides num_layers="
                     f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
@@ -332,6 +343,25 @@ class TransformerConfig:
                     "a delta layer with attention_impl='fpdt': the chunked "
                     "sequence path carries key-value chunks, not a "
                     "recurrent state")
+        if self.has_conv:
+            if self.conv_taps < 1:
+                raise ValueError(f"a conv layer needs conv_taps="
+                                 f"{self.conv_taps} above 0")
+            if (self.looped or self.parallel_block or self.loss_tiling > 1
+                    or self.attention_impl == "fpdt"
+                    or self.heads_held is not None):
+                raise NotImplementedError(
+                    "a model with short-convolution layers (attn_pattern "
+                    "holds 'conv') runs one pass, one branch after the "
+                    "other, with whole logits, whole sequences and whole "
+                    "mixers: not a looped stack (num_passes > 1, "
+                    "sandwich_norm or the exit gate: a pass would have to "
+                    "say what positions the next one's convolution starts "
+                    "from), parallel_block, the tiled loss (loss_tiling > "
+                    "1), attention_impl='fpdt' (its chunks carry keys and "
+                    "values, not the positions before a chunk) or "
+                    "heads_held (the gates and taps are by channel: there "
+                    "are no heads to hold a share of)")
         if self.one_branch:
             # (``kind_cfg``'s copy for one kind of layer has no pattern)
             pat = self.attn_pattern
@@ -344,6 +374,7 @@ class TransformerConfig:
                     f"where num_experts > 1")
             if (self.looped or self.parallel_block or self.loss_tiling > 1
                     or self.norm_placement != "pre" or self.has_delta
+                    or self.has_conv
                     or self.first_k_dense or self.heads_held is not None
                     or self.residual_multiplier != 1.0
                     or self.attention_impl == "fpdt"):
@@ -352,8 +383,9 @@ class TransformerConfig:
                     "runs one pass of pre-norm attention, state-space and "
                     "FFN layers with whole logits: not num_passes > 1, "
                     "sandwich_norm, the exit gate, parallel_block, "
-                    "loss_tiling > 1, norm_placement='post', delta layers, "
-                    "first_k_dense (the pattern names the dense layers), "
+                    "loss_tiling > 1, norm_placement='post', delta or conv "
+                    "layers, first_k_dense (the pattern names the dense "
+                    "layers), "
                     "heads_held, residual_multiplier or attention_impl="
                     "'fpdt'")
         if self.norm_placement not in ("pre", "post"):
@@ -365,9 +397,10 @@ class TransformerConfig:
                 "norm_placement='post' is one norm after each branch: not "
                 "with sandwich_norm (a norm before it too) or parallel_block "
                 "(one residual add)")
-        if self.qk_norm not in (None, "width"):
-            raise ValueError(f"qk_norm={self.qk_norm!r}: None or 'width' "
-                             f"(one RMSNorm over the whole projection)")
+        if self.qk_norm not in (None, "width", "head"):
+            raise ValueError(f"qk_norm={self.qk_norm!r}: None, 'width' (one "
+                             f"RMSNorm over the whole projection) or 'head' "
+                             f"(over each head's channels)")
         if self.qk_norm and (self.has_mla or self.norm != "rmsnorm"
                              or self.attention_impl == "fpdt"):
             raise NotImplementedError(
@@ -482,6 +515,11 @@ class TransformerConfig:
         return "delta" in (self.attn_pattern or ())
 
     @property
+    def has_conv(self) -> bool:
+        """Whether any layer's mixer is a gated short convolution."""
+        return "conv" in (self.attn_pattern or ())
+
+    @property
     def heads_here(self) -> int:
         """The attention heads this model holds (``heads_held``, else all)."""
         return self.heads_held or self.num_heads
@@ -503,7 +541,7 @@ class TransformerConfig:
         attention, or whose layers are one branch each (then every layer's
         branch output, an FFN layer's too)."""
         return (self.has_ssm or self.has_mla or self.has_delta
-                or self.one_branch)
+                or self.has_conv or self.one_branch)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -515,8 +553,8 @@ class TransformerConfig:
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of every layer: its mixer's, "window", "full", "ssm",
-        "delta" or "mla"; in a model whose FFNs differ by layer, then ":" and
-        its FFN's, "dense" or "moe". A layer of one branch (``one_branch``)
+        "delta", "conv" or "mla"; in a model whose FFNs differ by layer, then
+        ":" and its FFN's, "dense" or "moe". A layer of one branch (``one_branch``)
         names what it lacks "none": "ssm:none", "none:moe"."""
         pat = self.attn_pattern or (
             ("mla",) if self.has_mla else
@@ -537,7 +575,8 @@ class TransformerConfig:
         plain attention: the layer loop then runs by kind
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
-                or self.has_mla or self.has_delta or self.one_branch)
+                or self.has_mla or self.has_delta or self.has_conv
+                or self.one_branch)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -577,7 +616,7 @@ class TransformerConfig:
                          else 2) + (2 if self.sandwich_norm else 0))
         attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
         if self.qk_norm:
-            attn += (nh + nkv) * hd
+            attn += 2 * hd if self.qk_norm == "head" else (nh + nkv) * hd
         if self.qkv_bias:
             attn += (nh + 2 * nkv) * hd
         if self.proj_bias:
@@ -608,6 +647,10 @@ class TransformerConfig:
             from deepspeed_tpu.models import gated_delta
 
             mixers["delta"] = gated_delta.num_params(self)
+        if self.has_conv:
+            from deepspeed_tpu.models import short_conv
+
+            mixers["conv"] = short_conv.num_params(self)
         layers = 0
         for kind in self.layer_kinds:
             mixer, _, ffn = kind.partition(":")
@@ -890,12 +933,17 @@ def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
         q, k, v = linear(x, w["wq"]), linear(x, w["wk"]), linear(x, w["wv"])
         if "bq" in w:
             q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    if cfg.qk_norm:
+    if cfg.qk_norm == "width":
         # over the whole projection (every head held), before the split
         q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps)
         k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps)
-    return (q.reshape(B, T, H, hd), k.reshape(B, T, K, hd),
-            v.reshape(B, T, K, hd))
+    q, k = q.reshape(B, T, H, hd), k.reshape(B, T, K, hd)
+    if cfg.qk_norm == "head":
+        # over each head's channels, one scale for all heads; the caller's
+        # rope comes after it
+        q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps)
+        k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps)
+    return q, k, v.reshape(B, T, K, hd)
 
 
 def attn_out_proj(attn: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
@@ -1062,7 +1110,10 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # (models/mla.py); the shared experts inside moe
                "attn_mla", "mla_proj", "mla_rope", "moe_shared",
                # a delta layer's parts inside attn (models/gated_delta.py)
-               "delta_proj", "delta_conv", "delta_scan", "delta_gate")
+               "delta_proj", "delta_conv", "delta_scan", "delta_gate",
+               # a short-convolution layer's inside attn
+               # (models/short_conv.py)
+               "sconv_proj", "sconv_conv")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind. Layers of one branch
 #: each (``one_branch``) are half a block: a period of up to twice as many,
@@ -1082,7 +1133,7 @@ _KEEP_FP32 = ("A_log", "dt_bias", "D", "router_bias")
 #: a row for every layer. A layer of one branch names what it lacks "none"
 #: ("ssm:none", "none:moe") and keeps leaves in the one group it has
 _MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla",
-                "delta": "delta"}
+                "delta": "delta", "conv": "conv"}
 _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
 _KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
     _FFN_GROUP.values())
@@ -1113,7 +1164,7 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
     out = {}
     for k, v in w.items():
         with jax.named_scope(
-                "attn" if k in ("ln1", "attn", "ssm", "mla", "delta",
+                "attn" if k in ("ln1", "attn", "ssm", "mla", "delta", "conv",
                                 "ln1_post")
                 else _FFN_SCOPE.get(k, ffn)):
             if any(n in _KEEP_FP32 for n in v):
@@ -1142,7 +1193,9 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     layer of kind "ssm" mixes its tokens with ``w["ssm"]``
     (models/mamba.py:ssm_block) under ``attn/ssm_*``, one of kind "delta"
     with ``w["delta"]`` (models/gated_delta.py:delta_block) under
-    ``attn/delta_*``, one of kind "mla" with ``w["mla"]``
+    ``attn/delta_*``, one of kind "conv" with ``w["conv"]``
+    (models/short_conv.py:conv_block) under ``attn/sconv_*``, one of kind
+    "mla" with ``w["mla"]``
     (models/mla.py:mla_block); a kind that names its FFN
     ("mla:dense") runs ``moe_fn`` only where that is "moe". With ``mix_ms``
     the aux value is a dict that also holds the mean square of the mixer's
@@ -1161,7 +1214,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     res = cfg.residual_multiplier
     post = cfg.norm_placement == "post"
     with jax.named_scope("attn"), (jax.named_scope("attn_" + kind)
-                                   if kind and kind not in ("ssm", "delta")
+                                   if kind and kind not in ("ssm", "delta",
+                                                            "conv")
                                    else contextlib.nullcontext()):
         hn1 = x if post else _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
         if kind == "delta":
@@ -1173,6 +1227,11 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             from deepspeed_tpu.models.mamba import ssm_block
 
             attn_out = constrain(ssm_block(hn1, wc["ssm"], cfg),
+                                 P(("dp", "fsdp"), "sp", None))
+        elif kind == "conv":
+            from deepspeed_tpu.models.short_conv import conv_block
+
+            attn_out = constrain(conv_block(hn1, wc["conv"], cfg),
                                  P(("dp", "fsdp"), "sp", None))
         elif kind == "mla":
             from deepspeed_tpu.models.mla import mla_block
@@ -1509,6 +1568,13 @@ class TransformerLM:
                 f"whose recurrent and convolution state it would have to "
                 f"keep beside the key-value cache; only the train step runs "
                 f"them")
+        if cfg.has_conv:
+            raise NotImplementedError(
+                f"{what} is written for attention layers: this model has "
+                f"short-convolution layers (attn_pattern={cfg.attn_pattern}"
+                f"), whose last conv_taps - 1 = {cfg.conv_taps - 1} "
+                f"positions it would have to keep beside the key-value "
+                f"cache; only the train step runs them")
         if (cfg.norm_placement != "pre" or cfg.qk_norm
                 or cfg.heads_held is not None):
             raise NotImplementedError(
@@ -1584,10 +1650,12 @@ class TransformerLM:
             "layer_applications": self.layer_applications,
             # the period of layer kinds the layer loop scans ("window" /
             # "full" attention, "ssm" a state-space layer, "delta" a gated
-            # delta-rule layer; a stack whose FFNs differ by layer: its
-            # runs' kinds)
-            "layer_pattern": tuple(cfg.attn_pattern or dict.fromkeys(
-                cfg.layer_kinds))}
+            # delta-rule layer, "conv" a short convolution; a stack with a
+            # leading run of dense FFNs: its runs' kinds, "conv:dense",
+            # "full:moe")
+            "layer_pattern": tuple(
+                cfg.attn_pattern if cfg.attn_pattern and not cfg.first_k_dense
+                else dict.fromkeys(cfg.layer_kinds))}
         chunks = {}
         if cfg.has_ssm:
             chunks["ssm"] = cfg.ssm_chunk
@@ -1666,18 +1734,19 @@ class TransformerLM:
     def check_topology(self, axis_sizes: Dict[str, int]) -> None:
         """Raise where the mesh has an axis this model cannot be laid over:
         a ``tp`` axis divides an attention layer's heads, which a delta
-        layer's projections (replicated, ``gated_delta.param_specs``) and a
-        held share of the heads (``heads_held``, already one chip's part of
-        them) do not follow."""
+        or a conv layer's projections (replicated, ``gated_delta.param_specs``,
+        ``short_conv.param_specs``) and a held share of the heads
+        (``heads_held``, already one chip's part of them) do not follow."""
         cfg = self.cfg
-        if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta
+        if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta or cfg.has_conv
                                             or cfg.heads_held is not None):
             raise NotImplementedError(
                 f"a tp axis of {axis_sizes['tp']} with gated delta-rule "
-                f"layers or a held share of the heads (heads_held="
-                f"{cfg.heads_held}): tensor parallelism would divide heads "
-                f"that the delta layer keeps whole and that heads_held "
-                f"already divides")
+                f"layers, short-convolution layers or a held share of the "
+                f"heads (heads_held={cfg.heads_held}): tensor parallelism "
+                f"would divide heads that the delta layer keeps whole and "
+                f"that heads_held already divides, and a conv layer's fused "
+                f"in_proj holds three projections side by side")
 
     # ---- init -------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
@@ -1713,7 +1782,10 @@ class TransformerLM:
             attn_w["bv"] = jnp.zeros((La, K * hd), pd)
         if cfg.proj_bias:
             attn_w["bo"] = jnp.zeros((La, D), pd)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            attn_w["q_norm"] = jnp.ones((La, hd), pd)
+            attn_w["k_norm"] = jnp.ones((La, hd), pd)
+        elif cfg.qk_norm:
             attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
             attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
         # the FFNs: one stack with a row a layer, or (first_k_dense,
@@ -1775,6 +1847,12 @@ class TransformerLM:
             layers["delta"] = gated_delta.init(
                 jax.random.fold_in(rng, 15), cfg,
                 _in_group(kinds, "delta"), pd)
+        if cfg.has_conv:
+            from deepspeed_tpu.models import short_conv
+
+            layers["conv"] = short_conv.init(
+                jax.random.fold_in(rng, 17), cfg, _in_group(kinds, "conv"),
+                pd)
         if cfg.has_mla:
             from deepspeed_tpu.models import mla
 
@@ -2624,7 +2702,10 @@ class TransformerLM:
             attn_spec["bv"] = P(None, "tp")
         if cfg.proj_bias:
             attn_spec["bo"] = P(None, None)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            attn_spec["q_norm"] = P(None, None)
+            attn_spec["k_norm"] = P(None, None)
+        elif cfg.qk_norm:
             attn_spec["q_norm"] = P(None, "tp")
             attn_spec["k_norm"] = P(None, "tp")
         layer_specs: Params = {"ln1": norm_spec, "attn": attn_spec, "mlp": mlp}
@@ -2651,6 +2732,10 @@ class TransformerLM:
             from deepspeed_tpu.models import gated_delta
 
             layer_specs["delta"] = gated_delta.param_specs()
+        if cfg.has_conv:
+            from deepspeed_tpu.models import short_conv
+
+            layer_specs["conv"] = short_conv.param_specs()
         if not _in_group(cfg.layer_kinds, "attn"):
             del layer_specs["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):

@@ -1,14 +1,17 @@
-"""The depthwise causal convolution in front of a state-space or delta mixer,
-and the same convolution with its silu as one differentiable op.
+"""The depthwise causal convolution in front of a state-space or delta mixer
+or inside a short-convolution mixer, and the same convolution with its
+activation as one differentiable op.
 
-``y_t = silu(b + sum_k w[k] x_{t-(K-1)+k})`` over the last ``K`` positions
-of each channel, zeros before a sequence's start; taps, bias and silu in
-float32, one rounding to the output's dtype.
+``y_t = act(b + sum_k w[k] x_{t-(K-1)+k})`` over the last ``K`` positions
+of each channel, zeros before a sequence's start, ``act`` silu
+(:func:`causal_conv_silu`) or nothing (:func:`causal_conv_act` with
+``activation=None``); taps, bias and activation in float32, one rounding to
+the output's dtype.
 
 One algorithm, two lowerings (:func:`conv_lowering` picks by what the call
 can see: backend, dtype, shapes):
 
-* ``"xla"``: ``jax.nn.silu(causal_conv(x, w, b)).astype(out_dtype)``:
+* ``"xla"``: ``act(causal_conv(x, w, b)).astype(out_dtype)``:
   ``jnp.pad``, a cast to float32, ``K`` shifted multiply-adds and autodiff's
   backward (the pre-activation rebuilt, a second padded pass for ``dx``, a
   reduction over positions for each tap's weight). What a CPU and a float32
@@ -23,7 +26,8 @@ can see: backend, dtype, shapes):
   into (a state-space mixer's ``x``, ``B`` and ``C``), whose cotangents
   the backward then takes as it gets them. The backward visits a
   sequence's tiles last first: it rebuilds the pre-activation, forms ``g =
-  dy silu'``, keeps the first rows of ``g`` for the tile before (the
+  dy silu'`` (without an activation ``g = dy`` and nothing is rebuilt),
+  keeps the first rows of ``g`` for the tile before (the
   anti-causal halo of ``dx = sum_k w[k] g_{t+(K-1)-k}``), and sums ``dw``
   and ``db`` in float32 scratch over every tile, written once. Nothing
   ``[T, C]`` in float32 goes to HBM.
@@ -192,7 +196,8 @@ def _sigmoid(v):
     return 0.5 * jnp.tanh(0.5 * v) + 0.5
 
 
-def _fwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths):
+def _fwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths,
+                act: Optional[str] = "silu"):
     """One tile of positions. ``rest``: the bias (where ``bias``), the
     result's parts, then scratch: the tile in float32 behind its halo
     [_HALO + rows, C]."""
@@ -202,7 +207,7 @@ def _fwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths):
     for r, n, lanes, p, cols in _pieces(x_ref.shape[0], widths):
         pre = _pre(_taps(xf_ref, r, n, lanes, K), w_ref[:, lanes],
                    b_ref[:, lanes] if bias else None)
-        y_refs[p][r:r + n, cols] = (pre * _sigmoid(pre)
+        y_refs[p][r:r + n, cols] = (pre * _sigmoid(pre) if act else pre
                                     ).astype(y_refs[p].dtype)
 
 
@@ -212,7 +217,8 @@ def _eight(v):
     return jnp.sum(v.reshape(v.shape[0] // 8, 8, v.shape[1]), axis=0)
 
 
-def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths):
+def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths,
+                act: Optional[str] = "silu"):
     """One tile of positions, a sequence's tiles last first. ``rest``: the
     bias (where ``bias``), ``dy``'s parts, then ``dx``, ``dw``, ``db``
     (where ``bias``), then scratch, all float32: the tile behind its halo,
@@ -240,10 +246,11 @@ def _bwd_kernel(x_ref, halo_ref, w_ref, *rest, K: int, bias: bool, widths):
     for r, n, lanes, p, cols in reversed(_pieces(rows, widths)):
         taps = _taps(xf_ref, r, n, lanes, K)
         w = w_ref[:, lanes]
-        pre = _pre(taps, w, b_ref[:, lanes] if bias else None)
-        s = _sigmoid(pre)
-        g = dy_refs[p][r:r + n, cols].astype(F32) \
-            * (s * (1.0 + pre * (1.0 - s)))
+        g = dy_refs[p][r:r + n, cols].astype(F32)
+        if act:
+            pre = _pre(taps, w, b_ref[:, lanes] if bias else None)
+            s = _sigmoid(pre)
+            g = g * (s * (1.0 + pre * (1.0 - s)))
         g_ref[r:r + n, lanes] = g
         dx = None
         for k in range(K):
@@ -293,11 +300,11 @@ def _operands(x, w, b):
     return ops
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "widths", "interpret"))
+@functools.partial(jax.jit, static_argnames=("out_dtype", "widths",
+                                             "interpret", "act"))
 def conv_fwd(x, w, b, *, out_dtype: str, widths: Tuple[int, ...],
-             interpret: bool = False):
-    """``silu(causal_conv(x, w, b))`` in ``out_dtype`` as its parts
+             interpret: bool = False, act: Optional[str] = "silu"):
+    """``act(causal_conv(x, w, b))`` in ``out_dtype`` as its parts
     [B, T, width], side by side the whole [B, T, C]."""
     B, T, C = x.shape
     K = w.shape[0]
@@ -305,7 +312,8 @@ def conv_fwd(x, w, b, *, out_dtype: str, widths: Tuple[int, ...],
     tile, halo, taps, row = _specs(rows, C, K, T // rows, flip=False)
     bias = b is not None
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, K=K, bias=bias, widths=widths),
+        functools.partial(_fwd_kernel, K=K, bias=bias, widths=widths,
+                          act=act),
         grid=(B, T // rows),
         in_specs=[tile(C), halo, taps] + [row] * bias,
         out_specs=[tile(width) for width in widths],
@@ -319,8 +327,9 @@ def conv_fwd(x, w, b, *, out_dtype: str, widths: Tuple[int, ...],
     )(*_operands(x, w, b))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def conv_bwd(x, w, b, dy, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("interpret", "act"))
+def conv_bwd(x, w, b, dy, *, interpret: bool = False,
+             act: Optional[str] = "silu"):
     """The cotangents of :func:`conv_fwd`'s ``x``, ``w`` and ``b`` (None
     where there is no bias) from ``dy``, the cotangents of its parts."""
     B, T, C = x.shape
@@ -330,7 +339,8 @@ def conv_bwd(x, w, b, dy, *, interpret: bool = False):
     tile, halo, taps, row = _specs(rows, C, K, T // rows, flip=True)
     bias = b is not None
     out = pl.pallas_call(
-        functools.partial(_bwd_kernel, K=K, bias=bias, widths=widths),
+        functools.partial(_bwd_kernel, K=K, bias=bias, widths=widths,
+                          act=act),
         grid=(B, T // rows),
         in_specs=[tile(C), halo, taps] + [row] * bias
         + [tile(width) for width in widths],
@@ -354,36 +364,40 @@ def conv_bwd(x, w, b, dy, *, interpret: bool = False):
 # the op
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _conv_pallas(x, w, b, out_dtype, widths, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _conv_pallas(x, w, b, out_dtype, widths, interpret, act):
     return tuple(conv_fwd(x, w, b, out_dtype=out_dtype, widths=widths,
-                          interpret=interpret))
+                          interpret=interpret, act=act))
 
 
-def _conv_pallas_fwd(x, w, b, out_dtype, widths, interpret):
-    return _conv_pallas(x, w, b, out_dtype, widths, interpret), (x, w, b)
+def _conv_pallas_fwd(x, w, b, out_dtype, widths, interpret, act):
+    return _conv_pallas(x, w, b, out_dtype, widths, interpret, act), (x, w, b)
 
 
-def _conv_pallas_bwd(out_dtype, widths, interpret, res, dy):
+def _conv_pallas_bwd(out_dtype, widths, interpret, act, res, dy):
     lowerings.count("conv", "pallas")     # the kernels' own backward
-    return conv_bwd(*res, tuple(dy), interpret=interpret)
+    return conv_bwd(*res, tuple(dy), interpret=interpret, act=act)
 
 
 _conv_pallas.defvjp(_conv_pallas_fwd, _conv_pallas_bwd)
 
 
-def causal_conv_silu(x: jax.Array, w: jax.Array,
-                     b: Optional[jax.Array] = None, out_dtype=None,
-                     splits: Tuple[int, ...] = (),
-                     interpret: Optional[bool] = None):
-    """x [B, T, C], w [K, C], b [C] or None -> ``silu(causal_conv(x, w,
-    b))`` [B, T, C] rounded once, to ``out_dtype`` (``x``'s where None).
+def causal_conv_act(x: jax.Array, w: jax.Array,
+                    b: Optional[jax.Array] = None, out_dtype=None,
+                    splits: Tuple[int, ...] = (),
+                    interpret: Optional[bool] = None, *,
+                    activation: Optional[str]):
+    """x [B, T, C], w [K, C], b [C] or None -> ``act(causal_conv(x, w,
+    b))`` [B, T, C] rounded once, to ``out_dtype`` (``x``'s where None);
+    ``activation`` is ``"silu"`` or None (the convolution as it is).
     With ``splits`` (channel indices, as ``jnp.split`` takes them) the
     result comes as its parts between them, a list: the kernels write each
     as an array of its own and take their cotangents the same way, so that
     nothing puts the parts side by side in either direction. ``interpret``
     is the kernels' test handle (None: ask :func:`conv_lowering`; True: the
     kernels, interpreted, in any float dtype, for shapes they take)."""
+    if activation not in (None, "silu"):
+        raise ValueError(f"activation={activation!r}: 'silu' or None")
     _, T, C = x.shape
     K, splits = w.shape[0], tuple(int(c) for c in splits)
     out_dtype = jnp.dtype(x.dtype if out_dtype is None else out_dtype)
@@ -398,8 +412,13 @@ def causal_conv_silu(x: jax.Array, w: jax.Array,
     # the kernels' backward (the ``jax.numpy`` form's is autodiff's)
     lowerings.count("conv", lowering)
     if lowering == "xla":
-        y = jax.nn.silu(causal_conv(x, w, b)).astype(out_dtype)
+        y = causal_conv(x, w, b)
+        y = (jax.nn.silu(y) if activation else y).astype(out_dtype)
         return jnp.split(y, splits, axis=-1) if splits else y
     parts = _conv_pallas(x, w, b, out_dtype.name, _widths(C, splits),
-                         bool(interpret))
+                         bool(interpret), activation)
     return list(parts) if splits else parts[0]
+
+
+#: the silu spelling, what a state-space and a delta mixer call
+causal_conv_silu = functools.partial(causal_conv_act, activation="silu")

@@ -150,6 +150,50 @@ def test_nothing_crosses_a_rows_start(monkeypatch, tiles):
                                atol=1e-6)
 
 
+@pytest.mark.parametrize("tiles", sorted(TILES))
+@pytest.mark.parametrize("bias", [False, True], ids=["no-bias", "bias"])
+@pytest.mark.parametrize("K", [3, 4])
+@pytest.mark.parametrize("dtype", [F32, BF], ids=["float32", "bf16"])
+def test_without_an_activation_the_kernels_are_the_convolution(
+        monkeypatch, dtype, K, bias, tiles):
+    """``activation=None`` (a short-convolution mixer's call: 3 taps, no
+    bias, 2048 channels): the interpreted kernels against the ``"xla"`` form
+    of the same op, forward and both gradients (``g = dy``: the backward
+    rebuilds nothing). In float32 to the order of a sum; in bf16 to one
+    rounding of the result, ``dx`` and the float32 sums of ``dw``, ``db``."""
+    C = 2048 if not bias and K == 3 else 384
+    _tiles_of(monkeypatch, TILES[tiles])
+    args = _inputs(C, bias, dtype, K=K)
+    op = functools.partial(cc.causal_conv_act, activation=None)
+
+    def plain(x, w, b=None, out_dtype=None, splits=()):
+        return cc.causal_conv(x, w, b).astype(out_dtype or x.dtype)
+
+    snap = lowerings.snapshot()
+    assert op(*args).dtype == dtype                      # the "xla" form
+    assert lowerings.since(snap)["conv"] == {"xla": 1}
+    np.testing.assert_array_equal(_f32(op(*args)), _f32(plain(*args)))
+    y_k, g_k = _grads(functools.partial(op, interpret=True), args, dtype)
+    y_n, g_n = _grads(plain, args, dtype)
+    assert y_k.dtype == y_n.dtype == dtype
+    tol = 2.0 ** -8 if dtype == BF else 2e-6
+    np.testing.assert_allclose(_f32(y_k), _f32(y_n), rtol=tol, atol=1e-6)
+    for name, k, n in zip("xwb", g_k, g_n):
+        assert k.shape == n.shape and k.dtype == n.dtype == dtype, name
+        np.testing.assert_allclose(
+            _f32(k), _f32(n), rtol=tol, err_msg=name,
+            atol=tol * float(jnp.abs(_f32(n)).max()))
+    # and it is not the silu form
+    assert not np.allclose(_f32(y_k), _f32(numpy_form(*args, dtype)),
+                           atol=1e-2)
+
+
+def test_an_unknown_activation_is_refused():
+    with pytest.raises(ValueError, match="'silu' or None"):
+        cc.causal_conv_act(*_inputs(128, False, F32, B=1, T=16),
+                           activation="relu")
+
+
 @pytest.mark.parametrize("K", [1, 2, 8])
 def test_other_tap_counts(monkeypatch, K):
     """One tap (no halo at all), two, and eight: the whole of the eight rows

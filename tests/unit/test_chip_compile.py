@@ -224,12 +224,15 @@ def _delta(grad, H=15, dk=96, dv=192, T=4096, einsum=False, unit=True):
         qk, qk, ((1, T, H, dv), jnp.bfloat16), gb, gb]
 
 
-def _conv(grad, C=4352, bias=True, out=jnp.bfloat16, splits=(), T=4096, K=4):
+def _conv(grad, C=4352, bias=True, out=jnp.bfloat16, splits=(), T=4096, K=4,
+          act="silu"):
     """The mixers' convolution with its silu at the Granite cell's call
     (BENCHMARK.json: 1 x 4096 tokens, xBC 4352 wide, a bias, bf16 out, x, B
     and C as arrays of their own) and the Olmo-Hybrid cell's three (q and k
     1440 wide to float32, v 2880 wide to bf16, no bias; rows that end inside
-    a lane tile), by the picker's answer for the shape as if on the chip:
+    a lane tile), and without an activation at the LFM2 cell's (2 x 8192
+    tokens a step, a row a call here; 2048 wide, 3 taps, no bias), by the
+    picker's answer for the shape as if on the chip:
     the forward kernel alone, and the differentiated forward with the
     backward kernel."""
     from deepspeed_tpu.ops import causal_conv as cc
@@ -240,7 +243,7 @@ def _conv(grad, C=4352, bias=True, out=jnp.bfloat16, splits=(), T=4096, K=4):
         if took == "xla":
             return (jax.nn.silu(cc.causal_conv(x, w, b)).astype(out),)
         return cc._conv_pallas(x, w, b, jnp.dtype(out).name,
-                               cc._widths(C, splits), False)
+                               cc._widths(C, splits), False, act)
 
     def loss(*a):
         return sum((part.astype(jnp.float32) ** 2).sum() for part in fwd(*a))
@@ -351,6 +354,12 @@ CASES = {
         _conv, dict(grad=True, C=2880, bias=False), True),
     "conv-silu-grad-T4090-numpy-form": (
         _conv, dict(grad=True, C=256, T=4090), False),
+    "conv-plain-fwd-lfm2-cell": (
+        _conv, dict(grad=False, C=2048, bias=False, T=8192, K=3, act=None),
+        True),
+    "conv-plain-grad-lfm2-cell": (
+        _conv, dict(grad=True, C=2048, bias=False, T=8192, K=3, act=None),
+        True),
 }
 
 
@@ -894,9 +903,9 @@ def test_kernel_path_rules_match_what_compiled():
 
 
 def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
-                       parameters: int, seq: int = 4096):
+                       parameters: int, seq: int = 4096, rows: int = 1):
     """A benchmark cell's whole step (``benchmarks/configs/<config>.json``
-    at 1 x ``seq`` tokens, the file's recomputation policy): gradient and
+    at ``rows`` x ``seq`` tokens, the file's recomputation policy): gradient and
     AdamW over fp32 master weights, compiled for the chip, every picker
     answering as on a TPU."""
     import importlib
@@ -942,7 +951,7 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
         == parameters
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(params), described(jax.eval_shape(tx.init, params)),
-        {"input_ids": jax.ShapeDtypeStruct((1, seq), jnp.int32,
+        {"input_ids": jax.ShapeDtypeStruct((rows, seq), jnp.int32,
                                            sharding=one_chip)}).compile()
     mem = compiled.memory_analysis()
     # fp32 weights, Adam m and v: 12 B a parameter as arguments
@@ -1057,6 +1066,54 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     assert "/moe/moe_latent/" in text and "ragged-dot" not in text
     # the router's chosen scores, counts and rows: no gather, no scatter;
     # nor the combine's backward (a row's weight, a pair's dot)
+    assert "/moe/moe_router/" in text
+    assert _index_ops_under(text, "moe_router") == []
+    assert _index_ops_under(text, "moe_dispatch") == []
+
+
+def test_the_lfm2_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    lfm2_24b_train_d5e8v8.json``: a dense conv layer and one period, full,
+    conv, conv, conv, at the published widths, 2 x 8192 tokens,
+    ``dots_saveable``). It fits beside what a chip reserves; each of the two
+    runs of conv layers holds the convolution's forward kernel twice (once
+    in the backward's recomputed region: a kernel is no dot) and its
+    backward once under ``attn/sconv_conv``, **without an activation and
+    not as the padded float32 form**, with the two gates' products beside
+    them and the projections under ``attn/sconv_proj``; the attention layer
+    runs the flash kernels at 64-wide heads under ``attn/attn_full``, whose
+    instructions keep the names the benchmark's patterns look for, behind
+    the per-head norm and the rope; the four routed layers run the grouped
+    products and the row kernels under ``moe``, and under ``moe_router`` and
+    ``moe_dispatch`` nothing is gathered or scattered."""
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "lfm2_24b_train_d5e8v8", "modelcfg_lfm2",
+        469_285_248, seq=8192, rows=2)
+    assert mem.temp_size_in_bytes < 7.4e9
+    calls = _kernel_calls(text, "sconv_conv")
+    assert calls and all("/attn/sconv_conv/" in n for n in calls)
+    fwd = [n for n in calls if "jit(conv_fwd)" in n]
+    bwd = [n for n in calls if "jit(conv_bwd)" in n]
+    assert len(fwd) == 4 and len(bwd) == 2 and len(calls) == 6
+    assert sum("rematted_computation" in n for n in fwd) == 2
+    assert all("transpose(" in n for n in bwd)
+    beside = {n.rsplit("/", 1)[-1] for n in re.findall(
+        r'op_name="([^"]*)"', text) if "/sconv_conv/" in n}
+    assert "mul" in beside                      # the two gates
+    assert not beside & {"pad", "logistic", "reduce_sum"}, beside
+    assert "/attn/sconv_proj/" in text
+    assert not _kernel_calls(text, "sconv_proj")
+    assert len(_kernel_calls(text, "attn_full")) == 3
+    assert len(re.findall(r"^\s*%attn_full[.\d]* = .*custom-call\(.*"
+                          r"tpu_custom_call", text, re.M)) == 3
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert any("jit(sum_of_rows)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)
+    assert "ragged-dot" not in text and "/mlp/" in text
     assert "/moe/moe_router/" in text
     assert _index_ops_under(text, "moe_router") == []
     assert _index_ops_under(text, "moe_dispatch") == []

@@ -556,7 +556,7 @@ def test_the_new_fields_refuse_what_they_cannot_mean():
     for bad, says in (
             (dict(norm_placement="both"), "'pre' or 'post'"),
             (dict(norm_placement="post", sandwich_norm=True), "one norm"),
-            (dict(qk_norm="head"), "None or 'width'"),
+            (dict(qk_norm="rows"), "None, 'width'"),
             (dict(heads_held=5), "not among"),
             (dict(heads_held=1, num_kv_heads=2), "cut a group"),
             (dict(delta_heads=0, attn_pattern=("delta", "full")),

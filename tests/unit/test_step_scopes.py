@@ -77,6 +77,19 @@ CASES = {
                       "delta_key_dim": 8, "delta_value_dim": 16,
                       "delta_neg_eigval": True,
                       "tie_embeddings": False, "remat_policy": "full"}, 1),
+    # gated short convolutions and an attention layer whose q and k are
+    # normed per head before the rope, under recomputation; a dense FFN then
+    # routed ones (a sigmoid router, a held share) under the pattern of
+    # mixer kinds, three runs: the conv mixer's parts nest inside attn (the
+    # projections; the gates and the convolution), the attention layer keeps
+    # attn_full, the dense layer's FFN keeps mlp
+    "conv_moe": ({"num_layers": 5,
+                  "attn_pattern": ("conv", "full", "conv", "conv", "conv"),
+                  "qk_norm": "head", "first_k_dense": 1, "num_experts": 8,
+                  "top_k": 2, "moe_dispatch": "grouped",
+                  "moe_intermediate_size": 32, "moe_experts_held": 4,
+                  "moe_scoring": "sigmoid", "moe_bias_rate": 1e-3,
+                  "moe_bias_init": 0.1, "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -91,7 +104,12 @@ NESTED_DELTA = {"attn_full": "attn", "delta_proj": "attn",
 # first does
 ONE_OF_EACH = {"hybrid": {"num_layers": 2, "attn_pattern": ("ssm", "full")},
                "delta_hybrid": {"num_layers": 2,
-                                "attn_pattern": ("delta", "full")}}
+                                "attn_pattern": ("delta", "full")},
+               "conv_moe": {"num_layers": 2,
+                            "attn_pattern": ("conv", "full")}}
+NESTED_CONV = {"attn_full": "attn", "sconv_proj": "attn",
+               "sconv_conv": "attn", "moe_router": "moe",
+               "moe_dispatch": "moe", "moe_experts": "moe"}
 NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
               "mla_rope": "attn_mla", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe",
@@ -127,12 +145,14 @@ def test_every_operation_carries_a_step_scope(case):
     assert loose == []
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
-    ffn = "moe" if case in ("moe", "pattern_share", "mla_moe") else "mlp"
+    ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
+                            "conv_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
-    if case == "mla_moe":
+    if case in ("mla_moe", "conv_moe"):
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
-              "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA}.get(case)
+              "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
+              "conv_moe": NESTED_CONV}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
@@ -214,11 +234,17 @@ def test_the_rule_kernels_keep_the_scan_scope(monkeypatch):
 # (case, the mixer's module, the convolution's scope, the scan's, what the
 # trace counts: a convolution and its backward for the one state-space layer
 # kept of the hybrid case, three convolutions and the backward of each for
-# the one delta layer kept of the delta case)
+# the one delta layer kept of the delta case, the convolution without an
+# activation and its backward for the one conv layer kept of the conv case,
+# which has no scan: its kernels do not lie among the projections), and the
+# name the mixer calls the op by
 CONV_KERNELS = {
-    "hybrid": ("mamba", "ssm_conv", "ssm_scan", 2, "full"),
+    "hybrid": ("mamba", "ssm_conv", "ssm_scan", 2, "full",
+               "causal_conv_silu"),
     "delta_hybrid": ("gated_delta", "delta_conv", "delta_scan", 6,
-                     "dots_saveable"),
+                     "dots_saveable", "causal_conv_silu"),
+    "conv_moe": ("short_conv", "sconv_conv", "sconv_proj", 2, "full",
+                 "causal_conv_act"),
 }
 
 
@@ -237,15 +263,15 @@ def test_the_conv_kernels_keep_the_conv_scope(monkeypatch, case):
     import functools
     import importlib
 
-    module, conv, scan, counted, policy = CONV_KERNELS[case]
+    module, conv, scan, counted, policy, op = CONV_KERNELS[case]
     over = dict(CASES[case][0], **ONE_OF_EACH[case], remat_policy=policy)
     if case == "hybrid":        # x, B and C of whole lane tiles
         over.update(ssm_heads=2, ssm_head_dim=64, ssm_state=128, ssm_groups=1)
     _op_names(over, 1)
     assert steplog.programs()[-1].conv_lowerings == {"xla": counted // 2}
     mixer = importlib.import_module(f"deepspeed_tpu.models.{module}")
-    monkeypatch.setattr(mixer, "causal_conv_silu", functools.partial(
-        mixer.causal_conv_silu, interpret=True))
+    monkeypatch.setattr(mixer, op, functools.partial(
+        getattr(mixer, op), interpret=True))
     names = _op_names(over, 1)
     assert steplog.programs()[-1].conv_lowerings == {"pallas": counted}
     parts = [set(re.split(r"[/()]", n)) for n in names]
