@@ -46,10 +46,11 @@ from __future__ import annotations
 
 import gc
 import itertools
+import re
 import threading
 import time
 import weakref
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -447,6 +448,38 @@ class StepProgram:
     def hlo_text(self) -> Optional[str]:
         c = self.compiled()
         return None if c is None else c.as_text()
+
+    def recomputed_kernels(self) -> Optional[Dict[str, int]]:
+        """:func:`recomputed_kernels` of the compiled program's text: where
+        the recomputation policy still pays for a kernel twice."""
+        text = self.hlo_text()
+        return None if text is None else recomputed_kernels(text)
+
+
+_MOSAIC_CALL = re.compile(
+    r' custom-call\(.*tpu_custom_call.*op_name="([^"]*)"')
+_LOOP = re.compile(r' while\(.*op_name="([^"]*)"')
+
+
+def kernel_calls(hlo_text: str, scope: Optional[str] = None) -> List[str]:
+    """``op_name`` of a compiled program's kernel calls, those under
+    ``/<scope>/`` where one is given: its Mosaic calls, or, in a program
+    without any (off the chip a kernel runs interpreted, as a loop over its
+    grid), its loops, a ``lax.scan`` inside a layer among them."""
+    names = _MOSAIC_CALL.findall(hlo_text) or _LOOP.findall(hlo_text)
+    return [n for n in names if scope is None or f"/{scope}/" in n]
+
+
+def recomputed_kernels(hlo_text: str) -> Dict[str, int]:
+    """How many of a compiled program's :func:`kernel_calls` stand in a
+    ``rematted_computation``, the region ``jax.checkpoint`` makes again for
+    the backward, by innermost scope (the name in front of the call's own
+    ``jit(...)``): ``{"sconv_conv": 2}`` says two calls under that scope run a
+    second time, which a policy that kept what their kernel's forward rule
+    names would spare (``runtime/activation_checkpointing.py``)."""
+    return dict(Counter(
+        [p for p in name.split("/")[:-1] if "(" not in p][-1]
+        for name in kernel_calls(hlo_text) if "rematted_computation" in name))
 
 
 _PROGRAMS: deque = deque(maxlen=64)

@@ -14,7 +14,9 @@ is a first-class Pallas TPU kernel:
   built only on tiles the diagonal or the window's edge crosses;
 * GQA folded into the BlockSpec index maps (KV head = Q head // group);
 * fp32 accumulation, bf16 inputs; logsumexp saved for the backward, as ``[B, H, 1, T]``
-  rows where a ``[1, block_q]`` block is legal;
+  rows where a ``[1, block_q]`` block is legal; the forward rules give it and the
+  output a ``checkpoint_name`` each (:data:`RESIDUAL_NAMES`), so that a recomputation
+  policy can keep the two and the backward's recomputed region holds no second forward;
 * backward = one fused kernel (dq, dk, dv from one S, one exp and one dP per tile
   pair, the head's dq resident in VMEM) using the saved logsumexp, the standard
   flash-attention-2 recurrence; where a head's dq does not fit VMEM (long
@@ -58,6 +60,12 @@ from deepspeed_tpu.ops import lowerings
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
+#: the ``checkpoint_name`` of the forward kernel's output and of its log-sum-exp
+#: (``_named_fwd``). Literals, and not the policy table's constants that equal them
+#: (``runtime/activation_checkpointing.py``; ``tests/unit/test_flash_remat.py``
+#: holds the two equal): every model imports this module, and an import of
+#: ``deepspeed_tpu.runtime`` from here would load the engine with it
+RESIDUAL_NAMES = ("flash_attn_out", "flash_attn_lse")
 
 
 # ---------------------------------------------------------------------------
@@ -892,11 +900,26 @@ def _flash(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
     return out
 
 
-def _flash_fwd(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
-               interpret):
+def _named_fwd(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
+               interpret, rel_offset=0):
+    """The forward kernel's two results as both forward rules return them and
+    keep them for the backward: each under its ``checkpoint_name``, in the
+    kernel's own layout, so that a recomputation policy that keeps the names
+    keeps the very values the backward reads, and the recomputed region holds
+    no second forward. q, k, v and the rope operands stay residuals that the
+    region makes again. Outside ``jax.checkpoint`` a name is the identity."""
     out, lse = _fwd_pallas(q, k, v, scale=_scale(q, q_rope), causal=causal,
                            window=window, block_q=block_q, block_k=block_k,
-                           interpret=interpret, q_rope=q_rope, k_rope=k_rope)
+                           interpret=interpret, rel_offset=rel_offset,
+                           q_rope=q_rope, k_rope=k_rope)
+    return (checkpoint_name(out, RESIDUAL_NAMES[0]),
+            checkpoint_name(lse, RESIDUAL_NAMES[1]))
+
+
+def _flash_fwd(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
+               interpret):
+    out, lse = _named_fwd(q, k, v, q_rope, k_rope, causal, window, block_q,
+                          block_k, interpret)
     return out, (q, k, v, q_rope, k_rope, out, lse)
 
 
@@ -921,10 +944,8 @@ def _flash_lse(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
 
 def _flash_lse_fwd(q, k, v, q_rope, k_rope, causal, window, block_q, block_k,
                    interpret, rel_offset=0):
-    out, lse = _fwd_pallas(q, k, v, scale=_scale(q, q_rope), causal=causal,
-                           window=window, block_q=block_q, block_k=block_k,
-                           interpret=interpret, rel_offset=rel_offset,
-                           q_rope=q_rope, k_rope=k_rope)
+    out, lse = _named_fwd(q, k, v, q_rope, k_rope, causal, window, block_q,
+                          block_k, interpret, rel_offset)
     # the residual keeps the kernel's layout (rows for the fused backward);
     # the public result its documented column
     B, H, T, _ = q.shape
@@ -1045,11 +1066,7 @@ def flash_attention(q: jax.Array, k: jax.Array,
     bk = _pick_block(S, block_k)
     out = _flash(*_heads_first(q, k, v, q_rope, k_rope), causal, window, bq,
                  bk, interpret)
-    out = out.transpose(0, 2, 1, 3)
-    # Named so a remat policy can keep the kernel's output (``attn_saveable``,
-    # ``dots_and_attn_saveable``). Keeping it does not spare the backward a
-    # second run of the forward kernel today: the log-sum-exp is a residual of
-    # the custom_vjp that no policy can name, so under either policy the
-    # forward runs again for it (measured: PERF.md section 4; what would fix
-    # it: ROADMAP L2 (a)).
-    return checkpoint_name(out, "flash_attn_out")
+    # the output and the log-sum-exp carry their names inside the forward
+    # rule, in the kernel's layout (``_named_fwd``): none here, so that a
+    # policy keeps the output once and this relayout is made again
+    return out.transpose(0, 2, 1, 3)

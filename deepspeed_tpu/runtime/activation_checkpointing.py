@@ -24,9 +24,15 @@ POLICIES = (
     "dots_and_attn_saveable", "offload_dots", "offload_attn",
 )
 
-#: the checkpoint_name tag attached by ops/flash_attention.py (and the XLA
-#: fallback) to the attention output so policies can pin it
+#: the checkpoint_name tags ops/flash_attention.py attaches, in the forward
+#: rule of its kernels' ``custom_vjp`` (``RESIDUAL_NAMES`` there: literals, so
+#: that module imports nothing of this package), to the kernel's output
+#: ``[B, H, T, dv]`` in the kernel's layout and to its log-sum-exp rows
+#: (float32): the two values the backward reads of the forward. The XLA
+#: fallback (``models/transformer.py:xla_attention``) tags its output with the
+#: first; it has no log-sum-exp, and its products are dots
 ATTN_CHECKPOINT_NAME = "flash_attn_out"
+ATTN_LSE_CHECKPOINT_NAME = "flash_attn_lse"
 #: the tags ops/delta_rule.py attaches, in the forward of its kernels'
 #: ``custom_vjp``, to the rule's output and to the chunks' incoming states:
 #: the products a policy that keeps dots keeps of the einsum form
@@ -42,32 +48,34 @@ def resolve_policy(policy: str):
     if policy in (None, "none", "full"):
         return None
     cp = jax.checkpoint_policies
+    attn = (ATTN_CHECKPOINT_NAME, ATTN_LSE_CHECKPOINT_NAME)
     if policy == "attn_saveable":
-        # save only the attention output. Meant to spare the backward a
-        # second run of the attention forward; it does not today: the flash
-        # kernel's log-sum-exp is a residual this policy cannot name, so the
-        # forward kernel runs again for it all the same (PERF.md section 4;
-        # ROADMAP L2 (a) says what would fix it)
-        return cp.save_only_these_names(ATTN_CHECKPOINT_NAME)
-    if policy == "dots_and_attn_saveable":
-        # dots_saveable alone keeps nothing of the (opaque-to-XLA) pallas
-        # attention call; keep its named output as well (the forward kernel
-        # still runs again for the log-sum-exp, as above)
+        # keep what the flash kernel named, its output and its log-sum-exp:
+        # the two values its backward reads, so the recomputed region holds
+        # no second run of the forward kernel (what stands in front of it,
+        # norms, projections and rope, is made again from the layer's input)
+        return cp.save_only_these_names(*attn)
+    if policy in ("dots_saveable", "dots_and_attn_saveable"):
+        # a kernel's products are no dots: keep what a kernel named of what
+        # the einsum form's dots would have been (the flash kernel's output
+        # and log-sum-exp, the delta rule's output and chunk states), so that
+        # the recomputed region holds no second forward of either (a program
+        # without those names is unchanged). A flash layer costs one
+        # ``[B, H, T, dv]`` array and one float32 row set. One policy under
+        # two names: keeping dots has to keep the kernels' names to run them
+        # once, and the XLA fallback's named output is a dot's result
         return cp.save_from_both_policies(
-            cp.dots_saveable, cp.save_only_these_names(ATTN_CHECKPOINT_NAME))
-    if policy == "dots_saveable":
-        # a kernel's products are no dots: where the delta rule ran as
-        # kernels, keep what it named, so that the recomputed region holds
-        # no second forward (a program without those names is unchanged)
-        return cp.save_from_both_policies(
-            cp.dots_saveable, cp.save_only_these_names(*RULE_CHECKPOINT_NAMES))
+            cp.dots_saveable,
+            cp.save_only_these_names(*attn, *RULE_CHECKPOINT_NAMES))
     if policy == "offload_attn":
         # the FPDT/Ulysses-Offload memory tier (sequence/fpdt_layer.py:545):
         # attention outputs live in HOST memory between forward and backward,
         # freeing HBM ∝ L·B·T·D for long-context training; XLA schedules the
-        # D2H/H2D copies asynchronously around the remat boundaries
+        # D2H/H2D copies asynchronously around the remat boundaries. The
+        # log-sum-exp (float32 rows, 2/dv of the output's bytes at bf16)
+        # stays on the device, so no forward is run again for it
         return cp.save_and_offload_only_these_names(
-            names_which_can_be_saved=[],
+            names_which_can_be_saved=[ATTN_LSE_CHECKPOINT_NAME],
             names_which_can_be_offloaded=[ATTN_CHECKPOINT_NAME],
             offload_src="device", offload_dst="pinned_host")
     if policy == "offload_dots":

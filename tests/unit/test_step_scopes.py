@@ -335,6 +335,33 @@ def test_the_flash_kernels_in_parts_stay_under_attn_mla(monkeypatch):
     assert steplog.programs()[-1].flash_rope_operand_lowerings == {"none": 3}
 
 
+@pytest.mark.parametrize("policy,again", [
+    ("dots_saveable", {"sconv_conv": 1}),
+    ("full", {"sconv_conv": 1, "attn_full": 1})])
+def test_the_row_counts_the_kernels_run_again(monkeypatch, policy, again):
+    """A conv layer and an attention layer through their kernels
+    (interpreted here: a kernel is a loop over its grid) under a dense FFN:
+    the step-program row counts, by innermost scope, the kernel calls in the
+    backward's recomputed region. ``dots_saveable`` keeps what the flash
+    forward rule named, so only the convolution's forward (a kernel is no
+    dot, and it names nothing) runs again; under ``full`` both do."""
+    import functools
+
+    from deepspeed_tpu.models import short_conv
+
+    monkeypatch.setattr(short_conv, "causal_conv_act", functools.partial(
+        short_conv.causal_conv_act, interpret=True))
+    _op_names({"num_layers": 2, "attn_pattern": ("conv", "full"),
+               "qk_norm": "head", "attention_impl": "flash_pallas",
+               "remat_policy": policy}, 1)
+    row = steplog.programs()[-1]
+    assert row.conv_lowerings == {"pallas": 2}
+    assert row.recomputed_kernels() == again
+    calls = steplog.kernel_calls(row.hlo_text(), "attn_full")
+    assert len(calls) == 2 + ("attn_full" in again)
+    assert sum("transpose(" in n for n in calls) == 1 + ("attn_full" in again)
+
+
 def test_backward_operations_keep_their_scope():
     names = _op_names(*CASES["dense"])
     back = [n for n in names if "transpose(" in n]
