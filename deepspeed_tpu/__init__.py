@@ -7,18 +7,22 @@ engine with ``forward/backward/step`` plus data loader and LR scheduler.
 """
 
 import sys as _sys
-import time as _time
 
-_t_import, _jax_preloaded = _time.perf_counter(), "jax" in _sys.modules
+from deepspeed_tpu import _hoststate  # the standard library alone
+
+_at_import, _jax_preloaded = _hoststate.host_state(), "jax" in _sys.modules
 
 from deepspeed_tpu.observability import steplog as _steplog  # noqa: E402
 from deepspeed_tpu.observability.events import get_bus as _get_bus  # noqa: E402
 
-# the package's import is the first set-up span (stamped here, where the
-# import began, and closed on the last line) and every program the process
-# builds from here on enters the build record
+# the package's import is the first set-up span (sampled above, where the
+# import began, and closed on the last line: the thread's counters run from
+# its start, so the first sample also says how the time before the import
+# was spent) and every program the process builds from here on enters the
+# build record
 _steplog.install_build_hook()
-_import_span = _steplog.span(_get_bus(), "setup", "import", start=_t_import,
+_import_span = _steplog.span(_get_bus(), "setup", "import",
+                             host_start=_at_import,
                              jax_preloaded=_jax_preloaded)
 _import_span.__enter__()
 

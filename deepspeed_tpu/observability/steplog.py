@@ -6,13 +6,16 @@ Process-wide like the metrics registry, so a reader (the benchmark's
 ``readers/program.py``, a debugger) reaches it without a handle on the
 engine.
 
-* :class:`StepLog` — two preallocated float64 rings. ``steps``: one row per
+* :class:`StepLog` — three preallocated float64 rings. ``steps``: one row per
   ``fused_train_step`` call (step number, span enter, dispatch return, span
   exit, on ``time.perf_counter``), written once as the ``ds.train.step`` span
-  closes. ``pauses``: one row per cyclic collection of generation >= 1
-  (start, length, generation), written from ``gc.callbacks`` inside a
-  ``ds.gc`` profiler annotation. A write stores floats into the ring: nothing
-  outlives the step and nothing is collector-tracked.
+  closes. ``host``: beside each step row, what the calling thread's clocks
+  read at enter and at exit (:func:`host_state`: on a core, runnable, the
+  whole process on a core) and when ``_put_batch`` returned. ``pauses``: one
+  row per cyclic collection of generation >= 1 (start, length, generation),
+  written from ``gc.callbacks`` inside a ``ds.gc`` profiler annotation. A
+  write stores floats into the ring: nothing outlives the step and nothing is
+  collector-tracked.
 * ``loss_parts`` — for a model whose loss has parts (a looped model's
   per-pass losses and exit distribution, a held share of experts' router
   counts, a sigmoid router's counts over every expert and the biases its
@@ -28,11 +31,16 @@ engine.
   again: a hit in the persistent compile cache), never at engine build or on
   a step.
 * :func:`slow_steps` — the arithmetic that says which steps of a record were
-  slow and how much of their excess the host or the collector took.
+  slow and how much of their excess the host or the collector took;
+  :func:`host_states` — on the same terms, every period's four phases (put,
+  dispatch, commit, outside) and what state the thread was in inside the
+  span and outside it. ``benchmarks/readers/HOSTSTATE.md`` says which metric
+  reads which field.
 * set-up — :func:`span` opens a span through ``EventBus.span`` and keeps its
   name on the calling thread's stack while it is open; a ``ds.setup.*`` span
-  also leaves a row (name, start, end, parent) that :func:`setup` returns:
-  ``ds.setup.import``, ``ds.setup.initialize`` and its children.
+  also leaves a row (name, start, end, parent, the thread's clocks at both
+  ends) that :func:`setup` returns: ``ds.setup.import``,
+  ``ds.setup.initialize`` and its children.
 * the build record — :func:`install_build_hook` registers two
   ``jax.monitoring`` listeners that fold every trace, lowering, backend
   compile and compile-cache event of the process into :func:`builds`: one row
@@ -56,14 +64,24 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from deepspeed_tpu._hoststate import (host_state, thread_state,  # noqa: F401
+                                      unavailable)
+
 __all__ = ["StepLog", "StepProgram", "get_steplog", "install_gc_hook",
            "install_build_hook", "span", "setup", "builds", "build_events",
-           "record_program", "programs", "slow_steps", "SLOW_FACTOR"]
+           "record_program", "programs", "slow_steps", "host_states",
+           "host_state", "thread_state", "unavailable", "SLOW_FACTOR"]
 
 #: a step is slow when its period exceeds this many medians
 SLOW_FACTOR = 1.25
 #: steps whose loss parts are kept (device values: a few floats each)
 PARTS_KEPT = 256
+#: a row of the ``host`` ring: the thread's clocks at the span's enter
+#: (:func:`host_state` less its ``t``, which the step row holds), the moment
+#: ``_put_batch`` returned, and the thread's clocks at the span's exit
+HOST_COLUMNS = ("enter_cpu_ns", "enter_runnable_ns", "enter_process_cpu_ns",
+                "t_put", "exit_cpu_ns", "exit_runnable_ns")
+_NO_HOST = (float("nan"),) * len(HOST_COLUMNS)
 
 
 class StepLog:
@@ -74,6 +92,7 @@ class StepLog:
     def __init__(self, size: int = 4096):
         self.size = int(size)
         self._steps = np.zeros((self.size, 4))
+        self._host = np.zeros((self.size, len(HOST_COLUMNS)))
         self._pauses = np.zeros((self.size, 3))
         self._parts: List[Any] = [None] * PARTS_KEPT
         self.n_steps = 0
@@ -99,9 +118,12 @@ class StepLog:
                 for step, loss, parts in rows]
 
     def step(self, step: int, t_enter: float, t_dispatched: float,
-             t_exit: float) -> None:
-        self._steps[self.n_steps % self.size] = (step, t_enter, t_dispatched,
-                                                 t_exit)
+             t_exit: float, host: Sequence[float] = _NO_HOST) -> None:
+        """One row into ``steps`` and, at the same index, ``host``
+        (:data:`HOST_COLUMNS`; NaN where the caller took no sample)."""
+        i = self.n_steps % self.size
+        self._steps[i] = (step, t_enter, t_dispatched, t_exit)
+        self._host[i] = host
         self.n_steps += 1
 
     def pause(self, t_start: float, seconds: float, generation: int) -> None:
@@ -119,6 +141,28 @@ class StepLog:
     def steps(self) -> np.ndarray:
         """Rows ``[step, enter, dispatched, exit]``, oldest first."""
         return self._ordered(self._steps, self.n_steps)
+
+    def host(self) -> np.ndarray:
+        """Rows of :data:`HOST_COLUMNS`, oldest first, a row beside each row
+        of :meth:`steps`."""
+        return self._ordered(self._host, self.n_steps)
+
+    def last_period(self, step: int, enter: Sequence[float]
+                    ) -> Optional[tuple]:
+        """``(off_cpu_ms, runnable_ms)`` of the period that the sample
+        ``enter`` (:func:`host_state`, taken as step ``step``'s span opens)
+        closes: from the newest row's enter to it. None unless that row is
+        step ``step - 1``'s and holds a sample."""
+        i = (self.n_steps - 1) % self.size
+        if not self.n_steps or self._steps[i, 0] != step - 1:
+            return None
+        cpu_ms = (enter[1] - self._host[i, 0]) / 1e6
+        if cpu_ms != cpu_ms:
+            return None
+        runnable_ms = (enter[2] - self._host[i, 1]) / 1e6
+        wall_ms = (enter[0] - self._steps[i, 1]) * 1e3
+        return (wall_ms - cpu_ms - (runnable_ms if runnable_ms == runnable_ms
+                                    else 0.0), runnable_ms)
 
     def pauses(self) -> np.ndarray:
         """Rows ``[start, seconds, generation]``, oldest first."""
@@ -188,8 +232,9 @@ class _Recorded:
             self.row["parent"] = next(
                 (s.row["id"] for s in reversed(stack) if s.row is not None),
                 None)
-            if self.row["start"] is None:
-                self.row["start"] = time.perf_counter()
+            if self.row["host_start"] is None:
+                self.row["host_start"] = host_state()
+            self.row["start"] = self.row["host_start"][0]
             _SETUP.append(self.row)
         stack.append(self)
         self.inner.__enter__()
@@ -198,26 +243,30 @@ class _Recorded:
     def __exit__(self, exc_type, exc, tb):
         self.inner.__exit__(exc_type, exc, tb)
         if self.row is not None:
-            self.row["end"] = time.perf_counter()
+            self.row["host_end"] = host_state()
+            self.row["end"] = self.row["host_end"][0]
         _tls.stack.pop()
         return False
 
 
-def span(bus, cat: str, name: str, *, start: Optional[float] = None,
+def span(bus, cat: str, name: str, *,
+         host_start: Optional[Sequence[float]] = None,
          program: Optional[str] = None, **facts) -> _Recorded:
     """``with steplog.span(bus, "setup", "initialize"):`` — ``bus.span(cat,
     name)`` (the profiler's annotation ``ds.<cat>.<name>`` and, with tracing
     on, the ring's B/E pair), remembered as the innermost span of the calling
     thread while it is open, so that the build record can say under which
     span a program was built. A span of category ``setup`` also leaves a row
-    for :func:`setup`, with ``facts`` beside its times; ``start`` backdates
-    that row (the import span begins before anything could open it). For
-    spans that open a few times a process: a step's own spans stay on
-    ``bus.span``."""
+    for :func:`setup`, with ``facts`` beside its times and the thread's
+    clocks at both ends (:func:`host_state`); ``host_start``, a sample
+    taken earlier, backdates that row (the import span begins before
+    anything could open it). For spans that open a few times a process: a
+    step's own spans stay on ``bus.span``."""
     row = None
     if cat == "setup":
         row = {"id": next(_setup_ids), "name": f"ds.{cat}.{name}",
-               "start": start, "end": None, "parent": None, **facts}
+               "start": None, "end": None, "parent": None,
+               "host_start": host_start, "host_end": None, **facts}
     return _Recorded(bus.span(cat, name, program=program, args=facts or None),
                      f"ds.{cat}.{name}", row)
 
@@ -225,10 +274,16 @@ def span(bus, cat: str, name: str, *, start: Optional[float] = None,
 def setup() -> List[Dict]:
     """The process's set-up spans in the order they opened: ``{"id", "name",
     "start", "end" (None while open), "parent" (an id or None), "self_s"`` (the
-    span's length less its children's) and the facts the span was opened
-    with``}``, on ``time.perf_counter``. The last 256."""
+    span's length less its children's)``, "host_start", "host_end"`` (the
+    thread's clocks at both ends, :func:`host_state`'s fields as a list; a
+    clock the host lacks reads None, as ``host_end`` does while the span is
+    open) and the facts the span was opened with``}``, on
+    ``time.perf_counter``. The last 256."""
     rows = [dict(r) for r in _SETUP]
     for r in rows:
+        for key in ("host_start", "host_end"):
+            if r[key] is not None:
+                r[key] = [None if v != v else v for v in r[key]]
         r["self_s"] = None if r["end"] is None else (r["end"] - r["start"]) \
             - sum(c["end"] - c["start"] for c in rows
                   if c["parent"] == r["id"] and c["end"] is not None)
@@ -501,6 +556,22 @@ def programs() -> List[StepProgram]:
 
 # ---- reading a record -----------------------------------------------------
 
+def _periods(steps: np.ndarray, factor: float, exclude: Sequence[int]):
+    """What :func:`slow_steps` and :func:`host_states` agree on: the periods
+    of consecutive step rows in milliseconds (enter to the next enter), which
+    of them count (``exclude`` left out), their median, and the indices of
+    the slow ones (over ``factor`` medians). None when fewer than two
+    count."""
+    if len(steps) < 2:
+        return None
+    keep = ~np.isin(steps[:-1, 0], list(exclude))
+    if keep.sum() < 2:
+        return None
+    period = np.diff(steps[:, 1]) * 1e3
+    mid = float(np.median(period[keep]))
+    return period, keep, mid, np.nonzero(keep & (period > factor * mid))[0]
+
+
 def slow_steps(steps: np.ndarray, pauses: np.ndarray,
                factor: float = SLOW_FACTOR,
                exclude: Sequence[int] = ()) -> Optional[Dict]:
@@ -519,19 +590,16 @@ def slow_steps(steps: np.ndarray, pauses: np.ndarray,
     a profiler, saved a checkpoint). Times in milliseconds; None when fewer
     than two periods are left.
     """
-    if len(steps) < 2:
+    picked = _periods(steps, factor, exclude)
+    if picked is None:
         return None
+    period, keep, mid, slow_at = picked
     enter, dispatched, exit_ = steps[:, 1], steps[:, 2], steps[:, 3]
-    keep = ~np.isin(steps[:-1, 0], list(exclude))
-    period = np.diff(enter) * 1e3
     inside = (exit_ - enter)[:-1] * 1e3
-    if keep.sum() < 2:
-        return None
-    mid = float(np.median(period[keep]))
     mid_inside = float(np.median(inside[keep]))
     total = float(period[keep].sum())
     slow = []
-    for i in np.nonzero(keep & (period > factor * mid))[0]:
+    for i in slow_at:
         excess = float(period[i] - mid)
         inside_p = [[float((s - enter[i]) * 1e3), float(sec * 1e3), int(g)]
                     for s, sec, g in pauses if enter[i] <= s < enter[i + 1]]
@@ -558,3 +626,126 @@ def slow_steps(steps: np.ndarray, pauses: np.ndarray,
             "pause_ms_per_step": (float(in_window[:, 1].sum()) * 1e3
                                   / len(period)) if len(pauses) else 0.0,
             "pauses": int(len(in_window)), "slow": slow}
+
+
+#: the phases of a period by the wall clock, and the two of them whose ends
+#: carry the thread's clocks: the span (put, dispatch and commit together)
+#: and what lies outside it
+PHASES = ("put", "dispatch", "commit", "outside")
+STATE_PHASES = ("span", "outside")
+STATES = ("cpu", "runnable", "off_cpu")
+#: the longest periods :func:`host_states` lists phase by phase
+WORST_KEPT = 5
+
+
+def host_states(steps: np.ndarray, host: np.ndarray,
+                factor: float = SLOW_FACTOR,
+                exclude: Sequence[int] = ()) -> Optional[Dict]:
+    """Where each period of a record went, by phase and by what state the
+    host thread was in, on :func:`slow_steps`' terms (same periods, same
+    ``factor``, same ``exclude``).
+
+    ``steps`` are consecutive rows of :meth:`StepLog.steps`, ``host`` the
+    rows of :meth:`StepLog.host` beside them. A period has four phases by the
+    wall clock: ``put`` (enter to ``_put_batch``'s return), ``dispatch`` (to
+    the jitted call's return), ``commit`` (to exit) and ``outside`` (exit to
+    the next enter: the caller's wait for the device and its next batch).
+    The thread's clocks are read at enter and at exit, so the state is told
+    for the ``span`` (the first three phases) and for ``outside``: on a core
+    (``cpu``), ``runnable`` and waiting for one, and ``off_cpu``, which is
+    the rest of the phase's wall time. Where the host has no ``schedstat``
+    (``runnable_read`` False) runnable reads 0 and its time lies in
+    ``off_cpu``, or in ``cpu`` where the kernel under the thread is a
+    sandbox's. ``other_cpu`` is what the process's other threads burned in
+    the period: Δ(``process_cpu_ns`` − ``cpu_ns``) from enter to enter.
+
+    For the window: medians by phase and state, the sums, and the shares the
+    metrics read. For the steps :func:`slow_steps` calls slow: the split of
+    their excess by phase (a phase's excess is its length less that phase's
+    median) and, for the span and outside, by state (a state's excess is its
+    time less that state's median there; ``off_cpu`` takes the rest, so the
+    states sum to the phase). With no slow step every excess and share
+    reads 0. Times in milliseconds; None when fewer than two periods are
+    left or the rows hold no sample.
+    """
+    picked = _periods(steps, factor, exclude)
+    if picked is None:
+        return None
+    period, keep, _, slow_at = picked
+    enter, dispatched, exit_ = steps[:, 1], steps[:, 2], steps[:, 3]
+    t_put = host[:, 3]
+    wall = {"put": (t_put - enter)[:-1] * 1e3,
+            "dispatch": (dispatched - t_put)[:-1] * 1e3,
+            "commit": (exit_ - dispatched)[:-1] * 1e3,
+            "outside": (enter[1:] - exit_[:-1]) * 1e3,
+            "span": (exit_ - enter)[:-1] * 1e3}
+    runnable_read = bool(np.isfinite(host[:, 1]).any())
+    run_enter, run_exit = (np.nan_to_num(host[:, c]) for c in (1, 5))
+    state = {"span": {"cpu": (host[:, 4] - host[:, 0])[:-1] / 1e6,
+                      "runnable": (run_exit - run_enter)[:-1] / 1e6},
+             "outside": {"cpu": (host[1:, 0] - host[:-1, 4]) / 1e6,
+                         "runnable": (run_enter[1:] - run_exit[:-1]) / 1e6}}
+    for p in STATE_PHASES:
+        state[p]["off_cpu"] = wall[p] - state[p]["cpu"] - state[p]["runnable"]
+    other = np.diff(host[:, 2] - host[:, 0]) / 1e6
+    # a period whose rows hold no sample (another caller wrote them) counts
+    # in no sum
+    keep = keep & np.isfinite(state["span"]["cpu"] + state["outside"]["cpu"])
+    if keep.sum() < 2:
+        return None
+
+    def med(x):
+        return float(np.median(x[keep]))
+
+    def total(x):
+        return float(x[keep].sum())
+
+    medians = {p: med(wall[p]) for p in PHASES + ("span",)}
+    state_medians = {p: {s: med(state[p][s]) for s in STATES}
+                     for p in STATE_PHASES}
+    sums = {p: {"wall": total(wall[p]),
+                **{s: total(state[p][s]) for s in STATES}}
+            for p in STATE_PHASES}
+    slow_at = slow_at[keep[slow_at]]
+    by_phase = {p: float((wall[p][slow_at] - medians[p]).sum())
+                for p in PHASES}
+    by_state = {}
+    for p in STATE_PHASES:
+        part = {s: float((state[p][s][slow_at] - state_medians[p][s]).sum())
+                for s in ("cpu", "runnable")}
+        part["off_cpu"] = float((wall[p][slow_at] - medians[p]).sum()) \
+            - part["cpu"] - part["runnable"]
+        by_state[p] = part
+    excess = sum(sum(v.values()) for v in by_state.values())
+
+    def row(i):
+        return {"index": int(i), "step": int(steps[i, 0]),
+                "period_ms": float(period[i]),
+                **{f"{p}_ms": float(wall[p][i]) for p in PHASES},
+                **{p: {s: float(state[p][s][i]) for s in STATES}
+                   for p in STATE_PHASES},
+                "other_cpu_ms": float(other[i])}
+
+    def share(part, whole):
+        return 100.0 * part / whole if whole else 0.0
+
+    window = float(period[keep].sum())
+    return {
+        "steps": int(keep.sum()), "runnable_read": runnable_read,
+        "window_ms": window, "median_ms": medians,
+        "state_median_ms": state_medians, "sum_ms": sums,
+        "other_cpu_ms": total(other),
+        "span_off_cpu_share": share(sums["span"]["off_cpu"],
+                                    sums["span"]["wall"]),
+        "span_runnable_share": share(sums["span"]["runnable"],
+                                     sums["span"]["wall"]),
+        "other_threads_cpu_share": share(total(other), window),
+        "slow": len(slow_at), "excess_ms": excess,
+        "excess_by_phase_ms": by_phase, "excess_by_state_ms": by_state,
+        "slow_off_cpu_share": share(
+            sum(by_state[p]["off_cpu"] for p in STATE_PHASES), excess),
+        "slow_runnable_share": share(
+            sum(by_state[p]["runnable"] for p in STATE_PHASES), excess),
+        "worst": [row(i) for i in sorted(np.nonzero(keep)[0],
+                                         key=lambda i: -period[i])[:WORST_KEPT]],
+    }

@@ -731,7 +731,9 @@ class DeepSpeedTpuEngine:
             return jax.device_put(x, NamedSharding(self.mesh, spec))
 
         with self._ebus.span("train", "put_batch"):
-            return jax.tree_util.tree_map(put, batch)
+            batch = jax.tree_util.tree_map(put, batch)
+        self._t_put = time.perf_counter()
+        return batch
 
     # ------------------------------------------------------------------
     # train loop UX
@@ -1003,14 +1005,21 @@ class DeepSpeedTpuEngine:
 
         The whole call is the ``ds.train.step`` span (``put_batch``,
         ``dispatch`` and ``commit`` nest inside it), and leaves one row in the
-        process's :class:`~deepspeed_tpu.observability.steplog.StepLog`.
+        process's :class:`~deepspeed_tpu.observability.steplog.StepLog`: the
+        four stamps, and beside them what the thread's clocks read at enter
+        and at exit (on a core, runnable, the whole process on a core) and
+        when ``_put_batch`` returned.
         """
         step = self.global_steps
-        t_enter = time.perf_counter()
+        enter = steplog.host_state()
+        self._host_enter = (step, enter)
         with self._ebus.span("train", "step", step=step):
             loss = self._fused_train_step(batch)
-        self._steplog.step(step, t_enter, self._t_dispatched,
-                           time.perf_counter())
+        exit_ = steplog.thread_state()
+        self._steplog.step(step, enter[0], self._t_dispatched, exit_[0],
+                           (enter[1], enter[2], enter[3], self._t_put,
+                            exit_[1], exit_[2]))
+        self._host_enter = None
         return loss
 
     def _step_program(self, key, jitted: Callable) -> None:
@@ -1361,7 +1370,10 @@ class DeepSpeedTpuEngine:
         steplog.install_gc_hook()
         self._steplog = steplog.get_steplog()
         self._uncaptured: Dict[Any, steplog.StepProgram] = {}
-        self._t_dispatched = 0.0
+        self._t_dispatched = self._t_put = 0.0
+        # the fused step's number and the sample taken as its span opened,
+        # while it is open
+        self._host_enter: Optional[tuple] = None
         self._last_loss_parts: Optional[Dict[str, Any]] = None
         self._obs = None
         self._obs_bridge = None
@@ -1412,6 +1424,14 @@ class DeepSpeedTpuEngine:
             "samples": g("train/samples", "global samples consumed"),
             "skipped_steps": g("train/skipped_steps",
                                "overflow/guard-skipped steps"),
+            "host_off_cpu_ms": g(
+                "train/host_off_cpu_ms",
+                "of the last fused step's period (enter to enter), the time "
+                "its thread was neither on a core nor waiting for one"),
+            "host_runnable_ms": g(
+                "train/host_runnable_ms",
+                "of the same period, the time it was runnable and waiting "
+                "for a core (where the host has /proc schedstat)"),
         }
         if self._zpp is not None:
             # ZeRO++ instruments: in-jit quantized collectives are
@@ -1452,6 +1472,12 @@ class DeepSpeedTpuEngine:
         o["steps"].set(float(self.global_steps))
         o["samples"].set(float(self.global_samples))
         o["skipped_steps"].set(float(self.skipped_steps))
+        if self._host_enter is not None:    # inside a fused step's span
+            last = self._steplog.last_period(*self._host_enter)
+            if last is not None:
+                o["host_off_cpu_ms"].set(last[0])
+                if last[1] == last[1]:
+                    o["host_runnable_ms"].set(last[1])
         if self._ebus.enabled:
             # one instant per committed step: the training heartbeat the
             # flight recorder shows around an abort (host clock only)
