@@ -119,13 +119,11 @@ def _train_steps(engine, batch, steps, compiles):
         if i == 0:
             after_first = compiles.n
     recompiles = compiles.n - after_first
-    (step_fn,) = engine._fused_step_cache.values()
-    # the executable the steps ran: same avals, so a cache answers
-    with jax.sharding.set_mesh(engine.mesh):
-        hlo = step_fn.lower(engine.params, engine.opt_state,
-                            engine._put_batch(batch),
-                            engine.scaler_state).compile().as_text()
-    return losses, times, recompiles, hlo
+    from deepspeed_tpu.observability import steplog
+
+    # the executable the steps ran, compiled again from the abstract
+    # arguments its row kept: same avals, so a cache answers
+    return losses, times, recompiles, steplog.programs()[-1].hlo_text()
 
 
 def run_train(seed=0, toy=False, layers=TRAIN_LAYERS, steps=5):

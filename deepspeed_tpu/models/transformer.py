@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import lowerings
 from deepspeed_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -1155,18 +1156,36 @@ def _times(x: jax.Array, factor: float) -> jax.Array:
     return (x.astype(jnp.float32) * factor).astype(x.dtype)
 
 
-def _cast_layers(w: Params, dt, ffn: str) -> Params:
+def _compute(p: jax.Array, dt, count: bool = False) -> jax.Array:
+    """One weight leaf as the forward reads it: a float32 master cast to the
+    compute dtype ``dt``, a leaf that is not float32 (the engine's carried
+    copy, :meth:`TransformerLM.working_copy`; a block's own stack, cast
+    already) handed on as it is. With ``count`` (the forward's own first read
+    of the leaf) the registry's ``weight_cast`` says which it was: "in_step"
+    or "carried"; a float32 compute dtype casts nothing and counts nothing."""
+    if p.dtype != jnp.float32 or dt == jnp.float32:
+        if count and p.dtype == dt != jnp.float32:
+            lowerings.count("weight_cast", "carried")
+        return p
+    if count:
+        lowerings.count("weight_cast", "in_step")
+    return p.astype(dt)
+
+
+def _cast_layers(w: Params, dt, ffn: Optional[str],
+                 count: bool = False) -> Params:
     """fp32 master weights of one block (or the whole stack) to the compute
-    dtype, each under the scope of the block that reads it."""
-    def cast(p):
-        return p.astype(dt) if p.dtype == jnp.float32 else p
+    dtype, each under the scope of the block that reads it; with ``ffn`` None
+    under no scope of their own (the working copy, which the optimizer
+    writes under its own)."""
+    cast = partial(_compute, dt=dt, count=count)
 
     out = {}
     for k, v in w.items():
-        with jax.named_scope(
+        with (contextlib.nullcontext() if ffn is None else jax.named_scope(
                 "attn" if k in ("ln1", "attn", "ssm", "mla", "delta", "conv",
                                 "ln1_post")
-                else _FFN_SCOPE.get(k, ffn)):
+                else _FFN_SCOPE.get(k, ffn))):
             if any(n in _KEEP_FP32 for n in v):
                 out[k] = {n: p if n in _KEEP_FP32
                           else jax.tree_util.tree_map(cast, p)
@@ -1174,6 +1193,17 @@ def _cast_layers(w: Params, dt, ffn: str) -> Params:
             else:
                 out[k] = jax.tree_util.tree_map(cast, v)
     return out
+
+
+def _cast_alone(cast: Any, master: Any) -> Any:
+    """The sub-tree of ``cast`` (nested dicts, shaped like ``master``) that
+    holds only the leaves a cast made: a leaf that is ``master``'s own object
+    was handed on as it was and is left out, a dict left empty with it."""
+    if not isinstance(cast, dict):
+        return None if cast is master else cast
+    out = {k: c for k, v in cast.items()
+           if (c := _cast_alone(v, master[k])) is not None}
+    return out or None
 
 
 def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
@@ -1731,6 +1761,33 @@ class TransformerLM:
                     bias.dtype)
         return out
 
+    def working_copy(self, params: Params) -> Params:
+        """The weights' working copy: every leaf of ``params`` that the
+        forward casts whole to the compute dtype at one site (the stacks of
+        ``params["layers"]`` but their ``_KEEP_FP32`` leaves, an untied
+        table, the learned positions, the head), cast by the function that
+        site casts with, as nested dicts that hold those leaves alone; ``{}``
+        where the compute dtype is the masters' own. A forward handed the
+        copy's leaves in the masters' places (the engine's plain fused step
+        does that, and has AdamW write the next copy beside the master it has
+        just made) casts nothing: it reads the same bf16 values either way.
+
+        Left with the master, and cast in the step as before: a leaf the
+        forward reads as float32, and one it casts at more than one site,
+        whose cotangents are summed in float32 behind the casts (a tied
+        table, gathered and projected with; the head under the exit gate,
+        projected with once a pass): summed in the copy's dtype they would
+        round once more."""
+        cfg = self.cfg
+        dt = jnp.dtype(cfg.dtype)
+        cast = {"layers": _cast_layers(params["layers"], dt, None)}
+        embed = [] if cfg.tie_embeddings else ["tokens"]
+        embed += ["pos"] if cfg.learned_pos else []
+        cast["embed"] = {k: _compute(params["embed"][k], dt) for k in embed}
+        if "lm_head" in params and cfg.exit_loss_beta is None:
+            cast["lm_head"] = _compute(params["lm_head"], dt)
+        return _cast_alone(cast, params) or {}
+
     def check_topology(self, axis_sizes: Dict[str, int]) -> None:
         """Raise where the mesh has an axis this model cannot be laid over:
         a ``tp`` axis divides an attention layer's heads, which a delta
@@ -1899,7 +1956,7 @@ class TransformerLM:
         head = self._head(params)
         if isinstance(head, QuantizedWeight):
             return linear(x, head)
-        return x @ head.astype(jnp.dtype(self.cfg.dtype))
+        return x @ _compute(head, jnp.dtype(self.cfg.dtype), count=True)
 
     def _project(self, params: Params, hidden: jax.Array) -> jax.Array:
         """hidden [B, T, D] → logits [B, T, V] with the canonical sharding."""
@@ -1975,14 +2032,14 @@ class TransformerLM:
         if pld_theta is not None:
             self._one_pass_only("progressive layer drop")
         with jax.named_scope("embed"):
-            x = params["embed"]["tokens"].astype(dt)[input_ids]
+            x = _compute(params["embed"]["tokens"], dt, count=True)[input_ids]
             if cfg.embedding_multiplier != 1.0:
                 x = _times(x, cfg.embedding_multiplier)
             if cfg.learned_pos:
                 T = input_ids.shape[1]
                 pos_emb = (params["embed"]["pos"][:T] if positions is None
                            else params["embed"]["pos"][positions])
-                x = x + pos_emb.astype(dt)
+                x = x + _compute(pos_emb, dt, count=True)
             x = constrain(x, P(("dp", "fsdp"), "sp", None))
         attn_fn = get_attention_impl(cfg.attention_impl)
         with jax.named_scope("layers"):
@@ -1992,7 +2049,8 @@ class TransformerLM:
             # remat) this was a full extra pass over the fp32 master weights
             # every micro-batch.
             layers = _cast_layers(params["layers"], dt,
-                                  "moe" if self.moe_fn is not None else "mlp")
+                                  "moe" if self.moe_fn is not None else "mlp",
+                                  count=True)
         hs, aux = [], None
         for _ in range(cfg.num_passes):
             with jax.named_scope("layers"):
@@ -2187,7 +2245,7 @@ class TransformerLM:
                 labels = labels.at[:, :-1].set(
                     jnp.where(mask[:, 1:], labels[:, :-1], -100))
             h = hidden
-        head = self._head(params).astype(jnp.dtype(cfg.dtype))
+        head = _compute(self._head(params), jnp.dtype(cfg.dtype), count=True)
         return tiled_logits_loss(h, head, labels,
                                  num_shards=cfg.loss_tiling,
                                  z_loss=cfg.z_loss)

@@ -21,6 +21,18 @@ TPU-native design (NOT a port of the hook/stream machinery):
 * **Precision.** Params are fp32 master weights (``bf16_optimizer.py:37`` parity);
   compute is bf16 by default; fp16 mode adds ``DynamicLossScaler``-equivalent state
   (``runtime/fp16/loss_scaler.py:187``) folded into the jitted step.
+* **The weights' working copy.** As the reference's ``BF16_Optimizer`` keeps bf16
+  model weights beside the fp32 masters and refreshes them at the end of ``step()``,
+  the plain fused step program (``ds_train_step``) takes and returns, as donated
+  state beside ``engine.params``, the copy the model names (``model.working_copy``:
+  every leaf its forward would cast to the compute dtype, cast): the forward reads
+  it and casts nothing, and the optimizer writes the next one as one more output of
+  each leaf's update, so a step reads the masters once. The copy is derived state:
+  made with the weights in the engine's init program, never in a checkpoint,
+  dropped by whatever else writes ``engine.params`` (the property's setter: the
+  imperative ``step()``, the offload path, a checkpoint load, a compression pass)
+  and made again at the next fused step. The imperative path and the offload, 1-bit
+  and ZeRO++ step programs cast in the step; with fp32 compute there is no copy.
 """
 
 from __future__ import annotations
@@ -62,6 +74,22 @@ def _with_leaf(tree, path, value):
     return {**tree, path[0]: _with_leaf(tree[path[0]], path[1:], value)}
 
 
+def _overlaid(tree, over):
+    """``tree`` (nested dicts) with the leaves ``over`` holds in their
+    places."""
+    if not isinstance(over, dict):
+        return over
+    return {**tree, **{k: _overlaid(tree[k], v) for k, v in over.items()}}
+
+
+def _under(tree, over):
+    """The part of ``tree`` (nested dicts) at the places ``over`` has
+    leaves."""
+    if not isinstance(over, dict):
+        return tree
+    return {k: _under(tree[k], v) for k, v in over.items()}
+
+
 class DeepSpeedTpuEngine:
     """See module docstring. Public surface mirrors ``DeepSpeedEngine``."""
 
@@ -80,6 +108,21 @@ class DeepSpeedTpuEngine:
             self._init_state(config, init_rng, schedule_fn)
         with steplog.span(bus, "setup", "engine.rest"):
             self._init_rest(config, training_data, collate_fn)
+
+    @property
+    def params(self):
+        """The fp32 master weights: what a checkpoint holds, what serving and
+        every step path read."""
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        # whoever writes the masters from outside the plain fused step (the
+        # imperative ``step()``, the offload path, a checkpoint load, a
+        # compression pass) leaves the working copy stale: it is dropped
+        # here and made again at the next fused step
+        self._params = value
+        self._work = None
 
     def _init_plan(self, model, config, optimizer, lr_scheduler, topology,
                    init_rng):
@@ -217,7 +260,7 @@ class DeepSpeedTpuEngine:
                 "zero_optimization.zenflow requires offload_optimizer "
                 "(device cpu|nvme) — there is no host step to overlap")
         with jax.sharding.set_mesh(self.mesh):
-            self.params = self._init_fn(init_rng)
+            self._params, self._work = self._init_fn(init_rng)
             if off is not None and off.device in ("cpu", "nvme"):
                 self.opt_state = {}
                 self._configure_offload_optimizer(off, schedule_fn)
@@ -583,7 +626,33 @@ class DeepSpeedTpuEngine:
         # (``model.rule_leaves`` / ``rule_updates``: a sigmoid router's
         # selection bias): no gradient, no optimizer update, no weight decay
         self._rule_leaves = tuple(getattr(model, "rule_leaves", tuple)())
-        self._init_fn = jax.jit(model.init, out_shardings=self.param_sharding)
+        # the weights' working copy (``model.working_copy``: the leaves the
+        # forward would cast to the compute dtype, cast) that the plain
+        # fused step program carries beside the masters; ``{}`` where the
+        # model names none, the compute dtype is the masters', or the engine
+        # steps through a program that casts in the step (offload, 1-bit,
+        # ZeRO++)
+        off = self.config.zero_optimization.offload_optimizer
+        carried = (tx is not None and self._zpp is None
+                   and not (off is not None and off.device in ("cpu", "nvme")))
+        self._copy_of = (getattr(model, "working_copy", None) if carried
+                         else None) or (lambda params: {})
+        work_shapes = jax.eval_shape(self._copy_of, self._param_shapes)
+        self.work_sharding = _under(self.param_sharding, work_shapes)
+        self._work_bytes = sum(
+            x.size * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves(work_shapes))
+
+        def init_state(rng):
+            params = model.init(rng)
+            return params, self._copy_of(params)
+
+        self._init_fn = jax.jit(
+            init_state, out_shardings=(self.param_sharding,
+                                       self.work_sharding))
+        # the copy again, after something else wrote the masters
+        self._work_fn = jax.jit(self._copy_of,
+                                out_shardings=self.work_sharding)
         if tx is not None:
             self._apply_body = apply_step
             self._apply = jax.jit(
@@ -1003,6 +1072,11 @@ class DeepSpeedTpuEngine:
         scaling, overflow skip and scaler update ride inside the jit, and the
         host-offload optimizer is supported via a fused grads-only program.
 
+        The plain step program (``ds_train_step``: an optax optimizer on
+        the device, no ZeRO++) also carries the weights' working copy (module
+        docstring): ``engine.params`` stay the fp32 masters, the copy rides
+        beside them from step to step.
+
         The whole call is the ``ds.train.step`` span (``put_batch``,
         ``dispatch`` and ``commit`` nest inside it), and leaves one row in the
         process's :class:`~deepspeed_tpu.observability.steplog.StepLog`: the
@@ -1035,7 +1109,9 @@ class DeepSpeedTpuEngine:
         ``batch_shape``, once the first call has shown one); nothing where it
         does not say."""
         facts = getattr(self.module, "step_program_facts", None)
-        return {} if facts is None else facts(batch_shape)
+        return {**({} if facts is None else facts(batch_shape)),
+                # what the plain fused step carries beside the masters
+                "working_copy_bytes": self._work_bytes}
 
     def _dispatch_fused(self, key, *args):
         """Call the jitted step program of ``key`` (the ``ds.train.dispatch``
@@ -1133,9 +1209,13 @@ class DeepSpeedTpuEngine:
         if key not in self._fused_step_cache:
             rules = self._rule_leaves
 
-            def ds_train_step(params, opt_state, batch, scaler):
+            def ds_train_step(params, work, opt_state, batch, scaler):
+                # the forward reads the copy's leaf where there is one and
+                # casts nothing there; its cotangent is the cast's own
+                # operand, which ``ga_grads`` adds into float32 and
+                # ``apply_step`` reads as float32, as the cast's transpose did
                 grads, loss, parts = self._fused_grads(
-                    params, batch, scaler["scale"], ga)
+                    _overlaid(params, work), batch, scaler["scale"], ga)
                 for path in rules:      # out of the norm and the update
                     grads = _with_leaf(grads, path,
                                        jnp.zeros_like(_leaf(grads, path)))
@@ -1144,17 +1224,26 @@ class DeepSpeedTpuEngine:
                 if rules:
                     new_params = self._moved_by_rule(params, new_params,
                                                      parts, skipped)
-                return (new_params, new_opt, new_scaler, loss, gnorm, skipped,
-                        parts)
+                with jax.named_scope("optimizer"):
+                    # beside the master it has just made: one more output
+                    # of each leaf's update, not a pass of its own
+                    new_work = self._copy_of(new_params)
+                return (new_params, new_work, new_opt, new_scaler, loss,
+                        gnorm, skipped, parts)
 
             self._step_program(key, jax.jit(
-                ds_train_step, donate_argnums=(0, 1),
-                out_shardings=(self.param_sharding, self.opt_sharding,
-                               None, None, None, None, None)))
+                ds_train_step, donate_argnums=(0, 1, 2),
+                out_shardings=(self.param_sharding, self.work_sharding,
+                               self.opt_sharding, None, None, None, None,
+                               None)))
+        if self._work is None:      # something else wrote the masters
+            with jax.sharding.set_mesh(self.mesh):
+                self._work = self._work_fn(self._params)
         batch = self._put_batch(batch)
-        (self.params, self.opt_state, self.scaler_state, loss, gnorm,
-         skipped, parts) = self._dispatch_fused(
-            key, self.params, self.opt_state, batch, self.scaler_state)
+        (self._params, self._work, self.opt_state, self.scaler_state, loss,
+         gnorm, skipped, parts) = self._dispatch_fused(
+            key, self._params, self._work, self.opt_state, batch,
+            self.scaler_state)
         self._last_loss, self._last_gnorm = loss, gnorm
         if parts:
             # the loss's parts (a looped model's per-pass losses and exit

@@ -51,6 +51,9 @@ def safe_set_full_fp32_param(engine, path: PathLike, value) -> None:
     if new.shape != leaf.shape:
         raise ValueError(f"shape mismatch for {path}: {new.shape} vs {leaf.shape}")
     _set_in(engine.params, trail, new)
+    # written in place: tell the engine its masters changed (it drops what it
+    # derived from them)
+    engine.params = engine.params
 
 
 def safe_get_full_grad(engine, path: PathLike) -> Optional[np.ndarray]:

@@ -2,6 +2,8 @@
 trained a few steps on a virtual 8-device mesh, asserting convergence and
 cross-stage equivalence instead of hook/partition internals."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -190,3 +192,159 @@ def test_fused_step_with_offload(tmp_path, eight_devices):
     losses = [float(eng.fused_train_step(next(it))) for _ in range(4)]
     assert losses[-1] < losses[0]
     assert eng.global_steps == 4
+
+
+# ---- the weights' working copy (the plain fused step carries it) -----------
+
+_EXPERTS_RULE = dict(
+    num_layers=3, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    v_head_dim=16, rope_interleave=True, first_k_dense=1, num_experts=8,
+    top_k=2, moe_dispatch="grouped", moe_intermediate_size=32,
+    moe_experts_held=4, moe_scoring="sigmoid", moe_routed_scale=2.448,
+    moe_shared_experts=2, moe_bias_rate=1e-3, moe_bias_init=0.1,
+    tie_embeddings=False, remat_policy="full")
+#: case -> (model overrides, engine config overrides, the master leaves the
+#: copy must leave out)
+COPY_CASES = {
+    "dense": (dict(tie_embeddings=False), {}, [("final_norm", "scale")]),
+    # gathered from and projected with: two cotangents summed in float32
+    "tied_table": ({}, {}, [("embed", "tokens")]),
+    # the stack read by every pass through one cast; the head projected with
+    # once a pass, its cotangents summed in float32
+    "looped": (dict(num_passes=3, sandwich_norm=True, exit_loss_beta=0.1,
+                    tie_embeddings=False), {},
+               [("lm_head",), ("exit_gate", "w")]),
+    # the selection bias is moved by the model's rule and read as float32
+    "experts_rule_leaf": (_EXPERTS_RULE, {},
+                          [("layers", "mlp_moe", "router_bias")]),
+    "ga2": (dict(tie_embeddings=False), dict(gradient_accumulation_steps=2),
+            []),
+    # 2^17 overflows float16's range until the scaler has halved it twice
+    "fp16_skipped_step": (dict(tie_embeddings=False, dtype="float16"),
+                          dict(fp16={"enabled": True,
+                                     "initial_scale_power": 17}), []),
+    "fp32_compute": (dict(dtype="float32"), {}, None),
+}
+
+
+def _one_chip_engine(model_over, cfg_over, copy=True, seed=11):
+    import jax
+
+    from deepspeed_tpu.parallel import build_mesh
+
+    model = TransformerLM(get_preset("tiny", **model_over))
+    if not copy:
+        # the step body traced with an empty copy, the form fp32 compute
+        # takes anyway: every cast stays in the step, as before the copy
+        model.working_copy = lambda params: {}
+    eng, *_ = ds.initialize(
+        model=model, config=make_config(0, seed=seed, **cfg_over),
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    return eng
+
+
+def _same_bits(a, b):
+    import jax
+
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("case", sorted(COPY_CASES))
+def test_the_carried_copy_changes_no_bit_of_the_training_state(case):
+    """N fused steps with AdamW writing the working copy beside the masters
+    leave master weights, ``m``, ``v`` and losses bit-equal to the same steps
+    with every cast in the step; the copy holds what the model says, in the
+    compute dtype, and after each step is the cast of the masters beside
+    it; no master, moment or gradient changes dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    model_over, cfg_over, left_out = COPY_CASES[case]
+    eng = _one_chip_engine(model_over, cfg_over)
+    ref = _one_chip_engine(model_over, cfg_over, copy=False)
+    assert ref._work == {} and ref._work_bytes == 0
+    dt = jnp.dtype(eng.module.cfg.dtype)
+    if left_out is None:                    # fp32 compute: no copy
+        assert eng._work == {} and eng._work_bytes == 0
+    else:
+        copy = jax.tree_util.tree_leaves(eng._work)
+        assert copy and all(x.dtype == dt for x in copy)
+        assert eng._work_bytes == sum(x.nbytes for x in copy)
+        assert "final_norm" not in eng._work
+        for path in left_out:
+            node = eng._work
+            for name in path[:-1]:
+                node = node.get(name, {})
+            assert path[-1] not in node, path
+    rows = 2 * int(eng.config.gradient_accumulation_steps)
+    rng = np.random.default_rng(5)
+    steps = 4
+    for _ in range(steps):
+        batch = {"input_ids": rng.integers(0, 256, (rows, 32), dtype=np.int32)}
+        loss, ref_loss = eng.fused_train_step(batch), ref.fused_train_step(batch)
+        assert float(loss) == float(ref_loss)
+        _same_bits(eng._work, eng.module.working_copy(eng.params))
+    _same_bits((eng.params, eng.opt_state, eng.scaler_state),
+               (ref.params, ref.opt_state, ref.scaler_state))
+    assert all(x.dtype == jnp.float32 for x in jax.tree_util.tree_leaves(
+        (eng.params, eng.opt_state[0].mu, eng.opt_state[0].nu)))
+    assert eng.skipped_steps == ref.skipped_steps
+    if case == "fp16_skipped_step":
+        assert 1 <= eng.skipped_steps < steps   # skipped, then stepped
+
+
+def test_whoever_else_writes_the_masters_drops_the_copy(tmp_path):
+    """A checkpoint holds no copy and a load drops the engine's; so do a
+    direct write of ``engine.params``, the imperative ``step()`` and a leaf
+    written in place. The next fused step makes the copy again from the
+    masters it finds: its loss is that of an engine that casts in the
+    step."""
+    import jax
+
+    from deepspeed_tpu.utils.tensor_fragment import safe_set_full_fp32_param
+
+    over = dict(tie_embeddings=False)
+    eng = _one_chip_engine(over, {})
+    ref = _one_chip_engine(over, {}, copy=False)
+    rng = np.random.default_rng(6)
+
+    def batch():
+        return {"input_ids": rng.integers(0, 256, (2, 32), dtype=np.int32)}
+
+    def both_step():
+        b = batch()
+        loss, ref_loss = eng.fused_train_step(b), ref.fused_train_step(b)
+        assert float(loss) == float(ref_loss)
+        assert eng._work is not None and ref._work == {}
+
+    both_step()
+    eng.save_checkpoint(str(tmp_path))
+    assert not any("work" in name for _, _, files in os.walk(str(tmp_path))
+                   for name in files)
+    both_step()                 # past the checkpoint, then back to it
+    for e in (eng, ref):
+        e.load_checkpoint(str(tmp_path))
+    assert eng._work is None
+    both_step()
+    # a direct write (a compression pass): the stale copy would give the
+    # loss of the weights before it
+    for e in (eng, ref):
+        e.params = jax.tree_util.tree_map(lambda x: x * 0.5, e.params)
+    assert eng._work is None
+    both_step()
+    b = batch()
+    for e in (eng, ref):        # the imperative path
+        e.backward(e.forward(b))
+        e.step()
+    assert eng._work is None
+    both_step()
+    for e in (eng, ref):        # one leaf, in place
+        safe_set_full_fp32_param(
+            e, "lm_head", np.zeros(e.params["lm_head"].shape, np.float32))
+    assert eng._work is None
+    both_step()
+    _same_bits((eng.params, eng.opt_state), (ref.params, ref.opt_state))

@@ -3,6 +3,8 @@ named scopes (``models/transformer.py:STEP_SCOPES``): a refactor that drops a
 scope fails here, on the CPU, not in the benchmark's ``unscoped_device_ms`` on
 the chip."""
 
+import functools
+import operator
 import re
 
 import jax
@@ -160,8 +162,13 @@ def test_every_operation_carries_a_step_scope(case):
             assert ops and all(outer in re.split(r"[/()]", n) for n in ops)
     if CASES[case][0].get("loss_tiling", 0) <= 1:
         want.add("lm_head")
-    if CASES[case][1] > 1:      # with one micro-batch the compiler folds 0 + g
+    if CASES[case][1] > 1:
         want.add("grad_accum")
+    else:
+        # with one micro-batch the compiler folds 0 + g; the lift of the
+        # carried copy's bf16 cotangents to float32 is left under the scope
+        # where it stays an instruction of its own
+        found.discard("grad_accum")
     if case.startswith("looped"):
         want.add("exit_gate")
         # the gate's operations nest inside the loss: loss/exit_gate/...
@@ -366,3 +373,83 @@ def test_backward_operations_keep_their_scope():
     names = _op_names(*CASES["dense"])
     back = [n for n in names if "transpose(" in n]
     assert any("/attn/" in n for n in back) and any("/mlp/" in n for n in back)
+
+
+def _casts_to(jaxpr, dtype, stack=""):
+    """``(operand shape, name stack)`` of every float32 -> ``dtype`` convert
+    of ``jaxpr`` and of the jaxprs its equations call (a scan's body, a
+    recomputed block), the stack from the outermost scope down."""
+    import jax.numpy as jnp
+
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        if (eqn.primitive.name == "convert_element_type"
+                and eqn.invars[0].aval.dtype == jnp.float32
+                and eqn.params["new_dtype"] == dtype):
+            yield tuple(eqn.invars[0].aval.shape), here
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _casts_to(sub, dtype, here)
+
+
+#: case -> (model overrides, the master leaves that stay out of the copy and
+#: are still cast in the step)
+CARRIED = {
+    "untied": ({"tie_embeddings": False}, ()),
+    # gathered from and projected with: cast at both sites, as before
+    "tied_table": ({}, (("embed", "tokens"),)),
+    # the head under the exit gate is projected with once a pass
+    "looped": (CASES["looped"][0], (("lm_head",),)),
+    # a rule-moved float32 leaf beside the experts' stacks
+    "mla_moe": (CASES["mla_moe"][0], ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CARRIED))
+def test_the_step_program_casts_no_carried_weight(case):
+    """The traced ``ds_train_step`` holds no float32 -> compute-dtype convert
+    of a weight-shaped value outside the ``optimizer`` scope but of the
+    leaves the model keeps out of the working copy; inside it, one for each
+    leaf of the copy (AdamW's new master, rounded beside it). The program's
+    row says the same: what its trace counted at the model's cast sites, and
+    the bytes of the copy it carries, 2 B a cast parameter."""
+    import jax.numpy as jnp
+
+    over, in_step = CARRIED[case]
+    eng, *_ = ds.initialize(
+        model=TransformerLM(get_preset("tiny", **over)),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                "bf16": {"enabled": True}, "steps_per_print": 10 ** 9,
+                "zero_optimization": {"stage": 0}},
+        mesh=build_mesh(devices=jax.devices()[:1]))
+    batch = {"input_ids": np.zeros((2, 32), np.int32)}
+    eng.fused_train_step(batch)
+    row = steplog.programs()[-1]
+    copy = jax.tree_util.tree_leaves(eng._work)
+    in_step_shapes = {functools.reduce(operator.getitem, path,
+                                       eng.params).shape for path in in_step}
+    weight_shapes = {x.shape for x in jax.tree_util.tree_leaves(eng.params)}
+    (step,) = eng._fused_step_cache.values()
+    jaxpr = jax.make_jaxpr(step)(eng.params, eng._work, eng.opt_state, batch,
+                                 eng.scaler_state)
+    casts = [(shape, stack) for shape, stack in _casts_to(
+        jaxpr.jaxpr, jnp.bfloat16) if shape in weight_shapes]
+    inside = [shape for shape, stack in casts
+              if "optimizer" in stack.split("/")]
+    # (of the matrices: a vector's shape is also an activation's)
+    outside = [shape for shape, stack in casts
+               if "optimizer" not in stack.split("/") and len(shape) > 1]
+    assert sorted(inside) == sorted(x.shape for x in copy)
+    assert set(outside) == in_step_shapes
+    counted = row.counted["weight_cast"]
+    assert counted["carried"] >= len(copy)
+    assert ("in_step" in counted) == bool(in_step)
+    cast_parameters = sum(x.size for x in copy)
+    assert row.working_copy_bytes == 2 * cast_parameters
+    whole = sum(x.size for x in jax.tree_util.tree_leaves(eng.params))
+    kept = whole - cast_parameters
+    if not in_step:     # all but the float32-read leaves: norms, biases
+        assert kept < 0.01 * whole

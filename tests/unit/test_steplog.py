@@ -178,7 +178,9 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
     # XLA attention on the CPU: the program holds no flash backward
     assert rows[0].flash_bwd_lowerings is None
     assert rows[0].flash_fwd_tiles is None
-    assert rows[0].counted == {}
+    # (only where the weights were cast: the stacks' nine leaves carried,
+    # the tied table cast in the step at the gather and at the head)
+    assert rows[0].counted == {"weight_cast": {"carried": 9, "in_step": 2}}
     # a model that says nothing of a mixer: its row answers None
     assert rows[0].ssm_chunk is None and "ssm_chunk" not in rows[0].facts
     assert rows[0].moe_grouped_lowerings is None      # no expert layer
