@@ -55,7 +55,8 @@ OURO_TENSORS = {
 }
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
-                              "olmo_hybrid", "nemotron_h", "lfm2_moe")
+                              "olmo_hybrid", "nemotron_h", "lfm2_moe",
+                              "bailing_hybrid")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
@@ -63,7 +64,13 @@ _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
 _CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
-                "nemotron_h", "lfm2_moe")
+                "nemotron_h", "lfm2_moe", "bailing_hybrid")
+#: ``model_type: "bailing_hybrid"`` (the Ling-3.0 family): keys that turn on
+#: something the mapping does not build, with the value it takes
+_BAILING_PLAIN = {"use_nGPT": False, "value_norm": False,
+                  "up_proj_norm": False, "scale_router_input": False,
+                  "use_kda_lora": False, "mtp_use_kda": False,
+                  "num_kv_heads_for_linear_attn": 0, "group_norm_size": 1}
 #: ``hybrid_override_pattern`` letters (``model_type: "nemotron_h"``) ->
 #: layer kinds of a ``one_branch`` model
 _NEMOTRON_KINDS = {"M": "ssm", "*": "full", "E": "moe", "-": "dense"}
@@ -436,6 +443,95 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             moe_intermediate_size=get("moe_intermediate_size"),
             moe_dispatch="grouped", moe_scoring="sigmoid",
             moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+        )
+    elif model_type == "bailing_hybrid":
+        # the Ling-3.0 family: KDA layers (the delta rule with a decay a key
+        # channel, a lower-bounded gate, a head-wise output gate) with every
+        # ``layer_group_size``-th layer latent attention under the same
+        # head-wise gate; the first ``first_k_dense_replace`` FFNs dense
+        # SwiGLU, the others routed: sigmoid scores with a selection bias,
+        # group-limited selection, the top k normalised and scaled, one
+        # shared expert. ``use_qk_norm`` is read as the latent's norm in a
+        # latent-attention layer and the L2 norms of q and k in a KDA layer.
+        # The config side only. A share of the heads or experts is no config
+        # key: pass heads_held= and moe_experts_held=. What training adds
+        # (the bias rule's rate, the balance term's weight): pass
+        # moe_bias_rate= and moe_aux_loss_coef=.
+        for key, plain in _BAILING_PLAIN.items():
+            if (get(key, plain) or plain) != plain:
+                raise ValueError(
+                    f"bailing_hybrid with {key}={get(key)!r} is not mapped "
+                    f"(only {key}={plain!r})")
+        L, period = int(get("num_hidden_layers")), int(get("layer_group_size"))
+        for key in ("expert_swiglu_limit_list",
+                    "share_expert_swiglu_limit_list"):
+            if any(list(get(key) or ())[:L]):
+                raise ValueError(
+                    f"bailing_hybrid with a non-zero {key} among its "
+                    f"{L} layers (a clamp inside the experts' SwiGLU whose "
+                    f"form the config does not give) is not mapped")
+        if get("q_lora_rank") is not None or get("rope_scaling"):
+            raise ValueError(
+                f"bailing_hybrid with q_lora_rank={get('q_lora_rank')} / "
+                f"rope_scaling={get('rope_scaling')} is not mapped: one "
+                f"query matrix and a plain rope only")
+        if (get("score_function", "sigmoid") != "sigmoid"
+                or not get("norm_topk_prob", True)
+                or not get("moe_router_enable_expert_bias", True)
+                or not get("kda_safe_gate", True)
+                or not get("no_kda_lora", True)
+                or not get("linear_silu", True)
+                or not get("use_qk_norm", True)
+                or get("gated_attention_proj_granularity_type",
+                       "head_wise") != "head_wise"):
+            raise ValueError(
+                "bailing_hybrid is mapped with sigmoid scores and a "
+                "selection bias, a normalised top k, the lower-bounded KDA "
+                "gate at full rank (kda_safe_gate, no_kda_lora), silu after "
+                "the convolutions (linear_silu), use_qk_norm and a "
+                "head-wise output gate")
+        shared = int(get("moe_shared_expert_intermediate_size", 0) or 0)
+        width = int(get("moe_intermediate_size"))
+        if shared % width:
+            raise ValueError(
+                f"moe_shared_expert_intermediate_size={shared} is no "
+                f"multiple of moe_intermediate_size={width}")
+        if int(get("rotary_dim", get("qk_rope_head_dim"))) \
+                != int(get("qk_rope_head_dim")):
+            raise ValueError(
+                f"rotary_dim={get('rotary_dim')} is not qk_rope_head_dim="
+                f"{get('qk_rope_head_dim')}: the rope is on the keys' rope "
+                f"columns")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            rope_theta=float(get("rope_theta", 10000.0)),
+            # layer i is latent attention where (i + 1) % period == 0 (a cut
+            # that does not start at layer 0 passes its own attn_pattern=)
+            attn_pattern=tuple("mla" if (i + 1) % period == 0 else "kda"
+                               for i in range(L)),
+            delta_key_dim=get("head_dim"), delta_value_dim=get("head_dim"),
+            delta_conv=int(get("short_conv_kernel_size", 4)),
+            kda_lower_bound=float(get("kda_lower_bound", -5.0)),
+            kv_lora_rank=get("kv_lora_rank"),
+            qk_nope_head_dim=get("qk_nope_head_dim"),
+            qk_rope_head_dim=get("qk_rope_head_dim"),
+            v_head_dim=get("v_head_dim"),
+            rope_interleave=bool(get("rope_interleave", True)),
+            mla_head_gate=True,
+            first_k_dense=int(get("first_k_dense_replace", 0)),
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=width,
+            moe_dispatch="grouped", moe_scoring="sigmoid",
+            moe_routed_scale=float(get("routed_scaling_factor", 1.0)),
+            moe_shared_experts=shared // width,
+            moe_n_group=int(get("n_group", 1) or 1),
+            moe_topk_group=int(get("topk_group", 1) or 1),
         )
     elif model_type == "falcon":
         if get("alibi", False):

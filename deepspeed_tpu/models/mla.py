@@ -9,7 +9,13 @@ rope, then its ``dr`` rope columns), ``wkv_a`` [D, r + dr] (the latent, then
 the one rope key every head shares), ``kv_norm`` [r] (the latent's RMSNorm
 scale), ``wkv_b`` [r, H (dn + dv)] (a head's key columns, then its value
 columns) and ``wo`` [H dv, D]. Keys are ``dn + dr`` wide, values ``dv``; the
-softmax scale is ``1 / sqrt(dn + dr)``.
+softmax scale is ``1 / sqrt(dn + dr)``. ``H`` is the heads held
+(``cfg.heads_held`` of ``cfg.num_heads``, the first of them; all where it is
+None): ``wq``, ``wkv_b`` and ``wo`` are by heads, ``wkv_a`` and the latent's
+norm whole on every share, and the output is the partial sum the held heads
+give. With ``cfg.mla_head_gate`` also ``wg`` [D, H]: each head's output is
+multiplied by ``sigmoid(x wg)[h]`` before ``wo`` (one scalar a head and
+position).
 """
 
 from __future__ import annotations
@@ -27,40 +33,48 @@ from deepspeed_tpu.parallel.sharding import constrain
 
 
 def sizes(cfg) -> Dict[str, int]:
-    H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+    H, dn, dr, dv = (cfg.heads_here, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     return {"q": H * (dn + dr), "kv_a": cfg.kv_lora_rank + dr,
-            "kv_b": H * (dn + dv), "o": H * dv}
+            "kv_b": H * (dn + dv), "o": H * dv,
+            "gate": H if cfg.mla_head_gate else 0}
 
 
 def num_params(cfg) -> int:
     s, D, r = sizes(cfg), cfg.hidden_size, cfg.kv_lora_rank
-    return D * s["q"] + D * s["kv_a"] + r + r * s["kv_b"] + s["o"] * D
+    return (D * s["q"] + D * s["kv_a"] + r + r * s["kv_b"] + s["o"] * D
+            + D * s["gate"])
 
 
 def init(rng: jax.Array, cfg, n: int, pd) -> Dict[str, jax.Array]:
     """``n`` layers' leaves, normal at 1/sqrt(fan_in) like the program's
     other matrices."""
     s, D, r = sizes(cfg), cfg.hidden_size, cfg.kv_lora_rank
-    k = jax.random.split(rng, 4)
+    k = jax.random.split(rng, 5)
 
     def dense(key, fan_in, shape):
         return jax.random.normal(key, (n,) + shape, pd) / math.sqrt(fan_in)
 
-    return {"wq": dense(k[0], D, (D, s["q"])),
-            "wkv_a": dense(k[1], D, (D, s["kv_a"])),
-            "kv_norm": jnp.ones((n, r), pd),
-            "wkv_b": dense(k[2], r, (r, s["kv_b"])),
-            "wo": dense(k[3], s["o"], (s["o"], D))}
+    out = {"wq": dense(k[0], D, (D, s["q"])),
+           "wkv_a": dense(k[1], D, (D, s["kv_a"])),
+           "kv_norm": jnp.ones((n, r), pd),
+           "wkv_b": dense(k[2], r, (r, s["kv_b"])),
+           "wo": dense(k[3], s["o"], (s["o"], D))}
+    if s["gate"]:
+        out["wg"] = dense(k[4], D, (D, s["gate"]))
+    return out
 
 
-def param_specs() -> Dict[str, P]:
+def param_specs(cfg) -> Dict[str, P]:
     """Heads over tp, as the attention group's: the per-head products
     column-parallel, ``wo`` row-parallel; the latent projection and its norm
     whole on every shard."""
-    return {"wq": P(None, None, "tp"), "wkv_a": P(None, None, None),
-            "kv_norm": P(None, None), "wkv_b": P(None, None, "tp"),
-            "wo": P(None, "tp", None)}
+    out = {"wq": P(None, None, "tp"), "wkv_a": P(None, None, None),
+           "kv_norm": P(None, None), "wkv_b": P(None, None, "tp"),
+           "wo": P(None, "tp", None)}
+    if cfg.mla_head_gate:
+        out["wg"] = P(None, None, "tp")
+    return out
 
 
 def _halves(w: jax.Array, dr: int) -> jax.Array:
@@ -107,7 +121,7 @@ def mla_block(x: jax.Array, w: Dict[str, jax.Array], cfg,
     from deepspeed_tpu.ops.flash_attention import assembled
 
     B, T, D = x.shape
-    H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim,
+    H, dn, dr, dv = (cfg.heads_here, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     r = cfg.kv_lora_rank
     with jax.named_scope("mla_proj"):
@@ -139,5 +153,10 @@ def mla_block(x: jax.Array, w: Dict[str, jax.Array], cfg,
             whole = assembled(q, k, v, q_rope, k_rope)
         out = attn_fn(*whole, causal=True)                    # [B, T, H, dv]
     with jax.named_scope("mla_proj"):
+        if "wg" in w:
+            # one scalar a head and position, on the heads' outputs
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                (x @ w["wg"]).astype(jnp.float32))[..., None]
+            ).astype(x.dtype)
         o = out.reshape(B, T, H * dv) @ w["wo"]
     return constrain(o, P(("dp", "fsdp"), "sp", None))

@@ -70,7 +70,9 @@ class TransformerConfig:
     # ``ssm_*`` sizes below, models/mamba.py) or "delta" (a gated delta-rule
     # layer of the ``delta_*`` sizes, models/gated_delta.py) or "conv" (a
     # gated short convolution over ``conv_taps`` positions at the model's
-    # width, models/short_conv.py): layer i is of
+    # width, models/short_conv.py) or "kda" (the delta rule with a decay a
+    # key channel, models/kda.py) or, beside "kda" layers, "mla" (latent
+    # attention, models/mla.py): layer i is of
     # kind attn_pattern[i % len]. None = every layer the one attention kind
     # (windowed where sliding_window is set). HF qwen2's leading run of n
     # full layers is ("full",) * n + ("window",) * (L - n): a period of the
@@ -107,6 +109,14 @@ class TransformerConfig:
     delta_value_dim: int = 128
     delta_conv: int = 4
     delta_neg_eigval: bool = False
+    # a KDA layer (Kimi Delta Attention: the delta rule with a decay a key
+    # channel): ``num_heads`` heads (``heads_held`` of them where set) with
+    # keys of ``delta_key_dim`` and values of ``delta_value_dim``, the
+    # convolutions over ``delta_conv`` positions, a head-wise sigmoid output
+    # gate; the decay's logarithm is ``kda_lower_bound x sigmoid(.)``, in
+    # (``kda_lower_bound``, 0): the bound the chunked rule's operands need
+    # (ops/kda_rule.py)
+    kda_lower_bound: float = -5.0
     # a gated short-convolution layer (the LFM2 family's): ``in_proj`` to
     # three times the width, a gate before and a gate after a causal
     # depthwise convolution over ``conv_taps`` positions (no bias, no
@@ -165,8 +175,10 @@ class TransformerConfig:
     # are split and before any rope; None = none
     qk_norm: Optional[str] = None
     # a share of a mixer's heads: this model holds ``heads_held`` of an
-    # attention layer's ``num_heads`` (with the key-value heads that serve
-    # them) and of a delta layer's ``delta_heads``, the first of them (what
+    # attention, latent-attention or KDA layer's ``num_heads`` (with the
+    # key-value heads that serve them; latent attention's ``wkv_a`` and its
+    # norm stay whole) and of a delta layer's ``delta_heads``, the first of
+    # them (what
     # one chip of several that divide a layer's mixer by heads holds).
     # The projections are built for the heads held, the output projection has
     # their rows, and the mixer's output is the partial sum those heads give.
@@ -222,6 +234,13 @@ class TransformerConfig:
     moe_routed_scale: float = 1.0
     moe_bias_rate: float = 0.0
     moe_bias_init: float = 0.0
+    # group-limited selection of a sigmoid router (DeepSeek-V3's): the
+    # experts in ``moe_n_group`` groups of equal size, a group's score the
+    # sum of its two largest ``sigmoid + router_bias``, the ``moe_topk_group``
+    # best groups kept and the top k taken among their experts. 1 group: no
+    # limit
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # this many shared experts, every token's: one FFN of the experts' kind
     # (SwiGLU, or two products round relu^2) of that many times the experts'
     # width beside the routed ones (grouped dispatch only)
@@ -236,7 +255,8 @@ class TransformerConfig:
     # has a stack of its own (``mlp_dense``, ``mlp_moe``)
     first_k_dense: int = 0
     # latent attention (models/mla.py), every layer's mixer where
-    # ``kv_lora_rank`` is set: keys and values through a latent of that rank
+    # ``kv_lora_rank`` is set and no ``attn_pattern`` names the "mla" layers
+    # among "kda" ones: keys and values through a latent of that rank
     # with a norm in the middle, keys ``qk_nope_head_dim + qk_rope_head_dim``
     # wide (the rope part one vector a position, shared by the heads), values
     # ``v_head_dim``; ``rope_interleave``: the published weights pair the
@@ -248,6 +268,9 @@ class TransformerConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_interleave: bool = False
+    # each head's output times ``sigmoid(x wg)[h]`` before ``wo`` (a KDA
+    # layer always has the gate)
+    mla_head_gate: bool = False
 
     def __post_init__(self):
         is_llama = self.arch == "llama"
@@ -281,17 +304,20 @@ class TransformerConfig:
                              "loss) needs num_passes >= 2")
         if self.attn_pattern is not None:
             pat = tuple(self.attn_pattern)
-            if self.kv_lora_rank is not None:
+            if (self.kv_lora_rank is not None) != ("mla" in pat) or (
+                    "mla" in pat and set(pat) != {"kda", "mla"}):
                 raise NotImplementedError(
-                    "latent attention (kv_lora_rank) is every layer's mixer: "
-                    "not with attn_pattern")
+                    "latent attention (kv_lora_rank) is every layer's mixer, "
+                    "or the 'mla' layers of an attn_pattern of 'kda' and "
+                    "'mla' layers: not with another attn_pattern")
             ffns = {"moe", "dense"} if self.one_branch else set()
             if not pat or set(pat) - {"window", "full", "ssm", "delta",
-                                      "conv"} \
+                                      "conv", "kda", "mla"} \
                     - ffns or self.num_layers % len(pat):
                 raise ValueError(
                     f"attn_pattern={pat}: a period of 'window' / 'full' / "
-                    f"'ssm' / 'delta' / 'conv' (with one_branch also 'moe' / "
+                    f"'ssm' / 'delta' / 'conv' / 'kda' (beside 'kda' also "
+                    f"'mla'; with one_branch also 'moe' / "
                     f"'dense') whose length divides num_layers="
                     f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
@@ -344,6 +370,27 @@ class TransformerConfig:
                     "a delta layer with attention_impl='fpdt': the chunked "
                     "sequence path carries key-value chunks, not a "
                     "recurrent state")
+        if self.has_kda:
+            if self.delta_conv < 1 or not self.kda_lower_bound < 0:
+                raise ValueError(
+                    f"a KDA layer needs delta_conv={self.delta_conv} above "
+                    f"0 and kda_lower_bound={self.kda_lower_bound} below 0")
+            if (self.looped or self.parallel_block or self.loss_tiling > 1
+                    or self.attention_impl == "fpdt" or self.one_branch
+                    or set(self.attn_pattern) - {"kda", "mla"}
+                    or self.norm_placement != "pre"):
+                raise NotImplementedError(
+                    "a model with KDA layers (attn_pattern holds 'kda') "
+                    "runs one pre-norm pass of two-branch layers with whole "
+                    "logits and whole sequences, beside latent-attention "
+                    "layers alone: not a looped stack (num_passes > 1, "
+                    "sandwich_norm or the exit gate: a pass would have to "
+                    "say what state the next one starts from), "
+                    "parallel_block, the tiled loss (loss_tiling > 1), "
+                    "attention_impl='fpdt' (its chunks carry keys and "
+                    "values, not a recurrent state), one_branch, "
+                    "norm_placement='post' or another kind of mixer in the "
+                    "pattern")
         if self.has_conv:
             if self.conv_taps < 1:
                 raise ValueError(f"a conv layer needs conv_taps="
@@ -422,13 +469,14 @@ class TransformerConfig:
                 raise ValueError(
                     f"heads_held={n} cut a group of {group} query heads "
                     f"from the key-value head that serves it")
-            if (self.has_mla or self.has_ssm or self.looped
+            if (self.has_ssm or self.looped
                     or self.parallel_block or self.qkv_bias or self.proj_bias
                     or self.attention_impl == "fpdt"):
                 raise NotImplementedError(
                     "a held share of the heads (heads_held) is built for "
-                    "plain attention and delta layers without biases: not "
-                    "latent attention, a state-space layer, a looped stack, "
+                    "plain attention, latent attention, delta and KDA "
+                    "layers without biases: not "
+                    "a state-space layer, a looped stack, "
                     "parallel_block, qkv_bias / proj_bias or "
                     "attention_impl='fpdt'")
         if self.has_mla:
@@ -451,9 +499,24 @@ class TransformerConfig:
                     "rope_scaling, rope_by_kind, use_rope=False, "
                     "attention_multiplier, loss_tiling > 1 or "
                     "attention_impl='fpdt'")
+        if self.mla_head_gate and not self.has_mla:
+            raise ValueError("mla_head_gate gates latent attention's heads "
+                             "(kv_lora_rank)")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_scoring={self.moe_scoring!r}: 'softmax' "
                              f"or 'sigmoid'")
+        if self.moe_n_group != 1 or self.moe_topk_group != 1:
+            n, kept = self.moe_n_group, self.moe_topk_group
+            if (self.moe_scoring != "sigmoid" or n < 1
+                    or self.num_experts % n or not 1 <= kept <= n
+                    or self.num_experts // n < 2
+                    or kept * (self.num_experts // n) < self.top_k):
+                raise ValueError(
+                    f"moe_n_group={n}, moe_topk_group={kept}: group-limited "
+                    f"selection is a sigmoid router's (moe_scoring="
+                    f"'sigmoid'), over groups of equal size of at least two "
+                    f"of the num_experts={self.num_experts}, the kept "
+                    f"groups holding at least top_k={self.top_k} experts")
         if (self.moe_scoring == "sigmoid" or self.moe_shared_experts
                 or self.first_k_dense or self.moe_latent_size) and (
                     self.num_experts <= 1 or self.moe_dispatch != "grouped"
@@ -516,6 +579,12 @@ class TransformerConfig:
         return "delta" in (self.attn_pattern or ())
 
     @property
+    def has_kda(self) -> bool:
+        """Whether any layer's mixer is a KDA layer (the delta rule with a
+        decay a key channel)."""
+        return "kda" in (self.attn_pattern or ())
+
+    @property
     def has_conv(self) -> bool:
         """Whether any layer's mixer is a gated short convolution."""
         return "conv" in (self.attn_pattern or ())
@@ -532,7 +601,8 @@ class TransformerConfig:
 
     @property
     def has_mla(self) -> bool:
-        """Whether the layers' mixer is latent attention."""
+        """Whether a layer's mixer is latent attention: every layer's, or
+        the "mla" layers' of an ``attn_pattern`` beside "kda" ones."""
         return self.kv_lora_rank is not None
 
     @property
@@ -542,7 +612,7 @@ class TransformerConfig:
         attention, or whose layers are one branch each (then every layer's
         branch output, an FFN layer's too)."""
         return (self.has_ssm or self.has_mla or self.has_delta
-                or self.has_conv or self.one_branch)
+                or self.has_conv or self.has_kda or self.one_branch)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -554,7 +624,7 @@ class TransformerConfig:
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of every layer: its mixer's, "window", "full", "ssm",
-        "delta", "conv" or "mla"; in a model whose FFNs differ by layer, then
+        "delta", "conv", "kda" or "mla"; in a model whose FFNs differ by layer, then
         ":" and its FFN's, "dense" or "moe". A layer of one branch (``one_branch``)
         names what it lacks "none": "ssm:none", "none:moe"."""
         pat = self.attn_pattern or (
@@ -577,7 +647,7 @@ class TransformerConfig:
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
                 or self.has_mla or self.has_delta or self.has_conv
-                or self.one_branch)
+                or self.has_kda or self.one_branch)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -652,6 +722,10 @@ class TransformerConfig:
             from deepspeed_tpu.models import short_conv
 
             mixers["conv"] = short_conv.num_params(self)
+        if self.has_kda:
+            from deepspeed_tpu.models import kda
+
+            mixers["kda"] = kda.num_params(self)
         layers = 0
         for kind in self.layer_kinds:
             mixer, _, ffn = kind.partition(":")
@@ -1114,7 +1188,9 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                "delta_proj", "delta_conv", "delta_scan", "delta_gate",
                # a short-convolution layer's inside attn
                # (models/short_conv.py)
-               "sconv_proj", "sconv_conv")
+               "sconv_proj", "sconv_conv",
+               # a KDA layer's inside attn (models/kda.py)
+               "kda_proj", "kda_conv", "kda_scan", "kda_gate")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind. Layers of one branch
 #: each (``one_branch``) are half a block: a period of up to twice as many,
@@ -1134,7 +1210,7 @@ _KEEP_FP32 = ("A_log", "dt_bias", "D", "router_bias")
 #: a row for every layer. A layer of one branch names what it lacks "none"
 #: ("ssm:none", "none:moe") and keeps leaves in the one group it has
 _MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla",
-                "delta": "delta", "conv": "conv"}
+                "delta": "delta", "conv": "conv", "kda": "kda"}
 _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
 _KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
     _FFN_GROUP.values())
@@ -1184,7 +1260,7 @@ def _cast_layers(w: Params, dt, ffn: Optional[str],
     for k, v in w.items():
         with (contextlib.nullcontext() if ffn is None else jax.named_scope(
                 "attn" if k in ("ln1", "attn", "ssm", "mla", "delta", "conv",
-                                "ln1_post")
+                                "kda", "ln1_post")
                 else _FFN_SCOPE.get(k, ffn))):
             if any(n in _KEEP_FP32 for n in v):
                 out[k] = {n: p if n in _KEEP_FP32
@@ -1225,7 +1301,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     with ``w["delta"]`` (models/gated_delta.py:delta_block) under
     ``attn/delta_*``, one of kind "conv" with ``w["conv"]``
     (models/short_conv.py:conv_block) under ``attn/sconv_*``, one of kind
-    "mla" with ``w["mla"]``
+    "kda" with ``w["kda"]`` (models/kda.py:kda_block) under ``attn/kda_*``,
+    one of kind "mla" with ``w["mla"]``
     (models/mla.py:mla_block); a kind that names its FFN
     ("mla:dense") runs ``moe_fn`` only where that is "moe". With ``mix_ms``
     the aux value is a dict that also holds the mean square of the mixer's
@@ -1245,7 +1322,7 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     post = cfg.norm_placement == "post"
     with jax.named_scope("attn"), (jax.named_scope("attn_" + kind)
                                    if kind and kind not in ("ssm", "delta",
-                                                            "conv")
+                                                            "conv", "kda")
                                    else contextlib.nullcontext()):
         hn1 = x if post else _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
         if kind == "delta":
@@ -1262,6 +1339,11 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             from deepspeed_tpu.models.short_conv import conv_block
 
             attn_out = constrain(conv_block(hn1, wc["conv"], cfg),
+                                 P(("dp", "fsdp"), "sp", None))
+        elif kind == "kda":
+            from deepspeed_tpu.models.kda import kda_block
+
+            attn_out = constrain(kda_block(hn1, wc["kda"], cfg),
                                  P(("dp", "fsdp"), "sp", None))
         elif kind == "mla":
             from deepspeed_tpu.models.mla import mla_block
@@ -1443,6 +1525,9 @@ def _share_parts(aux: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         # a sigmoid router's: the pairs every expert the router scores
         # received, held here or not (what the bias rule reads)
         parts["router_counts"] = aux["router_counts"]
+    if "groups_kept" in aux:
+        # group-limited selection's: the tokens that kept each group
+        parts["groups_kept"] = aux["groups_kept"]
     return parts
 
 
@@ -1598,6 +1683,12 @@ class TransformerLM:
                 f"whose recurrent and convolution state it would have to "
                 f"keep beside the key-value cache; only the train step runs "
                 f"them")
+        if cfg.has_kda:
+            raise NotImplementedError(
+                f"{what} is written for attention layers: this model has "
+                f"KDA layers (attn_pattern={cfg.attn_pattern}), whose "
+                f"recurrent and convolution state it would have to keep "
+                f"beside the latent cache; only the train step runs them")
         if cfg.has_conv:
             raise NotImplementedError(
                 f"{what} is written for attention layers: this model has "
@@ -1689,9 +1780,10 @@ class TransformerLM:
         chunks = {}
         if cfg.has_ssm:
             chunks["ssm"] = cfg.ssm_chunk
-        if cfg.has_delta:
+        if cfg.has_delta or cfg.has_kda:
+            # (the KDA kind's rule runs in the delta rule's chunks)
             from deepspeed_tpu.ops import delta_rule
-            chunks["delta"] = delta_rule.CHUNK
+            chunks["delta" if cfg.has_delta else "kda"] = delta_rule.CHUNK
         for kind, chunk in chunks.items():
             # the chunk length of the kind's scan, and the chunks one step's
             # forward scans: layers x rows x ceil(T / chunk)
@@ -1719,6 +1811,9 @@ class TransformerLM:
             facts["experts_held"] = (
                 cfg.moe_first_expert if cfg.moe_experts_held else 0,
                 cfg.moe_experts_held or cfg.num_experts, cfg.num_experts)
+            if cfg.moe_n_group > 1:
+                # (groups, groups a token keeps) of group-limited selection
+                facts["moe_groups"] = (cfg.moe_n_group, cfg.moe_topk_group)
             if cfg.moe_dispatch == "grouped":
                 from deepspeed_tpu.moe.sharded_moe import resolve_moe_kernel
 
@@ -1796,10 +1891,11 @@ class TransformerLM:
         (``heads_held``, already one chip's part of them) do not follow."""
         cfg = self.cfg
         if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta or cfg.has_conv
+                                            or cfg.has_kda
                                             or cfg.heads_held is not None):
             raise NotImplementedError(
-                f"a tp axis of {axis_sizes['tp']} with gated delta-rule "
-                f"layers, short-convolution layers or a held share of the "
+                f"a tp axis of {axis_sizes['tp']} with gated delta-rule or "
+                f"KDA layers, short-convolution layers or a held share of the "
                 f"heads (heads_held={cfg.heads_held}): tensor parallelism "
                 f"would divide heads that the delta layer keeps whole and "
                 f"that heads_held already divides, and a conv layer's fused "
@@ -1913,7 +2009,13 @@ class TransformerLM:
         if cfg.has_mla:
             from deepspeed_tpu.models import mla
 
-            layers["mla"] = mla.init(jax.random.fold_in(rng, 14), cfg, L, pd)
+            layers["mla"] = mla.init(jax.random.fold_in(rng, 14), cfg,
+                                     _in_group(kinds, "mla"), pd)
+        if cfg.has_kda:
+            from deepspeed_tpu.models import kda
+
+            layers["kda"] = kda.init(jax.random.fold_in(rng, 18), cfg,
+                                     _in_group(kinds, "kda"), pd)
         if not La:
             del layers["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):
@@ -2785,7 +2887,7 @@ class TransformerLM:
         if cfg.has_mla:
             from deepspeed_tpu.models import mla
 
-            layer_specs["mla"] = mla.param_specs()
+            layer_specs["mla"] = mla.param_specs(cfg)
         if cfg.has_delta:
             from deepspeed_tpu.models import gated_delta
 
@@ -2794,6 +2896,10 @@ class TransformerLM:
             from deepspeed_tpu.models import short_conv
 
             layer_specs["conv"] = short_conv.param_specs()
+        if cfg.has_kda:
+            from deepspeed_tpu.models import kda
+
+            layer_specs["kda"] = kda.param_specs()
         if not _in_group(cfg.layer_kinds, "attn"):
             del layer_specs["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):

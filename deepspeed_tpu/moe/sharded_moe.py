@@ -201,22 +201,47 @@ def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
     return gates, aux_loss, topk_vals, topk_idx
 
 
-def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
+def _kept_groups(sb: jax.Array, n_group: int, topk_group: int):
+    """Group-limited selection (DeepSeek-V3's) on the selection scores
+    ``sb`` [B, T, E]: the experts in ``n_group`` groups of ``E / n_group``
+    neighbours, a group's score the sum of its two largest, the
+    ``topk_group`` best groups kept (``lax.top_k``: of equal scores the
+    lower group). Returns ``(sb with the other groups' experts at -inf,
+    tokens that kept each group [n_group])``. Two sorts and a compare of the
+    kept groups against ``arange(n_group)``: nothing is gathered."""
+    B, T, E = sb.shape
+    grouped = sb.reshape(B, T, n_group, E // n_group)
+    score = jax.lax.top_k(grouped, 2)[0].sum(-1)              # [B, T, n]
+    _, best = jax.lax.top_k(score, topk_group)
+    keep = _hot(best, n_group).any(axis=-2)                   # [B, T, n]
+    return (jnp.where(keep[..., None], grouped, -jnp.inf).reshape(B, T, E),
+            keep.sum(axis=(0, 1), dtype=jnp.int32))
+
+
+def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float,
+                   groups: Tuple[int, int] = (1, 1)):
     """The DeepSeek-V3 family's router (``moe_scoring="sigmoid"``) over
     ``logits`` [B, T, E] in float32: scores ``s = sigmoid(logits)``, the
     ``k`` experts with the largest ``s + bias`` (the selection bias picks
-    and gets no gradient), weights ``scale x s_i / sum_{chosen} s_j`` (the
+    and gets no gradient; with ``groups`` (n_group, topk_group) above (1, 1)
+    among the experts of the groups :func:`_kept_groups` keeps), weights
+    ``scale x s_i / sum_{chosen} s_j`` (the
     bias is not in them). Returns ``(balance term, weights [B T, k], experts
-    [B T, k], counts [E])``: the sequence-wise balance term ``sum_i f_i
+    [B T, k], counts [E], groups kept [n_group] or None)``: the sequence-wise
+    balance term ``sum_i f_i
     P_i``, ``f_i = E / (k T) x`` the sequence's pairs of expert i (the chosen
     pairs, bias included: a constant), ``P_i`` the sequence's mean of ``s_i /
-    sum_j s_j``, averaged over the sequences; and the pairs each expert
-    received from all of them. The chosen scores (:func:`_pick`) and the
+    sum_j s_j``, averaged over the sequences; the pairs each expert
+    received from all of them; and the tokens that kept each group. The
+    chosen scores (:func:`_pick`) and the
     counts both come from compares of the chosen experts against
     ``arange(E)``: no gather of a scalar a pair, no scatter-add behind it."""
     B, T, E = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)   # [B, T, k]
+    chosen_by, kept = s + bias.astype(jnp.float32), None
+    if tuple(groups) != (1, 1):
+        chosen_by, kept = _kept_groups(chosen_by, *groups)
+    _, idx = jax.lax.top_k(chosen_by, k)                      # [B, T, k]
     picked = _pick(s, idx, E)
     weights = scale * picked / picked.sum(-1, keepdims=True)
     by_seq = _hot(idx, E).sum(axis=(1, 2), dtype=jnp.int32)   # [B, E]
@@ -224,7 +249,7 @@ def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float):
     p = (s / s.sum(-1, keepdims=True)).mean(axis=1)           # [B, E]
     aux_loss = (f * p).sum(-1).mean()
     return (aux_loss, weights.reshape(B * T, k), idx.reshape(B * T, k),
-            by_seq.sum(axis=0))
+            by_seq.sum(axis=0), kept)
 
 
 # the running count's chunk of rows
@@ -631,9 +656,11 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
     add is left out, and the second value returned is a dict: the
     load-balance term under ``lb`` and the router's counts.
     ``cfg.moe_scoring="sigmoid"`` scores with :func:`_route_sigmoid` (the
-    selection bias ``w["router_bias"]``, ``cfg.moe_routed_scale``) and always
+    selection bias ``w["router_bias"]``, ``cfg.moe_routed_scale``, the groups
+    of ``cfg.moe_n_group`` / ``cfg.moe_topk_group``) and always
     returns the dict, with ``router_counts`` [E] beside the held experts'
-    ``expert_pairs``; ``w["shared"]`` (the shared experts' SwiGLU) is added
+    ``expert_pairs`` (and ``groups_kept`` [n_group] under group-limited
+    selection); ``w["shared"]`` (the shared experts' SwiGLU) is added
     for every token under the scope ``moe_shared``, whole on every share:
     summed over shares it counts once. Experts without ``w_gate`` are two
     products round :func:`_ungated_act`'s activation, routed and shared.
@@ -687,9 +714,12 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
         logits = x.astype(jnp.float32) @ w["router"].astype(jnp.float32)
         # the top k and their weights over ALL experts, as the whole model
         if sigmoid:
-            aux_loss, topk_vals, topk_idx, router_counts = _route_sigmoid(
+            (aux_loss, topk_vals, topk_idx, router_counts,
+             groups_kept) = _route_sigmoid(
                 logits.reshape(B, T, E), w["router_bias"], k,
-                float(getattr(cfg, "moe_routed_scale", 1.0)))
+                float(getattr(cfg, "moe_routed_scale", 1.0)),
+                (int(getattr(cfg, "moe_n_group", 1)),
+                 int(getattr(cfg, "moe_topk_group", 1))))
         else:
             _gates, aux_loss, topk_vals, topk_idx = _route(
                 logits, k,
@@ -751,6 +781,8 @@ def grouped_moe_mlp_block(h: jax.Array, w: Dict[str, jax.Array], cfg: Any,
            "pairs_dropped": counts.sum() - n_here}
     if sigmoid:
         aux["router_counts"] = router_counts
+        if groups_kept is not None:
+            aux["groups_kept"] = groups_kept
     return out, aux
 
 

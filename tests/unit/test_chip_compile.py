@@ -1136,3 +1136,55 @@ def test_the_lfm2_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     assert "/moe/moe_router/" in text
     assert _index_ops_under(text, "moe_router") == []
     assert _index_ops_under(text, "moe_dispatch") == []
+
+
+def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    ling3_flash_train_d7h16e8v8.json``: a dense KDA layer and one period,
+    three KDA layers, latent attention, two KDA layers, 16 of 32 heads, at
+    the published widths, 1 x 8192 tokens, ``attn_saveable``). It fits beside what a
+    chip reserves; the rule with a decay a key channel is the einsum form
+    (it has no kernels: no Mosaic call under ``attn/kda_scan``), counted
+    once a traced body; each of the three runs of KDA layers holds the
+    convolution's forward kernel for q, k and v twice (once recomputed) and
+    its backward once under ``attn/kda_conv``; the latent-attention layer
+    runs the flash kernels on 16 heads under ``attn/attn_mla``, the forward
+    once (the policy keeps what its rule named), whose
+    instructions keep the names the benchmark's patterns look for; the six
+    routed layers run the grouped products and the row kernels under
+    ``moe``, and under ``moe_router``, group selection and all, and
+    ``moe_dispatch`` nothing is gathered or scattered."""
+    snap = lowerings.snapshot()
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "ling3_flash_train_d7h16e8v8",
+        "modelcfg_ling3", 648_853_344, seq=8192)
+    assert mem.temp_size_in_bytes < 6.3e9
+    counted = lowerings.since(snap)
+    # a dense run, two routed runs: three bodies of a KDA layer
+    assert counted["kda_scan"] == {"xla": 3}
+    assert counted["conv"] == {"pallas": 3 * 3 * 2}
+    assert not _kernel_calls(text, "kda_scan")
+    assert "/attn/kda_scan/" in text and "/attn/kda_gate/" in text
+    conv = _kernel_calls(text, "kda_conv")
+    assert conv and all("/attn/kda_conv/" in n for n in conv)
+    assert sum("jit(conv_fwd)" in n for n in conv) == 3 * 3 * 2
+    assert sum("jit(conv_bwd)" in n for n in conv) == 3 * 3
+    assert not _kernel_calls(text, "kda_proj")
+    flash = _kernel_calls(text, "attn_mla")
+    # one forward and the fused backward: the policy keeps the forward's
+    # output and log-sum-exp
+    assert len(flash) == 2 and sum("transpose(" in n for n in flash) == 1
+    assert "attn_mla" not in steplog.recomputed_kernels(text)
+    assert len(re.findall(r"^\s*%attn_mla[.\d]* = .*custom-call\(.*"
+                          r"tpu_custom_call", text, re.M)) == 2
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert any("jit(sum_of_rows)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)
+    assert "/moe/moe_shared/" in text and "ragged-dot" not in text
+    assert "/moe/moe_router/" in text and "/mlp/" in text
+    assert _index_ops_under(text, "moe_router") == []
+    assert _index_ops_under(text, "moe_dispatch") == []

@@ -630,7 +630,7 @@ def test_sigmoid_routers_pick_is_the_gathers_to_the_bit(ties, monkeypatch):
     r = jax.random.normal(jax.random.key(2), (B * T, k))
 
     def objective(logits):
-        aux, weights, idx, counts = sm._route_sigmoid(logits, bias, k, 2.5)
+        aux, weights, idx, counts, _ = sm._route_sigmoid(logits, bias, k, 2.5)
         return (weights * r).sum() + 3.0 * aux, (weights, idx, counts, aux)
 
     got = jax.value_and_grad(objective, has_aux=True)(logits)

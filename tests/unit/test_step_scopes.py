@@ -92,6 +92,27 @@ CASES = {
                   "moe_intermediate_size": 32, "moe_experts_held": 4,
                   "moe_scoring": "sigmoid", "moe_bias_rate": 1e-3,
                   "moe_bias_init": 0.1, "remat_policy": "full"}, 1),
+    # KDA layers (the delta rule with a decay a key channel) and a
+    # latent-attention layer in one pattern under recomputation, both on a
+    # held share of the heads under a head-wise gate; a dense FFN then routed
+    # ones with a shared expert, chosen under a group limit: every operation
+    # of the KDA mixer under one of its four scopes inside attn, the
+    # latent-attention layer keeps attn/attn_mla, the group selection lies
+    # under moe_router
+    "kda_moe": ({"num_layers": 4,
+                 "attn_pattern": ("kda", "kda", "mla", "kda"),
+                 "delta_key_dim": 16, "delta_value_dim": 16,
+                 "kv_lora_rank": 32, "qk_nope_head_dim": 16,
+                 "qk_rope_head_dim": 8, "v_head_dim": 16,
+                 "rope_interleave": True, "mla_head_gate": True,
+                 "heads_held": 2, "first_k_dense": 1, "num_experts": 8,
+                 "top_k": 2, "moe_dispatch": "grouped",
+                 "moe_intermediate_size": 32, "moe_experts_held": 4,
+                 "moe_scoring": "sigmoid", "moe_routed_scale": 2.5,
+                 "moe_shared_experts": 1, "moe_n_group": 4,
+                 "moe_topk_group": 2, "moe_bias_rate": 1e-3,
+                 "moe_bias_init": 0.1, "tie_embeddings": False,
+                 "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -116,6 +137,8 @@ NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
               "mla_rope": "attn_mla", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe",
               "moe_shared": "moe"}
+NESTED_KDA = {**NESTED_MLA, "kda_proj": "attn", "kda_conv": "attn",
+              "kda_scan": "attn", "kda_gate": "attn"}
 
 
 def _op_names(overrides, ga):
@@ -148,13 +171,13 @@ def test_every_operation_carries_a_step_scope(case):
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
-                            "conv_moe") else "mlp"
+                            "conv_moe", "kda_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
-    if case in ("mla_moe", "conv_moe"):
+    if case in ("mla_moe", "conv_moe", "kda_moe"):
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
               "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
-              "conv_moe": NESTED_CONV}.get(case)
+              "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
@@ -169,6 +192,19 @@ def test_every_operation_carries_a_step_scope(case):
         # carried copy's bf16 cotangents to float32 is left under the scope
         # where it stays an instruction of its own
         found.discard("grad_accum")
+    if case == "kda_moe":
+        # every operation of a KDA mixer lies under one of its four scopes:
+        # what lies under attn and outside them (and outside attn_mla) is
+        # the block's own norm before the mixer and the mean square of its
+        # output, elementwise: no product, no loop, no exponential
+        own = {re.sub(r".*/attn/", "", n).split("/")[0] for n in names
+               if "/attn/" in n} - set(NESTED_KDA)
+        assert own <= {"add", "add_any", "broadcast_in_dim", "mul", "div",
+                       "convert_element_type", "reduce_sum", "rsqrt",
+                       "square"}, own
+        # the groups are chosen inside the router: two sorts more than the
+        # plain top k has
+        assert any("moe_router" in n and "top_k" in n for n in names)
     if case.startswith("looped"):
         want.add("exit_gate")
         # the gate's operations nest inside the loss: loss/exit_gate/...
