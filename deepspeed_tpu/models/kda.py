@@ -126,9 +126,12 @@ def kda_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     """The mixer on its input u [B, T, D] -> [B, T, D]. Its operations lie
     under the nested scopes ``kda_proj`` (the seven products), ``kda_conv``
     (three :func:`causal_conv_silu`: q and k to float32, which the rule
-    norms, v to ``u``'s dtype), ``kda_scan`` (the gate's ``g``, ``beta``, the
-    norms of q and k, the chunked rule) and ``kda_gate`` (the per-head norm
-    and the head-wise gate), inside the caller's ``attn``."""
+    norms, v to ``u``'s dtype), ``kda_scan`` (the gate's ``g`` and ``beta``,
+    then the chunked rule, which takes the norms of q and k: on a TPU two
+    Mosaic kernels that read q, k, v, ``g`` and ``beta`` and write o, the
+    einsum form elsewhere; ``ops/kda_rule.py:kda_lowering``) and
+    ``kda_gate`` (the per-head norm and the head-wise gate), inside the
+    caller's ``attn``."""
     B, T, _ = u.shape
     s, dk, dv = sizes(cfg), cfg.delta_key_dim, cfg.delta_value_dim
     H = s["heads"]

@@ -1143,28 +1143,42 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     ling3_flash_train_d7h16e8v8.json``: a dense KDA layer and one period,
     three KDA layers, latent attention, two KDA layers, 16 of 32 heads, at
     the published widths, 1 x 8192 tokens, ``attn_saveable``). It fits beside what a
-    chip reserves; the rule with a decay a key channel is the einsum form
-    (it has no kernels: no Mosaic call under ``attn/kda_scan``), counted
-    once a traced body; each of the three runs of KDA layers holds the
-    convolution's forward kernel for q, k and v twice (once recomputed) and
-    its backward once under ``attn/kda_conv``; the latent-attention layer
+    chip reserves; the rule with a decay a key channel is its two Mosaic
+    kernels under ``attn/kda_scan`` (``ops/kda_rule.py``), counted twice a
+    traced body (the rule and the kernels' own backward), and each of the
+    three bodies holds the forward kernel twice (the policy names no
+    residual of the rule, so the backward's region runs it again: what
+    ``recomputed_kernels`` says) and the backward kernel once; each of the
+    three runs of KDA layers holds the convolution's forward kernel for q,
+    k and v twice (once recomputed) and its backward once under
+    ``attn/kda_conv``; the latent-attention layer
     runs the flash kernels on 16 heads under ``attn/attn_mla``, the forward
     once (the policy keeps what its rule named), whose
     instructions keep the names the benchmark's patterns look for; the six
     routed layers run the grouped products and the row kernels under
     ``moe``, and under ``moe_router``, group selection and all, and
     ``moe_dispatch`` nothing is gathered or scattered."""
+    from deepspeed_tpu.ops import kda_rule
+
+    monkeypatch.setattr(kda_rule, "_on_tpu", lambda: True)
     snap = lowerings.snapshot()
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "ling3_flash_train_d7h16e8v8",
         "modelcfg_ling3", 648_853_344, seq=8192)
-    assert mem.temp_size_in_bytes < 6.3e9
+    # 4.91 GB as compiled here (6.22 with the rule's einsum form: PR 54)
+    assert mem.temp_size_in_bytes < 5.0e9
     counted = lowerings.since(snap)
-    # a dense run, two routed runs: three bodies of a KDA layer
-    assert counted["kda_scan"] == {"xla": 3}
+    # a dense run, two routed runs: three bodies of a KDA layer, each a rule
+    # and the kernels' own backward
+    assert counted["kda_scan"] == {"pallas": 3 * 2}
     assert counted["conv"] == {"pallas": 3 * 3 * 2}
-    assert not _kernel_calls(text, "kda_scan")
-    assert "/attn/kda_scan/" in text and "/attn/kda_gate/" in text
+    rule = _kernel_calls(text, "kda_scan")
+    assert rule and all("/attn/kda_scan/" in n for n in rule)
+    assert sum("jit(kda_fwd)" in n for n in rule) == 3 * 2
+    assert sum("jit(kda_bwd)" in n for n in rule) == 3
+    assert sum("rematted_computation" in n for n in rule) == 3
+    assert steplog.recomputed_kernels(text)["kda_scan"] == 3
+    assert "/attn/kda_gate/" in text
     conv = _kernel_calls(text, "kda_conv")
     assert conv and all("/attn/kda_conv/" in n for n in conv)
     assert sum("jit(conv_fwd)" in n for n in conv) == 3 * 3 * 2
