@@ -56,7 +56,7 @@ OURO_TENSORS = {
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
                               "olmo_hybrid", "nemotron_h", "lfm2_moe",
-                              "bailing_hybrid")
+                              "bailing_hybrid", "KeyeVL2")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
@@ -64,7 +64,14 @@ _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
 _CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
-                "nemotron_h", "lfm2_moe", "bailing_hybrid")
+                "nemotron_h", "lfm2_moe", "bailing_hybrid", "KeyeVL2")
+#: ``model_type: "KeyeVL2"``: the keys of ``sa_config`` (the indexer and the
+#: set a query keeps) -> the fields here
+_KEYE_SA = {"indexer_num_heads": "dsa_index_heads",
+            "indexer_head_dim": "dsa_index_head_dim",
+            "indexer_num_kv_heads": "dsa_index_kv_heads",
+            "topk": "dsa_topk", "q_chunk_size": "dsa_q_chunk",
+            "kv_chunk_size": "dsa_kv_chunk"}
 #: ``model_type: "bailing_hybrid"`` (the Ling-3.0 family): keys that turn on
 #: something the mapping does not build, with the value it takes
 _BAILING_PLAIN = {"use_nGPT": False, "value_norm": False,
@@ -533,6 +540,55 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             moe_n_group=int(get("n_group", 1) or 1),
             moe_topk_group=int(get("topk_group", 1) or 1),
         )
+    elif model_type == "KeyeVL2":
+        # the language model of Keye-VL-2.0: every layer grouped-query
+        # attention under a per-head RMSNorm on q and k, over the ``topk``
+        # keys a 16-head indexer picks for each query (``sa_config``; kind
+        # "dsa"), a rope over three position axes
+        # (``rope_scaling.mrope_section``), every FFN ``num_experts``
+        # softmax-routed experts, the top k renormalised. The vision tower
+        # has no key here and is not built. The config side only
+        sa = dict(get("sa_config") or {})
+        unknown = sorted(set(sa) - set(_KEYE_SA))
+        if unknown or not sa:
+            raise ValueError(
+                f"KeyeVL2 with sa_config keys {unknown or 'absent'}: the "
+                f"mapping knows {sorted(_KEYE_SA)} and guesses at no other")
+        rs = dict(get("rope_scaling") or {})
+        if (rs.get("rope_type", rs.get("type", "default")) != "default"
+                or set(rs) - {"mrope_section", "rope_type", "type"}
+                or "mrope_section" not in rs):
+            raise ValueError(
+                f"KeyeVL2 with rope_scaling={rs}: mapped is the default rope "
+                f"over the three axes of mrope_section")
+        if (get("mlp_only_layers") or get("decoder_sparse_step", 1) != 1
+                or not get("norm_topk_prob", True)
+                or get("attention_bias", False)
+                or get("use_sliding_window", False)
+                or get("sliding_window") is not None):
+            raise ValueError(
+                "KeyeVL2 with dense FFN layers (mlp_only_layers, "
+                "decoder_sparse_step), without norm_topk_prob, with "
+                "attention biases or with a sliding window is not mapped")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=get("num_hidden_layers"),
+            num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            intermediate_size=get("intermediate_size"),   # no layer uses it
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            rope_theta=float(get("rope_theta", 10000.0)),
+            mrope_section=tuple(rs["mrope_section"]),
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            qk_norm="head", attn_pattern=("dsa",),
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            moe_dispatch="grouped", moe_aux_loss_coef=0.001,
+            **{field: int(sa[key]) for key, field in _KEYE_SA.items()
+               if key in sa})
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "

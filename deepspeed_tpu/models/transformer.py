@@ -72,7 +72,9 @@ class TransformerConfig:
     # gated short convolution over ``conv_taps`` positions at the model's
     # width, models/short_conv.py) or "kda" (the delta rule with a decay a
     # key channel, models/kda.py) or, beside "kda" layers, "mla" (latent
-    # attention, models/mla.py): layer i is of
+    # attention, models/mla.py) or "dsa" (attention over the ``dsa_topk``
+    # keys a learned indexer picks for each query, models/dsa.py; the kind's
+    # fields are below, with ``mrope_section``): layer i is of
     # kind attn_pattern[i % len]. None = every layer the one attention kind
     # (windowed where sliding_window is set). HF qwen2's leading run of n
     # full layers is ("full",) * n + ("window",) * (L - n): a period of the
@@ -271,6 +273,28 @@ class TransformerConfig:
     # each head's output times ``sigmoid(x wg)[h]`` before ``wo`` (a KDA
     # layer always has the gate)
     mla_head_gate: bool = False
+    # a rope whose frequency pairs follow three position axes (time, height,
+    # width; HF ``rope_scaling.mrope_section``): consecutive sections of the
+    # head's rope_dim / 2 pairs, the first turning by the first axis's
+    # position and so on. The batch then may carry ``position_ids`` [3, B, T]
+    # (``loss_fn``); without them the three are the token's index and the
+    # rope the plain one. None = one axis
+    mrope_section: Optional[Tuple[int, ...]] = None
+    # a "dsa" layer (attention over the keys a learned indexer picks,
+    # models/dsa.py, ops/dsa.py): the indexer's ``dsa_index_heads`` heads of
+    # ``dsa_index_head_dim`` channels over ``dsa_index_kv_heads`` key heads
+    # (one), the ``dsa_topk`` keys a query keeps, the query and key tiles its
+    # scores and the attention are evaluated in (equal; they change no
+    # result), and what the indexer's own loss (the KL from the heads' mean
+    # attention over the set to the indexer's softmax over it, which trains
+    # the indexer alone) is multiplied by in the step's loss
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 64
+    dsa_index_kv_heads: int = 1
+    dsa_topk: int = 2048
+    dsa_q_chunk: int = 512
+    dsa_kv_chunk: int = 512
+    indexer_loss_coef: float = 1.0
 
     def __post_init__(self):
         is_llama = self.arch == "llama"
@@ -312,12 +336,12 @@ class TransformerConfig:
                     "'mla' layers: not with another attn_pattern")
             ffns = {"moe", "dense"} if self.one_branch else set()
             if not pat or set(pat) - {"window", "full", "ssm", "delta",
-                                      "conv", "kda", "mla"} \
+                                      "conv", "kda", "mla", "dsa"} \
                     - ffns or self.num_layers % len(pat):
                 raise ValueError(
                     f"attn_pattern={pat}: a period of 'window' / 'full' / "
-                    f"'ssm' / 'delta' / 'conv' / 'kda' (beside 'kda' also "
-                    f"'mla'; with one_branch also 'moe' / "
+                    f"'ssm' / 'delta' / 'conv' / 'kda' / 'dsa' (beside 'kda' "
+                    f"also 'mla'; with one_branch also 'moe' / "
                     f"'dense') whose length divides num_layers="
                     f"{self.num_layers}")
             if "window" in pat and self.sliding_window is None:
@@ -391,6 +415,71 @@ class TransformerConfig:
                     "values, not a recurrent state), one_branch, "
                     "norm_placement='post' or another kind of mixer in the "
                     "pattern")
+        if self.mrope_section is not None:
+            sec = tuple(int(n) for n in self.mrope_section)
+            object.__setattr__(self, "mrope_section", sec)
+            if (len(sec) != 3 or min(sec) < 0 or not self.use_rope
+                    or 2 * sum(sec) != self.rope_dim):
+                raise ValueError(
+                    f"mrope_section={sec}: three sections (time, height, "
+                    f"width) that add up to the rope's {self.rope_dim // 2} "
+                    f"frequency pairs")
+            if (self.has_mla or self.rope_scaling or self.rope_by_kind
+                    or self.one_branch
+                    or self.attention_impl in ("fpdt", "ring")):
+                raise NotImplementedError(
+                    "a rope over three position axes (mrope_section) is "
+                    "applied by plain and 'dsa' attention layers of "
+                    "two-branch blocks over whole sequences: not latent "
+                    "attention (its rope kernel takes one axis), "
+                    "rope_scaling, rope_by_kind, one_branch or "
+                    "attention_impl='fpdt' / 'ring' (which rotate chunk by "
+                    "chunk from one axis)")
+        if self.has_dsa:
+            J, c = self.dsa_index_heads, self.dsa_index_head_dim
+            if J < 1 or c < 2 or c % 2 or self.dsa_topk < 1:
+                raise ValueError(
+                    f"a 'dsa' layer needs dsa_index_heads={J} above 0, an "
+                    f"even dsa_index_head_dim={c} and dsa_topk="
+                    f"{self.dsa_topk} above 0")
+            if self.dsa_index_kv_heads != 1:
+                raise NotImplementedError(
+                    f"dsa_index_kv_heads={self.dsa_index_kv_heads}: the "
+                    f"indexer scores every query head against one key head")
+            if self.dsa_q_chunk != self.dsa_kv_chunk:
+                raise NotImplementedError(
+                    f"dsa_q_chunk={self.dsa_q_chunk} and dsa_kv_chunk="
+                    f"{self.dsa_kv_chunk}: the selected-key attention runs "
+                    f"in one tile for queries and keys")
+            if self.mrope_section is not None and any(
+                    s * c % self.head_dim for s in self.mrope_section):
+                raise ValueError(
+                    f"mrope_section={self.mrope_section} does not scale to "
+                    f"the indexer's {c // 2} frequency pairs")
+            if (self.looped or self.parallel_block or self.one_branch
+                    or self.heads_held is not None
+                    or self.sliding_window is not None
+                    or self.qkv_bias or self.proj_bias
+                    or self.rope_scaling or self.rope_by_kind
+                    or self.rope_pct != 1.0
+                    or self.attention_multiplier is not None
+                    or self.norm_placement != "pre"
+                    or self.attention_impl in ("fpdt", "ring")):
+                raise NotImplementedError(
+                    "a model with 'dsa' layers (attention over the keys a "
+                    "learned indexer picks) runs one pre-norm pass of "
+                    "two-branch layers over whole sequences, every head "
+                    "held, its set the only limit on the keys: not a looped "
+                    "stack (num_passes > 1, sandwich_norm or the exit gate: "
+                    "each pass would select again and add an indexer loss "
+                    "of its own), parallel_block, one_branch, heads_held "
+                    "(the indexer's target is the mean over all heads), "
+                    "sliding_window (a window beside the set), biases, "
+                    "rope_scaling, rope_by_kind, rope_pct, "
+                    "attention_multiplier, norm_placement='post' or "
+                    "attention_impl='fpdt' / 'ring' (their chunks carry "
+                    "keys and values, not the indexer's keys and each "
+                    "query's set)")
         if self.has_conv:
             if self.conv_taps < 1:
                 raise ValueError(f"a conv layer needs conv_taps="
@@ -590,6 +679,12 @@ class TransformerConfig:
         return "conv" in (self.attn_pattern or ())
 
     @property
+    def has_dsa(self) -> bool:
+        """Whether any layer's mixer is attention over the keys a learned
+        indexer picks."""
+        return "dsa" in (self.attn_pattern or ())
+
+    @property
     def heads_here(self) -> int:
         """The attention heads this model holds (``heads_held``, else all)."""
         return self.heads_held or self.num_heads
@@ -612,7 +707,8 @@ class TransformerConfig:
         attention, or whose layers are one branch each (then every layer's
         branch output, an FFN layer's too)."""
         return (self.has_ssm or self.has_mla or self.has_delta
-                or self.has_conv or self.has_kda or self.one_branch)
+                or self.has_conv or self.has_kda or self.has_dsa
+                or self.one_branch)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -624,7 +720,8 @@ class TransformerConfig:
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         """The kind of every layer: its mixer's, "window", "full", "ssm",
-        "delta", "conv", "kda" or "mla"; in a model whose FFNs differ by layer, then
+        "delta", "conv", "kda", "mla" or "dsa"; in a model whose FFNs differ by
+        layer, then
         ":" and its FFN's, "dense" or "moe". A layer of one branch (``one_branch``)
         names what it lacks "none": "ssm:none", "none:moe"."""
         pat = self.attn_pattern or (
@@ -647,7 +744,7 @@ class TransformerConfig:
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
                 or self.has_mla or self.has_delta or self.has_conv
-                or self.has_kda or self.one_branch)
+                or self.has_kda or self.has_dsa or self.one_branch)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -726,12 +823,16 @@ class TransformerConfig:
             from deepspeed_tpu.models import kda
 
             mixers["kda"] = kda.num_params(self)
+        if self.has_dsa:
+            from deepspeed_tpu.models import dsa
+
+            mixers["indexer"] = dsa.num_params(self)
         layers = 0
         for kind in self.layer_kinds:
             mixer, _, ffn = kind.partition(":")
             layers += norms
             if mixer != "none":
-                layers += mixers[_MIXER_GROUP[mixer]]
+                layers += sum(mixers[g] for g in _groups_of(mixer))
             if ffn != "none":
                 layers += dense if ffn == "dense" else routed
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
@@ -880,12 +981,17 @@ def rope_attention_factor(scaling: Optional[Dict[str, Any]]) -> float:
 
 
 def apply_rope(x: jax.Array, freqs: jax.Array, positions: Optional[jax.Array] = None,
-               scale: float = 1.0) -> jax.Array:
+               scale: float = 1.0,
+               sections: Optional[Tuple[int, ...]] = None) -> jax.Array:
     """x: [B, T, H, d]; freqs: [max_seq, rd//2]; positions: [B, T] (default arange).
 
     When ``2*freqs.shape[-1] < d`` only the leading rotary dims rotate and the
     tail passes through (gpt-neox/phi partial rotary, ``rotary_pct``).
-    ``scale`` multiplies cos and sin (yarn's attention factor)."""
+    ``scale`` multiplies cos and sin (yarn's attention factor). With
+    ``positions`` [3, B, T] (time, height, width) and ``sections`` that add up
+    to the rd/2 frequency pairs, pair i turns by the position of the axis
+    whose section holds it (consecutive sections; three equal axes are the
+    plain rope)."""
     B, T = x.shape[0], x.shape[1]
     rd = 2 * freqs.shape[-1]
     tail = None
@@ -893,6 +999,17 @@ def apply_rope(x: jax.Array, freqs: jax.Array, positions: Optional[jax.Array] = 
         x, tail = x[..., :rd], x[..., rd:]
     if positions is None:
         f = freqs[:T][None, :, None, :]  # [1, T, 1, rd/2]
+    elif positions.ndim == 3:
+        if sections is None or sum(sections) != freqs.shape[-1]:
+            raise ValueError(
+                f"positions {positions.shape} over {positions.shape[0]} axes "
+                f"need sections that add up to the rope's {freqs.shape[-1]} "
+                f"frequency pairs (mrope_section), not {sections}")
+        edges = [sum(sections[:a]) for a in range(len(sections) + 1)]
+        f = jnp.concatenate(
+            [freqs[positions[a]][..., lo:hi]
+             for a, (lo, hi) in enumerate(zip(edges, edges[1:]))],
+            axis=-1)[:, :, None, :]  # [B, T, 1, rd/2]
     else:
         f = freqs[positions][:, :, None, :]  # [B, T, 1, rd/2]
     cos, sin = jnp.cos(f), jnp.sin(f)
@@ -1063,7 +1180,10 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     q, k, v = qkv_proj(x, w, cfg)
     q = constrain(q, P(("dp", "fsdp"), "sp", "tp", None))
     k = constrain(k, P(("dp", "fsdp"), "sp", "tp", None))
-    if cfg.use_rope:
+    if cfg.use_rope and cfg.mrope_section is not None:
+        q = apply_rope(q, freqs, positions, rope_scale, cfg.mrope_section)
+        k = apply_rope(k, freqs, positions, rope_scale, cfg.mrope_section)
+    elif cfg.use_rope:
         q = apply_rope(q, freqs, positions, rope_scale)
         k = apply_rope(k, freqs, positions, rope_scale)
     if cfg.attention_multiplier is not None:
@@ -1190,7 +1310,13 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # (models/short_conv.py)
                "sconv_proj", "sconv_conv",
                # a KDA layer's inside attn (models/kda.py)
-               "kda_proj", "kda_conv", "kda_scan", "kda_gate")
+               "kda_proj", "kda_conv", "kda_scan", "kda_gate",
+               # a layer that attends to the keys its indexer picks, inside
+               # attn/attn_dsa (models/dsa.py): the indexer's projections and
+               # scores, the threshold and the set, the attention over the
+               # set, the indexer's loss with its gradient
+               "attn_dsa", "dsa_indexer", "dsa_select", "dsa_attend",
+               "dsa_loss")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind. Layers of one branch
 #: each (``one_branch``) are half a block: a period of up to twice as many,
@@ -1210,10 +1336,13 @@ _KEEP_FP32 = ("A_log", "dt_bias", "D", "router_bias")
 #: a row for every layer. A layer of one branch names what it lacks "none"
 #: ("ssm:none", "none:moe") and keeps leaves in the one group it has
 _MIXER_GROUP = {"window": "attn", "full": "attn", "ssm": "ssm", "mla": "mla",
-                "delta": "delta", "conv": "conv", "kda": "kda"}
+                "delta": "delta", "conv": "conv", "kda": "kda", "dsa": "attn"}
 _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
+#: what a kind keeps beside its mixer's group: a "dsa" layer the attention
+#: leaves every attention kind has and, in a group of its own, its indexer's
+_MIXER_EXTRA = {"dsa": "indexer"}
 _KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
-    _FFN_GROUP.values())
+    _FFN_GROUP.values()) | frozenset(_MIXER_EXTRA.values())
 #: the scope an FFN kind's own group is cast and run under
 _FFN_SCOPE = {"mlp_dense": "mlp", "mlp_moe": "moe"}
 
@@ -1222,8 +1351,9 @@ def _groups_of(kind: str) -> Tuple[str, ...]:
     """The groups that hold the own leaves of a layer of ``kind``."""
     mixer, _, ffn = kind.partition(":")
     return tuple(group[k] for k, group in ((mixer, _MIXER_GROUP),
+                                           (mixer, _MIXER_EXTRA),
                                            (ffn, _FFN_GROUP))
-                 if k and k != "none")
+                 if k and k != "none" and k in group)
 
 
 def _times(x: jax.Array, factor: float) -> jax.Array:
@@ -1260,7 +1390,7 @@ def _cast_layers(w: Params, dt, ffn: Optional[str],
     for k, v in w.items():
         with (contextlib.nullcontext() if ffn is None else jax.named_scope(
                 "attn" if k in ("ln1", "attn", "ssm", "mla", "delta", "conv",
-                                "kda", "ln1_post")
+                                "kda", "indexer", "ln1_post")
                 else _FFN_SCOPE.get(k, ffn))):
             if any(n in _KEEP_FP32 for n in v):
                 out[k] = {n: p if n in _KEEP_FP32
@@ -1303,7 +1433,11 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
     (models/short_conv.py:conv_block) under ``attn/sconv_*``, one of kind
     "kda" with ``w["kda"]`` (models/kda.py:kda_block) under ``attn/kda_*``,
     one of kind "mla" with ``w["mla"]``
-    (models/mla.py:mla_block); a kind that names its FFN
+    (models/mla.py:mla_block), one of kind "dsa" with the attention leaves
+    ``w["attn"]`` and its indexer's ``w["indexer"]``
+    (models/dsa.py:dsa_block, under ``attn/attn_dsa/dsa_*``; its aux then
+    also holds the layer's ``indexer_loss`` and ``dsa_probe_sets``); a kind
+    that names its FFN
     ("mla:dense") runs ``moe_fn`` only where that is "moe". With ``mix_ms``
     the aux value is a dict that also holds the mean square of the mixer's
     output (``mix_out_ms``), taken before a norm on the branch's output,
@@ -1349,6 +1483,11 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             from deepspeed_tpu.models.mla import mla_block
 
             attn_out = mla_block(hn1, wc["mla"], cfg, freqs, attn_fn)
+        elif kind == "dsa":
+            from deepspeed_tpu.models.dsa import dsa_block
+
+            attn_out, *of_set = dsa_block(
+                hn1, wc["attn"], wc["indexer"], cfg, freqs, positions)
         else:
             attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                        positions=positions)
@@ -1382,6 +1521,9 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             if not isinstance(aux, dict):
                 aux = {} if ffn_kind == "dense" else {"lb": aux}
             aux = {**aux, "mix_out_ms": ms}
+        if kind == "dsa":
+            aux = {**(aux if isinstance(aux, dict) else {"lb": aux}),
+                   "indexer_loss": of_set[0], "dsa_probe_sets": of_set[1]}
         x = x + mlp_out + attn_out if cfg.parallel_block else x + mlp_out
         return constrain(x, P(("dp", "fsdp"), "sp", None)), aux
 
@@ -1689,6 +1831,19 @@ class TransformerLM:
                 f"KDA layers (attn_pattern={cfg.attn_pattern}), whose "
                 f"recurrent and convolution state it would have to keep "
                 f"beside the latent cache; only the train step runs them")
+        if cfg.has_dsa:
+            raise NotImplementedError(
+                f"{what} is written for attention over every key a query may "
+                f"see: this model has 'dsa' layers (attn_pattern="
+                f"{cfg.attn_pattern}), whose indexer's keys it would have to "
+                f"cache beside the key-value cache and whose top "
+                f"{cfg.dsa_topk} keys a query it would have to select at "
+                f"every step; only the train step runs them")
+        if cfg.mrope_section is not None:
+            raise NotImplementedError(
+                f"{what} rotates by one position a token: this model's rope "
+                f"follows three position axes (mrope_section="
+                f"{cfg.mrope_section}); only the train step applies them")
         if cfg.has_conv:
             raise NotImplementedError(
                 f"{what} is written for attention layers: this model has "
@@ -1796,6 +1951,19 @@ class TransformerLM:
         if cfg.heads_held is not None:
             # (count, all) of the heads a mixer holds, where a share of them
             facts["heads_held"] = (cfg.heads_held, cfg.num_heads)
+        if cfg.mrope_section is not None:
+            # the position axes the rope's frequency pairs follow
+            facts["mrope_axes"] = len(cfg.mrope_section)
+        if cfg.has_dsa:
+            # the keys a query of a "dsa" layer keeps and, once the rows'
+            # length is known, the (query, key) pairs kept over the causal
+            # pairs of a step
+            facts["dsa_topk"] = cfg.dsa_topk
+            if batch_shape is not None:
+                from deepspeed_tpu.ops.dsa import selected_share
+
+                facts["dsa_selected_share"] = selected_share(
+                    int(batch_shape[1]), cfg.dsa_topk)
         if cfg.has_mla:
             # (key width, value width) of a head where they differ (latent
             # attention: the flash kernels take both)
@@ -1890,6 +2058,14 @@ class TransformerLM:
         ``short_conv.param_specs``) and a held share of the heads
         (``heads_held``, already one chip's part of them) do not follow."""
         cfg = self.cfg
+        if cfg.has_dsa and (axis_sizes.get("tp", 1) > 1
+                            or axis_sizes.get("sp", 1) > 1):
+            raise NotImplementedError(
+                f"a tp or sp axis (tp={axis_sizes.get('tp', 1)}, sp="
+                f"{axis_sizes.get('sp', 1)}) with 'dsa' layers: the "
+                f"indexer's target is the mean over all of a query's heads "
+                f"and its set is chosen among all of a row's keys, so the "
+                f"layer keeps heads and rows whole on a chip")
         if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta or cfg.has_conv
                                             or cfg.has_kda
                                             or cfg.heads_held is not None):
@@ -2016,6 +2192,11 @@ class TransformerLM:
 
             layers["kda"] = kda.init(jax.random.fold_in(rng, 18), cfg,
                                      _in_group(kinds, "kda"), pd)
+        if cfg.has_dsa:
+            from deepspeed_tpu.models import dsa
+
+            layers["indexer"] = dsa.init(jax.random.fold_in(rng, 19), cfg,
+                                         _in_group(kinds, "indexer"), pd)
         if not La:
             del layers["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):
@@ -2120,7 +2301,8 @@ class TransformerLM:
     def _hidden_passes(self, params: Params, input_ids: jax.Array,
                        positions: Optional[jax.Array] = None,
                        ltd_seed: Optional[jax.Array] = None,
-                       pld_theta: Optional[jax.Array] = None):
+                       pld_theta: Optional[jax.Array] = None,
+                       rope_positions: Optional[jax.Array] = None):
         """``([h_1 .. h_R], aux)``: the final-norm hidden states after each
         of the ``cfg.num_passes`` passes of the layer stack, and the MoE aux
         loss summed over layers and passes (with a held share of the experts
@@ -2157,7 +2339,7 @@ class TransformerLM:
         for _ in range(cfg.num_passes):
             with jax.named_scope("layers"):
                 x, a = self._run_layers(layers, x, input_ids, attn_fn,
-                                        ltd_seed, pld_theta)
+                                        ltd_seed, pld_theta, rope_positions)
             with jax.named_scope("final_norm"):
                 x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
                 x = constrain(x, P(("dp", "fsdp"), "sp", None))
@@ -2166,9 +2348,12 @@ class TransformerLM:
         return hs, aux
 
     def _run_layers(self, layers: Params, x: jax.Array, input_ids: jax.Array,
-                    attn_fn: Callable, ltd_seed, pld_theta):
+                    attn_fn: Callable, ltd_seed, pld_theta,
+                    rope_positions: Optional[jax.Array] = None):
         """One pass of the (already cast) layer stack on ``x``: ``(x, the
-        layers' MoE aux values as :func:`_layer_aux` hands them on)``."""
+        layers' MoE aux values as :func:`_layer_aux` hands them on)``.
+        ``rope_positions`` [3, B, T]: the batch's positions over the rope's
+        three axes (``cfg.mrope_section``)."""
         cfg = self.cfg
         patterned = cfg.patterned
         T = input_ids.shape[1]
@@ -2189,7 +2374,8 @@ class TransformerLM:
                     "layers of more than one attention kind (attn_pattern) "
                     "cannot combine with random-LTD or progressive layer "
                     "drop")
-            return self._run_periods(self._layer_plan(), layers, x, attn_fn)
+            return self._run_periods(self._layer_plan(), layers, x, attn_fn,
+                                     rope_positions)
         cfg, freqs = self._kinds[cfg.layer_kinds[0]]
         if ltd or pld_theta is not None:
             # shared routing key for LTD/PLD: step seed (engine-provided,
@@ -2249,7 +2435,8 @@ class TransformerLM:
         else:
             def body(carry, xs):
                 y, aux = transformer_block(carry, xs, cfg, freqs, attn_fn,
-                                           self.moe_fn)
+                                           self.moe_fn,
+                                           positions=rope_positions)
                 return y, aux
 
             xs = layers
@@ -2268,7 +2455,8 @@ class TransformerLM:
         return x, _layer_aux(auxes)
 
     def _run_periods(self, plan, layers: Params, x: jax.Array,
-                     attn_fn: Callable):
+                     attn_fn: Callable,
+                     rope_positions: Optional[jax.Array] = None):
         """The layer loop of a stack whose layers are of more than one
         attention kind: each entry of ``plan`` (:meth:`_layer_plan`) is a scan
         over its periods, the body the period's blocks, each under its kind's
@@ -2284,6 +2472,9 @@ class TransformerLM:
                           partial(self._kind_block, kind, attn_fn),
                           cfg.remat_policy)
                       for kind in period]
+            if rope_positions is not None:
+                blocks = [partial(blk, positions=rope_positions)
+                          for blk in blocks]
             p = len(period)
             seg = _segment(layers, cfg.layer_kinds, lo, hi, period)
 
@@ -2318,13 +2509,13 @@ class TransformerLM:
         return self._blocks[key]
 
     def _kind_block(self, kind: str, attn_fn: Callable, x: jax.Array,
-                    w: Params):
+                    w: Params, positions: Optional[jax.Array] = None):
         ck, freqs = self._kinds[kind]
         if ck.one_branch:
             return branch_block(x, w, ck, freqs, attn_fn, self.moe_fn, kind)
         return transformer_block(
-            x, w, ck, freqs, attn_fn, self.moe_fn, kind=kind,
-            mix_ms=self.cfg.reports_mixer_outputs)
+            x, w, ck, freqs, attn_fn, self.moe_fn, positions=positions,
+            kind=kind, mix_ms=self.cfg.reports_mixer_outputs)
 
     def _tiled_loss(self, params: Params, batch: Dict[str, jax.Array],
                     hidden: jax.Array) -> jax.Array:
@@ -2370,7 +2561,8 @@ class TransformerLM:
         hs, aux = self._hidden_passes(
             params, batch["input_ids"],
             ltd_seed=None if seed is None else seed[0],
-            pld_theta=None if pld is None else pld[0])
+            pld_theta=None if pld is None else pld[0],
+            rope_positions=self._rope_positions(batch))
         if cfg.exit_loss_beta is not None:
             loss, parts = self._expected_exit_loss(params, batch, hs)
         else:
@@ -2385,6 +2577,16 @@ class TransformerLM:
         if cfg.reports_mixer_outputs:
             # by layer, the mean square of the mixer's output
             parts = {**parts, "mix_out_ms": aux["mix_out_ms"]}
+        if cfg.has_dsa:
+            with jax.named_scope("loss"):
+                # the layers' indexer losses, summed: they train the
+                # indexers alone (models/dsa.py)
+                parts = {**parts,
+                         "indexer_loss": jnp.sum(aux["indexer_loss"]),
+                         # by layer, the sets of a few queries of each row
+                         # (ops/dsa.py:probe_positions), packed
+                         "dsa_probe_sets": aux["dsa_probe_sets"]}
+                loss = loss + cfg.indexer_loss_coef * parts["indexer_loss"]
         if cfg.num_experts > 1:
             with jax.named_scope("loss"):
                 if isinstance(aux, dict):
@@ -2401,6 +2603,21 @@ class TransformerLM:
                     aux = aux["lb"]
                 loss = loss + cfg.moe_aux_loss_coef * aux
         return loss, parts
+
+    def _rope_positions(self, batch: Dict[str, jax.Array]
+                        ) -> Optional[jax.Array]:
+        """The batch's ``position_ids`` [3, B, T] where the model's rope
+        follows three position axes (``cfg.mrope_section``) and the batch
+        has them; None elsewhere (the token's index on every axis)."""
+        pos = batch.get("position_ids")
+        if self.cfg.mrope_section is None or pos is None:
+            return None
+        ids = batch["input_ids"]
+        if pos.shape != (3,) + ids.shape:
+            raise ValueError(
+                f"position_ids {pos.shape}: a rope over three position axes "
+                f"takes [3, B, T] beside input_ids {ids.shape}")
+        return pos
 
     def _expected_exit_loss(self, params: Params,
                             batch: Dict[str, jax.Array], hs):
@@ -2900,6 +3117,10 @@ class TransformerLM:
             from deepspeed_tpu.models import kda
 
             layer_specs["kda"] = kda.param_specs()
+        if cfg.has_dsa:
+            from deepspeed_tpu.models import dsa
+
+            layer_specs["indexer"] = dsa.param_specs()
         if not _in_group(cfg.layer_kinds, "attn"):
             del layer_specs["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):

@@ -37,6 +37,11 @@ ATTN_LSE_CHECKPOINT_NAME = "flash_attn_lse"
 #: ``custom_vjp``, to the rule's output and to the chunks' incoming states:
 #: the products a policy that keeps dots keeps of the einsum form
 RULE_CHECKPOINT_NAMES = ("delta_rule_out", "delta_rule_states")
+#: the tags ops/dsa.py attaches, in the forward of its ``custom_vjp``, to what
+#: its backward reads of the forward beside its inputs (``RESIDUAL_NAMES``
+#: there): the output, the heads' log-sum-exps, each query's set packed to
+#: bits, and the indexer's gradients to a unit cotangent
+DSA_CHECKPOINT_NAMES = ("dsa_out", "dsa_lse", "dsa_set", "dsa_index_grads")
 
 
 def resolve_policy(policy: str):
@@ -48,7 +53,10 @@ def resolve_policy(policy: str):
     if policy in (None, "none", "full"):
         return None
     cp = jax.checkpoint_policies
-    attn = (ATTN_CHECKPOINT_NAME, ATTN_LSE_CHECKPOINT_NAME)
+    # (attention over a selected set names its own residuals: kept with the
+    # flash kernel's, so that the region holds no second selection either)
+    attn = (ATTN_CHECKPOINT_NAME, ATTN_LSE_CHECKPOINT_NAME,
+            *DSA_CHECKPOINT_NAMES)
     if policy == "attn_saveable":
         # keep what the flash kernel named, its output and its log-sum-exp:
         # the two values its backward reads, so the recomputed region holds

@@ -738,7 +738,9 @@ class DeepSpeedTpuEngine:
         out = {}
         for k, v in batch.items():
             arr = np.asarray(v)
-            if arr.ndim >= 2 and arr.shape[1] > diff and k in (
+            if k == "position_ids" and arr.ndim == 3:
+                arr = arr[..., :diff]       # [axes, B, T]
+            elif arr.ndim >= 2 and arr.shape[1] > diff and k in (
                     "input_ids", "labels", "attention_mask", "position_ids"):
                 arr = arr[:, :diff]
             out[k] = arr
@@ -794,13 +796,24 @@ class DeepSpeedTpuEngine:
         """Host batch → device arrays laid out over (dp, fsdp) × sp."""
         bspec = shd.batch_spec(self.topology)
 
-        def put(x):
+        def put(x, lead=()):
             x = np.asarray(x)
-            spec = P(*list(bspec)[:max(x.ndim, 0)]) if x.ndim else P()
+            dims = x.ndim - len(lead)
+            spec = P(*lead, *list(bspec)[:max(dims, 0)]) if x.ndim else P()
             return jax.device_put(x, NamedSharding(self.mesh, spec))
 
         with self._ebus.span("train", "put_batch"):
-            batch = jax.tree_util.tree_map(put, batch)
+            axes = (batch.get("position_ids")
+                    if isinstance(batch, dict) else None)
+            if axes is not None and np.ndim(axes) == 3:
+                # positions over the rope's axes [axes, B, T]: the batch
+                # is the second dimension
+                batch = {**jax.tree_util.tree_map(
+                    put, {k: v for k, v in batch.items()
+                          if k != "position_ids"}),
+                    "position_ids": put(axes, (None,))}
+            else:
+                batch = jax.tree_util.tree_map(put, batch)
         self._t_put = time.perf_counter()
         return batch
 

@@ -104,6 +104,14 @@ def ga_grads(model, params, batch, scale, ga: int):
         zeros = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
         if ga > 1:
+            axes = batch.get("position_ids") if isinstance(batch, dict) \
+                else None
+            if axes is not None and axes.ndim == 3:
+                raise NotImplementedError(
+                    "gradient accumulation inside the step (ga > 1) cuts "
+                    "every batch leaf by its first dimension: position_ids "
+                    f"{axes.shape} over the rope's axes have the batch "
+                    "second; run one micro-batch a step")
             mbs = jax.tree_util.tree_map(
                 lambda x: x.reshape((ga, x.shape[0] // ga) + x.shape[1:]),
                 batch)

@@ -904,11 +904,13 @@ def test_kernel_path_rules_match_what_compiled():
 
 
 def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
-                       parameters: int, seq: int = 4096, rows: int = 1):
+                       parameters: int, seq: int = 4096, rows: int = 1,
+                       position_axes: int = 0):
     """A benchmark cell's whole step (``benchmarks/configs/<config>.json``
     at ``rows`` x ``seq`` tokens, the file's recomputation policy): gradient and
     AdamW over fp32 master weights, compiled for the chip, every picker
-    answering as on a TPU."""
+    answering as on a TPU. With ``position_axes`` the batch also holds
+    ``position_ids`` [axes, rows, seq]."""
     import importlib
     import json
     import os
@@ -950,10 +952,14 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
     params = jax.eval_shape(model.init, jax.random.key(0))
     assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
         == parameters
+    batch = {"input_ids": jax.ShapeDtypeStruct((rows, seq), jnp.int32,
+                                               sharding=one_chip)}
+    if position_axes:
+        batch["position_ids"] = jax.ShapeDtypeStruct(
+            (position_axes, rows, seq), jnp.int32, sharding=one_chip)
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(params), described(jax.eval_shape(tx.init, params)),
-        {"input_ids": jax.ShapeDtypeStruct((rows, seq), jnp.int32,
-                                           sharding=one_chip)}).compile()
+        batch).compile()
     mem = compiled.memory_analysis()
     # fp32 weights, Adam m and v: 12 B a parameter as arguments
     assert mem.argument_size_in_bytes == pytest.approx(12 * parameters,
@@ -1202,3 +1208,52 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     assert "/moe/moe_router/" in text and "/mlp/" in text
     assert _index_ops_under(text, "moe_router") == []
     assert _index_ops_under(text, "moe_dispatch") == []
+
+
+def test_the_keye_vl2_cells_step_program_compiles_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    keye_vl2_30b_train_d5e16v8.json``: five layers of grouped-query attention
+    over the 2,048 keys a 16-head indexer picks for each query, 16 of 128
+    experts, at the published widths, one row of 16,384 positions with its
+    positions over three axes, under the file's policy). It fits beside what
+    a chip reserves; the selection, the attention over the set and the
+    indexer's loss are the ``jax.numpy`` form (``ops/dsa.py``), traced once
+    for the scanned layer body and once for what the policy leaves to the
+    backward's region; no array of the program holds ``[T, T]`` or is as
+    large as two heads' ``[T, T]`` scores, let alone 32; the sets are found
+    with no sort; and the five routed layers run the grouped products and
+    the row kernels under ``moe``."""
+    T = 16384
+    snap = lowerings.snapshot()
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "keye_vl2_30b_train_d5e16v8",
+        "modelcfg_keye_vl2", 562_290_560, seq=T, position_axes=3)
+    # 7.41 GB of temporaries as compiled here under the file's
+    # "attn_saveable" (5.83 under "full"), beside 6.75 GB of arguments
+    assert mem.temp_size_in_bytes < 7.6e9
+    counted = lowerings.since(snap)
+    # the scanned layer body and the custom_vjp's own forward rule
+    assert counted["dsa"] == {"jnp": 2}
+    largest = max(
+        int(np_prod) for np_prod in (
+            eval("*".join(dims.split(",")))  # noqa: S307 (digits and commas)
+            for dims in re.findall(r"[a-z]+\d*\[([\d,]+)\]", text)))
+    # the five layers' kept outputs [5, 1, T, 32, 128] are the largest, 336M
+    # elements, then the logits [T, 18992], 311M; two heads' [T, T] would
+    # be 537M
+    assert largest == 5 * T * 32 * 128 < 2 * T * T
+    assert f"{T},{T}" not in text
+    under = lambda scope: [n for n in re.findall(  # noqa: E731
+        r'op_name="([^"]*)"', text) if f"/{scope}/" in n]
+    assert not any(n.rsplit("/", 1)[-1] in ("sort", "top_k", "approx_top_k")
+                   for n in under("attn_dsa"))
+    for scope in ("dsa_indexer", "dsa_select", "dsa_attend", "dsa_loss"):
+        assert under(scope), scope
+    assert any("while" in n for n in under("dsa_select"))
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)

@@ -113,6 +113,19 @@ CASES = {
                  "moe_topk_group": 2, "moe_bias_rate": 1e-3,
                  "moe_bias_init": 0.1, "tie_embeddings": False,
                  "remat_policy": "full"}, 1),
+    # attention over the keys a learned indexer picks (two query tiles, the
+    # second past the topk-th key) under recomputation, head norms on q and
+    # k, a rope over three position axes, a held share of grouped experts:
+    # the indexer's projections and scores, the threshold and the set, the
+    # attention over the set and the indexer's loss with its gradient each
+    # under their scope inside attn/attn_dsa, forward and backward
+    "dsa_moe": ({"attn_pattern": ("dsa",), "num_kv_heads": 2,
+                 "qk_norm": "head", "mrope_section": (2, 2, 4),
+                 "dsa_index_heads": 4, "dsa_index_head_dim": 8,
+                 "dsa_topk": 8, "dsa_q_chunk": 16, "dsa_kv_chunk": 16,
+                 "num_experts": 8, "top_k": 2, "moe_dispatch": "grouped",
+                 "moe_intermediate_size": 32, "moe_experts_held": 4,
+                 "tie_embeddings": False, "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -139,6 +152,10 @@ NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
               "moe_shared": "moe"}
 NESTED_KDA = {**NESTED_MLA, "kda_proj": "attn", "kda_conv": "attn",
               "kda_scan": "attn", "kda_gate": "attn"}
+NESTED_DSA = {"attn_dsa": "attn", "dsa_indexer": "attn_dsa",
+              "dsa_select": "attn_dsa", "dsa_attend": "attn_dsa",
+              "dsa_loss": "attn_dsa", "moe_router": "moe",
+              "moe_dispatch": "moe", "moe_experts": "moe"}
 
 
 def _op_names(overrides, ga):
@@ -171,17 +188,23 @@ def test_every_operation_carries_a_step_scope(case):
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
-                            "conv_moe", "kda_moe") else "mlp"
+                            "conv_moe", "kda_moe", "dsa_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
     if case in ("mla_moe", "conv_moe", "kda_moe"):
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
               "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
-              "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA}.get(case)
+              "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA,
+              "dsa_moe": NESTED_DSA}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
             ops = [n for n in names if inner in re.split(r"[/()]", n)]
+            if case == "dsa_moe":
+                # (a constant the compiler lifts out of the recomputed
+                # region's loops keeps its innermost scope alone)
+                ops = [n for n in ops if n.rsplit("/", 1)[-1] not in (
+                    "iota", "broadcast_in_dim")]
             assert ops and all(outer in re.split(r"[/()]", n) for n in ops)
     if CASES[case][0].get("loss_tiling", 0) <= 1:
         want.add("lm_head")
@@ -205,6 +228,17 @@ def test_every_operation_carries_a_step_scope(case):
         # the groups are chosen inside the router: two sorts more than the
         # plain top k has
         assert any("moe_router" in n and "top_k" in n for n in names)
+    if case == "dsa_moe":
+        # every exponential, logarithm and branch of the mixer lies under
+        # one of its four scopes: what lies under attn_dsa and outside them
+        # is the main projections, the head norms, the rope and the loops
+        # over rows and query tiles themselves
+        own = {n.rsplit("/", 1)[-1] for n in names
+               if "attn_dsa" in re.split(r"[/()]", n)
+               and not set(re.split(r"[/()]", n)) & {
+                   "dsa_indexer", "dsa_select", "dsa_attend", "dsa_loss"}}
+        assert not own & {"exp", "sort", "top_k", "cond", "log"}, own
+        assert steplog.programs()[-1].dsa_lowerings == {"jnp": 2}
     if case.startswith("looped"):
         want.add("exit_gate")
         # the gate's operations nest inside the loss: loss/exit_gate/...
