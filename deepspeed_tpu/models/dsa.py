@@ -20,6 +20,16 @@ keys a query, the attention over it and the loss that trains the indexer are
 layer's indexer loss, ``sum_rows kl / (B T)``. The main branch gets no gradient
 from that loss and the indexer none from the model's.
 
+What is a kernel and what is not: the projections, the LayerNorm and the
+ropes here are XLA's; of ``dsa_attention``, the indexer's weighted head-score
+sum (under ``dsa_indexer``) and the gradient of its loss by ``qi``, ``ki`` and
+``wi`` (under ``dsa_loss``) are two Mosaic kernels on a TPU in bf16 where the
+shapes fit (tiles a multiple of 128, heads of 64 or 128 that fill lane tiles:
+``ops/dsa.py:index_lowering``), and ``jax.numpy`` einsums everywhere else; the
+selection (``dsa_select``), the attention over the set (``dsa_attend``) and
+the loss's own lines are ``jax.numpy`` on every backend. Nothing here chooses:
+the op does, by platform, dtype and shapes, and counts what it took.
+
 The indexer's leaves: ``wq`` [D, J c], ``wk`` [D, c], ``ww`` [D, J],
 ``k_norm`` and ``k_bias`` [c] (the LayerNorm on its key).
 """

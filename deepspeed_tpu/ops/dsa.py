@@ -1,8 +1,11 @@
 """Attention over the keys a learned indexer picks for each query (DeepSeek
 sparse attention: the lightning indexer of DeepSeek-V3.2-Exp), the exact
 selection of each query's ``topk`` keys, and the loss that trains the indexer
-beside the model: one ``jax.custom_vjp`` in plain ``jax.numpy``, in query
-tiles, with no ``[heads, T, T]`` array forward or backward.
+beside the model: one ``jax.custom_vjp``, in query tiles, with no
+``[heads, T, T]`` array forward or backward. The selection and the attention
+over the set are plain ``jax.numpy``; the indexer's weighted head-score sum
+and the gradient of its loss are two Mosaic kernels where the platform and
+the shapes allow, and ``jax.numpy`` lines elsewhere.
 
 For one row, queries ``q`` [T, H, d] and keys and values ``k``, ``v``
 [T, K, d] (grouped: key-value head g serves query heads g H/K ..), the
@@ -28,7 +31,7 @@ heads in float32.
 tiles: a run's tiles score the keys up to the run's last position (a static
 length, so that the first tiles do not pay for the whole row), the attention
 loops over key tiles up to the query tile's own (a dynamic trip count). For a
-tile: the indexer's scores [tile, J, S] and their weighted sum [tile, S]; the
+tile: the indexer's weighted sum of head scores ``I`` [tile, S]; the
 threshold, the k-th largest of each row, by 32 counts of ``score >= candidate``
 over the bits of the float (no sort: the count is exact, and so is the set),
 and only where a row has more scores at the threshold than places left, a
@@ -39,6 +42,40 @@ I = softmax_S(I) - p``), which the forward keeps as residuals: the backward
 multiplies them by the loss's cotangent. The backward of the attention reads
 the set from a bit-packed mask [T, S / 8] the forward left.
 
+**The indexer's two lowerings** (:func:`index_lowering` picks by what the
+call can see: backend, dtype, shapes; ``lowerings`` site ``dsa`` counts an
+op by the one it took):
+
+* ``"jnp"``: :func:`index_scores` and :func:`index_grads`, einsums. The
+  heads' relu'd scores ``r`` [tile, J, S] are an array in the compute dtype:
+  written, read back for the weighted sum, kept across the attention for
+  the loss, whose ``d_r`` is a second array of that size read by two
+  products. What a CPU runs, what float32 and shapes the kernels do not take
+  run, and the unit tests' oracle.
+* ``"pallas"``: :func:`dsa_index_fwd` and :func:`dsa_index_bwd`, a grid over
+  the key tiles of one query tile, the tile's index a scalar-prefetch
+  operand: a key tile after the query tile's own is not computed (``select``
+  masks it; the forward writes zeros there) and, its index map clamped to
+  the last live tile, not fetched. A head's scores [tile, tile] exist only
+  in VMEM, forward and backward: the product accumulated in float32 and
+  rounded to the compute dtype as the einsum's output is, ``relu``, times
+  ``wi[:, j]`` and summed over the heads in float32 (``I``, 4 bytes a pair,
+  the one array that leaves); the gradient kernel rebuilds them the same way
+  from ``qi`` and ``ki``, forms ``d_r_j = d_scores wi_j [x_j > 0]`` in the
+  compute dtype and accumulates ``dqi`` over the key tiles in VMEM, writes
+  each key tile's ``dki`` once and ``dwi = sum_s d_scores relu(x_j)`` from
+  lane-partial float32 sums. ``qi`` goes in as [T, J c], the heads side by
+  side in lanes; with heads of 64 a lane tile holds two, and so that no head
+  is cut out of a lane tile the keys go in as two copies [S, 128], each
+  zero in the other head's lanes (:func:`lane_keys`): a product with a copy
+  is one head's scores at the MXU's full depth, ``d_r_j`` times a copy lands
+  in the head's own lanes of ``dqi``, and ``d_r_j^T`` times the lane tile of
+  ``qi`` holds ``dki``'s part in the head's lanes (the other half is
+  dropped: a 64-wide product costs a 128-wide one on this MXU either way).
+  The same arithmetic as the lines but the order of the float32 sums over
+  the heads and the key tiles, and ``dwi``'s product, float32 here where the
+  einsum rounds ``d_scores`` to bf16 on a TPU.
+
 What a recomputation policy may keep of the forward is named
 (:data:`RESIDUAL_NAMES`), as ``ops/flash_attention.py`` names its own.
 """
@@ -47,14 +84,18 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.accelerator.real_accelerator import on_tpu as _on_tpu
 from deepspeed_tpu.ops import lowerings
+from deepspeed_tpu.ops.ssd_scan import _NT, _TN, _dot
 
 F32 = jnp.float32
 #: the runs of query tiles, each scoring keys up to its own last position
@@ -155,6 +196,223 @@ def index_scores(qi: jax.Array, ki: jax.Array, wi: jax.Array):
     return jnp.einsum("qjs,qj->qs", r.astype(F32), wi.astype(F32)), r
 
 
+def index_grads(d_scores: jax.Array, r: jax.Array, qi: jax.Array,
+                ki: jax.Array, wi: jax.Array):
+    """``(dqi [n, J, c], dki [S, c], dwi [n, J])``, float32, from ``d_scores``
+    [n, S] float32, the gradient by ``I``, and :func:`index_scores`' ``r``:
+    the heads' cotangent ``d_r`` in the compute dtype, three products."""
+    d_r = (d_scores[:, None, :] * wi.astype(F32)[:, :, None]
+           * (r > 0)).astype(r.dtype)
+    dqi = jnp.einsum("qjs,sc->qjc", d_r, ki, preferred_element_type=F32)
+    dki = jnp.einsum("qjs,qjc->sc", d_r, qi, preferred_element_type=F32)
+    return dqi, dki, jnp.einsum("qs,qjs->qj", d_scores, r.astype(F32))
+
+
+# ---------------------------------------------------------------------------
+# the indexer's kernels
+# ---------------------------------------------------------------------------
+
+_LANES = 128
+#: the heads the kernels were built, tested and measured for: two of them
+#: fill a lane tile of ``qi`` [T, J c]
+_HEAD = 64
+#: the most heads a query tile may have (the cell's 16 are 1024 columns):
+#: what the gradient kernel holds in VMEM grows with them
+_MAX_HEADS = 32
+#: the gradient kernel holds a query tile's ``qi`` twice, its gradient three
+#: times and a lane tile of partial sums a head (14.5 MB at 512 x 16 x 64
+#: beside 3 MB of a tile pair's values): over Mosaic's default 16 MiB scope
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _shapes_taken(tile: int, J: int, c: int) -> str:
+    """Why the kernels do not take these shapes; "" where they do."""
+    if tile % _LANES:
+        return f"tiles of {tile} (the kernels: a multiple of {_LANES})"
+    if c != _HEAD or J % 2 or J > _MAX_HEADS:
+        return (f"{J} heads of {c} (the kernels: pairs of heads of {_HEAD}, "
+                f"at most {_MAX_HEADS})")
+    return ""
+
+
+def index_lowering(tile: int, J: int, c: int, dtype, *,
+                   tpu: Optional[bool] = None) -> Tuple[str, str]:
+    """``("pallas" | "jnp", why)`` for the indexer's scores and their
+    gradient over tiles of ``tile`` queries and keys: the kernels where they
+    were measured (a TPU, bf16 operands, shapes :func:`_shapes_taken`
+    accepts), the ``jax.numpy`` lines everywhere else."""
+    if tpu is None:
+        tpu = _on_tpu()
+    if not tpu:
+        return "jnp", "not a TPU backend"
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return "jnp", f"{jnp.dtype(dtype).name} operands (the kernels: bf16)"
+    why = _shapes_taken(tile, J, c)
+    return ("jnp", why) if why else ("pallas", "")
+
+
+def lane_keys(ki: jax.Array) -> jax.Array:
+    """``ki`` [S, 64] -> [2, S, 128]: copy h holds the keys in lanes 64 h ..
+    64 (h + 1) and zeros in the other half, so that a lane tile of ``qi``
+    [n, J c] (two heads side by side) times copy h is the h-th head's scores
+    alone, at the MXU's full depth, and no head is cut out of a lane tile."""
+    return jnp.stack([jnp.pad(ki, ((0, 0), (0, _HEAD))),
+                      jnp.pad(ki, ((0, 0), (_HEAD, 0)))])
+
+
+def _head_scores_relu(q_ref, k_ref, j: int):
+    """``relu(qi_j . ki)`` of the query tile's head ``j`` against the key
+    tile, [tile, tk] float32 holding values of the compute dtype: the
+    product accumulated in float32 and rounded as the einsum's output is.
+    Also the head's lane tile of ``qi`` and its copy of the keys."""
+    qp = q_ref[:, j // 2 * _LANES:(j // 2 + 1) * _LANES]
+    kk = k_ref[j % 2]
+    x = _dot(qp, kk, _NT)
+    return jnp.maximum(x, 0.0).astype(q_ref.dtype).astype(F32), qp, kk
+
+
+def _index_fwd_kernel(i_ref, q_ref, k_ref, w_ref, o_ref):
+    """One key tile of a query tile's weighted head-score sum. ``i_ref``:
+    the query tile's index (scalar prefetch); key tiles after it are zeros
+    (``select`` masks them) and, their index map clamped, not fetched."""
+    kj = pl.program_id(0)
+
+    @pl.when(kj > i_ref[0])
+    def _dead():
+        o_ref[...] = jnp.zeros(o_ref.shape, F32)
+
+    @pl.when(kj <= i_ref[0])
+    def _live():
+        w = w_ref[...]
+        acc = None
+        for j in range(w.shape[1]):
+            r, _, _ = _head_scores_relu(q_ref, k_ref, j)
+            term = r * w[:, j:j + 1]
+            acc = term if acc is None else acc + term
+        o_ref[...] = acc
+
+
+def _index_bwd_kernel(i_ref, q_ref, k_ref, w_ref, ds_ref, dq_ref, dk_ref,
+                      dw_ref, dq_acc, dw_acc):
+    """One key tile of a query tile's gradient of the indexer's loss: the
+    head scores rebuilt as the forward built them, ``d_r_j = d_scores wi_j
+    [x_j > 0]`` in the compute dtype, and its three products. ``dq_acc``
+    [tile, J c] and ``dw_acc`` [J, tile, 128] (a head's ``sum_s d_scores
+    relu(x_j)`` in lane-partial sums) are carried over the key tiles and
+    written out at the query tile's own, the last that is live; ``dk_ref``
+    is this key tile's [tk, 128]: the even heads' sum in the first 64 lanes,
+    the odd heads' in the others (the caller adds the halves)."""
+    kj, i = pl.program_id(0), i_ref[0]
+    heads, dt = w_ref.shape[1], q_ref.dtype
+
+    @pl.when(kj == 0)
+    def _start():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, F32)
+        dw_acc[...] = jnp.zeros(dw_acc.shape, F32)
+
+    @pl.when(kj > i)
+    def _dead():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, F32)
+
+    @pl.when(kj <= i)
+    def _live():
+        w, ds = w_ref[...], ds_ref[...]
+        dk = [None, None]
+        for j in range(heads):
+            r, qp, kk = _head_scores_relu(q_ref, k_ref, j)
+            d_r = jnp.where(r > 0, ds * w[:, j:j + 1], 0.0).astype(dt)
+            # the head's own half of the lane tile: the other is zero
+            mine = _dot(d_r, kk)                            # [tile, 128]
+            dq = mine if j % 2 == 0 else dq + mine
+            if j % 2:
+                dq_acc[:, j // 2 * _LANES:(j // 2 + 1) * _LANES] += dq
+            # the head's half is its own, the other its neighbour's: dropped
+            theirs = _dot(d_r, qp, _TN)                     # [tk, 128]
+            dk[j % 2] = theirs if j < 2 else dk[j % 2] + theirs
+            pw = ds * r
+            dw_acc[j] += sum(pw[:, n:n + _LANES]
+                             for n in range(0, pw.shape[1], _LANES))
+        lane = lax.broadcasted_iota(jnp.int32, dk_ref.shape, 1)
+        dk_ref[...] = jnp.where(lane < _HEAD, dk[0], dk[1])
+
+    @pl.when(kj == i)
+    def _finish():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        lane = lax.broadcasted_iota(jnp.int32, dw_ref.shape, 1)
+        out = jnp.zeros(dw_ref.shape, F32)
+        for j in range(heads):
+            out = jnp.where(lane == j, jnp.sum(dw_acc[j], axis=1,
+                                               keepdims=True), out)
+        dw_ref[...] = out
+
+
+def _index_specs(tile: int, W: int, J: int):
+    """Block specs over the grid of key tiles, the query tile's index ``i``
+    prefetched: the query tile's rows of ``qi`` [T, J c] and ``wi`` [T, J];
+    the key tile's rows of the two lane copies and its columns of an operand
+    over the keys (the last live tile's again after it: nothing is fetched
+    for a dead step); a key tile's columns of a result over the keys."""
+    rows = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda kj, i: (i[0], 0))
+    live = lambda kj, i: jnp.minimum(kj, i[0])  # noqa: E731
+    keys = pl.BlockSpec((2, tile, _LANES), lambda kj, i: (0, live(kj, i), 0))
+    read = pl.BlockSpec((tile, tile), lambda kj, i: (0, live(kj, i)))
+    written = pl.BlockSpec((tile, tile), lambda kj, i: (0, kj))
+    return rows(W), keys, rows(J), read, written
+
+
+def _index_call(kernel, n_keys: int, interpret: bool, name: str, in_specs,
+                out_specs, out_shape, scratch_shapes=()):
+    return pl.pallas_call(
+        kernel, grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_keys,), in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch_shapes),
+        out_shape=out_shape, compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret, name=name)
+
+
+@partial(jax.jit, static_argnames=("tile", "keys", "interpret"))
+def dsa_index_fwd(i, qi, kk, wi, *, tile: int, keys: int,
+                  interpret: bool = False):
+    """``I`` [tile, keys] float32 of query tile ``i`` of a row: ``qi``
+    [T, J c] (the heads side by side), ``kk`` :func:`lane_keys` of the row's
+    keys, ``wi`` [T, J] float32; the columns of key tiles after the query
+    tile's own are zeros."""
+    q, k, w, _, written = _index_specs(tile, qi.shape[1], wi.shape[1])
+    return _index_call(
+        _index_fwd_kernel, keys // tile, interpret, "dsa_index_fwd",
+        in_specs=[q, k, w], out_specs=written,
+        out_shape=jax.ShapeDtypeStruct((tile, keys), F32))(
+            i.reshape(1), qi, kk, wi)
+
+
+@partial(jax.jit, static_argnames=("tile", "interpret"))
+def dsa_index_bwd(i, qi, kk, wi, d_scores, *, tile: int,
+                  interpret: bool = False):
+    """``(dqi_t [tile, J c] in qi's dtype, dki [keys, c] float32, dwi_t
+    [tile, J] float32)`` of query tile ``i`` from ``d_scores`` [tile, keys]
+    float32, the gradient of the loss by ``I``: ``dki`` is the tile's
+    contribution to every key it may see, zero after them."""
+    keys, W, J = d_scores.shape[1], qi.shape[1], wi.shape[1]
+    q, k, w, read, _ = _index_specs(tile, W, J)
+    held = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda kj, i: (0, 0))
+    dq, dk, dw = _index_call(
+        _index_bwd_kernel, keys // tile, interpret, "dsa_index_bwd",
+        in_specs=[q, k, w, read],
+        out_specs=[held(W), pl.BlockSpec((tile, _LANES),
+                                         lambda kj, i: (kj, 0)), held(J)],
+        out_shape=[jax.ShapeDtypeStruct((tile, W), qi.dtype),
+                   jax.ShapeDtypeStruct((keys, _LANES), F32),
+                   jax.ShapeDtypeStruct((tile, J), F32)],
+        scratch_shapes=[pltpu.VMEM((tile, W), F32),
+                        pltpu.VMEM((J, tile, _LANES), F32)])(
+            i.reshape(1), qi, kk, wi, d_scores)
+    return dq, dk[:, :_HEAD] + dk[:, _HEAD:], dw
+
+
 def _pack(mask: jax.Array) -> jax.Array:
     """bool [n, S] -> uint8 [n, S / 8], bit b of byte i is column 8 i + b."""
     n, S = mask.shape
@@ -187,15 +445,22 @@ def _head_scores(qg: jax.Array, kc: jax.Array, scale: float) -> jax.Array:
                       preferred_element_type=F32) * scale
 
 
-def _row_forward(q, k, v, qi, ki, wi, *, topk: int, tile: int):
+def _row_forward(q, k, v, qi, ki, wi, *, topk: int, tile: int,
+                 interpret: Optional[bool]):
     """One row's forward: ``(o [T, H, d], kl, lse [T, H] float32, the set
-    packed [T, T / 8], d kl / d (qi, ki, wi))``."""
+    packed [T, T / 8], d kl / d (qi, ki, wi))``. ``interpret``: None for the
+    ``jax.numpy`` lines of the indexer's scores and their gradient, else
+    the kernels (True: interpreted)."""
     T, H, d = q.shape
     K = k.shape[1]
     scale = 1.0 / math.sqrt(d)
     kt, vt = k.transpose(1, 0, 2), v.transpose(1, 0, 2)        # [K, T, d]
     outs = []
     dki = jnp.zeros(ki.shape, F32)
+    kernels = interpret is not None
+    if kernels:
+        with jax.named_scope("dsa_indexer"):
+            qi_rows, kk, wi = qi.reshape(T, -1), lane_keys(ki), wi.astype(F32)
     for first, n_tiles in runs(T, tile):
         S = (first + n_tiles) * tile
 
@@ -204,9 +469,13 @@ def _row_forward(q, k, v, qi, ki, wi, *, topk: int, tile: int):
             q_pos = t0 + jnp.arange(tile, dtype=jnp.int32)
             chunks = i + 1                  # key tiles up to the query's own
             with jax.named_scope("dsa_indexer"):
-                qi_t = lax.dynamic_slice_in_dim(qi, t0, tile)
-                wi_t = lax.dynamic_slice_in_dim(wi, t0, tile)
-                scores, r = index_scores(qi_t, ki[:S], wi_t)
+                if kernels:
+                    scores = dsa_index_fwd(i, qi_rows, kk, wi, tile=tile,
+                                           keys=S, interpret=interpret)
+                else:
+                    qi_t = lax.dynamic_slice_in_dim(qi, t0, tile)
+                    wi_t = lax.dynamic_slice_in_dim(wi, t0, tile)
+                    scores, r = index_scores(qi_t, ki[:S], wi_t)
             with jax.named_scope("dsa_select"):
                 sel = select(scores, q_pos, topk)
                 packed = jnp.zeros((tile, T // 8), jnp.uint8
@@ -264,13 +533,15 @@ def _row_forward(q, k, v, qi, ki, wi, *, topk: int, tile: int):
                     on, p, 1.0)) - log_q), 0.0))
                 d_scores = jnp.where(sel, jnp.exp(jnp.where(
                     sel, log_q, 0.0)) - p, 0.0)               # [tile, S]
-                d_r = (d_scores[:, None, :] * wi_t.astype(F32)[:, :, None]
-                       * (r > 0)).astype(r.dtype)
-                dqi_t = jnp.einsum("qjs,sc->qjc", d_r, ki[:S],
-                                   preferred_element_type=F32)
-                dki = dki.at[:S].add(jnp.einsum(
-                    "qjs,qjc->sc", d_r, qi_t, preferred_element_type=F32))
-                dwi_t = jnp.einsum("qs,qjs->qj", d_scores, r.astype(F32))
+                if kernels:
+                    dqi_t, dki_t, dwi_t = dsa_index_bwd(
+                        i, qi_rows, kk, wi, d_scores, tile=tile,
+                        interpret=interpret)
+                    dqi_t = dqi_t.reshape((tile,) + qi.shape[1:])
+                else:
+                    dqi_t, dki_t, dwi_t = index_grads(d_scores, r, qi_t,
+                                                      ki[:S], wi_t)
+                dki = dki.at[:S].add(dki_t)
             return dki, (o_t, lse_t, packed, kl, dqi_t.astype(qi.dtype),
                          dwi_t)
 
@@ -351,21 +622,34 @@ def tile_for(T: int, topk: int, tile: int) -> int:
     return tile
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def dsa_attention(q, k, v, qi, ki, wi, topk: int, tile: int):
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def dsa_attention(q, k, v, qi, ki, wi, topk: int, tile: int,
+                  interpret: Optional[bool] = None):
     """``(o [B, T, H, d], kl [B], probes [B, PROBES, T / 8] uint8)`` of the
     module docstring, for rows ``q`` [B, T, H, d], ``k``, ``v`` [B, T, K, d],
     ``qi`` [B, T, J, c], ``ki`` [B, T, c], ``wi`` [B, T, J] float32; ``tile``
     as :func:`tile_for` gives it. ``probes`` are the sets of the queries at
     :func:`probe_positions`, bit s % 8 of byte s // 8 key s: no part of the
-    arithmetic."""
-    return _dsa_fwd(q, k, v, qi, ki, wi, topk, tile)[0]
+    arithmetic. ``interpret`` is the indexer kernels' test handle (None: ask
+    :func:`index_lowering`; True: the kernels, interpreted, in any float
+    dtype, for shapes they take)."""
+    return _dsa_fwd(q, k, v, qi, ki, wi, topk, tile, interpret)[0]
 
 
-def _dsa_fwd(q, k, v, qi, ki, wi, topk, tile):
-    lowerings.count("dsa", "jnp")
+def _dsa_fwd(q, k, v, qi, ki, wi, topk, tile, interpret):
+    J, c = qi.shape[2:]
+    if interpret is None:
+        lowering, _ = index_lowering(tile, J, c, qi.dtype)
+    else:
+        why = _shapes_taken(tile, J, c)
+        if why:
+            raise ValueError(f"the indexer's kernels do not take {why}")
+        lowering = "pallas"
+    # an op by the lowering its indexer took, as ``ops/kda_rule.py`` counts
+    lowerings.count("dsa", lowering)
+    how = bool(interpret) if lowering == "pallas" else None
     o, kl, lse, packed, grads = lax.map(
-        lambda row: _row_forward(*row, topk=topk, tile=tile),
+        lambda row: _row_forward(*row, topk=topk, tile=tile, interpret=how),
         (q, k, v, qi, ki, wi))
     o = checkpoint_name(o, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
@@ -376,7 +660,7 @@ def _dsa_fwd(q, k, v, qi, ki, wi, topk, tile):
     return (o, kl, probes), (q, k, v, o, lse, packed, grads)
 
 
-def _dsa_bwd(topk, tile, res, cot):
+def _dsa_bwd(topk, tile, interpret, res, cot):
     q, k, v, o, lse, packed, (dqi, dki, dwi) = res
     do, dkl, _ = cot
     dq, dk, dv = lax.map(

@@ -1217,24 +1217,38 @@ def test_the_keye_vl2_cells_step_program_compiles_for_v5e(one_chip,
     over the 2,048 keys a 16-head indexer picks for each query, 16 of 128
     experts, at the published widths, one row of 16,384 positions with its
     positions over three axes, under the file's policy). It fits beside what
-    a chip reserves; the selection, the attention over the set and the
-    indexer's loss are the ``jax.numpy`` form (``ops/dsa.py``), traced once
-    for the scanned layer body and once for what the policy leaves to the
-    backward's region; no array of the program holds ``[T, T]`` or is as
-    large as two heads' ``[T, T]`` scores, let alone 32; the sets are found
-    with no sort; and the five routed layers run the grouped products and
-    the row kernels under ``moe``."""
+    a chip reserves; the indexer's weighted head-score sum and the gradient
+    of its loss are the two Mosaic kernels of ``ops/dsa.py`` under
+    ``dsa_indexer`` and ``dsa_loss``, one call for each of the four runs'
+    key lengths, and **no array of the program holds a query tile's head
+    scores** (``[512, 16, S]``: what the einsum form wrote and read four
+    times a tile); the selection and the attention over the set are the
+    ``jax.numpy`` form, traced once for the scanned layer body and once for
+    what the policy leaves to the backward's region; no array of the program
+    holds ``[T, T]`` or is as large as two heads' ``[T, T]`` scores, let
+    alone 32; the sets are found with no sort; and the five routed layers
+    run the grouped products and the row kernels under ``moe``."""
+    from deepspeed_tpu.ops import dsa
+
     T = 16384
+    monkeypatch.setattr(dsa, "_on_tpu", lambda: True)
     snap = lowerings.snapshot()
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "keye_vl2_30b_train_d5e16v8",
         "modelcfg_keye_vl2", 562_290_560, seq=T, position_axes=3)
-    # 7.41 GB of temporaries as compiled here under the file's
-    # "attn_saveable" (5.83 under "full"), beside 6.75 GB of arguments
-    assert mem.temp_size_in_bytes < 7.6e9
+    # 7.405 GB of temporaries as compiled here under the file's
+    # "attn_saveable" (7.41 with the einsum form, PR 56: the peak is not in
+    # the indexer's tiles), beside 6.75 GB of arguments
+    assert mem.temp_size_in_bytes < 7.45e9
     counted = lowerings.since(snap)
     # the scanned layer body and the custom_vjp's own forward rule
-    assert counted["dsa"] == {"jnp": 2}
+    assert counted["dsa"] == {"pallas": 2}
+    scores = _kernel_calls(text, "dsa_indexer")
+    grads = _kernel_calls(text, "dsa_loss")
+    assert scores and all("jit(dsa_index_fwd)" in n for n in scores)
+    assert grads and all("jit(dsa_index_bwd)" in n for n in grads)
+    assert len(scores) == len(grads) == len(dsa.runs(T, 512))
+    assert not re.search(r"\[512,16,\d{4,}\]", text)
     largest = max(
         int(np_prod) for np_prod in (
             eval("*".join(dims.split(",")))  # noqa: S307 (digits and commas)

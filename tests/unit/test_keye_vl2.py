@@ -241,6 +241,144 @@ def test_packed_sets_unpack_to_the_mask():
     assert dsa.runs(64, 64) == [(0, 1)]
 
 
+# ---- the indexer's kernels against the jax.numpy lines ---------------------
+
+#: a row of 512 in tiles of 128: four runs of one query tile, up to four key
+#: tiles a query tile; 4 heads of 64 are two lane tiles of ``qi``
+KT, KTILE, KJ, KC, KTOP = 512, 128, 4, 64, 96
+
+
+def _indexer_inputs(dtype):
+    ks = jax.random.split(jax.random.key(7), 4)
+    draw = lambda k, shape: jax.random.normal(  # noqa: E731
+        k, shape, jnp.float32).astype(dtype)
+    qi, ki = draw(ks[0], (KT, KJ, KC)), draw(ks[1], (KT, KC))
+    wi = jax.random.normal(ks[2], (KT, KJ)) / np.sqrt(KJ * KC)
+    return qi, ki, wi, ks[3]
+
+
+def _both_lowerings(dtype, i, keys, beyond=0.0):
+    """The kernels (interpreted) and the ``jax.numpy`` lines on query tile
+    ``i`` against ``keys`` keys: ``(scores, dqi, dki, dwi)`` of each.
+    ``d_scores`` is drawn, a seventh of it kept, and ``beyond`` times the
+    draw on the key tiles after the query tile's own (where the program's is
+    zero: the set is causal)."""
+    qi, ki, wi, key = _indexer_inputs(dtype)
+    kept = jax.random.uniform(jax.random.fold_in(key, 1), (KTILE, keys)) < 1 / 7
+    d_scores = jax.random.normal(key, (KTILE, keys)) * kept * jnp.where(
+        jnp.arange(keys) < (i + 1) * KTILE, 1.0, beyond)
+    rows = slice(i * KTILE, (i + 1) * KTILE)
+    scores, r = dsa.index_scores(qi[rows], ki[:keys], wi[rows])
+    twin = (scores,) + dsa.index_grads(d_scores, r, qi[rows], ki[:keys],
+                                       wi[rows])
+    at, flat, kk = jnp.int32(i), qi.reshape(KT, -1), dsa.lane_keys(ki)
+    dq, dk, dw = dsa.dsa_index_bwd(at, flat, kk, wi, d_scores, tile=KTILE,
+                                   interpret=True)
+    mine = (dsa.dsa_index_fwd(at, flat, kk, wi, tile=KTILE, keys=keys,
+                              interpret=True),
+            dq.reshape(KTILE, KJ, KC), dk, dw)
+    return [np.asarray(x, np.float64) for x in mine], \
+        [np.asarray(x, np.float64) for x in twin], d_scores
+
+
+def _rel(a, b):
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("case", ["float32", "bfloat16", "dead_key_tiles",
+                                  "shapes_that_do_not_fit"])
+def test_the_indexers_kernels_are_the_jax_numpy_lines(case):
+    """``dsa_index_fwd`` / ``dsa_index_bwd`` interpreted, against
+    ``index_scores`` / ``index_grads``, a query tile of each run."""
+    from deepspeed_tpu.ops import lowerings
+
+    if case == "float32":
+        assert dsa.runs(KT, KTILE) == [(0, 1), (1, 1), (2, 1), (3, 1)]
+        for i in (1, 3):            # the second run's tile and the last's
+            mine, twin, _ = _both_lowerings(jnp.float32, i, (i + 1) * KTILE)
+            for a, b in zip(mine, twin):
+                assert _rel(a, b) < 1e-5
+    elif case == "bfloat16":
+        # the head scores round as the einsum's do: the weighted sums differ
+        # by the order of a float32 sum over the heads, but where a product
+        # fell within an ulp of a rounding boundary
+        (scores, dq, dk, dw), twin, _ = _both_lowerings(jnp.bfloat16, 3, KT)
+        off = np.abs(scores - twin[0]) > 4e-7 * np.abs(twin[0]).max()
+        assert off.mean() < 1e-3 and _rel(scores, twin[0]) < 1e-4
+        for a, b in zip((dq, dk, dw), twin[1:]):
+            assert _rel(a, b) < 2e-3
+        pos = 3 * KTILE + jnp.arange(KTILE, dtype=jnp.int32)
+        sets = [np.asarray(dsa.select(jnp.asarray(x, jnp.float32), pos,
+                                      KTOP)) for x in (scores, twin[0])]
+        # a set differs only where a flipped product sits at the threshold
+        assert (sets[0] != sets[1]).sum() <= 2 * off.sum()
+        assert (sets[0].sum(-1) == KTOP).all()
+    elif case == "dead_key_tiles":
+        # query tile 1 against the whole row: key tiles 2 and 3 come after
+        # it, whatever ``d_scores`` holds there
+        mine, _, d_scores = _both_lowerings(jnp.float32, 1, KT, beyond=1.0)
+        _, twin, _ = _both_lowerings(jnp.float32, 1, KT, beyond=0.0)
+        assert np.any(np.asarray(d_scores)[:, 2 * KTILE:])
+        assert not mine[0][:, 2 * KTILE:].any()
+        assert not mine[2][2 * KTILE:].any()
+        assert _rel(mine[0][:, :2 * KTILE], twin[0][:, :2 * KTILE]) < 1e-5
+        for a, b in zip(mine[1:], twin[1:]):
+            assert _rel(a, b) < 1e-5
+    else:
+        assert dsa.index_lowering(512, 16, 64, jnp.bfloat16, tpu=True) \
+            == ("pallas", "")
+        for shape in ((512, 16, 64, jnp.float32), (72, 16, 64, jnp.bfloat16),
+                      (512, 3, 64, jnp.bfloat16), (512, 16, 128, jnp.bfloat16),
+                      (512, 64, 64, jnp.bfloat16)):
+            assert dsa.index_lowering(*shape, tpu=True)[0] == "jnp", shape
+        assert dsa.index_lowering(512, 16, 64, jnp.bfloat16)[0] == "jnp"
+        # heads of 8 in tiles of 16 (the module's small model): the picker
+        # is not asked under ``interpret`` and the kernels refuse; asked, on
+        # this backend, it takes the lines and says so
+        draw = lambda *shape: jax.random.normal(  # noqa: E731
+            jax.random.key(0), shape)
+        args = (draw(1, 32, 2, 16), draw(1, 32, 1, 16), draw(1, 32, 1, 16),
+                draw(1, 32, 4, 8), draw(1, 32, 8), draw(1, 32, 4))
+        with pytest.raises(ValueError, match="do not take tiles of 16"):
+            dsa.dsa_attention(*args, 12, 16, True)
+        snap = lowerings.snapshot()
+        jax.make_jaxpr(lambda *a: dsa.dsa_attention(*a, 12, 16))(*args)
+        assert lowerings.since(snap)["dsa"] == {"jnp": 1}
+
+
+def test_the_op_with_the_kernels_keeps_the_sets_and_the_gradients():
+    """The whole op with the indexer's kernels (interpreted) against the
+    ``jax.numpy`` lines through its ``custom_vjp``, two rows of two query
+    tiles: the probe queries' sets, the loss and every gradient; and the
+    count names the kernels."""
+    from deepspeed_tpu.ops import lowerings
+
+    ks = jax.random.split(jax.random.key(3), 6)
+    n = 2 * KTILE
+    draw = lambda k, *shape: jax.random.normal(k, shape)  # noqa: E731
+    args = (draw(ks[0], 2, n, 2, 16), draw(ks[1], 2, n, 1, 16),
+            draw(ks[2], 2, n, 1, 16), draw(ks[3], 2, n, KJ, KC),
+            draw(ks[4], 2, n, KC), draw(ks[5], 2, n, KJ) / np.sqrt(KJ * KC))
+
+    def run(interpret):
+        def loss(*a):
+            o, kl, probes = dsa.dsa_attention(*a, KTOP, KTILE, interpret)
+            return jnp.sum(o.astype(jnp.float32) ** 2) + jnp.sum(kl), probes
+        return jax.jit(jax.value_and_grad(loss, argnums=range(6),
+                                          has_aux=True))(*args)
+
+    snap = lowerings.snapshot()
+    (a, sets_a), grads_a = run(True)
+    assert lowerings.since(snap)["dsa"] == {"pallas": 1}
+    (b, sets_b), grads_b = run(None)
+    assert float(a) == pytest.approx(float(b), rel=1e-5)
+    np.testing.assert_array_equal(sets_a, sets_b)
+    assert np.unpackbits(np.asarray(sets_a)).sum() > dsa.PROBES * KTOP
+    for x, y in zip(grads_a, grads_b):
+        assert _rel(np.asarray(x, np.float64), np.asarray(y, np.float64)) \
+            < 1e-5
+
+
 # ---- which loss trains which leaves ---------------------------------------
 
 def _leaves(tree):
