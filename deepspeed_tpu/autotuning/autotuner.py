@@ -11,7 +11,8 @@ v2 adds the axis the reference never had — **mesh shape**, the dominant perf
 knob on TPU. ``mesh_candidates`` takes explicit axis-size dicts or
 ``"auto"``: enumerate every legal factorization of the device count (pruned
 by model divisibility — heads % tp, layers % pp, experts % ep; see
-``parallel/cost_model.py``), rank by the ledger-calibrated cost model, and
+``parallel/cost_model.py``), rank by the cost model (its default rates, or
+the ``cost_model`` given, say one fitted from a sweep just run), and
 measure only the ``mesh_top_k`` survivors. The winning shape is persisted to
 the :class:`~deepspeed_tpu.autotuning.mesh_store.WinnerStore` keyed
 (model signature, world size, device kind) so ``mesh: "auto"`` engine
@@ -167,7 +168,7 @@ class Autotuner:
             return [dict(m) for m in self.mesh_candidates]
         import jax
 
-        from deepspeed_tpu.parallel.cost_model import (calibrated_cost_model,
+        from deepspeed_tpu.parallel.cost_model import (CostModel,
                                                        enumerate_meshes)
 
         world = len(jax.devices())
@@ -179,7 +180,7 @@ class Autotuner:
             # a mesh-aware factory can switch on ulysses/ring for sp > 1
             profile = dataclasses.replace(profile, sp_capable=True)
         cands = enumerate_meshes(world, profile, axes=self.mesh_axes)
-        cm = self.cost_model or calibrated_cost_model()
+        cm = self.cost_model or CostModel()
         stage = max(self.zero_stage_candidates or [0])
         ranked = cm.rank_by_throughput(
             profile, cands, zero_stage=stage,

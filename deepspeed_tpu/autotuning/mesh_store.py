@@ -6,8 +6,9 @@ that says ``"mesh": "auto"`` then adopts the measured-best shape for *this*
 model on *this* hardware under *this* sharding regime without re-tuning —
 a shape tuned at stage 3 (where the fsdp gather dominates) must not leak
 into a stage-0 run whose best shape is pure dp. Cache misses fall back to
-the cost model's top prediction (calibrated from the bench ledger when
-scaling curves exist) — never to a silent re-measure at engine init.
+the cost model's top prediction (its default rates, unless the caller
+gives a model fitted from a sweep) — never to a silent re-measure at engine
+init.
 
 File format (one JSON object)::
 
@@ -30,7 +31,6 @@ import time
 from typing import Any, Dict, Optional
 
 from deepspeed_tpu.parallel.cost_model import (CostModel, ModelProfile,
-                                               calibrated_cost_model,
                                                enumerate_meshes,
                                                model_signature)
 from deepspeed_tpu.utils.logging import log_dist
@@ -129,7 +129,7 @@ def resolve_auto_axis_sizes(n_devices: int,
                  f"({rec.get('metric', 0):.1f} {rec.get('metric_name', '')}"
                  f" on {kind}, w={n_devices})")
         return dict(rec["mesh"]) or {"dp": n_devices}
-    cm = cost_model or calibrated_cost_model()
+    cm = cost_model or CostModel()
     cands = enumerate_meshes(n_devices, profile)
     if not cands:
         return {"dp": n_devices}
@@ -138,5 +138,5 @@ def resolve_auto_axis_sizes(n_devices: int,
     best = ranked[0][0] or {"dp": n_devices}
     log_dist(f"mesh=auto: no measured winner for ({sig}, w={n_devices}, "
              f"{kind}); adopting cost-model prediction {best} "
-             f"(calibrated_from={cm.bw.calibrated_from} ledger points)")
+             f"(calibrated_from={cm.bw.calibrated_from} points)")
     return best

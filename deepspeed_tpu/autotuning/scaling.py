@@ -13,12 +13,10 @@ a real engine on a device subset, times ``fused_train_step``, and records
 * the analytic volume breakdown (``parallel/cost_model.py``) the bandwidth
   calibration regresses against.
 
-``bench.py --scaling`` runs :func:`run_sweep` on the forced-8-virtual-device
-CPU mesh (the ``--zero-pp`` subprocess trick) and appends one schema'd
-``bench_scaling`` entry to ``tools/bench_ledger.jsonl``; ``bench_trend.py``
-gates per-(shape, world) regressions on the recorded series. On real
-hardware the same sweep measures actual ICI/DCN rates — the harness is
-device-agnostic, only the numbers change.
+``tools/scaling_drill.py`` runs :func:`run_sweep` on the eight virtual CPU
+devices and fits the rates from the sweep it has just run, in memory; nothing
+records or gates the curves. On real hardware the same sweep measures actual
+ICI/DCN rates — the harness is device-agnostic, only the numbers change.
 """
 
 from __future__ import annotations
@@ -233,7 +231,7 @@ def run_sweep(worlds: Sequence[int] = DEFAULT_WORLDS,
               seq: int = DEFAULT_SEQ, devices=None) -> Dict[str, Any]:
     """The full scaling sweep: world sizes × mesh shapes, normalized to the
     measured 1-chip baseline of each model kind. Returns the
-    ``bench_scaling`` ledger result (curves keyed ``shape → wN → point``)."""
+    sweep's result (curves keyed ``shape → wN → point``)."""
     import jax
 
     from deepspeed_tpu.autotuning.mesh_store import device_kind
@@ -294,8 +292,7 @@ def run_sweep(worlds: Sequence[int] = DEFAULT_WORLDS,
                      f"{pt['tokens_per_sec_per_chip']} tok/s/chip "
                      f"(eff={pt.get('parallel_efficiency')})")
 
-    # calibrate link bandwidths from THIS sweep's measured points (the
-    # ledger-backed calibration reads the same structure back later)
+    # calibrate link bandwidths from THIS sweep's measured points
     samples = [{"step_s": pt["step_ms"] / 1e3, **pt["predicted"]}
                for pts in curves.values() for pt in pts.values()]
     samples += [{"step_s": b["step_ms"] / 1e3, **b["predicted"]}
@@ -313,10 +310,8 @@ def run_sweep(worlds: Sequence[int] = DEFAULT_WORLDS,
         "device": kind, "worlds": worlds, "steps": steps,
         "micro_batch": micro_batch, "seq": seq,
         "baselines": baselines,
-        # curves are scoped under the device kind: each (device, shape,
-        # world) config is its own trend series — a TPU sweep entry must
-        # never become the "best prior" a CPU-harness run gates against
-        # (the same split bench_capacity's by_device applies)
+        # curves are scoped under the device kind: a TPU sweep's points
+        # and a CPU harness's are orders of magnitude apart
         "curves": {kind: curves},
         "failures": failures, "calibration": bw.as_dict(),
     }

@@ -20,7 +20,7 @@ mesh shape into predicted step time from first principles:
 
 Bandwidths are NOT hardcoded truths: :func:`fit_bandwidths` calibrates
 (sustained flops, ICI B/s, DCN B/s, fixed overhead) by least squares from
-measured scaling curves — the ``bench_scaling`` ledger entries record each
+measured scaling curves — a sweep (``autotuning/scaling.py``) records each
 point's measured step time next to its analytic volume breakdown, so the
 model learns the harness it runs on (CPU dev mesh or real pod alike).
 
@@ -342,7 +342,7 @@ def collective_volumes(profile: ModelProfile, mesh: Dict[str, int], *,
 class LinkBandwidths:
     """Sustained rates the predictor divides volumes by. The defaults are
     deliberately round placeholders — real numbers come from
-    :func:`fit_bandwidths` over measured ledger curves."""
+    :func:`fit_bandwidths` over a sweep's measured curves."""
 
     flops_per_s: float = 1e12
     ici_bytes_per_s: float = 4e10
@@ -355,7 +355,8 @@ class LinkBandwidths:
 
 
 class CostModel:
-    """Predicted step time per mesh shape, with ledger-calibrated rates."""
+    """Predicted step time per mesh shape, at the rates given (the defaults,
+    or a sweep's fit)."""
 
     def __init__(self, bandwidths: Optional[LinkBandwidths] = None):
         self.bw = bandwidths or LinkBandwidths()
@@ -477,92 +478,3 @@ def fit_bandwidths(samples: Sequence[Dict[str, Any]],
         ici_bytes_per_s=rate("ici_bytes", base.ici_bytes_per_s),
         dcn_bytes_per_s=rate("dcn_bytes", base.dcn_bytes_per_s),
         overhead_s=overhead, calibrated_from=len(pts))
-
-
-def samples_from_ledger(entries: Sequence[Dict[str, Any]],
-                        device: Optional[str] = None
-                        ) -> List[Dict[str, Any]]:
-    """Flatten ``bench_scaling`` ledger entries into calibration samples —
-    every curve point AND 1-chip baseline that recorded both a measured
-    step time and its analytic volume breakdown (the zero-comm baselines
-    anchor the flops/overhead separation; dropping them would fit a more
-    collinear system than the sweep's own recorded calibration).
-
-    ``device`` restricts to entries measured on that device kind — fitting
-    one rate set across CPU-harness and TPU entries (orders of magnitude
-    apart) would produce bandwidths meaningful for neither."""
-
-    def walk(node):
-        # curves nest device → shape → world → point; tolerate any depth
-        if not isinstance(node, dict):
-            return
-        if "predicted" in node and "step_ms" in node:
-            yield node
-            return
-        for v in node.values():
-            yield from walk(v)
-
-    out: List[Dict[str, Any]] = []
-    for e in entries:
-        if e.get("bench") != "bench_scaling":
-            continue
-        result = e.get("result") or {}
-        if device is not None and result.get("device") not in (None, device):
-            continue
-        for section in ("curves", "baselines"):
-            for pt in walk(result.get(section) or {}):
-                pred = pt.get("predicted") or {}
-                if pt.get("step_ms") and pred.get("flops"):
-                    out.append({"step_s": float(pt["step_ms"]) / 1e3,
-                                **pred})
-    return out
-
-
-def _read_scaling_ledger(path: Optional[str]) -> List[Dict[str, Any]]:
-    """Minimal JSONL ledger reader (schema-1 entries, corrupt lines
-    skipped). Inlined rather than importing ``tools/bench_ledger.py``: a
-    library module must not reach into (or sys.path-mutate toward) the
-    dev ``tools/`` directory, which does not exist in an installed
-    package."""
-    import json
-    import os
-
-    if path is None:
-        path = os.environ.get("DSTPU_BENCH_LEDGER_PATH") or os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "tools", "bench_ledger.jsonl")
-    out: List[Dict[str, Any]] = []
-    try:
-        with open(path, "r", encoding="utf-8") as f:
-            for line in f:
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    entry = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if isinstance(entry, dict) and entry.get("schema") == 1:
-                    out.append(entry)
-    except OSError:
-        pass
-    return out
-
-
-def calibrated_cost_model(ledger_path: Optional[str] = None,
-                          device: Optional[str] = None) -> CostModel:
-    """A :class:`CostModel` whose rates are fitted from the bench ledger's
-    ``bench_scaling`` curves measured on THIS device kind when any exist;
-    default rates otherwise (the ``calibrated_from`` field says which you
-    got)."""
-    if device is None:
-        try:
-            # lazy: mesh_store imports this module at load time
-            from deepspeed_tpu.autotuning.mesh_store import device_kind
-
-            device = device_kind()
-        except Exception:
-            device = None       # no backend yet → fit over everything
-    samples = samples_from_ledger(_read_scaling_ledger(ledger_path),
-                                  device=device)
-    return CostModel(fit_bandwidths(samples))

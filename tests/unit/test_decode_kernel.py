@@ -320,7 +320,7 @@ class TestFallback:
 
 @pytest.mark.pallas
 @pytest.mark.slow
-@pytest.mark.parametrize("scenario", ["parity", "fused-fence", "throughput"])
+@pytest.mark.parametrize("scenario", ["parity", "fused-fence"])
 def test_decode_kernel_drill(scenario):
     import sys
 

@@ -188,9 +188,13 @@ def engines():
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models import TransformerLM, get_preset
 
+    # what the front end and the router do: the first replica attends with
+    # the XLA twin; the second, which the two-replica cases (fail-over, the
+    # storm) reach, keeps the kernel (interpreted here)
     return [InferenceEngineV2(TransformerLM(get_preset("tiny")),
                               max_sequences=8, max_seq_len=128,
-                              block_size=16) for _ in range(2)]
+                              block_size=16, decode_kernel=kernel)
+            for kernel in ("xla", "pallas")]
 
 
 def _batcher(engine, **kw):

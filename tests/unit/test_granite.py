@@ -87,7 +87,7 @@ def getter(params, hf):
 def init(model, seed=0):
     """Seeded random weights; the leaves the initialiser sets to a constant
     (D, the norms' scales) drawn too, so that leaving one out shows."""
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     k = jax.random.split(jax.random.key(seed + 100), 4)
     ssm = params["layers"]["ssm"]
     ssm["D"] = 1.0 + 0.5 * jax.random.normal(k[0], ssm["D"].shape)
@@ -184,11 +184,12 @@ def test_the_convolution_is_the_direct_sum():
 # ---- the model against the reference --------------------------------------
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    return hf, model, params, ref.batch_loss(hf, getter(params, hf), ROWS)
+    return hf, model, params, run_memo("granite_small", lambda: ref.batch_loss(
+        hf, getter(params, hf), ROWS))
 
 
 def test_loss_and_mixer_outputs_match_the_reference(small):
@@ -636,7 +637,7 @@ FAULTS = {
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances, and the reference at a small size (hidden
     256, the period m m a m, 64-token rows, chunks of 8) on bf16-rounded
     weights."""
@@ -652,8 +653,9 @@ def cell_check():
     params = jax.tree_util.tree_map(
         lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
     rows = np.random.default_rng(7).integers(0, 512, (2, 64)).astype(np.int32)
-    return check, hf, params, rows, ref.batch_loss(hf, getter(params, hf),
-                                                   rows)
+    return check, hf, params, rows, run_memo(
+        "granite_cell_check", lambda: ref.batch_loss(
+            hf, getter(params, hf), rows))
 
 
 def _failed(check, got, want):
@@ -701,7 +703,8 @@ def test_a_model_without_the_layer_loads_none_of_its_modules():
         " num_layers=2, vocab_size=64))\n"
         "p = m.init(jax.random.key(0)); m.param_specs()\n"
         "m.cfg.num_params_estimate(); m.step_program_facts()\n"
-        "jax.grad(m.loss_fn)(p, {'input_ids': jnp.zeros((1, 8), 'int32')})\n"
+        "jax.jit(jax.grad(m.loss_fn))(p, {'input_ids': jnp.zeros((1, 8), "
+        "'int32')})\n"
         "print([k for k in sys.modules if 'ssd_scan' in k or 'mamba' in k"
         " or 'delta' in k])")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True,

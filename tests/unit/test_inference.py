@@ -29,6 +29,14 @@ def tiny_lm():
     return model, params
 
 
+def _engine_on_the_xla_twin(model, params, **kw):
+    """An engine for a case that tests what stands round the step (the pool,
+    the buckets, sampling, capacity): attention is the XLA twin's. The parity
+    cases (``*_parity*``, ``*matches*``, the quantised pools) build theirs
+    with the kernel, interpreted here."""
+    return InferenceEngineV2(model, params=params, decode_kernel="xla", **kw)
+
+
 def test_cached_forward_matches_full(tiny_lm):
     model, params = tiny_lm
     ids = np.random.default_rng(0).integers(0, 256, (2, 12)).astype(np.int32)
@@ -150,8 +158,8 @@ def test_paged_pool_smaller_than_dense(tiny_lm):
     """HBM footprint must follow allocated blocks, not max_seqs x max_seq_len:
     a pool sized for half the dense capacity still serves short sequences."""
     model, params = tiny_lm
-    eng = InferenceEngineV2(model, params=params, max_sequences=8,
-                            max_seq_len=64, block_size=8, num_blocks=16)
+    eng = _engine_on_the_xla_twin(
+        model, params, max_sequences=8, max_seq_len=64, block_size=8, num_blocks=16)
     dense_blocks = 8 * (64 // 8)
     assert eng.cache["k"].shape[1] == 16 + 1 < dense_blocks
     # 5 sequences x 2 blocks each fit with 6 blocks spare
@@ -303,8 +311,8 @@ def test_packed_jit_cache_bounded(tiny_lm):
     """Power-of-two bucketing keeps the packed step's jit cache at
     O(log max_batched_tokens) entries regardless of chunk-length variety."""
     model, params = tiny_lm
-    eng = InferenceEngineV2(model, params=params, max_sequences=4,
-                            max_seq_len=64, block_size=8)
+    eng = _engine_on_the_xla_twin(
+        model, params, max_sequences=4, max_seq_len=64, block_size=8)
     rng = np.random.default_rng(8)
     for uid, n in enumerate([3, 5, 7, 6]):        # all bucket to 8
         eng.put([uid], [rng.integers(0, 256, n)])
@@ -438,8 +446,8 @@ def test_decode_batch_sampling(tiny_lm):
     prompt = rng.integers(0, 256, 6)
     B = 8
 
-    eng = InferenceEngineV2(model, params=params, max_sequences=B,
-                            max_seq_len=64, block_size=8)
+    eng = _engine_on_the_xla_twin(
+        model, params, max_sequences=B, max_seq_len=64, block_size=8)
     uids = list(range(B))
     r = eng.put(uids, [prompt] * B)        # identical context per row
     logits = np.asarray(r[0], np.float32)  # [V] — same for every row
@@ -569,8 +577,8 @@ def test_joint_capacity_rejected_before_any_scheduling(tiny_lm):
     model, params = tiny_lm
     rng = np.random.default_rng(11)
     # pool fits ONE 64-token prompt (8 blocks) but not two
-    eng = InferenceEngineV2(model, params=params, max_sequences=4,
-                            max_seq_len=600, block_size=8, num_blocks=10)
+    eng = _engine_on_the_xla_twin(
+        model, params, max_sequences=4, max_seq_len=600, block_size=8, num_blocks=10)
     p = rng.integers(0, 256, 64)
     with pytest.raises(RuntimeError, match="cannot schedule"):
         eng.put([1, 2], [p, p])

@@ -100,7 +100,7 @@ def getter(params, hf, dense_as_routed=False):
 
 
 def init(model, seed=0, router_gain=4.0):
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     # a router that prefers some experts, so that the top k is not a toss-up
     moe = params["layers"]["mlp_moe"]
     moe["router"] = moe["router"] * router_gain
@@ -111,12 +111,12 @@ ROWS = np.random.default_rng(0).integers(0, 128, (2, 24)).astype(np.int32)
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    return hf, model, params, ref.batch_loss(hf, getter(params, hf), ROWS,
-                                             ALPHA)
+    return hf, model, params, run_memo("kanana_small", lambda: ref.batch_loss(
+        hf, getter(params, hf), ROWS, ALPHA))
 
 
 def test_loss_balance_term_mixer_outputs_and_counts_match_the_reference(
@@ -737,7 +737,7 @@ FAULTS = {
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances, and the reference at a small size (hidden
     256, a dense and three routed layers, 64-token rows) on bf16-rounded
     weights."""
@@ -754,8 +754,9 @@ def cell_check():
         lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
     params["layers"]["mlp_moe"]["router_bias"] = bias      # kept in float32
     rows = np.random.default_rng(7).integers(0, 512, (2, 64)).astype(np.int32)
-    return check, hf, params, rows, ref.batch_loss(
-        hf, getter(params, hf), rows, ALPHA)
+    return check, hf, params, rows, run_memo(
+        "kanana_cell_check", lambda: ref.batch_loss(
+            hf, getter(params, hf), rows, ALPHA))
 
 
 def _failed(check, got, want, bias):
@@ -814,7 +815,8 @@ def test_a_model_without_the_layers_loads_none_of_their_modules():
         " num_layers=2, vocab_size=64, num_experts=4, moe_dispatch='grouped'))\n"
         "p = m.init(jax.random.key(0)); m.param_specs()\n"
         "m.cfg.num_params_estimate(); m.step_program_facts()\n"
-        "jax.grad(m.loss_fn)(p, {'input_ids': jnp.zeros((1, 8), 'int32')})\n"
+        "jax.jit(jax.grad(m.loss_fn))(p, {'input_ids': jnp.zeros((1, 8), "
+        "'int32')})\n"
         "print([k for k in sys.modules if k.endswith('.mla') or 'mamba' in k])")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True,
                          capture_output=True,

@@ -71,7 +71,7 @@ def model_for(hf, dtype="float32", **over):
 def init(model, seed=0):
     """Seeded weights with nothing left at 1 or 0: every norm's scale and
     the LayerNorm's bias drawn."""
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     key = jax.random.key(seed + 1)
 
     def jig(path, a):
@@ -108,15 +108,15 @@ def reference_of(hf, params, batch, **kw):
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     old, ref._QUERY_BLOCK = ref._QUERY_BLOCK, 32
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
     batch = a_batch()
-    (loss, parts), grads = jax.jit(jax.value_and_grad(
-        model.loss_and_parts, has_aux=True))(params, batch)
-    want, ref_grads = reference_of(hf, params, batch)
+    ((loss, parts), grads), (want, ref_grads) = run_memo("keye_vl2_small", lambda: (
+        jax.jit(jax.value_and_grad(model.loss_and_parts, has_aux=True))(
+            params, batch), reference_of(hf, params, batch)))
     yield hf, model, params, batch, loss, parts, grads, want, ref_grads
     ref._QUERY_BLOCK = old
 
@@ -701,10 +701,11 @@ def test_a_plain_attention_model_takes_the_three_axes_too(small):
         "dtype": "float32", "attention_impl": "xla"}))
     params = model.init(jax.random.key(0))
     index = np.broadcast_to(np.arange(T, dtype=np.int32), (3, 2, T))
-    a = float(model.loss_fn(params, {"input_ids": batch["input_ids"]}))
-    b = float(model.loss_fn(params, {"input_ids": batch["input_ids"],
-                                     "position_ids": index}))
-    c = float(model.loss_fn(params, batch))
+    loss_fn = jax.jit(model.loss_fn)
+    a = float(loss_fn(params, {"input_ids": batch["input_ids"]}))
+    b = float(loss_fn(params, {"input_ids": batch["input_ids"],
+                               "position_ids": index}))
+    c = float(loss_fn(params, batch))
     assert a == pytest.approx(b, abs=1e-6) and abs(c - a) > 1e-4
 
 

@@ -73,7 +73,7 @@ def init(model, seed=0, router_gain=4.0, qk_gain=1.0):
     is no toss-up; q/k norm scales that differ by channel, so that a norm on
     the wrong side of the rope shows (at a scale of ones it commutes with
     the rotation)."""
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     moe, attn = params["layers"]["mlp_moe"], params["layers"]["attn"]
     moe["router"] = moe["router"] * router_gain
     for i, n in enumerate(("q_norm", "k_norm")):
@@ -87,12 +87,12 @@ ROWS = np.random.default_rng(0).integers(0, 256, (2, 24)).astype(np.int32)
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    want, grads = ref.batch_loss_and_grads(
-        hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA)
+    want, grads = run_memo("lfm2_small", lambda: ref.batch_loss_and_grads(
+        hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA))
     return hf, model, params, want, grads
 
 
@@ -329,9 +329,9 @@ def test_a_pattern_with_ffn_kinds_runs_as_three_runs_over_four_stacks(small):
     assert layers["mlp_moe"]["router"].shape == (4, 64, 16)
     assert layers["ln1"]["scale"].shape == (5, 64)
     batch = {"input_ids": ROWS}
-    loss, parts = model.loss_and_parts(params, batch)
+    loss, parts = jax.jit(model.loss_and_parts)(params, batch)
     unrolled = model_for(hf, scan_layers=False, remat_policy="full")
-    loss2, parts2 = unrolled.loss_and_parts(params, batch)
+    loss2, parts2 = jax.jit(unrolled.loss_and_parts)(params, batch)
     assert float(loss2) == pytest.approx(float(loss), rel=1e-6)
     np.testing.assert_allclose(parts2["mix_out_ms"], parts["mix_out_ms"],
                                rtol=1e-5)
@@ -592,7 +592,7 @@ def _getter(params, hf, dense_as_routed=False, head=None):
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances on the forward's parts, and the reference
     at a small size (hidden 256, the five layers, 64-token rows) on
     bf16-rounded weights."""
@@ -609,8 +609,9 @@ def cell_check():
     params = _bf16(init(model_for(hf), seed=5, router_gain=2.0, qk_gain=3.0))
     rows = list(np.random.default_rng(7).integers(0, 512, (2, 64))
                 .astype(np.int32))
-    return check, hf, params, rows, ref.batch_loss(
-        hf, modelcfg.weights_getter(params, hf), rows, ALPHA)
+    return check, hf, params, rows, run_memo(
+        "lfm2_cell_check", lambda: ref.batch_loss(
+            hf, modelcfg.weights_getter(params, hf), rows, ALPHA))
 
 
 def _judged(check, got, want, bias):
@@ -666,7 +667,7 @@ def test_the_program_passes_the_cells_forward_limits_at_the_small_size(
     larger share than at 16,384, so these are the small size's limits, not
     the cell's)."""
     check, hf, params, rows, want = cell_check
-    loss, parts = model_for(hf, "bfloat16").loss_and_parts(
+    loss, parts = jax.jit(model_for(hf, "bfloat16").loss_and_parts)(
         params, {"input_ids": np.stack(rows)})
     got = {**{k: np.asarray(v) for k, v in parts.items()},
            "loss": np.asarray(loss)}

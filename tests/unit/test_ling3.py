@@ -79,7 +79,7 @@ def init(model, seed=0):
     (0.5, 1.5), a router that prefers some experts (so that the top k is no
     toss-up); ``A_log``, ``dt_bias``, the taps and the selection biases are
     the initialiser's own draws."""
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     layers = params["layers"]
     layers["mlp_moe"]["router"] = layers["mlp_moe"]["router"] * 4.0
     scales = [(layers["ln1"], "scale"), (layers["ln2"], "scale"),
@@ -99,14 +99,15 @@ ROWS = np.random.default_rng(0).integers(0, 256, (2, 80)).astype(np.int32)
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    want, grads = ref.batch_loss_and_grads(
-        hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA)
-    (loss, parts), got = jax.jit(jax.value_and_grad(
-        model.loss_and_parts, has_aux=True))(params, {"input_ids": ROWS})
+    (want, grads), ((loss, parts), got) = run_memo("ling3_small", lambda: (
+        ref.batch_loss_and_grads(
+            hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA),
+        jax.jit(jax.value_and_grad(model.loss_and_parts, has_aux=True))(
+            params, {"input_ids": ROWS})))
     return hf, model, params, want, grads, (loss, parts, got)
 
 

@@ -138,7 +138,7 @@ def _reference_grads(params, toks, untied):
 def program_grads(case):
     model, params, toks = case
     with jax.default_matmul_precision("highest"):
-        return jax.grad(model.loss_fn)(
+        return jax.jit(jax.grad(model.loss_fn))(
             params, {"input_ids": jnp.asarray(toks)[None]})
 
 

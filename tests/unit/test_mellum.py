@@ -78,7 +78,7 @@ def getter(params):
 
 
 def init(model, seed=0, router_gain=4.0):
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     # a router that prefers some experts, so that the top k is not a toss-up
     params["layers"]["mlp"]["router"] = \
         params["layers"]["mlp"]["router"] * router_gain
@@ -89,11 +89,12 @@ ROWS = np.random.default_rng(0).integers(0, 96, (2, 24)).astype(np.int32)
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    want = ref.batch_loss(hf, getter(params), ROWS, COEF)
+    want = run_memo("mellum_small", lambda: ref.batch_loss(
+        hf, getter(params), ROWS, COEF))
     return hf, model, params, want
 
 
@@ -426,7 +427,7 @@ FAULTS = {
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances, and the reference at a small size (hidden
     256, one period, 64-token rows) on bf16-rounded weights."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
@@ -444,8 +445,9 @@ def cell_check():
         lambda w: w.astype(jnp.bfloat16).astype(jnp.float32),
         init(model, seed=5, router_gain=1.0))
     rows = np.random.default_rng(7).integers(0, 512, (2, 64)).astype(np.int32)
-    return check, hf, params, rows, ref.batch_loss(hf, getter(params), rows,
-                                                   COEF)
+    return check, hf, params, rows, run_memo(
+        "mellum_cell_check", lambda: ref.batch_loss(
+            hf, getter(params), rows, COEF))
 
 
 def _failed(check, got, want):

@@ -141,14 +141,18 @@ class TestManifestProtocol:
 # batcher-level A -> B adoption (fp32 engines, bit-identical greedy)
 # ---------------------------------------------------------------------------
 
-def _mig_batcher(shared, **serving):
-    """fp32 engine + SLO preemption + migration pointed at ``shared``."""
+def _mig_batcher(shared, decode_kernel="xla", **serving):
+    """fp32 engine + SLO preemption + migration pointed at ``shared``. What
+    these cases test is what the router and the store do, so the engine
+    attends with the XLA twin; the tentpole case asks for the kernel
+    (interpreted here), whose parity is ``test_decode_kernel.py``'s."""
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models import TransformerLM, get_preset
 
     eng = InferenceEngineV2(
         TransformerLM(get_preset("tiny", dtype="float32")),
-        max_sequences=8, max_seq_len=128, block_size=16)
+        max_sequences=8, max_seq_len=128, block_size=16,
+        decode_kernel=decode_kernel)
     cfg = ServingConfig(**{
         "prefill_chunk": 32, "default_max_new_tokens": 8,
         "slo": {"enabled": True, "preempt": True},
@@ -203,7 +207,9 @@ class TestCrossReplicaAdoption:
         prompt = PROMPT
         base = _baseline(shared, prompt)
 
-        a = _mig_batcher(shared)
+        # the file's end-to-end case on the kernel: both replicas attend with
+        # it, and finish the XLA twin's baseline to the token
+        a = _mig_batcher(shared, decode_kernel="pallas")
         uid = a.submit(prompt, max_new_tokens=8, tier="batch")
         req = _pause_mid_decode(a, uid)
         mid = len(req.generated)
@@ -214,7 +220,7 @@ class TestCrossReplicaAdoption:
         assert path is not None and os.path.exists(path)
         assert a.counters["pause_exports"] == 1
 
-        b = _mig_batcher(shared)
+        b = _mig_batcher(shared, decode_kernel="pallas")
         claimed = claim_manifest(path)
         assert claimed is not None
         payload = load_manifest(claimed)
@@ -510,7 +516,7 @@ class TestRebalanceAndTrace:
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_serve_drill_crash_migrate(tmp_path, monkeypatch):
+def test_serve_drill_crash_migrate(tmp_path):
     """Tier-1 (slow) wrapper for ``serve_drill --scenario crash-migrate``:
     storm two replicas sharing an NVMe namespace, kill one mid-decode;
     the sibling resumes >= 1 request from its durable manifest and
@@ -519,7 +525,6 @@ def test_serve_drill_crash_migrate(tmp_path, monkeypatch):
     entry and manifest reclaimed."""
     import sys
 
-    monkeypatch.setenv("DSTPU_BENCH_LEDGER", "0")
     sys.path.insert(0, _TOOLS)
     from serve_drill import run_scenario
 

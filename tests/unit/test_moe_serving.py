@@ -28,13 +28,17 @@ _TOOLS = os.path.join(os.path.dirname(os.path.dirname(
 _FP32 = {"dtype": "float32", "param_dtype": "float32"}
 
 
-def _engine(E=4, top_k=2, mesh=None, **kw):
+def _engine(E=4, top_k=2, mesh=None, decode_kernel="xla", **kw):
+    """What these cases test is the experts' dispatch, so attention is the
+    XLA twin's; the first case's sharded engine keeps the kernel
+    (interpreted here)."""
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
 
     return InferenceEngineV2(
         TransformerLM(get_preset("tiny", num_experts=E, top_k=top_k,
                                  moe_dispatch="grouped", **_FP32)),
-        max_sequences=8, max_seq_len=128, block_size=16, mesh=mesh, **kw)
+        max_sequences=8, max_seq_len=128, block_size=16, mesh=mesh,
+        decode_kernel=decode_kernel, **kw)
 
 
 def _greedy(eng, prompt, n=8):
@@ -52,7 +56,7 @@ class TestEngineExpertParallel:
         parallelism is a pure layout choice."""
         prompt = np.random.default_rng(0).integers(0, 250, 16)
         ref = _greedy(_engine(), prompt)
-        ep = _engine(mesh={"ep": 4, "dp": 2})
+        ep = _engine(mesh={"ep": 4, "dp": 2}, decode_kernel="pallas")
         assert ep._moe_ep and ep.moe_kernel in ("ragged", "padded")
         assert _greedy(ep, prompt) == ref
 
@@ -219,40 +223,12 @@ class TestFaultSite:
         assert inj.fired == ["moe_a2a_error@moe_a2a:decode:step=-1"]
 
 
-def test_bench_moe_trend_gate():
-    """A tokens/s regression in any ep-sweep cell trips the ledger gate;
-    an unmeasured cell in the newest run is 'no data', not a regression."""
-    import sys
-
-    sys.path.insert(0, _TOOLS)
-    from bench_trend import compare
-
-    def entry(sha, cells):
-        return {"schema": 1, "bench": "bench_moe", "git_sha": sha,
-                "result": {"metric": "moe_decode_tokens_per_sec",
-                           "moe": cells}}
-
-    a = entry("a", {"E8-ep8-ragged": {"tokens_per_sec": 150.0,
-                                      "ragged_speedup": 1.2,
-                                      "balance": 0.6},
-                    "E4-ep4-ragged": {"tokens_per_sec": 90.0}})
-    b = entry("b", {"E8-ep8-ragged": {"tokens_per_sec": 40.0,
-                                      "ragged_speedup": 1.15,
-                                      "balance": 0.6}})
-    rep = compare([a, b], threshold=0.15)
-    regressed = {r["metric"] for r in rep["regressions"]}
-    assert "moe.E8-ep8-ragged.tokens_per_sec" in regressed
-    assert not any("E4-ep4" in m for m in regressed)   # unmeasured: no gate
-    assert not any("ragged_speedup" in m for m in regressed)  # within 15%
-    assert rep["ok"] is False
-
-
 # ---------------------------------------------------------------------------
 # drill wrappers (slow): the scenario CLIs are the authority
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_moe_storm_drill(tmp_path, monkeypatch):
+def test_moe_storm_drill(tmp_path):
     """serve_drill moe-storm: skewed-router storm + mid-dispatch a2a
     faults -> zero token loss, bounded rebalance, identical greedy across
     the swap, pool restored."""
@@ -261,7 +237,6 @@ def test_moe_storm_drill(tmp_path, monkeypatch):
     sys.path.insert(0, _TOOLS)
     from serve_drill import run_scenario
 
-    monkeypatch.setenv("DSTPU_BENCH_LEDGER", "0")
     verdict = run_scenario("moe-storm", workdir=str(tmp_path))
     assert verdict["ok"], verdict
 

@@ -63,7 +63,7 @@ def model_for(hf, dtype="float32", **over):
 
 
 def init(model, seed=0, router_gain=4.0):
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     # a router that prefers some experts, so that the top k is not a toss-up
     moe = params["layers"]["mlp_moe"]
     moe["router"] = moe["router"] * router_gain
@@ -74,12 +74,12 @@ ROWS = np.random.default_rng(0).integers(0, 256, (2, 24)).astype(np.int32)
 
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    want, grads = ref.batch_loss_and_grads(
-        hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA)
+    want, grads = run_memo("nemotron_h_small", lambda: ref.batch_loss_and_grads(
+        hf, modelcfg.weights_getter(params, hf), list(ROWS), ALPHA))
     return hf, model, params, want, grads
 
 
@@ -184,17 +184,18 @@ def test_the_eight_head_shares_of_a_mamba_layer_are_the_uncut_layer():
         jax.random.key(1), whole, 1, jnp.float32))
     u = jax.random.normal(jax.random.key(2), (24, 64), jnp.float32)
     names = {"gate_norm": "norm"}
-    want = ref.mamba(u, {n: w[names.get(n, n)] for n in ref.TENSORS["M"]
-                         if n != "norm"}, hf)
+    want = jax.jit(lambda u, w: ref.mamba(u, w, hf))(
+        u, {n: w[names.get(n, n)] for n in ref.TENSORS["M"] if n != "norm"})
     share = dataclasses.replace(whole, ssm_heads=2, ssm_groups=1)
-    total = sum(mamba.ssm_block(u[None], _mamba_share(w, hf, s), share)[0]
+    ssm_block = jax.jit(mamba.ssm_block, static_argnums=2)    # a program a cfg
+    total = sum(ssm_block(u[None], _mamba_share(w, hf, s), share)[0]
                 for s in range(8))
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
     # and the uncut layer in one piece, its norm over 8 groups apart
-    np.testing.assert_allclose(mamba.ssm_block(u[None], w, whole)[0], want,
+    np.testing.assert_allclose(ssm_block(u[None], w, whole)[0], want,
                                rtol=2e-4, atol=2e-5)
     one_group = dataclasses.replace(whole, ssm_group_norm=False)
-    assert float(jnp.abs(mamba.ssm_block(u[None], w, one_group)[0]
+    assert float(jnp.abs(ssm_block(u[None], w, one_group)[0]
                          - want).max()) > 1e-2
 
 
@@ -204,17 +205,18 @@ def test_the_eight_head_shares_of_an_attention_layer_are_the_uncut_layer():
     w = jax.tree_util.tree_map(lambda a: a[0] * 2.0, whole.init(
         jax.random.key(3))["layers"]["attn"])
     u = jax.random.normal(jax.random.key(4), (24, 64), jnp.float32)
-    want = ref.attention_layer(u, w, hf)
+    want = jax.jit(lambda u, w: ref.attention_layer(u, w, hf))(u, w)
     cut = dataclasses.replace(whole.cfg.kind_cfg("full:none"), num_heads=2,
                               num_kv_heads=1)
+    attention_block = jax.jit(tf.attention_block, static_argnums=(2, 3, 4))
     total = 0.0
     for s in range(8):
         q = np.arange(16 * s, 16 * (s + 1))         # 2 heads of 8
         kv = np.arange(8 * (s // 4), 8 * (s // 4 + 1))
         ws = {"wq": w["wq"][:, q], "wk": w["wk"][:, kv],
               "wv": w["wv"][:, kv], "wo": w["wo"][q]}
-        total = total + tf.attention_block(u[None], ws, cut, None,
-                                           tf.xla_attention)[0]
+        total = total + attention_block(u[None], ws, cut, None,
+                                        tf.xla_attention)[0]
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
 
 
@@ -238,10 +240,11 @@ def test_the_expert_shares_and_the_shared_expert_once_are_the_uncut_layer():
         return tree
 
     rw = {n: leaf(w, names[n]) for n in ref.TENSORS["E"] if n != "norm"}
-    want = jnp.stack([ref.experts(row, rw, hf, held=range(64))[0]
-                      for row in u])
+    want = jax.jit(jax.vmap(
+        lambda row: ref.experts(row, rw, hf, held=range(64))[0]))(u)
     shared = jnp.stack([
         ref.relu2(row @ rw["shared_w1"]) @ rw["shared_w2"] for row in u])
+    block = jax.jit(grouped_moe_mlp_block, static_argnums=2)
     routed = 0.0
     for s in range(8):
         cut = dataclasses.replace(whole.cfg, moe_experts_held=8,
@@ -249,7 +252,7 @@ def test_the_expert_shares_and_the_shared_expert_once_are_the_uncut_layer():
                                   moe_ep_capacity_factor=8.0)
         ws = {**w, "w_up": w["w_up"][8 * s:8 * s + 8],
               "w_down": w["w_down"][8 * s:8 * s + 8]}
-        out, aux = grouped_moe_mlp_block(u, ws, cut)
+        out, aux = block(u, ws, cut)
         assert int(aux["pairs_dropped"]) == 0
         routed = routed + (out - shared)
     np.testing.assert_allclose(routed + shared, want, rtol=2e-4, atol=2e-5)
@@ -279,20 +282,20 @@ def test_eleven_alternating_layers_trace_three_block_bodies(small,
     monkeypatch.setattr(tf, "branch_block", lambda *a: (
         seen.append(a[-1]), block(*a))[1])
     fresh = model_for(hf, remat_policy="full")
-    (loss, parts), _ = jax.value_and_grad(
-        fresh.loss_and_parts, has_aux=True)(params, {"input_ids": ROWS})
+    (loss, parts), _ = jax.jit(jax.value_and_grad(
+        fresh.loss_and_parts, has_aux=True))(params, {"input_ids": ROWS})
     assert seen == ["ssm:none", "none:moe", "full:none"]
     monkeypatch.setattr(tf, "_MAX_PERIOD", 4)       # 11 > 2 x 4: runs
     runs = model_for(hf)
     assert len(runs._layer_plan()) == 11
-    loss2, parts2 = runs.loss_and_parts(params, {"input_ids": ROWS})
+    loss2, parts2 = jax.jit(runs.loss_and_parts)(params, {"input_ids": ROWS})
     assert float(loss2) == pytest.approx(float(loss), rel=1e-6)
     np.testing.assert_allclose(parts2["mix_out_ms"], parts["mix_out_ms"],
                                rtol=1e-5)
     np.testing.assert_array_equal(parts2["router_counts"],
                                   parts["router_counts"])
     unrolled = model_for(hf, scan_layers=False)
-    assert float(unrolled.loss_fn(params, {"input_ids": ROWS})) \
+    assert float(jax.jit(unrolled.loss_fn)(params, {"input_ids": ROWS})) \
         == pytest.approx(float(loss), rel=1e-6)
 
 
@@ -414,7 +417,7 @@ def test_state_space_layers_beside_experts_keep_their_other_refusals():
                       attn_pattern=("ssm", "full"), num_experts=4,
                       moe_dispatch="grouped")
     model = TransformerLM(TransformerConfig(**two_branch))
-    loss, parts = model.loss_and_parts(
+    loss, parts = jax.jit(model.loss_and_parts)(
         model.init(jax.random.key(0)),
         {"input_ids": jnp.zeros((1, 8), jnp.int32)})
     assert np.isfinite(float(loss)) and parts["mix_out_ms"].shape == (2,)
@@ -614,7 +617,7 @@ FAULTS = {
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances on the forward's parts, and the reference
     at a small size (hidden 256, the 11 layers, 2 Mamba groups, 64-token
     rows) on bf16-rounded weights."""
@@ -636,8 +639,9 @@ def cell_check():
     params = _bf16(params)
     rows = list(np.random.default_rng(7).integers(0, 512, (2, 64))
                 .astype(np.int32))
-    return check, hf, params, rows, ref.batch_loss(
-        hf, modelcfg.weights_getter(params, hf), rows, ALPHA)
+    return check, hf, params, rows, run_memo(
+        "nemotron_h_cell_check", lambda: ref.batch_loss(
+            hf, modelcfg.weights_getter(params, hf), rows, ALPHA))
 
 
 def _judged(check, got, want, bias):

@@ -85,7 +85,7 @@ def getter(params, hf):
 def init(model, seed=0):
     """Seeded random weights; the leaves the initialiser sets to a constant
     (the norms' scales) drawn too, so that leaving one out shows."""
-    params = model.init(jax.random.key(seed))
+    params = jax.jit(model.init)(jax.random.key(seed))     # one program, not an op at a time
     keys = iter(jax.random.split(jax.random.key(seed + 100), 8))
     layers = params["layers"]
     for group, name in (("delta", "o_norm"), ("attn", "q_norm"),
@@ -171,11 +171,12 @@ def test_bf16_inputs_keep_the_decays_in_float32(monkeypatch):
 # ---- the model against the reference --------------------------------------
 
 @pytest.fixture(scope="module")
-def small():
+def small(run_memo):
     hf = hf_config()
     model = model_for(hf)
     params = init(model)
-    return hf, model, params, ref.batch_loss(hf, getter(params, hf), ROWS)
+    return hf, model, params, run_memo(
+        "olmo_hybrid_small", lambda: ref.batch_loss(hf, getter(params, hf), ROWS))
 
 
 def test_loss_and_mixer_outputs_match_the_reference(small, monkeypatch):
@@ -289,7 +290,7 @@ def test_a_post_norm_block_of_plain_attention_layers():
     want = h + tf._norm(tf.mlp_block(h, w["mlp"], pre.cfg), w["ln2_post"],
                         "rmsnorm", 1e-5)
     np.testing.assert_allclose(y, want, atol=1e-6)
-    assert np.isfinite(float(model.loss_fn(
+    assert np.isfinite(float(jax.jit(model.loss_fn)(
         params, {"input_ids": ROWS[:, :8] % 64})))
 
 
@@ -496,8 +497,8 @@ def test_zero_stages_shard_the_new_leaves_and_give_the_same_loss(stage):
     model = model_for(hf_config(L=2, types=PAIR))
     rows = np.random.default_rng(4).integers(0, 96, (8, 24)).astype(np.int32)
     eng = _engine(model, stage=stage, rows=8, fsdp=8)
-    want = float(model.loss_fn(jax.device_get(eng.params),
-                               {"input_ids": rows}))
+    want = float(jax.jit(model.loss_fn)(jax.device_get(eng.params),
+                                        {"input_ids": rows}))
     got = float(eng.fused_train_step({"input_ids": rows}))
     assert got == pytest.approx(want, abs=2e-5)
     for leaf in ("wq", "wz", "wo", "conv_v"):
@@ -784,7 +785,7 @@ FAULTS = {
 
 
 @pytest.fixture(scope="module")
-def cell_check():
+def cell_check(run_memo):
     """The cell's own tolerances, and the reference at a small size (hidden
     256, one period, 64-token rows) on bf16-rounded weights."""
     with open(CELL_CONFIG) as f:
@@ -798,8 +799,9 @@ def cell_check():
     params = jax.tree_util.tree_map(
         lambda w: w.astype(jnp.bfloat16).astype(jnp.float32), params)
     rows = np.random.default_rng(7).integers(0, 512, (2, 64)).astype(np.int32)
-    return check, hf, params, rows, ref.batch_loss(hf, getter(params, hf),
-                                                   rows)
+    return check, hf, params, rows, run_memo(
+        "olmo_hybrid_cell_check", lambda: ref.batch_loss(
+            hf, getter(params, hf), rows))
 
 
 def _failed(check, got, want):

@@ -245,7 +245,12 @@ _SPEC = {"enabled": True, "ngram": 2, "max_draft": 4, "fallback_steps": 4}
 
 
 def _engine(model, params, **kw):
-    base = dict(max_sequences=8, max_seq_len=128, block_size=16)
+    """The cache's, the drafter's and the tiers' cases test what the store
+    does, so their engines attend with the XLA twin; ``tier_eng`` keeps the
+    kernel (interpreted here), with which promotions ride the step's own
+    dispatch."""
+    base = dict(max_sequences=8, max_seq_len=128, block_size=16,
+                decode_kernel="xla")
     base.update(kw)
     return InferenceEngineV2(model, params=params, **base)
 
@@ -1169,7 +1174,7 @@ def tier_eng(f32_lm, tmp_path_factory):
     nvme = tmp_path_factory.mktemp("kv_tier_nvme")
     # host budget ~2 blocks (tiny block = 2*16*64*4*2 bytes) so a few
     # demotions reach NVMe too
-    eng = _engine(model, params, num_blocks=24,
+    eng = _engine(model, params, num_blocks=24, decode_kernel="pallas",
                   prefix_cache={"enabled": True,
                                 "tiers": {"enabled": True,
                                           "host_mb": 2 * 16384 / 2**20,

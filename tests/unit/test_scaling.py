@@ -1,9 +1,8 @@
 """Mesh cost model + autotuner v2 + scaling-harness tests.
 
 Fast tier: enumeration legality/determinism, calibration round-trip,
-winner-store semantics, the ``mesh: "auto"`` config path, the Autotuner
-engine-lifecycle regression, and the ``bench_scaling`` /
-``bench_capacity`` trend series. The ``scaling``+``slow`` wrapper runs a
+winner-store semantics, the ``mesh: "auto"`` config path and the Autotuner
+engine-lifecycle regression. The ``scaling``+``slow`` wrapper runs a
 real tiny 2-world sweep through the harness (the drill CLI
 ``tools/scaling_drill.py`` is the full-loop authority).
 """
@@ -208,6 +207,46 @@ class TestCostModel:
         ranked = cm.rank_by_throughput(p, [{"tp": 8}, {"dp": 8}],
                                        micro_batch=2)
         assert ranked[0][0] == {"dp": 8}
+
+    def test_mesh_auto_ranks_by_the_default_rates_and_opens_no_file(
+            self, tmp_path, monkeypatch, eight_devices):
+        """With no cost model given, ``mesh: "auto"``'s resolution and the
+        autotuner's mesh axis rank by ``CostModel()``, and read nothing
+        outside the winner store they were pointed at."""
+        import builtins
+
+        from deepspeed_tpu.autotuning import Autotuner
+        from deepspeed_tpu.autotuning.mesh_store import resolve_auto_axis_sizes
+        from deepspeed_tpu.models import TransformerLM, get_preset
+        from deepspeed_tpu.parallel.cost_model import (CostModel, ModelProfile,
+                                                       enumerate_meshes)
+
+        real_open = builtins.open
+        # its own directory, and the run's compile cache (tests/conftest.py)
+        mine = (str(tmp_path), os.environ.get("JAX_COMPILATION_CACHE_DIR", str(tmp_path)))
+
+        def fenced(path, *a, **kw):
+            if not str(path).startswith(mine):
+                raise AssertionError(f"opened {path}")
+            return real_open(path, *a, **kw)
+
+        monkeypatch.setattr(builtins, "open", fenced)
+        p = _profile()
+        want = CostModel().rank_by_throughput(
+            p, enumerate_meshes(8, p), zero_stage=3, micro_batch=2)
+        got = resolve_auto_axis_sizes(8, p, zero_stage=3, micro_batch=2,
+                                      winner_cache=str(tmp_path / "w.json"))
+        assert got == (want[0][0] or {"dp": 8})
+        assert CostModel().bw.calibrated_from == 0
+
+        tuner = Autotuner(lambda: TransformerLM(get_preset("tiny")), {},
+                          micro_batch_candidates=(2,),
+                          zero_stage_candidates=(3,), mesh_candidates="auto",
+                          mesh_top_k=3, make_batch=lambda n: None)
+        prof = ModelProfile.from_model(TransformerLM(get_preset("tiny")))
+        want = CostModel().rank_by_throughput(
+            prof, enumerate_meshes(8, prof), zero_stage=3, micro_batch=2)
+        assert tuner._resolved_mesh_candidates() == [m for m, _ in want[:3]]
 
 
 # ---------------------------------------------------------------------------
@@ -488,116 +527,11 @@ class TestSchedulerWriteback:
 
 
 # ---------------------------------------------------------------------------
-# trend gate: the bench_scaling + per-device capacity series
-# ---------------------------------------------------------------------------
-class TestScalingTrendSeries:
-    def _scaling_entry(self, sha, curves, device="cpu"):
-        return {"schema": 1, "bench": "bench_scaling", "git_sha": sha,
-                "time": 1, "iso_time": "x",
-                "metric": "scaling_tokens_per_sec_per_chip", "value": None,
-                "unit": "tokens/s/chip",
-                "result": {"device": device, "curves": {device: {
-                    shape: {w: {"tokens_per_sec_per_chip": tps,
-                                "parallel_efficiency": eff}
-                            for w, (tps, eff) in pts.items()}
-                    for shape, pts in curves.items()}}}}
-
-    def test_per_shape_world_series_gate(self):
-        from bench_trend import compare
-
-        a = self._scaling_entry("a", {
-            "fsdp": {"w2": (100.0, 0.9), "w8": (80.0, 0.7)},
-            "dp": {"w2": (110.0, 1.0)}})
-        # fsdp@w8 regresses 40%; dp@w2 holds; fsdp@w2 unmeasured → no gate
-        b = self._scaling_entry("b", {
-            "fsdp": {"w8": (48.0, 0.42)},
-            "dp": {"w2": (108.0, 0.99)}})
-        v = compare([a, b], threshold=0.15)
-        regressed = {r["metric"] for r in v["regressions"]}
-        assert "curves.cpu.fsdp.w8.tokens_per_sec_per_chip" in regressed
-        assert "curves.cpu.fsdp.w8.parallel_efficiency" in regressed
-        assert not any("fsdp.w2" in m for m in regressed)
-        assert not any(".dp." in m for m in regressed)
-        assert not v["ok"]
-
-    def test_scaling_series_is_per_device(self):
-        # a fast TPU sweep entry must not become the "best prior" a
-        # CPU-harness run gates against (same split as capacity)
-        from bench_trend import compare
-
-        cpu = self._scaling_entry("c1", {"dp": {"w8": (150.0, 0.8)}})
-        tpu = self._scaling_entry(
-            "t1", {"dp": {"w8": (24000.0, 0.9)}}, device="TPU v5e")
-        cpu2 = self._scaling_entry("c2", {"dp": {"w8": (145.0, 0.78)}})
-        assert compare([cpu, tpu, cpu2], threshold=0.15)["ok"]
-        # a genuine same-device drop still gates
-        cpu3 = self._scaling_entry("c3", {"dp": {"w8": (60.0, 0.3)}})
-        assert not compare([cpu, tpu, cpu2, cpu3], threshold=0.15)["ok"]
-
-    def test_ledger_samples_include_baselines_and_filter_device(self):
-        from deepspeed_tpu.parallel.cost_model import samples_from_ledger
-
-        pt = {"step_ms": 100.0, "predicted": {"flops": 1e9,
-                                              "ici_bytes": 1e6,
-                                              "dcn_bytes": 0,
-                                              "bubble_frac": 0.0}}
-        def entry(device):
-            return {"schema": 1, "bench": "bench_scaling",
-                    "result": {"device": device,
-                               "curves": {device: {"fsdp":
-                                                   {"w2": dict(pt)}}},
-                               "baselines": {"dense": dict(pt)}}}
-        # the zero-comm w=1 baselines anchor the flops/overhead split —
-        # the ledger-backed refit must see the same points the sweep's
-        # own in-process calibration used
-        assert len(samples_from_ledger([entry("cpu")])) == 2
-        # and the fit never mixes device kinds: CPU and TPU rates are
-        # orders of magnitude apart — one fit over both fits neither
-        both = [entry("cpu"), entry("TPU v5e")]
-        assert len(samples_from_ledger(both, device="cpu")) == 2
-        assert len(samples_from_ledger(both)) == 4
-
-    def test_capacity_series_is_per_device(self):
-        from bench_trend import compare
-
-        old = {"schema": 1, "bench": "bench_capacity", "git_sha": "tpu",
-               "time": 1, "iso_time": "x", "metric": "m", "value": None,
-               "unit": None, "result": {"best": {"params_b": 0.81}}}
-        dev = {"schema": 1, "bench": "bench_capacity", "git_sha": "cpu",
-               "time": 2, "iso_time": "x", "metric": "m", "value": None,
-               "unit": None,
-               "result": {"best": {"params_b": 0.05},
-                          "by_device": {"cpu": {"dev":
-                                                {"params_b": 0.05}}}}}
-        # a CPU dev-ladder restatement after a TPU figure is a NEW series,
-        # not a 94% regression of the old one
-        v = compare([old, dev], threshold=0.15)
-        assert v["ok"], v
-        # the dev ladder tops out lower than the full ladder even on one
-        # device — a full-ladder figure must not gate a dev-ladder run
-        full = json.loads(json.dumps(dev))
-        full["git_sha"] = "cpu-full"
-        full["result"]["by_device"]["cpu"] = {"full": {"params_b": 0.8}}
-        assert compare([old, full, dev], threshold=0.15)["ok"]
-        # but a genuine drop within the same (device, ladder) still gates
-        dev2 = json.loads(json.dumps(dev))
-        dev2["git_sha"] = "cpu2"
-        dev2["result"]["by_device"]["cpu"]["dev"]["params_b"] = 0.01
-        v2 = compare([old, dev, dev2], threshold=0.15)
-        assert not v2["ok"]
-        assert v2["regressions"][0]["metric"] == \
-            "by_device.cpu.dev.params_b"
-
-
-# ---------------------------------------------------------------------------
 # the real thing (slow): a tiny 2-world sweep through the harness
 # ---------------------------------------------------------------------------
 @pytest.mark.scaling
 @pytest.mark.slow
-def test_tiny_two_world_sweep(tmp_path, monkeypatch, eight_devices):
-    from bench_ledger import append_ledger, read_ledger
-    from bench_trend import compare
-
+def test_tiny_two_world_sweep(eight_devices):
     from deepspeed_tpu.autotuning.scaling import run_sweep
 
     res = run_sweep(worlds=(1, 2), shapes=("dp", "fsdp"), steps=2)
@@ -612,16 +546,6 @@ def test_tiny_two_world_sweep(tmp_path, monkeypatch, eight_devices):
     assert curves["fsdp"]["w2"]["comm_bytes_per_step"].get(
         "reduce_scatter", 0) > 0
     assert res["calibration"]["calibrated_from"] >= 3
-
-    # the entry is ledger-appendable and bench_trend-readable
-    path = str(tmp_path / "ledger.jsonl")
-    monkeypatch.setenv("DSTPU_BENCH_LEDGER_PATH", path)
-    assert append_ledger(res, "bench_scaling") == path
-    assert append_ledger(res, "bench_scaling") == path
-    v = compare(read_ledger(path), threshold=0.15)
-    mets = {c["metric"] for c in v["comparisons"]}
-    assert f"curves.{res['device']}.dp.w2.tokens_per_sec_per_chip" in mets
-    assert v["ok"]
 
 
 @pytest.mark.scaling

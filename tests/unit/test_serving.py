@@ -132,8 +132,11 @@ def tiny_engine():
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
     from deepspeed_tpu.models import TransformerLM, get_preset
 
+    # what the batcher and the manager do: the XLA twin attends (the SLO
+    # tentpole case below keeps the kernel, interpreted here)
     return InferenceEngineV2(TransformerLM(get_preset("tiny")),
-                             max_sequences=8, max_seq_len=128, block_size=16)
+                             max_sequences=8, max_seq_len=128, block_size=16,
+                             decode_kernel="xla")
 
 
 def test_put_overload_raises_typed_capacity_error(tiny_engine):
@@ -283,7 +286,7 @@ def test_prefix_aware_admission_admits_mostly_cached_request():
 
     eng = InferenceEngineV2(TransformerLM(get_preset("tiny")),
                             max_sequences=8, max_seq_len=128, block_size=16,
-                            prefix_cache=True)
+                            prefix_cache=True, decode_kernel="xla")
     # warm the cache: 96-token prompt -> 6 published blocks (80 attachable
     # under the len-1 cap)
     shared = np.arange(96) % 250
@@ -322,7 +325,7 @@ def test_prefix_aware_admission_admits_mostly_cached_request():
 # SLO tiers + preemptible requests (pause/resume through the KV tier store)
 # ---------------------------------------------------------------------------
 
-def _slo_batcher(**serving):
+def _slo_batcher(decode_kernel="xla", **serving):
     """fp32 engine (bit-identical greedy across pause/resume) + a batcher
     with the SLO block enabled."""
     from deepspeed_tpu.inference.engine_v2 import InferenceEngineV2
@@ -330,7 +333,8 @@ def _slo_batcher(**serving):
 
     eng = InferenceEngineV2(
         TransformerLM(get_preset("tiny", dtype="float32")),
-        max_sequences=8, max_seq_len=128, block_size=16)
+        max_sequences=8, max_seq_len=128, block_size=16,
+        decode_kernel=decode_kernel)
     cfg = ServingConfig(**{
         "prefill_chunk": 32, "default_max_new_tokens": 8,
         "slo": {"enabled": True, "preempt": True}, **serving})
@@ -343,7 +347,7 @@ class TestSLOPreemption:
         """Tentpole invariant: pause -> demote through the tier store ->
         promote -> resume reproduces the EXACT greedy token sequence of an
         unpreempted run (fp32; KV bytes round-trip unquantized)."""
-        b = _slo_batcher()
+        b = _slo_batcher(decode_kernel="pallas")
         rng = np.random.default_rng(7)
         prompt = list(rng.integers(0, 250, 40))
         base_uid = b.submit(prompt, max_new_tokens=8, tier="batch")
@@ -555,7 +559,7 @@ def test_serve_drill_scenario(scenario, tmp_path):
 
 @pytest.mark.slo
 @pytest.mark.slow
-def test_serve_drill_slo_storm(tmp_path, monkeypatch):
+def test_serve_drill_slo_storm(tmp_path):
     """Tier-1 authority for the preemption subsystem: zero latency-tier
     sheds under a preempt storm, >= 1 pause -> resume round-trip, streams
     bit-identical to an injection-free replay, pools/store restored."""
@@ -564,6 +568,5 @@ def test_serve_drill_slo_storm(tmp_path, monkeypatch):
     sys.path.insert(0, _TOOLS)
     from serve_drill import run_scenario
 
-    monkeypatch.setenv("DSTPU_BENCH_LEDGER", "0")
     verdict = run_scenario("slo-storm", workdir=str(tmp_path))
     assert verdict["ok"], verdict

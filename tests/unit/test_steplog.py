@@ -453,8 +453,16 @@ def test_a_setup_row_carries_the_samples_of_both_ends():
         pass
     row = steplog.setup()[-1]
     assert row["start"] == early[0] and row["host_start"][1] == early[1]
-    first = steplog.setup()[0]
-    assert first["name"] == "ds.setup.import" and first["host_end"] is not None
+    # the package's import is the process's first set-up span, whatever ran
+    # in this worker since; the record keeps the last 256 rows, so after
+    # enough engines it no longer lists it
+    import deepspeed_tpu
+
+    first = deepspeed_tpu._import_span.row
+    assert first["id"] == 1 and first["name"] == "ds.setup.import"
+    assert first["host_end"] is not None
+    rows = steplog.setup()
+    assert rows[0]["id"] == 1 or len(rows) == 256
 
 
 class _Seen:

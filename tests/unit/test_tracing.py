@@ -1,6 +1,6 @@
-"""Causal event tracing + flight recorder + perf-trend ledger (ISSUE 13).
+"""Causal event tracing + flight recorder (ISSUE 13).
 
-Four layers:
+Three layers:
 
 * event-bus semantics — disabled no-op, deterministic sampling, span
   pairing on every exit path, ring boundedness under an event storm,
@@ -10,9 +10,7 @@ Four layers:
   (every B matched on its tid, async ids balanced);
 * the flight recorder — dump contents, the exactly-once ``key=`` guard,
   and the bounded-ledger fix (a uid evicted from ``RequestManager.done``
-  still resolves through the recorder's retained terminal spans);
-* the perf-trend ledger — append/read round-trip and the
-  ``bench_trend`` regression gate's verdicts + exit codes.
+  still resolves through the recorder's retained terminal spans).
 
 Slow wrappers at the bottom run ``tools/trace_drill.py`` (storm trace,
 abort dump, disabled-no-events) and the ``obs_drill`` tracing-overhead
@@ -338,118 +336,6 @@ def test_traced_serving_chain_and_http_export(tmp_path):
     finally:
         configure_tracing(enabled=False)
         bus.clear()
-
-
-# ---------------------------------------------------------------------------
-# perf-trend ledger + bench_trend gate
-# ---------------------------------------------------------------------------
-class TestBenchLedger:
-    def _entry(self, bench, value, sha, t, result=None):
-        return {"schema": 1, "bench": bench, "git_sha": sha, "time": t,
-                "iso_time": "x", "metric": "m", "value": value,
-                "unit": "u", "result": result or {"value": value}}
-
-    def test_append_and_read_roundtrip(self, tmp_path, monkeypatch):
-        from bench_ledger import append_ledger, read_ledger
-
-        path = str(tmp_path / "ledger.jsonl")
-        monkeypatch.setenv("DSTPU_BENCH_LEDGER_PATH", path)
-        out = append_ledger({"metric": "m", "value": 1.5, "unit": "u"},
-                            "bench")
-        assert out == path
-        # a corrupt line (interrupted append) must not poison the read
-        with open(path, "a") as f:
-            f.write('{"schema": 1, "bench": "tru\n')
-        append_ledger({"metric": "m", "value": 2.0, "unit": "u"}, "bench")
-        entries = read_ledger(path)
-        assert [e["value"] for e in entries] == [1.5, 2.0]
-        assert all(e["git_sha"] for e in entries)
-
-    def test_env_kill_switch(self, tmp_path, monkeypatch):
-        from bench_ledger import append_ledger
-
-        path = str(tmp_path / "ledger.jsonl")
-        monkeypatch.setenv("DSTPU_BENCH_LEDGER_PATH", path)
-        monkeypatch.setenv("DSTPU_BENCH_LEDGER", "0")
-        assert append_ledger({"value": 1}, "bench") is None
-        assert not os.path.exists(path)
-
-    def test_trend_passes_within_threshold(self):
-        from bench_trend import compare
-
-        entries = [self._entry("bench", 100.0, "a", 1),
-                   self._entry("bench", 110.0, "b", 2),
-                   self._entry("bench", 104.0, "c", 3)]   # -5.4% vs best
-        v = compare(entries, threshold=0.10)
-        assert v["ok"] and len(v["comparisons"]) == 1
-        assert v["comparisons"][0]["best_prior"] == 110.0
-
-    def test_trend_fails_past_threshold(self):
-        from bench_trend import compare
-
-        entries = [self._entry("bench", 100.0, "a", 1),
-                   self._entry("bench", 70.0, "b", 2)]    # -30%
-        v = compare(entries, threshold=0.15)
-        assert not v["ok"]
-        assert v["regressions"][0]["latest_sha"] == "b"
-
-    def test_trend_wildcard_compares_per_config(self):
-        # each measured config is its own series: runs with DIFFERENT
-        # config sets must not be compared as a max across the set
-        from bench_trend import compare
-
-        def infer(sha, decode):
-            return self._entry(
-                "bench_infer", None, sha, 1,
-                result={"prefill_tokens_per_sec": 1.0,
-                        "decode": {k: {"tokens_per_sec": v}
-                                   for k, v in decode.items()}})
-
-        v = compare([infer("a", {"32": 100.0, "128": 50.0}),
-                     infer("b", {"32": 90.0, "128": 48.0})],
-                    threshold=0.15)
-        mets = {c["metric"]: c for c in v["comparisons"]}
-        assert mets["decode.32.tokens_per_sec"]["latest"] == 90.0
-        assert mets["decode.32.tokens_per_sec"]["best_prior"] == 100.0
-        assert mets["decode.128.tokens_per_sec"]["latest"] == 48.0
-        assert v["ok"]
-        # a config the latest run SKIPPED is "no data", not a regression
-        # (and a fast sibling config cannot mask a slow one)
-        v2 = compare([infer("a", {"32": 100.0, "128": 14000.0}),
-                      infer("b", {"32": 60.0})], threshold=0.15)
-        mets2 = {c["metric"] for c in v2["comparisons"]}
-        assert "decode.128.tokens_per_sec" not in mets2
-        assert not v2["ok"]               # the real 40% drop on "32" gates
-
-    def test_trend_cli_exit_codes(self, tmp_path):
-        import subprocess
-
-        ledger = tmp_path / "l.jsonl"
-        rows = [self._entry("bench", 100.0, "a", 1),
-                self._entry("bench", 50.0, "b", 2)]
-        ledger.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        cli = os.path.join(TOOLS, "bench_trend.py")
-        r = subprocess.run([sys.executable, cli, "--ledger", str(ledger)],
-                           capture_output=True, text=True, timeout=60)
-        assert r.returncode == 1, r.stdout + r.stderr   # 50% drop
-        r = subprocess.run([sys.executable, cli, "--ledger", str(ledger),
-                            "--threshold", "0.6"],
-                           capture_output=True, text=True, timeout=60)
-        assert r.returncode == 0, r.stdout + r.stderr
-        r = subprocess.run([sys.executable, cli, "--ledger",
-                            str(tmp_path / "missing.jsonl")],
-                           capture_output=True, text=True, timeout=60)
-        assert r.returncode == 0                        # no data = no gate
-
-    def test_checked_in_ledger_parses_and_gates(self):
-        # the seeded trajectory (round artifacts) must stay loadable and
-        # pass its own gate at the shipped threshold
-        from bench_ledger import read_ledger
-        from bench_trend import compare
-
-        entries = read_ledger()
-        assert len(entries) >= 5
-        assert compare(entries, threshold=0.15)["ok"]
 
 
 # ---------------------------------------------------------------------------

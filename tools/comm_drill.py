@@ -27,9 +27,7 @@
 
 Exit code 0 = invariants held; 1 = violated (details on stdout as JSON).
 Slow pytest wrappers live in ``tests/unit/test_zeropp.py`` under the
-``zpp`` + ``slow`` markers. ``bench.py --zero-pp`` reuses
-:func:`measure_pair` to record comm-bytes and step-time into the bench
-ledger.
+``zpp`` + ``slow`` markers.
 """
 
 from __future__ import annotations
@@ -157,7 +155,7 @@ def scenario_bytes(workdir=None):
 
 
 # ---------------------------------------------------------------------------
-# shared fsdp-training comparison (parity scenario + bench.py --zero-pp)
+# the fsdp-training comparison the parity scenario makes
 # ---------------------------------------------------------------------------
 
 def _train(zero_pp, steps=5, seed=0, mesh=None, timing=False):
@@ -208,7 +206,7 @@ def _train(zero_pp, steps=5, seed=0, mesh=None, timing=False):
 
 def measure_pair(steps=5, quant=None, mesh=None, timing=True):
     """Baseline (explicit dense bf16 collectives) vs quantized run — the
-    shared body of the parity drill and the ``bench.py`` zero_pp section."""
+    body of the parity drill."""
     quant = quant or {"enabled": True, "qwz": True, "qgz": True,
                       "hpz": True, "hpz_partition_size": 2,
                       "weight_bits": 4, "grad_bits": 8}
@@ -297,8 +295,7 @@ def scenario_moe_a2a(workdir=None):
     form (dense / int8 / int4 / hierarchical two-hop) logs exactly the
     analytic payload of ``moe_a2a_wire_bytes``, and a full traced
     ``_grouped_moe_ep`` dispatch (x out, ids out, y back) decomposes into
-    those same terms — the instrument the bench_moe ledger rides is
-    itself pinned."""
+    those same terms."""
     import types
 
     import jax

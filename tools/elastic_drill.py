@@ -30,10 +30,9 @@ Invariants asserted after EVERY drill:
     python tools/elastic_drill.py --all
 
 Exit code 0 = invariants held; 1 = violated (details on stdout as JSON).
-Scenarios that measure (cold/warm start, drain->rejoin) append a
-``bench_elastic`` entry to the perf ledger (``tools/bench_ledger.py``),
-gated by ``tools/bench_trend.py`` on the higher-is-better restatements
-(``warm_speedup``, ``rejoin_per_sec``). Slow pytest wrappers live in
+Scenarios that measure (cold/warm start, drain->rejoin) print what they
+timed on this host under ``bench`` in their details; nothing records or
+gates it. Slow pytest wrappers live in
 ``tests/unit/test_fleet.py`` under the ``elastic`` + ``slow`` markers.
 """
 
@@ -433,8 +432,6 @@ def main(argv=None) -> int:
     ap.add_argument("--scenario", help="which drill to run")
     ap.add_argument("--all", action="store_true", help="run every scenario")
     ap.add_argument("--list", action="store_true", help="list scenarios")
-    ap.add_argument("--no-ledger", action="store_true",
-                    help="skip the bench_elastic perf-ledger append")
     args = ap.parse_args(argv)
     if args.list:
         for name, fn in SCENARIOS.items():
@@ -445,22 +442,11 @@ def main(argv=None) -> int:
     if not names:
         ap.error("pass --scenario NAME, --all, or --list")
     rc = 0
-    bench = {}
     for name in names:
         verdict = run_scenario(name)
         print(json.dumps(verdict, indent=2, default=str))
         if not verdict["ok"]:
             rc = 1
-        for k, v in (verdict["details"].get("bench") or {}).items():
-            if v is not None:
-                bench[k] = v
-    if bench and rc == 0 and not args.no_ledger:
-        from bench_ledger import append_ledger
-
-        result = {"metric": "warm_speedup",
-                  "value": bench.get("warm_speedup"), "unit": "x", **bench}
-        path = append_ledger(result, "bench_elastic")
-        print(json.dumps({"ledger": path, "bench_elastic": bench}))
     return rc
 
 

@@ -19,9 +19,9 @@
 
 Exit code 0 = invariants held; 1 = violated (details on stdout as JSON).
 Slow pytest wrappers live in ``tests/unit/test_scaling.py`` under the
-``scaling`` + ``slow`` markers. The measured scaling CURVES (tokens/s/chip
-vs world size) are ``bench.py --scaling``'s job, not this drill's — the
-drill asserts the decision loop, the bench records the artifact.
+``scaling`` + ``slow`` markers. The drill asserts the decision loop; the
+scaling curves themselves (tokens/s/chip vs world size) are measured nowhere
+yet (``autotuning/scaling.py:run_sweep`` would, on a pod).
 """
 
 from __future__ import annotations

@@ -24,11 +24,9 @@ Invariants asserted after EVERY drill:
     python tools/serve_drill.py --scenario moe-storm
 
 Exit code 0 = invariants held; 1 = violated (details on stdout as JSON).
-A passing ``slo-storm`` run appends a ``bench_slo`` entry (preemption
-counters, resume success rate) to the perf ledger (``tools/
-bench_ledger.py``) unless ``--no-ledger``; a passing ``crash-migrate``
-run appends a ``bench_migration`` entry (migration success rate, resumed
-tokens/s); ``tools/bench_trend.py`` gates on both. Slow pytest wrappers
+``slo-storm`` (preemption counters, resume success rate) and
+``crash-migrate`` (migration success rate, resumed tokens/s) print what they
+counted under ``bench`` in their details. Slow pytest wrappers
 live in ``tests/unit/test_serving.py`` under the ``serving`` + ``slow``
 markers (``slo`` for the SLO drill, ``migrate`` for the migration
 drill in ``tests/unit/test_migration.py``).
@@ -885,7 +883,7 @@ def scenario_moe_storm(workdir):
                                     "device_count=8"))
             p = subprocess.run(
                 [sys.executable, os.path.abspath(__file__),
-                 "--scenario", "moe-storm", "--no-ledger"],
+                 "--scenario", "moe-storm"],
                 env=env, capture_output=True, text=True, timeout=1800)
             if not os.path.exists(out):
                 return False, {"error": "moe-storm child produced no "
@@ -1029,8 +1027,6 @@ def main(argv=None) -> int:
     ap.add_argument("--scenario", help="which drill to run")
     ap.add_argument("--all", action="store_true", help="run every scenario")
     ap.add_argument("--list", action="store_true", help="list scenarios")
-    ap.add_argument("--no-ledger", action="store_true",
-                    help="skip the bench_slo perf-ledger append")
     args = ap.parse_args(argv)
     if args.list:
         for name, fn in SCENARIOS.items():
@@ -1046,20 +1042,6 @@ def main(argv=None) -> int:
         print(json.dumps(verdict, indent=2, default=str))
         if not verdict["ok"]:
             rc = 1
-        elif name == "slo-storm" and not args.no_ledger:
-            from bench_ledger import append_ledger
-
-            path = append_ledger(verdict["details"]["bench"], "bench_slo")
-            print(json.dumps({"ledger": path,
-                              "bench_slo": verdict["details"]["bench"]}))
-        elif name == "crash-migrate" and not args.no_ledger:
-            from bench_ledger import append_ledger
-
-            path = append_ledger(verdict["details"]["bench"],
-                                 "bench_migration")
-            print(json.dumps({"ledger": path,
-                              "bench_migration":
-                                  verdict["details"]["bench"]}))
     return rc
 
 
