@@ -41,9 +41,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.transformer import _norm
 from deepspeed_tpu.ops.causal_conv import causal_conv_silu
-from deepspeed_tpu.ops.kda_rule import chunked_kda_rule
+from deepspeed_tpu.ops.head_norm_gate import head_norm_gate
+from deepspeed_tpu.ops.kda_rule import kda_rule_lanes
 
 F32 = jnp.float32
 #: what the sum of squares of a head's q or k is raised by before its root
@@ -113,13 +113,15 @@ def param_specs() -> Dict[str, Any]:
 
 
 def decay_log(f: jax.Array, A_log: jax.Array, dt_bias: jax.Array,
-              lower: float, H: int) -> jax.Array:
-    """The gate's logarithm ``g`` [B, T, H, dk] in (``lower``, 0), float32,
-    from the projection ``f`` [B, T, H dk]."""
-    B, T, _ = f.shape
-    z = (f.astype(F32) + dt_bias.astype(F32)).reshape(B, T, H, -1)
+              lower: float) -> jax.Array:
+    """The gate's logarithm ``g`` [B, T, H dk] in (``lower``, 0), float32,
+    from the projection ``f`` [B, T, H dk]: a head's rate ``exp(A_log[h])``
+    on each of its ``dk`` lanes, the heads side by side as ``wf``'s product
+    wrote them and as the rule's kernels read them."""
+    rate = jnp.repeat(jnp.exp(A_log.astype(F32)),
+                      f.shape[-1] // A_log.shape[0])
     return lower * jax.nn.sigmoid(
-        jnp.exp(A_log.astype(F32))[:, None] * z)
+        rate * (f.astype(F32) + dt_bias.astype(F32)))
 
 
 def kda_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
@@ -130,11 +132,13 @@ def kda_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     then the chunked rule, which takes the norms of q and k: on a TPU two
     Mosaic kernels that read q, k, v, ``g`` and ``beta`` and write o, the
     einsum form elsewhere; ``ops/kda_rule.py:kda_lowering``) and
-    ``kda_gate`` (the per-head norm and the head-wise gate), inside the
-    caller's ``attn``."""
-    B, T, _ = u.shape
-    s, dk, dv = sizes(cfg), cfg.delta_key_dim, cfg.delta_value_dim
-    H = s["heads"]
+    ``kda_gate`` (the per-head norm and the head-wise gate as one op on o
+    [B, T, H dv], a row's heads side by side as the rule's kernels wrote them
+    and as ``wo`` reads them: on a TPU two Mosaic row kernels, forward and
+    backward, so that neither o nor its cotangent is ever tiled over the
+    heads; the ``jax.numpy`` lines elsewhere;
+    ``ops/head_norm_gate.py:gate_lowering``), inside the caller's ``attn``."""
+    dk = cfg.delta_key_dim
     with jax.named_scope("kda_proj"):
         q, k, v, f = (u @ w[n] for n in ("wq", "wk", "wv", "wf"))
         b, z = u @ w["wb"], u @ w["wg"]
@@ -144,16 +148,11 @@ def kda_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
         v = causal_conv_silu(v, w["conv_v"], out_dtype=u.dtype)
     with jax.named_scope("kda_scan"):
         beta = jax.nn.sigmoid(b.astype(F32))
-        g = decay_log(f, w["A_log"], w["dt_bias"], cfg.kda_lower_bound, H)
-        o = chunked_kda_rule(
-            q.reshape(B, T, H, dk), k.reshape(B, T, H, dk),
-            v.reshape(B, T, H, dv), g, beta,
-            unit=(1.0 / math.sqrt(dk), L2_EPS))
+        g = decay_log(f, w["A_log"], w["dt_bias"], cfg.kda_lower_bound)
+        o = kda_rule_lanes(q, k, v, g, beta,
+                           unit=(1.0 / math.sqrt(dk), L2_EPS))
     with jax.named_scope("kda_gate"):
         # the norm first, over a head's dv channels, then the head's gate
-        o = _norm(o.astype(F32), {"scale": w["o_norm"]}, "rmsnorm",
-                  cfg.norm_eps)
-        y = (o * jax.nn.sigmoid(z.astype(F32))[..., None]
-             ).astype(u.dtype).reshape(B, T, H * dv)
+        y = head_norm_gate(o, z, w["o_norm"], cfg.norm_eps)
     with jax.named_scope("kda_proj"):
         return y @ w["wo"]

@@ -79,10 +79,12 @@ can see: backend, dtype, widths):
   decayed operand ``x exp(+-G)`` sends ``+-`` itself times its cotangent to
   ``G`` (no array a pair of positions), then one reversed running sum.
   Outside the kernels stay the gate's ``g`` and ``beta`` themselves
-  (``models/kda.py``) and what XLA copies where a neighbour works on
-  ``[B, T, H, d]`` (tiled over the heads) and not on ``[B, T, H d]``;
-  ``beta`` [B, T, H] goes in as it is, every head's column held across the
-  grid's head axis.
+  (``models/kda.py``); ``beta`` [B, T, H] goes in as it is, every head's
+  column held across the grid's head axis. The kernels' own layout,
+  ``[B, T, H d]``, is an entry of its own (:func:`kda_rule_lanes`, which
+  ``models/kda.py`` calls): a neighbour that works on ``[B, T, H, d]``
+  (tiled over the heads: other bytes on a TPU) costs a copy each way, and
+  :func:`chunked_kda_rule` is that entry between two reshapes.
 
 The rules traced are counted by lowering (``ops/lowerings.py``, site
 ``kda_scan``) for the step-program table: one for a rule, one more for the
@@ -333,19 +335,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
 
 
 def _prepare(q, k, v, g, beta):
-    """The kernels' operands from the rule's: padded to whole grid steps
-    (positions of ``k = 0``, ``g = 0``), q, k, v and ``g`` with the heads
-    side by side in lanes as the projections wrote them, ``beta`` [B, T, H]
-    float32."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
+    """The kernels' operands from the rule's, all with the heads side by side
+    in lanes as the projections wrote them (q, k, ``g`` [B, T, H dk], v
+    [B, T, H dv], ``beta`` [B, T, H]): padded to whole grid steps (positions
+    of ``k = 0``, ``g = 0``), ``g`` and ``beta`` float32."""
+    B, T, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
     pairs = min(_CHUNKS_A_STEP // 2, -(-T // _PAIR))
     q, k, v, g, beta = _pad_to_chunks(T, pairs * _PAIR, q, k, v, g, beta)
-    Tp = q.shape[1]
     consts = tuple(jnp.asarray(m, jnp.bfloat16)
                    for m in _layout_constants(2 * pairs))
-    return tuple(a.reshape(B, Tp, -1) for a in (q, k, v, g.astype(F32))) \
-        + (beta.astype(F32),) + consts, (B, T, Tp, H, dk, dv, pairs)
+    return (q, k, v, g.astype(F32), beta.astype(F32)) + consts, \
+        (B, T, q.shape[1], H, dk, dv, pairs)
 
 
 def _specs(H, dk, dv, pairs, flip=None):
@@ -372,8 +373,9 @@ def _specs(H, dk, dv, pairs, flip=None):
 @functools.partial(jax.jit, static_argnames=("states", "unit", "interpret"))
 def kda_fwd(q, k, v, g, beta, *, states: bool, unit=None,
             interpret: bool = False):
-    """``o`` [B, T, H, dv] and, where ``states``, each chunk's incoming
-    state [B, H, N, dk, dv] float32 (else None)."""
+    """``o`` [B, T, H dv] from q, k, ``g`` [B, T, H dk], v [B, T, H dv] and
+    ``beta`` [B, T, H] and, where ``states``, each chunk's incoming state
+    [B, H, N, dk, dv] float32 (else None)."""
     ops, (B, T, Tp, H, dk, dv, pairs) = _prepare(q, k, v, g, beta)
     L = pairs * _PAIR
     keys, vals, steps, state, consts = _specs(H, dk, dv, pairs)
@@ -390,7 +392,7 @@ def kda_fwd(q, k, v, g, beta, *, states: bool, unit=None,
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((H, dk, dv), F32)],
         interpret=interpret)(*ops)
-    return out[0].reshape(B, Tp, H, dv)[:, :T], (out[1] if states else None)
+    return out[0][:, :T], (out[1] if states else None)
 
 
 def _tile_rows(x, left):
@@ -528,8 +530,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
 @functools.partial(jax.jit, static_argnames=("unit", "interpret"))
 def kda_bwd(q, k, v, g, beta, s_in, do, *, unit=None,
             interpret: bool = False):
-    """The five cotangents of :func:`kda_fwd`'s ``o`` from ``do`` and the
-    chunks' incoming states ``s_in``."""
+    """The five cotangents of :func:`kda_fwd`'s ``o`` from ``do`` [B, T,
+    H dv] and the chunks' incoming states ``s_in``, each as its operand
+    came."""
     ops, (B, T, Tp, H, dk, dv, pairs) = _prepare(q, k, v, g, beta)
     L = pairs * _PAIR
     do, = _pad_to_chunks(T, L, do)
@@ -541,13 +544,9 @@ def kda_bwd(q, k, v, g, beta, s_in, do, *, unit=None,
         out_specs=[keys, keys, vals, keys, steps],
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ops[:5]],
         scratch_shapes=[pltpu.VMEM((H, dk, dv), F32)],
-        interpret=interpret)(*ops, do.reshape(B, Tp, H * dv), s_in)
-
-    def heads(a, w, like):
-        return a.reshape(B, Tp, H, w)[:, :T].astype(like.dtype)
-
-    return (heads(dq, dk, q), heads(dk_, dk, k), heads(dv_, dv, v),
-            heads(dg, dk, g), db[:, :T].astype(beta.dtype))
+        interpret=interpret)(*ops, do, s_in)
+    return tuple(a[:, :T].astype(like.dtype) for a, like in zip(
+        (dq, dk_, dv_, dg, db), (q, k, v, g, beta)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -604,6 +603,39 @@ def kda_lowering(T: int, H: int, dk: int, dv: int, dtype, *,
     return ("xla", why) if why else ("pallas", "")
 
 
+def kda_rule_lanes(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                   beta: jax.Array, unit=None,
+                   interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`chunked_kda_rule` on operands with the heads side by side in
+    lanes, as the projections and the convolutions write them and as the
+    kernels hold them: q, k, g [B, T, H dk], v [B, T, H dv], beta [B, T, H]
+    -> o [B, T, H dv]. On a TPU ``[B, T, H, 128]`` is other bytes than
+    ``[B, T, H 128]`` (the last two axes are what is tiled), and XLA does not
+    always cancel a reshape to the one in front of a kernel against the
+    reshape to the other inside it (``g``, made by a fusion of XLA's own,
+    was copied before ``kda_bwd``): a caller that holds ``[B, T, H d]`` on
+    both sides calls this entry and no array tiled over the heads exists."""
+    _, T, H = beta.shape
+    dk, dv = q.shape[-1] // H, v.shape[-1] // H
+    if interpret is None:
+        lowering, _ = kda_lowering(T, H, dk, dv, v.dtype)
+    else:
+        why = _shapes_taken(dk, dv)
+        if why:
+            raise ValueError(f"the rule's kernels do not take {why}")
+        lowering = "pallas"
+    # a rule by the lowering it took, as ``ops/delta_rule.py`` counts its own
+    lowerings.count("kda_scan", lowering)
+    if lowering == "pallas":
+        return _rule_pallas(q, k, v, g, beta, unit, bool(interpret))
+    q, k, v, g = (a.reshape(a.shape[:2] + (H, -1)) for a in (q, k, v, g))
+    if unit is not None:
+        q = unit_heads(q, unit[0], unit[1], v.dtype)
+        k = unit_heads(k, 1.0, unit[1], v.dtype)
+    o = kda_einsum(q, k, v, g, beta)
+    return o.reshape(o.shape[:2] + (H * dv,))
+
+
 def chunked_kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, unit=None,
                      interpret: Optional[bool] = None) -> jax.Array:
@@ -618,21 +650,9 @@ def chunked_kda_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     (``delta_rule.unit_heads``): the kernels do it on a head's rows in VMEM
     and hand back the cotangents of the rows as they arrived. ``interpret``
     is the kernels' test handle (None: ask :func:`kda_lowering`; True: the
-    kernels, interpreted, in any float dtype, for shapes they take)."""
-    _, T, H, dk = q.shape
-    dv = v.shape[-1]
-    if interpret is None:
-        lowering, _ = kda_lowering(T, H, dk, dv, v.dtype)
-    else:
-        why = _shapes_taken(dk, dv)
-        if why:
-            raise ValueError(f"the rule's kernels do not take {why}")
-        lowering = "pallas"
-    # a rule by the lowering it took, as ``ops/delta_rule.py`` counts its own
-    lowerings.count("kda_scan", lowering)
-    if lowering == "pallas":
-        return _rule_pallas(q, k, v, g, beta, unit, bool(interpret))
-    if unit is not None:
-        q = unit_heads(q, unit[0], unit[1], v.dtype)
-        k = unit_heads(k, 1.0, unit[1], v.dtype)
-    return kda_einsum(q, k, v, g, beta)
+    kernels, interpreted, in any float dtype, for shapes they take). A
+    reshape of :func:`kda_rule_lanes`, which is the rule."""
+    B, T, H, _ = q.shape
+    o = kda_rule_lanes(*(a.reshape(B, T, -1) for a in (q, k, v, g)), beta,
+                       unit=unit, interpret=interpret)
+    return o.reshape(B, T, H, -1)

@@ -255,6 +255,30 @@ def _conv(grad, C=4352, bias=True, out=jnp.bfloat16, splits=(), T=4096, K=4,
             else fwd), shapes
 
 
+def _head_gate(grad, T=8192, H=16, dv=128):
+    """KDA's output norm and head gate at the Ling cell's call
+    (BENCHMARK.json: 1 x 8192 tokens, 16 heads held, values of 128, a head
+    one lane tile of ``[1, 8192, 2048]``) and at Olmo-Hybrid's 192-wide
+    heads, by the picker's answer for the shape as if on the chip: the
+    forward kernel alone, and the differentiated forward with the backward
+    kernel."""
+    from deepspeed_tpu.ops import head_norm_gate as hg
+
+    took, _ = hg.gate_lowering(T, H, dv, jnp.bfloat16, tpu=True)
+
+    def fwd(o, z, scale):
+        if took == "xla":
+            return hg.head_norm_gate_xla(o, z, scale, 1e-6)
+        return hg._gate_pallas(o, z, scale, 1e-6, False)
+
+    def loss(*a):
+        return (fwd(*a).astype(jnp.float32) ** 2).sum()
+
+    return (jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd), [
+        ((1, T, H * dv), jnp.bfloat16), ((1, T, H), jnp.bfloat16),
+        ((dv,), jnp.float32)]
+
+
 # (builder, kwargs, must the compiled program hold a Mosaic kernel?)
 CASES = {
     "flash-fwd-d64": (_flash, dict(d=64, grad=False), True),
@@ -361,6 +385,12 @@ CASES = {
     "conv-plain-grad-lfm2-cell": (
         _conv, dict(grad=True, C=2048, bias=False, T=8192, K=3, act=None),
         True),
+    # KDA's output norm and head gate at the Ling cell's call; heads of 192
+    # (a lane tile and a half: the picker gives the jax.numpy lines)
+    "head-gate-fwd-ling-cell": (_head_gate, dict(grad=False), True),
+    "head-gate-grad-ling-cell": (_head_gate, dict(grad=True), True),
+    "head-gate-grad-dv192-numpy-lines": (
+        _head_gate, dict(grad=True, T=4096, H=15, dv=192), False),
 }
 
 
@@ -1154,7 +1184,11 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     traced body (the rule and the kernels' own backward), and each of the
     three bodies holds the forward kernel twice (the policy names no
     residual of the rule, so the backward's region runs it again: what
-    ``recomputed_kernels`` says) and the backward kernel once; each of the
+    ``recomputed_kernels`` says) and the backward kernel once, and the same
+    of the output norm and head gate's two row kernels under
+    ``attn/kda_gate`` (``ops/head_norm_gate.py``), with no array tiled over
+    the heads (``[1, 8192, 16, 128]``) and no ``copy``, ``transpose`` or
+    ``reshape`` of a whole array under either scope; each of the
     three runs of KDA layers holds the convolution's forward kernel for q,
     k and v twice (once recomputed) and its backward once under
     ``attn/kda_conv``; the latent-attention layer
@@ -1164,14 +1198,17 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     routed layers run the grouped products and the row kernels under
     ``moe``, and under ``moe_router``, group selection and all, and
     ``moe_dispatch`` nothing is gathered or scattered."""
-    from deepspeed_tpu.ops import kda_rule
+    from deepspeed_tpu.ops import head_norm_gate, kda_rule
 
-    monkeypatch.setattr(kda_rule, "_on_tpu", lambda: True)
+    for module in (kda_rule, head_norm_gate):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
     snap = lowerings.snapshot()
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "ling3_flash_train_d7h16e8v8",
         "modelcfg_ling3", 648_853_344, seq=8192)
-    # 4.91 GB as compiled here (6.22 with the rule's einsum form: PR 54)
+    # 4.77 GB as compiled here (4.91 with the output norm and gate as
+    # float32 ``jax.numpy`` lines on [1, 8192, 16, 128]: PR 55; 6.22 with
+    # the rule's einsum form: PR 54)
     assert mem.temp_size_in_bytes < 5.0e9
     counted = lowerings.since(snap)
     # a dense run, two routed runs: three bodies of a KDA layer, each a rule
@@ -1184,7 +1221,26 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     assert sum("jit(kda_bwd)" in n for n in rule) == 3
     assert sum("rematted_computation" in n for n in rule) == 3
     assert steplog.recomputed_kernels(text)["kda_scan"] == 3
-    assert "/attn/kda_gate/" in text
+    # the output norm and the head's gate: the row kernels on o as the rule's
+    # kernels wrote it, the forward twice a body (y is not kept) and the
+    # backward once, and no array tiled over the heads between the rule's
+    # kernels and ``wo``'s product, in either direction
+    assert counted["kda_gate"] == {"pallas": 3 * 2}
+    gate = _kernel_calls(text, "kda_gate")
+    assert gate and all("/attn/kda_gate/" in n for n in gate)
+    assert sum("jit(gate_fwd)" in n for n in gate) == 3 * 2
+    assert sum("jit(gate_bwd)" in n and "transpose(" in n
+               for n in gate) == 3 and len(gate) == 9
+    assert sum("rematted_computation" in n for n in gate) == 3
+    assert steplog.recomputed_kernels(text)["kda_gate"] == 3
+    # nothing under either scope is tiled over the heads, and no array is
+    # laid out anew (on a TPU a ``reshape`` that is no bitcast is a copy)
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(r"/attn/kda_(gate|scan)/", ln)
+             and re.search(r"= \S*\[1,8192,16,128\]| (copy|transpose|reshape)"
+                           r"\(%\S+\), metadata", ln)
+             and not re.search(r"= \w+\[\d*\]", ln)]
+    assert not moved, moved[:3]
     conv = _kernel_calls(text, "kda_conv")
     assert conv and all("/attn/kda_conv/" in n for n in conv)
     assert sum("jit(conv_fwd)" in n for n in conv) == 3 * 3 * 2

@@ -264,3 +264,34 @@ def test_rules_are_counted_by_lowering_when_traced():
         == {"pallas": 2}
     assert took(jax.grad(lambda *a: kr.chunked_kda_rule(*a).sum())) \
         == {"xla": 1}
+
+
+# ---- the kernels' own layout -------------------------------------------------
+
+@pytest.mark.parametrize("lowering", ["einsum-form", "kernels"])
+def test_the_lanes_entry_is_the_rule_with_the_heads_side_by_side(lowering):
+    """``kda_rule_lanes`` takes q, k, v and ``g`` as ``[B, T, H d]`` and
+    hands o out, and takes its cotangent, the same way: the four-dimensional
+    entry is a reshape of it, to the bit, in both lowerings, a ``T`` that is
+    padded to whole steps and the norms of q and k among them; and its
+    kernels' program holds no array with an axis of heads but ``beta``."""
+    interpret = {"einsum-form": None, "kernels": True}[lowering]
+    unit = (DK ** -0.5, 1e-6)
+    q, k, v, g, beta = _inputs(192)
+    flat = tuple(a.reshape(a.shape[:2] + (-1,)) for a in (q, k, v, g))
+    o_4, g_4 = _grads(functools.partial(
+        kr.chunked_kda_rule, unit=unit, interpret=interpret),
+        (q, k, v, g, beta))
+    o_3, g_3 = _grads(functools.partial(
+        kr.kda_rule_lanes, unit=unit, interpret=interpret), flat + (beta,))
+    assert o_3.shape == (1, 192, H * DV)
+    np.testing.assert_array_equal(o_3.reshape(o_4.shape), o_4)
+    for name, a, b in zip(NAMES, g_3, g_4):
+        assert a.shape == b.reshape(b.shape[:2] + (-1,)).shape, name
+        np.testing.assert_array_equal(np.asarray(a).reshape(b.shape), b,
+                                      err_msg=name)
+    if interpret:
+        jaxpr = str(jax.make_jaxpr(jax.grad(lambda *a: kr.kda_rule_lanes(
+            *a, unit=unit, interpret=True).sum(), argnums=range(5)))(
+                *flat, beta))
+        assert f"192,{H},{DK}]" not in jaxpr and f"256,{H},{DK}]" not in jaxpr

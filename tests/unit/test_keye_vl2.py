@@ -524,13 +524,14 @@ def test_a_wrong_reading_in_the_references_place_shows(small, fault):
 #: at the tiny preset under ``test_step_scopes.CASES`` (addresses blanked),
 #: taken at the commit before the kind came in (PR 55's tree): the nine
 #: benchmark configurations' families trace to the programs they had. A PR
-#: that changes one of these programs on purpose pins it anew
+#: that changes one of these programs on purpose pins it anew (``kda_moe``:
+#: PR 59, the output norm and gate as one op and the rule on ``[B, T, H d]``)
 PARENT_PROGRAMS = {
     "dense": "ca70fe9e6dc06654", "moe": "add2dbd3b8475941",
     "looped": "22d1ece2738da248", "pattern_share": "5f8a663a70b70003",
     "hybrid": "45320f843a00a76c", "mla_moe": "3b6cf076e31ab958",
     "delta_hybrid": "12b6c412bcf1322e", "conv_moe": "bcd0d74b3485ac49",
-    "kda_moe": "caa38dc102a99b7f"}
+    "kda_moe": "91f2119635905b12"}
 
 
 @pytest.mark.parametrize("case", sorted(PARENT_PROGRAMS))
