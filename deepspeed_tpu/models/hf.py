@@ -56,7 +56,7 @@ OURO_TENSORS = {
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
                               "olmo_hybrid", "nemotron_h", "lfm2_moe",
-                              "bailing_hybrid", "KeyeVL2")
+                              "bailing_hybrid", "KeyeVL2", "laguna")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
@@ -64,7 +64,8 @@ _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
 #: types whose config maps (config_from_hf) and whose checkpoint does not
 #: load: no description of the tensor names was at hand, and none is guessed
 _CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
-                "nemotron_h", "lfm2_moe", "bailing_hybrid", "KeyeVL2")
+                "nemotron_h", "lfm2_moe", "bailing_hybrid", "KeyeVL2",
+                "laguna")
 #: ``model_type: "KeyeVL2"``: the keys of ``sa_config`` (the indexer and the
 #: set a query keeps) -> the fields here
 _KEYE_SA = {"indexer_num_heads": "dsa_index_heads",
@@ -589,6 +590,95 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             moe_dispatch="grouped", moe_aux_loss_coef=0.001,
             **{field: int(sa[key]) for key, field in _KEYE_SA.items()
                if key in sa})
+    elif model_type == "laguna":
+        # window and full attention layers in turn (``layer_types``), each
+        # kind with its own number of query heads over the same key-value
+        # heads (``num_attention_heads_per_layer``), its own rope and the
+        # share of a head that rope turns (``rope_parameters``), every head
+        # under a sigmoid gate (``gating``); the FFN of ``mlp_only_layers``
+        # dense, the others' ``num_experts`` experts beside a shared one. The
+        # router's score function is no key of the family's file: sigmoid
+        # scores with a selection bias, the top k normalised and scaled
+        # (``norm_topk_prob`` beside ``moe_routed_scaling_factor``, the
+        # DeepSeek-V3 family's pair), is what this mapping builds. What
+        # training adds (the bias rule's rate, the balance term's weight) is
+        # no config key: pass moe_bias_rate= and moe_aux_loss_coef=; a share
+        # of the heads or of the experts: heads_held=, moe_experts_held=. The
+        # config side only
+        L = get("num_hidden_layers")
+        kinds = tuple(_HF_KINDS[k] for k in get("layer_types")[:L])
+        per_layer = list(get("num_attention_heads_per_layer")
+                         or [get("num_attention_heads")] * L)[:L]
+        heads = {k: sorted({n for kk, n in zip(kinds, per_layer) if kk == k})
+                 for k in dict.fromkeys(kinds)}
+        if set(kinds) - {"window", "full"} or any(
+                len(ns) != 1 for ns in heads.values()):
+            raise ValueError(
+                f"laguna with num_attention_heads_per_layer {heads} by kind "
+                f"of layer: mapped is one head count for the window layers "
+                f"and one for the full layers")
+        gating = {str(g).replace("_", "-") for g in
+                  [get("gating", "per-head")] + list(get("gating_types")
+                                                     or [])[:L]}
+        if gating != {"per-head"}:
+            raise ValueError(
+                f"laguna with gating {sorted(gating)}: mapped is the gate of "
+                f"one scalar a head ('per-head') on every layer")
+        dense = sorted(get("mlp_only_layers") or [])
+        mlp_types = list(get("mlp_layer_types") or [])[:L]
+        if (dense != list(range(len(dense))) or len(dense) >= L
+                or int(get("decoder_sparse_step", 1)) != 1
+                or (mlp_types and mlp_types != [
+                    "dense" if i < len(dense) else "sparse"
+                    for i in range(L)])):
+            raise ValueError(
+                f"laguna with mlp_only_layers={dense}, decoder_sparse_step="
+                f"{get('decoder_sparse_step', 1)}: mapped is a leading run of "
+                f"dense FFN layers before routed ones")
+        if get("moe_router_logit_softcapping", 0):
+            raise ValueError(
+                f"laguna with moe_router_logit_softcapping="
+                f"{get('moe_router_logit_softcapping')}: the router's logits "
+                f"are not capped here")
+        if get("moe_apply_router_weight_on_input", False):
+            raise ValueError(
+                "laguna with moe_apply_router_weight_on_input: the router's "
+                "weight is applied to an expert's output here")
+        if not get("norm_topk_prob", True) or get("attention_bias", False):
+            raise ValueError("laguna without norm_topk_prob, or with "
+                             "attention biases, is not mapped")
+        Fm = get("moe_intermediate_size")
+        Fs = int(get("shared_expert_intermediate_size", 0) or 0)
+        if Fs % Fm:
+            raise ValueError(
+                f"laguna with shared_expert_intermediate_size={Fs}: the "
+                f"shared expert is built as a whole number of experts' "
+                f"widths (moe_intermediate_size={Fm})")
+        ropes = {_HF_KINDS[k]: dict(v)
+                 for k, v in (get("rope_parameters") or {}).items()
+                 if _HF_KINDS[k] in kinds}
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            heads_by_kind={k: ns[0] for k, ns in heads.items()},
+            intermediate_size=get("intermediate_size"),
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            sliding_window=get("sliding_window") if "window" in kinds
+            else None,
+            attn_pattern=kinds, rope_by_kind=ropes or None,
+            mla_head_gate=True,
+            first_k_dense=len(dense),
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=Fm,
+            moe_dispatch="grouped", moe_scoring="sigmoid",
+            moe_routed_scale=float(get("moe_routed_scaling_factor", 1.0)),
+            moe_shared_experts=Fs // Fm,
+        )
     elif model_type == "falcon":
         if get("alibi", False):
             raise ValueError("falcon alibi variants are not supported "

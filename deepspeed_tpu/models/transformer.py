@@ -135,8 +135,9 @@ class TransformerConfig:
     # ...}); None = unscaled
     rope_scaling: Optional[Dict[str, Any]] = None
     # a rope of its own for a kind of layer: {"full": {"rope_theta": ...,
-    # "rope_type": "yarn", ...}}; a kind that is not named here takes
-    # rope_theta and rope_scaling
+    # "rope_type": "yarn", ..., "partial_rotary_factor": 0.5}}; a kind that is
+    # not named here takes rope_theta and rope_scaling, a kind without
+    # "partial_rotary_factor" takes rope_pct
     rope_by_kind: Optional[Dict[str, Dict[str, Any]]] = None
     norm_eps: float = 1e-5
 
@@ -186,6 +187,14 @@ class TransformerConfig:
     # their rows, and the mixer's output is the partial sum those heads give.
     # None = all of them
     heads_held: Optional[int] = None
+    # query heads by kind of attention layer, {"window": 72}: a "window" or
+    # "full" kind that is not named has ``num_heads``, every kind
+    # ``num_kv_heads`` key-value heads of ``head_dim_override`` channels.
+    # Where a count differs from ``num_heads`` each kind keeps a stack of its
+    # own attention leaves (``attn_window``, ``attn_full``) and
+    # ``heads_held`` is a share of each kind's heads (24 of 48: 36 of 72);
+    # where none does, this is None and the model the one it was
+    heads_by_kind: Optional[Dict[str, int]] = None
     # not None: a per-token exit gate sigmoid(w_g . h_t + b_g) after every
     # pass and the expected-exit loss sum_t p_t CE_t - beta H(p) (``loss_fn``)
     exit_loss_beta: Optional[float] = None
@@ -270,8 +279,10 @@ class TransformerConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_interleave: bool = False
-    # each head's output times ``sigmoid(x wg)[h]`` before ``wo`` (a KDA
-    # layer always has the gate)
+    # each head's output times ``sigmoid(x wg)[h]`` before ``wo``, ``x`` the
+    # layer's normed input, ``wg`` [D, H] (arXiv:2505.06708's head-wise
+    # form): in a latent-attention layer and in a "window" or "full" layer,
+    # there under the scope ``attn_gate`` (a KDA layer always has the gate)
     mla_head_gate: bool = False
     # a rope whose frequency pairs follow three position axes (time, height,
     # width; HF ``rope_scaling.mrope_section``): consecutive sections of the
@@ -588,9 +599,55 @@ class TransformerConfig:
                     "rope_scaling, rope_by_kind, use_rope=False, "
                     "attention_multiplier, loss_tiling > 1 or "
                     "attention_impl='fpdt'")
-        if self.mla_head_gate and not self.has_mla:
-            raise ValueError("mla_head_gate gates latent attention's heads "
-                             "(kv_lora_rank)")
+        if self.mla_head_gate and not (self.has_mla
+                                       or self.gates_plain_heads):
+            raise ValueError("mla_head_gate gates the heads of latent "
+                             "attention (kv_lora_rank) or of 'window' / "
+                             "'full' attention layers: this model has "
+                             "neither")
+        if self.heads_by_kind is not None:
+            by = {k: int(n) for k, n in self.heads_by_kind.items()}
+            if (set(by) - {"window", "full"}
+                    or set(by) - set(self.attn_pattern or ())
+                    or any(n < 1 or n % self.num_kv_heads
+                           for n in by.values())
+                    or self.head_dim_override is None):
+                raise ValueError(
+                    f"heads_by_kind={by}: query heads for the 'window' / "
+                    f"'full' kinds of attn_pattern={self.attn_pattern}, each "
+                    f"count a multiple of num_kv_heads={self.num_kv_heads}, "
+                    f"the head's width given (head_dim_override)")
+            by = {k: n for k, n in by.items() if n != self.num_heads}
+            object.__setattr__(self, "heads_by_kind", by or None)
+            held = self.heads_held
+            for kind, n in by.items():
+                if held is not None and (
+                        held * n % self.num_heads
+                        or held * n // self.num_heads
+                        % (n // self.num_kv_heads)):
+                    raise ValueError(
+                        f"heads_held={held} of num_heads={self.num_heads} is "
+                        f"no whole number of groups of "
+                        f"{n // self.num_kv_heads} of a {kind!r} layer's "
+                        f"{n} query heads")
+        if self.attn_differs_by_kind and (
+                self.looped or self.parallel_block or self.one_branch
+                or self.has_dsa or self.qkv_bias or self.proj_bias
+                or self.loss_tiling > 1
+                or self.attention_impl in ("fpdt", "ring")):
+            raise NotImplementedError(
+                "query heads by kind (heads_by_kind), a rope width by kind "
+                "(rope_by_kind's partial_rotary_factor) and the head gate on "
+                "'window' / 'full' layers (mla_head_gate) run one pre-norm "
+                "pass of two-branch layers without biases over whole "
+                "sequences with whole logits: not a looped stack "
+                "(num_passes > 1, sandwich_norm or the exit gate), "
+                "parallel_block, one_branch, 'dsa' layers (whose block reads "
+                "one attention stack and no gate), qkv_bias / proj_bias, the "
+                "tiled loss (loss_tiling > 1: the step record's mixer "
+                "outputs come from the whole-logits path) or "
+                "attention_impl='fpdt' / 'ring' (which project and rotate "
+                "chunk by chunk from one head count and one rope width)")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_scoring={self.moe_scoring!r}: 'softmax' "
                              f"or 'sigmoid'")
@@ -695,6 +752,23 @@ class TransformerConfig:
         return self.heads_here * self.num_kv_heads // self.num_heads
 
     @property
+    def gates_plain_heads(self) -> bool:
+        """Whether a "window" or "full" layer's heads are under the head gate
+        (``mla_head_gate`` in a model with such layers)."""
+        return self.mla_head_gate and any(
+            k.partition(":")[0] in ("window", "full")
+            for k in self.layer_kinds)
+
+    @property
+    def attn_differs_by_kind(self) -> bool:
+        """Whether "window" and "full" layers carry what only the train
+        step's block applies: query heads by kind, a rope width by kind
+        (``rope_by_kind``'s "partial_rotary_factor") or the head gate."""
+        return bool(self.heads_by_kind) or self.gates_plain_heads or any(
+            "partial_rotary_factor" in r
+            for r in (self.rope_by_kind or {}).values())
+
+    @property
     def has_mla(self) -> bool:
         """Whether a layer's mixer is latent attention: every layer's, or
         the "mla" layers' of an ``attn_pattern`` beside "kda" ones."""
@@ -704,11 +778,13 @@ class TransformerConfig:
     def reports_mixer_outputs(self) -> bool:
         """Whether the step record carries each layer's mixer-output mean
         square (``mix_out_ms``): a model with a mixer that is no plain
-        attention, or whose layers are one branch each (then every layer's
+        attention, one whose attention kinds differ in their heads or gate
+        them, or one whose layers are one branch each (then every layer's
         branch output, an FFN layer's too)."""
         return (self.has_ssm or self.has_mla or self.has_delta
                 or self.has_conv or self.has_kda or self.has_dsa
-                or self.one_branch)
+                or self.one_branch or bool(self.heads_by_kind)
+                or self.gates_plain_heads)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -748,8 +824,9 @@ class TransformerConfig:
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
-        full layer, the kind's own rope. The same object where nothing
-        differs."""
+        full layer, the kind's own rope (its theta, its scaling, the share of
+        a head it turns), the kind's own query heads and ``heads_held`` as
+        the same share of them. The same object where nothing differs."""
         kind = kind.partition(":")[0]
         rope = (self.rope_by_kind or {}).get(kind)
         window = self.sliding_window if kind == "window" else None
@@ -757,12 +834,20 @@ class TransformerConfig:
                 and self.attn_pattern is None):
             return self
         kw: Dict[str, Any] = dict(sliding_window=window, attn_pattern=None,
-                                  rope_by_kind=None)
+                                  rope_by_kind=None, heads_by_kind=None)
         if rope is not None:
-            scaling = {k: v for k, v in rope.items() if k != "rope_theta"}
+            scaling = {k: v for k, v in rope.items()
+                       if k not in ("rope_theta", "partial_rotary_factor")}
             kw["rope_theta"] = float(rope.get("rope_theta", self.rope_theta))
             kw["rope_scaling"] = (scaling if scaling.get(
                 "rope_type", "default") != "default" else None)
+            if "partial_rotary_factor" in rope:
+                kw["rope_pct"] = float(rope["partial_rotary_factor"])
+        heads = (self.heads_by_kind or {}).get(kind)
+        if heads is not None:
+            kw["num_heads"] = heads
+            if self.heads_held is not None:
+                kw["heads_held"] = self.heads_held * heads // self.num_heads
         return dataclasses.replace(self, **kw)
 
     @property
@@ -777,18 +862,24 @@ class TransformerConfig:
         """The leaves ``TransformerLM.init`` makes, counted from the sizes:
         each layer's mixer and FFN by its kind, the norms once each."""
         D, F, V, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
-        hd, nh, nkv = self.head_dim, self.heads_here, self.kv_heads_here
         gated = self.activation == "swiglu"
         norm = D * (2 if self.norm == "layernorm" else 1)
         norms = norm * ((1 if self.parallel_shared_norm or self.one_branch
                          else 2) + (2 if self.sandwich_norm else 0))
-        attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
-        if self.qk_norm:
-            attn += 2 * hd if self.qk_norm == "head" else (nh + nkv) * hd
-        if self.qkv_bias:
-            attn += (nh + 2 * nkv) * hd
-        if self.proj_bias:
-            attn += D
+
+        def attn_of(ck: "TransformerConfig") -> int:
+            hd, nh, nkv = ck.head_dim, ck.heads_here, ck.kv_heads_here
+            attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
+            if ck.qk_norm:
+                attn += 2 * hd if ck.qk_norm == "head" else (nh + nkv) * hd
+            if ck.qkv_bias:
+                attn += (nh + 2 * nkv) * hd
+            if ck.proj_bias:
+                attn += D
+            if self.gates_plain_heads:
+                attn += D * nh
+            return attn
+
         dense = (3 if gated else 2) * D * F
         if self.proj_bias and not gated:
             dense += F + D
@@ -802,7 +893,9 @@ class TransformerConfig:
                       + 2 * D * (Z or 0))
             if self.moe_scoring == "sigmoid":
                 routed += self.num_experts
-        mixers = {"attn": attn}
+        mixers = {"attn": attn_of(self)}
+        for kind in ("window", "full") if self.heads_by_kind else ():
+            mixers["attn_" + kind] = attn_of(self.kind_cfg(kind))
         if self.has_ssm:
             from deepspeed_tpu.models import mamba
 
@@ -832,7 +925,8 @@ class TransformerConfig:
             mixer, _, ffn = kind.partition(":")
             layers += norms
             if mixer != "none":
-                layers += sum(mixers[g] for g in _groups_of(mixer))
+                layers += sum(mixers[g] for g in _groups_of(
+                    mixer, bool(self.heads_by_kind)))
             if ffn != "none":
                 layers += dense if ffn == "dense" else routed
         embed = V * D + (self.max_seq_len * D if self.learned_pos else 0)
@@ -1205,6 +1299,12 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         out = attn_fn(q, k, v, causal=True, chunk=cfg.fpdt_chunk)
     else:
         out = attn_fn(q, k, v, causal=True)
+    if "wg" in w:
+        with jax.named_scope("attn_gate"):
+            # one scalar a head and position, on the heads' outputs
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                (x @ w["wg"]).astype(jnp.float32))[..., None]
+            ).astype(x.dtype)
     o = attn_out_proj(out, w, cfg)
     return constrain(o, P(("dp", "fsdp"), "sp", None))
 
@@ -1231,6 +1331,12 @@ def _decode_block(h: jax.Array, wc: Params, cfg: TransformerConfig,
     shared norm, biases, MoE).
     ``moe_valid`` [B, t] marks real (non-padding/idle) lanes: without it the
     batch's no-op rows would compete for expert capacity and skew routing."""
+    if cfg.gates_plain_heads:
+        raise NotImplementedError(
+            "the decode block multiplies no head's output by a gate "
+            "(mla_head_gate on 'window' / 'full' layers); only the train "
+            "step's block does")
+
     def _mlp(hn):
         if moe_fn is not None:
             try:
@@ -1294,9 +1400,10 @@ def mlp_block(x: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
 STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                "lm_head", "loss", "exit_gate", "grad_accum", "optimizer",
                # nested: the layer's kind inside attn (a model whose layers
-               # are of more than one), the grouped expert layer's parts
+               # are of more than one) and inside that the head gate of a
+               # "window" or "full" layer, the grouped expert layer's parts
                # inside moe (moe/sharded_moe.py)
-               "attn_window", "attn_full",
+               "attn_window", "attn_full", "attn_gate",
                "moe_router", "moe_dispatch", "moe_experts", "moe_latent",
                # a state-space layer's parts inside attn, the token mixer's
                # slot (models/mamba.py)
@@ -1341,19 +1448,31 @@ _FFN_GROUP = {"dense": "mlp_dense", "moe": "mlp_moe"}
 #: what a kind keeps beside its mixer's group: a "dsa" layer the attention
 #: leaves every attention kind has and, in a group of its own, its indexer's
 _MIXER_EXTRA = {"dsa": "indexer"}
+#: in a model whose attention kinds differ in their query heads
+#: (``cfg.heads_by_kind``) each keeps a stack of its own in place of "attn"
+_SPLIT_GROUP = {"window": "attn_window", "full": "attn_full"}
 _KIND_GROUPS = frozenset(_MIXER_GROUP.values()) | frozenset(
-    _FFN_GROUP.values()) | frozenset(_MIXER_EXTRA.values())
+    _FFN_GROUP.values()) | frozenset(_MIXER_EXTRA.values()) | frozenset(
+    _SPLIT_GROUP.values())
 #: the scope an FFN kind's own group is cast and run under
 _FFN_SCOPE = {"mlp_dense": "mlp", "mlp_moe": "moe"}
 
 
-def _groups_of(kind: str) -> Tuple[str, ...]:
-    """The groups that hold the own leaves of a layer of ``kind``."""
+def _groups_of(kind: str, split: bool = False) -> Tuple[str, ...]:
+    """The groups that hold the own leaves of a layer of ``kind``; with
+    ``split`` a "window" or a "full" layer's mixer in its kind's own."""
     mixer, _, ffn = kind.partition(":")
-    return tuple(group[k] for k, group in ((mixer, _MIXER_GROUP),
+    mixers = {**_MIXER_GROUP, **_SPLIT_GROUP} if split else _MIXER_GROUP
+    return tuple(group[k] for k, group in ((mixer, mixers),
                                            (mixer, _MIXER_EXTRA),
                                            (ffn, _FFN_GROUP))
                  if k and k != "none" and k in group)
+
+
+def _split(layers: Params) -> bool:
+    """Whether the stacks ``layers`` keep a "window" or "full" layer's
+    attention leaves by kind (:data:`_SPLIT_GROUP`)."""
+    return any(g in layers for g in _SPLIT_GROUP.values())
 
 
 def _times(x: jax.Array, factor: float) -> jax.Array:
@@ -1390,7 +1509,8 @@ def _cast_layers(w: Params, dt, ffn: Optional[str],
     for k, v in w.items():
         with (contextlib.nullcontext() if ffn is None else jax.named_scope(
                 "attn" if k in ("ln1", "attn", "ssm", "mla", "delta", "conv",
-                                "kda", "indexer", "ln1_post")
+                                "kda", "indexer", "ln1_post", "attn_window",
+                                "attn_full")
                 else _FFN_SCOPE.get(k, ffn))):
             if any(n in _KEEP_FP32 for n in v):
                 out[k] = {n: p if n in _KEEP_FP32
@@ -1688,9 +1808,9 @@ def _block_of(xs, j: int, p: int):
     return xs if p == 1 else jax.tree_util.tree_map(lambda a: a[j], xs)
 
 
-def _in_group(kinds, grp: str) -> int:
+def _in_group(kinds, grp: str, split: bool = False) -> int:
     """How many of ``kinds`` keep leaves of their own in the group ``grp``."""
-    return sum(grp in _groups_of(k) for k in kinds)
+    return sum(grp in _groups_of(k, split) for k in kinds)
 
 
 def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
@@ -1699,15 +1819,15 @@ def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
     kind's group is cut to the rows of its own layers among them (a group
     none of the period's kinds reads is left out), every other group to the
     layers themselves."""
-    out = {}
+    out, split = {}, _split(layers)
     for grp in sorted(layers):
         if grp in _KIND_GROUPS:
-            n = _in_group(period, grp)
+            n = _in_group(period, grp, split)
             if n:
-                first = _in_group(kinds[:lo], grp)
+                first = _in_group(kinds[:lo], grp, split)
                 out[grp] = _by_period(
-                    layers[grp], first, first + _in_group(kinds[lo:hi], grp),
-                    n)
+                    layers[grp], first,
+                    first + _in_group(kinds[lo:hi], grp, split), n)
         else:
             out[grp] = _by_period(layers[grp], lo, hi, len(period))
     return out
@@ -1715,16 +1835,20 @@ def _segment(layers: Params, kinds, lo: int, hi: int, period) -> Params:
 
 def _block_weights(xs: Params, j: int, period) -> Params:
     """Block ``j``'s weights out of one period's scan input
-    (:func:`_segment`): its kind's own groups (its FFN's under ``mlp``, where
-    the block reads it) and every shared group."""
-    mine = _groups_of(period[j])
+    (:func:`_segment`): its kind's own groups (its FFN's under ``mlp`` and
+    its kind's attention stack under ``attn``, where the block reads them)
+    and every shared group."""
+    split = _split(xs)
+    mine = _groups_of(period[j], split)
     out = {}
     for grp in sorted(xs):
         if grp not in _KIND_GROUPS:
             out[grp] = _block_of(xs[grp], j, len(period))
         elif grp in mine:
-            out["mlp" if grp in _FFN_GROUP.values() else grp] = _block_of(
-                xs[grp], _in_group(period[:j], grp), _in_group(period, grp))
+            out["mlp" if grp in _FFN_GROUP.values()
+                else "attn" if grp in _SPLIT_GROUP.values() else grp] = \
+                _block_of(xs[grp], _in_group(period[:j], grp, split),
+                          _in_group(period, grp, split))
     return out
 
 
@@ -1818,6 +1942,15 @@ class TransformerLM:
         heads, and for one with the Granite multipliers (only
         ``transformer_block`` and the train forward apply them)."""
         cfg = self.cfg
+        if cfg.attn_differs_by_kind:
+            raise NotImplementedError(
+                f"{what} reads one stack of attention leaves with one head "
+                f"count, one rope width and no gate: this model has "
+                f"heads_by_kind={cfg.heads_by_kind} (a stack of leaves and a "
+                f"cache shape for each kind), a rope width by kind "
+                f"(rope_by_kind={cfg.rope_by_kind}) or the head gate on its "
+                f"attention layers (mla_head_gate={cfg.mla_head_gate}); only "
+                f"the train step's block applies them")
         if cfg.has_delta:
             raise NotImplementedError(
                 f"{what} is written for attention layers: this model has "
@@ -1949,8 +2082,19 @@ class TransformerLM:
                     sum(k.partition(":")[0] == kind for k in cfg.layer_kinds)
                     * int(rows) * -(-int(T) // chunk))
         if cfg.heads_held is not None:
-            # (count, all) of the heads a mixer holds, where a share of them
-            facts["heads_held"] = (cfg.heads_held, cfg.num_heads)
+            # (count, all) of the heads a mixer holds, where a share of them;
+            # by kind where the kinds' counts differ
+            facts["heads_held"] = (
+                {kind.partition(":")[0]: (ck.heads_here, ck.num_heads)
+                 for kind, (ck, _) in self._kinds.items()}
+                if cfg.heads_by_kind else (cfg.heads_held, cfg.num_heads))
+        plain = [self._kinds[k][0].heads_here for k in cfg.layer_kinds
+                 if k.partition(":")[0] in ("window", "full")]
+        if plain and cfg.attn_pattern is not None:
+            # the query heads the "window" and "full" layers of one
+            # micro-batch's forward run, summed over those layers (a model
+            # of more than one kind of layer)
+            facts["attn_heads_per_step"] = sum(plain) * cfg.num_passes
         if cfg.mrope_section is not None:
             # the position axes the rope's frequency pairs follow
             facts["mrope_axes"] = len(cfg.mrope_section)
@@ -2066,6 +2210,13 @@ class TransformerLM:
                 f"indexer's target is the mean over all of a query's heads "
                 f"and its set is chosen among all of a row's keys, so the "
                 f"layer keeps heads and rows whole on a chip")
+        if axis_sizes.get("tp", 1) > 1 and (cfg.heads_by_kind
+                                            or cfg.gates_plain_heads):
+            raise NotImplementedError(
+                f"a tp axis of {axis_sizes['tp']} with query heads by kind "
+                f"(heads_by_kind={cfg.heads_by_kind}) or the head gate on "
+                f"attention layers (mla_head_gate): the kinds' stacks and "
+                f"the gate's [D, H] columns are laid out whole on a chip")
         if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta or cfg.has_conv
                                             or cfg.has_kda
                                             or cfg.heads_held is not None):
@@ -2082,8 +2233,7 @@ class TransformerLM:
         cfg = self.cfg
         pd = jnp.dtype(cfg.param_dtype)
         D, F, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
-        hd, H, K, L = (cfg.head_dim, cfg.heads_here, cfg.kv_heads_here,
-                       cfg.num_layers)
+        L = cfg.num_layers
         keys = jax.random.split(rng, 12)
 
         def dense(key, fan_in, shape):
@@ -2097,26 +2247,38 @@ class TransformerLM:
         if cfg.norm == "layernorm":
             norm_w["bias"] = jnp.zeros((L, D), pd)
         # one stack of mixer leaves for each kind of mixer, a row for each
-        # layer of that kind (every layer's, where all are attention)
-        La = _in_group(kinds, "attn")
-        attn_w = {
-            "wq": layer_stack(keys[1], D, (D, H * hd), La),
-            "wk": layer_stack(keys[2], D, (D, K * hd), La),
-            "wv": layer_stack(keys[10], D, (D, K * hd), La),
-            "wo": layer_stack(keys[3], H * hd, (H * hd, D), La),
-        }
-        if cfg.qkv_bias:
-            attn_w["bq"] = jnp.zeros((La, H * hd), pd)
-            attn_w["bk"] = jnp.zeros((La, K * hd), pd)
-            attn_w["bv"] = jnp.zeros((La, K * hd), pd)
-        if cfg.proj_bias:
-            attn_w["bo"] = jnp.zeros((La, D), pd)
-        if cfg.qk_norm == "head":
-            attn_w["q_norm"] = jnp.ones((La, hd), pd)
-            attn_w["k_norm"] = jnp.ones((La, hd), pd)
-        elif cfg.qk_norm:
-            attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
-            attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
+        # layer of that kind (every layer's, where all are attention; a
+        # stack for "window" and one for "full" where their heads differ)
+        split = bool(cfg.heads_by_kind)
+
+        def attn_stack(ck: TransformerConfig, La: int, ks) -> Params:
+            hd, H, K = ck.head_dim, ck.heads_here, ck.kv_heads_here
+            attn_w = {
+                "wq": layer_stack(ks[0], D, (D, H * hd), La),
+                "wk": layer_stack(ks[1], D, (D, K * hd), La),
+                "wv": layer_stack(ks[2], D, (D, K * hd), La),
+                "wo": layer_stack(ks[3], H * hd, (H * hd, D), La),
+            }
+            if cfg.qkv_bias:
+                attn_w["bq"] = jnp.zeros((La, H * hd), pd)
+                attn_w["bk"] = jnp.zeros((La, K * hd), pd)
+                attn_w["bv"] = jnp.zeros((La, K * hd), pd)
+            if cfg.proj_bias:
+                attn_w["bo"] = jnp.zeros((La, D), pd)
+            if cfg.qk_norm == "head":
+                attn_w["q_norm"] = jnp.ones((La, hd), pd)
+                attn_w["k_norm"] = jnp.ones((La, hd), pd)
+            elif cfg.qk_norm:
+                attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
+                attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
+            if cfg.gates_plain_heads:
+                attn_w["wg"] = layer_stack(ks[4], D, (D, H), La)
+            return attn_w
+
+        La = _in_group(kinds, "attn", split)
+        attn_w = attn_stack(cfg, La, (
+            keys[1], keys[2], keys[10], keys[3],
+            jax.random.fold_in(rng, 20) if cfg.gates_plain_heads else None))
         # the FFNs: one stack with a row a layer, or (first_k_dense,
         # one_branch) a dense stack and a routed one, a row for each layer
         # of the kind
@@ -2199,6 +2361,11 @@ class TransformerLM:
                                          _in_group(kinds, "indexer"), pd)
         if not La:
             del layers["attn"]
+        for n, (kind, grp) in enumerate(_SPLIT_GROUP.items() if split
+                                        else ()):
+            layers[grp] = attn_stack(
+                cfg.kind_cfg(kind), _in_group(kinds, grp, split),
+                jax.random.split(jax.random.fold_in(rng, 21 + n), 5))
         if not (cfg.parallel_shared_norm or cfg.one_branch):
             layers["ln2"] = jax.tree_util.tree_map(jnp.copy, norm_w)
         if cfg.sandwich_norm or cfg.norm_placement == "post":
@@ -3121,7 +3288,13 @@ class TransformerLM:
             from deepspeed_tpu.models import dsa
 
             layer_specs["indexer"] = dsa.param_specs()
-        if not _in_group(cfg.layer_kinds, "attn"):
+        split = bool(cfg.heads_by_kind)
+        if cfg.gates_plain_heads:
+            attn_spec["wg"] = P(None, None, "tp")
+        for grp in _SPLIT_GROUP.values() if split else ():
+            if _in_group(cfg.layer_kinds, grp, split):
+                layer_specs[grp] = dict(attn_spec)
+        if not _in_group(cfg.layer_kinds, "attn", split):
             del layer_specs["attn"]
         if not (cfg.parallel_shared_norm or cfg.one_branch):
             layer_specs["ln2"] = dict(norm_spec)

@@ -1327,3 +1327,63 @@ def test_the_keye_vl2_cells_step_program_compiles_for_v5e(one_chip,
     moves = _kernel_calls(text, "moe_dispatch")
     assert any("jit(rows_of_tokens)" in n for n in moves)
     assert all("/moe/" in n for n in experts + moves)
+
+
+def test_the_laguna_cells_step_program_compiles_for_v5e(one_chip,
+                                                        monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    laguna_s21_train_d5h24e8v8.json``: a full dense layer, three window
+    layers and a full routed one at the published widths, 24 of 48 and 36 of
+    72 query heads over 4 of 8 key-value heads, 8 of 256 experts, one row of
+    8,192 positions, under the file's policy). It fits beside what a chip
+    reserves; **one program holds the flash kernels at two shapes**, window
+    512 at 36 heads in groups of 9 and full at 24 heads in groups of 6,
+    forward and fused backward, under ``attn/attn_window`` and
+    ``attn/attn_full`` with the names the benchmark's patterns look for; the
+    head gate's operations lie under ``attn_gate`` inside either kind's
+    scope and are no kernel; the dense layer's FFN stands under ``mlp`` and
+    the four routed layers run the grouped products, the row kernels and
+    the shared expert under ``moe``."""
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "laguna_s21_train_d5h24e8v8",
+        "modelcfg_laguna", 672_126_976, seq=8192)
+    # 6.33 GB of temporaries as compiled here under the file's
+    # "dots_saveable" (this plain step casts its weights inside: the
+    # engine's own, with the carried copy, compiles to 4.84 beside 9.41 GB
+    # of arguments), beside 8.07 GB of arguments
+    assert mem.temp_size_in_bytes < 6.5e9
+    # the policy keeps the forward kernels' named results: neither kind's
+    # forward runs again in the backward's region
+    assert not {"attn_window", "attn_full"} & set(
+        steplog.recomputed_kernels(text))
+    shapes = {"attn_window": 36, "attn_full": 24}
+    for scope, H in shapes.items():
+        calls = _kernel_calls(text, scope)
+        assert calls and all(f"/attn/{scope}/" in n for n in calls)
+        assert any("transpose(" in n for n in calls)
+        fwd = re.findall(
+            rf"^\s*%{scope}[.\d]* = \(bf16\[1,{H},8192,128\]\S*, "
+            rf"f32\[1,{H},1,8192\]\S*\) custom-call\(.*tpu_custom_call",
+            text, re.M)
+        bwd = re.findall(
+            rf"^\s*%{scope}[.\d]* = \(bf16\[1,{H},8,1024,128\]\S*, "
+            rf"bf16\[2,1,{H},8192,128\]\S*\) custom-call\(.*tpu_custom_call",
+            text, re.M)
+        assert fwd and bwd, scope
+        # nothing of the other kind's width under this kind's scope
+        other = next(h for s, h in shapes.items() if s != scope)
+        assert not re.search(rf"^\s*%{scope}[.\d]* = \(bf16\[1,{other},",
+                             text, re.M)
+    gate = [n for n in re.findall(r'op_name="([^"]*)"', text)
+            if "/attn_gate/" in n]
+    assert gate and all("/attn/attn_window/attn_gate/" in n
+                        or "/attn/attn_full/attn_gate/" in n for n in gate)
+    assert not _kernel_calls(text, "attn_gate")
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)
+    assert "/moe/moe_shared/" in text and "ragged-dot" not in text
+    assert "/moe/moe_router/" in text and "/mlp/" in text

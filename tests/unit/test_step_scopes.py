@@ -126,6 +126,33 @@ CASES = {
                  "num_experts": 8, "top_k": 2, "moe_dispatch": "grouped",
                  "moe_intermediate_size": 32, "moe_experts_held": 4,
                  "tie_embeddings": False, "remat_policy": "full"}, 1),
+    # window and full layers that differ in their query heads (a stack of
+    # leaves a kind), half a head turned under yarn on the full ones, every
+    # head under the sigmoid gate, on a held share of the heads, under
+    # recomputation; a dense FFN then routed ones with a shared expert: the
+    # gate's operations lie under attn_gate inside the kind's scope, forward
+    # and backward
+    "heads_moe": ({"num_layers": 5, "num_heads": 4, "num_kv_heads": 2,
+                   "head_dim_override": 16, "sliding_window": 8,
+                   "attn_pattern": ("full", "window", "window", "window",
+                                    "full"),
+                   "heads_by_kind": {"window": 6}, "heads_held": 2,
+                   "mla_head_gate": True,
+                   "rope_by_kind": {
+                       "full": {"rope_theta": 500000.0, "rope_type": "yarn",
+                                "factor": 8.0,
+                                "original_max_position_embeddings": 16,
+                                "attention_factor": 1.4852,
+                                "partial_rotary_factor": 0.5},
+                       "window": {"rope_theta": 10000.0,
+                                  "rope_type": "default",
+                                  "partial_rotary_factor": 1}},
+                   "first_k_dense": 1, "num_experts": 8, "top_k": 2,
+                   "moe_dispatch": "grouped", "moe_intermediate_size": 32,
+                   "moe_experts_held": 4, "moe_scoring": "sigmoid",
+                   "moe_routed_scale": 2.5, "moe_shared_experts": 1,
+                   "moe_bias_rate": 1e-3, "moe_bias_init": 0.1,
+                   "tie_embeddings": False, "remat_policy": "full"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -152,6 +179,9 @@ NESTED_MLA = {"attn_mla": "attn", "mla_proj": "attn_mla",
               "moe_shared": "moe"}
 NESTED_KDA = {**NESTED_MLA, "kda_proj": "attn", "kda_conv": "attn",
               "kda_scan": "attn", "kda_gate": "attn"}
+NESTED_HEADS = {"attn_window": "attn", "attn_full": "attn",
+                "moe_router": "moe", "moe_dispatch": "moe",
+                "moe_experts": "moe", "moe_shared": "moe"}
 NESTED_DSA = {"attn_dsa": "attn", "dsa_indexer": "attn_dsa",
               "dsa_select": "attn_dsa", "dsa_attend": "attn_dsa",
               "dsa_loss": "attn_dsa", "moe_router": "moe",
@@ -188,14 +218,15 @@ def test_every_operation_carries_a_step_scope(case):
     found = {p for n in names for p in re.split(r"[/()]", n)} \
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
-                            "conv_moe", "kda_moe", "dsa_moe") else "mlp"
+                            "conv_moe", "kda_moe", "dsa_moe",
+                            "heads_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
-    if case in ("mla_moe", "conv_moe", "kda_moe"):
+    if case in ("mla_moe", "conv_moe", "kda_moe", "heads_moe"):
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
               "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
               "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA,
-              "dsa_moe": NESTED_DSA}.get(case)
+              "dsa_moe": NESTED_DSA, "heads_moe": NESTED_HEADS}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
@@ -228,6 +259,22 @@ def test_every_operation_carries_a_step_scope(case):
         # the groups are chosen inside the router: two sorts more than the
         # plain top k has
         assert any("moe_router" in n and "top_k" in n for n in names)
+    if case == "heads_moe":
+        # the gate lies under either kind's scope, forward, recomputed and
+        # backward, and holds the sigmoid and the product with wg alone
+        want.add("attn_gate")
+        gate = [n for n in names if "attn_gate" in re.split(r"[/()]", n)]
+        for kind in ("attn_window", "attn_full"):
+            mine = [n for n in gate if f"/attn/{kind}/" in n]
+            assert any("transpose(" in n for n in mine), kind
+            assert any("rematted_computation" in n for n in mine), kind
+        assert all("/attn/attn_window/" in n or "/attn/attn_full/" in n
+                   for n in gate)
+        assert {n.rsplit("/", 1)[-1] for n in gate} >= {"exp", "dot_general",
+                                                        "mul"}
+        row = steplog.programs()[-1]
+        assert row.attn_heads_per_step == 2 * 2 + 3 * 3
+        assert row.heads_held == {"full": (2, 4), "window": (3, 6)}
     if case == "dsa_moe":
         # every exponential, logarithm and branch of the mixer lies under
         # one of its four scopes: what lies under attn_dsa and outside them
