@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops import lowerings
+from deepspeed_tpu.ops.topk_select import topk_select
 from deepspeed_tpu.parallel.sharding import constrain
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -192,7 +193,7 @@ def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
     aux_loss = jnp.sum(g_sum * m_sum) / (denom * denom) * E
     # the chosen gates by :func:`_pick`, the values ``top_k`` gives to the
     # bit: the transpose of ``top_k``'s own is a scatter-add of S k scalars
-    _, topk_idx = jax.lax.top_k(gates, k)  # [S, k]
+    topk_idx, _ = topk_select(gates, k)  # [S, k]
     topk_vals = _pick(gates, topk_idx, E)
     # renormalize the kept gate mass (reference normalizes combine weights)
     topk_vals = topk_vals / jnp.maximum(topk_vals.sum(-1, keepdims=True), 1e-9)
@@ -201,33 +202,17 @@ def _route(logits: jax.Array, k: int, rng: Optional[jax.Array] = None,
     return gates, aux_loss, topk_vals, topk_idx
 
 
-def _kept_groups(sb: jax.Array, n_group: int, topk_group: int):
-    """Group-limited selection (DeepSeek-V3's) on the selection scores
-    ``sb`` [B, T, E]: the experts in ``n_group`` groups of ``E / n_group``
-    neighbours, a group's score the sum of its two largest, the
-    ``topk_group`` best groups kept (``lax.top_k``: of equal scores the
-    lower group). Returns ``(sb with the other groups' experts at -inf,
-    tokens that kept each group [n_group])``. Two sorts and a compare of the
-    kept groups against ``arange(n_group)``: nothing is gathered."""
-    B, T, E = sb.shape
-    grouped = sb.reshape(B, T, n_group, E // n_group)
-    score = jax.lax.top_k(grouped, 2)[0].sum(-1)              # [B, T, n]
-    _, best = jax.lax.top_k(score, topk_group)
-    keep = _hot(best, n_group).any(axis=-2)                   # [B, T, n]
-    return (jnp.where(keep[..., None], grouped, -jnp.inf).reshape(B, T, E),
-            keep.sum(axis=(0, 1), dtype=jnp.int32))
-
-
 def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float,
                    groups: Tuple[int, int] = (1, 1)):
     """The DeepSeek-V3 family's router (``moe_scoring="sigmoid"``) over
     ``logits`` [B, T, E] in float32: scores ``s = sigmoid(logits)``, the
     ``k`` experts with the largest ``s + bias`` (the selection bias picks
     and gets no gradient; with ``groups`` (n_group, topk_group) above (1, 1)
-    among the experts of the groups :func:`_kept_groups` keeps), weights
-    ``scale x s_i / sum_{chosen} s_j`` (the
-    bias is not in them). Returns ``(balance term, weights [B T, k], experts
-    [B T, k], counts [E], groups kept [n_group] or None)``: the sequence-wise
+    among the experts of the groups the limit keeps:
+    ``ops/topk_select.py``, which picks without a sort where it can), weights
+    ``scale x s_i / sum_{chosen} s_j`` (the bias is not in them). Returns
+    ``(balance term, weights [B T, k], experts [B T, k], counts [E], groups
+    kept [n_group] or None)``: the sequence-wise
     balance term ``sum_i f_i
     P_i``, ``f_i = E / (k T) x`` the sequence's pairs of expert i (the chosen
     pairs, bias included: a constant), ``P_i`` the sequence's mean of ``s_i /
@@ -238,10 +223,8 @@ def _route_sigmoid(logits: jax.Array, bias: jax.Array, k: int, scale: float,
     ``arange(E)``: no gather of a scalar a pair, no scatter-add behind it."""
     B, T, E = logits.shape
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    chosen_by, kept = s + bias.astype(jnp.float32), None
-    if tuple(groups) != (1, 1):
-        chosen_by, kept = _kept_groups(chosen_by, *groups)
-    _, idx = jax.lax.top_k(chosen_by, k)                      # [B, T, k]
+    idx, keep = topk_select(s + bias.astype(jnp.float32), k, groups)
+    kept = None if keep is None else keep.sum(axis=(0, 1), dtype=jnp.int32)
     picked = _pick(s, idx, E)
     weights = scale * picked / picked.sum(-1, keepdims=True)
     by_seq = _hot(idx, E).sum(axis=(1, 2), dtype=jnp.int32)   # [B, E]

@@ -812,6 +812,40 @@ def _index_ops_under(text, scope):
     return found
 
 
+def _sorts_under(text, scope):
+    """``op_name`` of the ``sort`` instructions of a compiled program under
+    ``/<scope>/``."""
+    return [n for n in re.findall(r'^.* sort\(.*op_name="([^"]*)"', text,
+                                  re.M) if f"/{scope}/" in n]
+
+
+def _picks_without_a_sort(text, calls):
+    """Under ``moe_router`` the selection kernel ``calls`` times, and no sort
+    but ``_placement``'s, one a call of the router; under ``moe_dispatch``
+    only ``_pairs_of_rows``', in the backward."""
+    select = _kernel_calls(text, "moe_router")
+    assert len(select) == calls
+    assert all("/moe/moe_router/jit(select_rounds)/" in n for n in select)
+    assert len(_sorts_under(text, "moe_router")) == calls
+    assert all("transpose(" in n for n in _sorts_under(text, "moe_dispatch"))
+
+
+@pytest.mark.parametrize("k, groups", [(8, (8, 4)), (22, (1, 1))])
+def test_the_selection_kernel_compiles_for_v5e(one_chip, k, groups):
+    """``ops/topk_select.py`` alone at the Ling and Nemotron routers' shapes
+    ([8192, 512]: 8 a token under the group limit, 22 without): one Mosaic
+    call, no sort beside it."""
+    from deepspeed_tpu.ops import topk_select as ts
+
+    assert ts.topk_lowering(8192, 512, k, groups, jnp.float32,
+                            tpu=True) == ("pallas", "")
+    x = jax.ShapeDtypeStruct((8192, 512), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda x: ts.select_rounds(x, k=k, groups=groups)).lower(
+        x).compile().as_text()
+    assert len(re.findall(r"custom-call\(.*tpu_custom_call", text)) == 1
+    assert not re.findall(r" sort\(", text)
+
+
 # an expert layer at a cell's shapes: tokens (2 x 8192), width, experts'
 # width, experts, held, a token's picks, the buffer's factor, sigmoid scores
 CELL_LAYERS = {
@@ -950,12 +984,13 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
     import deepspeed_tpu.ops as ops
     from deepspeed_tpu.models import TransformerLM
     from deepspeed_tpu.ops import (causal_conv, delta_rule, grouped_matmul,
-                                   ssd_scan)
+                                   ssd_scan, topk_select)
     from deepspeed_tpu.ops import flash_attention as fa
     from deepspeed_tpu.runtime.optimizers import build_optimizer
 
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    for module in (fa, delta_rule, ssd_scan, causal_conv, grouped_matmul):
+    for module in (fa, delta_rule, ssd_scan, causal_conv, grouped_matmul,
+                   topk_select):
         monkeypatch.setattr(module, "_on_tpu", lambda: True)
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -1088,9 +1123,11 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     ``attn/attn_full``, whose instructions keep the names the benchmark's
     patterns look for; under ``moe_router`` and ``moe_dispatch`` nothing
     is gathered or scattered."""
+    snap = lowerings.snapshot()
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "nemotron3_super_120b_train_d11h16e8v8",
         "modelcfg_nemotron_h", 700_865_520, seq=8192)
+    counted = lowerings.since(snap)
     assert mem.temp_size_in_bytes < 6.4e9
     experts = _kernel_calls(text, "moe_experts")
     assert sum("jit(gmm)" in n for n in experts) == 5 * 4
@@ -1114,6 +1151,10 @@ def test_the_nemotron_cells_step_program_compiles_for_v5e(one_chip,
     assert "/moe/moe_router/" in text
     assert _index_ops_under(text, "moe_router") == []
     assert _index_ops_under(text, "moe_dispatch") == []
+    # 22 of 512 a token by the selection kernel, once a layer (no
+    # recomputation): no sort of the scores
+    assert counted["moe_topk"] == {"pallas": 1}
+    _picks_without_a_sort(text, calls=5)
 
 
 def test_the_lfm2_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
@@ -1264,6 +1305,12 @@ def test_the_ling3_cells_step_program_compiles_for_v5e(one_chip, monkeypatch):
     assert "/moe/moe_router/" in text and "/mlp/" in text
     assert _index_ops_under(text, "moe_router") == []
     assert _index_ops_under(text, "moe_dispatch") == []
+    # the group limit and the 8 of 512 as one selection kernel, once a
+    # routed body and once more in its recomputed region: no sort of the
+    # scores, of a group's or of the groups'
+    assert counted["moe_topk"] == {"pallas": 3}
+    _picks_without_a_sort(text, calls=3 * 2)
+    assert steplog.recomputed_kernels(text)["moe_router"] == 3
 
 
 def test_the_keye_vl2_cells_step_program_compiles_for_v5e(one_chip,

@@ -525,13 +525,16 @@ def test_a_wrong_reading_in_the_references_place_shows(small, fault):
 #: taken at the commit before the kind came in (PR 55's tree): the nine
 #: benchmark configurations' families trace to the programs they had. A PR
 #: that changes one of these programs on purpose pins it anew (``kda_moe``:
-#: PR 59, the output norm and gate as one op and the rule on ``[B, T, H d]``)
+#: PR 59, the output norm and gate as one op and the rule on ``[B, T, H d]``;
+#: PR 61, the group limit inside the selection op, ``ops/topk_select.py``:
+#: the tokens a group kept are counted after the k are chosen, the same
+#: equations in another order; the routers without groups trace as they did)
 PARENT_PROGRAMS = {
     "dense": "ca70fe9e6dc06654", "moe": "add2dbd3b8475941",
     "looped": "22d1ece2738da248", "pattern_share": "5f8a663a70b70003",
     "hybrid": "45320f843a00a76c", "mla_moe": "3b6cf076e31ab958",
     "delta_hybrid": "12b6c412bcf1322e", "conv_moe": "bcd0d74b3485ac49",
-    "kda_moe": "91f2119635905b12"}
+    "kda_moe": "ad206fc800b9fa8f"}
 
 
 @pytest.mark.parametrize("case", sorted(PARENT_PROGRAMS))

@@ -357,6 +357,66 @@ def test_a_token_whose_best_experts_lie_in_five_groups_gets_the_rules():
     assert set(np.asarray(idx[0]) // 4) == {0, 1, 2, 5}
 
 
+def _route_sigmoid_as_it_was(logits, bias, k, scale, groups=(1, 1)):
+    """``sharded_moe._route_sigmoid`` before the selection became an op
+    (``ops/topk_select.py``): ``lax.top_k`` and the group limit's lines in
+    place."""
+    B, T, E = logits.shape
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    chosen_by, kept = s + bias.astype(jnp.float32), None
+    if tuple(groups) != (1, 1):
+        n_group, topk_group = groups
+        grouped = chosen_by.reshape(B, T, n_group, E // n_group)
+        score = jax.lax.top_k(grouped, 2)[0].sum(-1)
+        _, best = jax.lax.top_k(score, topk_group)
+        keep = sm._hot(best, n_group).any(axis=-2)
+        chosen_by = jnp.where(keep[..., None], grouped,
+                              -jnp.inf).reshape(B, T, E)
+        kept = keep.sum(axis=(0, 1), dtype=jnp.int32)
+    _, idx = jax.lax.top_k(chosen_by, k)
+    picked = sm._pick(s, idx, E)
+    weights = scale * picked / picked.sum(-1, keepdims=True)
+    by_seq = sm._hot(idx, E).sum(axis=(1, 2), dtype=jnp.int32)
+    f = by_seq.astype(jnp.float32) * (E / (k * T))
+    p = (s / s.sum(-1, keepdims=True)).mean(axis=1)
+    return ((f * p).sum(-1).mean(), weights.reshape(B * T, k),
+            idx.reshape(B * T, k), by_seq.sum(axis=0), kept)
+
+
+@pytest.mark.parametrize("lowering", ["xla", "kernel"])
+@pytest.mark.parametrize("groups", [(1, 1), (8, 4)])
+def test_the_router_is_what_it_was_through_either_lowering(groups, lowering,
+                                                           monkeypatch):
+    """Balance term, weights, experts, counts, groups kept and ``jax.grad``
+    with respect to the logits, bit for bit: through the picker's answer
+    off a TPU (``lax.top_k``'s lines, moved) and through the selection
+    kernel, interpreted; scores on a grid, so experts and groups tie."""
+    import functools
+
+    from deepspeed_tpu.ops.topk_select import topk_select
+
+    B, T, E, k = 2, 128, 128, 8
+    logits = jnp.round(2 * jax.random.normal(jax.random.key(0), (B, T, E))) / 2
+    bias = jnp.round(8 * jax.random.normal(jax.random.key(1), (E,))) / 64
+    r = jax.random.normal(jax.random.key(2), (B * T, k))
+    if lowering == "kernel":
+        monkeypatch.setattr(sm, "topk_select", functools.partial(
+            topk_select, interpret=True))
+
+    def both(route):
+        def objective(logits):
+            aux, weights, *rest = route(logits, bias, k, 2.5, groups)
+            return (weights * r).sum() + 3.0 * aux, (weights, *rest)
+        return jax.value_and_grad(objective, has_aux=True)(logits)
+
+    got, want = both(sm._route_sigmoid), both(_route_sigmoid_as_it_was)
+    assert (got[0][1][-1] is None) == (groups == (1, 1))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_one_group_selects_as_before_bit_for_bit():
     """``n_group`` 1 traces the program the three sigmoid cells have: the
     same jaxpr as a call without groups, and no kept-groups part."""

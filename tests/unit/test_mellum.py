@@ -306,6 +306,8 @@ def test_the_step_record_carries_the_routers_counts():
     assert prog.moe_grouped_lowerings == {"xla": 12}
     # and every dispatch and combine, with its backward, is jnp.take
     assert prog.moe_dispatch_lowerings == {"xla": 16}
+    # and a router's 2 of 8 off a TPU is lax.top_k (ops/topk_select.py)
+    assert prog.moe_topk_lowerings == {"xla": 4}
     assert prog.experts_held == (2, 4, 8)
     assert prog.layer_applications == 4
     dense = TransformerLM(TransformerConfig(hidden_size=64, num_heads=4))

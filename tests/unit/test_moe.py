@@ -647,6 +647,30 @@ def test_sigmoid_routers_pick_is_the_gathers_to_the_bit(ties, monkeypatch):
     assert np.abs(np.asarray(got[1])).sum() > 0
 
 
+@pytest.mark.parametrize("lowering", ["xla", "kernel"])
+def test_softmax_routers_experts_are_lax_top_ks(lowering, monkeypatch):
+    """:func:`sharded_moe._route`'s experts and renormalised weights are
+    those of ``lax.top_k`` over the gates, bit for bit, through the picker's
+    answer off a TPU and through the selection kernel, interpreted (logits
+    on a grid: equal gates a token)."""
+    import functools
+
+    from deepspeed_tpu.moe import sharded_moe as sm
+    from deepspeed_tpu.ops.topk_select import topk_select
+
+    S, E, k = 256, 128, 8
+    logits = jnp.round(2 * jax.random.normal(jax.random.key(0), (S, E))) / 2
+    if lowering == "kernel":
+        monkeypatch.setattr(sm, "topk_select", functools.partial(
+            topk_select, interpret=True))
+    gates, _, vals, idx = sm._route(logits, k)
+    want_vals, want_idx = jax.lax.top_k(gates, k)
+    assert any(len(np.unique(row)) < E - k for row in np.asarray(gates))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(
+        want_vals / jnp.maximum(want_vals.sum(-1, keepdims=True), 1e-9)))
+
+
 def test_the_pick_keeps_the_experts_and_not_the_one_hot():
     """What the pick's derivative keeps from the forward is ``idx``: no
     array of tokens x k x experts elements is a residual (92 MB a layer as
