@@ -2,7 +2,7 @@
 
 A picker that has more than one lowering for an op (a Pallas kernel or its
 ``jax.numpy`` twin, a fused backward or a split one) says which it took with
-:func:`count`, when traced; :func:`note` keeps the one record that is no count.
+:func:`count`, when traced; :func:`note` keeps the records that are no count.
 Whoever traces a program (the engine, round a step program's first call; a
 test) takes a :func:`snapshot` before and reads :func:`since` after, and never
 learns a picker's name. The counts are the proof that a program ran the kernel
@@ -12,7 +12,8 @@ its roofline claims; nothing here is touched in a timed step.
 from typing import Any, Dict, Tuple
 
 _COUNTS: Dict[Tuple[str, str], int] = {}
-_NOTES: Dict[str, Tuple[int, Any]] = {}     # site -> (when, value)
+#: (site, by) -> (when, value)
+_NOTES: Dict[Tuple[str, Any], Tuple[int, Any]] = {}
 _clock = [0]                                # notes written so far
 
 Snapshot = Tuple[Dict[Tuple[str, str], int], int]
@@ -23,10 +24,11 @@ def count(site: str, answer: str, n: int = 1) -> None:
     _COUNTS[site, answer] = _COUNTS.get((site, answer), 0) + n
 
 
-def note(site: str, value: Any) -> None:
-    """``site``'s newest record (the older one is forgotten)."""
+def note(site: str, value: Any, by: Any = None) -> None:
+    """``site``'s newest record (the older one is forgotten); given ``by``,
+    the newest under each ``by``, and the site reads as ``{by: value}``."""
     _clock[0] += 1
-    _NOTES[site] = (_clock[0], value)
+    _NOTES[site, by] = (_clock[0], value)
 
 
 def snapshot() -> Snapshot:
@@ -35,14 +37,20 @@ def snapshot() -> Snapshot:
 
 def since(snap: Snapshot) -> Dict[str, Any]:
     """``{site: {answer: n}}`` of what counted after ``snap``, and ``{site:
-    value}`` of the notes written after it: a site that counted nothing is
-    absent, an answer that counted nothing is left out."""
+    value}`` (``{site: {by: value}}``) of the notes written after it: a site
+    that counted nothing is absent, an answer that counted nothing is left
+    out."""
     counts, clock = snap
     out: Dict[str, Any] = {}
     for (site, answer), n in _COUNTS.items():
         added = n - counts.get((site, answer), 0)
         if added:
             out.setdefault(site, {})[answer] = added
-    out.update({site: value for site, (when, value) in _NOTES.items()
-                if when > clock})
+    for (site, by), (when, value) in _NOTES.items():
+        if when <= clock:
+            continue
+        if by is None:
+            out[site] = value
+        else:
+            out.setdefault(site, {})[by] = value
     return out

@@ -414,6 +414,22 @@ FLASH_BWD = {
     "ouro-cell": (4096, 16, 16, None, "fused"),
     "16k-largest-fused": (16384, 4, 4, None, "fused"),
     "32k-over-budget": (32768, 4, 4, None, "split"),
+    # the window layers of the Laguna cell (one row, 36 heads held over 4)
+    # and of the Mellum2 cell (two rows)
+    "laguna-window-cell": (8192, 36, 4, 512, "fused"),
+    "mellum2-window-cell": (8192, 32, 4, 1024, "fused", 2),
+}
+# ``flash_bwd_tiles`` a head at the cells' shapes: the tiles by arm and, of
+# the masked tiles' 512 x 512 sub-blocks, those worked, skipped and unmasked
+FLASH_BWD_TILES = {
+    "mistral-cell": dict(masked=4, unmasked=6, dead=6, sub_live=12,
+                         sub_dead=4, sub_inside=4),
+    "ouro-cell": dict(masked=4, unmasked=6, dead=6, sub_live=12, sub_dead=4,
+                      sub_inside=4),
+    "laguna-window-cell": dict(masked=15, unmasked=0, dead=49, sub_live=31,
+                               sub_dead=29, sub_inside=0),
+    "mellum2-window-cell": dict(masked=15, unmasked=0, dead=49, sub_live=45,
+                                sub_dead=15, sub_inside=15),
 }
 
 
@@ -660,7 +676,8 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
 
     from deepspeed_tpu.ops import flash_attention as fa
 
-    T, heads, kv_heads, window, took = FLASH_BWD[name]
+    T, heads, kv_heads, window, took, *rows = FLASH_BWD[name]
+    B = rows[0] if rows else 1
     assert fa._bwd_takes_fused(T, 128, fa.DEFAULT_BLOCK_Q,
                                fa.DEFAULT_BLOCK_K, 2) == (took == "fused")
 
@@ -668,9 +685,9 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
         return fa.flash_attention(q, k, v, causal=True, window=window,
                                   interpret=False).astype(jnp.float32).sum()
 
-    q = jax.ShapeDtypeStruct((1, T, heads, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((B, T, heads, 128), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, T, kv_heads, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((B, T, kv_heads, 128), jnp.bfloat16,
                               sharding=one_chip)
     before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -685,12 +702,18 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
     fused = re.findall(rf"= \({five}, {five}\) custom-call\(.*"
                        r"tpu_custom_call", text)
     assert len(fused) == int(took == "fused"), name
+    # a crossed tile in sub-blocks is still that one call, and the counter
+    # says how many of them it works
+    assert ("flash_bwd_tiles" in said) == (took == "fused")
+    if name in FLASH_BWD_TILES:
+        assert said["flash_bwd_tiles"] == {
+            window or "causal": FLASH_BWD_TILES[name]}
     # the forward is one Mosaic call with the results (bf16 out, f32 lse),
     # what metrics/flash_fwd_roofline.json looks for, and its log-sum-exp
     # leaves as the [B, H, 1, T] rows the fused kernel reads
     fwd = re.findall(r"= \(bf16\[[\d,]*\]\{[^}]*\}, (f32\[[\d,]*\])\{[^}]*\}\) "
                      r"custom-call\(.*tpu_custom_call", text)
-    assert fwd == [f"f32[1,{heads},1,{T}]"], (name, fwd)
+    assert fwd == [f"f32[{B},{heads},1,{T}]"], (name, fwd)
     tiles = said["flash_fwd_tiles"]
     assert tiles["rows"]
     if T == 4096:       # the cells: 4 x 4 tiles of 1024 a head, window inert
@@ -1390,10 +1413,17 @@ def test_the_laguna_cells_step_program_compiles_for_v5e(one_chip,
     head gate's operations lie under ``attn_gate`` inside either kind's
     scope and are no kernel; the dense layer's FFN stands under ``mlp`` and
     the four routed layers run the grouped products, the row kernels and
-    the shared expert under ``moe``."""
+    the shared expert under ``moe``. The fused backward's counter reads both
+    kinds: a window head works 31 of its crossed tiles' 60 sub-blocks, a full
+    head 24 of 32."""
+    before = lowerings.snapshot()
     text, mem = _cell_step_program(
         one_chip, monkeypatch, "laguna_s21_train_d5h24e8v8",
         "modelcfg_laguna", 672_126_976, seq=8192)
+    assert lowerings.since(before)["flash_bwd_tiles"] == {
+        512: FLASH_BWD_TILES["laguna-window-cell"],
+        "causal": dict(masked=8, unmasked=28, dead=28, sub_live=24,
+                       sub_dead=8, sub_inside=8)}
     # 6.33 GB of temporaries as compiled here under the file's
     # "dots_saveable" (this plain step casts its weights inside: the
     # engine's own, with the carried copy, compiles to 4.84 beside 9.41 GB

@@ -262,11 +262,14 @@ def test_the_fused_backward_budget_counts_both_widths():
 
 # ---- q and k in the parts the projections write -----------------------------
 
-# (T, dn, dr, dv, tile, keys and values in one array): the fused backward at
-# 128-wide tiles, the split pair at 16-wide ones, at 128 + 64 over 128 and
-# at a toy pair
+# (T, dn, dr, dv, tile, keys and values in one array[, the sub-block's edge
+# in place of the kernels' 512]): the fused backward at 128-wide tiles, the
+# split pair at 16-wide ones, at 128 + 64 over 128 and at a toy pair; with
+# the edge at 32 each diagonal tile is 16 sub-blocks, of which the fused
+# backward skips 6, masks 4 and works 6 unmasked
 IN_PARTS = {
     "192-fused": (256, 128, 64, 128, 128, False),
+    "192-fused-kv-whole-sub-blocks": (256, 128, 64, 128, 128, True, 32),
     "192-fused-kv-whole": (256, 128, 64, 128, 128, True),
     "192-split-kv-whole": (32, 128, 64, 128, 16, True),
     "toy-fused-kv-whole": (256, 16, 8, 16, 128, True),
@@ -276,14 +279,16 @@ IN_PARTS = {
 
 
 @pytest.mark.parametrize("case", sorted(IN_PARTS))
-def test_flash_kernels_take_q_and_k_in_parts(case):
+def test_flash_kernels_take_q_and_k_in_parts(case, monkeypatch):
     """The rope columns of q and the one rope key as operands of their own,
     keys and values an array each or side by side in one, interpreted,
     against ``xla_attention`` on q and k put together: the forward and every
     gradient, the rope key's as the sum over the heads."""
     from deepspeed_tpu.ops import flash_attention as fa
 
-    T, dn, dr, dv, block, kv_whole = IN_PARTS[case]
+    T, dn, dr, dv, block, kv_whole, *sub = IN_PARTS[case]
+    if sub:
+        monkeypatch.setattr(fa, "_SUB", sub[0])
     rng = np.random.default_rng(T + dn + kv_whole)
     B, H = 1, 2
     q, qr, k, kr, v, do = (
@@ -313,6 +318,10 @@ def test_flash_kernels_take_q_and_k_in_parts(case):
         np.testing.assert_allclose(got, ref_g, atol=1e-4, err_msg=name)
     took = lowerings.since(before)
     assert took["flash_bwd"] == {"fused" if block == 128 else "split": 1}
+    if sub:
+        assert took["flash_bwd_tiles"] == {"causal": dict(
+            masked=2, unmasked=1, dead=1, sub_live=20, sub_dead=12,
+            sub_inside=12)}
     # a forward and a backward, each counted as taking the operands
     assert took["flash_rope_operand"] == {"operand": 2}
     # the log-sum-exp variant takes the parts too, gradients through both

@@ -44,6 +44,17 @@ def test_a_note_is_the_newest_written_since():
     assert lowerings.since(snap) == {"site_t": {"masked": 3}}
 
 
+def test_notes_by_a_key_are_the_newest_under_each_written_since():
+    lowerings.note("site_k", {"live": 1}, by=512)
+    snap = lowerings.snapshot()
+    assert "site_k" not in lowerings.since(snap)
+    lowerings.note("site_k", {"live": 2}, by="causal")
+    lowerings.note("site_k", {"live": 3}, by=1024)
+    lowerings.note("site_k", {"live": 4}, by=1024)
+    assert lowerings.since(snap) == {
+        "site_k": {"causal": {"live": 2}, 1024: {"live": 4}}}
+
+
 def test_a_row_reads_its_two_dictionaries_as_attributes():
     from deepspeed_tpu.observability.steplog import StepProgram
 

@@ -104,6 +104,10 @@ class TestFlashAttention:
 # kernel; "long-split" is over the budget and takes the split ones. T != S
 # puts query row t at position t + S - T (rel_offset, xla_attention's
 # end-aligned mask); "dlse" sends a cotangent into the log-sum-exp output.
+# "sub" stands in for the kernel's 512 (``fa._SUB``), so that a tile a
+# boundary crosses is worked in sub-blocks, of which some are dead, some masked
+# and some wholly inside the band; "tiles" pins the counter where the pattern
+# is a training cell's, elsewhere the dense mask says what to expect.
 FUSED_BWD_CASES = {
     "causal-gqa4": dict(H=4, K=1, causal=True),
     "full-mha": dict(H=2, K=2, causal=False),
@@ -115,6 +119,33 @@ FUSED_BWD_CASES = {
     "dlse-mha": dict(H=2, K=2, causal=True, dlse=True),
     "long-split": dict(H=1, K=1, T=65536, S=128, causal=False, block=1024,
                        took="split"),
+    # the causal cells' diagonal tiles (T 4096 in 1,024-wide tiles with an
+    # edge of 512 is these 4 x 4 with one of 64)
+    "sub-diagonal-the-cells-pattern-gqa4": dict(
+        H=4, K=1, causal=True, sub=64,
+        tiles=dict(masked=4, unmasked=6, dead=6, sub_live=12, sub_dead=4,
+                   sub_inside=4)),
+    "sub-diagonal-quarter-edge-mha-dlse": dict(H=2, K=2, causal=True, sub=32,
+                                               dlse=True),
+    # Laguna's window layers: half a tile, so no sub-block is inside the band
+    "sub-window-half-a-tile-gqa4": dict(
+        H=4, K=1, causal=True, window=64, sub=64,
+        tiles=dict(masked=7, unmasked=0, dead=9, sub_live=15, sub_dead=13,
+                   sub_inside=0)),
+    # Mellum2's: a tile, on the tiles' edge
+    "sub-window-on-a-tile-edge-gqa2-dlse": dict(
+        H=4, K=2, causal=True, window=128, sub=64, dlse=True,
+        tiles=dict(masked=7, unmasked=0, dead=9, sub_live=21, sub_dead=7,
+                   sub_inside=7)),
+    "sub-window-ends-inside-a-tile-mha-dlse": dict(
+        H=2, K=2, causal=True, window=200, sub=32, dlse=True),
+    "sub-window-of-one-key-gqa2": dict(H=4, K=2, causal=True, window=1,
+                                       sub=64),
+    "sub-rect-rel-offset-gqa2-dlse": dict(H=4, K=2, T=256, causal=True,
+                                          sub=32, dlse=True),
+    "sub-rect-window-rel-offset-gqa4-dlse": dict(
+        H=4, K=1, T=256, causal=True, window=300, sub=32, dlse=True),
+    "sub-full-mha": dict(H=2, K=2, causal=False, sub=32),
 }
 
 
@@ -134,9 +165,29 @@ def _ref_lse(q, k, causal, window):
     return jax.scipy.special.logsumexp(s, axis=-1)[..., None]
 
 
+def _dense_keep(T, S, causal, window):
+    """[T, S] of the pairs attention keeps, query row t at position
+    t + S - T."""
+    gap = (np.arange(T)[:, None] + S - T) - np.arange(S)[None, :]
+    keep = np.ones((T, S), bool)
+    if causal:
+        keep &= gap >= 0
+    if window is not None:
+        keep &= gap < window
+    return keep
+
+
+def _some_every(keep, bq, bk):
+    """Per ``[bq, bk]`` tile of a dense mask: keeps a pair, keeps them all."""
+    per_tile = keep.reshape(keep.shape[0] // bq, bq, keep.shape[1] // bk, bk)
+    return per_tile.any((1, 3)), per_tile.all((1, 3))
+
+
 @pytest.mark.parametrize("name", sorted(FUSED_BWD_CASES))
-def test_fused_backward(name):
+def test_fused_backward(name, monkeypatch):
     case = dict(FUSED_BWD_CASES[name])
+    if "sub" in case:
+        monkeypatch.setattr(fa, "_SUB", case["sub"])
     H, K, causal = case["H"], case["K"], case["causal"]
     S = case.get("S", 512)
     T = case.get("T", S)
@@ -161,8 +212,25 @@ def test_fused_backward(name):
         delta = delta - jax.random.normal(keys[4], delta.shape, jnp.float32)
     # the fused kernel reads its statistics as rows, the split pair as columns
     row, col = (1, H, 1, T), (1, H, T, 1)
+    before = lowerings.snapshot()
     fused = fa._bwd_fused_call(qt, kt, vt, do, lse.reshape(row),
                                delta.reshape(row), interpret=True, **kernel_kw)
+    # the counter: a head's tiles by arm and the sub-blocks of its crossed
+    # tiles, as the dense mask has them
+    hq, w = (min(case.get("sub", 512), b) for b in (bq, bk))
+    keep = _dense_keep(T, S, causal, window)
+    some, every = _some_every(keep, bq, bk)
+    sub_some, sub_every = _some_every(keep, hq, w)
+    masked = np.repeat(np.repeat(some & ~every, bq // hq, 0), bk // w, 1)
+    want = dict(masked=int((some & ~every).sum()), unmasked=int(every.sum()),
+                dead=int((~some).sum()),
+                sub_live=int((masked & sub_some).sum()),
+                sub_dead=int((masked & ~sub_some).sum()),
+                sub_inside=int((masked & sub_every).sum()))
+    assert want == case.get("tiles", want)
+    # (kept under the mask's name: a model's window and full layers both read)
+    want = {window or ("causal" if causal else "none"): want}
+    assert lowerings.since(before)["flash_bwd_tiles"] == want
     split = fa._bwd_split_call(qt, kt, vt, do, lse.reshape(col),
                                delta.reshape(col), interpret=True, **kernel_kw)
     for a, b in zip(fused, split):
@@ -198,7 +266,9 @@ def test_fused_backward(name):
 
     before = lowerings.snapshot()
     g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    assert lowerings.since(before)["flash_bwd"] == {took: 1}
+    said = lowerings.since(before)
+    assert said["flash_bwd"] == {took: 1}
+    assert said.get("flash_bwd_tiles") == (want if took == "fused" else None)
     g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
@@ -245,7 +315,7 @@ def test_flash_forward_tiles(name, monkeypatch):
     bq = min(case.get("block_q", case.get("block", 16)), T)
     bk = min(case.get("block_k", case.get("block", 16)), S)
     if case.get("sub", 8) is not None:
-        monkeypatch.setattr(fa, "_FWD_SUB", case.get("sub", 8))
+        monkeypatch.setattr(fa, "_SUB", case.get("sub", 8))
     rel = S - T
     q, k, v = _qkv(T=T, S=S, H=H, K=K, d=d)
     kw = dict(causal=causal, window=window, block_q=bq, block_k=bk,
@@ -267,14 +337,7 @@ def test_flash_forward_tiles(name, monkeypatch):
 
     # (2) the counter: tiles by arm as the dense mask has them, and the
     # layout the kernel's own lse left in
-    gap = (np.arange(T)[:, None] + rel) - np.arange(S)[None, :]
-    keep = np.ones((T, S), bool)
-    if causal:
-        keep &= gap >= 0
-    if window is not None:
-        keep &= gap < window
-    per_tile = keep.reshape(T // bq, bq, S // bk, bk)
-    some, every = per_tile.any((1, 3)), per_tile.all((1, 3))
+    some, every = _some_every(_dense_keep(T, S, causal, window), bq, bk)
     rows = bq == T or bq % 128 == 0
     want = dict(masked=int((some & ~every).sum()), unmasked=int(every.sum()),
                 dead=int((~some).sum()), rows=rows)

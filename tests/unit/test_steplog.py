@@ -178,6 +178,7 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
     # XLA attention on the CPU: the program holds no flash backward
     assert rows[0].flash_bwd_lowerings is None
     assert rows[0].flash_fwd_tiles is None
+    assert rows[0].flash_bwd_tiles is None
     # (only where the weights were cast: the stacks' nine leaves carried,
     # the tied table cast in the step at the gather and at the head)
     assert rows[0].counted == {"weight_cast": {"carried": 9, "in_step": 2}}
@@ -207,8 +208,9 @@ def test_program_table_one_row_a_build_and_analysis_on_request(monkeypatch):
 def test_program_row_counts_the_flash_backwards_it_lowered():
     """The row of a program whose attention is the flash kernel says which
     backward kernel its trace took (counted in ``_bwd_pallas``) and which arms
-    its forward's tiles take (``_fwd_pallas``), once the first call has traced
-    it; later calls leave it alone."""
+    its forward's and its fused backward's tiles take (``_fwd_pallas``,
+    ``_bwd_fused_call``), once the first call has traced it; later calls
+    leave it alone."""
     before = len(steplog.programs())
     eng = _engine(attention_impl="flash_pallas")
     batch = {"input_ids": np.zeros((2, 32), np.int32)}
@@ -220,6 +222,10 @@ def test_program_row_counts_the_flash_backwards_it_lowered():
     # tile, which the diagonal crosses; a [1, 32] row is the whole sequence
     tiles = dict(row.flash_fwd_tiles)
     assert tiles == {"masked": 1, "unmasked": 0, "dead": 0, "rows": True}
+    # the backward's: the one crossed tile is one sub-block, masked
+    assert row.flash_bwd_tiles == {"causal": {
+        "masked": 1, "unmasked": 0, "dead": 0, "sub_live": 1, "sub_dead": 0,
+        "sub_inside": 0}}
     eng.fused_train_step(batch)
     assert row.flash_bwd_lowerings == said and row.flash_fwd_tiles == tiles
 
