@@ -56,7 +56,8 @@ OURO_TENSORS = {
 _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
                               "olmo_hybrid", "nemotron_h", "lfm2_moe",
-                              "bailing_hybrid", "KeyeVL2", "laguna")
+                              "bailing_hybrid", "KeyeVL2", "laguna",
+                              "sdar_moe")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
@@ -65,7 +66,7 @@ _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
 #: load: no description of the tensor names was at hand, and none is guessed
 _CONFIG_ONLY = ("mellum", "granitemoehybrid", "deepseek_v3", "olmo_hybrid",
                 "nemotron_h", "lfm2_moe", "bailing_hybrid", "KeyeVL2",
-                "laguna")
+                "laguna", "sdar_moe")
 #: ``model_type: "KeyeVL2"``: the keys of ``sa_config`` (the indexer and the
 #: set a query keeps) -> the fields here
 _KEYE_SA = {"indexer_num_heads": "dsa_index_heads",
@@ -590,6 +591,42 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             moe_dispatch="grouped", moe_aux_loss_coef=0.001,
             **{field: int(sa[key]) for key, field in _KEYE_SA.items()
                if key in sa})
+    elif model_type == "sdar_moe":
+        # SDAR's mixture of experts: the Qwen3-MoE block (grouped-query
+        # attention under a per-head RMSNorm on q and k, a plain rope, every
+        # FFN ``num_experts`` softmax-routed experts, the top k
+        # renormalised), trained and decoded by diffusion over blocks. The
+        # block's length and the mask token are no keys of ``config.json``:
+        # ``diffusion_block`` and ``mask_token_id`` come as overrides, and
+        # without them the config is the next-token model of the same block.
+        # The config side only
+        if (get("mlp_only_layers") or get("decoder_sparse_step", 1) != 1
+                or not get("norm_topk_prob", True)
+                or get("attention_bias", False)
+                or get("use_sliding_window", False)
+                or get("sliding_window") is not None
+                or get("rope_scaling")):
+            raise ValueError(
+                "sdar_moe with dense FFN layers (mlp_only_layers, "
+                "decoder_sparse_step), without norm_topk_prob, with "
+                "attention biases, a sliding window or rope_scaling is not "
+                "mapped")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=get("num_hidden_layers"),
+            num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            intermediate_size=get("intermediate_size"),   # no layer uses it
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            rope_theta=float(get("rope_theta", 10000.0)),
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            qk_norm="head",
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=get("moe_intermediate_size"),
+            moe_dispatch="grouped", moe_aux_loss_coef=0.001)
     elif model_type == "laguna":
         # window and full attention layers in turn (``layer_types``), each
         # kind with its own number of query heads over the same key-value

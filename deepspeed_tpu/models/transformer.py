@@ -306,6 +306,16 @@ class TransformerConfig:
     dsa_q_chunk: int = 512
     dsa_kv_chunk: int = 512
     indexer_loss_coef: float = 1.0
+    # block-diffusion training (models/block_diffusion.py): a row of L
+    # tokens is trained as ``[noised ; clean]``, 2L positions at positions
+    # ``0..L-1`` twice, under the block-diffusion mask over blocks of
+    # ``diffusion_block`` tokens (a power of two), the loss over the noised
+    # half alone at the positions the noise hid, each weighted by the batch's
+    # ``loss_weights``; the batch carries ``noised_ids`` and ``loss_weights``
+    # beside ``input_ids`` (runtime/data_pipeline/block_noise.py), whose ids
+    # never are ``mask_token_id``. None = next-token training
+    diffusion_block: Optional[int] = None
+    mask_token_id: Optional[int] = None
 
     def __post_init__(self):
         is_llama = self.arch == "llama"
@@ -536,6 +546,39 @@ class TransformerConfig:
                     "layers), "
                     "heads_held, residual_multiplier or attention_impl="
                     "'fpdt'")
+        if self.has_bd:
+            n, mask = self.diffusion_block, self.mask_token_id
+            if n < 1 or n & (n - 1):
+                raise NotImplementedError(
+                    f"diffusion_block={n}: the flash kernels round the "
+                    f"diagonal to blocks whose length is a power of two")
+            if mask is None or not 0 <= mask < self.vocab_size:
+                raise ValueError(
+                    f"block diffusion (diffusion_block={n}) needs "
+                    f"mask_token_id={mask} among the vocab_size="
+                    f"{self.vocab_size} rows the model holds")
+            if (self.looped or self.parallel_block or self.one_branch
+                    or self.loss_tiling > 1 or self.attn_pattern is not None
+                    or self.sliding_window is not None or self.has_mla
+                    or self.mrope_section is not None
+                    or not self.use_rope or self.learned_pos
+                    or self.exit_loss_beta is not None
+                    or self.attention_impl not in ("auto", "xla", "flash",
+                                                   "flash_pallas")):
+                raise NotImplementedError(
+                    f"block diffusion (diffusion_block={n}) trains one "
+                    f"pre-norm pass of plain attention layers of one kind "
+                    f"under a rope, over whole rows with whole logits: not "
+                    f"a looped stack (num_passes > 1, sandwich_norm or the "
+                    f"exit gate: each pass would need its own noised row), "
+                    f"parallel_block, one_branch, the tiled loss "
+                    f"(loss_tiling > 1: it shifts the labels and weighs no "
+                    f"position), an attn_pattern, sliding_window (a window "
+                    f"beside the rounded diagonal), latent attention, "
+                    f"mrope_section, use_rope=False / learned positions "
+                    f"(the two halves repeat their positions through the "
+                    f"rope), or attention_impl='fpdt' / 'ring' / 'ulysses' "
+                    f"(their chunks know the causal mask only)")
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"norm_placement={self.norm_placement!r}: "
                              f"'pre' or 'post'")
@@ -742,6 +785,13 @@ class TransformerConfig:
         return "dsa" in (self.attn_pattern or ())
 
     @property
+    def has_bd(self) -> bool:
+        """Whether the model trains by block diffusion
+        (``diffusion_block``): a ``[noised ; clean]`` row under the
+        block-diffusion mask."""
+        return self.diffusion_block is not None
+
+    @property
     def heads_here(self) -> int:
         """The attention heads this model holds (``heads_held``, else all)."""
         return self.heads_held or self.num_heads
@@ -784,7 +834,7 @@ class TransformerConfig:
         return (self.has_ssm or self.has_mla or self.has_delta
                 or self.has_conv or self.has_kda or self.has_dsa
                 or self.one_branch or bool(self.heads_by_kind)
-                or self.gates_plain_heads)
+                or self.gates_plain_heads or self.has_bd)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -820,7 +870,8 @@ class TransformerConfig:
         (``_run_periods``)."""
         return (len(set(self.layer_kinds)) > 1 or self.has_ssm
                 or self.has_mla or self.has_delta or self.has_conv
-                or self.has_kda or self.has_dsa or self.one_branch)
+                or self.has_kda or self.has_dsa or self.one_branch
+                or self.has_bd)
 
     def kind_cfg(self, kind: str) -> "TransformerConfig":
         """The configuration a block of ``kind`` runs under: no window on a
@@ -1284,7 +1335,10 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         # the kernels scale the scores by 1/sqrt(d); q carries the rest
         # (Granite's 1/64 at d = 64: a factor of 1/8, exact in bf16)
         q = _times(q, cfg.attention_multiplier * math.sqrt(hd))
-    if cfg.sliding_window is not None:
+    if cfg.has_bd:
+        # the ``[noised ; clean]`` row under the block-diffusion mask
+        out = _bd_attend(q, k, v, cfg)
+    elif cfg.sliding_window is not None:
         # windowed families (mistral/qwen2): the flash kernel takes the
         # window natively (block-skipping); impls without window support
         # (ring/ulysses SP wrappers) fall back to the masked XLA path
@@ -1307,6 +1361,31 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             ).astype(x.dtype)
     o = attn_out_proj(out, w, cfg)
     return constrain(o, P(("dp", "fsdp"), "sp", None))
+
+
+def _bd_attend(q: jax.Array, k: jax.Array, v: jax.Array,
+               cfg: TransformerConfig) -> jax.Array:
+    """Attention of a block-diffusion row (models/block_diffusion.py): the
+    flash kernels under the rounded diagonal where a Mosaic call runs whole
+    (the TPU, no mesh axis to partition over; ``attention_impl=
+    "flash_pallas"`` forces them, interpreted off the TPU), else the same
+    mask as a dense softmax, with a warning on the TPU."""
+    from deepspeed_tpu import ops
+    from deepspeed_tpu.models import block_diffusion as bd
+
+    block = cfg.diffusion_block
+    if cfg.attention_impl == "flash_pallas" or (
+            cfg.attention_impl != "xla" and ops.mosaic_runs_whole()):
+        return bd.attention(q, k, v, block)
+    if cfg.attention_impl != "xla" and ops.on_tpu():
+        from deepspeed_tpu.utils.logging import logger
+
+        logger.warning(
+            f"block diffusion: the flash kernels cannot run per shard on "
+            f"this mesh — a dense softmax over q{q.shape}'s "
+            f"{q.shape[1]} x {q.shape[1]} scores runs instead")
+    with jax.named_scope(bd.CROSS_SCOPE):
+        return bd.dense_attention(q, k, v, block)
 
 
 def _cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1423,7 +1502,11 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                # scores, the threshold and the set, the attention over the
                # set, the indexer's loss with its gradient
                "attn_dsa", "dsa_indexer", "dsa_select", "dsa_attend",
-               "dsa_loss")
+               "dsa_loss",
+               # block diffusion's attention inside attn
+               # (models/block_diffusion.py): the flash calls under the
+               # rounded diagonal, the own-block term and its merge
+               "bd_cross", "bd_own")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind. Layers of one branch
 #: each (``one_branch``) are half a block: a period of up to twice as many,
@@ -1612,7 +1695,12 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             attn_out = attention_block(hn1, wc["attn"], cfg, freqs, attn_fn,
                                        positions=positions)
         if mix_ms:
-            ms = jnp.mean(jnp.square(attn_out.astype(jnp.float32)))
+            ms = {"mix_out_ms": jnp.mean(jnp.square(
+                attn_out.astype(jnp.float32)))}
+            if cfg.has_bd:
+                from deepspeed_tpu.models.block_diffusion import early_ms
+
+                ms["bd_early_ms"] = early_ms(attn_out)
         if cfg.sandwich_norm or post:
             attn_out = _norm(attn_out, wc["ln1_post"], cfg.norm, cfg.norm_eps)
         if res != 1.0:
@@ -1640,7 +1728,7 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
             # reports no balance term
             if not isinstance(aux, dict):
                 aux = {} if ffn_kind == "dense" else {"lb": aux}
-            aux = {**aux, "mix_out_ms": ms}
+            aux = {**aux, **ms}
         if kind == "dsa":
             aux = {**(aux if isinstance(aux, dict) else {"lb": aux}),
                    "indexer_loss": of_set[0], "dsa_probe_sets": of_set[1]}
@@ -1761,7 +1849,14 @@ def _lm_targets(batch: Dict[str, jax.Array]):
 
 def lm_loss(cfg: TransformerConfig, logits: jax.Array,
             batch: Dict[str, jax.Array]) -> jax.Array:
-    """Next-token / labeled cross-entropy with masking and optional z-loss."""
+    """Next-token / labeled cross-entropy with masking and optional z-loss;
+    under block diffusion the noised half's logits [B, L, V] against the
+    clean ids at the same positions (no shift), each position times the
+    batch's ``loss_weights``, over the B L tokens."""
+    if cfg.has_bd:
+        nll = token_cross_entropy(logits, batch["input_ids"], cfg.z_loss)
+        w = batch["loss_weights"].astype(jnp.float32)
+        return (w * nll).sum() / w.size
     labels, lmask = _lm_targets(batch)
     nll = token_cross_entropy(logits, labels, cfg.z_loss)
     denom = jnp.maximum(lmask.sum(), 1)
@@ -1942,6 +2037,13 @@ class TransformerLM:
         heads, and for one with the Granite multipliers (only
         ``transformer_block`` and the train forward apply them)."""
         cfg = self.cfg
+        if cfg.has_bd:
+            raise NotImplementedError(
+                f"{what} runs a row of tokens under the causal mask and the "
+                f"next-token loss: this model trains by block diffusion "
+                f"(diffusion_block={cfg.diffusion_block}: a [noised ; "
+                f"clean] row under the block-diffusion mask, decoded a "
+                f"block at a time); only the train step runs it")
         if cfg.attn_differs_by_kind:
             raise NotImplementedError(
                 f"{what} reads one stack of attention leaves with one head "
@@ -2108,6 +2210,22 @@ class TransformerLM:
 
                 facts["dsa_selected_share"] = selected_share(
                     int(batch_shape[1]), cfg.dsa_topk)
+        if cfg.has_bd:
+            # block diffusion: the block's length, the positions the layers
+            # run for each token of a row (the noised copy and the clean
+            # one) and, once the rows' length is known, the positions of a
+            # row the head reads (the noised half) and what the flash
+            # kernels do with a head of a row under the three rounded
+            # diagonals (``block_diffusion.kernel_tiles``: tiles by arm,
+            # the crossed tiles' sub-blocks, the pairs worked and kept)
+            facts["diffusion_block"] = cfg.diffusion_block
+            facts["positions_per_token"] = 2
+            if batch_shape is not None:
+                from deepspeed_tpu.models import block_diffusion as bd
+
+                facts["head_rows"] = int(batch_shape[1])
+                facts["bd_mask_tiles"] = bd.kernel_tiles(
+                    int(batch_shape[1]), cfg.diffusion_block)
         if cfg.has_mla:
             # (key width, value width) of a head where they differ (latent
             # attention: the flash kernels take both)
@@ -2462,6 +2580,9 @@ class TransformerLM:
         """Final-norm hidden states [B, T, D] (everything before the LM
         head) — the input of the tiled logits loss; of a looped model, the
         last pass's."""
+        if self.cfg.has_bd:
+            self._one_pass_only("a forward over a plain row (hidden_states, "
+                                "logits)")
         return self._hidden_passes(params, input_ids, positions, ltd_seed,
                                    pld_theta)[0][-1]
 
@@ -2469,7 +2590,8 @@ class TransformerLM:
                        positions: Optional[jax.Array] = None,
                        ltd_seed: Optional[jax.Array] = None,
                        pld_theta: Optional[jax.Array] = None,
-                       rope_positions: Optional[jax.Array] = None):
+                       rope_positions: Optional[jax.Array] = None,
+                       head_rows: Optional[int] = None):
         """``([h_1 .. h_R], aux)``: the final-norm hidden states after each
         of the ``cfg.num_passes`` passes of the layer stack, and the MoE aux
         loss summed over layers and passes (with a held share of the experts
@@ -2477,7 +2599,9 @@ class TransformerLM:
         :func:`_layer_aux`). Every pass reads the same stacked
         weights, cast once; the final norm closes a pass and its output is
         what the next pass reads, so one backward sums each weight's gradient
-        over its uses."""
+        over its uses. ``head_rows``: the final norm and what follows read
+        the first that many positions of a row only (block diffusion's
+        noised half)."""
         cfg = self.cfg
         dt = jnp.dtype(cfg.dtype)
         if pld_theta is not None:
@@ -2508,6 +2632,8 @@ class TransformerLM:
                 x, a = self._run_layers(layers, x, input_ids, attn_fn,
                                         ltd_seed, pld_theta, rope_positions)
             with jax.named_scope("final_norm"):
+                if head_rows is not None:
+                    x = x[:, :head_rows]
                 x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
                 x = constrain(x, P(("dp", "fsdp"), "sp", None))
             hs.append(x)
@@ -2725,11 +2851,14 @@ class TransformerLM:
             self._one_pass_only("the tiled logits loss (loss_tiling > 1)")
         seed = batch.get("ltd_seed")
         pld = batch.get("pld_theta")
-        hs, aux = self._hidden_passes(
-            params, batch["input_ids"],
-            ltd_seed=None if seed is None else seed[0],
-            pld_theta=None if pld is None else pld[0],
-            rope_positions=self._rope_positions(batch))
+        if cfg.has_bd:
+            hs, aux = self._bd_hidden(params, batch)
+        else:
+            hs, aux = self._hidden_passes(
+                params, batch["input_ids"],
+                ltd_seed=None if seed is None else seed[0],
+                pld_theta=None if pld is None else pld[0],
+                rope_positions=self._rope_positions(batch))
         if cfg.exit_loss_beta is not None:
             loss, parts = self._expected_exit_loss(params, batch, hs)
         else:
@@ -2741,6 +2870,15 @@ class TransformerLM:
                 loss = (self._tiled_loss(params, batch, hs[-1])
                         if logits is None else lm_loss(cfg, logits, batch))
             parts = {}
+        if cfg.has_bd:
+            with jax.named_scope("loss"):
+                # the positions the noise hid, and their weights' sum
+                w = batch["loss_weights"]
+                parts = {**parts, "bd_masked_targets": jnp.sum(w > 0),
+                         "bd_weight_sum": jnp.sum(w.astype(jnp.float32)),
+                         # by layer, the mixer output's mean square over
+                         # the first positions of each half
+                         "bd_early_ms": aux["bd_early_ms"]}
         if cfg.reports_mixer_outputs:
             # by layer, the mean square of the mixer's output
             parts = {**parts, "mix_out_ms": aux["mix_out_ms"]}
@@ -2770,6 +2908,44 @@ class TransformerLM:
                     aux = aux["lb"]
                 loss = loss + cfg.moe_aux_loss_coef * aux
         return loss, parts
+
+    def _bd_hidden(self, params: Params, batch: Dict[str, jax.Array]):
+        """:meth:`_hidden_passes` of a block-diffusion batch: the layers run
+        the ``[noised ; clean]`` row of ``2L`` positions at positions
+        ``0..L-1`` twice (models/block_diffusion.py), the final norm and the
+        head read the noised half."""
+        from deepspeed_tpu.models import block_diffusion as bd
+
+        cfg = self.cfg
+        ids = batch["input_ids"]
+        rows, L = ids.shape
+        missing = [k for k in ("noised_ids", "loss_weights")
+                   if k not in batch]
+        if missing or L % cfg.diffusion_block or any(
+                k in batch for k in ("segment_ids", "attention_mask",
+                                     "labels", "ltd_seed", "pld_theta")):
+            raise NotImplementedError(
+                f"block diffusion (diffusion_block={cfg.diffusion_block}) "
+                f"takes a batch of input_ids, noised_ids and loss_weights "
+                f"[rows, L], L a whole number of blocks "
+                f"(runtime/data_pipeline/block_noise.py): this one has "
+                f"{sorted(batch)} at L={L}; segment_ids, attention_mask and "
+                f"labels (document boundaries and padding under the rounded "
+                f"diagonal), random-LTD and progressive layer drop are not "
+                f"implemented for it")
+        with jax.named_scope("embed"):
+            row = jnp.concatenate(
+                [batch["noised_ids"].astype(ids.dtype), ids], axis=1)
+            positions = bd.row_positions(rows, L)
+        return self._hidden_passes(params, row, rope_positions=positions,
+                                   head_rows=L)
+
+    def bd_logits(self, params: Params,
+                  batch: Dict[str, jax.Array]) -> jax.Array:
+        """The noised half's logits [rows, L, V] of a block-diffusion
+        batch: position ``i`` of block ``b`` predicts ``x0_i`` from the
+        noised block ``b`` and the clean blocks before it."""
+        return self._project(params, self._bd_hidden(params, batch)[0][-1])
 
     def _rope_positions(self, batch: Dict[str, jax.Array]
                         ) -> Optional[jax.Array]:

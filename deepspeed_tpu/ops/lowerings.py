@@ -7,6 +7,17 @@ Whoever traces a program (the engine, round a step program's first call; a
 test) takes a :func:`snapshot` before and reads :func:`since` after, and never
 learns a picker's name. The counts are the proof that a program ran the kernel
 its roofline claims; nothing here is touched in a timed step.
+
+The flash kernels' sites (``ops/flash_attention.py``) say which mask a call
+ran under, because the masks the kernels take are few: none, the causal
+diagonal, a sliding window beside it, and the diagonal rounded to blocks
+(``diag``: block diffusion's two calls a layer). ``flash_bwd_tiles`` is keyed
+by the mask (the window's length, ``"causal"``, ``"none"``, or
+``"diag<n>"`` / ``"diag<n>_strict"``), ``flash_diag_fwd_tiles`` by the rounded
+diagonal's label alone, ``flash_fwd_tiles`` is the newest forward's whatever
+its mask. A mask the kernels do not take leaves no record here: a call with
+``segment_ids`` is ``xla_attention``'s (dense), and with ``diag`` besides it is
+refused by name.
 """
 
 from typing import Any, Dict, Tuple

@@ -6,6 +6,7 @@ data_sampler.py:36), ``indexed_dataset.py`` mmap binary datasets, and the ALST
 sequence-sharding loader (``UlyssesSPDataLoaderAdapter`` ulysses_sp.py:564).
 """
 
+from deepspeed_tpu.runtime.data_pipeline.block_noise import noise_batch  # noqa: F401
 from deepspeed_tpu.runtime.data_pipeline.curriculum import CurriculumScheduler  # noqa: F401
 from deepspeed_tpu.runtime.data_pipeline.data_sampler import (  # noqa: F401
     DataEfficiencySampler,

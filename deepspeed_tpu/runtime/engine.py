@@ -741,7 +741,8 @@ class DeepSpeedTpuEngine:
             if k == "position_ids" and arr.ndim == 3:
                 arr = arr[..., :diff]       # [axes, B, T]
             elif arr.ndim >= 2 and arr.shape[1] > diff and k in (
-                    "input_ids", "labels", "attention_mask", "position_ids"):
+                    "input_ids", "labels", "attention_mask", "position_ids",
+                    "noised_ids", "loss_weights"):
                 arr = arr[:, :diff]
             out[k] = arr
         return out
@@ -1212,6 +1213,17 @@ class DeepSpeedTpuEngine:
             self._rule_moves_only_here(
                 "the fused offload, 1-bit and ZeRO++ step programs "
                 "(ds_train_step_offload, _onebit, _zpp)")
+            block = getattr(getattr(self.module, "cfg", None),
+                            "diffusion_block", None)
+            if block is not None:
+                raise NotImplementedError(
+                    f"the fused offload, 1-bit and ZeRO++ step programs "
+                    f"(ds_train_step_offload, _onebit, _zpp) have not been "
+                    f"run with a block-diffusion batch (diffusion_block="
+                    f"{block}: noised_ids and loss_weights beside "
+                    f"input_ids, the loss's parts in the step record); only "
+                    f"fused_train_step's plain step program "
+                    f"(ds_train_step) has")
         if self._offload is not None:
             return self._guarded_loss(self._fused_offload_step(batch, ga))
         if self._onebit is not None:

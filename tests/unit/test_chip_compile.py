@@ -992,12 +992,15 @@ def test_kernel_path_rules_match_what_compiled():
 
 def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
                        parameters: int, seq: int = 4096, rows: int = 1,
-                       position_axes: int = 0):
+                       position_axes: int = 0, noised: bool = False,
+                       **overrides):
     """A benchmark cell's whole step (``benchmarks/configs/<config>.json``
     at ``rows`` x ``seq`` tokens, the file's recomputation policy): gradient and
     AdamW over fp32 master weights, compiled for the chip, every picker
     answering as on a TPU. With ``position_axes`` the batch also holds
-    ``position_ids`` [axes, rows, seq]."""
+    ``position_ids`` [axes, rows, seq], with ``noised`` a block-diffusion
+    batch's ``noised_ids`` and ``loss_weights``; ``overrides`` go to the
+    file's mapping (another recomputation policy)."""
     import importlib
     import json
     import os
@@ -1022,7 +1025,7 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
         cfg = json.load(f)
     model = TransformerLM(importlib.import_module(
         "benchmarks." + modelcfg).transformer_config(
-            cfg, max_seq_len=seq, param_dtype="float32"))
+            cfg, max_seq_len=seq, param_dtype="float32", **overrides))
     tx = build_optimizer("adamw", {"lr": 1e-6}, lr_schedule=None,
                          gradient_clipping=0.0)
 
@@ -1045,6 +1048,10 @@ def _cell_step_program(one_chip, monkeypatch, config: str, modelcfg: str,
     if position_axes:
         batch["position_ids"] = jax.ShapeDtypeStruct(
             (position_axes, rows, seq), jnp.int32, sharding=one_chip)
+    if noised:
+        batch["noised_ids"] = batch["input_ids"]
+        batch["loss_weights"] = jax.ShapeDtypeStruct(
+            (rows, seq), jnp.float32, sharding=one_chip)
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         described(params), described(jax.eval_shape(tx.init, params)),
         batch).compile()
@@ -1464,3 +1471,106 @@ def test_the_laguna_cells_step_program_compiles_for_v5e(one_chip,
     assert all("/moe/" in n for n in experts + moves)
     assert "/moe/moe_shared/" in text and "ragged-dot" not in text
     assert "/moe/moe_router/" in text and "/mlp/" in text
+
+
+#: temporaries of the SDAR cell's step as compiled here, by policy (bytes,
+#: upper bounds a hundredth above what was read: 8.048 and 6.270 GB, beside
+#: 6.61 GB of arguments). The test compiles the file's policy; the other is
+#: ``remat_policy="full"`` passed to the same helper (70 s more)
+SDAR_TEMP = {"attn_saveable": 8.15e9, "full": 6.35e9}
+
+
+def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
+                                                      policy="attn_saveable"):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    sdar_30b_a3b_train_d5e16v8.json``: five layers of grouped-query attention
+    trained by block diffusion, 16 of 128 experts, at the published widths,
+    one row of 8,192 tokens as 16,384 positions, under the file's policy).
+    It fits beside what a chip reserves; each layer's attention is
+    **two calls of the flash kernels over the clean keys under the rounded
+    diagonal**, forward and fused backward, under ``attn/attn_full/bd_cross``
+    with the names the benchmark's patterns look for, each over 8,192 query
+    rows and 8,192 keys (neither sees a noised key), and a third under
+    ``bd_own``, the noised half over its own blocks as 32 sequences of 256
+    (a tile each: no dead grid step); **no array of the program is as
+    large as three heads' ``[L, L]`` scores and none has a query and a key
+    dimension, let alone ``[2L, 2L]``**; no
+    gather or scatter stands under the mixer's scopes (the repeated positions
+    read the rope's table once a layer, a position); the head's product runs
+    over 8,192 rows, not 16,384; and the five routed layers run the grouped
+    products and the row kernels under ``moe`` over both halves."""
+    import json
+    import os
+
+    L = 8192
+    with open(os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), "benchmarks", "configs",
+            "sdar_30b_a3b_train_d5e16v8.json")) as f:
+        assert json.load(f)["deployment"]["remat_policy"] == policy
+    before = lowerings.snapshot()
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "sdar_30b_a3b_train_d5e16v8", "modelcfg_sdar",
+        550_984_960, seq=L, noised=True, remat_policy=policy)
+    print(f"sdar temp {policy}: {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB")
+    assert mem.temp_size_in_bytes < SDAR_TEMP[policy]
+    counted = lowerings.since(before)
+    arms = dict(masked=8, unmasked=28, dead=28, sub_live=24, sub_dead=8,
+                sub_inside=8)
+    assert counted["flash_bwd_tiles"] == {
+        "diag4": arms, "diag4_strict": arms,
+        # (a segment's one tile, one sub-block)
+        "diag4_own": dict(masked=1, unmasked=0, dead=0, sub_live=1,
+                          sub_dead=0, sub_inside=0)}
+    assert counted["flash_diag_fwd_tiles"] == counted["flash_bwd_tiles"]
+    assert counted["flash_bwd"] == {"fused": 3}
+    for scope in ("bd_cross", "bd_own"):
+        calls = _kernel_calls(text, scope)
+        assert calls and all(f"/attn/attn_full/{scope}/" in n for n in calls)
+        assert any("transpose(" in n for n in calls)
+    own = re.findall(
+        r"^\s*%bd_own[.\d]* = \(bf16\[32,32,256,128\]\S*, "
+        r"f32\[32,32,1,256\]\S*\) custom-call\(.*tpu_custom_call", text, re.M)
+    assert own
+    fwd = re.findall(
+        r"^\s*%bd_cross[.\d]* = \(bf16\[1,32,8192,128\]\S*, "
+        r"f32\[1,32,1,8192\]\S*\) custom-call\(.*tpu_custom_call", text, re.M)
+    bwd = re.findall(
+        r"^\s*%bd_cross[.\d]* = \(bf16\[1,32,8,1024,128\]\S*, "
+        r"bf16\[2,1,32,8192,128\]\S*\) custom-call\(.*tpu_custom_call",
+        text, re.M)
+    # a layer's two halves forward (the body of the layer scan; once more in
+    # the backward's region where the policy keeps nothing of them) and
+    # backward
+    again = "bd_cross" in steplog.recomputed_kernels(text)
+    assert again == (policy == "full")
+    assert len(fwd) == (4 if again else 2) and len(bwd) == 2
+    # no Mosaic call of the mixer takes an operand of 16,384 rows
+    for line in text.splitlines():
+        if "tpu_custom_call" in line and re.search(r"%bd_(cross|own)", line):
+            assert ",16384," not in line.split("custom-call(")[1] \
+                .split("custom_call_target")[0]
+    sizes = [eval("*".join(dims.split(",")))  # noqa: S307 (digits and commas)
+             for dims in re.findall(r"[a-z]+\d*\[([\d,]+)\]", text)]
+    # the largest array is the five layers' kept inputs [5, 1, 2L, 2048];
+    # one head's [L, L] scores would be two fifths of it, 32 heads' thirteen
+    # times it; no array has a query and a key dimension
+    assert max(sizes) == 5 * 2 * L * 2048 < 3 * L * L
+    for dims in (f"{2 * L},{2 * L}", f"{L},{L}", f"{2 * L},{L}",
+                 f"{L},{2 * L}"):
+        assert dims not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("bd_cross", "bd_own"):
+        mine = [n for n in names if f"/{scope}/" in n]
+        assert mine, scope
+        assert not any(n.rsplit("/", 1)[-1].startswith(("gather", "scatter"))
+                       for n in mine)
+    # the head reads the noised half alone
+    assert re.search(r"bf16\[1,8192,18992\]", text)
+    assert not re.search(r"\[1,16384,18992\]", text)
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)

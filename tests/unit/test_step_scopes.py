@@ -153,6 +153,19 @@ CASES = {
                    "moe_routed_scale": 2.5, "moe_shared_experts": 1,
                    "moe_bias_rate": 1e-3, "moe_bias_init": 0.1,
                    "tie_embeddings": False, "remat_policy": "full"}, 1),
+    # block diffusion: the [noised ; clean] row through the flash kernels
+    # under the rounded diagonal (interpreted here) and the own-block term,
+    # head norms on q and k, the repeated positions through the rope, a held
+    # share of grouped experts over both halves, the weighted loss over the
+    # noised half, under recomputation: the kernels' operations lie under
+    # bd_cross, the own-block term and its merge under bd_own, forward,
+    # recomputed and backward
+    "bd_moe": ({"diffusion_block": 4, "mask_token_id": 255,
+                "num_kv_heads": 2, "qk_norm": "head",
+                "num_experts": 8, "top_k": 2, "moe_dispatch": "grouped",
+                "moe_intermediate_size": 32, "moe_experts_held": 4,
+                "tie_embeddings": False, "remat_policy": "full",
+                "attention_impl": "flash_pallas"}, 1),
 }
 NESTED = {"attn_window": "attn", "attn_full": "attn", "moe_router": "moe",
           "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -186,6 +199,20 @@ NESTED_DSA = {"attn_dsa": "attn", "dsa_indexer": "attn_dsa",
               "dsa_select": "attn_dsa", "dsa_attend": "attn_dsa",
               "dsa_loss": "attn_dsa", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe"}
+NESTED_BD = {"attn_full": "attn", "bd_cross": "attn_full",
+             "bd_own": "attn_full", "moe_router": "moe",
+             "moe_dispatch": "moe", "moe_experts": "moe"}
+
+
+def _batch(overrides, rows):
+    ids = np.zeros((rows, 32), np.int32)
+    if "diffusion_block" not in overrides:
+        return {"input_ids": ids}
+    from deepspeed_tpu.runtime.data_pipeline import noise_batch
+
+    return noise_batch({"input_ids": ids}, seed=0, t_min=0.5,
+                       block=overrides["diffusion_block"],
+                       mask_token_id=overrides["mask_token_id"])
 
 
 def _op_names(overrides, ga):
@@ -197,7 +224,7 @@ def _op_names(overrides, ga):
                 "bf16": {"enabled": True}, "steps_per_print": 10 ** 9,
                 "zero_optimization": {"stage": 0}},
         mesh=build_mesh(devices=jax.devices()[:1]))
-    eng.fused_train_step({"input_ids": np.zeros((2 * ga, 32), np.int32)})
+    eng.fused_train_step(_batch(overrides, 2 * ga))
     row = steplog.programs()[-1]
     assert row.name == "ds_train_step" and row.key == str(ga)
     text = row.hlo_text()
@@ -219,14 +246,15 @@ def test_every_operation_carries_a_step_scope(case):
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
                             "conv_moe", "kda_moe", "dsa_moe",
-                            "heads_moe") else "mlp"
+                            "heads_moe", "bd_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
     if case in ("mla_moe", "conv_moe", "kda_moe", "heads_moe"):
         want.add("mlp")         # the dense layer's
     nested = {"pattern_share": NESTED, "hybrid": NESTED_HYBRID,
               "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
               "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA,
-              "dsa_moe": NESTED_DSA, "heads_moe": NESTED_HEADS}.get(case)
+              "dsa_moe": NESTED_DSA, "heads_moe": NESTED_HEADS,
+              "bd_moe": NESTED_BD}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
@@ -286,6 +314,25 @@ def test_every_operation_carries_a_step_scope(case):
                    "dsa_indexer", "dsa_select", "dsa_attend", "dsa_loss"}}
         assert not own & {"exp", "sort", "top_k", "cond", "log"}, own
         assert steplog.programs()[-1].dsa_lowerings == {"jnp": 2}
+    if case == "bd_moe":
+        # both parts run forward, again under recomputation, and backward;
+        # every product over (query, key) pairs and every exponential of
+        # the mixer lies under one of the two (what lies under attn_full and
+        # outside them is the projections, the head norms, the rope over
+        # the repeated positions and the early positions' mean square)
+        for part in ("bd_cross", "bd_own"):
+            mine = [n for n in names if part in re.split(r"[/()]", n)]
+            assert any("transpose(" in n for n in mine), part
+            assert any("rematted_computation" in n for n in mine), part
+        own = {n.rsplit("/", 1)[-1] for n in names
+               if "attn_full" in re.split(r"[/()]", n)
+               and not set(re.split(r"[/()]", n)) & {"bd_cross", "bd_own"}}
+        assert not own & {"exp", "exp2", "log", "while"}, own
+        row = steplog.programs()[-1]
+        assert (row.diffusion_block, row.positions_per_token,
+                row.head_rows) == (4, 2, 32)
+        assert set(row.flash_bwd_tiles) == {"diag4", "diag4_strict",
+                                            "diag4_own"}
     if case.startswith("looped"):
         want.add("exit_gate")
         # the gate's operations nest inside the loss: loss/exit_gate/...
