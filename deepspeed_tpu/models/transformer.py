@@ -560,7 +560,7 @@ class TransformerConfig:
             if (self.looped or self.parallel_block or self.one_branch
                     or self.loss_tiling > 1 or self.attn_pattern is not None
                     or self.sliding_window is not None or self.has_mla
-                    or self.mrope_section is not None
+                    or self.mrope_section is not None or self.mla_head_gate
                     or not self.use_rope or self.learned_pos
                     or self.exit_loss_beta is not None
                     or self.attention_impl not in ("auto", "xla", "flash",
@@ -575,7 +575,8 @@ class TransformerConfig:
                     f"(loss_tiling > 1: it shifts the labels and weighs no "
                     f"position), an attn_pattern, sliding_window (a window "
                     f"beside the rounded diagonal), latent attention, "
-                    f"mrope_section, use_rope=False / learned positions "
+                    f"mrope_section, mla_head_gate (the halves' results are "
+                    f"projected apart), use_rope=False / learned positions "
                     f"(the two halves repeat their positions through the "
                     f"rope), or attention_impl='fpdt' / 'ring' / 'ulysses' "
                     f"(their chunks know the causal mask only)")
@@ -1336,9 +1337,13 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         # (Granite's 1/64 at d = 64: a factor of 1/8, exact in bf16)
         q = _times(q, cfg.attention_multiplier * math.sqrt(hd))
     if cfg.has_bd:
-        # the ``[noised ; clean]`` row under the block-diffusion mask
-        out = _bd_attend(q, k, v, cfg)
-    elif cfg.sliding_window is not None:
+        # the ``[noised ; clean]`` row under the block-diffusion mask, a
+        # result a half: each projected on its own, the halves joined at the
+        # model's width (models/block_diffusion.py:attention says why)
+        o = jnp.concatenate([attn_out_proj(half, w, cfg)
+                             for half in _bd_attend(q, k, v, cfg)], axis=1)
+        return constrain(o, P(("dp", "fsdp"), "sp", None))
+    if cfg.sliding_window is not None:
         # windowed families (mistral/qwen2): the flash kernel takes the
         # window natively (block-skipping); impls without window support
         # (ring/ulysses SP wrappers) fall back to the masked XLA path
@@ -1364,12 +1369,13 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
 
 
 def _bd_attend(q: jax.Array, k: jax.Array, v: jax.Array,
-               cfg: TransformerConfig) -> jax.Array:
-    """Attention of a block-diffusion row (models/block_diffusion.py): the
-    flash kernels under the rounded diagonal where a Mosaic call runs whole
-    (the TPU, no mesh axis to partition over; ``attention_impl=
-    "flash_pallas"`` forces them, interpreted off the TPU), else the same
-    mask as a dense softmax, with a warning on the TPU."""
+               cfg: TransformerConfig) -> Tuple[jax.Array, jax.Array]:
+    """Attention of a block-diffusion row (models/block_diffusion.py), the
+    noised half's result and the clean half's: the flash kernels under the
+    rounded diagonal where a Mosaic call runs whole (the TPU, no mesh axis to
+    partition over; ``attention_impl="flash_pallas"`` forces them,
+    interpreted off the TPU), else the same mask as a dense softmax, with a
+    warning on the TPU."""
     from deepspeed_tpu import ops
     from deepspeed_tpu.models import block_diffusion as bd
 
@@ -1385,7 +1391,8 @@ def _bd_attend(q: jax.Array, k: jax.Array, v: jax.Array,
             f"this mesh — a dense softmax over q{q.shape}'s "
             f"{q.shape[1]} x {q.shape[1]} scores runs instead")
     with jax.named_scope(bd.CROSS_SCOPE):
-        return bd.dense_attention(q, k, v, block)
+        out = bd.dense_attention(q, k, v, block)
+    return out[:, :q.shape[1] // 2], out[:, q.shape[1] // 2:]
 
 
 def _cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1504,9 +1511,10 @@ STEP_SCOPES = ("embed", "layers", "attn", "mlp", "moe", "final_norm",
                "attn_dsa", "dsa_indexer", "dsa_select", "dsa_attend",
                "dsa_loss",
                # block diffusion's attention inside attn
-               # (models/block_diffusion.py): the flash calls under the
-               # rounded diagonal, the own-block term and its merge
-               "bd_cross", "bd_own")
+               # (models/block_diffusion.py): the two flash calls under the
+               # rounded diagonal (the noised half's with its own block as
+               # a second key source)
+               "bd_cross")
 #: a period of up to this many blocks is the body of one scan over periods;
 #: a longer list of kinds is cut into runs of one kind. Layers of one branch
 #: each (``one_branch``) are half a block: a period of up to twice as many,
@@ -2215,9 +2223,10 @@ class TransformerLM:
             # run for each token of a row (the noised copy and the clean
             # one) and, once the rows' length is known, the positions of a
             # row the head reads (the noised half) and what the flash
-            # kernels do with a head of a row under the three rounded
-            # diagonals (``block_diffusion.kernel_tiles``: tiles by arm,
-            # the crossed tiles' sub-blocks, the pairs worked and kept)
+            # kernels do with a head of a row in the two calls
+            # (``block_diffusion.kernel_tiles``: tiles by arm under the two
+            # rounded diagonals, the noised call's own tiles under the
+            # band's label, their sub-blocks, the pairs worked and kept)
             facts["diffusion_block"] = cfg.diffusion_block
             facts["positions_per_token"] = 2
             if batch_shape is not None:

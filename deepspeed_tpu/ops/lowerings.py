@@ -13,9 +13,10 @@ ran under, because the masks the kernels take are few: none, the causal
 diagonal, a sliding window beside it, and the diagonal rounded to blocks
 (``diag``: block diffusion's two calls a layer). ``flash_bwd_tiles`` is keyed
 by the mask (the window's length, ``"causal"``, ``"none"``, or
-``"diag<n>"`` / ``"diag<n>_strict"``), ``flash_diag_fwd_tiles`` by the rounded
-diagonal's label alone, ``flash_fwd_tiles`` is the newest forward's whatever
-its mask. A mask the kernels do not take leaves no record here: a call with
+``"diag<n>"`` / ``"diag<n>_strict"``; ``"diag<n>_own"``: the own tiles of a
+call with a second key source, which ``flash_own_keys`` counts as
+``"operand"``), ``flash_diag_fwd_tiles`` by the rounded diagonal's label
+alone, ``flash_fwd_tiles`` is the newest forward's whatever its mask. A mask the kernels do not take leaves no record here: a call with
 ``segment_ids`` is ``xla_attention``'s (dense), and with ``diag`` besides it is
 refused by name.
 """

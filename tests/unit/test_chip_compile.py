@@ -1474,10 +1474,12 @@ def test_the_laguna_cells_step_program_compiles_for_v5e(one_chip,
 
 
 #: temporaries of the SDAR cell's step as compiled here, by policy (bytes,
-#: upper bounds a hundredth above what was read: 8.048 and 6.270 GB, beside
-#: 6.61 GB of arguments). The test compiles the file's policy; the other is
-#: ``remat_policy="full"`` passed to the same helper (70 s more)
-SDAR_TEMP = {"attn_saveable": 8.15e9, "full": 6.35e9}
+#: upper bounds a hundredth above what was read: 7.209 and 5.878 GB, beside
+#: 6.61 GB of arguments; 8.048 and 6.270 before PR 65, with a third flash
+#: call, a merge and the halves joined at the heads' width). The test
+#: compiles the file's policy; the other is ``remat_policy="full"`` passed to
+#: the same helper (70 s more)
+SDAR_TEMP = {"attn_saveable": 7.3e9, "full": 5.95e9}
 
 
 def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
@@ -1490,9 +1492,12 @@ def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
     **two calls of the flash kernels over the clean keys under the rounded
     diagonal**, forward and fused backward, under ``attn/attn_full/bd_cross``
     with the names the benchmark's patterns look for, each over 8,192 query
-    rows and 8,192 keys (neither sees a noised key), and a third under
-    ``bd_own``, the noised half over its own blocks as 32 sequences of 256
-    (a tile each: no dead grid step); **no array of the program is as
+    rows and 8,192 clean keys; the noised half's takes its own noised block
+    as a second key source (two more operands of 8,192 rows, the own tile a
+    q-tile on a grid step the call has, the four of its sixteen 256-wide
+    sub-blocks that keep a pair worked; its gradients two more slabs of the
+    backward's stacked result), and nothing stands under a scope ``bd_own``;
+    **no array of the program is as
     large as three heads' ``[L, L]`` scores and none has a query and a key
     dimension, let alone ``[2L, 2L]``**; no
     gather or scatter stands under the mixer's scopes (the repeated positions
@@ -1519,26 +1524,31 @@ def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
                 sub_inside=8)
     assert counted["flash_bwd_tiles"] == {
         "diag4": arms, "diag4_strict": arms,
-        # (a segment's one tile, one sub-block)
-        "diag4_own": dict(masked=1, unmasked=0, dead=0, sub_live=1,
-                          sub_dead=0, sub_inside=0)}
+        # (a head's eight own tiles of 1,024, a q-tile each: of a tile's
+        # sixteen sub-blocks of 256 the four on its diagonal)
+        "diag4_own": dict(masked=8, unmasked=0, dead=0, sub_live=32,
+                          sub_dead=96, sub_inside=0)}
     assert counted["flash_diag_fwd_tiles"] == counted["flash_bwd_tiles"]
-    assert counted["flash_bwd"] == {"fused": 3}
-    for scope in ("bd_cross", "bd_own"):
-        calls = _kernel_calls(text, scope)
-        assert calls and all(f"/attn/attn_full/{scope}/" in n for n in calls)
-        assert any("transpose(" in n for n in calls)
-    own = re.findall(
-        r"^\s*%bd_own[.\d]* = \(bf16\[32,32,256,128\]\S*, "
-        r"f32\[32,32,1,256\]\S*\) custom-call\(.*tpu_custom_call", text, re.M)
-    assert own
+    assert counted["flash_bwd"] == {"fused": 2}
+    # a layer's noised call, and as often its clean call (each lowering,
+    # forward or backward, counts)
+    own = counted["flash_own_keys"]
+    assert set(own) == {"operand", "none"} and own["operand"] == own["none"]
+    calls = _kernel_calls(text, "bd_cross")
+    assert calls and all("/attn/attn_full/bd_cross/" in n for n in calls)
+    assert any("transpose(" in n for n in calls)
+    assert "bd_own" not in text
     fwd = re.findall(
         r"^\s*%bd_cross[.\d]* = \(bf16\[1,32,8192,128\]\S*, "
         r"f32\[1,32,1,8192\]\S*\) custom-call\(.*tpu_custom_call", text, re.M)
+    # dq, and the keys' and values' gradients stacked: the clean call's two,
+    # the noised call's two and after them its own keys' two
     bwd = re.findall(
         r"^\s*%bd_cross[.\d]* = \(bf16\[1,32,8,1024,128\]\S*, "
-        r"bf16\[2,1,32,8192,128\]\S*\) custom-call\(.*tpu_custom_call",
+        r"bf16\[[24],1,32,8192,128\]\S*\) custom-call\(.*tpu_custom_call",
         text, re.M)
+    assert sorted(re.search(r"bf16\[([24]),1,32,8192", b).group(1)
+                  for b in bwd) == ["2", "4"]
     # a layer's two halves forward (the body of the layer scan; once more in
     # the backward's region where the policy keeps nothing of them) and
     # backward
@@ -1547,7 +1557,7 @@ def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
     assert len(fwd) == (4 if again else 2) and len(bwd) == 2
     # no Mosaic call of the mixer takes an operand of 16,384 rows
     for line in text.splitlines():
-        if "tpu_custom_call" in line and re.search(r"%bd_(cross|own)", line):
+        if "tpu_custom_call" in line and "%bd_cross" in line:
             assert ",16384," not in line.split("custom-call(")[1] \
                 .split("custom_call_target")[0]
     sizes = [eval("*".join(dims.split(",")))  # noqa: S307 (digits and commas)
@@ -1560,11 +1570,10 @@ def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
                  f"{L},{2 * L}"):
         assert dims not in text
     names = re.findall(r'op_name="([^"]*)"', text)
-    for scope in ("bd_cross", "bd_own"):
-        mine = [n for n in names if f"/{scope}/" in n]
-        assert mine, scope
-        assert not any(n.rsplit("/", 1)[-1].startswith(("gather", "scatter"))
-                       for n in mine)
+    mine = [n for n in names if "/bd_cross/" in n]
+    assert mine
+    assert not any(n.rsplit("/", 1)[-1].startswith(("gather", "scatter"))
+                   for n in mine)
     # the head reads the noised half alone
     assert re.search(r"bf16\[1,8192,18992\]", text)
     assert not re.search(r"\[1,16384,18992\]", text)

@@ -334,42 +334,134 @@ def test_the_clean_queries_fetch_no_tile_above_the_rounded_diagonal():
         == np.eye(4, dtype=bool).tolist()
     tiles = bd.kernel_tiles(8192, 4)
     assert tiles["pairs_kept"] == 8192 * 8192 + 8192 * 4
-    assert tiles["pairs_worked"] == 73_400_320 <= 1.15 * tiles["pairs_kept"]
     assert tiles["diag4"] == tiles["diag4_strict"] == {
         "masked": 8, "unmasked": 28, "dead": 28, "sub_live": 24,
         "sub_dead": 8, "sub_inside": 8}
-    # the own-block call: 32 segments of 256, each one tile, no dead step
+
+
+def _grids(fn, *args):
+    """The grids of the Mosaic calls ``fn(*args)`` traces, in order."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_the_own_keys_add_no_grid_step_and_work_their_live_sub_blocks():
+    """The noised call with its own block as the second key source runs the
+    grids the call over the clean keys alone runs, forward and backward (the
+    own tile is an operand block of a step the grid has), and of a head's
+    pairs the two calls work 73,400,320 at the own tiles' 256 edge: a 1,024
+    own tile's four live sub-blocks of sixteen a q-tile (75,497,472 at 512,
+    79,691,776 whole), under the runner's 1.15 x."""
+    L = 2048
+    q, k, v = (jax.ShapeDtypeStruct((1, L, h, 128), jnp.bfloat16)
+               for h in (4, 2, 2))
+
+    def call(own):
+        def loss(q, k, v, ko, vo):
+            return jnp.sum(fa.flash_attention(
+                q, k, v, diag=(4, fa.DIAG_BEFORE), interpret=False,
+                **(dict(k_own=ko, v_own=vo) if own else {})
+            ).astype(jnp.float32))
+        return _grids(jax.grad(loss, (0, 1, 2) + ((3, 4) if own else ())),
+                      q, k, v, k, v)
+
+    before = lowerings.snapshot()
+    assert call(True) == call(False) == [(1, 4, 2, 2)] * 2
+    said = lowerings.since(before)
+    assert said["flash_own_keys"] == {"operand": 2, "none": 2}
+    assert said["flash_bwd"] == {"fused": 2}
+    own = dict(masked=2, unmasked=0, dead=0, sub_live=8, sub_dead=24,
+               sub_inside=0)
+    assert said["flash_diag_fwd_tiles"]["diag4_own"] == own \
+        == said["flash_bwd_tiles"]["diag4_own"]
+    tiles = bd.kernel_tiles(8192, 4)
+    assert (fa._SUB, fa._OWN_SUB) == (512, 256)
+    assert tiles["pairs_worked"] == 73_400_320 <= 1.15 * tiles["pairs_kept"]
+    # a head's eight own tiles, a q-tile each: no dead step, none of its own
     assert tiles["diag4_own"] == {
-        "masked": 32, "unmasked": 0, "dead": 0, "sub_live": 32,
-        "sub_dead": 0, "sub_inside": 0}
+        "masked": 8, "unmasked": 0, "dead": 0, "sub_live": 32,
+        "sub_dead": 96, "sub_inside": 0}
+    # the fused backward holds the own tiles and their gradients too
+    assert fa._bwd_takes_fused(8192, 128, 1024, 1024, 2, own=True)
+    assert fa._fused_bwd_vmem_bytes(8192, 128, 1024, 1024, 2, own=True) \
+        - fa._fused_bwd_vmem_bytes(8192, 128, 1024, 1024, 2) == 2 * 2 ** 20
 
 
-@pytest.mark.parametrize("L, B, segment", [(32, 4, 256), (64, 8, 16)])
-def test_the_own_block_term_merges_to_a_dense_masked_softmax(L, B, segment,
-                                                             monkeypatch):
-    """(segments of 16: the own-block call runs the row as four sequences)"""
-    monkeypatch.setattr(bd, "OWN_SEGMENT", segment)
+@pytest.mark.parametrize("L, B, tile, sub, took", [
+    (32, 4, 1024, 512, "fused"),        # a row of one tile
+    (64, 8, 16, 512, "split"),          # clean tiles before the own tile
+    (64, 4, 32, 16, "split"),
+    (256, 4, 128, 64, "fused"),         # an own tile's dead sub-blocks
+    (256, 8, 128, 32, "fused"),
+    (128, 64, 128, 64, "fused"),        # a block is a sub-block: unmasked
+])
+def test_the_two_calls_are_a_dense_masked_softmax(L, B, tile, sub, took,
+                                                  monkeypatch):
+    """``bd.attention``'s two halves (a call of the kernels each, the noised
+    half's with its own block as the second key source of one softmax)
+    against the mask as a dense softmax: the value and the three
+    gradients."""
+    monkeypatch.setattr(fa, "_SUB", sub)
+    monkeypatch.setattr(fa, "_OWN_SUB", sub)
     q, k, v = _qkv(2 * L, seed=9)
 
     def by_parts(q, k, v):
-        return bd.attention(q, k, v, B, interpret=True)
+        halves = bd.attention(q, k, v, B, block_q=tile, block_k=tile,
+                              interpret=True)
+        assert [h.shape for h in halves] == [(1, L) + q.shape[2:]] * 2
+        return jnp.concatenate(halves, axis=1)
 
     def whole(q, k, v):
         return bd.dense_attention(q, k, v, B)
 
     np.testing.assert_allclose(by_parts(q, k, v), whole(q, k, v), atol=2e-6)
     w = jax.random.normal(jax.random.key(2), q.shape)
+    before = lowerings.snapshot()
     g1 = jax.grad(lambda *a: jnp.sum(by_parts(*a) * w), (0, 1, 2))(q, k, v)
+    said = lowerings.since(before)
     g2 = jax.grad(lambda *a: jnp.sum(whole(*a) * w), (0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         assert np.all(np.isfinite(a))
         np.testing.assert_allclose(a, b, atol=1e-5)
+    assert said["flash_bwd"] == {took: 2}
+    assert said["flash_own_keys"] == {"operand": 2, "none": 2}
+    # the registry's own tiles are ``kernel_tiles``'
+    tiles = bd.kernel_tiles(L, B, tile, tile)
+    label = fa.diag_label((B, fa.DIAG_OWN))
+    assert said["flash_diag_fwd_tiles"][label] == tiles[label]
+    edge = min(tile, L, sub)
+    assert tiles[label]["sub_live"] + tiles[label]["unmasked"] \
+        == L // edge
     m = np.asarray(bd.mask(L, B))
     assert m.sum() == bd.mask_pairs(L, B) == L * L + L * B
     assert not m[L:, :L].any()              # clean queries, noised keys
     # the mask by the reference's four lines
     np.testing.assert_array_equal(m, ref.mask_rows(jnp.arange(2 * L), 2 * L,
                                                    L, B))
+
+
+def test_the_own_keys_are_refused_where_the_kernels_cannot_take_them():
+    q, k, v = _qkv(32)
+    own = dict(k_own=k, v_own=v, interpret=True)
+    for kw, error, said in (
+            (dict(k_own=k, diag=(4, 1)), ValueError, "together"),
+            (dict(own), ValueError, "DIAG_BEFORE"),
+            (dict(own, diag=(4, fa.DIAG_UPTO)), ValueError, "DIAG_BEFORE"),
+            (dict(own, diag=(4, 1), block_q=16, block_k=32), ValueError,
+             "one tile edge"),
+            (dict(own, diag=(4, 1), k_own=k[:, :16], v_own=v[:, :16]),
+             ValueError, "queries' positions")):
+        with pytest.raises(error, match=said):
+            fa.flash_attention(q, k, v, **kw)
 
 
 def test_kernels_refuse_a_diagonal_they_cannot_round():
@@ -503,7 +595,7 @@ def test_params_plan_specs_facts_and_scopes(small):
     assert facts["positions_per_token"] == 2 and facts["head_rows"] == L
     assert facts["bd_mask_tiles"]["pairs_kept"] == L * L + L * cfg.diffusion_block
     assert "head_rows" not in model.step_program_facts()
-    assert {"bd_cross", "bd_own"} <= set(tr.STEP_SCOPES)
+    assert "bd_cross" in tr.STEP_SCOPES and "bd_own" not in tr.STEP_SCOPES
 
 
 def test_the_engines_step_takes_the_three_keys():

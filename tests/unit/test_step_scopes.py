@@ -154,11 +154,11 @@ CASES = {
                    "moe_bias_rate": 1e-3, "moe_bias_init": 0.1,
                    "tie_embeddings": False, "remat_policy": "full"}, 1),
     # block diffusion: the [noised ; clean] row through the flash kernels
-    # under the rounded diagonal (interpreted here) and the own-block term,
-    # head norms on q and k, the repeated positions through the rope, a held
-    # share of grouped experts over both halves, the weighted loss over the
-    # noised half, under recomputation: the kernels' operations lie under
-    # bd_cross, the own-block term and its merge under bd_own, forward,
+    # under the rounded diagonal (interpreted here), the noised half's call
+    # with its own block as a second key source, head norms on q and k, the
+    # repeated positions through the rope, a held share of grouped experts
+    # over both halves, the weighted loss over the noised half, under
+    # recomputation: the kernels' operations lie under bd_cross, forward,
     # recomputed and backward
     "bd_moe": ({"diffusion_block": 4, "mask_token_id": 255,
                 "num_kv_heads": 2, "qk_norm": "head",
@@ -200,7 +200,7 @@ NESTED_DSA = {"attn_dsa": "attn", "dsa_indexer": "attn_dsa",
               "dsa_loss": "attn_dsa", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe"}
 NESTED_BD = {"attn_full": "attn", "bd_cross": "attn_full",
-             "bd_own": "attn_full", "moe_router": "moe",
+             "moe_router": "moe",
              "moe_dispatch": "moe", "moe_experts": "moe"}
 
 
@@ -315,24 +315,26 @@ def test_every_operation_carries_a_step_scope(case):
         assert not own & {"exp", "sort", "top_k", "cond", "log"}, own
         assert steplog.programs()[-1].dsa_lowerings == {"jnp": 2}
     if case == "bd_moe":
-        # both parts run forward, again under recomputation, and backward;
-        # every product over (query, key) pairs and every exponential of
-        # the mixer lies under one of the two (what lies under attn_full and
-        # outside them is the projections, the head norms, the rope over
-        # the repeated positions and the early positions' mean square)
-        for part in ("bd_cross", "bd_own"):
-            mine = [n for n in names if part in re.split(r"[/()]", n)]
-            assert any("transpose(" in n for n in mine), part
-            assert any("rematted_computation" in n for n in mine), part
+        # the two calls run forward, again under recomputation, and
+        # backward; every product over (query, key) pairs and every
+        # exponential of the mixer lies under their scope (what lies under
+        # attn_full and outside it is the projections, the head norms, the
+        # rope over the repeated positions, the joining of the halves and
+        # the early positions' mean square)
+        mine = [n for n in names if "bd_cross" in re.split(r"[/()]", n)]
+        assert any("transpose(" in n for n in mine)
+        assert any("rematted_computation" in n for n in mine)
         own = {n.rsplit("/", 1)[-1] for n in names
                if "attn_full" in re.split(r"[/()]", n)
-               and not set(re.split(r"[/()]", n)) & {"bd_cross", "bd_own"}}
+               and "bd_cross" not in re.split(r"[/()]", n)}
         assert not own & {"exp", "exp2", "log", "while"}, own
         row = steplog.programs()[-1]
         assert (row.diffusion_block, row.positions_per_token,
                 row.head_rows) == (4, 2, 32)
         assert set(row.flash_bwd_tiles) == {"diag4", "diag4_strict",
                                             "diag4_own"}
+        # the noised call's second key source, and the clean call without
+        assert set(row.flash_own_keys_lowerings) == {"operand", "none"}
     if case.startswith("looped"):
         want.add("exit_gate")
         # the gate's operations nest inside the loss: loss/exit_gate/...
