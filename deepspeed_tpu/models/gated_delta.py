@@ -3,16 +3,27 @@ kind of :mod:`deepspeed_tpu.models.transformer` (``attn_pattern`` kind
 ``"delta"``): its parameters, their sharding and the block. Loaded only by a
 model that has such a layer.
 
+Two head counts: ``cfg.delta_heads`` value heads (v, z, the step, the decay
+and the state are a value head's) and ``cfg.delta_key_heads`` key heads (q, k
+and their convolutions; None: as many), a divisor of them: value head ``i``
+reads key head ``i // (delta_heads / delta_key_heads)`` (Qwen3-Next: 16 key
+heads for 32 value heads; Olmo-Hybrid: one each). The rule reads a key
+head's q and k once for its value heads (``ops/delta_rule.py``).
+
 A layer's leaves (``params["layers"]["delta"]``, one row per delta layer),
-for the ``H`` heads held (``cfg.heads_held`` of ``cfg.delta_heads``; all of
-them where it is None), keys ``dk`` and values ``dv`` wide: ``wq``, ``wk``
-[D, H dk], ``wv``, ``wz`` [D, H dv] (``wz`` the output gate's), ``wb``, ``wa``
-[D, H] (the step's and the decay's), ``conv_q``, ``conv_k``, ``conv_v``
-[K, width] (causal depthwise, no bias; tap k meets position t - (K - 1) + k),
+for the ``H`` value heads held (``cfg.heads_held`` of ``cfg.delta_heads``;
+all of them where it is None) and the ``Hk`` key heads that serve them, keys
+``dk`` and values ``dv`` wide: ``wq``, ``wk`` [D, Hk dk], ``wv``, ``wz``
+[D, H dv] (``wz`` the output gate's), ``wb``, ``wa`` [D, H] (the step's and
+the decay's), ``conv_q``, ``conv_k`` [K, Hk dk], ``conv_v`` [K, H dv] (causal
+depthwise, no bias; tap k meets position t - (K - 1) + k),
 ``A_log``, ``dt_bias`` [H] (float32 in the compute copy of the weights),
-``o_norm`` [dv] (the output norm's scale, one for every head) and ``wo``
+``o_norm`` [dv] (the output norm's scale, one for every head, plain: times
+the scale, drawn at 1, whatever ``cfg.norm_zero_centred`` says of the
+block's norms) and ``wo``
 [H dv, D]. Heads are independent and the output norm is per head, so a share
-of the heads gives its part of the sum ``wo`` takes over them.
+of the value heads, with the key heads that serve them, gives its part of
+the sum ``wo`` takes over them.
 """
 
 from __future__ import annotations
@@ -34,8 +45,11 @@ L2_EPS = 1e-6
 
 
 def sizes(cfg) -> Dict[str, int]:
+    """The value heads held, the key heads that serve them, and the widths
+    of a key-head and of a value-head projection."""
     H = cfg.heads_held or cfg.delta_heads
-    return {"heads": H, "key": H * cfg.delta_key_dim,
+    Hk = H * (cfg.delta_key_heads or cfg.delta_heads) // cfg.delta_heads
+    return {"heads": H, "key_heads": Hk, "key": Hk * cfg.delta_key_dim,
             "value": H * cfg.delta_value_dim}
 
 
@@ -101,7 +115,7 @@ def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
     (inside the caller's ``attn``)."""
     B, T, _ = u.shape
     s, dk, dv = sizes(cfg), cfg.delta_key_dim, cfg.delta_value_dim
-    H = s["heads"]
+    H, Hk = s["heads"], s["key_heads"]
     with jax.named_scope("delta_proj"):
         q, k, v, z = (u @ w[n] for n in ("wq", "wk", "wv", "wz"))
         b, a = u @ w["wb"], u @ w["wa"]
@@ -117,7 +131,7 @@ def delta_block(u: jax.Array, w: Dict[str, jax.Array], cfg) -> jax.Array:
             a.astype(F32) + w["dt_bias"].astype(F32))
         # the rule puts the norms on a head's q and k itself
         o = chunked_delta_rule(
-            q.reshape(B, T, H, dk), k.reshape(B, T, H, dk),
+            q.reshape(B, T, Hk, dk), k.reshape(B, T, Hk, dk),
             v.reshape(B, T, H, dv), g, beta,
             unit=(1.0 / math.sqrt(dk), L2_EPS))
     with jax.named_scope("delta_gate"):

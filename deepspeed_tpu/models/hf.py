@@ -10,7 +10,11 @@ arbitrary external pytrees by the same name-pattern table AutoTP uses.
 
 Supported families (the reference's inference-v2 model_implementations/ set):
 Llama/Llama-2/3, Mistral, Qwen2, Phi-3, Mixtral, Falcon (rotary variants),
-GPT-NeoX/Pythia, GPT-2, OPT. Weight-layout notes:
+GPT-NeoX/Pythia, GPT-2, OPT; and ``qwen3_next`` (gated delta-rule layers whose
+key heads serve several value heads beside gated full-attention layers of
+256-wide heads, zero-centred norms, routed experts beside a gated shared
+one: config and tensors, :func:`_build_qwen3_next` and back,
+:func:`qwen3_next_state_dict`). Weight-layout notes:
   * torch ``nn.Linear`` stores ``[out, in]``; our matmuls are ``x @ w`` with
     ``w [in, out]`` → every projection transposes on import.
   * per-layer tensors stack on a leading layer axis (the ``lax.scan`` layout).
@@ -37,7 +41,8 @@ from deepspeed_tpu.models.transformer import TransformerConfig, TransformerLM
 from deepspeed_tpu.utils.logging import log_dist
 
 __all__ = ["config_from_hf", "load_hf_checkpoint", "from_pretrained",
-           "infer_tp_specs", "TP_PATTERNS", "OURO_TENSORS"]
+           "infer_tp_specs", "TP_PATTERNS", "OURO_TENSORS",
+           "qwen3_next_state_dict"]
 
 
 _LLAMA_FAMILY = ("llama", "mistral", "qwen2", "phi3", "mixtral", "ouro")
@@ -57,7 +62,7 @@ _SUPPORTED = _LLAMA_FAMILY + ("falcon", "gpt_neox", "gpt2", "opt", "mellum",
                               "granitemoehybrid", "deepseek_v3",
                               "olmo_hybrid", "nemotron_h", "lfm2_moe",
                               "bailing_hybrid", "KeyeVL2", "laguna",
-                              "sdar_moe")
+                              "sdar_moe", "qwen3_next")
 #: HF ``layer_types`` / ``rope_parameters`` names -> layer kinds here
 _HF_KINDS = {"sliding_attention": "window", "full_attention": "full",
              "attention": "full", "mamba": "ssm",
@@ -627,6 +632,63 @@ def config_from_hf(hf_cfg: Any, **overrides) -> TransformerConfig:
             top_k=get("num_experts_per_tok"),
             moe_intermediate_size=get("moe_intermediate_size"),
             moe_dispatch="grouped", moe_aux_loss_coef=0.001)
+    elif model_type == "qwen3_next":
+        # gated delta-rule layers and full-attention layers in turn (layer i
+        # full where (i + 1) % full_attention_interval == 0, or as
+        # ``layer_types`` says), pre-norm; the delta layers'
+        # ``linear_num_key_heads`` serve ``linear_num_value_heads`` value
+        # heads; the full layers have heads of ``head_dim`` under a per-head
+        # RMSNorm on q and k, a rope on ``partial_rotary_factor`` of a head
+        # and a gate a channel from ``q_proj``'s second half; every RMSNorm
+        # but the delta layer's gated one multiplies by 1 + weight; every
+        # FFN is softmax-routed experts, the top k renormalised, beside one
+        # shared expert under a sigmoid gate a token. The last two and the
+        # gate a channel are fixed in ``modeling_qwen3_next.py``, no key
+        # declares them. The multi-token module (``mtp.*`` tensors) is no
+        # key of the config and is not built. A share of the heads or of the
+        # experts is no config key: pass heads_held= / moe_experts_held=.
+        L = get("num_hidden_layers")
+        interval = get("full_attention_interval", 4)
+        types = list(get("layer_types") or (
+            "linear_attention" if (i + 1) % interval else "full_attention"
+            for i in range(L)))[:L]
+        F, Fs = get("moe_intermediate_size"), get(
+            "shared_expert_intermediate_size")
+        if (get("mlp_only_layers") or get("decoder_sparse_step", 1) != 1
+                or not get("norm_topk_prob", True)
+                or get("attention_bias", False) or get("rope_scaling")
+                or not Fs or Fs % F
+                or set(types) - {"linear_attention", "full_attention"}):
+            raise ValueError(
+                "qwen3_next with dense FFN layers (mlp_only_layers, "
+                "decoder_sparse_step), without norm_topk_prob, with "
+                "attention biases, rope_scaling, a shared expert whose "
+                "width is no multiple of the experts' or another kind of "
+                "layer than linear_attention / full_attention is not "
+                "mapped")
+        kw = dict(
+            vocab_size=get("vocab_size"), hidden_size=get("hidden_size"),
+            num_layers=L, num_heads=get("num_attention_heads"),
+            num_kv_heads=get("num_key_value_heads"),
+            head_dim_override=get("head_dim"),
+            intermediate_size=get("intermediate_size"),   # no layer uses it
+            max_seq_len=get("max_position_embeddings", 2048), arch="llama",
+            rope_theta=float(get("rope_theta", 10000.0)),
+            rope_pct=float(get("partial_rotary_factor", 1.0)),
+            norm_eps=float(get("rms_norm_eps", 1e-6)),
+            tie_embeddings=bool(get("tie_word_embeddings", False)),
+            attn_pattern=tuple(_HF_KINDS[k] for k in types),
+            qk_norm="head", norm_zero_centred=True, attn_channel_gate=True,
+            delta_heads=get("linear_num_value_heads"),
+            delta_key_heads=get("linear_num_key_heads"),
+            delta_key_dim=get("linear_key_head_dim"),
+            delta_value_dim=get("linear_value_head_dim"),
+            delta_conv=get("linear_conv_kernel_dim"),
+            num_experts=get("num_experts"),
+            top_k=get("num_experts_per_tok"),
+            moe_intermediate_size=F, moe_shared_experts=Fs // F,
+            moe_shared_gate=True, moe_dispatch="grouped",
+            moe_aux_loss_coef=float(get("router_aux_loss_coef", 0.001)))
     elif model_type == "laguna":
         # window and full attention layers in turn (``layer_types``), each
         # kind with its own number of query heads over the same key-value
@@ -1072,8 +1134,161 @@ def _build_opt(sd, cfg: TransformerConfig, model_type: str):
     }, "lm_head.weight"
 
 
+def _qwen3_next_kinds(cfg: TransformerConfig):
+    if (cfg.heads_held is not None or not cfg.has_delta
+            or set(cfg.layer_kinds) - {"delta", "full"}):
+        raise NotImplementedError(
+            "the qwen3_next tensors map to a whole stack of 'delta' and "
+            "'full' layers (every head held; a share of the experts is cut "
+            "out, moe_experts_held)")
+    return cfg.layer_kinds
+
+
+def _build_qwen3_next(sd, cfg: TransformerConfig, model_type: str):
+    """The tree from a state dict as ``modeling_qwen3_next.py`` lays it out.
+    A delta layer's ``in_proj_qkvz`` rows are a key head's ``[q dk, k dk,
+    v r dv, z r dv]`` after each other (``r`` value heads a key head),
+    ``in_proj_ba``'s a key head's ``[b r, a r]``, ``conv1d``'s the joined
+    ``[q, k, v]`` channels; a full layer's ``q_proj`` rows are a head's
+    query then its gate, which is ``wq``'s layout here. Every norm weight is
+    the program's scale as it is (both keep the distance from one; the delta
+    layer's gated norm is plain in both). With ``cfg.moe_experts_held`` the
+    held experts are cut out. ``mtp.*`` (the multi-token module) is dropped:
+    the program has no such module."""
+    kinds = _qwen3_next_kinds(cfg)
+    for k in [k for k in sd if k.startswith("mtp.")]:
+        del sd[k]
+    D = cfg.hidden_size
+    Hk, Hv = cfg.delta_key_heads or cfg.delta_heads, cfg.delta_heads
+    dk, dv, r = cfg.delta_key_dim, cfg.delta_value_dim, Hv // Hk
+    pre = "model.layers.{}."
+    T = np.ascontiguousarray
+    delta = {n: [] for n in ("wq", "wk", "wv", "wz", "wb", "wa", "conv_q",
+                             "conv_k", "conv_v", "A_log", "dt_bias",
+                             "o_norm", "wo")}
+    attn = {n: [] for n in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")}
+    for i, kind in enumerate(kinds):
+        p = pre.format(i)
+        if kind == "delta":
+            la = p + "linear_attn."
+            qkvz = sd.pop(la + "in_proj_qkvz.weight").reshape(
+                Hk, 2 * dk + 2 * r * dv, D)
+            q, k, v, z = np.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv], axis=1)
+            ba = sd.pop(la + "in_proj_ba.weight").reshape(Hk, 2 * r, D)
+            conv = sd.pop(la + "conv1d.weight")[:, 0, :]    # [C, K]
+            cq, ck, cv = np.split(conv, [Hk * dk, 2 * Hk * dk], axis=0)
+            for n, a in (("wq", q), ("wk", k), ("wv", v), ("wz", z),
+                         ("wb", ba[:, :r]), ("wa", ba[:, r:])):
+                delta[n].append(T(a.reshape(-1, D).T))
+            for n, a in (("conv_q", cq), ("conv_k", ck), ("conv_v", cv)):
+                delta[n].append(T(a.T))
+            delta["A_log"].append(sd.pop(la + "A_log"))
+            delta["dt_bias"].append(sd.pop(la + "dt_bias"))
+            delta["o_norm"].append(sd.pop(la + "norm.weight"))
+            delta["wo"].append(T(sd.pop(la + "out_proj.weight").T))
+        else:
+            sa = p + "self_attn."
+            for n, t in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+                         ("wo", "o_proj")):
+                attn[n].append(T(sd.pop(sa + t + ".weight").T))
+            attn["q_norm"].append(sd.pop(sa + "q_norm.weight"))
+            attn["k_norm"].append(sd.pop(sa + "k_norm.weight"))
+    L, E = cfg.num_layers, cfg.num_experts
+    lo = cfg.moe_first_expert if cfg.moe_experts_held else 0
+    held = range(lo, lo + (cfg.moe_experts_held or E))
+    mlp = {n: np.stack([np.stack([T(sd.pop(
+        f"{pre.format(i)}mlp.experts.{e}.{t}.weight").T) for e in held])
+        for i in range(L)])
+        for n, t in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                     ("w_down", "down_proj"))}
+    for k in [k for k in sd if ".mlp.experts." in k]:
+        del sd[k]                                   # the experts not held
+    mlp["router"] = _stack(sd, pre + "mlp.gate.weight", L, transpose=True)
+    mlp["shared"] = {
+        n: _stack(sd, pre + f"mlp.shared_expert.{t}.weight", L,
+                  transpose=True)
+        for n, t in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                     ("w_down", "down_proj"))}
+    mlp["shared"]["w_sg"] = _stack(
+        sd, pre + "mlp.shared_expert_gate.weight", L, transpose=True)
+    return {
+        "embed": {"tokens": sd.pop("model.embed_tokens.weight")},
+        "layers": {
+            "ln1": {"scale": _stack(sd, pre + "input_layernorm.weight", L)},
+            "ln2": {"scale": _stack(
+                sd, pre + "post_attention_layernorm.weight", L)},
+            "delta": {n: np.stack(a) for n, a in delta.items()},
+            "attn": {n: np.stack(a) for n, a in attn.items()},
+            "mlp": mlp},
+        "final_norm": {"scale": sd.pop("model.norm.weight")},
+    }, "lm_head.weight"
+
+
+def qwen3_next_state_dict(params, cfg: TransformerConfig
+                          ) -> Dict[str, np.ndarray]:
+    """:func:`_build_qwen3_next` backwards: the tree's leaves under the
+    names and in the layouts of ``modeling_qwen3_next.py`` (torch ``[out,
+    in]``, the interleaved ``in_proj_qkvz`` / ``in_proj_ba``, the joined
+    ``conv1d`` [C, 1, K], ``q_proj`` a head's query then its gate), every
+    expert held."""
+    kinds = _qwen3_next_kinds(cfg)
+    if cfg.moe_experts_held:
+        raise NotImplementedError("a state dict holds every expert: this "
+                                  "tree holds a share (moe_experts_held)")
+    D = cfg.hidden_size
+    Hk, Hv = cfg.delta_key_heads or cfg.delta_heads, cfg.delta_heads
+    r = Hv // Hk
+    mlp = params["layers"]["mlp"]
+    lay = {g: {n: np.asarray(a) for n, a in w.items()}
+           for g, w in params["layers"].items() if g != "mlp"}
+    sd = {"model.embed_tokens.weight": np.asarray(params["embed"]["tokens"]),
+          "model.norm.weight": np.asarray(params["final_norm"]["scale"]),
+          "lm_head.weight": np.asarray(params["lm_head"]).T}
+    seen = {"delta": 0, "full": 0}
+    for i, kind in enumerate(kinds):
+        p, j = f"model.layers.{i}.", seen[kind]
+        seen[kind] += 1
+        sd[p + "input_layernorm.weight"] = lay["ln1"]["scale"][i]
+        sd[p + "post_attention_layernorm.weight"] = lay["ln2"]["scale"][i]
+        if kind == "delta":
+            w, la = lay["delta"], p + "linear_attn."
+            heads = [w[n][j].T.reshape(Hk, -1, D)
+                     for n in ("wq", "wk", "wv", "wz")]
+            sd[la + "in_proj_qkvz.weight"] = np.concatenate(
+                heads, axis=1).reshape(-1, D)
+            sd[la + "in_proj_ba.weight"] = np.concatenate(
+                [w[n][j].T.reshape(Hk, r, D) for n in ("wb", "wa")],
+                axis=1).reshape(-1, D)
+            sd[la + "conv1d.weight"] = np.concatenate(
+                [w[n][j].T for n in ("conv_q", "conv_k", "conv_v")],
+                axis=0)[:, None, :]
+            sd[la + "A_log"], sd[la + "dt_bias"] = w["A_log"][j], \
+                w["dt_bias"][j]
+            sd[la + "norm.weight"] = w["o_norm"][j]
+            sd[la + "out_proj.weight"] = w["wo"][j].T
+        else:
+            w, sa = lay["attn"], p + "self_attn."
+            for n, t in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj"),
+                         ("wo", "o_proj")):
+                sd[sa + t + ".weight"] = w[n][j].T
+            sd[sa + "q_norm.weight"] = w["q_norm"][j]
+            sd[sa + "k_norm.weight"] = w["k_norm"][j]
+        for n, t in (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                     ("w_down", "down_proj")):
+            for e in range(cfg.num_experts):
+                sd[f"{p}mlp.experts.{e}.{t}.weight"] = np.asarray(
+                    mlp[n][i, e]).T
+            sd[f"{p}mlp.shared_expert.{t}.weight"] = np.asarray(
+                mlp["shared"][n][i]).T
+        sd[p + "mlp.gate.weight"] = np.asarray(mlp["router"][i]).T
+        sd[p + "mlp.shared_expert_gate.weight"] = np.asarray(
+            mlp["shared"]["w_sg"][i]).T
+    return sd
+
+
 _PARAM_BUILDERS = {
     **{m: _build_llama_family for m in _LLAMA_FAMILY},
+    "qwen3_next": _build_qwen3_next,
     "falcon": _build_falcon,
     "gpt_neox": _build_gpt_neox,
     "gpt2": _build_gpt2,

@@ -107,6 +107,12 @@ class TransformerConfig:
     # (ops/delta_rule.py: ``CHUNK``); ``delta_neg_eigval``: the step is
     # 2 sigmoid, so that a head's transition may have eigenvalues below 0
     delta_heads: int = 0
+    # the heads of a delta layer's q and k where they are fewer than its
+    # ``delta_heads`` (then the heads of v, z, the step, the decay and the
+    # state): a divisor of them, value head i reading key head
+    # i // (delta_heads / delta_key_heads) (Qwen3-Next: 16 for 32). None =
+    # as many
+    delta_key_heads: Optional[int] = None
     delta_key_dim: int = 128
     delta_value_dim: int = 128
     delta_conv: int = 4
@@ -140,6 +146,12 @@ class TransformerConfig:
     # "partial_rotary_factor" takes rope_pct
     rope_by_kind: Optional[Dict[str, Dict[str, Any]]] = None
     norm_eps: float = 1e-5
+    # an RMSNorm's scale is kept as its distance from one: every norm of the
+    # block, the q / k norms (``qk_norm``) and the final norm multiply by
+    # ``1 + scale`` and the scale is drawn at 0 (the Qwen3-Next family's
+    # norms, Gemma's: weight decay then pulls the factor to 1, not to 0); a
+    # delta layer's gated output norm stays plain
+    norm_zero_centred: bool = False
 
     dtype: str = "bfloat16"        # compute dtype
     param_dtype: str = "float32"   # storage dtype (master weights)
@@ -256,6 +268,10 @@ class TransformerConfig:
     # (SwiGLU, or two products round relu^2) of that many times the experts'
     # width beside the routed ones (grouped dispatch only)
     moe_shared_experts: int = 0
+    # the shared experts' output times ``sigmoid(x w_sg)``, ``w_sg`` [D, 1]
+    # (``shared/w_sg``), one scalar a token (Qwen2-MoE's and Qwen3-Next's
+    # ``shared_expert_gate``)
+    moe_shared_gate: bool = False
     # LatentMoE: the tokens go through dispatch, the routed experts and
     # combine in a latent of this width (a plain linear map down before the
     # dispatch, one up after the combine; ``latent_down``, ``latent_up``);
@@ -284,6 +300,13 @@ class TransformerConfig:
     # form): in a latent-attention layer and in a "window" or "full" layer,
     # there under the scope ``attn_gate`` (a KDA layer always has the gate)
     mla_head_gate: bool = False
+    # a gate a channel on a "window" or "full" layer's heads
+    # (arXiv:2505.06708's element-wise form, Qwen3-Next's): ``wq`` is twice
+    # as wide, a head's ``2 head_dim`` columns its query then its gate, and
+    # each head's output is multiplied by ``sigmoid(gate)`` before ``wo``,
+    # under the scope ``attn_gate`` (``mla_head_gate`` beside it is the gate
+    # a head from ``wg``; a model has one or the other)
+    attn_channel_gate: bool = False
     # a rope whose frequency pairs follow three position axes (time, height,
     # width; HF ``rope_scaling.mrope_section``): consecutive sections of the
     # head's rope_dim / 2 pairs, the first turning by the first axis's
@@ -391,11 +414,18 @@ class TransformerConfig:
                 raise ValueError(
                     f"a delta layer needs delta_heads={self.delta_heads} "
                     f"and delta_conv={self.delta_conv} above 0")
-            if self.num_experts > 1:
+            kh = self.delta_key_heads
+            if kh is not None and (kh < 1 or self.delta_heads % kh):
+                raise ValueError(
+                    f"delta_key_heads={kh} does not divide delta_heads="
+                    f"{self.delta_heads}: a key head serves a whole number "
+                    f"of value heads")
+            if self.num_experts > 1 and self.moe_dispatch != "grouped":
                 raise NotImplementedError(
-                    "a delta layer beside routed experts (num_experts > 1): "
-                    "the expert layer's step record and a recurrent mixer's "
-                    "have not been run together")
+                    "a delta layer beside routed experts (num_experts > 1) "
+                    "runs the grouped dispatch (moe_dispatch='grouped'), "
+                    "whose step record it has been run with; not the "
+                    "capacity form")
             if self.looped:
                 raise NotImplementedError(
                     "a delta layer in a looped stack (num_passes > 1, "
@@ -613,6 +643,13 @@ class TransformerConfig:
                 raise ValueError(
                     f"heads_held={n} cut a group of {group} query heads "
                     f"from the key-value head that serves it")
+            if self.has_delta and n % (self.delta_heads // (
+                    self.delta_key_heads or self.delta_heads)):
+                raise ValueError(
+                    f"heads_held={n} cut a group of a delta layer's value "
+                    f"heads (delta_heads={self.delta_heads}) from the key "
+                    f"head that serves it (delta_key_heads="
+                    f"{self.delta_key_heads})")
             if (self.has_ssm or self.looped
                     or self.parallel_block or self.qkv_bias or self.proj_bias
                     or self.attention_impl == "fpdt"):
@@ -645,10 +682,51 @@ class TransformerConfig:
                     "attention_impl='fpdt'")
         if self.mla_head_gate and not (self.has_mla
                                        or self.gates_plain_heads):
-            raise ValueError("mla_head_gate gates the heads of latent "
+            raise ValueError("mla_head_gate (the gate a head, from wg "
+                             "[D, H]) gates the heads of latent "
                              "attention (kv_lora_rank) or of 'window' / "
                              "'full' attention layers: this model has "
-                             "neither")
+                             "neither (the gate a channel, from wq's second "
+                             "half, is attn_channel_gate)")
+        if self.attn_channel_gate:
+            if self.mla_head_gate or not self.has_plain_attention:
+                raise ValueError(
+                    "attn_channel_gate (the gate a channel, from wq's "
+                    "second half) gates the heads of 'window' / 'full' "
+                    "attention layers, which this model lacks, and not "
+                    "beside mla_head_gate (the gate a head, from wg): one "
+                    "gate or the other")
+            if self.qk_norm == "width" or self.has_dsa or self.has_bd:
+                raise NotImplementedError(
+                    "attn_channel_gate with qk_norm='width' (the norm over "
+                    "the whole projection would take the gates' columns "
+                    "in), 'dsa' layers (whose block reads no gate) or block "
+                    "diffusion (the halves' results are projected apart)")
+        if self.norm_zero_centred:
+            if self.norm != "rmsnorm":
+                raise ValueError(
+                    f"norm_zero_centred is an RMSNorm's scale kept as its "
+                    f"distance from one: not norm={self.norm!r}")
+            if (self.looped or self.parallel_block or self.one_branch
+                    or self.norm_placement != "pre" or self.has_mla
+                    or self.has_kda or self.has_dsa or self.has_ssm
+                    or self.has_conv or self.has_bd or self.loss_tiling > 1
+                    or self.attention_impl in ("fpdt", "ring")):
+                raise NotImplementedError(
+                    "zero-centred norms (norm_zero_centred) are applied by "
+                    "the train step's two-branch pre-norm block of plain "
+                    "attention and delta layers, its q / k norms and the "
+                    "final norm: not a looped stack (num_passes > 1, "
+                    "sandwich_norm or the exit gate), parallel_block, "
+                    "one_branch, norm_placement='post', latent attention, "
+                    "KDA, 'dsa', state-space or short-convolution layers "
+                    "(whose own norms are written plain), block diffusion, "
+                    "the tiled loss (loss_tiling > 1) or attention_impl="
+                    "'fpdt' / 'ring' (which norm chunk by chunk)")
+        if self.moe_shared_gate and not self.moe_shared_experts:
+            raise ValueError("moe_shared_gate gates the shared experts' "
+                             "output: this model has none "
+                             "(moe_shared_experts=0)")
         if self.heads_by_kind is not None:
             by = {k: int(n) for k, n in self.heads_by_kind.items()}
             if (set(by) - {"window", "full"}
@@ -681,8 +759,9 @@ class TransformerConfig:
                 or self.attention_impl in ("fpdt", "ring")):
             raise NotImplementedError(
                 "query heads by kind (heads_by_kind), a rope width by kind "
-                "(rope_by_kind's partial_rotary_factor) and the head gate on "
-                "'window' / 'full' layers (mla_head_gate) run one pre-norm "
+                "(rope_by_kind's partial_rotary_factor) and a gate on "
+                "'window' / 'full' layers' heads (mla_head_gate: a head; "
+                "attn_channel_gate: a channel) run one pre-norm "
                 "pass of two-branch layers without biases over whole "
                 "sequences with whole logits: not a looped stack "
                 "(num_passes > 1, sandwich_norm or the exit gate), "
@@ -803,19 +882,26 @@ class TransformerConfig:
         return self.heads_here * self.num_kv_heads // self.num_heads
 
     @property
+    def has_plain_attention(self) -> bool:
+        """Whether any layer's mixer is plain attention ("window" or
+        "full")."""
+        return any(k.partition(":")[0] in ("window", "full")
+                   for k in self.layer_kinds)
+
+    @property
     def gates_plain_heads(self) -> bool:
         """Whether a "window" or "full" layer's heads are under the head gate
         (``mla_head_gate`` in a model with such layers)."""
-        return self.mla_head_gate and any(
-            k.partition(":")[0] in ("window", "full")
-            for k in self.layer_kinds)
+        return self.mla_head_gate and self.has_plain_attention
 
     @property
     def attn_differs_by_kind(self) -> bool:
         """Whether "window" and "full" layers carry what only the train
         step's block applies: query heads by kind, a rope width by kind
-        (``rope_by_kind``'s "partial_rotary_factor") or the head gate."""
-        return bool(self.heads_by_kind) or self.gates_plain_heads or any(
+        (``rope_by_kind``'s "partial_rotary_factor"), the gate a head or the
+        gate a channel."""
+        return bool(self.heads_by_kind) or self.gates_plain_heads \
+            or self.attn_channel_gate or any(
             "partial_rotary_factor" in r
             for r in (self.rope_by_kind or {}).values())
 
@@ -835,7 +921,8 @@ class TransformerConfig:
         return (self.has_ssm or self.has_mla or self.has_delta
                 or self.has_conv or self.has_kda or self.has_dsa
                 or self.one_branch or bool(self.heads_by_kind)
-                or self.gates_plain_heads or self.has_bd)
+                or self.gates_plain_heads or self.attn_channel_gate
+                or self.has_bd)
 
     @property
     def has_ffn_kinds(self) -> bool:
@@ -922,6 +1009,8 @@ class TransformerConfig:
         def attn_of(ck: "TransformerConfig") -> int:
             hd, nh, nkv = ck.head_dim, ck.heads_here, ck.kv_heads_here
             attn = D * nh * hd + 2 * D * nkv * hd + nh * hd * D
+            if self.attn_channel_gate:
+                attn += D * nh * hd
             if ck.qk_norm:
                 attn += 2 * hd if ck.qk_norm == "head" else (nh + nkv) * hd
             if ck.qkv_bias:
@@ -942,6 +1031,7 @@ class TransformerConfig:
             routed = ((self.moe_experts_held or self.num_experts)
                       * n * (Z or D) * Fm + D * self.num_experts
                       + n * D * Fm * self.moe_shared_experts
+                      + (D if self.moe_shared_gate else 0)
                       + 2 * D * (Z or 0))
             if self.moe_scoring == "sigmoid":
                 routed += self.num_experts
@@ -1055,11 +1145,15 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = Tr
 # Layers
 # ---------------------------------------------------------------------------
 
-def _norm(x: jax.Array, w: Params, kind: str, eps: float) -> jax.Array:
+def _norm(x: jax.Array, w: Params, kind: str, eps: float,
+          zero_centred: bool = False) -> jax.Array:
+    """``zero_centred`` (``cfg.norm_zero_centred``): the RMSNorm multiplies
+    by ``1 + scale``, float32."""
     xf = x.astype(jnp.float32)
     if kind == "rmsnorm":
         xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-        out = xf * w["scale"].astype(jnp.float32)
+        scale = w["scale"].astype(jnp.float32)
+        out = xf * (1.0 + scale if zero_centred else scale)
     else:
         mu = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
@@ -1255,11 +1349,19 @@ def linear(x: jax.Array, w) -> jax.Array:
     return x @ w
 
 
-def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
+def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig,
+             with_gate: bool = False):
     """Shared q/k/v projection (+ optional qwen-style biases) for every
     forward path (train, dense decode, paged decode). Serving engines may
     install a fused ``wqkv`` [D, (H+2K)*hd] leaf (one kernel launch instead
-    of three — decode is a chain of small kernels)."""
+    of three — decode is a chain of small kernels). ``with_gate``: a fourth
+    value, the gate a channel [B, T, H, hd] that ``wq``'s second half of each
+    head's columns gives under ``cfg.attn_channel_gate`` (None without it);
+    a caller that does not ask is one that applies no gate, and is refused."""
+    if cfg.attn_channel_gate and not with_gate:
+        raise NotImplementedError(
+            "attn_channel_gate: wq holds each head's query and its gate; "
+            "only the train step's attention block applies the gate")
     B, T = x.shape[0], x.shape[1]
     hd, H, K = cfg.head_dim, cfg.heads_here, cfg.kv_heads_here
     if "wqkv" in w:
@@ -1275,13 +1377,20 @@ def qkv_proj(x: jax.Array, w: Params, cfg: TransformerConfig):
         # over the whole projection (every head held), before the split
         q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps)
         k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps)
+    gate = None
+    if cfg.attn_channel_gate:
+        # a head's columns: its query, then its gate
+        q, gate = jnp.split(q.reshape(B, T, H, 2 * hd), 2, axis=-1)
     q, k = q.reshape(B, T, H, hd), k.reshape(B, T, K, hd)
     if cfg.qk_norm == "head":
         # over each head's channels, one scale for all heads; the caller's
         # rope comes after it
-        q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps)
-        k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps)
-    return q, k, v.reshape(B, T, K, hd)
+        q = _norm(q, {"scale": w["q_norm"]}, "rmsnorm", cfg.norm_eps,
+                  cfg.norm_zero_centred)
+        k = _norm(k, {"scale": w["k_norm"]}, "rmsnorm", cfg.norm_eps,
+                  cfg.norm_zero_centred)
+    v = v.reshape(B, T, K, hd)
+    return (q, k, v, gate) if with_gate else (q, k, v)
 
 
 def attn_out_proj(attn: jax.Array, w: Params, cfg: TransformerConfig) -> jax.Array:
@@ -1323,7 +1432,7 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         o = fpdt_block_attention(x, w, cfg, freqs)
         if o is not None:
             return constrain(o, P(("dp", "fsdp"), "sp", None))
-    q, k, v = qkv_proj(x, w, cfg)
+    q, k, v, gate = qkv_proj(x, w, cfg, with_gate=True)
     q = constrain(q, P(("dp", "fsdp"), "sp", "tp", None))
     k = constrain(k, P(("dp", "fsdp"), "sp", "tp", None))
     if cfg.use_rope and cfg.mrope_section is not None:
@@ -1358,12 +1467,14 @@ def attention_block(x: jax.Array, w: Params, cfg: TransformerConfig,
         out = attn_fn(q, k, v, causal=True, chunk=cfg.fpdt_chunk)
     else:
         out = attn_fn(q, k, v, causal=True)
-    if "wg" in w:
+    if "wg" in w or gate is not None:
         with jax.named_scope("attn_gate"):
-            # one scalar a head and position, on the heads' outputs
+            # on the heads' outputs: one scalar a head and position from
+            # ``wg``, or one a channel from ``wq``'s second half
+            if gate is None:
+                gate = (x @ w["wg"])[..., None]
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(
-                (x @ w["wg"]).astype(jnp.float32))[..., None]
-            ).astype(x.dtype)
+                gate.astype(jnp.float32))).astype(x.dtype)
     o = attn_out_proj(out, w, cfg)
     return constrain(o, P(("dp", "fsdp"), "sp", None))
 
@@ -1417,11 +1528,16 @@ def _decode_block(h: jax.Array, wc: Params, cfg: TransformerConfig,
     shared norm, biases, MoE).
     ``moe_valid`` [B, t] marks real (non-padding/idle) lanes: without it the
     batch's no-op rows would compete for expert capacity and skew routing."""
-    if cfg.gates_plain_heads:
+    if cfg.gates_plain_heads or cfg.attn_channel_gate:
         raise NotImplementedError(
             "the decode block multiplies no head's output by a gate "
-            "(mla_head_gate on 'window' / 'full' layers); only the train "
-            "step's block does")
+            "(mla_head_gate, a head, or attn_channel_gate, a channel, on "
+            "'window' / 'full' layers); only the train step's block does")
+    if cfg.norm_zero_centred or cfg.moe_shared_gate:
+        raise NotImplementedError(
+            "the decode block's norms multiply by the scale itself and its "
+            "shared experts have no gate (norm_zero_centred, "
+            "moe_shared_gate); only the train step's block applies them")
 
     def _mlp(hn):
         if moe_fn is not None:
@@ -1669,7 +1785,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                                    if kind and kind not in ("ssm", "delta",
                                                             "conv", "kda")
                                    else contextlib.nullcontext()):
-        hn1 = x if post else _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps)
+        hn1 = x if post else _norm(x, wc["ln1"], cfg.norm, cfg.norm_eps,
+                                   cfg.norm_zero_centred)
         if kind == "delta":
             from deepspeed_tpu.models.gated_delta import delta_block
 
@@ -1721,7 +1838,8 @@ def transformer_block(x: jax.Array, w: Params, cfg: TransformerConfig,
                 x, wc["ln2"], cfg.norm, cfg.norm_eps)
         else:
             x = x + attn_out
-            h = x if post else _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps)
+            h = x if post else _norm(x, wc["ln2"], cfg.norm, cfg.norm_eps,
+                                     cfg.norm_zero_centred)
         if moe_fn is not None:
             mlp_out, aux = moe_fn(h, wc["mlp"], cfg)
         else:
@@ -2052,14 +2170,24 @@ class TransformerLM:
                 f"(diffusion_block={cfg.diffusion_block}: a [noised ; "
                 f"clean] row under the block-diffusion mask, decoded a "
                 f"block at a time); only the train step runs it")
+        if cfg.norm_zero_centred or cfg.moe_shared_gate:
+            raise NotImplementedError(
+                f"{what} norms by the scale itself and adds the shared "
+                f"experts ungated: this model has zero-centred norms "
+                f"(norm_zero_centred={cfg.norm_zero_centred}: 1 + scale) or "
+                f"a gate on its shared experts (moe_shared_gate="
+                f"{cfg.moe_shared_gate}); only the train step's block "
+                f"applies them")
         if cfg.attn_differs_by_kind:
             raise NotImplementedError(
                 f"{what} reads one stack of attention leaves with one head "
                 f"count, one rope width and no gate: this model has "
                 f"heads_by_kind={cfg.heads_by_kind} (a stack of leaves and a "
                 f"cache shape for each kind), a rope width by kind "
-                f"(rope_by_kind={cfg.rope_by_kind}) or the head gate on its "
-                f"attention layers (mla_head_gate={cfg.mla_head_gate}); only "
+                f"(rope_by_kind={cfg.rope_by_kind}) or a gate on its "
+                f"attention layers' heads (mla_head_gate="
+                f"{cfg.mla_head_gate}, a head; attn_channel_gate="
+                f"{cfg.attn_channel_gate}, a channel); only "
                 f"the train step's block applies them")
         if cfg.has_delta:
             raise NotImplementedError(
@@ -2182,6 +2310,25 @@ class TransformerLM:
             # (the KDA kind's rule runs in the delta rule's chunks)
             from deepspeed_tpu.ops import delta_rule
             chunks["delta" if cfg.has_delta else "kda"] = delta_rule.CHUNK
+        if cfg.has_delta:
+            from deepspeed_tpu.models import gated_delta
+
+            # (key heads, value heads) a delta layer holds and, once the
+            # rows' length is known, the lowering the rule's picker gives
+            # each delta layer, by layer index (``ops/delta_rule.py:
+            # rule_lowering``, the function the layer itself asks; the row's
+            # ``delta_qk_rows`` counts the rows of q and k the rules traced
+            # read: a repeat of q and k to the value heads doubles it)
+            sz = gated_delta.sizes(cfg)
+            facts["delta_heads"] = (sz["key_heads"], sz["heads"])
+            if batch_shape is not None:
+                took = delta_rule.rule_lowering(
+                    int(batch_shape[1]), sz["heads"], cfg.delta_key_dim,
+                    cfg.delta_value_dim, cfg.dtype,
+                    key_heads=sz["key_heads"])[0]
+                facts["delta_rule_lowering"] = {
+                    i: took for i, k in enumerate(cfg.layer_kinds)
+                    if k.partition(":")[0] == "delta"}
         for kind, chunk in chunks.items():
             # the chunk length of the kind's scan, and the chunks one step's
             # forward scans: layers x rows x ceil(T / chunk)
@@ -2240,6 +2387,11 @@ class TransformerLM:
             # attention: the flash kernels take both)
             facts["attn_widths"] = (
                 cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim)
+        elif plain and cfg.head_dim > 128:
+            # and where a plain head is wider than the kernels' usual 128
+            # (the row's ``flash_bwd_arm`` says which backward such a head
+            # took, fused or split, by width)
+            facts["attn_widths"] = (cfg.head_dim, cfg.head_dim)
         if cfg.num_experts > 1:
             if cfg.moe_scoring != "softmax":
                 # how the router scores where it is not the softmax
@@ -2338,12 +2490,14 @@ class TransformerLM:
                 f"and its set is chosen among all of a row's keys, so the "
                 f"layer keeps heads and rows whole on a chip")
         if axis_sizes.get("tp", 1) > 1 and (cfg.heads_by_kind
-                                            or cfg.gates_plain_heads):
+                                            or cfg.gates_plain_heads
+                                            or cfg.attn_channel_gate):
             raise NotImplementedError(
                 f"a tp axis of {axis_sizes['tp']} with query heads by kind "
-                f"(heads_by_kind={cfg.heads_by_kind}) or the head gate on "
-                f"attention layers (mla_head_gate): the kinds' stacks and "
-                f"the gate's [D, H] columns are laid out whole on a chip")
+                f"(heads_by_kind={cfg.heads_by_kind}) or a gate on "
+                f"attention layers' heads (mla_head_gate, attn_channel_gate"
+                f"): the kinds' stacks, the gate's [D, H] columns and wq's "
+                f"query-then-gate columns are laid out whole on a chip")
         if axis_sizes.get("tp", 1) > 1 and (cfg.has_delta or cfg.has_conv
                                             or cfg.has_kda
                                             or cfg.heads_held is not None):
@@ -2370,7 +2524,9 @@ class TransformerLM:
             return dense(key, fan_in, (n,) + shape)
 
         kinds = cfg.layer_kinds
-        norm_w = {"scale": jnp.ones((L, D), pd)}
+        # a norm's scale as drawn: 1, or 0 where it is its distance from one
+        unit_scale = jnp.zeros if cfg.norm_zero_centred else jnp.ones
+        norm_w = {"scale": unit_scale((L, D), pd)}
         if cfg.norm == "layernorm":
             norm_w["bias"] = jnp.zeros((L, D), pd)
         # one stack of mixer leaves for each kind of mixer, a row for each
@@ -2381,7 +2537,9 @@ class TransformerLM:
         def attn_stack(ck: TransformerConfig, La: int, ks) -> Params:
             hd, H, K = ck.head_dim, ck.heads_here, ck.kv_heads_here
             attn_w = {
-                "wq": layer_stack(ks[0], D, (D, H * hd), La),
+                # (under the gate a channel a head's query, then its gate)
+                "wq": layer_stack(ks[0], D, (D, H * hd * (
+                    2 if cfg.attn_channel_gate else 1)), La),
                 "wk": layer_stack(ks[1], D, (D, K * hd), La),
                 "wv": layer_stack(ks[2], D, (D, K * hd), La),
                 "wo": layer_stack(ks[3], H * hd, (H * hd, D), La),
@@ -2393,11 +2551,11 @@ class TransformerLM:
             if cfg.proj_bias:
                 attn_w["bo"] = jnp.zeros((La, D), pd)
             if cfg.qk_norm == "head":
-                attn_w["q_norm"] = jnp.ones((La, hd), pd)
-                attn_w["k_norm"] = jnp.ones((La, hd), pd)
+                attn_w["q_norm"] = unit_scale((La, hd), pd)
+                attn_w["k_norm"] = unit_scale((La, hd), pd)
             elif cfg.qk_norm:
-                attn_w["q_norm"] = jnp.ones((La, H * hd), pd)
-                attn_w["k_norm"] = jnp.ones((La, K * hd), pd)
+                attn_w["q_norm"] = unit_scale((La, H * hd), pd)
+                attn_w["k_norm"] = unit_scale((La, K * hd), pd)
             if cfg.gates_plain_heads:
                 attn_w["wg"] = layer_stack(ks[4], D, (D, H), La)
             return attn_w
@@ -2448,6 +2606,9 @@ class TransformerLM:
                     "w_down": layer_stack(more[3], Fs, (Fs, D), Lm)}
                 if cfg.activation != "swiglu":
                     del mlp["shared"]["w_gate"]
+                if cfg.moe_shared_gate:
+                    mlp["shared"]["w_sg"] = layer_stack(
+                        jax.random.fold_in(rng, 23), D, (D, 1), Lm)
             if cfg.moe_latent_size:
                 lat = jax.random.split(jax.random.fold_in(rng, 16), 2)
                 mlp["latent_down"] = layer_stack(lat[0], D, (D, Z), Lm)
@@ -2504,7 +2665,7 @@ class TransformerLM:
             "embed": {"tokens": dense(keys[0], 1, (V, D))
                       * cfg.embed_init_std},
             "layers": layers,
-            "final_norm": {"scale": jnp.ones((D,), pd)},
+            "final_norm": {"scale": unit_scale((D,), pd)},
         }
         if cfg.norm == "layernorm":
             params["final_norm"]["bias"] = jnp.zeros((D,), pd)
@@ -2643,7 +2804,8 @@ class TransformerLM:
             with jax.named_scope("final_norm"):
                 if head_rows is not None:
                     x = x[:, :head_rows]
-                x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+                x = _norm(x, params["final_norm"], cfg.norm, cfg.norm_eps,
+                          cfg.norm_zero_centred)
                 x = constrain(x, P(("dp", "fsdp"), "sp", None))
             hs.append(x)
             aux = a if aux is None else jax.tree_util.tree_map(jnp.add, aux, a)
@@ -3420,6 +3582,8 @@ class TransformerLM:
                              "w_down": P(None, "tp", None)}
             if cfg.activation != "swiglu":
                 del mlp["shared"]["w_gate"]
+            if cfg.moe_shared_gate:
+                mlp["shared"]["w_sg"] = P(None, None, None)
         if cfg.num_experts > 1 and cfg.moe_latent_size:
             mlp["latent_down"] = P(None, None, None)
             mlp["latent_up"] = P(None, None, None)

@@ -359,10 +359,18 @@ def _ungated_act(cfg: Any):
 
 def _shared_ffn(h: jax.Array, w: Dict[str, jax.Array], act) -> jax.Array:
     """The shared experts, every token's: one SwiGLU, or without a gate
-    product two products round ``act``."""
+    product two products round ``act``; with ``w["w_sg"]`` [D, 1] times
+    ``sigmoid(h w_sg)``."""
     if "w_gate" in w:
-        return (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
-    return act(h @ w["w_up"]) @ w["w_down"]
+        out = (jax.nn.silu(h @ w["w_gate"]) * (h @ w["w_up"])) @ w["w_down"]
+    else:
+        out = act(h @ w["w_up"]) @ w["w_down"]
+    if "w_sg" in w:
+        # one scalar a token on the shared experts' output (``cfg.
+        # moe_shared_gate``): the sigmoid in float32, rounded once
+        out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+            (h @ w["w_sg"]).astype(jnp.float32))).astype(out.dtype)
+    return out
 
 
 def topk_gating(logits: jax.Array, k: int = 2, capacity_factor: float = 1.25,

@@ -61,7 +61,15 @@ can see: backend, dtype, widths):
   layer puts on a head's q and k are taken there too, on the float32 rows
   the convolutions left, and the backward hands out the cotangents of those
   rows: a ``[B, T, H, 96]`` view of them costs XLA a copy of the array each
-  way, which were a third of the scope's time around the kernels. The
+  way, which were a third of the scope's time around the kernels. At keys
+  and values of 128 a head's columns are whole lane tiles: a block is then
+  the head's own columns and nothing is cut out or copied in VMEM; and there
+  q and k may have fewer heads than v (``Hk`` key heads, value head ``i``
+  reading key head ``i // (H / Hk)``, Qwen3-Next's 16 for 32): the grid's
+  value heads that share a key head follow each other, so its q and k block
+  stays in VMEM for them, and the backward adds their dq and dk in the
+  output block that stays with it. No ``[B, T, H, dk]`` copy of q or k
+  exists in HBM. The
   forward that is differentiated also writes each chunk's incoming state
   (float32), which the backward reads instead of running the recurrence
   again; its output and those states carry a
@@ -213,8 +221,8 @@ def rule_einsum(q: jax.Array, k: jax.Array, v: jax.Array,
 #: one head's work under 1 us); its body is unrolled over them, two by two
 _CHUNKS_A_STEP = 8
 #: (keys, values) a head: what the kernels were built, tested and (the
-#: first) measured for; widths need only be whole sublane tiles
-_WIDTHS = ((96, 192), (64, 128), (32, 64))
+#: first and the last) measured for; widths need only be whole sublane tiles
+_WIDTHS = ((96, 192), (64, 128), (32, 64), (128, 128))
 _VMEM_LIMIT = 64 * 1024 * 1024
 #: two chunks' positions: the rows of a pair's operands, the lanes of its
 #: packed [C, C] tiles
@@ -392,6 +400,12 @@ def _halves(x):
     return x[:CHUNK], x[CHUNK:]
 
 
+def _whole_tiles(dk: int, dv: int) -> bool:
+    """Whether a head's columns of q, k and v are whole lane tiles: a block
+    is then one head's, cut by the block spec and not in VMEM."""
+    return dk % 128 == 0 and dv % 128 == 0
+
+
 def unit_heads(x: jax.Array, scale: float, eps: float, dtype) -> jax.Array:
     """x [..., d] -> each row scaled to length ``scale``, ``x rsqrt(sum x^2
     + eps) scale`` in ``x``'s dtype, rounded to ``dtype``: the norm a delta
@@ -450,14 +464,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
                 unit):
     """``pairs`` pairs of chunks of one head. ``rest``: the incoming states'
     block [2 pairs, dk, dv] (where ``states``), then scratch: every head's
-    carried state [H, dk, dv] float32 and the head's q, k, v and o. ``unit``
-    (q's length, eps): q and k arrive as the convolutions left them."""
+    carried state [H, dk, dv] float32 and (where the blocks are the arrays'
+    full width) the head's q, k, v and o. ``unit`` (q's length, eps): q and k
+    arrive as the convolutions left them."""
     sin_ref = rest[0] if states else None
     s_ref, *mine = rest[1 if states else 0:]
     C, dt = CHUNK, v_ref.dtype
     n, h = pl.program_id(1), pl.program_id(2)
-    qh_ref, kh_ref, vh_ref = _one_head((q_ref, k_ref, v_ref), mine[:3], h, H)
-    oh_ref = mine[3]
+    if mine:
+        qh_ref, kh_ref, vh_ref = _one_head((q_ref, k_ref, v_ref), mine[:3],
+                                           h, H)
+        oh_ref = mine[3]
+    else:                       # the blocks are the head's own columns
+        qh_ref, kh_ref, vh_ref, oh_ref = q_ref, k_ref, v_ref, o_ref
 
     @pl.when(n == 0)
     def _start():
@@ -498,16 +517,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
             + jnp.concatenate(from_state, axis=0) * since
         ).astype(oh_ref.dtype)
     s_ref[h] = s
-    _heads_back((o_ref,), (oh_ref,), h, H)
+    if mine:
+        _heads_back((o_ref,), (oh_ref,), h, H)
 
 
 def _prepare(q, k, v, g, beta):
     """The kernels' operands from the rule's: padded to whole grid steps
     (positions of ``k = 0``, ``g = 0``), q, k and v with the heads side by
     side in lanes as the projections wrote them, ``g`` and ``beta`` as
-    float32 rows a head and pair of chunks [B, H, steps, pairs, 2 C]."""
-    B, T, H, dk = q.shape
-    dv = v.shape[-1]
+    float32 rows a head and pair of chunks [B, H, steps, pairs, 2 C]. q and
+    k may have fewer heads than v (``Hk``: a whole number of value heads to
+    each)."""
+    B, T, Hk, dk = q.shape
+    H, dv = v.shape[-2:]
     pairs = min(_CHUNKS_A_STEP // 2, -(-T // _PAIR))
     L = pairs * _PAIR
     q, k, v, g, beta = _pad_to_chunks(T, L, q, k, v, g, beta)
@@ -523,20 +545,29 @@ def _prepare(q, k, v, g, beta):
     consts = tuple(jnp.asarray(m, jnp.bfloat16)
                    for m in _layout_constants(2 * pairs))
     return (lanes(q), lanes(k), lanes(v), rows(g), rows(beta)) + consts, \
-        (B, T, Tp, H, dk, dv, pairs)
+        (B, T, Tp, H, Hk, dk, dv, pairs)
 
 
-def _specs(H, dk, dv, pairs, flip=None):
+def _specs(H, Hk, dk, dv, pairs, flip=None):
     """Block specs over the grid (sequence, step of ``pairs`` pairs of
-    chunks, head); ``flip`` (the steps there are) turns the steps around,
-    for the backward."""
+    chunks, value head); ``flip`` (the steps there are) turns the steps
+    around, for the backward. A block of q, k or v is the arrays' full width
+    (the kernel cuts its head out), or where a head's columns are whole lane
+    tiles the head's own: q's and k's then the key head's that serves the
+    step's value head."""
     L = pairs * _PAIR
 
     def st(n):
         return n if flip is None else flip - 1 - n
 
-    keys = pl.BlockSpec((None, L, H * dk), lambda b, n, h: (b, st(n), 0))
-    vals = pl.BlockSpec((None, L, H * dv), lambda b, n, h: (b, st(n), 0))
+    if _whole_tiles(dk, dv):
+        rep = H // Hk
+        keys = pl.BlockSpec((None, L, dk),
+                            lambda b, n, h: (b, st(n), h // rep))
+        vals = pl.BlockSpec((None, L, dv), lambda b, n, h: (b, st(n), h))
+    else:
+        keys = pl.BlockSpec((None, L, H * dk), lambda b, n, h: (b, st(n), 0))
+        vals = pl.BlockSpec((None, L, H * dv), lambda b, n, h: (b, st(n), 0))
     rows = pl.BlockSpec((None, None, None, pairs, _PAIR),
                         lambda b, n, h: (b, h, st(n), 0, 0))
     state = pl.BlockSpec((None, None, 2 * pairs, dk, dv),
@@ -546,9 +577,11 @@ def _specs(H, dk, dv, pairs, flip=None):
     return keys, vals, rows, state, consts
 
 
-def _head_scratch(L, *like):
-    """A head's columns of a step's blocks ``like`` [B, T, H w]."""
-    return [pltpu.VMEM((L, a.shape[-1]), a.dtype) for a in like]
+def _head_scratch(L, whole, *like):
+    """A head's columns of a step's blocks ``like`` [B, T, H w]; none where
+    the blocks are a head's own (``whole``)."""
+    return [] if whole else [pltpu.VMEM((L, a.shape[-1]), a.dtype)
+                             for a in like]
 
 
 def _call(kernel, steps, B, H, **kw):
@@ -564,9 +597,9 @@ def rule_fwd(q, k, v, g, beta, *, states: bool, unit=None,
              interpret: bool = False):
     """``o`` [B, T, H, dv] and, where ``states``, each chunk's incoming
     state [B, H, N, dk, dv] float32 (else None)."""
-    ops, (B, T, Tp, H, dk, dv, pairs) = _prepare(q, k, v, g, beta)
+    ops, (B, T, Tp, H, Hk, dk, dv, pairs) = _prepare(q, k, v, g, beta)
     L = pairs * _PAIR
-    keys, vals, rows, state, consts = _specs(H, dk, dv, pairs)
+    keys, vals, rows, state, consts = _specs(H, Hk, dk, dv, pairs)
     out_shape = [jax.ShapeDtypeStruct(ops[2].shape, v.dtype)]
     out_specs = [vals]
     if states:
@@ -579,8 +612,8 @@ def rule_fwd(q, k, v, g, beta, *, states: bool, unit=None,
         in_specs=[keys, keys, vals, rows, rows] + consts,
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((H, dk, dv), F32)]
-        + _head_scratch(L, q[..., 0, :], k[..., 0, :], v[..., 0, :],
-                        v[..., 0, :]),
+        + _head_scratch(L, _whole_tiles(dk, dv), q[..., 0, :], k[..., 0, :],
+                        v[..., 0, :], v[..., 0, :]),
         interpret=interpret)(*ops)
     return out[0].reshape(B, Tp, H, dv)[:, :T], (out[1] if states else None)
 
@@ -591,17 +624,32 @@ def rule_fwd(q, k, v, g, beta, *, states: bool, unit=None,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
                 unfold_ref, do_ref, sin_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                db_ref, ds_ref, *mine, pairs: int, H: int, unit):
+                db_ref, ds_ref, *mine, pairs: int, H: int, rep: int, unit):
     """``pairs`` pairs of chunks of one head, the steps and a step's chunks
     visited last first. Scratch: the cotangent of the state each head's
-    visited chunks leave [H, dk, dv] float32 and the head's q, k, v, do and
-    dq, dk, dv. A chunk's decays, ``T`` and ``U`` are rebuilt from the
-    operands and its saved incoming state."""
+    visited chunks leave [H, dk, dv] float32 and (where the blocks are the
+    arrays' full width) the head's q, k, v, do and dq, dk, dv. A chunk's
+    decays, ``T`` and ``U`` are rebuilt from the operands and its saved
+    incoming state. ``rep`` value heads read one key head: they follow each
+    other on the grid, the block of dq and of dk stays in VMEM for them, the
+    first writes it and the others add to it (float32 where q and k arrive
+    float32, as under ``unit``)."""
     C, dt = CHUNK, v_ref.dtype
     n, h = pl.program_id(1), pl.program_id(2)
-    qh_ref, kh_ref, vh_ref, doh_ref = _one_head(
-        (q_ref, k_ref, v_ref, do_ref), mine[:4], h, H)
-    dqh_ref, dkh_ref, dvh_ref = outs = mine[4:]
+    if mine:
+        qh_ref, kh_ref, vh_ref, doh_ref = _one_head(
+            (q_ref, k_ref, v_ref, do_ref), mine[:4], h, H)
+        dqh_ref, dkh_ref, dvh_ref = outs = mine[4:]
+    else:                       # the blocks are the head's own columns
+        qh_ref, kh_ref, vh_ref, doh_ref = q_ref, k_ref, v_ref, do_ref
+        dqh_ref, dkh_ref, dvh_ref = dq_ref, dk_ref, dv_ref
+    # whether this value head is the first of its key head's
+    first = None if rep == 1 else (h % rep) == 0
+
+    def onto_key_head(ref, rows, d):
+        if first is not None:
+            d = d + jnp.where(first, 0.0, ref[rows, :].astype(F32))
+        ref[rows, :] = d.astype(ref.dtype)
 
     @pl.when(n == 0)
     def _start():
@@ -704,13 +752,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
             + (lanes_sum(dof * qs) + m * b_c) * since - spent \
             + jnp.where((pos & (C - 1)) == C - 1, d_total, 0.0)
         dbeta = tile_rows(da * (ratio * kk)) + lanes_sum(dvb * vf) + m * since
-        dqh_ref[rows, :] = _unit_back(
+        onto_key_head(dqh_ref, rows, _unit_back(
             onto_k[:_PAIR] + jnp.concatenate([b[:C] for b in backs], axis=0),
-            q_in[p][1], dt).astype(dqh_ref.dtype)
-        dkh_ref[rows, :] = _unit_back(
+            q_in[p][1], dt))
+        onto_key_head(dkh_ref, rows, _unit_back(
             onto_k[_PAIR:] + _dot(both, jnp.concatenate([q, k], axis=0), _TN)
-            + dkb * (b_c * since) + dk_end * to_end, k_in[p][1], dt
-        ).astype(dkh_ref.dtype)
+            + dkb * (b_c * since) + dk_end * to_end, k_in[p][1], dt))
         dvh_ref[rows, :] = (dvb * b_c).astype(dvh_ref.dtype)
         dcum_cols = jnp.where(lane == p, dcum, dcum_cols)
         dbeta_cols = jnp.where(lane == p, dbeta, dbeta_cols)
@@ -720,7 +767,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, fold_ref, spread_ref,
         dcum_cols, (((r2 >> 6) == (c2 >> 6)) & (r2 >= c2)).astype(
             jnp.bfloat16), _TN)
     db_ref[...] = _exact_dot(dbeta_cols, (r2 == c2).astype(jnp.bfloat16), _TN)
-    _heads_back((dq_ref, dk_ref, dv_ref), outs, h, H)
+    if mine:
+        _heads_back((dq_ref, dk_ref, dv_ref), outs, h, H)
 
 
 @functools.partial(jax.jit, static_argnames=("unit", "interpret"))
@@ -728,23 +776,27 @@ def rule_bwd(q, k, v, g, beta, s_in, do, *, unit=None,
              interpret: bool = False):
     """The five cotangents of :func:`rule_fwd`'s ``o`` from ``do`` and the
     chunks' incoming states ``s_in``."""
-    ops, (B, T, Tp, H, dk, dv, pairs) = _prepare(q, k, v, g, beta)
+    ops, (B, T, Tp, H, Hk, dk, dv, pairs) = _prepare(q, k, v, g, beta)
     L = pairs * _PAIR
     do, = _pad_to_chunks(T, L, do)
-    keys, vals, rows, state, consts = _specs(H, dk, dv, pairs, flip=Tp // L)
+    keys, vals, rows, state, consts = _specs(H, Hk, dk, dv, pairs,
+                                             flip=Tp // L)
     heads = [a[..., 0, :] for a in (q, k, v)]
+    whole = _whole_tiles(dk, dv)
     dq, dk_, dv_, dg, db = _call(
-        functools.partial(_bwd_kernel, pairs=pairs, H=H, unit=unit),
+        functools.partial(_bwd_kernel, pairs=pairs, H=H, rep=H // Hk,
+                          unit=unit),
         Tp // L, B, H,
         in_specs=[keys, keys, vals, rows, rows] + consts + [vals, state],
         out_specs=[keys, keys, vals, rows, rows],
         out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in ops[:5]],
         scratch_shapes=[pltpu.VMEM((H, dk, dv), F32)]
-        + _head_scratch(L, *heads, heads[2]) + _head_scratch(L, *heads),
+        + _head_scratch(L, whole, *heads, heads[2])
+        + _head_scratch(L, whole, *heads),
         interpret=interpret)(*ops, do.reshape(B, Tp, H * dv), s_in)
 
     def lanes(a, w):
-        return a.reshape(B, Tp, H, w)[:, :T]
+        return a.reshape(B, Tp, -1, w)[:, :T]
 
     def rows_back(a, like):
         return a.reshape(B, H, Tp).transpose(0, 2, 1)[:, :T].astype(like.dtype)
@@ -782,38 +834,47 @@ def _rule_pallas_bwd(unit, interpret, res, do):
 _rule_pallas.defvjp(_rule_pallas_fwd, _rule_pallas_bwd)
 
 
-def _shapes_taken(dk: int, dv: int) -> str:
+def _shapes_taken(dk: int, dv: int, H: int = 1, key_heads: int = 1) -> str:
     """Why the kernels do not take these shapes; "" where they do."""
     if CHUNK != 64:
         return f"chunks of {CHUNK} (the kernels: 64)"
     if (dk, dv) not in _WIDTHS:
         return (f"keys of {dk} and values of {dv} (the kernels: "
                 + ", ".join(f"{a} / {b}" for a, b in _WIDTHS) + ")")
+    if key_heads != H and not _whole_tiles(dk, dv):
+        return (f"{key_heads} key heads for {H} value heads at {dk} / {dv} "
+                f"(the kernels share a key head's q and k where a head's "
+                f"columns are whole lane tiles: 128 / 128)")
     return ""
 
 
 def rule_lowering(T: int, H: int, dk: int, dv: int, dtype, *,
-                  tpu: Optional[bool] = None) -> Tuple[str, str]:
+                  tpu: Optional[bool] = None,
+                  key_heads: Optional[int] = None) -> Tuple[str, str]:
     """``("pallas" | "xla", why)`` for one rule over ``T`` positions of ``H``
-    heads: the kernels where they were measured (a TPU, bf16 operands, the
-    key and value widths of :data:`_WIDTHS`, chunks of 64), the einsum form
-    everywhere else."""
+    (value) heads whose q and k have ``key_heads`` (None: as many): the
+    kernels where they were measured (a TPU, bf16 operands, the key and value
+    widths of :data:`_WIDTHS`, fewer key heads than value heads only at
+    128 / 128, chunks of 64), the einsum form everywhere else."""
     if tpu is None:
         tpu = _on_tpu()
     if not tpu:
         return "xla", "not a TPU backend"
     if jnp.dtype(dtype) != jnp.bfloat16:
         return "xla", f"{jnp.dtype(dtype).name} operands (the kernels: bf16)"
-    why = _shapes_taken(dk, dv)
+    why = _shapes_taken(dk, dv, H, key_heads or H)
     return ("xla", why) if why else ("pallas", "")
 
 
 def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
                        g: jax.Array, beta: jax.Array, unit=None,
                        interpret: Optional[bool] = None) -> jax.Array:
-    """q, k [B, T, H, dk] (``q`` scaled, both as the rule reads them), v
+    """q, k [B, T, Hk, dk] (``q`` scaled, both as the rule reads them), v
     [B, T, H, dv], g [B, T, H] (the decay's logarithm, <= 0) and beta
-    [B, T, H] -> o [B, T, H, dv] in ``v``'s dtype. A ``T`` that is not a
+    [B, T, H] -> o [B, T, H, dv] in ``v``'s dtype. ``Hk`` divides ``H``:
+    value head ``i`` reads key head ``i // (H / Hk)`` (the kernels read a
+    key head's q and k once for its value heads; the einsum form repeats
+    them). A ``T`` that is not a
     multiple of :data:`CHUNK` is padded with positions of ``k = 0``, ``g = 0``
     (the state passes through them unchanged) and their outputs dropped.
     ``unit`` (q's length, eps): q and k arrive as the convolutions left them
@@ -823,21 +884,30 @@ def chunked_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array,
     heads costs XLA a copy of the array each way. ``interpret`` is the
     kernels' test handle (None: ask :func:`rule_lowering`; True: the
     kernels, interpreted, in any float dtype, for shapes they take)."""
-    _, T, H, dk = q.shape
-    dv = v.shape[-1]
+    B, T, Hk, dk = q.shape
+    H, dv = v.shape[-2:]
+    if H % Hk or k.shape != q.shape:
+        raise ValueError(f"q {q.shape} and k {k.shape} for v {v.shape}: as "
+                         f"many key heads each, a divisor of the value heads")
     if interpret is None:
-        lowering, _ = rule_lowering(T, H, dk, dv, v.dtype)
+        lowering, _ = rule_lowering(T, H, dk, dv, v.dtype, key_heads=Hk)
     else:
-        why = _shapes_taken(dk, dv)
+        why = _shapes_taken(dk, dv, H, Hk)
         if why:
             raise ValueError(f"the rule's kernels do not take {why}")
         lowering = "pallas"
     # a rule by the lowering it took (``ops/ssd_scan.py`` counts its scans
     # the same way)
     lowerings.count("delta_scan", lowering)
+    # the rows of q and k the rule reads: a key head's once in the kernels,
+    # once a value head in the einsum form, which repeats them
+    lowerings.count("delta_qk_rows", lowering,
+                    2 * B * T * (Hk if lowering == "pallas" else H))
     if lowering == "pallas":
         return _rule_pallas(q, k, v, g, beta, unit, bool(interpret))
     if unit is not None:
         q = unit_heads(q, unit[0], unit[1], v.dtype)
         k = unit_heads(k, 1.0, unit[1], v.dtype)
+    if Hk != H:
+        q, k = (jnp.repeat(a, H // Hk, axis=2) for a in (q, k))
     return rule_einsum(q, k, v, g, beta)

@@ -1291,6 +1291,7 @@ def _bwd_pallas(q, k, v, out, lse, do, *, scale, causal, window, block_q,
                                         q.dtype.itemsize, dv_w, dr, own)
             else "split")
     lowerings.count("flash_bwd", took)     # by the kernel it took
+    lowerings.note("flash_bwd_arm", took, by=d)     # and by the head's width
     call = _bwd_fused_call if took == "fused" else _bwd_split_call
     # the fused kernel reads rows, the split pair columns; the forward's lse
     # is rows wherever the fused kernel runs (``_rows_legal``), so this reshape

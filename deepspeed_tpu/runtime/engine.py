@@ -1224,6 +1224,17 @@ class DeepSpeedTpuEngine:
                     f"input_ids, the loss's parts in the step record); only "
                     f"fused_train_step's plain step program "
                     f"(ds_train_step) has")
+            cfg = getattr(self.module, "cfg", None)
+            if getattr(cfg, "has_delta", False) and cfg.num_experts > 1:
+                raise NotImplementedError(
+                    f"the fused offload, 1-bit and ZeRO++ step programs "
+                    f"(ds_train_step_offload, _onebit, _zpp) have not been "
+                    f"run with gated delta-rule layers beside routed experts "
+                    f"(attn_pattern={cfg.attn_pattern}, num_experts="
+                    f"{cfg.num_experts}: a recurrent mixer's outputs and "
+                    f"the router's counts in one step record); only "
+                    f"fused_train_step's plain step program (ds_train_step) "
+                    f"has")
         if self._offload is not None:
             return self._guarded_loss(self._fused_offload_step(batch, ga))
         if self._onebit is not None:

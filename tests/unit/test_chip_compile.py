@@ -363,8 +363,10 @@ CASES = {
         _delta, dict(grad=True, einsum=True), False),
     "delta-rule-grad-T1000-einsum": (
         _delta, dict(grad=True, H=4, T=1000, einsum=True), False),
-    "delta-rule-grad-k128-refused": (
-        _delta, dict(grad=True, H=4, dk=128, dv=128, T=1024), False),
+    # (keys and values of 128 take the kernels since PR 66: the Qwen3-Next
+    # cell's whole step below; a width off the list still takes the einsums)
+    "delta-rule-grad-k48-refused": (
+        _delta, dict(grad=True, H=4, dk=48, dv=96, T=1024), False),
     # the mixers' convolution with its silu at both cells' calls, a T that
     # is no whole sublane tiles (the picker gives the jax.numpy form)
     "conv-silu-fwd-granite-cell": (
@@ -1471,6 +1473,69 @@ def test_the_laguna_cells_step_program_compiles_for_v5e(one_chip,
     assert all("/moe/" in n for n in experts + moves)
     assert "/moe/moe_shared/" in text and "ragged-dot" not in text
     assert "/moe/moe_router/" in text and "/mlp/" in text
+
+
+def test_the_qwen3_next_cells_step_program_compiles_for_v5e(one_chip,
+                                                            monkeypatch):
+    """The whole step at the benchmark cell's size (``benchmarks/configs/
+    qwen3_next_80b_train_d4e32v8.json``: one period at the published widths,
+    three gated delta-rule layers whose 16 key heads serve 32 value heads at
+    128 / 128 and one gated full-attention layer of 256-wide heads, 32 of 512
+    experts beside the gated shared one, one row of 16,384 positions, under
+    the file's policy). It fits beside what a chip reserves; **the delta
+    layers take the rule's Mosaic kernels**, whose q and k operands are the
+    ``[1, T, 16 x 128]`` arrays the convolutions wrote (float32, handed over
+    by the convolution kernels with no copy between), read once a key head,
+    and no ``[1, T, 32, 128]`` array stands under ``delta_scan`` (a repeat of
+    q or k to the value heads would be one); the full layer runs the flash
+    kernels at d 256, its backward the split pair (a head's dq of 16,384 x
+    256 is past the fused kernel's budget); the gate a channel lies under
+    ``attn_gate``; the router picks 10 of 512 in the selection kernel and the
+    four routed layers run the grouped products and the row kernels under
+    ``moe``."""
+    T = 16384
+    snap = lowerings.snapshot()
+    text, mem = _cell_step_program(
+        one_chip, monkeypatch, "qwen3_next_80b_train_d4e32v8",
+        "modelcfg_qwen3_next", 625_667_136, seq=T)
+    # 4.71 GB of temporaries as compiled here under the file's
+    # "attn_saveable" (4.18 under "full", 7.21 under "dots_saveable", which
+    # does not fit beside 7.51 GB of arguments and the carried copy)
+    assert mem.temp_size_in_bytes < 4.8e9
+    counted = lowerings.since(snap)
+    assert counted["delta_scan"] == {"pallas": 6}     # three rules, and back
+    assert counted["delta_qk_rows"] == {"pallas": 2 * T * 16 * 3}
+    assert counted["flash_bwd"] == {"split": 1}
+    assert counted["flash_bwd_arm"] == {256: "split"}
+    assert counted["moe_topk"] == {"pallas": 4}
+    assert set(counted["conv"]) == set(counted["moe_grouped"]) == {"pallas"}
+    rules = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "/delta_scan/" in line]
+    fwd = [r for r in rules if "jit(rule_fwd)" in r]
+    bwd = [r for r in rules if "jit(rule_bwd)" in r]
+    assert len(bwd) == 3 and len(fwd) in (3, 6)
+    for call in rules:
+        # q and k as the convolutions left them, v a value head's columns
+        assert call.count(f"f32[1,{T},2048]{{2,1,0}}") >= 2, call[:200]
+        assert f"bf16[1,{T},4096]{{2,1,0}}" in call
+    assert all(re.search(r"custom-call\(%conv_fwd[.\d]*, %conv_fwd", r)
+               for r in fwd)
+    for line in text.splitlines():
+        if "/delta_scan/" in line:
+            assert f"[1,{T},32,128]" not in line.split(" = ")[1][:60], line
+    flash = _kernel_calls(text, "attn_full")
+    assert len([n for n in flash if "transpose(" in n]) == 2     # dq; dk, dv
+    assert re.search(rf"bf16\[1,16,{T},256\]", text)
+    gate = [n for n in re.findall(r'op_name="([^"]*)"', text)
+            if "/attn_full/attn_gate/" in n]
+    assert gate and not any(n.endswith("dot_general") for n in gate)
+    experts = _kernel_calls(text, "moe_experts")
+    assert any("jit(gmm)" in n for n in experts)
+    assert any("jit(tgmm)" in n for n in experts)
+    moves = _kernel_calls(text, "moe_dispatch")
+    assert any("jit(rows_of_tokens)" in n for n in moves)
+    assert all("/moe/" in n for n in experts + moves)
+
 
 
 #: temporaries of the SDAR cell's step as compiled here, by policy (bytes,

@@ -217,7 +217,10 @@ PICKS = {
     "keys-of-32-values-of-64": (dict(dk=32, dv=64), "pallas", ""),
     "float32": (dict(dtype=jnp.float32), "xla", "float32"),
     "float16": (dict(dtype=jnp.float16), "xla", "float16"),
-    "keys-of-128": (dict(dk=128, dv=128), "xla", "keys of 128"),
+    "keys-of-128": (dict(dk=128, dv=128), "pallas", ""),
+    "keys-of-48": (dict(dk=48, dv=96), "xla", "keys of 48"),
+    "shared-key-heads-off-whole-tiles": (dict(H=30, key_heads=15), "xla",
+                                         "15 key heads for 30 value heads"),
     "values-of-96": (dict(dv=96), "xla", "values of 96"),
 }
 

@@ -528,10 +528,11 @@ ROUTED = dict(num_experts=8, top_k=2, moe_dispatch="grouped",
     ("kda_lower_bound", dict(kda_lower_bound=0.0), ValueError),
     ("mla_head_gate", dict(attn_pattern=("kda",), kv_lora_rank=None),
      ValueError),
-    # a delta kind beside routed experts stays refused: only KDA was run
+    # a delta kind beside routed experts runs the grouped dispatch since
+    # PR 66 (tests/unit/test_qwen3_next.py); the capacity form stays refused
     ("delta layer beside routed", dict(
         attn_pattern=("delta",), kv_lora_rank=None, mla_head_gate=False,
-        delta_heads=2, **ROUTED), NotImplementedError),
+        delta_heads=2, num_experts=8, top_k=2), NotImplementedError),
     ("group-limited", dict(moe_n_group=3, moe_topk_group=1, **ROUTED),
      ValueError),
     ("group-limited", dict(moe_n_group=4, moe_topk_group=2, num_experts=8,

@@ -540,7 +540,9 @@ def test_every_other_path_refuses_the_model():
 
 
 @pytest.mark.parametrize("bad,says", [
-    (dict(num_experts=4), "beside routed experts"),
+    # (beside routed experts a delta layer runs since PR 66, under the
+    # grouped dispatch; the capacity form stays refused)
+    (dict(num_experts=4), "runs the grouped dispatch"),
     (dict(num_passes=2), "a looped stack"),
     (dict(sandwich_norm=True, norm_placement="pre"), "a looped stack"),
     (dict(parallel_block=True, norm_placement="pre"), "parallel_block"),

@@ -79,6 +79,23 @@ CASES = {
                       "delta_key_dim": 8, "delta_value_dim": 16,
                       "delta_neg_eigval": True,
                       "tie_embeddings": False, "remat_policy": "full"}, 1),
+    # the Qwen3-Next cell's scopes: gated delta-rule layers whose two key
+    # heads serve four value heads and a full layer in turn under
+    # recomputation, zero-centred norms, head norms on q and k, a quarter of
+    # a head under the rope, the gate a channel from wq's second half, a held
+    # share of softmax-routed experts beside a gated shared one: the delta
+    # mixer's parts nest inside attn, the channel gate under
+    # attn/attn_full/attn_gate, the shared expert and its gate under
+    # moe/moe_shared
+    "gdn_moe": ({"num_layers": 2, "attn_pattern": ("delta", "full"),
+                 "num_kv_heads": 2, "qk_norm": "head", "rope_pct": 0.25,
+                 "norm_zero_centred": True, "attn_channel_gate": True,
+                 "delta_heads": 4, "delta_key_heads": 2, "delta_key_dim": 8,
+                 "delta_value_dim": 16, "num_experts": 8, "top_k": 3,
+                 "moe_dispatch": "grouped", "moe_intermediate_size": 32,
+                 "moe_experts_held": 4, "moe_shared_experts": 1,
+                 "moe_shared_gate": True, "tie_embeddings": False,
+                 "remat_policy": "full"}, 1),
     # gated short convolutions and an attention layer whose q and k are
     # normed per head before the rope, under recomputation; a dense FFN then
     # routed ones (a sigmoid router, a held share) under the pattern of
@@ -199,6 +216,9 @@ NESTED_DSA = {"attn_dsa": "attn", "dsa_indexer": "attn_dsa",
               "dsa_select": "attn_dsa", "dsa_attend": "attn_dsa",
               "dsa_loss": "attn_dsa", "moe_router": "moe",
               "moe_dispatch": "moe", "moe_experts": "moe"}
+NESTED_GDN = {**NESTED_DELTA, "attn_gate": "attn_full", "moe_router": "moe",
+              "moe_dispatch": "moe", "moe_experts": "moe",
+              "moe_shared": "moe"}
 NESTED_BD = {"attn_full": "attn", "bd_cross": "attn_full",
              "moe_router": "moe",
              "moe_dispatch": "moe", "moe_experts": "moe"}
@@ -246,7 +266,7 @@ def test_every_operation_carries_a_step_scope(case):
         & set(STEP_SCOPES)
     ffn = "moe" if case in ("moe", "pattern_share", "mla_moe",
                             "conv_moe", "kda_moe", "dsa_moe",
-                            "heads_moe", "bd_moe") else "mlp"
+                            "heads_moe", "bd_moe", "gdn_moe") else "mlp"
     want = {"embed", "layers", "attn", ffn, "final_norm", "loss", "optimizer"}
     if case in ("mla_moe", "conv_moe", "kda_moe", "heads_moe"):
         want.add("mlp")         # the dense layer's
@@ -254,7 +274,7 @@ def test_every_operation_carries_a_step_scope(case):
               "mla_moe": NESTED_MLA, "delta_hybrid": NESTED_DELTA,
               "conv_moe": NESTED_CONV, "kda_moe": NESTED_KDA,
               "dsa_moe": NESTED_DSA, "heads_moe": NESTED_HEADS,
-              "bd_moe": NESTED_BD}.get(case)
+              "bd_moe": NESTED_BD, "gdn_moe": NESTED_GDN}.get(case)
     if nested:
         want |= set(nested)
         for inner, outer in nested.items():
@@ -303,6 +323,22 @@ def test_every_operation_carries_a_step_scope(case):
         row = steplog.programs()[-1]
         assert row.attn_heads_per_step == 2 * 2 + 3 * 3
         assert row.heads_held == {"full": (2, 4), "window": (3, 6)}
+    if case == "gdn_moe":
+        # the gate a channel holds the sigmoid and the product alone (its
+        # columns come out of wq's product, under attn_full), forward,
+        # recomputed and backward; the shared expert's gate lies with it
+        gate = [n for n in names if "attn_gate" in re.split(r"[/()]", n)]
+        assert any("transpose(" in n for n in gate)
+        assert any("rematted_computation" in n for n in gate)
+        assert {n.rsplit("/", 1)[-1] for n in gate} >= {"exp", "mul"}
+        assert not any(n.endswith("dot_general") for n in gate)
+        shared = {n.rsplit("/", 1)[-1] for n in names
+                  if "moe_shared" in re.split(r"[/()]", n)}
+        assert shared >= {"exp", "dot_general"}
+        row = steplog.programs()[-1]
+        assert row.delta_heads == (2, 4)
+        assert row.delta_rule_lowering == {0: "xla"}
+        assert row.delta_qk_rows == {"xla": 2 * 2 * 32 * 4}
     if case == "dsa_moe":
         # every exponential, logarithm and branch of the mixer lies under
         # one of its four scopes: what lies under attn_dsa and outside them
