@@ -33,9 +33,10 @@ POLICIES = (
 #: first; it has no log-sum-exp, and its products are dots
 ATTN_CHECKPOINT_NAME = "flash_attn_out"
 ATTN_LSE_CHECKPOINT_NAME = "flash_attn_lse"
-#: the tags ops/delta_rule.py attaches, in the forward of its kernels'
-#: ``custom_vjp``, to the rule's output and to the chunks' incoming states:
-#: the products a policy that keeps dots keeps of the einsum form
+#: the tags ops/delta_rule.py and ops/kda_rule.py attach, in the forward of
+#: their kernels' ``custom_vjp``, to the rule's output and to the chunks'
+#: incoming states: the products a policy that keeps dots keeps of the einsum
+#: form, and what the backward's region reads of the forward kernel
 RULE_CHECKPOINT_NAMES = ("delta_rule_out", "delta_rule_states")
 #: the tags ops/dsa.py attaches, in the forward of its ``custom_vjp``, to what
 #: its backward reads of the forward beside its inputs (``RESIDUAL_NAMES``
@@ -53,28 +54,30 @@ def resolve_policy(policy: str):
     if policy in (None, "none", "full"):
         return None
     cp = jax.checkpoint_policies
-    # (attention over a selected set names its own residuals: kept with the
-    # flash kernel's, so that the region holds no second selection either)
-    attn = (ATTN_CHECKPOINT_NAME, ATTN_LSE_CHECKPOINT_NAME,
-            *DSA_CHECKPOINT_NAMES)
+    # what the token mixer's kernels named, whatever the mixer: the flash
+    # kernel's output and log-sum-exp, the selected-key op's four residuals,
+    # the delta and KDA rules' output and chunk states. Each is what its
+    # kernel's backward reads of the forward, so the recomputed region holds
+    # no second run of a forward kernel (a layer none of whose ops carries a
+    # name keeps nothing more). What they cost between the forward and the
+    # backward: a flash layer one ``[B, H, T, dv]`` array and one float32 row
+    # set; a rule layer its output and ``T / 64`` float32 ``[H, dk, dv]``
+    # states (168 MB a KDA layer at 8,192 positions of 16 heads, 671 MB a
+    # delta layer at 16,384 of 32, keys and values of 128)
+    named = cp.save_only_these_names(
+        ATTN_CHECKPOINT_NAME, ATTN_LSE_CHECKPOINT_NAME,
+        *DSA_CHECKPOINT_NAMES, *RULE_CHECKPOINT_NAMES)
     if policy == "attn_saveable":
-        # keep what the flash kernel named, its output and its log-sum-exp:
-        # the two values its backward reads, so the recomputed region holds
-        # no second run of the forward kernel (what stands in front of it,
-        # norms, projections and rope, is made again from the layer's input)
-        return cp.save_only_these_names(*attn)
+        # the kernels' names alone: what stands in front of a kernel (norms,
+        # projections, convolutions, rope) is made again from the layer's
+        # input
+        return named
     if policy in ("dots_saveable", "dots_and_attn_saveable"):
-        # a kernel's products are no dots: keep what a kernel named of what
-        # the einsum form's dots would have been (the flash kernel's output
-        # and log-sum-exp, the delta rule's output and chunk states), so that
-        # the recomputed region holds no second forward of either (a program
-        # without those names is unchanged). A flash layer costs one
-        # ``[B, H, T, dv]`` array and one float32 row set. One policy under
-        # two names: keeping dots has to keep the kernels' names to run them
-        # once, and the XLA fallback's named output is a dot's result
-        return cp.save_from_both_policies(
-            cp.dots_saveable,
-            cp.save_only_these_names(*attn, *RULE_CHECKPOINT_NAMES))
+        # a kernel's products are no dots: keeping dots has to keep the
+        # kernels' names as well to run them once (what the einsum forms'
+        # dots would have been), and the XLA fallback's named output is a
+        # dot's result. One policy under two names
+        return cp.save_from_both_policies(cp.dots_saveable, named)
     if policy == "offload_attn":
         # the FPDT/Ulysses-Offload memory tier (sequence/fpdt_layer.py:545):
         # attention outputs live in HOST memory between forward and backward,

@@ -71,12 +71,15 @@ def _operands():
 
 def _loss(form, policy):
     """The sum of squares of an attention block (the projections, the kernel,
-    the output's product, the residual add) under ``policy``; None: no
+    the output's product, the residual add) under ``policy``, a name of the
+    table's or a ``jax.checkpoint_policies`` callable; None: no
     ``jax.checkpoint`` at all."""
     def block(ws, x):
         return x + FORMS[form](ws, x).reshape(B, T, WIDTH) @ ws["o"]
 
-    if policy is not None:
+    if callable(policy):
+        block = jax.checkpoint(block, policy=policy)
+    elif policy is not None:
         block = ac.checkpoint_wrapper(block, policy=policy)
     return lambda ws, x: jnp.sum(block(ws, x) ** 2)
 
@@ -152,6 +155,23 @@ def test_a_policy_that_keeps_nothing_traces_the_same_program(policy):
     assert calls == bare_calls == (1 if policy == "none" else 2, 1)
     assert "name" in named and "name" not in bare
     assert [p for p in named if p != "name"] == bare
+
+
+def test_names_that_nothing_carries_keep_nothing_more():
+    """``attn_saveable`` also keeps the delta and KDA rules' names and the
+    selected-key op's: a block whose one kernel is the flash kernel carries
+    none of them, and its differentiated program is, to the letter, the
+    program under a policy of the flash kernel's two names alone."""
+    ws, x = _operands()
+
+    def text(policy):
+        jaxpr = jax.make_jaxpr(jax.grad(_loss("plain", policy)))(ws, x)
+        return re.sub(r"0x[0-9a-f]+", "", str(jaxpr))
+
+    assert set(ac.RULE_CHECKPOINT_NAMES + ac.DSA_CHECKPOINT_NAMES).isdisjoint(
+        fa.RESIDUAL_NAMES)
+    assert text("attn_saveable") == text(
+        jax.checkpoint_policies.save_only_these_names(*fa.RESIDUAL_NAMES))
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))

@@ -406,14 +406,18 @@ def test_the_scan_kernels_keep_the_scan_scope(monkeypatch):
     assert any("transpose(" not in n for n in fwd)
 
 
-def test_the_rule_kernels_keep_the_scan_scope(monkeypatch):
+@pytest.mark.parametrize("policy", ("dots_saveable", "attn_saveable", "full"))
+def test_the_rule_kernels_keep_the_scan_scope(monkeypatch, policy):
     """The delta case at widths the rule's Pallas kernels take (interpreted
-    here), under the benchmark cell's policy: the forward and the backward
-    kernel lower under ``attn/delta_scan`` with the jitted kernel's name in
-    the path, which is how the benchmark's scope readers find the Mosaic
-    calls on the chip; the policy keeps what the forward rule named (its
-    output and the chunks' states), so the recomputed region holds no second
-    forward; nothing is left without a scope. ``full`` runs it again."""
+    here), under the policies of the two benchmark cells that run the rule
+    (``dots_saveable``: Olmo-Hybrid; ``attn_saveable``: Qwen3-Next): the
+    forward and the backward kernel lower under ``attn/delta_scan`` with the
+    jitted kernel's name in the path, which is how the benchmark's scope
+    readers find the Mosaic calls on the chip; both policies keep what the
+    forward rule named (its output and the chunks' states), so the recomputed
+    region holds no second forward and the row's ``recomputed_kernels`` does
+    not list the scope; nothing is left without a scope. ``full`` runs it
+    again, and the row says so."""
     import functools
 
     from deepspeed_tpu.models import gated_delta
@@ -421,23 +425,22 @@ def test_the_rule_kernels_keep_the_scan_scope(monkeypatch):
     monkeypatch.setattr(gated_delta, "chunked_delta_rule", functools.partial(
         gated_delta.chunked_delta_rule, interpret=True))
     over = dict(CASES["delta_hybrid"][0], **ONE_OF_EACH["delta_hybrid"],
-                delta_key_dim=32, delta_value_dim=64,
-                remat_policy="dots_saveable")
+                delta_key_dim=32, delta_value_dim=64, remat_policy=policy)
     names = _op_names(over, 1)
+    row = steplog.programs()[-1]
     # the one rule and its backward
-    assert steplog.programs()[-1].delta_scan_lowerings == {"pallas": 2}
+    assert row.delta_scan_lowerings == {"pallas": 2}
     parts = [set(re.split(r"[/()]", n)) for n in names]
     assert all(p & set(STEP_SCOPES) for p in parts)
     fwd = [n for n in names if "/delta_scan/jit(rule_fwd)/" in n]
     bwd = [n for n in names if "/delta_scan/jit(rule_bwd)/" in n]
     assert fwd and bwd and all("/attn/" in n for n in fwd + bwd)
     assert all("transpose(" in n for n in bwd)
-    assert not any("transpose(" in n for n in fwd)
-    assert not any("rematted_computation" in n for n in fwd)
-    again = [n for n in _op_names(dict(over, remat_policy="full"), 1)
-             if "/delta_scan/jit(rule_fwd)/" in n]
-    assert any("rematted_computation" in n for n in again)
-    assert any("rematted_computation" not in n for n in again)
+    first = [n for n in fwd if "rematted_computation" not in n]
+    assert first and not any("transpose(" in n for n in first)
+    again = policy == "full"
+    assert (len(first) < len(fwd)) == again
+    assert ("delta_scan" in row.recomputed_kernels()) == again
 
 
 # (case, the mixer's module, the convolution's scope, the scan's, what the
