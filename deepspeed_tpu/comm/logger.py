@@ -5,6 +5,15 @@ Parity target: ``deepspeed/utils/comms_logging.py`` — ``CommsLogger`` (:67) an
 compiler-scheduled, so per-op wall-clock timing is only meaningful eagerly; at trace
 time we record op name + message size + participating axis, which is what the busbw
 accounting needs. ``log_summary()`` mirrors ``dist.log_summary``.
+
+**What is not here.** Only explicit ``comm.*`` calls pass this logger (the ZeRO++,
+1-bit and MoE regions, eager host collectives). ZeRO 1-3's gathers and reductions in
+the fused step (``ds_train_step``) are no calls: ``parallel/sharding.py`` annotates
+and the compiler puts them in. For such a job the counts here, ``log_summary()`` and
+the engine's ``train/comm_ms`` gauge (eager latencies alone) read 0 whatever the step
+exchanges. What the compiled step exchanges is on its step-program row:
+``steplog.programs()[-1].collectives()`` and ``.collective_bytes_per_step``
+(``observability/steplog.py``), read from the compiled text on request.
 """
 
 from __future__ import annotations

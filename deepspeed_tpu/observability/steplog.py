@@ -29,7 +29,13 @@ engine.
   keeps the program's abstract arguments, so ``memory_analysis()`` and the
   compiled HLO text are computed when a reader asks (lowering and compiling
   again: a hit in the persistent compile cache), never at engine build or on
-  a step.
+  a step. Two readings of that text are pure functions of it, so that they
+  run on the CPU against a kept text: :func:`recomputed_kernels` (where the
+  recomputation policy still pays for a kernel twice) and :func:`collectives`
+  (what a sharded step exchanges between chips: ZeRO's gathers and
+  reductions are the compiler's, pass no ``comm.*`` call, and stand nowhere
+  but in that text). The row answers their sums as attributes
+  (``row.collective_bytes_per_step``).
 * :func:`slow_steps` — the arithmetic that says which steps of a record were
   slow and how much of their excess the host or the collector took;
   :func:`host_states` — on the same terms, every period's four phases (put,
@@ -59,7 +65,8 @@ import threading
 import time
 import weakref
 from collections import Counter, deque
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence)
 
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -70,7 +77,8 @@ from deepspeed_tpu._hoststate import (host_state, thread_state,  # noqa: F401
 __all__ = ["StepLog", "StepProgram", "get_steplog", "install_gc_hook",
            "install_build_hook", "span", "setup", "builds", "build_events",
            "record_program", "programs", "slow_steps", "host_states",
-           "host_state", "thread_state", "unavailable", "SLOW_FACTOR"]
+           "host_state", "thread_state", "unavailable", "SLOW_FACTOR",
+           "collectives", "collective_sums", "COLLECTIVE_KINDS"]
 
 #: a step is slow when its period exceeds this many medians
 SLOW_FACTOR = 1.25
@@ -443,12 +451,19 @@ class StepProgram:
         self._mesh = mesh
         self._args = None
         self._compiled = None
+        self._collectives = None
 
     def __getattr__(self, name: str):
         if name.startswith("_") or name in ("facts", "counted"):
             raise AttributeError(name)
         if name in self.facts:
             return self.facts[name]
+        if name.startswith("collective_"):
+            # a sum over :meth:`collectives` (:func:`collective_sums` names
+            # them); 0 where the program exchanges nothing, None only where
+            # the program is gone
+            rows = self.collectives()
+            return None if rows is None else collective_sums(rows).get(name)
         return self.counted.get(name.removesuffix("_lowerings"))
 
     def capture(self, args) -> None:
@@ -510,6 +525,18 @@ class StepProgram:
         text = self.hlo_text()
         return None if text is None else recomputed_kernels(text)
 
+    def collectives(self) -> Optional[List[Dict[str, Any]]]:
+        """:func:`collectives` of the compiled program's text (memoised):
+        one row for each exchange between chips the compiled step holds,
+        ``[]`` on one device. For a reader after the window: it compiles
+        again (:meth:`compiled`), so nothing on a step's path calls it."""
+        if self._collectives is None:
+            text = self.hlo_text()
+            if text is None:
+                return None
+            self._collectives = collectives(text)
+        return self._collectives
+
 
 _MOSAIC_CALL = re.compile(
     r' custom-call\(.*tpu_custom_call.*op_name="([^"]*)"')
@@ -535,6 +562,349 @@ def recomputed_kernels(hlo_text: str) -> Dict[str, int]:
     return dict(Counter(
         [p for p in name.split("/")[:-1] if "(" not in p][-1]
         for name in kernel_calls(hlo_text) if "rematted_computation" in name))
+
+
+# ---- what a sharded step exchanges ------------------------------------------
+
+#: the exchanges between chips an HLO program can hold, by opcode
+COLLECTIVE_KINDS = ("all-gather", "reduce-scatter", "all-reduce",
+                    "all-to-all", "collective-permute")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OPERAND = re.compile(r"(?<![=\w])%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"\b(calls|to_apply|body|condition|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_ARRAY = re.compile(r"\b(pred|[suf]\d+|bf16|f8\w+|c64|c128)\[([\d,]*)\]")
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+_GROUPS_IOTA = re.compile(r"replica_groups=\[\d+,(\d+)\]<=")
+_GROUPS_LIST = re.compile(r"replica_groups=\{\{([\d,]*)\}")
+_PAIRS = re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}")
+_CHANNEL = re.compile(r"channel_id=(\d+)")
+_INDEX = re.compile(r"index=(\d+)")
+_CONSTANT = re.compile(r" constant\((-?\d+)\)")
+_BITS = {"pred": 8, "c64": 64, "c128": 128, "bf16": 16}
+
+
+def _type_and_opcode(rest: str):
+    """``"(f32[4]{0}, u32[]) all-gather-start(%x), ..."`` -> (the result's
+    type, the opcode, what follows the opcode's bracket)."""
+    end = 0
+    if rest.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if not depth:
+                break
+    space = rest.find(" ", end)
+    bracket = rest.find("(", space)
+    return rest[:space], rest[space + 1:bracket], rest[bracket:]
+
+
+def _bytes(type_text: str) -> int:
+    """Bytes of every array a type names; a tuple's are summed."""
+    total = 0
+    for dtype, dims in _ARRAY.findall(type_text):
+        bits = _BITS.get(dtype) or (8 if dtype.startswith("f8")
+                                    else int(dtype[1:]))
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += -(-n * bits // 8)
+    return total
+
+
+def _tuple_parts(type_text: str) -> List[str]:
+    """The elements of a tuple type, one level down."""
+    parts, depth, start = [], 0, 1
+    for i, ch in enumerate(type_text):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if (ch == "," and depth == 1) or (ch == ")" and depth == 0):
+            parts.append(type_text[start:i].strip())
+            start = i + 1
+    return parts
+
+
+class _Instruction(NamedTuple):
+    comp: str           # the computation it stands in
+    type: str           # its result's type, as written
+    opcode: str
+    line: str
+
+
+_NO_INSTRUCTION = _Instruction("", "", "", "")
+
+
+def _path_parts(op_name: str) -> List[str]:
+    """The scopes and transforms a JAX ``op_name`` path names, outermost
+    first (``jit(f)/transpose(jvp(layers))/while/body/attn/dot``)."""
+    return re.split(r"[/()]", op_name)
+
+
+class _Module:
+    """A compiled module's text, parsed once: every instruction with its
+    computation, type, opcode and operands, and who calls which computation."""
+
+    def __init__(self, hlo_text: str):
+        self.at: Dict[str, _Instruction] = {}
+        self._runs: Dict[str, tuple] = {}
+        self.members: Dict[str, List[str]] = {}
+        self.operands: Dict[str, List[str]] = {}
+        self.op_name: Dict[str, str] = {}
+        self.entry = comp = ""
+        for line in hlo_text.splitlines():
+            m = _COMPUTATION.match(line)
+            if m:
+                comp = m.group(1)
+                if line.startswith("ENTRY"):
+                    self.entry = comp
+                continue
+            m = _INSTRUCTION.match(line)
+            if not m:
+                continue
+            name = m.group(1)
+            type_text, opcode, tail = _type_and_opcode(line[m.end():])
+            self.at[name] = _Instruction(comp, type_text, opcode, line)
+            self.members.setdefault(comp, []).append(name)
+            self.operands[name] = _OPERAND.findall(tail)
+            meta = _OP_NAME.search(tail)
+            if meta:
+                self.op_name[name] = meta.group(1)
+        #: computation -> [(calling instruction, how it is called)]
+        self.callers: Dict[str, List[tuple]] = {}
+        self.users: Dict[str, List[str]] = {}
+        for name, at in self.at.items():
+            for o in self.operands[name]:
+                self.users.setdefault(o, []).append(name)
+            for how, called in _CALLED.findall(at.line):
+                self.callers.setdefault(called, []).append((name, how))
+            for group in _BRANCHES.findall(at.line):
+                for called in _OPERAND.findall(group):
+                    self.callers.setdefault(called, []).append(
+                        (name, "branch"))
+
+    def trips(self, loop: str) -> Optional[int]:
+        """How often a ``while`` runs its body: what the compiler wrote
+        (``known_trip_count``), else what a counted loop's own text says (a
+        condition ``i < N`` on a tuple element that starts at a constant 0
+        and that the body adds a constant 1 to: every ``lax.scan`` and
+        ``fori_loop``; the v5e compiler writes no trip count), else None."""
+        line = self.at[loop].line
+        known = _TRIPS.search(line)
+        if known:
+            return int(known.group(1))
+        called = dict(_CALLED.findall(line))
+        cond, body = called.get("condition"), called.get("body")
+        root = next((self.at[n] for n in self.members.get(cond, [])
+                     if self.at[n].line.lstrip().startswith("ROOT")), None)
+        if root is None or root.opcode != "compare" \
+                or "direction=LT" not in root.line:
+            return None
+        index = bound = None
+        for o in _OPERAND.findall(root.line.partition(" compare(")[2]):
+            if self._tuple_index(o) is not None:
+                index = self._tuple_index(o)
+            elif self._constant(o) is not None:
+                bound = self._constant(o)
+        if index is None or bound is None:
+            return None
+        # the counter starts at 0 ...
+        start = self.operands[loop][:1]
+        if not start or self.at.get(start[0], _NO_INSTRUCTION).opcode \
+                != "tuple" or len(self.operands[start[0]]) <= index or \
+                self._constant(self.operands[start[0]][index]) != 0:
+            return None
+        # ... and the body adds 1 to it
+        for n in self.members.get(body, []):
+            if self.at[n].opcode != "add" or len(self.operands[n]) != 2:
+                continue
+            a, b = self.operands[n]
+            for counter, step in ((a, b), (b, a)):
+                if self._tuple_index(counter) == index \
+                        and self._constant(step) == 1:
+                    return bound
+        return None
+
+    def _tuple_index(self, name: str) -> Optional[int]:
+        """Which element of a tuple a ``get-tuple-element`` takes."""
+        at = self.at.get(name, _NO_INSTRUCTION)
+        return int(_INDEX.search(at.line).group(1)) \
+            if at.opcode == "get-tuple-element" else None
+
+    def _constant(self, name: str) -> Optional[int]:
+        """The integer an instruction is a constant of, through copies."""
+        at = self.at.get(name, _NO_INSTRUCTION)
+        while at.opcode in ("copy", "bitcast") and self.operands[name]:
+            name = self.operands[name][0]
+            at = self.at.get(name, _NO_INSTRUCTION)
+        hit = _CONSTANT.search(at.line) if at.opcode == "constant" else None
+        return int(hit.group(1)) if hit else None
+
+    def runs(self, comp: str, seen=()) -> tuple:
+        """(how often a step runs the computation: the trip counts of every
+        ``while`` whose body holds it, multiplied; how many of those loops
+        there are; how many of them gave no trip count and count once;
+        whether one of them is the layer loop)."""
+        if comp == self.entry or comp in seen:
+            return 1, 0, 0, False
+        if comp in self._runs:
+            return self._runs[comp]
+        total = loops = unknown = 0
+        in_layers = False
+        for caller, how in self.callers.get(comp, []):
+            if how in ("condition", "to_apply"):
+                continue
+            n, depth, u, inside = self.runs(self.at[caller].comp,
+                                            seen + (comp,))
+            if how == "body":
+                trips = self.trips(caller)
+                n, depth, u = n * (trips or 1), depth + 1, u + (trips is None)
+                inside = inside or "layers" in _path_parts(
+                    self.op_name.get(caller, ""))
+            total, loops = total + n, max(loops, depth)
+            unknown, in_layers = unknown + u, in_layers or inside
+        out = (total or 1, loops, unknown, in_layers)
+        if not seen:
+            self._runs[comp] = out
+        return out
+
+    def scope(self, name: str, scopes: Sequence[str]) -> Optional[str]:
+        """The innermost of ``scopes`` that the instruction's ``op_name``
+        names; for one the compiler made and gave no path (the gather of a
+        sharded argument, an asynchronous half), the scope of the nearest
+        user that has one, else of the nearest operand, the fusion that
+        holds it standing in for it where its own computation has none."""
+        def own(n):
+            named = [p for p in _path_parts(self.op_name.get(n, ""))
+                     if p in scopes]
+            return named[-1] if named else None
+
+        while name:
+            for edges in (self.users, self.operands):
+                seen, frontier = {name}, [name]
+                while frontier:
+                    found = next(filter(None, map(own, frontier)), None)
+                    if found:
+                        return found
+                    frontier = [o for n in frontier
+                                for o in edges.get(n, [])
+                                if o not in seen and not seen.add(o)]
+            holders = [c for c, how in self.callers.get(
+                self.at[name].comp, []) if how == "calls"]
+            name = holders[0] if holders else ""
+        return None
+
+
+def collectives(hlo_text: str) -> List[Dict[str, Any]]:
+    """What one run of a compiled program exchanges between chips, from its
+    text alone: a row for every ``all-gather``, ``reduce-scatter``,
+    ``all-reduce``, ``all-to-all`` and ``collective-permute`` of it, those
+    inside a fusion or a called computation too, in the text's order. An
+    asynchronous pair is one row, at its ``-start``; so is a gather the v5e
+    compiler spreads over a chain of fusions (``async-collective-start``,
+    compute fusions that each hold one more ``all-gather`` of the same
+    ``channel_id``, ``async-collective-done``), at the member a step runs
+    least often. A program on one device: ``[]``.
+
+    A row: ``name``; ``kind``; ``async`` (a ``-start`` / ``-done`` pair or
+    such a chain, which compute can hide, and not one blocking operation);
+    ``bytes`` a device receives in one run of it (of a group of ``g``: a
+    gather's result less its own shard, ``(g - 1) / g`` of it; a
+    reduce-scatter's operand likewise, ``g - 1`` results; an all-reduce's
+    result ``2 (g - 1) / g`` times, as a ring moves it; an all-to-all's
+    ``(g - 1) / g``; a permute's operand whole; a tuple's arrays summed);
+    ``group`` (the replica group's size; a permute's pairs); ``scope`` (the
+    innermost of the step's ``models/transformer.py:STEP_SCOPES`` in its
+    ``op_name``, or lent: :meth:`_Module.scope`; None where nothing names
+    one); ``backward``
+    (the path holds ``transpose(``); ``loops``, how many ``while`` bodies it
+    stands inside, and ``trips``, how often a step runs it: their trip
+    counts multiplied (:meth:`_Module.trips`), 1 outside every loop;
+    ``unknown_trips``, how many of those loops gave no count and were taken
+    as one; ``in_layer_loop`` (one of them names the ``layers`` scope: a
+    gather there runs once a layer, one outside it once a step)."""
+    from deepspeed_tpu.models.transformer import STEP_SCOPES
+    m = _Module(hlo_text)
+    rows: Dict[Any, Dict[str, Any]] = {}
+    for name, at in m.at.items():
+        kind = at.opcode.removesuffix("-start")
+        if kind not in COLLECTIVE_KINDS:
+            continue
+        trips, loops, unknown, in_layers = m.runs(at.comp)
+        group = _group_size(at.line)
+        channel = _CHANNEL.search(at.line)
+        chained = "async_collective_fusion_config" in at.line
+        row = {"name": name, "kind": kind,
+               "async": at.opcode.endswith("-start") or chained,
+               "bytes": _received_bytes(m, name, kind, group),
+               "group": group, "scope": m.scope(name, STEP_SCOPES),
+               "backward": "transpose(" in m.op_name.get(name, ""),
+               "loops": loops, "trips": trips, "unknown_trips": unknown,
+               "in_layer_loop": in_layers}
+        key = (kind, channel.group(1)) if channel and chained else name
+        if key not in rows or trips < rows[key]["trips"]:
+            rows[key] = row
+    return list(rows.values())
+
+
+def _received_bytes(m: _Module, name: str, kind: str, group: int) -> int:
+    """The bytes a device receives in one run of a collective instruction."""
+    at = m.at[name]
+    result = at.type
+    if at.opcode.endswith("-start") and at.type.startswith("("):
+        # (operands, results[, contexts]); a reduction's start has its
+        # result's type
+        parts = _tuple_parts(at.type)
+        result = at.type if kind == "all-reduce" else parts[1]
+    size, g = _bytes(result), max(group, 1)
+    if kind == "collective-permute":
+        return size
+    if kind == "reduce-scatter":
+        return size * (g - 1)
+    if kind == "all-reduce":
+        return 2 * size * (g - 1) // g
+    return size * (g - 1) // g
+
+
+def _group_size(line: str) -> int:
+    """A replica group's size (either spelling), a permute's pairs, 0 where
+    the instruction names neither."""
+    pairs = _PAIRS.search(line)
+    if pairs:
+        return pairs.group(1).count("{")
+    iota = _GROUPS_IOTA.search(line)
+    if iota:
+        return int(iota.group(1))
+    listed = _GROUPS_LIST.search(line)
+    return listed.group(1).count(",") + 1 if listed else 0
+
+
+def collective_sums(rows: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
+    """:func:`collectives`' rows summed a step (``trips`` applied), under the
+    names a :class:`StepProgram` answers as attributes: calls and bytes in
+    all, by kind, and inside or outside the layer loop. No row: zeros."""
+    out: Dict[str, Any] = {
+        "collective_calls_per_step": 0, "collective_bytes_per_step": 0,
+        "collective_calls_by_kind": {}, "collective_bytes_by_kind": {},
+        "collective_calls_in_layer_loop": 0,
+        "collective_bytes_in_layer_loop": 0,
+        "collective_calls_outside_layer_loop": 0,
+        "collective_bytes_outside_layer_loop": 0,
+        "collective_unknown_trips": 0}
+    for r in rows:
+        where = "in" if r["in_layer_loop"] else "outside"
+        for what, n in (("calls", r["trips"]),
+                        ("bytes", r["trips"] * r["bytes"])):
+            out[f"collective_{what}_per_step"] += n
+            out[f"collective_{what}_{where}_layer_loop"] += n
+            by = out[f"collective_{what}_by_kind"]
+            by[r["kind"]] = by.get(r["kind"], 0) + n
+        out["collective_unknown_trips"] += bool(r["unknown_trips"])
+    return out
 
 
 _PROGRAMS: deque = deque(maxlen=64)

@@ -9,6 +9,7 @@ composition, and the builder registry keeps the same discovery/compatibility sur
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Dict, List, Optional, Type
 
 import jax
@@ -90,6 +91,18 @@ def _live_axes() -> List[str]:
         if a not in mesh.manual_axes and mesh.shape[a] > 1]
 
 
+def _innermost_scope() -> Optional[str]:
+    """The name of the innermost ``jax.named_scope`` open at the call, None
+    outside every one (JAX keeps the stack; it has no public reader)."""
+    try:
+        from jax._src import source_info_util as info
+        names = [s.name for s in info.current_name_stack().stack
+                 if isinstance(s, info.Scope)]
+    except (ImportError, AttributeError):
+        return None
+    return names[-1] if names else None
+
+
 def mosaic_runs_whole() -> bool:
     """On the TPU with no mesh axis to partition over: a Mosaic call runs as
     it stands (Mosaic kernels cannot be partitioned automatically)."""
@@ -130,11 +143,21 @@ def _flash_on_mesh(q, k, v, q_rope=None, k_rope=None, **kw):
     parts = {"v": (v, spec), "q_rope": (q_rope, spec),
              "k_rope": (k_rope, P(batch or None, None, None, None))}
     given = {n: x for n, (x, _) in parts.items() if x is not None}
+    # the compiled kernel takes the innermost scope's name, and inside the
+    # ``shard_map`` that would be the map's own (``%shard_map.n`` on a mesh
+    # where one chip's is ``%attn.n`` or ``%attn_full.n``): the caller's
+    # innermost scope is opened again in there, whatever the model named it
+    scope = _innermost_scope()
+
+    def per_shard(a, b, *rest):
+        with (jax.named_scope(scope) if scope
+              else contextlib.nullcontext()):
+            return flash_attention(a, b, **dict(zip(given, rest)), **kw)
+
     # every remaining axis goes manual (unnamed ones replicate): Mosaic
     # refuses a region that is only partly manual
     return jax.shard_map(
-        lambda a, b, *rest: flash_attention(
-            a, b, **dict(zip(given, rest)), **kw), mesh=mesh,
+        per_shard, mesh=mesh,
         in_specs=(spec, spec) + tuple(parts[n][1] for n in given),
         out_specs=spec,
         axis_names=set(mesh.axis_names) - set(mesh.manual_axes),

@@ -1116,7 +1116,13 @@ class DeepSpeedTpuEngine:
         what the device trace's module line says ran (``jit_ds_train_step``)."""
         self._fused_step_cache[key] = jitted
         self._uncaptured[key] = steplog.record_program(
-            jitted.__name__, key, jitted, self.mesh, **self._program_facts())
+            jitted.__name__, key, jitted, self.mesh,
+            # how the state is partitioned: what the row's collectives (the
+            # compiler's, read from the compiled text on request) are for
+            zero_stage=self.zero_stage,
+            mesh_axes={a: n for a, n in self.topology.axis_sizes.items()
+                       if n > 1},
+            **self._program_facts())
 
     def _program_facts(self, batch_shape=None) -> Dict[str, Any]:
         """What the model says of a step program of its own (over a batch of
@@ -1540,7 +1546,9 @@ class DeepSpeedTpuEngine:
             "bwd_ms": g("train/bwd_ms", "grad fold (breakdown mode)"),
             "optimizer_ms": g("train/optimizer_ms", "optimizer apply"),
             "comm_ms": g("train/comm_ms",
-                         "eager host-collective time this step"),
+                         "eager comm.* call time this step; a fused step's "
+                         "exchanges are the compiler's and read 0 here "
+                         "(steplog: StepProgram.collectives)"),
             "checkpoint_ms": g("train/checkpoint_ms",
                                "last checkpoint save wall clock"),
             "loss": g("train/loss", "last reported loss"),

@@ -1665,3 +1665,152 @@ def test_the_sdar_cells_step_program_compiles_for_v5e(one_chip, monkeypatch,
     moves = _kernel_calls(text, "moe_dispatch")
     assert any("jit(rows_of_tokens)" in n for n in moves)
     assert all("/moe/" in n for n in experts + moves)
+
+
+def _sharded_step_text(monkeypatch, mesh_axes, stage, cfg_kw, rows, seq):
+    """The engine's own ``ds_train_step`` over a described v5e 2x2, compiled
+    and never run: the engine's state and batch are stubbed with
+    ``ShapeDtypeStruct``s of its own shardings and the dispatch hands the
+    jitted program back (``.claude/skills/verify/SKILL.md``, PR 53)."""
+    import time
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    import deepspeed_tpu as ds
+    import deepspeed_tpu.ops as ops
+    from deepspeed_tpu.models import TransformerConfig, TransformerLM
+    from deepspeed_tpu.ops import flash_attention as fa
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel import sharding as shd
+    from deepspeed_tpu.runtime.engine import DeepSpeedTpuEngine
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+    def described(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, shardings)
+
+    def init_state(self, config, init_rng, schedule_fn):
+        self._offload = None
+        self._params = described(self._param_shapes, self.param_sharding)
+        self._work = described(
+            jax.eval_shape(self._copy_of, self._param_shapes),
+            self.work_sharding)
+        self.opt_state = described(
+            jax.eval_shape(self.tx.init, self._param_shapes),
+            self.opt_sharding)
+        rep = NamedSharding(self.mesh, P())
+        self.scaler_state = {
+            "scale": jax.ShapeDtypeStruct((), jnp.float32, sharding=rep),
+            "good_steps": jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)}
+        self._grad_acc = self._pending = None
+        self._grad_acc_count = 0
+
+    def put_batch(self, batch):
+        spec = shd.batch_spec(self.topology)
+        self._t_put = time.perf_counter()
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(
+                    self.mesh, P(*list(spec)[:x.ndim]))), batch)
+
+    class Captured(Exception):
+        pass
+
+    got = {}
+
+    def dispatch(self, key, *args):
+        got["fn"], got["args"] = self._fused_step_cache[key], args
+        raise Captured()
+
+    monkeypatch.setattr(DeepSpeedTpuEngine, "_init_state", init_state)
+    monkeypatch.setattr(DeepSpeedTpuEngine, "_put_batch", put_batch)
+    monkeypatch.setattr(DeepSpeedTpuEngine, "_dispatch_fused", dispatch)
+    engine, *_ = ds.initialize(
+        model=TransformerLM(TransformerConfig(**cfg_kw)),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
+                "bf16": {"enabled": True},
+                "zero_optimization": {"stage": stage},
+                "steps_per_print": 10 ** 9},
+        mesh=build_mesh(axis_sizes=mesh_axes, devices=list(topo.devices)))
+    with pytest.raises(Captured):
+        engine.fused_train_step(
+            {"input_ids": np.zeros((rows, seq), np.int32)})
+    with jax.sharding.set_mesh(engine.mesh):
+        return got["fn"].lower(*got["args"]).compile().as_text()
+
+
+def test_the_zero3_steps_collectives_as_the_v5e_compiler_writes_them(
+        one_chip, monkeypatch):
+    """ZeRO-3 over ``fsdp=4`` of a described v5e 2x2, the engine's own step
+    at narrow widths. What the four-chip cell's readers rest on
+    (``benchmarks/readers/COLLECTIVES.md``): the compiler writes no trip count
+    and the record reads the layer loop's from its condition; the exchanges
+    in the loops are asynchronous; every one of them has an owner among the
+    step's scopes; and the flash kernels, one a shard inside
+    ``_flash_on_mesh``'s ``shard_map``, keep the name of the model's
+    innermost scope there, ``attn``, which is what the benchmark's roofline
+    readers know them by."""
+    from deepspeed_tpu.models.transformer import STEP_SCOPES
+
+    layers = 3
+    text = _sharded_step_text(
+        monkeypatch, {"fsdp": 4}, 3,
+        dict(vocab_size=2048, hidden_size=512, num_layers=layers,
+             num_heads=4, num_kv_heads=2, intermediate_size=1024,
+             max_seq_len=1024, arch="llama", dtype="bfloat16",
+             param_dtype="float32", attention_impl="auto"),
+        rows=4, seq=1024)
+    assert "known_trip_count" not in text
+    rows = steplog.collectives(text)
+    assert rows and all(r["unknown_trips"] == 0 for r in rows)
+    in_loop = [r for r in rows if r["in_layer_loop"]]
+    assert in_loop and {r["trips"] for r in in_loop} == {layers}
+    assert {r["trips"] for r in rows if not r["in_layer_loop"]} == {1}
+    assert any(r["async"] for r in in_loop)
+    assert all(r["scope"] in STEP_SCOPES for r in rows), \
+        [r["name"] for r in rows if r["scope"] not in STEP_SCOPES]
+    assert {r["scope"] for r in in_loop} <= {"attn", "mlp", "layers"}
+    assert all(r["group"] == 4 for r in rows)
+    sums = steplog.collective_sums(rows)
+    # at least the bf16 weights of the layers thrice over (gathered for the
+    # forward, again for the backward, the gradients reduced), 3/4 of each
+    layer = 2 * 512 * 512 + 2 * 512 * 256 + 3 * 512 * 1024
+    assert sums["collective_bytes_in_layer_loop"] \
+        >= 3 * layers * layer * 2 * 3 // 4
+    assert set(sums["collective_calls_by_kind"]) \
+        <= set(steplog.COLLECTIVE_KINDS)
+    # the kernels under the mesh: named for the scope, forward and backward
+    kernels = re.findall(r"^\s*%([\w.\-]+) = .* custom-call\(.*"
+                         r"tpu_custom_call", text, re.M)
+    assert len(kernels) == 2 and all(
+        re.fullmatch(r"attn[.\d]*", k) for k in kernels), kernels
+
+
+def test_the_flash_kernels_keep_each_kinds_scope_on_a_mesh(one_chip,
+                                                           monkeypatch):
+    """A model whose layers are of two kinds, under the same mesh: the
+    kernels inside ``_flash_on_mesh``'s ``shard_map`` are named for the
+    scope the model had open at the call, ``attn_window`` and ``attn_full``
+    (what the readers of a cell with both tell them apart by), not for one
+    name the ops layer chose."""
+    text = _sharded_step_text(
+        monkeypatch, {"fsdp": 4}, 3,
+        dict(vocab_size=2048, hidden_size=512, num_layers=2, num_heads=4,
+             num_kv_heads=2, intermediate_size=1024, max_seq_len=1024,
+             arch="llama", dtype="bfloat16", param_dtype="float32",
+             attention_impl="auto", sliding_window=256,
+             attn_pattern=("window", "full")),
+        rows=4, seq=1024)
+    kernels = re.findall(r"^\s*%([\w\-]+)[.\d]* = .* custom-call\(.*"
+                         r"tpu_custom_call", text, re.M)
+    assert sorted(kernels) == ["attn_full"] * 2 + ["attn_window"] * 2, kernels
+

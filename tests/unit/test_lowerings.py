@@ -92,8 +92,8 @@ def test_a_dense_step_loads_no_mixer_or_expert_kernel():
         # tied table is cast in the step, at the gather and at the head)
         "assert row.first_call_s is not None and row.counted == {"
         "'weight_cast': {'carried': 9, 'in_step': 2}}, row.counted\n"
-        "assert row.facts == {'layer_applications': 2,"
-        " 'layer_pattern': ('full',),"
+        "assert row.facts == {'zero_stage': 0, 'mesh_axes': {},"
+        " 'layer_applications': 2, 'layer_pattern': ('full',),"
         " 'working_copy_bytes': 2 * (2 * 16 * 64 * 64 + 4 * 64)}, row.facts\n"
         "print([k for k in sys.modules if k.rpartition('.')[2] in ("
         "'ssd_scan', 'delta_rule', 'causal_conv', 'grouped_matmul',"
