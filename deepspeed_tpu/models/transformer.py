@@ -2390,7 +2390,8 @@ class TransformerLM:
         elif plain and cfg.head_dim > 128:
             # and where a plain head is wider than the kernels' usual 128
             # (the row's ``flash_bwd_arm`` says which backward such a head
-            # took, fused or split, by width)
+            # took, fused or split, by width, and ``flash_bwd_segments`` in
+            # how many q-segments the fused one worked it)
             facts["attn_widths"] = (cfg.head_dim, cfg.head_dim)
         if cfg.num_experts > 1:
             if cfg.moe_scoring != "softmax":

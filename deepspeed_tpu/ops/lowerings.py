@@ -16,7 +16,10 @@ by the mask (the window's length, ``"causal"``, ``"none"``, or
 ``"diag<n>"`` / ``"diag<n>_strict"``; ``"diag<n>_own"``: the own tiles of a
 call with a second key source, which ``flash_own_keys`` counts as
 ``"operand"``), ``flash_diag_fwd_tiles`` by the rounded diagonal's label
-alone, ``flash_fwd_tiles`` is the newest forward's whatever its mask. A mask the kernels do not take leaves no record here: a call with
+alone, ``flash_fwd_tiles`` is the newest forward's whatever its mask;
+``flash_bwd_arm`` and ``flash_bwd_segments`` are keyed by the head's width:
+which backward the newest call at that width took, and in how many segments
+of q-tiles the fused one worked a head (1: whole). A mask the kernels do not take leaves no record here: a call with
 ``segment_ids`` is ``xla_attention``'s (dense), and with ``diag`` besides it is
 refused by name.
 """

@@ -409,17 +409,22 @@ def test_compiles_for_v5e(one_chip, name):
 # The flash backward at the two training cells' shapes (BENCHMARK.json:
 # 1 x 4096 tokens; Mistral 32 query heads over 8 KV heads with window 4096,
 # Ouro 16 over 16), where a head's dq fits the fused kernel's VMEM budget, and
-# at a length over it, where the two split kernels run under Mosaic's default
-# limit: (T, H, K, window, which kernel the shape takes)
+# at lengths over it, where the same call works the head in segments of
+# q-tiles that fit (that it compiles under the budget as its
+# ``vmem_limit_bytes`` is the proof of ``_fused_bwd_vmem_bytes``): (T, H, K,
+# window, which kernel the shape takes, its segments[, rows, head width])
 FLASH_BWD = {
-    "mistral-cell": (4096, 32, 8, 4096, "fused"),
-    "ouro-cell": (4096, 16, 16, None, "fused"),
-    "16k-largest-fused": (16384, 4, 4, None, "fused"),
-    "32k-over-budget": (32768, 4, 4, None, "split"),
+    "mistral-cell": (4096, 32, 8, 4096, "fused", 1),
+    "ouro-cell": (4096, 16, 16, None, "fused", 1),
+    "16k-largest-fused": (16384, 4, 4, None, "fused", 1),
+    "32k-over-budget": (32768, 4, 4, None, "fused", 2),
     # the window layers of the Laguna cell (one row, 36 heads held over 4)
     # and of the Mellum2 cell (two rows)
-    "laguna-window-cell": (8192, 36, 4, 512, "fused"),
-    "mellum2-window-cell": (8192, 32, 4, 1024, "fused", 2),
+    "laguna-window-cell": (8192, 36, 4, 512, "fused", 1),
+    "mellum2-window-cell": (8192, 32, 4, 1024, "fused", 1, 2),
+    # the full layer of the Qwen3-Next cell: one row of 16,384 at d 256,
+    # 16 query heads over 2
+    "qwen3-next-cell": (16384, 16, 2, None, "fused", 2, 1, 256),
 }
 # ``flash_bwd_tiles`` a head at the cells' shapes: the tiles by arm and, of
 # the masked tiles' 512 x 512 sub-blocks, those worked, skipped and unmasked
@@ -476,19 +481,20 @@ def _mla_roofline_patterns():
 # of 128 + 64 rope dimensions over values of 128), forward and backward, at the
 # kanana-2 cell's shape (BENCHMARK.json: 2 x 8192 tokens, 32 heads), where the
 # fused backward fits its budget with dk and dv as a result each, and at a
-# length over it: (rows, T, heads, which backward the shape takes)
+# length over it, which it works in segments: (rows, T, heads, which backward
+# the shape takes, its segments)
 FLASH_TWO_WIDTHS = {
-    "kanana2-cell": (2, 8192, 32, "fused"),
-    "32k-over-budget": (1, 32768, 2, "split"),
+    "kanana2-cell": (2, 8192, 32, "fused", 1),
+    "32k-over-budget": (1, 32768, 2, "fused", 4),
 }
 
 # The same shapes with q and k in the parts the products write, the rope
 # columns and the one rope key as operands of their own: (rows, T, heads,
-# which backward, keys and values in one array)
+# which backward, keys and values in one array, the backward's segments)
 FLASH_IN_PARTS = {
-    "kanana2-cell": (2, 8192, 32, "fused", True),
-    "kanana2-cell-k-and-v-apart": (2, 8192, 32, "fused", False),
-    "32k-over-budget": (1, 32768, 2, "split", True),
+    "kanana2-cell": (2, 8192, 32, "fused", True, 1),
+    "kanana2-cell-k-and-v-apart": (2, 8192, 32, "fused", False, 1),
+    "32k-over-budget": (1, 32768, 2, "fused", True, 4),
 }
 
 
@@ -496,7 +502,7 @@ FLASH_IN_PARTS = {
 def test_flash_at_a_key_and_a_value_width_compiles_for_v5e(one_chip, name):
     from deepspeed_tpu.ops import flash_attention as fa
 
-    rows, T, heads, took = FLASH_TWO_WIDTHS[name]
+    rows, T, heads, took, segments = FLASH_TWO_WIDTHS[name]
     assert fa._bwd_takes_fused(T, 192, fa.DEFAULT_BLOCK_Q,
                                fa.DEFAULT_BLOCK_K, 2, 128) \
         == (took == "fused")
@@ -512,20 +518,22 @@ def test_flash_at_a_key_and_a_value_width_compiles_for_v5e(one_chip, name):
     before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile().as_text()
-    assert lowerings.since(before)["flash_bwd"] == {took: 1}
+    said = lowerings.since(before)
+    assert said["flash_bwd"] == {took: 1}
+    assert said["flash_bwd_segments"] == {192: segments}
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
              and " custom-call(" in ln]
     # the forward writes values' width; the fused backward dq by q-tile at
     # the keys' width and dk, dv each at its own (three results: what
-    # metrics/flash_bwd_roofline.mla.json looks for)
+    # metrics/flash_bwd_roofline.mla.json looks for), a partial a segment
+    # where the head is worked in segments
     assert any(f"= (bf16[{rows},{heads},{T},128]" in ln and "f32[" in ln
                for ln in calls)
-    if took == "fused":
-        assert any(f"bf16[{rows},{heads},{T // 1024},1024,192]" in ln
-                   and f"bf16[{rows},{heads},{T},192]" in ln
-                   and f"bf16[{rows},{heads},{T},128]" in ln for ln in calls)
-    else:
-        assert len(calls) == 3
+    part = f"{segments}," if segments > 1 else ""
+    assert len(calls) == 2
+    assert any(f"bf16[{rows},{heads},{T // 1024},1024,192]" in ln
+               and f"bf16[{part}{rows},{heads},{T},192]" in ln
+               and f"bf16[{part}{rows},{heads},{T},128]" in ln for ln in calls)
 
 
 @pytest.mark.parametrize("name", sorted(FLASH_IN_PARTS))
@@ -540,7 +548,7 @@ def test_flash_with_the_rope_columns_as_operands_compiles_for_v5e(one_chip,
     share a head)."""
     from deepspeed_tpu.ops import flash_attention as fa
 
-    rows, T, heads, took, kv_whole = FLASH_IN_PARTS[name]
+    rows, T, heads, took, kv_whole, segments = FLASH_IN_PARTS[name]
     assert fa._bwd_takes_fused(T, 128, fa.DEFAULT_BLOCK_Q,
                                fa.DEFAULT_BLOCK_K, 2, 128, 64) \
         == (took == "fused")
@@ -549,6 +557,7 @@ def test_flash_with_the_rope_columns_as_operands_compiles_for_v5e(one_chip,
     text = jax.jit(fn).lower(*args).compile().as_text()
     said = lowerings.since(before)
     assert said["flash_bwd"] == {took: 1}
+    assert said["flash_bwd_segments"] == {128: segments}
     assert list(said["flash_rope_operand"]) == ["operand"]
     calls = [ln.strip() for ln in text.splitlines()
              if "tpu_custom_call" in ln and " custom-call(" in ln]
@@ -559,17 +568,15 @@ def test_flash_with_the_rope_columns_as_operands_compiles_for_v5e(one_chip,
     assert len(fwd) == 1 and len(fwd) + len(bwd) == len(calls)
     assert f"= (bf16[{rows},{heads},{T},128]" in fwd[0] \
         and f"f32[{rows},{heads},1,{T}]" in fwd[0]
-    dkv = (f"bf16[{rows},{heads},{T},256]" if kv_whole
-           else f"bf16[2,{rows},{heads},{T},128]")
-    dk_rope = f"bf16[{rows},{heads},{T},64]"
-    if took == "fused":
-        assert len(bwd) == 1
-        assert f"= (bf16[{rows},{heads},{T // 1024},1024,192]" in bwd[0]
-        assert dkv in bwd[0].split(" custom-call(")[0]
-        assert dk_rope in bwd[0].split(" custom-call(")[0]
-    else:
-        assert len(bwd) == 2
-        assert any(f"= bf16[{rows},{heads},{T},192]" in ln for ln in bwd)
+    # (a partial a segment where the head is worked in segments)
+    part = f"{segments}," if segments > 1 else ""
+    dkv = (f"bf16[{part}{rows},{heads},{T},256]" if kv_whole
+           else f"bf16[2,{part}{rows},{heads},{T},128]")
+    dk_rope = f"bf16[{part}{rows},{heads},{T},64]"
+    assert len(bwd) == 1
+    assert f"= (bf16[{rows},{heads},{T // 1024},1024,192]" in bwd[0]
+    assert dkv in bwd[0].split(" custom-call(")[0]
+    assert dk_rope in bwd[0].split(" custom-call(")[0]
     # nothing 192 wide is written on the way in: the operands are the parts
     operands = [ln.split(" custom-call(")[1] for ln in calls]
     assert not any("192]" in o.split("custom_call_target")[0]
@@ -678,32 +685,38 @@ def test_flash_backward_compiles_for_v5e(one_chip, name):
 
     from deepspeed_tpu.ops import flash_attention as fa
 
-    T, heads, kv_heads, window, took, *rows = FLASH_BWD[name]
-    B = rows[0] if rows else 1
-    assert fa._bwd_takes_fused(T, 128, fa.DEFAULT_BLOCK_Q,
+    T, heads, kv_heads, window, took, segments, *rest = FLASH_BWD[name]
+    B, d = (rest + [1, 128][len(rest):])
+    assert fa._bwd_takes_fused(T, d, fa.DEFAULT_BLOCK_Q,
                                fa.DEFAULT_BLOCK_K, 2) == (took == "fused")
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True, window=window,
                                   interpret=False).astype(jnp.float32).sum()
 
-    q = jax.ShapeDtypeStruct((B, T, heads, 128), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((B, T, heads, d), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((B, T, kv_heads, 128), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((B, T, kv_heads, d), jnp.bfloat16,
                               sharding=one_chip)
     before = lowerings.snapshot()
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     said = lowerings.since(before)
     assert said["flash_bwd"] == {took: 1}
-    # the fused kernel is one Mosaic call with two five-dimensional bf16
-    # results (dq by q-tile; dk and dv stacked): what the benchmark's
-    # metrics/flash_bwd_fused_roofline.json looks for. The split pair's
-    # results have four dimensions
+    assert said["flash_bwd_segments"] == {d: segments}
+    # the fused kernel is one Mosaic call with two bf16 results, both of five
+    # dimensions (dq by q-tile; dk and dv stacked): what the benchmark's
+    # metrics/flash_bwd_fused_roofline.json looks for. In segments it is
+    # still the one call, and the stack has a sixth extent, the segments'
+    # partials: metrics/flash_bwd_roofline.gdn.json takes either. The split
+    # pair's results have four dimensions
     five = r"bf16\[\d+,\d+,\d+,\d+,\d+\]\{[^}]*\}"
-    fused = re.findall(rf"= \({five}, {five}\) custom-call\(.*"
+    stack = (five if segments == 1 else
+             rf"bf16\[2,{segments},\d+,\d+,\d+,\d+\]\{{[^}}]*\}}")
+    fused = re.findall(rf"= \({five}, {stack}\) custom-call\(.*"
                        r"tpu_custom_call", text)
     assert len(fused) == int(took == "fused"), name
+    assert len(re.findall(r" custom-call\(.*tpu_custom_call", text)) == 2
     # a crossed tile in sub-blocks is still that one call, and the counter
     # says how many of them it works
     assert ("flash_bwd_tiles" in said) == (took == "fused")
@@ -1494,8 +1507,9 @@ def test_the_qwen3_next_cells_step_program_compiles_for_v5e(one_chip,
     by the convolution kernels with no copy between), read once a key head,
     and no ``[1, T, 32, 128]`` array stands under ``delta_scan`` (a repeat of
     q or k to the value heads would be one); the full layer runs the flash
-    kernels at d 256, its backward the split pair (a head's dq of 16,384 x
-    256 is past the fused kernel's budget); the gate a channel lies under
+    kernels at d 256, its backward the fused kernel in two segments of
+    8,192 rows (a head's dq of 16,384 x 256 is past its budget, a
+    segment's fits); the gate a channel lies under
     ``attn_gate``; the router picks 10 of 512 in the selection kernel and the
     four routed layers run the grouped products and the row kernels under
     ``moe``."""
@@ -1512,8 +1526,9 @@ def test_the_qwen3_next_cells_step_program_compiles_for_v5e(one_chip,
     counted = lowerings.since(snap)
     assert counted["delta_scan"] == {"pallas": 6}     # three rules, and back
     assert counted["delta_qk_rows"] == {"pallas": 2 * T * 16 * 3}
-    assert counted["flash_bwd"] == {"split": 1}
-    assert counted["flash_bwd_arm"] == {256: "split"}
+    assert counted["flash_bwd"] == {"fused": 1}
+    assert counted["flash_bwd_arm"] == {256: "fused"}
+    assert counted["flash_bwd_segments"] == {256: 2}
     assert counted["moe_topk"] == {"pallas": 4}
     assert set(counted["conv"]) == set(counted["moe_grouped"]) == {"pallas"}
     rules = [line for line in text.splitlines()
@@ -1541,7 +1556,12 @@ def test_the_qwen3_next_cells_step_program_compiles_for_v5e(one_chip,
         if "/delta_scan/" in line:
             assert f"[1,{T},32,128]" not in line.split(" = ")[1][:60], line
     flash = _kernel_calls(text, "attn_full")
-    assert len([n for n in flash if "transpose(" in n]) == 2     # dq; dk, dv
+    # the backward is one call: dq by q-tile, and dk and dv stacked, a
+    # partial a segment (what metrics/flash_bwd_roofline.gdn.json finds)
+    assert len([n for n in flash if "transpose(" in n]) == 1
+    assert re.search(rf"^\s*%attn_full[.\d]* = \(bf16\[1,16,{T // 1024},1024,"
+                     rf"256\]\S*, bf16\[2,2,1,16,{T},256\]\S*\) custom-call\(",
+                     text, re.M)
     assert re.search(rf"bf16\[1,16,{T},256\]", text)
     gate = [n for n in re.findall(r'op_name="([^"]*)"', text)
             if "/attn_full/attn_gate/" in n]

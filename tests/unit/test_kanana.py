@@ -258,6 +258,7 @@ def test_the_fused_backward_budget_counts_both_widths():
     wide = fa._fused_bwd_vmem_bytes(8192, 192, 1024, 1024, 2, 128)
     assert same < wide < fa._fused_bwd_vmem_bytes(8192, 256, 1024, 1024, 2)
     assert fa._bwd_takes_fused(8192, 192, 1024, 1024, 2, 128)
+    assert fa._bwd_segments(8192, 192, 1024, 1024, 2, 128) == 1
 
 
 # ---- q and k in the parts the projections write -----------------------------
@@ -367,7 +368,13 @@ def test_the_fused_backward_budget_counts_the_rope_operands():
     assert parts == whole + (1024 + 1024) * 256 * 2
     assert 38 * MiB < whole < parts < 40 * MiB < fa._FUSED_VMEM_BUDGET
     assert fa._bwd_takes_fused(8192, 128, 1024, 1024, 2, 128, 64)
-    assert not fa._bwd_takes_fused(32768, 128, 1024, 1024, 2, 128, 64)
+    assert fa._bwd_segments(8192, 128, 1024, 1024, 2, 128, 64) == 1
+    # past the budget the head is worked in segments of rows whose dq fits:
+    # at 32,768 four of 8,192 (two of 16,384 would hold 57.8 MB)
+    assert fa._bwd_takes_fused(32768, 128, 1024, 1024, 2, 128, 64)
+    assert fa._bwd_segments(32768, 128, 1024, 1024, 2, 128, 64) == 4
+    assert fa._fused_bwd_vmem_bytes(16384, 128, 1024, 1024, 2, 128, 64) \
+        > fa._FUSED_VMEM_BUDGET
 
 
 def test_the_rope_kernel_is_apply_rope_on_heads_first_rows():

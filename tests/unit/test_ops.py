@@ -101,13 +101,25 @@ class TestFlashAttention:
 # The fused flash backward against the two split kernels and XLA's autodiff.
 # 128-wide tiles over 512 keys: 4 x 4 tile pairs, a [1, 128] row is a legal
 # block, and a head's dq fits the VMEM budget, so the shape takes the fused
-# kernel; "long-split" is over the budget and takes the split ones. T != S
-# puts query row t at position t + S - T (rel_offset, xla_attention's
-# end-aligned mask); "dlse" sends a cotangent into the log-sum-exp output.
-# "sub" stands in for the kernel's 512 (``fa._SUB``), so that a tile a
-# boundary crosses is worked in sub-blocks, of which some are dead, some masked
-# and some wholly inside the band; "tiles" pins the counter where the pattern
-# is a training cell's, elsewhere the dense mask says what to expect.
+# kernel in one segment. T != S puts query row t at position t + S - T
+# (rel_offset, xla_attention's end-aligned mask); "dlse" sends a cotangent
+# into the log-sum-exp output. "sub" stands in for the kernel's 512
+# (``fa._SUB``), so that a tile a boundary crosses is worked in sub-blocks, of
+# which some are dead, some masked and some wholly inside the band; "tiles"
+# pins the counter where the pattern is a training cell's, elsewhere the dense
+# mask says what to expect.
+#
+# "segments": the budget is set to what the fused kernel holds with the dq of
+# that share of the head's rows, so the head is worked in that many segments
+# of q-tiles by the one call (Qwen3-Next's 16,384 rows at d 256 are two of
+# these at the real budget); each segment's dq rows and dk/dv partial are
+# held to the split pair run on the segment's rows alone. "long-split" is
+# past the real budget: two segments (four in float32), and the split pair
+# is still compared through ``_bwd_split_call``; "budget" 1 fits no segment
+# and takes the split pair. "dv": values of another width than keys; "dr": the rope columns of q
+# and the one rope key as operands, "kv_whole" then keys and values side by
+# side in one array; "own": the keys and values at the queries' own positions
+# as a second source, in blocks of that length under ``DIAG_BEFORE``.
 FUSED_BWD_CASES = {
     "causal-gqa4": dict(H=4, K=1, causal=True),
     "full-mha": dict(H=2, K=2, causal=False),
@@ -118,7 +130,9 @@ FUSED_BWD_CASES = {
                                   dlse=True),
     "dlse-mha": dict(H=2, K=2, causal=True, dlse=True),
     "long-split": dict(H=1, K=1, T=65536, S=128, causal=False, block=1024,
-                       took="split"),
+                       segments=2, segments_f32=4, budget=None),
+    "no-segment-fits-split": dict(H=2, K=1, causal=True, budget=1,
+                                  took="split"),
     # the causal cells' diagonal tiles (T 4096 in 1,024-wide tiles with an
     # edge of 512 is these 4 x 4 with one of 64)
     "sub-diagonal-the-cells-pattern-gqa4": dict(
@@ -146,6 +160,36 @@ FUSED_BWD_CASES = {
     "sub-rect-window-rel-offset-gqa4-dlse": dict(
         H=4, K=1, T=256, causal=True, window=300, sub=32, dlse=True),
     "sub-full-mha": dict(H=2, K=2, causal=False, sub=32),
+    # Qwen3-Next's pattern: two segments under the diagonal, the first one's
+    # last two kv-tiles dead for every row of it
+    "seg2-causal-the-cells-pattern-gqa4": dict(
+        H=4, K=1, causal=True, segments=2, sub=64,
+        tiles=dict(masked=4, unmasked=6, dead=6, sub_live=12, sub_dead=4,
+                   sub_inside=4)),
+    "seg4-causal-a-tile-a-segment-gqa2-dlse": dict(H=4, K=2, causal=True,
+                                                   segments=4, dlse=True),
+    "seg2-full-mha": dict(H=2, K=2, causal=False, segments=2),
+    # the second segment's rows keep no key of the first kv-tile, the first
+    # segment's none of the last two: dead kv-tiles lead and trail
+    "seg2-window100-leading-kv-tiles-dead-gqa4": dict(
+        H=4, K=1, causal=True, window=100, segments=2),
+    "seg4-window200-mha-dlse": dict(H=2, K=2, causal=True, window=200,
+                                    segments=4, dlse=True, sub=32),
+    "seg2-rect-rel-offset-gqa2-dlse": dict(H=4, K=2, T=256, causal=True,
+                                           segments=2, dlse=True),
+    "seg2-rect-window-rel-offset-gqa4-dlse": dict(
+        H=4, K=1, T=256, causal=True, window=300, segments=2, dlse=True,
+        sub=32),
+    "two-widths-gqa2": dict(H=4, K=2, causal=True, d=32, dv=16),
+    "seg2-two-widths-gqa2-dlse": dict(H=4, K=2, causal=True, d=32, dv=16,
+                                      segments=2, dlse=True),
+    "seg2-rope-operands-mha": dict(H=2, K=2, causal=True, dr=8, segments=2),
+    "seg4-rope-operands-kv-whole-dlse": dict(
+        H=2, K=2, causal=True, dr=8, kv_whole=True, segments=4, dlse=True),
+    "own-keys-gqa2": dict(H=4, K=2, causal=True, own=4),
+    "seg2-own-keys-gqa2": dict(H=4, K=2, causal=True, own=4, segments=2),
+    "seg4-own-keys-mha-sub": dict(H=2, K=2, causal=True, own=8, segments=4,
+                                  sub=32),
 }
 
 
@@ -163,6 +207,22 @@ def _ref_lse(q, k, causal, window):
         keep &= gap < window
     s = jnp.where(keep, s, -jnp.inf)
     return jax.scipy.special.logsumexp(s, axis=-1)[..., None]
+
+
+def _ref_own(q, k, v, k_own, v_own, n):
+    """One softmax over the keys before a query's block of ``n`` and the own
+    keys of its block, model layout."""
+    (_, T, H, d), K = q.shape, k.shape[2]
+    k, v, k_own, v_own = (jnp.repeat(x, H // K, axis=2)
+                          for x in (k, v, k_own, v_own))
+    qb, kb = jnp.arange(T)[:, None] // n, jnp.arange(T)[None, :] // n
+    s = jnp.concatenate([
+        jnp.where(m, jnp.einsum("bthd,bshd->bhts", q, x) / np.sqrt(d),
+                  -jnp.inf) for m, x in ((kb < qb, k), (kb == qb, k_own))],
+        axis=-1)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p,
+                      jnp.concatenate([v, v_own], axis=1))
 
 
 def _dense_keep(T, S, causal, window):
@@ -183,30 +243,68 @@ def _some_every(keep, bq, bk):
     return per_tile.any((1, 3)), per_tile.all((1, 3))
 
 
+def _held_to(a, b):
+    """Two kernels' bf16 results. Bit for bit on the chip (PERF.md section
+    6, PR 29). The CPU sums a transposed operand's products in another
+    order, which now and then moves the bf16 rounding of one P or dS and
+    with it an element by 2^-8 of one of its terms: all but a few in a
+    thousand equal, and none further off than that."""
+    assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    assert np.mean(a != b) < 0.01
+    np.testing.assert_allclose(a, b, rtol=2.0 ** -7, atol=2e-3)
+
+
 @pytest.mark.parametrize("name", sorted(FUSED_BWD_CASES))
 def test_fused_backward(name, monkeypatch):
     case = dict(FUSED_BWD_CASES[name])
     if "sub" in case:
         monkeypatch.setattr(fa, "_SUB", case["sub"])
+        monkeypatch.setattr(fa, "_OWN_SUB", case["sub"])
     H, K, causal = case["H"], case["K"], case["causal"]
     S = case.get("S", 512)
     T = case.get("T", S)
     window, dlse = case.get("window"), case.get("dlse", False)
     block = case.get("block", 128)
-    bq, bk, rel, d = min(block, T), min(block, S), S - T, 16
-    took = case.get("took", "fused")
-    assert fa._bwd_takes_fused(T, d, bq, bk, 2) == (took == "fused")
+    bq, bk, rel, d = min(block, T), min(block, S), S - T, case.get("d", 16)
+    dv, dr, own = case.get("dv", d), case.get("dr", 0), case.get("own")
+    kv_whole = case.get("kv_whole", False)
+    diag = (own, fa.DIAG_BEFORE) if own else None
+    took, segments = case.get("took", "fused"), case.get("segments", 1)
+
+    def budget_for(itemsize):
+        """What that many segments of the head hold is the budget (or the
+        case's own; None: the real one)."""
+        shape = (T, d, bq, bk, itemsize, dv, dr, bool(own))
+        n = case.get("segments_f32", segments) if itemsize == 4 else segments
+        budget = case.get("budget", fa._fused_bwd_vmem_bytes(
+            T // n, *shape[1:]))
+        if budget is not None:
+            monkeypatch.setattr(fa, "_FUSED_VMEM_BUDGET", budget)
+        assert fa._bwd_takes_fused(*shape) == (took == "fused")
+        assert fa._bwd_segments(*shape) == (n if took == "fused" else 0)
+        return n
+
+    budget_for(2)
 
     # (1) the kernels themselves, bf16 as in training: same dq, dk, dv (per
     # query head, before the GQA sum)
-    keys = jax.random.split(jax.random.key(7), 6)
-    qt = jax.random.normal(keys[0], (1, H, T, d), jnp.bfloat16)
-    kt = jax.random.normal(keys[1], (1, K, S, d), jnp.bfloat16)
-    vt = jax.random.normal(keys[2], (1, K, S, d), jnp.bfloat16)
-    do = jax.random.normal(keys[3], (1, H, T, d), jnp.bfloat16)
-    kernel_kw = dict(scale=d ** -0.5, causal=causal, window=window,
-                     block_q=bq, block_k=bk, rel_offset=rel)
-    out, lse = fa._fwd_pallas(qt, kt, vt, interpret=True, **kernel_kw)
+    keys = list(jax.random.split(jax.random.key(7), 10))
+    qt, kt, vt, do, qr, kr, ko, vo = (
+        jax.random.normal(key, (1, h, t, w), jnp.bfloat16)
+        for key, (h, t, w) in zip(keys[:4] + keys[6:], (
+            (H, T, d), (K, S, d), (K, S, dv), (H, T, dv), (H, T, dr),
+            (1, S, dr), (K, S, d), (K, S, dv))))
+    more = (qr, kr) if dr else (None, None)
+    more += (ko, vo) if own else ()
+    if kv_whole:
+        kt, vt = jnp.concatenate([kt, vt], axis=-1), None
+    kernel_kw = dict(scale=(d + dr) ** -0.5, causal=causal, window=window,
+                     block_q=bq, block_k=bk, rel_offset=rel, diag=diag)
+    out, lse = fa._fwd_pallas(
+        qt, kt, vt, interpret=True, **kernel_kw,
+        **(dict(q_rope=qr, k_rope=kr) if dr else {}),
+        **(dict(k_own=ko, v_own=vo) if own else {}))
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), -1)
     if dlse:
         delta = delta - jax.random.normal(keys[4], delta.shape, jnp.float32)
@@ -214,62 +312,138 @@ def test_fused_backward(name, monkeypatch):
     row, col = (1, H, 1, T), (1, H, T, 1)
     before = lowerings.snapshot()
     fused = fa._bwd_fused_call(qt, kt, vt, do, lse.reshape(row),
-                               delta.reshape(row), interpret=True, **kernel_kw)
-    # the counter: a head's tiles by arm and the sub-blocks of its crossed
-    # tiles, as the dense mask has them
-    hq, w = (min(case.get("sub", 512), b) for b in (bq, bk))
-    keep = _dense_keep(T, S, causal, window)
-    some, every = _some_every(keep, bq, bk)
-    sub_some, sub_every = _some_every(keep, hq, w)
-    masked = np.repeat(np.repeat(some & ~every, bq // hq, 0), bk // w, 1)
-    want = dict(masked=int((some & ~every).sum()), unmasked=int(every.sum()),
-                dead=int((~some).sum()),
-                sub_live=int((masked & sub_some).sum()),
-                sub_dead=int((masked & ~sub_some).sum()),
-                sub_inside=int((masked & sub_every).sum()))
-    assert want == case.get("tiles", want)
-    # (kept under the mask's name: a model's window and full layers both read)
-    want = {window or ("causal" if causal else "none"): want}
-    assert lowerings.since(before)["flash_bwd_tiles"] == want
-    split = fa._bwd_split_call(qt, kt, vt, do, lse.reshape(col),
-                               delta.reshape(col), interpret=True, **kernel_kw)
-    for a, b in zip(fused, split):
-        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        # bit for bit on the chip (PERF.md section 6, PR 29). The CPU sums a
-        # transposed operand's products in another order, which now and then
-        # moves the bf16 rounding of one P or dS and with it an element by
-        # 2^-8 of one of its terms: all but a few in a thousand equal, and
-        # none further off than that
-        assert np.mean(a != b) < 0.01
-        np.testing.assert_allclose(a, b, rtol=2.0 ** -7, atol=2e-3)
+                               delta.reshape(row), *more, interpret=True,
+                               **kernel_kw)
+    said = lowerings.since(before)
+    assert said["flash_bwd_segments"] == {d: segments}
+    want = None
+    if not own:
+        # the counter: a head's tiles by arm and the sub-blocks of its
+        # crossed tiles, as the dense mask has them
+        hq, w = (min(case.get("sub", 512), b) for b in (bq, bk))
+        keep = _dense_keep(T, S, causal, window)
+        some, every = _some_every(keep, bq, bk)
+        sub_some, sub_every = _some_every(keep, hq, w)
+        masked = np.repeat(np.repeat(some & ~every, bq // hq, 0), bk // w, 1)
+        want = dict(masked=int((some & ~every).sum()),
+                    unmasked=int(every.sum()), dead=int((~some).sum()),
+                    sub_live=int((masked & sub_some).sum()),
+                    sub_dead=int((masked & ~sub_some).sum()),
+                    sub_inside=int((masked & sub_every).sum()))
+        assert want == case.get("tiles", want)
+        # (kept under the mask's name: a model's window and full layers both
+        # read)
+        want = {window or ("causal" if causal else "none"): want}
+        assert said["flash_bwd_tiles"] == want
+
+    # the kv-tiles a segment's index maps keep (the others name the nearest
+    # of them: dead steps fetch nothing) are those some row of it keeps a
+    # key of, by the kernels' own predicate
+    live, _ = fa._tiles(T, S, bq, bk, causal, window, rel, diag)
+    for s in range(segments):
+        first, last = (T // segments * x + rel for x in (s, s + 1))
+        lo, hi = fa._live_kv_tiles(causal, window, first, last - 1, bk, diag)
+        kept = [lo <= ik <= (S // bk if hi is None else hi)
+                for ik in range(S // bk)]
+        seg_nq = T // bq // segments
+        assert live[s * seg_nq:(s + 1) * seg_nq].any(0).tolist() == kept
+
+    def split_pair(rows, rel):
+        stats = (x.reshape(col)[:, :, rows] for x in (lse, delta))
+        return fa._bwd_split_call(
+            qt[:, :, rows], kt, vt, do[:, :, rows], *stats,
+            more[0] if more[0] is None else more[0][:, :, rows], *more[1:],
+            interpret=True, **dict(kernel_kw, rel_offset=rel))
+
+    if segments == 1:
+        for a, b in zip(fused, split_pair(slice(None), rel)):
+            _held_to(a, b)
+    elif not own:
+        # a segment's rows of dq and its partials of the others: the split
+        # pair's over those rows alone
+        assert all(x.shape[0] == segments for x in fused[1:] if x is not None)
+        for s in range(segments):
+            rows = slice(s * T // segments, (s + 1) * T // segments)
+            fdq, *fkv = fused
+            sdq, *skv = split_pair(rows, rel + rows.start)
+            _held_to(fdq[:, :, rows], sdq)
+            for a, b in zip(fkv, skv):
+                assert (a is None) == (b is None)
+                if a is not None:
+                    _held_to(a[s], b)
+    else:
+        # (the own keys lie at every query's position: no split pair over a
+        # segment's rows alone.) dq and the own keys' gradients, which one
+        # segment holds and the others leave 0, as the split pair's; dk and
+        # dv to the bf16 rounding of a partial a segment
+        fdq, *fkv = fused
+        sdq, *skv = split_pair(slice(None), rel)
+        _held_to(fdq, sdq)
+        for a, b, whole in zip(fkv, skv, (False, False, True, True)):
+            assert a.shape == (segments,) + b.shape
+            if whole:
+                assert np.all(np.asarray(a != 0).any((1, 2, 4)).sum(0) <= 1)
+                _held_to(a.astype(jnp.float32).sum(0).astype(jnp.bfloat16),
+                         b)
+            else:
+                a = np.asarray(a, np.float32)
+                np.testing.assert_allclose(
+                    a.sum(0), np.asarray(b, np.float32), rtol=2.0 ** -7,
+                    atol=2.0 ** -8 * segments * np.abs(a).max())
 
     # (2) through the public entry in float32, against XLA's autodiff, and
     # (3) the lowering counter says which kernel the shape took
-    q, k, v = (x.astype(jnp.float32).transpose(0, 2, 1, 3)
-               for x in (qt, kt, vt))
-    w_out = jax.random.normal(keys[5], q.shape, jnp.float32)
+    args = [x.astype(jnp.float32).transpose(0, 2, 1, 3)
+            for x in (qt, kt, vt, qr, kr, ko, vo) if x is not None]
+    if not dr:
+        del args[2 + (vt is not None):4 + (vt is not None)]
+    if not own:
+        del args[-2:]
+    w_out = jax.random.normal(keys[5], (1, T, H, dv), jnp.float32)
     w_lse = jax.random.normal(keys[4], (1, H, T, 1), jnp.float32)
 
-    def f_flash(q, k, v):
+    def operands(args):
+        """(q, k, v, {the optional operands by name}) of ``args``."""
+        q, k, *rest = args
+        v = None if kv_whole else rest.pop(0)
+        named = {}
+        if dr:
+            named.update(q_rope=rest.pop(0), k_rope=rest.pop(0))
+        if own:
+            named.update(k_own=rest.pop(0), v_own=rest.pop(0))
+        return q, k, v, named
+
+    def f_flash(*args):
+        q, k, v, named = operands(args)
         kw = dict(causal=causal, window=window, block_q=bq, block_k=bk,
-                  interpret=True)
+                  interpret=True, diag=diag, **named)
         if not dlse and T == S:
             return (flash_attention(q, k, v, **kw) * w_out).sum()
         o, l = flash_attention_lse(q, k, v, rel_offset=rel, **kw)
         return (o * w_out).sum() + ((l * w_lse).sum() if dlse else 0.0)
 
-    def f_ref(q, k, v):
+    def f_ref(*args):
+        q, k, v, named = operands(args)
+        if own:
+            return (_ref_own(q, k, v, named["k_own"], named["v_own"], own)
+                    * w_out).sum()
+        q, k, v = fa.assembled(q, k, v, named.get("q_rope"),
+                               named.get("k_rope"))
         o = xla_attention(q, k, v, causal=causal, window=window)
         l = _ref_lse(q, k, causal, window) if dlse else 0.0
         return (o * w_out).sum() + (l * w_lse).sum()
 
+    segments = budget_for(4)
     before = lowerings.snapshot()
-    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.grad(f_flash, argnums=tuple(range(len(args))))(*args)
     said = lowerings.since(before)
     assert said["flash_bwd"] == {took: 1}
-    assert said.get("flash_bwd_tiles") == (want if took == "fused" else None)
-    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    assert said.get("flash_bwd_segments") == (
+        {d: segments} if took == "fused" else None)
+    if not own:
+        assert said.get("flash_bwd_tiles") == (
+            want if took == "fused" else None)
+    g2 = jax.grad(f_ref, argnums=tuple(range(len(args))))(*args)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 

@@ -391,7 +391,7 @@ def test_the_own_keys_add_no_grid_step_and_work_their_live_sub_blocks():
         "masked": 8, "unmasked": 0, "dead": 0, "sub_live": 32,
         "sub_dead": 96, "sub_inside": 0}
     # the fused backward holds the own tiles and their gradients too
-    assert fa._bwd_takes_fused(8192, 128, 1024, 1024, 2, own=True)
+    assert fa._bwd_segments(8192, 128, 1024, 1024, 2, own=True) == 1
     assert fa._fused_bwd_vmem_bytes(8192, 128, 1024, 1024, 2, own=True) \
         - fa._fused_bwd_vmem_bytes(8192, 128, 1024, 1024, 2) == 2 * 2 ** 20
 
